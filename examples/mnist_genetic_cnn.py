@@ -1,6 +1,6 @@
 """BASELINE config #1: Genetic CNN on MNIST, S=(3,5), 10 individuals.
 
-Single-process, CPU-runnable (pass --cpu to force the virtual CPU mesh).
+Single-process, CPU-runnable (run under JAX_PLATFORMS=cpu to stay off the chip).
 Mirrors the reference's MNIST example (gentun examples [PUB]); data loads
 offline (sklearn digits upscaled, or real MNIST via GENTUN_TPU_DATA).
 """
@@ -30,13 +30,7 @@ def main(argv=None):
     ap.add_argument("--batch-size", type=int, default=128)
     ap.add_argument("--dense-units", type=int, default=500)
     ap.add_argument("--checkpoint", default="")
-    ap.add_argument("--cpu", action="store_true", help="force CPU (no TPU touch)")
     args = ap.parse_args(argv)
-
-    if args.cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
 
     if args.n_images is not None and args.n_images <= 0:
         raise SystemExit(f"--n-images must be positive, got {args.n_images}")

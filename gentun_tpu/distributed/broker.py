@@ -124,13 +124,13 @@ class _Worker:
 
     __slots__ = ("worker_id", "writer", "capacity", "prefetch_depth", "credit",
                  "in_flight", "last_seen", "n_chips", "backend", "draining",
-                 "mesh", "caps", "preemptible", "homes")
+                 "mesh", "caps", "preemptible", "homes", "device")
 
     def __init__(self, worker_id: str, writer: asyncio.StreamWriter, capacity: int,
                  n_chips: int = 1, backend: Optional[str] = None,
                  prefetch_depth: int = 0, mesh: Optional[Dict[str, int]] = None,
                  caps: frozenset = frozenset(), preemptible: bool = False,
-                 homes: int = 1):
+                 homes: int = 1, device: Optional[Dict[str, Any]] = None):
         self.worker_id = worker_id
         self.writer = writer
         self.capacity = capacity
@@ -165,6 +165,10 @@ class _Worker:
         #: capacity sums correctly: a 2-homed capacity-8 worker shows 8 on
         #: BOTH shards.  1 for every single-homed (old) worker.
         self.homes = homes
+        #: Device advertisement (protocol.py "Device field"):
+        #: {"platform", "kind", "count"} as the worker's jax reports them;
+        #: None for non-jax species and workers that never sent one.
+        self.device = device
         #: True once the worker announced an orderly exit (elastic
         #: membership): no new dispatches, excluded from the fleet sums —
         #: but still a live connection until its in-flight results land.
@@ -1248,6 +1252,13 @@ class JobBroker:
         """
         return max(1, sum(w.n_chips for w in list(self._workers.values())))
 
+    def fleet_devices(self) -> List[Dict[str, Any]]:
+        """The connected workers' ``device`` advertisements (protocol.py
+        "Device field"); workers that sent none are left out.  Snapshot
+        read — safe from any thread."""
+        return [dict(w.device) for w in list(self._workers.values())
+                if w.device is not None]
+
     def reset_chips_seen(self) -> None:
         """Start a fresh per-sweep chip-count observation window."""
         with self._cond:
@@ -1322,6 +1333,26 @@ class JobBroker:
         except (TypeError, ValueError):
             return 1
         return max(1, homes)
+
+    @staticmethod
+    def _parse_device(hello: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+        """The worker's OPTIONAL device advertisement, validated.
+
+        Expects ``{"platform": str, "kind": str, "count": int >= 1}``.
+        Advisory — malformed values degrade to None, never drop the
+        worker, same convention as ``n_chips``.
+        """
+        device = hello.get("device")
+        if not isinstance(device, dict):
+            return None
+        platform, kind = device.get("platform"), device.get("kind")
+        try:
+            count = int(device.get("count", 0))
+        except (TypeError, ValueError):
+            return None
+        if not isinstance(platform, str) or not isinstance(kind, str) or count < 1:
+            return None
+        return {"platform": platform, "kind": kind, "count": count}
 
     @staticmethod
     def _parse_mesh(msg: Dict[str, Any]) -> Optional[Dict[str, int]]:
@@ -2120,6 +2151,7 @@ class JobBroker:
             "mesh": w.mesh,
             "wire_caps": sorted(w.caps),
             "homes": w.homes,
+            "device": w.device,
         } for w in list(self._workers.values())]
         return {
             "address": list(self._bound) if self._started.is_set() else None,
@@ -2212,6 +2244,7 @@ class JobBroker:
                 # stable, the conservative placement default.
                 preemptible=hello.get("preemptible") is True,
                 homes=self._parse_homes(hello),
+                device=self._parse_device(hello),
             )
             # Heterogeneous-fleet check (ADVICE r3): two workers scoring one
             # generation with different estimators (e.g. xgb.cv on one host,
@@ -2260,9 +2293,11 @@ class JobBroker:
                 welcome["boot_id"] = self._boot_id
             writer.write(encode(welcome))
             logger.info(
-                "worker %s connected (capacity %d, prefetch %d, %d chip(s)%s)",
+                "worker %s connected (capacity %d, prefetch %d, %d chip(s)%s%s)",
                 worker.worker_id, worker.capacity, worker.prefetch_depth,
                 worker.n_chips,
+                ", %(count)d x %(kind)s on %(platform)s" % worker.device
+                if worker.device else "",
                 ", mesh pop=%(pop)d x data=%(data)d" % worker.mesh
                 if worker.mesh else "",
             )

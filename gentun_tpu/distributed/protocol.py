@@ -26,6 +26,17 @@ worker → broker       ``ping`` {}               liveness, from a side thread
 per-chip metric) and ``backend`` (fitness-model class name — the broker
 warns on a heterogeneous fleet).
 
+Device field (OPTIONAL, advisory — a broker that ignores it sees the same
+frames as before):
+
+- ``hello`` may carry ``device`` {platform, kind, count}: what the
+  worker's jax reports it runs on (``jax.devices()[0].platform`` /
+  ``.device_kind``, ``jax.device_count()``), sent by jax species only.
+  ``n_chips`` says how many; this says of what, so a master can tell a
+  fleet that came up on CPUs from one on TPUs (``broker.fleet_devices()``,
+  the ``/statusz`` fleet table; ``chip_smoke.py`` asserts on it).
+  Malformed values degrade to "no device recorded".
+
 Pipelined-dispatch field (new fields are OPTIONAL with conservative
 defaults, the same versioning convention as the telemetry fields below —
 old workers and old masters interoperate unchanged):

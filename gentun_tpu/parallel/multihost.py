@@ -65,44 +65,6 @@ logger = logging.getLogger("gentun_tpu")
 _coordinator: Optional[str] = None
 
 
-def _enable_cpu_collectives() -> None:
-    """Give CPU-backend clusters a cross-process collectives implementation.
-
-    jaxlib's default CPU client has none: the first collective of a
-    multi-process CPU cluster raises ``Multiprocess computations aren't
-    implemented on the CPU backend``.  jax ≥ 0.4.3x ships gloo behind
-    ``jax_cpu_collectives_implementation``, which must be set BEFORE the
-    backend initializes — exactly where :func:`initialize` sits.  Only the
-    CPU platform is touched (TPU slices ride ICI and never take this
-    path), an explicit user setting wins, and an older jax without the
-    option is left alone (its CPU clusters simply can't collective — the
-    tests skip there).
-    """
-    platforms = (os.environ.get("JAX_PLATFORMS")
-                 or str(getattr(jax.config, "jax_platforms", None) or "")).lower()
-    if "cpu" not in platforms:
-        return
-    try:
-        # The option has no attribute accessor in jax 0.4.3x; _read is the
-        # only way to see the current value ('none' = jaxlib's default).
-        current = jax.config._read("jax_cpu_collectives_implementation")
-    except Exception:
-        return
-    if current in (None, "", "none"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # pragma: no cover - gloo not compiled into jaxlib
-            return
-    # The XLA:CPU thunk runtime races gloo's TCP pairs on multi-collective
-    # programs (sharded CV aborts with "gloo::EnforceNotMet ...
-    # op.preamble.length <= op.nbytes"); the pre-thunk runtime runs them
-    # correctly.  Must land in XLA_FLAGS before the first backend init —
-    # which is why this hook lives at the top of :func:`initialize`.
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" not in flags:
-        os.environ["XLA_FLAGS"] = (flags + " --xla_cpu_use_thunk_runtime=false").strip()
-
-
 def initialize(
     coordinator: str,
     num_processes: Optional[int] = None,
@@ -119,7 +81,6 @@ def initialize(
     required.
     """
     global _coordinator
-    _enable_cpu_collectives()
     kwargs: dict = {"coordinator_address": coordinator}
     if num_processes is not None:
         kwargs["num_processes"] = int(num_processes)

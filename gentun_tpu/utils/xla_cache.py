@@ -7,14 +7,24 @@ pay the full XLA compile again.  jax ships a persistent on-disk compilation
 cache; this module is the one place that manages it, so every entry point
 (models, bench, examples) shares the same knob.
 
-The cache is **ON by default** at ``~/.cache/gentun_tpu/xla`` (measured
-3-6× cheaper than recompiling on restart — DISTRIBUTED.md).  Control it:
+The cache is **ON by default**, and its place follows one rule
+(:func:`default_cache_dir`):
 
-- ``GENTUN_TPU_CACHE_DIR=/path/to/cache`` relocates it;
-- ``GENTUN_TPU_CACHE_DIR=off`` (or ``0``/``none``/``disabled``) turns it
-  off, as does ``cache_dir=False`` on ``GeneticCnnModel`` /
-  ``additional_parameters``;
-- ``enable_compilation_cache("/path")`` enables it programmatically.
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it and
+  that directory is the cache — nothing in this package re-points
+  ``jax_compilation_cache_dir`` anywhere else, so whoever launches the
+  program (a chip driver, an operator) decides where compiled programs
+  survive;
+- otherwise it is ``<checkout>/.jax_cache`` — one fixed, git-ignored
+  directory beside the package, never derived from ``$HOME``, a temp name,
+  a pid or the time, so two processes of one checkout always share it.
+
+``GENTUN_TPU_CACHE_DIR=off`` (or ``0``/``none``/``disabled``) is the kill
+switch (the test suite uses it), as is ``cache_dir=False`` on
+``GeneticCnnModel`` / ``additional_parameters``.  It only disables; it is
+not a second way to place the cache.  ``enable_compilation_cache("/path")``
+enables a directory programmatically where the environment has not placed
+one.
 
 An unwritable cache directory degrades to caching disabled with a loud
 warning — it must never take the training path down.
@@ -44,7 +54,12 @@ logger = logging.getLogger("gentun_tpu")
 
 _enabled_dir: Optional[str] = None
 _failed_dirs: set = set()  # dirs that failed makedirs — don't retry/re-warn
-_missing_knobs: set = set()  # jax config keys this jax lacks — warn once each
+
+#: The in-checkout default: the directory that holds the ``gentun_tpu``
+#: package, i.e. the repository root of a checkout.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
 
 # Publish hooks: the compile cache service client
 # (``distributed/compile_service.py``) registers its scan-and-publish here
@@ -54,34 +69,43 @@ _missing_knobs: set = set()  # jax config keys this jax lacks — warn once each
 _publish_hooks: list = []
 
 
+def _env_cache_dir() -> Optional[str]:
+    """``JAX_COMPILATION_CACHE_DIR`` as jax itself reads it, or None."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip() or None
+
+
 def default_cache_dir() -> Optional[str]:
     """The persistent-cache directory, ON by default (opt out explicitly).
 
-    Resolution: ``GENTUN_TPU_CACHE_DIR`` if set (the values ``0``, ``off``
-    and ``none`` disable caching); otherwise ``~/.cache/gentun_tpu/xla``.
-    Measured on the real chip (DISTRIBUTED.md): a restarted search pays
-    15-25 s per program to load from this cache versus 70-145 s to
-    recompile — too big a win to leave opt-in.
+    ``GENTUN_TPU_CACHE_DIR`` set to ``0``/``off``/``none``/``disabled``
+    returns None (caching is left alone).  Otherwise the directory is
+    ``JAX_COMPILATION_CACHE_DIR`` where the environment sets it, else the
+    fixed ``<checkout>/.jax_cache``.  Measured on the chip in July 2026
+    (DISTRIBUTED.md): a restarted search loaded a program from this cache
+    in 15-25 s against 70-145 s to recompile it.
     """
-    d = os.environ.get("GENTUN_TPU_CACHE_DIR", "").strip()
-    if d.lower() in ("0", "off", "none", "disabled"):
+    kill = os.environ.get("GENTUN_TPU_CACHE_DIR", "").strip().lower()
+    if kill in ("0", "off", "none", "disabled"):
         return None
-    if d:
-        return d
-    return os.path.join(os.path.expanduser("~"), ".cache", "gentun_tpu", "xla")
+    return _env_cache_dir() or _CHECKOUT_CACHE_DIR
 
 
 def enable_compilation_cache(cache_dir: str) -> Optional[str]:
     """Point jax's persistent compilation cache at ``cache_dir``.
 
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory wins over
+    the argument: jax read it at import, ``jax_compilation_cache_dir`` is
+    left exactly as found, and only the thresholds below are applied.
+
     Idempotent; safe to call before or after jax backend init (the cache is
     consulted at compile time, not at backend-init time).  Returns the
-    directory on success, or ``None`` when it could not be enabled (ADVICE
-    r4: callers must be able to tell the difference — and a failed dir must
-    not shadow a previously-enabled one, which stays active in jax).
+    directory in use on success, or ``None`` when it could not be enabled
+    (ADVICE r4: callers must be able to tell the difference — and a failed
+    dir must not shadow a previously-enabled one, which stays active in jax).
     """
     global _enabled_dir
-    cache_dir = os.path.abspath(os.path.expanduser(str(cache_dir)))
+    env_dir = _env_cache_dir()
+    cache_dir = os.path.abspath(os.path.expanduser(str(env_dir or cache_dir)))
     if _enabled_dir == cache_dir:
         return cache_dir
     if cache_dir in _failed_dirs:
@@ -89,8 +113,7 @@ def enable_compilation_cache(cache_dir: str) -> Optional[str]:
     try:
         os.makedirs(cache_dir, exist_ok=True)
     except OSError as e:
-        # On-by-default must not break environments with unwritable HOMEs
-        # (read-only containers, HOME=/nonexistent CI): degrade loudly.
+        # On-by-default must not break read-only checkouts: degrade loudly.
         _failed_dirs.add(cache_dir)  # don't retry (and re-warn) every call
         if _enabled_dir is not None:
             logger.warning(
@@ -100,62 +123,31 @@ def enable_compilation_cache(cache_dir: str) -> Optional[str]:
         else:
             logger.warning(
                 "persistent XLA cache dir %s is unusable (%s); caching DISABLED "
-                "— set GENTUN_TPU_CACHE_DIR to a writable path or to 'off' to "
-                "silence this", cache_dir, e,
+                "— set JAX_COMPILATION_CACHE_DIR to a writable path, or "
+                "GENTUN_TPU_CACHE_DIR=off to silence this", cache_dir, e,
             )
         return None
     import jax
 
-    try:
+    if env_dir is None:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception as e:  # noqa: BLE001 - version probe, not control flow
-        # A jax without the persistent cache at all (ancient or exotic
-        # build): degrade loudly instead of raising out of every entry
-        # point — the training path must survive, it just recompiles.
-        _failed_dirs.add(cache_dir)
-        logger.warning(
-            "this jax (%s) does not support the persistent compilation "
-            "cache (%s); caching DISABLED — restarts and elastic joins "
-            "will pay full recompiles", getattr(jax, "__version__", "?"), e)
-        return None
-    if _enabled_dir is not None and _enabled_dir != cache_dir:
-        # jax materializes its cache object lazily and keeps it for the
-        # process lifetime: without a reset, writes keep landing in the OLD
-        # dir even though the config now names the new one (silently, as a
-        # UserWarning per entry once the old dir disappears).
-        try:
+        if _enabled_dir is not None:
+            # jax materializes its cache object lazily and keeps it for the
+            # process lifetime: without a reset, writes keep landing in the
+            # OLD dir even though the config now names the new one.
             from jax.experimental.compilation_cache import compilation_cache as _cc
 
             _cc.reset_cache()
-        except Exception as e:  # noqa: BLE001 - version probe
-            logger.warning(
-                "could not reset jax's compilation-cache object while "
-                "switching %s -> %s (%s); cache writes may keep using the "
-                "old directory", _enabled_dir, cache_dir, e)
     # GA fitness programs compile in well under the default 1 s threshold on
-    # CPU test runs; cache everything.  jax versions that lack these knobs
-    # keep the cache enabled with their default thresholds — degraded
-    # loudly (once per knob), because small programs may silently not be
-    # cached there.
-    # The third knob makes cache keys independent of the cache dir PATH:
-    # by default jax derives an xla_gpu_per_fusion_autotune_cache_dir
-    # under the cache dir and hashes that absolute path into every cache
-    # key, so two hosts mounting the cache at different paths could never
-    # reuse each other's artifacts through the compile service.
-    for knob, value in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-            ("jax_persistent_cache_enable_xla_caches", "none")):
-        try:
-            jax.config.update(knob, value)
-        except Exception as e:  # noqa: BLE001 - version probe
-            if knob not in _missing_knobs:
-                _missing_knobs.add(knob)
-                logger.warning(
-                    "this jax (%s) has no %s config key (%s); the "
-                    "persistent cache stays enabled with jax's default "
-                    "threshold — small/fast programs may not be cached",
-                    getattr(jax, "__version__", "?"), knob, e)
+    # CPU test runs; cache everything.  The third knob makes cache keys
+    # independent of the cache dir PATH: by default jax derives an
+    # xla_gpu_per_fusion_autotune_cache_dir under the cache dir and hashes
+    # that absolute path into every cache key, so two hosts (or two
+    # checkouts) holding the cache at different paths could never reuse
+    # each other's artifacts.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
     _enabled_dir = cache_dir
     logger.info("persistent XLA compilation cache enabled at %s", cache_dir)
     return cache_dir

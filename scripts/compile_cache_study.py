@@ -235,8 +235,8 @@ def run_service_killed_study() -> dict:
 
     root = tempfile.mkdtemp(prefix="compile-kill-")
     cache_dir = os.path.join(root, "xla")
-    saved_env = os.environ.get("GENTUN_TPU_CACHE_DIR")
-    os.environ["GENTUN_TPU_CACHE_DIR"] = cache_dir
+    saved_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     sink = _ListSink()
     spans_mod.enable()
     spans_mod.set_run_sink(sink)
@@ -303,9 +303,9 @@ def run_service_killed_study() -> dict:
         spans_mod.disable()
         spans_mod.set_run_sink(None)
         if saved_env is None:
-            os.environ.pop("GENTUN_TPU_CACHE_DIR", None)
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
         else:
-            os.environ["GENTUN_TPU_CACHE_DIR"] = saved_env
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = saved_env
         shutil.rmtree(root, ignore_errors=True)
 
     return {

@@ -52,8 +52,10 @@ def run_variant(name: str, spec_flag: str, args, port: int) -> dict:
     env = dict(os.environ)
     if args.tiny:
         env["JAX_PLATFORMS"] = "cpu"
-    master_log = open(os.path.join(REPO, "scripts", "logs", f"tailgen_{name}_master.log"), "w")
-    worker_log = open(os.path.join(REPO, "scripts", "logs", f"tailgen_{name}_worker.log"), "w")
+    log_dir = os.path.join(REPO, "scripts", "logs")
+    os.makedirs(log_dir, exist_ok=True)  # git-ignored, so absent in a fresh copy
+    master_log = open(os.path.join(log_dir, f"tailgen_{name}_master.log"), "w")
+    worker_log = open(os.path.join(log_dir, f"tailgen_{name}_worker.log"), "w")
     t0 = time.monotonic()
     master = subprocess.Popen(master_cmd, cwd=REPO, env=env,
                               stdout=master_log, stderr=subprocess.STDOUT)

@@ -446,9 +446,11 @@ class TestEndToEnd:
             seed=ga_seed)
         ref.run(generations)
 
-        # The worker's compile client resolves its cache dir from the env.
+        # The worker's compile client resolves its cache dir from the env
+        # (conftest's kill switch would leave it with none).
         cache_dir = tmp_path / "xla"
-        monkeypatch.setenv("GENTUN_TPU_CACHE_DIR", str(cache_dir))
+        monkeypatch.delenv("GENTUN_TPU_CACHE_DIR", raising=False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
         sink = _ListSink()
         spans_mod.enable()
         spans_mod.set_run_sink(sink)

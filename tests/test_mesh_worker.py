@@ -237,3 +237,52 @@ class TestHostMeshEndToEnd:
         finally:
             stop.set()
             pop.close()
+
+
+class JaxOneMax(OneMax):
+    uses_jax = True  # the device advert is for species whose fitness runs on jax
+
+
+class TestDeviceAdvert:
+    """The OPTIONAL ``device`` hello field (protocol.py "Device field")."""
+
+    def _join(self, species):
+        pop = DistributedPopulation(species, size=2, seed=0, port=0, job_timeout=30)
+        stop = threading.Event()
+        client = GentunClient(species, *DATA, host="127.0.0.1",
+                              port=pop.broker_address[1], heartbeat_interval=0.2,
+                              reconnect_delay=0.05)
+        threading.Thread(target=lambda: client.work(stop_event=stop), daemon=True).start()
+        assert _wait(lambda: pop.broker.fleet_members() == 1)
+        return pop, stop
+
+    def test_jax_species_tells_the_master_what_it_runs_on(self):
+        pop, stop = self._join(JaxOneMax)
+        try:
+            # conftest: 8 virtual CPU devices
+            want = {"platform": "cpu", "kind": "cpu", "count": 8}
+            assert pop.broker.fleet_devices() == [want]
+            assert pop.broker._ops_status()["workers"][0]["device"] == want
+            assert pop.broker.fleet_chips() == 8
+        finally:
+            stop.set()
+            pop.close()
+
+    def test_other_species_send_no_device_field(self):
+        pop, stop = self._join(OneMax)
+        try:
+            assert pop.broker.fleet_devices() == []
+            assert pop.broker._ops_status()["workers"][0]["device"] is None
+        finally:
+            stop.set()
+            pop.close()
+
+    def test_malformed_adverts_degrade_to_none(self):
+        from gentun_tpu.distributed.broker import JobBroker
+
+        parse = JobBroker._parse_device
+        good = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}
+        assert parse({"device": good}) == good
+        for bad in (None, "tpu", {"platform": "tpu"}, dict(good, count=0),
+                    dict(good, count="many"), dict(good, kind=5)):
+            assert parse({"device": bad}) is None

@@ -80,9 +80,7 @@ _ENV_SKIP_PATTERNS = (
      "this jaxlib's CPU backend has no cross-process collectives "
      "implementation (jax_cpu_collectives_implementation/gloo unavailable)"),
     ("gloo::EnforceNotMet",
-     "jaxlib's gloo CPU collectives crashed inside the cluster child "
-     "(XLA:CPU thunk-runtime incompatibility, see "
-     "parallel/multihost.py::_enable_cpu_collectives)"),
+     "jaxlib's gloo CPU collectives crashed inside the cluster child"),
     ("external/gloo/gloo/transport/tcp",
      "jaxlib's gloo TCP collectives lost a peer mid-collective (abort "
      "cascade — seen with 8 ranks contending for this box's single CPU "

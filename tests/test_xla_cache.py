@@ -1,9 +1,11 @@
 """Persistent XLA compilation cache tests (SURVEY.md §7 hard part #1).
 
-The claim under test: a *second process* running the same search config reuses
-the on-disk compiled program instead of recompiling.  Each run happens in a
-fresh subprocess (so no in-process jit cache can help), pinned to a single
-CPU device for byte-identical cache keys.
+The claims under test: the cache lives where ``JAX_COMPILATION_CACHE_DIR``
+puts it, else in one fixed directory inside the checkout; and a *second
+process* running the same search config reuses the on-disk compiled program
+instead of recompiling.  Each run happens in a fresh subprocess (so no
+in-process jit cache can help), pinned to a single CPU device for
+byte-identical cache keys.
 """
 
 import json
@@ -12,8 +14,6 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
 from gentun_tpu.utils.xla_cache import (
     cache_stats,
     default_cache_dir,
@@ -21,76 +21,132 @@ from gentun_tpu.utils.xla_cache import (
     list_cache_entries,
 )
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# One tiny CV run that asks for its own cache directory (argv[1]) and says
+# where jax's persistent-cache config pointed before and after.
 RUN_CV = textwrap.dedent(
     """
-    import json, os, sys, time
+    import json, sys
     import numpy as np
+    import jax
 
-    cache_dir = sys.argv[1]
-
+    before = jax.config.jax_compilation_cache_dir
     from gentun_tpu.models.cnn import GeneticCnnModel
 
     rng = np.random.default_rng(0)
     x = rng.normal(size=(64, 8, 8, 1)).astype(np.float32)
     y = rng.integers(0, 2, size=64).astype(np.int32)
-    t0 = time.monotonic()
     accs = GeneticCnnModel.cross_validate_population(
         x, y, [{"S_1": (1, 0, 1)}],
         nodes=(3,), kernels_per_layer=(4,), kfold=2, epochs=(1,),
         learning_rate=(0.05,), batch_size=16, dense_units=8,
-        compute_dtype="float32", seed=0, cache_dir=cache_dir,
+        compute_dtype="float32", seed=0, cache_dir=sys.argv[1],
     )
-    print(json.dumps({"wall_s": time.monotonic() - t0, "acc": float(accs[0])}))
+    print(json.dumps({"acc": float(accs[0]), "before": before,
+                      "after": jax.config.jax_compilation_cache_dir}))
     """
 )
 
+PRINT_DEFAULT = (
+    "from gentun_tpu.utils.xla_cache import default_cache_dir; "
+    "print(default_cache_dir())"
+)
 
-def _run_in_subprocess(cache_dir: str) -> dict:
-    env = dict(os.environ)
+
+def _subprocess_env(home, env_cache_dir=None) -> dict:
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("GENTUN_TPU_CACHE_DIR", "JAX_COMPILATION_CACHE_DIR")}
     env["JAX_PLATFORMS"] = "cpu"
-    # ONE device: the test asserts cache hits, and the cache key includes the
+    # ONE device: the tests assert cache hits, and the cache key includes the
     # device topology, so both runs must see identical topology.
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
+    env["HOME"] = str(home)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    if env_cache_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_cache_dir)
+    return env
+
+
+def _run(code: str, env: dict, cwd: str, *argv: str) -> str:
     proc = subprocess.run(
-        [sys.executable, "-c", RUN_CV, cache_dir],
-        capture_output=True,
-        text=True,
-        env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        timeout=300,
+        [sys.executable, "-c", code, *argv],
+        capture_output=True, text=True, env=env, cwd=cwd, timeout=300,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return proc.stdout.strip().splitlines()[-1]
 
 
-class TestPersistentCompilationCache:
-    def test_second_process_reuses_compiled_program(self, tmp_path):
-        cache_dir = str(tmp_path / "xla-cache")
-        self_snapshot = lambda: sorted(os.listdir(cache_dir))
+class TestCachePlacement:
+    """Where the persistent cache lives (ISSUE 21): the environment places
+    it; otherwise one fixed directory inside the checkout."""
 
-        _run_in_subprocess(cache_dir)
-        entries_after_first = self_snapshot()
-        assert entries_after_first, "first run wrote no cache entries"
+    def test_env_dir_takes_every_entry_and_second_process_adds_none(self, tmp_path):
+        placed, asked, home = tmp_path / "placed", tmp_path / "asked", tmp_path / "home"
+        home.mkdir()
+        env = _subprocess_env(home, env_cache_dir=placed)
 
-        _run_in_subprocess(cache_dir)
-        entries_after_second = self_snapshot()
+        first = json.loads(_run(RUN_CV, env, REPO, str(asked)))
+        # jax read the variable itself and nothing re-pointed it, not even
+        # the cache_dir= this call passed.
+        assert first["before"] == first["after"] == str(placed)
+        entries = sorted(os.listdir(placed))
+        assert entries, "first run wrote no cache entries"
+        assert not asked.exists()
+        assert not (home / ".cache").exists()
+
+        second = json.loads(_run(RUN_CV, env, REPO, str(asked)))
+        assert second["acc"] == first["acc"]
         # All compiles hit the persistent cache: no new entries were written.
-        assert entries_after_second == entries_after_first
+        assert sorted(os.listdir(placed)) == entries
 
-    def test_enable_is_idempotent(self, tmp_path):
-        d = str(tmp_path / "c")
-        assert enable_compilation_cache(d) == enable_compilation_cache(d)
+    def test_unset_env_means_one_fixed_dir_inside_the_checkout(self, tmp_path):
+        dirs = []
+        for name in ("a", "b"):
+            home = tmp_path / name
+            home.mkdir()
+            env = _subprocess_env(home)
+            env["TMPDIR"] = str(home)
+            dirs.append(_run(PRINT_DEFAULT, env, str(home)))
+        assert dirs[0] == dirs[1] == os.path.join(REPO, ".jax_cache")
+        with open(os.path.join(REPO, ".gitignore")) as fh:
+            assert ".jax_cache/" in fh.read().split()
 
-    def test_default_cache_dir_env(self, monkeypatch):
-        # ON by default (opt out with 0/off/none): a restarted search
-        # loads programs from disk instead of recompiling (DISTRIBUTED.md).
+    def test_default_cache_dir_rule(self, monkeypatch):
         monkeypatch.delenv("GENTUN_TPU_CACHE_DIR", raising=False)
-        assert default_cache_dir().endswith("gentun_tpu/xla")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert default_cache_dir() == os.path.join(REPO, ".jax_cache")
+        # GENTUN_TPU_CACHE_DIR is a kill switch only: a path there places nothing.
         monkeypatch.setenv("GENTUN_TPU_CACHE_DIR", "/tmp/foo")
-        assert default_cache_dir() == "/tmp/foo"
+        assert default_cache_dir() == os.path.join(REPO, ".jax_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/placed")
+        assert default_cache_dir() == "/tmp/placed"
         for off in ("0", "off", "NONE", "disabled"):
             monkeypatch.setenv("GENTUN_TPU_CACHE_DIR", off)
             assert default_cache_dir() is None
+
+    def test_enable_never_repoints_a_cache_placed_from_outside(self, tmp_path, monkeypatch):
+        import jax
+
+        from gentun_tpu.utils import xla_cache
+
+        placed = str(tmp_path / "placed")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        monkeypatch.setattr(xla_cache, "_enabled_dir", None)
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda name, value: (updates.append(name), real_update(name, value)))
+        assert xla_cache.enable_compilation_cache(str(tmp_path / "asked")) == placed
+        assert "jax_compilation_cache_dir" not in updates
+        assert "jax_persistent_cache_min_compile_time_secs" in updates
+        assert not (tmp_path / "asked").exists()
+
+    def test_enable_is_idempotent(self, tmp_path, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        d = str(tmp_path / "c")
+        assert enable_compilation_cache(d) == enable_compilation_cache(d)
 
 
 class TestEntryListing:
@@ -132,8 +188,10 @@ class TestEntryListing:
 
 
 class TestCacheOptOutAndDegrade:
-    def test_unwritable_dir_degrades_with_warning(self, caplog):
+    def test_unwritable_dir_degrades_with_warning(self, caplog, monkeypatch):
         import logging
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
 
         from gentun_tpu.utils import xla_cache
 
@@ -148,8 +206,10 @@ class TestCacheOptOutAndDegrade:
             for r in caplog.records
         )
 
-    def test_failed_dir_does_not_shadow_enabled_dir(self, tmp_path):
+    def test_failed_dir_does_not_shadow_enabled_dir(self, tmp_path, monkeypatch):
         from gentun_tpu.utils import xla_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
 
         good = str(tmp_path / "good")
         assert xla_cache.enable_compilation_cache(good) == os.path.abspath(good)
@@ -166,6 +226,7 @@ class TestCacheOptOutAndDegrade:
 
         from gentun_tpu.utils import xla_cache
 
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         calls = []
         monkeypatch.setattr(cc, "reset_cache", lambda: calls.append(1))
         a, b = str(tmp_path / "a"), str(tmp_path / "b")
@@ -175,51 +236,6 @@ class TestCacheOptOutAndDegrade:
         assert len(calls) == n0, "same-dir re-enable must not reset"
         assert xla_cache.enable_compilation_cache(b) == os.path.abspath(b)
         assert len(calls) == n0 + 1, "dir switch must reset jax's cache object"
-
-    def test_missing_config_knobs_degrade_loudly(self, tmp_path, caplog, monkeypatch):
-        """A jax without the threshold knobs keeps the cache ENABLED (with
-        jax's default thresholds) and warns once — it must never raise out
-        of an entry point."""
-        import logging
-
-        import jax
-
-        from gentun_tpu.utils import xla_cache
-
-        real_update = jax.config.update
-
-        def picky_update(name, value):
-            if name.startswith("jax_persistent_cache_min"):
-                raise AttributeError(f"no config key {name}")
-            return real_update(name, value)
-
-        monkeypatch.setattr(jax.config, "update", picky_update)
-        monkeypatch.setattr(xla_cache, "_missing_knobs", set())
-        d = str(tmp_path / "degraded")
-        with caplog.at_level(logging.WARNING, logger="gentun_tpu"):
-            assert xla_cache.enable_compilation_cache(d) == os.path.abspath(d)
-            # Idempotent second call: no duplicate warnings.
-            assert xla_cache.enable_compilation_cache(d) == os.path.abspath(d)
-        knob_warnings = [r for r in caplog.records if "config key" in r.message]
-        assert len(knob_warnings) == 2  # one per missing knob, warned once
-
-    def test_jax_without_persistent_cache_disables_loudly(self, tmp_path, caplog, monkeypatch):
-        import logging
-
-        import jax
-
-        from gentun_tpu.utils import xla_cache
-
-        def no_cache_update(name, value):
-            raise AttributeError(f"no config key {name}")
-
-        monkeypatch.setattr(jax.config, "update", no_cache_update)
-        d = str(tmp_path / "unsupported")
-        with caplog.at_level(logging.WARNING, logger="gentun_tpu"):
-            assert xla_cache.enable_compilation_cache(d) is None
-        assert any("caching DISABLED" in r.message for r in caplog.records)
-        # The failure is remembered: no retry storm on later entry points.
-        assert os.path.abspath(d) in xla_cache._failed_dirs
 
     def test_cache_dir_false_is_programmatic_opt_out(self, monkeypatch):
         import jax
