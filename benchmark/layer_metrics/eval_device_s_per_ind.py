@@ -1,0 +1,8 @@
+"""Device seconds of the eval program per individual: the durations of
+``jit_eval_fold`` on the trace's "XLA Modules" line over the individuals of the
+``cv_call``s traced (``scope_reduce.py``)."""
+import scope_reduce
+
+
+def read(run):
+    return scope_reduce.per_individual(run, scope_reduce.EVAL)

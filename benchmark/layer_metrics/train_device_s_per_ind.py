@@ -1,0 +1,9 @@
+"""Device seconds of the train program per individual: the durations of
+``jit_train_segment`` on the trace's "XLA Modules" line over the individuals
+of the ``cv_call``s traced (``scope_reduce.py``).  Beside ``train_s_per_ind``,
+the fenced span: their difference is what fencing and launching cost."""
+import scope_reduce
+
+
+def read(run):
+    return scope_reduce.per_individual(run, scope_reduce.TRAIN)

@@ -1,0 +1,8 @@
+"""Self time of ``glue`` in the train program, per individual traced: mask
+sums, node gating, stage merge, pooling, relu and casts around the
+convolutions (``scope_reduce.py``); what ROADMAP S2 (b)-(d) may remove."""
+import scope_reduce
+
+
+def read(run):
+    return scope_reduce.per_individual(run, scope_reduce.TRAIN, ("glue",))

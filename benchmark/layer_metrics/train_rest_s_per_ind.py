@@ -1,0 +1,7 @@
+"""Self time of ``head`` + ``rest`` (loss, optimizer, batch gather, rng, loop
+bookkeeping) in the train program, per individual traced (``scope_reduce.py``)."""
+import scope_reduce
+
+
+def read(run):
+    return scope_reduce.per_individual(run, scope_reduce.TRAIN, ("head", "rest"))

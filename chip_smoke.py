@@ -122,8 +122,10 @@ class Records:
 
 
 def device_spans(records: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
-    """The model-level device spans (``models/cnn.py``): those with a fold."""
+    """The model-level device spans (``models/cnn.py``): ``compile``, ``train``
+    and ``eval`` with a fold (the host phase ``fold_slice`` has one too)."""
     return [r for r in records if r.get("type") == "span"
+            and r["kind"] in ("compile", "train", "eval")
             and "fold" in (r.get("attrs") or {})]
 
 

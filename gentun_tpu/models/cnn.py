@@ -46,6 +46,7 @@ TPU-first architecture (NOT how the reference does it — SURVEY.md §7
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import hashlib
 import logging
@@ -140,28 +141,33 @@ class MaskedGeneticCnn(nn.Module):
             has_active = m["has_active"].astype(dtype)
             outs: List[jax.Array] = []
             for j in range(k):
-                inp = entry[j] * a0
-                for i in range(j):
-                    inp = inp + adj[i, j] * outs[i]
+                with jax.named_scope(f"stage{s}/mask_sum"):
+                    inp = entry[j] * a0
+                    for i in range(j):
+                        inp = inp + adj[i, j] * outs[i]
                 h = nn.relu(conv(name=f"stage{s}_node{j}")(inp))
                 # Zero inactive nodes so they cannot leak into any sum.
-                outs.append(active[j] * h)
+                with jax.named_scope(f"stage{s}/gate"):
+                    outs.append(active[j] * h)
             if k:
-                out = outs[0] * exit_[0]
-                for i in range(1, k):
-                    out = out + exit_[i] * outs[i]
-                x = has_active * out + (1.0 - has_active) * a0
+                with jax.named_scope(f"stage{s}/merge"):
+                    out = outs[0] * exit_[0]
+                    for i in range(1, k):
+                        out = out + exit_[i] * outs[i]
+                    x = has_active * out + (1.0 - has_active) * a0
             else:
                 x = a0
             if self.stage_exit_conv:
                 x = nn.relu(conv(name=f"stage{s}_exit")(x))
-            x = nn.max_pool(x, (2, 2), strides=(2, 2))
-        x = x.reshape((x.shape[0], -1))
-        x = nn.relu(nn.Dense(self.dense_units, dtype=dtype)(x))
-        x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
-        # Final projection + logits in float32: cheap, and keeps the
-        # softmax/cross-entropy numerics out of bfloat16.
-        x = nn.Dense(self.n_classes, dtype=jnp.float32)(x.astype(jnp.float32))
+            with jax.named_scope(f"stage{s}/pool"):
+                x = nn.max_pool(x, (2, 2), strides=(2, 2))
+        with jax.named_scope("head"):
+            x = x.reshape((x.shape[0], -1))
+            x = nn.relu(nn.Dense(self.dense_units, dtype=dtype)(x))
+            x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
+            # Final projection + logits in float32: cheap, and keeps the
+            # softmax/cross-entropy numerics out of bfloat16.
+            x = nn.Dense(self.n_classes, dtype=jnp.float32)(x.astype(jnp.float32))
         return x
 
 
@@ -247,7 +253,8 @@ def _training_primitives(
         logits = model.apply(
             {"params": params}, batch_x, masks, train=True, rngs={"dropout": dropout_rng}
         )
-        return optax.softmax_cross_entropy_with_integer_labels(logits, batch_y).mean()
+        with jax.named_scope("loss"):
+            return optax.softmax_cross_entropy_with_integer_labels(logits, batch_y).mean()
 
     def train_segment(params, opt_state, masks, x_full, y_full, batch_idx_seg, rng):
         """Scan any number of train steps; carries advance, schedule
@@ -260,8 +267,9 @@ def _training_primitives(
                 idx_m = idx_b.reshape(microbatch, batch_size // microbatch)
 
                 def micro(acc, im):
-                    bx = jnp.take(x_full, im, axis=0)
-                    by = jnp.take(y_full, im, axis=0)
+                    with jax.named_scope("gather"):
+                        bx = jnp.take(x_full, im, axis=0)
+                        by = jnp.take(y_full, im, axis=0)
                     _, g = jax.value_and_grad(loss_fn)(params, masks, bx, by, dropout_rng)
                     return jax.tree.map(jnp.add, acc, g), None
 
@@ -270,11 +278,13 @@ def _training_primitives(
                 )
                 grads = jax.tree.map(lambda g: g / microbatch, grads)
             else:
-                batch_x = jnp.take(x_full, idx_b, axis=0)
-                batch_y = jnp.take(y_full, idx_b, axis=0)
+                with jax.named_scope("gather"):
+                    batch_x = jnp.take(x_full, idx_b, axis=0)
+                    batch_y = jnp.take(y_full, idx_b, axis=0)
                 _, grads = jax.value_and_grad(loss_fn)(params, masks, batch_x, batch_y, dropout_rng)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return (params, opt_state, rng), None
 
         (params, opt_state, rng), _ = jax.lax.scan(
@@ -286,8 +296,9 @@ def _training_primitives(
         def eval_batch(correct, start):
             idx_b = jax.lax.dynamic_slice_in_dim(val_idx, start, eval_batch_size, axis=0)
             wb = jax.lax.dynamic_slice_in_dim(val_weight, start, eval_batch_size, axis=0)
-            xb = jnp.take(x_full, idx_b, axis=0)
-            yb = jnp.take(y_full, idx_b, axis=0)
+            with jax.named_scope("gather"):
+                xb = jnp.take(x_full, idx_b, axis=0)
+                yb = jnp.take(y_full, idx_b, axis=0)
             logits = model.apply({"params": params}, xb, masks, train=False)
             # Materialize the logits before the argmax.  Fused into the
             # vmapped forward, XLA:TPU (jax 0.9.0 / libtpu 0.0.34) returns
@@ -295,9 +306,10 @@ def _training_primitives(
             # at the CIFAR-10 width, whatever genome sits there — while the
             # logits themselves are right (PERF.md, PR 21).  The barrier is
             # an identity; chip_smoke.py's slot-order check guards it.
-            logits = jax.lax.optimization_barrier(logits)
-            hits = (jnp.argmax(logits, axis=-1) == yb).astype(jnp.float32)
-            return correct + jnp.sum(hits * wb), None
+            with jax.named_scope("score"):
+                logits = jax.lax.optimization_barrier(logits)
+                hits = (jnp.argmax(logits, axis=-1) == yb).astype(jnp.float32)
+                return correct + jnp.sum(hits * wb), None
 
         starts = jnp.arange(0, n_val_padded, eval_batch_size)
         correct, _ = jax.lax.scan(eval_batch, jnp.float32(0.0), starts)
@@ -429,24 +441,52 @@ def _segment_bounds(total_steps: int, segment_steps) -> List[Tuple[int, int]]:
 _tele_seen_programs: set = set()
 
 
-def _tele_device_span(kind_key, t0, result, attrs):
-    """End a telemetry span around one device call: sync on ``result``
-    (honest duration under jax async dispatch — ONLY reached when telemetry
-    is enabled), then record `compile` for a first-seen program shape and
-    the phase kind (`train`/`eval`) afterwards."""
-    jax.block_until_ready(result)
-    dur = time.monotonic() - t0
-    if kind_key in _tele_seen_programs:
-        kind = attrs.pop("_kind")
-    else:
-        _tele_seen_programs.add(kind_key)
-        attrs["phase"] = attrs.pop("_kind")
-        kind = "compile"
+@contextlib.contextmanager
+def _live_phase(kind: str, attrs: Dict[str, Any], program):
+    """The telemetry-on half of :func:`_phase`."""
+    first = program is not None and program not in _tele_seen_programs
+    if first:
+        attrs["phase"], kind = kind, "compile"
+    # The annotation puts the span into the profiler's own trace, on the
+    # profiler's clock, above the device ops it launched; scalars known at
+    # entry ride along as its stats.
+    with jax.profiler.TraceAnnotation(
+        f"gentun/{kind}", **{k: v for k, v in attrs.items() if isinstance(v, (int, float, str))}
+    ), _tele.span(kind, attrs) as sp:
+        t0 = time.monotonic()
+        try:
+            yield sp
+        except BaseException:
+            if program is not None:
+                # `compile`/`train`/`eval` stay what their readers take them
+                # for, calls that returned: the deep configuration's 50-wide
+                # attempt compiles for ~23 s and then runs out of memory.
+                sp.kind = "call_failed"
+            raise
+        dur = time.monotonic() - t0
+    if first:
         # First-compile latency histogram (docs/OBSERVABILITY.md): what a
-        # compile-cache hit saves.  Same honesty caveat as the span kind —
-        # this is compile + first execution.
+        # compile-cache hit saves — compile + first execution, as the span.
+        _tele_seen_programs.add(program)
         _get_registry().histogram("compile_seconds").observe(dur)
-    _tele.record_span(kind, t0, dur, attrs=attrs)
+
+
+def _phase(kind: str, attrs: Optional[Dict[str, Any]] = None, program=None):
+    """One named phase of an evaluation call (docs/OBSERVABILITY.md).
+
+    Telemetry off: the spans module's shared no-op, nothing allocated and
+    nothing synchronised.  On: a ``gentun/<kind>`` profiler annotation plus
+    a span record.  A device call passes ``program`` (callable id + shape
+    signature) and fences its result with ``sp.fence(...)``: the span's
+    ``dispatch_s`` is how long the jitted call took to return, the rest of
+    ``dur_s`` the wait for the device — jax dispatch is async, so an honest
+    duration needs the block, and the block costs pipelining, which is why
+    it happens ONLY when telemetry is on.  The first call of a program shape
+    is labelled ``compile`` with the would-have-been kind as ``phase``.
+    """
+    if not _tele.enabled():
+        return _tele.span(kind)
+    return _live_phase(kind, dict(attrs) if attrs else {}, program)
 
 
 def _carry_devices(carries) -> int:
@@ -512,37 +552,30 @@ def _run_segmented(
 
     kfold, total_steps = batch_idx.shape[0], batch_idx.shape[1]
     bounds = _segment_bounds(total_steps, cfg["segment_steps"])
-    # Telemetry (docs/OBSERVABILITY.md): per-call compile/train/eval spans
-    # need block_until_ready for honest durations — jax dispatch is async
-    # and every call below returns before the device finishes.  That sync
-    # costs pipelining, so it happens ONLY when telemetry is enabled; the
-    # disabled path is byte-identical to the uninstrumented executor.
     tele = _tele.enabled()
     pop_dim = int(next(iter(stacked[0].values())).shape[0]) if stacked else 0
+    mesh_sizes = list(mesh_axis_sizes(mesh))
     accs = []
     for f in range(kfold):
-        p = jax.tree.map(lambda a: a[f], params)
-        rng_f = fold_keys[f]
-        if mesh is not None:
-            p = place_tree(p, pop_s)
-            rng_f = place(rng_f, pop_s)
-        opt = init_pop(p)
+        with _phase("fold_slice", {"fold": f}):
+            p = jax.tree.map(lambda a: a[f], params)
+            rng_f = fold_keys[f]
+            if mesh is not None:
+                p = place_tree(p, pop_s)
+                rng_f = place(rng_f, pop_s)
+            opt = init_pop(p)
         for s, e in bounds:
             if mesh is not None:
                 seg = place(batch_idx[f, s:e], batch_s)
             else:
                 seg = jnp.asarray(batch_idx[f, s:e])
-            if tele:
-                t0 = time.monotonic()
-                p, opt, rng_f = train_pop(p, opt, masks, x_full, y_full, seg, rng_f)
-                _tele_device_span(
-                    (id(train_pop), e - s, pop_dim, kfold), t0, (p, opt, rng_f),
-                    {"_kind": "train", "steps": e - s, "pop": pop_dim, "fold": f,
-                     "mesh": list(mesh_axis_sizes(mesh)),
-                     "carry_devices": _carry_devices((p, opt, rng_f))},
-                )
-            else:
-                p, opt, rng_f = train_pop(p, opt, masks, x_full, y_full, seg, rng_f)
+            with _phase(
+                "train", {"steps": e - s, "pop": pop_dim, "fold": f, "mesh": mesh_sizes},
+                program=(id(train_pop), e - s, pop_dim, kfold),
+            ) as sp:
+                p, opt, rng_f = sp.fence(train_pop(p, opt, masks, x_full, y_full, seg, rng_f))
+                if tele:
+                    sp.set(carry_devices=_carry_devices((p, opt, rng_f)))
         if mesh is not None:
             vi, vw = place(val_idx[f], repl), place(val_weight[f], repl)
         else:
@@ -552,16 +585,9 @@ def _run_segmented(
         # prepares fold f+1.  jax dispatch is async, so appending the device
         # array keeps the execution queue full across folds; params/opt
         # buffers still die at loop end (acc is tiny).
-        if tele:
-            t0 = time.monotonic()
-            acc = eval_pop(p, masks, x_full, y_full, vi, vw)
-            _tele_device_span(
-                (id(eval_pop), pop_dim, kfold), t0, acc,
-                {"_kind": "eval", "pop": pop_dim, "fold": f},
-            )
-            accs.append(acc)
-        else:
-            accs.append(eval_pop(p, masks, x_full, y_full, vi, vw))
+        with _phase("eval", {"pop": pop_dim, "fold": f},
+                    program=(id(eval_pop), pop_dim, kfold)) as sp:
+            accs.append(sp.fence(eval_pop(p, masks, x_full, y_full, vi, vw)))
         if f == 0 and warm_keys is not None:
             # Deposit BEFORE the carry dies: fold 0's trained params become
             # the warm-start seed a later higher-rung evaluation of the
@@ -571,7 +597,8 @@ def _run_segmented(
     # fetch = np.asarray single-process; an all-gather of the pop-sharded
     # accuracies when the mesh spans processes (every host gets the full
     # vector, keeping the SPMD ranks in lockstep).
-    return np.stack([fetch(a).astype(np.float32) for a in accs])
+    with _phase("fetch"):
+        return np.stack([fetch(a).astype(np.float32) for a in accs])
 
 
 @functools.lru_cache(maxsize=32)
@@ -789,6 +816,14 @@ def _device_dataset(key_x, key_y, xp: np.ndarray, yp: np.ndarray, perm: np.ndarr
     LRU one-at-a-time, so the hot dataset survives a fifth dataset showing
     up; dead-referent entries are dropped eagerly.
     """
+    with _phase("dataset") as sp:
+        xd, yd, found = _dataset_lookup(key_x, key_y, xp, yp, perm, cfg, mesh)
+        sp.set(source="found" if found else "uploaded")
+        return xd, yd
+
+
+def _dataset_lookup(key_x, key_y, xp, yp, perm, cfg, mesh):
+    """:func:`_device_dataset` proper: (x, y, whether the cache had them)."""
     # Evict dead entries eagerly so device copies never outlive their host
     # arrays just because the cache hasn't hit its size bound.
     for k in [k for k, (xr, yr, *_dv) in _DATASET_CACHE.items() if xr() is None or yr() is None]:
@@ -808,7 +843,7 @@ def _device_dataset(key_x, key_y, xp: np.ndarray, yp: np.ndarray, perm: np.ndarr
         xref, yref, xd, yd = hit
         if xref() is key_x and yref() is key_y:
             _DATASET_CACHE[key] = _DATASET_CACHE.pop(key)  # LRU: refresh recency
-            return xd, yd
+            return xd, yd, True
     # Same arrays, different fingerprint ⇒ the caller mutated in place; the
     # predecessor entries can never hit again, so drop them now instead of
     # pinning stale device copies of the same dataset until LRU catches up.
@@ -832,11 +867,11 @@ def _device_dataset(key_x, key_y, xp: np.ndarray, yp: np.ndarray, perm: np.ndarr
     try:
         xref, yref = weakref.ref(key_x), weakref.ref(key_y)
     except TypeError:
-        return xd, yd  # un-weakref-able input (e.g. a list): don't cache
+        return xd, yd, False  # un-weakref-able input (e.g. a list): don't cache
     while len(_DATASET_CACHE) >= 4:  # datasets are big; keep device HBM bounded
         _DATASET_CACHE.pop(next(iter(_DATASET_CACHE)))  # LRU eviction
     _DATASET_CACHE[key] = (xref, yref, xd, yd)
-    return xd, yd
+    return xd, yd, False
 
 
 #: Per-config cap on how many genomes one compiled program may carry,
@@ -872,6 +907,16 @@ def _oom_cap_key(cfg: Dict[str, Any]):
 def _is_oom_error(e: BaseException) -> bool:
     s = str(e)
     return "RESOURCE_EXHAUSTED" in s or "out of memory" in s.lower()
+
+
+def _record_oom_split(t0: float, genomes: int, cap: int) -> None:
+    """The healer just learned a cap: the ``oom_split`` event, and the failed
+    attempt since ``t0`` as an ``oom_attempt`` span — what the OOM cost.
+    Retroactive because only its end tells an attempt that OOMs from one
+    that does not."""
+    attrs = {"genomes": genomes, "cap": cap}
+    _tele.record_event("oom_split", attrs)
+    _tele.record_span("oom_attempt", t0, time.monotonic() - t0, attrs=dict(attrs))
 
 
 def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
@@ -913,6 +958,7 @@ def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
             )
         return run_exact(genomes)
     fallback = None
+    t0 = time.monotonic()
     try:
         return run(genomes)
     except Exception as e:
@@ -922,7 +968,7 @@ def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
             if run_exact is None:
                 raise
             _POP_PROGRAM_CAP[cap_key] = 1
-            _tele.record_event("oom_split", {"genomes": 1, "cap": 1})
+            _record_oom_split(t0, 1, 1)
             logger.warning(
                 "singleton population batch exhausted device memory in its "
                 "padded (2-wide) program; retrying exact-size (1-wide, "
@@ -936,7 +982,7 @@ def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
             while b * 2 <= half:
                 b *= 2
             _POP_PROGRAM_CAP[cap_key] = b
-            _tele.record_event("oom_split", {"genomes": len(genomes), "cap": b})
+            _record_oom_split(t0, len(genomes), b)
             logger.warning(
                 "population batch of %d genomes exhausted device memory; "
                 "chunking to <=%d genomes per program (remembered for this "
@@ -1359,120 +1405,119 @@ class GeneticCnnModel(GentunModel):
         genomes: Sequence[Mapping[str, Any]],
         **config,
     ) -> np.ndarray:
-        cfg = _normalize_config(x_train, y_train, config)
-        x, y = _prepare_data(x_train, y_train, cfg)
-        if len(genomes) == 0:
-            return np.zeros((0,), dtype=np.float32)
-        mesh, genomes, n_real, pop, stacked, model, hashes = _prepare_population_setup(cfg, genomes)
+        """One chunk of a population through k-fold CV.  With telemetry on,
+        one ``cv_call`` span whose children name every host phase of the
+        call, in order (docs/OBSERVABILITY.md)."""
+        with _phase("cv_call", {"n_real": len(genomes)}) as call:
+            with _phase("prepare"):
+                cfg = _normalize_config(x_train, y_train, config)
+                x, y = _prepare_data(x_train, y_train, cfg)
+                if len(genomes) == 0:
+                    return np.zeros((0,), dtype=np.float32)
+                mesh, genomes, n_real, pop, stacked, model, hashes = _prepare_population_setup(cfg, genomes)
+            call.set(pop=pop)
 
-        kfold = cfg["kfold"]
-        n = x.shape[0]
-        if kfold < 2:
-            raise ValueError("kfold must be >= 2")
-        fold_size = n // kfold
-        if fold_size == 0:
-            raise ValueError(f"kfold={kfold} exceeds dataset size {n}")
-        n_use = fold_size * kfold  # equal folds → one compiled shape
-        rng = np.random.default_rng(cfg["seed"])
-        perm = rng.permutation(n)[:n_use]
-        # The device-resident dataset is x[perm]; folds are consecutive
-        # position blocks within it, so every index array below addresses
-        # x_full/y_full directly.
-        folds = np.arange(n_use, dtype=np.int32).reshape(kfold, fold_size)
+            kfold = cfg["kfold"]
+            n = x.shape[0]
+            if kfold < 2:
+                raise ValueError("kfold must be >= 2")
+            fold_size = n // kfold
+            if fold_size == 0:
+                raise ValueError(f"kfold={kfold} exceeds dataset size {n}")
+            with _phase("index_build"):
+                n_use = fold_size * kfold  # equal folds → one compiled shape
+                rng = np.random.default_rng(cfg["seed"])
+                perm = rng.permutation(n)[:n_use]
+                # The device-resident dataset is x[perm]; folds are consecutive
+                # position blocks within it, so every index array below addresses
+                # x_full/y_full directly.
+                folds = np.arange(n_use, dtype=np.int32).reshape(kfold, fold_size)
 
-        batch_size = min(cfg["batch_size"], n_use - fold_size)
-        n_tr = n_use - fold_size
-        steps_per_epoch = max(n_tr // batch_size, 1)
-        total_steps = sum(cfg["epochs"]) * steps_per_epoch
-        eval_bs, n_val_padded = _eval_batch_size(batch_size, fold_size)
-        pad = n_val_padded - fold_size
-        _account_sharded_batch(cfg, mesh, batch_size, total_steps * kfold)
+                batch_size = min(cfg["batch_size"], n_use - fold_size)
+                n_tr = n_use - fold_size
+                steps_per_epoch = max(n_tr // batch_size, 1)
+                total_steps = sum(cfg["epochs"]) * steps_per_epoch
+                eval_bs, n_val_padded = _eval_batch_size(batch_size, fold_size)
+                pad = n_val_padded - fold_size
+                _account_sharded_batch(cfg, mesh, batch_size, total_steps * kfold)
 
-        # Per-fold index arrays (host-side numpy, tiny): the fold IS its
-        # indices.  batch_idx holds *global* dataset indices, so the compiled
-        # program gathers straight from the one device-resident copy of x.
-        batch_idx = np.zeros((kfold, total_steps, batch_size), dtype=np.int32)
-        val_idx = np.zeros((kfold, n_val_padded), dtype=np.int32)
-        val_weight = np.zeros((kfold, n_val_padded), dtype=np.float32)
-        for f in range(kfold):
-            tr_idx = np.concatenate([folds[g] for g in range(kfold) if g != f])
-            order = np.concatenate(
-                [rng.permutation(n_tr) for _ in range(sum(cfg["epochs"]))]
-            )[: total_steps * batch_size]
-            batch_idx[f] = tr_idx[order].reshape(total_steps, batch_size)
-            val_idx[f] = np.concatenate([folds[f], np.full(pad, folds[f][0])])
-            val_weight[f] = np.concatenate(
-                [np.ones(fold_size, np.float32), np.zeros(pad, np.float32)]
+                # Per-fold index arrays (host-side numpy, tiny): the fold IS its
+                # indices.  batch_idx holds *global* dataset indices, so the compiled
+                # program gathers straight from the one device-resident copy of x.
+                batch_idx = np.zeros((kfold, total_steps, batch_size), dtype=np.int32)
+                val_idx = np.zeros((kfold, n_val_padded), dtype=np.int32)
+                val_weight = np.zeros((kfold, n_val_padded), dtype=np.float32)
+                for f in range(kfold):
+                    tr_idx = np.concatenate([folds[g] for g in range(kfold) if g != f])
+                    order = np.concatenate(
+                        [rng.permutation(n_tr) for _ in range(sum(cfg["epochs"]))]
+                    )[: total_steps * batch_size]
+                    batch_idx[f] = tr_idx[order].reshape(total_steps, batch_size)
+                    val_idx[f] = np.concatenate([folds[f], np.full(pad, folds[f][0])])
+                    val_weight[f] = np.concatenate(
+                        [np.ones(fold_size, np.float32), np.zeros(pad, np.float32)]
+                    )
+
+            with _phase("init_params"):
+                params = _init_population_params(
+                    model, stacked, cfg["input_shape"], pop, kfold, cfg["seed"], hashes
+                )
+                _record_cost_calibration(cfg, params, kfold * pop)
+                # Parent→child weight inheritance (multi-fidelity ladder): overlay
+                # each slot's own lower-rung trained params where shapes match, and
+                # bank fold-0 results for the NEXT rung.  Segmented single-process
+                # path only: the fused fold_parallel program has no per-fold host
+                # boundary to deposit at, and on a multi-process mesh the gather
+                # would stall every rank for a process-local cache — both fall back
+                # to cold starts, which is always correct (pure speedup).
+                warm = cfg["warm_start"] and mesh is None and not cfg["fold_parallel"]
+                if warm:
+                    params, warmed = _warm_start_overlay(params, hashes[:n_real])
+                    if warmed:
+                        logger.debug("warm start: %d/%d slots inherited banked params",
+                                     warmed, n_real)
+                fold_keys = _content_keys(jax.random.PRNGKey(cfg["seed"]), kfold, hashes)
+
+            x_dev, y_dev = _device_dataset(x_train, y_train, x, y, perm, cfg, mesh)
+
+            if not cfg["fold_parallel"]:
+                accs = _run_segmented(
+                    cfg, stacked, params, fold_keys, x_dev, y_dev,
+                    val_idx, val_weight, batch_idx, mesh, batch_size, n_tr,
+                    n_val_padded, eval_bs,
+                    warm_keys=hashes[:n_real] if warm else None,
+                )
+                return accs.mean(axis=0)[:n_real]
+
+            fn = _population_cv_fn(*_static_key(cfg, batch_size, n_tr, n_val_padded, eval_bs))
+            arrays = dict(
+                x_full=x_dev,
+                y_full=y_dev,
+                val_idx=jnp.asarray(val_idx),
+                val_weight=jnp.asarray(val_weight),
+                batch_idx=jnp.asarray(batch_idx),
             )
-
-        params = _init_population_params(
-            model, stacked, cfg["input_shape"], pop, kfold, cfg["seed"], hashes
-        )
-        _record_cost_calibration(cfg, params, kfold * pop)
-        # Parent→child weight inheritance (multi-fidelity ladder): overlay
-        # each slot's own lower-rung trained params where shapes match, and
-        # bank fold-0 results for the NEXT rung.  Segmented single-process
-        # path only: the fused fold_parallel program has no per-fold host
-        # boundary to deposit at, and on a multi-process mesh the gather
-        # would stall every rank for a process-local cache — both fall back
-        # to cold starts, which is always correct (pure speedup).
-        warm = cfg["warm_start"] and mesh is None and not cfg["fold_parallel"]
-        if warm:
-            params, warmed = _warm_start_overlay(params, hashes[:n_real])
-            if warmed:
-                logger.debug("warm start: %d/%d slots inherited banked params",
-                             warmed, n_real)
-        fold_keys = _content_keys(jax.random.PRNGKey(cfg["seed"]), kfold, hashes)
-
-        if not cfg["fold_parallel"]:
-            accs = _run_segmented(
-                cfg, stacked, params, fold_keys,
-                *_device_dataset(x_train, y_train, x, y, perm, cfg, mesh),
-                val_idx, val_weight, batch_idx, mesh, batch_size, n_tr,
-                n_val_padded, eval_bs,
-                warm_keys=hashes[:n_real] if warm else None,
-            )
-            return accs.mean(axis=0)[:n_real]
-
-        fn = _population_cv_fn(*_static_key(cfg, batch_size, n_tr, n_val_padded, eval_bs))
-        x_dev, y_dev = _device_dataset(x_train, y_train, x, y, perm, cfg, mesh)
-        arrays = dict(
-            x_full=x_dev,
-            y_full=y_dev,
-            val_idx=jnp.asarray(val_idx),
-            val_weight=jnp.asarray(val_weight),
-            batch_idx=jnp.asarray(batch_idx),
-        )
-        masks = stacked
-        if mesh is not None:
-            params, masks, fold_keys, arrays = shard_cv_args(
-                mesh, params, stacked, fold_keys, arrays
-            )
-        if _tele.enabled():
+            masks = stacked
+            if mesh is not None:
+                params, masks, fold_keys, arrays = shard_cv_args(
+                    mesh, params, stacked, fold_keys, arrays
+                )
             # Fused executor: train + eval are ONE program, so the split
             # collapses to a single span (`compile` on the first shape).
-            t0 = time.monotonic()
-            acc = fn(
-                params, masks, arrays["x_full"], arrays["y_full"],
-                arrays["val_idx"], arrays["val_weight"], arrays["batch_idx"],
-                fold_keys,
-            )
-            _tele_device_span(
-                (id(fn), pop, kfold), t0, acc,
-                {"_kind": "train", "fused": True, "pop": pop, "kfold": kfold},
-            )
-        else:
-            acc = fn(
-                params,
-                masks,
-                arrays["x_full"],
-                arrays["y_full"],
-                arrays["val_idx"],
-                arrays["val_weight"],
-                arrays["batch_idx"],
-                fold_keys,
-            )
-        return fetch(acc).astype(np.float32).mean(axis=0)[:n_real]
+            with _phase("train", {"fused": True, "pop": pop, "kfold": kfold},
+                        program=(id(fn), pop, kfold)) as sp:
+                acc = sp.fence(fn(
+                    params,
+                    masks,
+                    arrays["x_full"],
+                    arrays["y_full"],
+                    arrays["val_idx"],
+                    arrays["val_weight"],
+                    arrays["batch_idx"],
+                    fold_keys,
+                ))
+            with _phase("fetch"):
+                return fetch(acc).astype(np.float32).mean(axis=0)[:n_real]
 
 
     # -- final holdout evaluation (not part of the reference's API) --------

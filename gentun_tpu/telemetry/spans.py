@@ -170,6 +170,9 @@ class _NoopSpan:
     def set(self, **attrs) -> None:
         pass
 
+    def fence(self, result):
+        return result
+
 
 _NOOP = _NoopSpan()
 
@@ -195,6 +198,17 @@ class _Span:
     def set(self, **attrs: Any) -> None:
         """Attach attributes after entry (e.g. a result count)."""
         self.attrs.update(attrs)
+
+    def fence(self, result):
+        """Wait for ``result``, the value a jitted call just returned, inside
+        the span: ``dispatch_s`` is how long the call took to return, the
+        rest of ``dur_s`` is the wait for the device.  Only a caller that
+        holds a device result gets here, so jax is already imported."""
+        self.attrs["dispatch_s"] = time.monotonic() - self._t0
+        import jax
+
+        jax.block_until_ready(result)
+        return result
 
     def __enter__(self) -> "_Span":
         self._token = _CTX.set((self.trace_id, self.span_id))

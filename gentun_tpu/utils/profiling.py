@@ -9,12 +9,9 @@ Two tools:
   source of the north-star metric (individuals/hour/chip) at finer grain
   than the per-generation log.
 
-Since the telemetry plane landed (``gentun_tpu/telemetry``,
-docs/OBSERVABILITY.md), :class:`EvalTimer` is a thin compatibility layer:
-each ``measure()`` block ALSO emits an ``eval_timer`` span into the active
-telemetry run (when tracing is enabled), so old call sites feed the new
-``telemetry.jsonl`` artifact without changes.  New code should open spans
-directly (``telemetry.span(...)``).
+:class:`EvalTimer` keeps its own records and emits nothing into the telemetry
+plane (``gentun_tpu/telemetry``, docs/OBSERVABILITY.md); code that wants a
+span opens one directly (``telemetry.span(...)``).
 """
 
 from __future__ import annotations
@@ -24,8 +21,6 @@ import json
 import logging
 import time
 from typing import Any, Dict, List, Optional
-
-from ..telemetry import spans as _tele
 
 __all__ = ["trace", "EvalTimer"]
 
@@ -76,12 +71,6 @@ class EvalTimer:
             ),
         }
         self.records.append(rec)
-        # Absorbed into the telemetry plane: the measurement doubles as an
-        # `eval_timer` span so legacy call sites appear in telemetry.jsonl.
-        _tele.record_span(
-            "eval_timer", t0, elapsed,
-            attrs={"label": label, "individuals": int(n_individuals)},
-        )
         logger.info("eval %s", json.dumps(rec))
 
     @property
