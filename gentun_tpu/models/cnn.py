@@ -503,11 +503,29 @@ def _carry_devices(carries) -> int:
     )
 
 
+def _mesh_shardings(mesh):
+    """``(pop, batch, replicated)`` shardings of the segmented executor on
+    ``mesh``; three ``None`` without one."""
+    if mesh is None:
+        return None, None, None
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return (NamedSharding(mesh, P("pop")), NamedSharding(mesh, P(None, "data")),
+            NamedSharding(mesh, P()))
+
+
+def _put(x, sharding):
+    """Host value → device: plain upload off-mesh; on a mesh through
+    parallel.multihost.place, which is plain device_put single-process and
+    the multi-controller-legal make_array path when this worker spans
+    several hosts."""
+    return jnp.asarray(x) if sharding is None else place(x, sharding)
+
+
 def _run_segmented(
     cfg: Dict[str, Any],
-    stacked,
-    params,
-    fold_keys,
+    masks,
+    carries,
     x_np,
     y_np,
     val_idx,
@@ -522,53 +540,36 @@ def _run_segmented(
 ) -> np.ndarray:
     """Host loop over folds × bounded segments; returns (kfold, P) accs.
 
-    Every device call is short (``segment_steps`` train steps), every carry
-    (params, opt state, rng) stays device-resident, and the dataset uploads
-    once — so the only host↔device traffic per segment is one tiny index
-    array.  This is the default executor; the fused single-program path
-    remains available via ``fold_parallel=True``.
+    ``masks`` and ``carries`` are :func:`_fold_carries`' outputs: each fold's
+    starting ``(params, rng)`` is already a tree of its own on the device (and
+    on the mesh), so the per-fold prologue is one compiled ``init_pop`` and no
+    indexing of device arrays.  Every device call is short (``segment_steps``
+    train steps), every carry (params, opt state, rng) stays device-resident,
+    and the dataset uploads once — so the only host↔device traffic per
+    segment is one tiny index array.  Whatever the first ``train_pop`` does
+    not need (cost calibration, validation indices, later folds' index
+    arrays) runs after it is dispatched, while the device is busy.  This is
+    the default executor; the fused single-program path remains available via
+    ``fold_parallel=True``.
     """
     init_pop, train_pop, eval_pop = _fold_segment_fns(
         *_static_key(cfg, batch_size, n_train, n_val_padded, eval_batch_size)
     )
-    masks = stacked
-    pop_s = batch_s = repl = None
-    if mesh is not None:
-        # All placements go through parallel.multihost.place, which is
-        # plain device_put single-process and the multi-controller-legal
-        # make_array path when this worker spans several hosts.
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        pop_s = NamedSharding(mesh, P("pop"))
-        batch_s = NamedSharding(mesh, P(None, "data"))
-        repl = NamedSharding(mesh, P())
-        masks = [
-            {k: place(v, pop_s) for k, v in stage.items()} for stage in stacked
-        ]
-        x_full = place(x_np, repl)
-        y_full = place(y_np, repl)
-    else:
-        x_full, y_full = jnp.asarray(x_np), jnp.asarray(y_np)
+    _, batch_s, repl = _mesh_shardings(mesh)
+    x_full, y_full = _put(x_np, repl), _put(y_np, repl)
 
     kfold, total_steps = batch_idx.shape[0], batch_idx.shape[1]
     bounds = _segment_bounds(total_steps, cfg["segment_steps"])
     tele = _tele.enabled()
-    pop_dim = int(next(iter(stacked[0].values())).shape[0]) if stacked else 0
+    pop_dim = int(next(iter(masks[0].values())).shape[0]) if masks else 0
     mesh_sizes = list(mesh_axis_sizes(mesh))
     accs = []
     for f in range(kfold):
         with _phase("fold_slice", {"fold": f}):
-            p = jax.tree.map(lambda a: a[f], params)
-            rng_f = fold_keys[f]
-            if mesh is not None:
-                p = place_tree(p, pop_s)
-                rng_f = place(rng_f, pop_s)
+            p, rng_f = carries[f]
             opt = init_pop(p)
         for s, e in bounds:
-            if mesh is not None:
-                seg = place(batch_idx[f, s:e], batch_s)
-            else:
-                seg = jnp.asarray(batch_idx[f, s:e])
+            seg = _put(batch_idx[f, s:e], batch_s)
             with _phase(
                 "train", {"steps": e - s, "pop": pop_dim, "fold": f, "mesh": mesh_sizes},
                 program=(id(train_pop), e - s, pop_dim, kfold),
@@ -576,10 +577,9 @@ def _run_segmented(
                 p, opt, rng_f = sp.fence(train_pop(p, opt, masks, x_full, y_full, seg, rng_f))
                 if tele:
                     sp.set(carry_devices=_carry_devices((p, opt, rng_f)))
-        if mesh is not None:
-            vi, vw = place(val_idx[f], repl), place(val_weight[f], repl)
-        else:
-            vi, vw = jnp.asarray(val_idx[f]), jnp.asarray(val_weight[f])
+            if f == 0 and s == 0:
+                _record_cost_calibration(cfg, p, pop_dim)
+        vi, vw = _put(val_idx[f], repl), _put(val_weight[f], repl)
         # Keep the result ON device: materialising here would block the host
         # until fold f finishes and leave the device idle while the host
         # prepares fold f+1.  jax dispatch is async, so appending the device
@@ -601,22 +601,26 @@ def _run_segmented(
         return np.stack([fetch(a).astype(np.float32) for a in accs])
 
 
+def _init_slot(model: MaskedGeneticCnn, input_shape: Tuple[int, ...], key, masks):
+    """One slot's fresh parameters.  ``model.init`` runs a full forward pass;
+    unjitted it dispatches op by op (3+ seconds per generation measured on
+    the chip in July 2026), so it is only ever traced: by :func:`_init_fn`
+    and by :func:`_carry_fn`."""
+    dummy = jnp.zeros((1, *input_shape), dtype=jnp.float32)
+    return model.init({"params": key}, dummy, masks, train=False)["params"]
+
+
 @functools.lru_cache(maxsize=32)
 def _init_fn(model: MaskedGeneticCnn, input_shape: Tuple[int, ...]):
-    """Jitted (fold × pop)-vmapped parameter init for one module config.
+    """Jitted (fold × pop)-vmapped parameter init for one module config: the
+    STACKED ``(kfold, P, ...)`` tree the fused ``fold_parallel`` executor
+    trains from (the segmented executor starts from :func:`_carry_fn`).
 
-    ``model.init`` runs a full forward pass; unjitted it dispatches op by op
-    (3+ seconds per generation measured on the chip in July 2026, ~30% of a
-    proxy-schedule evaluation).  The jitted callable is cached per (module, input_shape) —
-    flax modules are frozen dataclasses, so they hash by config — and jax
-    re-specialises it per (kfold, pop) shape automatically.
+    The jitted callable is cached per (module, input_shape) — flax modules
+    are frozen dataclasses, so they hash by config — and jax re-specialises
+    it per (kfold, pop) shape automatically.
     """
-    dummy = jnp.zeros((1, *input_shape), dtype=jnp.float32)
-
-    def init_one(key, masks):
-        return model.init({"params": key}, dummy, masks, train=False)["params"]
-
-    over_pop = jax.vmap(init_one, in_axes=(0, 0))
+    over_pop = jax.vmap(functools.partial(_init_slot, model, input_shape), in_axes=(0, 0))
     return jax.jit(jax.vmap(over_pop, in_axes=(0, None)))
 
 
@@ -659,18 +663,20 @@ def _genome_hashes(genomes: Sequence[Mapping[str, Any]]) -> np.ndarray:
     return out
 
 
+def _fold_content_keys(base_key, f: int, genome_hashes) -> jnp.ndarray:
+    """(P, 2) PRNG keys of fold ``f``: the fold index, then the 64-bit genome
+    content hash — as two uint32 words — folded into ``base_key``."""
+    k = jax.random.fold_in(base_key, f)
+    return jax.vmap(lambda hh: jax.random.fold_in(jax.random.fold_in(k, hh[0]), hh[1]))(genome_hashes)
+
+
 def _content_keys(base_key, kfold: int, genome_hashes) -> jnp.ndarray:
-    """(kfold, P, 2) PRNG keys: fold index then the 64-bit genome content
-    hash — as two uint32 words — folded in."""
+    """(kfold, P, 2) PRNG keys, :func:`_fold_content_keys` stacked over the
+    folds.  Eager, a few dispatches a fold: the fused executor's input and
+    the tests' oracle; the segmented executor derives the same keys inside
+    :func:`_carry_fn`'s one program."""
     h = jnp.asarray(genome_hashes)  # (P, 2) uint32
-
-    def fold(hh, f):
-        k = jax.random.fold_in(base_key, f)
-        return jax.random.fold_in(jax.random.fold_in(k, hh[0]), hh[1])
-
-    return jnp.stack(
-        [jax.vmap(lambda hh, f=f: fold(hh, f))(h) for f in range(kfold)]
-    )
+    return jnp.stack([_fold_content_keys(base_key, f, h) for f in range(kfold)])
 
 
 #: Domain constants for PRNG stream separation.  _INIT_DOMAIN keeps
@@ -683,6 +689,16 @@ _INIT_DOMAIN = 0x1217
 _HOLDOUT_DOMAIN = 0x5C04E
 
 
+def _base_keys(seed: int, domain: int = 0):
+    """``(init, train)`` base PRNG keys of one evaluation: the two streams'
+    roots under ``seed``, both moved into ``domain`` when it is non-zero."""
+    train = jax.random.PRNGKey(seed)
+    init = jax.random.fold_in(train, _INIT_DOMAIN)
+    if domain:
+        init, train = jax.random.fold_in(init, domain), jax.random.fold_in(train, domain)
+    return init, train
+
+
 def _init_population_params(model: MaskedGeneticCnn, masks_stacked, input_shape, pop_size, kfold, seed, genome_hashes, domain=0):
     """Per-(fold, individual) parameter init → shapes carry a (kfold, P) prefix.
 
@@ -693,11 +709,53 @@ def _init_population_params(model: MaskedGeneticCnn, masks_stacked, input_shape,
     ``domain`` separates callers (train_and_score vs CV) that would
     otherwise replicate each other's fold-0 streams under one seed.
     """
-    base = jax.random.fold_in(jax.random.PRNGKey(seed), _INIT_DOMAIN)
-    if domain:
-        base = jax.random.fold_in(base, domain)
-    keys = _content_keys(base, kfold, genome_hashes)
+    keys = _content_keys(_base_keys(seed, domain)[0], kfold, genome_hashes)
     return _init_fn(model, tuple(input_shape))(keys, masks_stacked)
+
+
+@functools.lru_cache(maxsize=32)
+def _carry_fn(model: MaskedGeneticCnn, input_shape: Tuple[int, ...], kfold: int, pop_sharding=None):
+    """One compiled program from genome hashes to every fold's starting carry.
+
+    ``build(init_base, train_base, hashes, masks)`` returns a list of
+    ``kfold`` pairs ``(params, rng)`` with a leading population axis: the
+    key derivation of :func:`_fold_content_keys` for both streams, the
+    per-(fold, slot) ``model.init`` and the split by fold all happen inside
+    it, so a call pays one dispatch where it paid about a hundred eager ones
+    at the flagship's 34 leaves (two key chains, then one slice per parameter
+    leaf per fold).  Same integer arithmetic and initialisers as
+    :func:`_init_population_params` / :func:`_content_keys`: bit-identical
+    on CPU (``tests/test_carry_builder.py``).  Cached like :func:`_init_fn`,
+    per (module, input shape, kfold, the mesh's ``pop`` sharding or None);
+    jax re-specialises it per population width.
+    """
+    init_pop_slots = jax.vmap(functools.partial(_init_slot, model, input_shape))
+
+    def build(init_base, train_base, hashes, masks):
+        return [
+            (init_pop_slots(_fold_content_keys(init_base, f, hashes), masks),
+             _fold_content_keys(train_base, f, hashes))
+            for f in range(kfold)
+        ]
+
+    return jax.jit(build, out_shardings=pop_sharding)
+
+
+def _fold_carries(cfg: Dict[str, Any], model: MaskedGeneticCnn, stacked, hashes, kfold: int, mesh, domain: int = 0):
+    """``(masks, carries)`` of the segmented executor: the stacked masks on the
+    device (``pop``-sharded on a mesh) and :func:`_carry_fn`'s per-fold
+    ``(params, rng)`` — born with the ``pop`` sharding, so nothing is
+    re-placed.  ``domain`` separates callers (train_and_score vs CV) as in
+    :func:`_init_population_params`; the base keys are four tiny cached
+    programs, all that is left of the eager head."""
+    pop_s, _, repl = _mesh_shardings(mesh)
+    init_base, train_base = _base_keys(cfg["seed"], domain)
+    masks = stacked
+    if mesh is not None:
+        masks = place_tree(stacked, pop_s)
+        hashes, init_base, train_base = place(hashes, pop_s), place(init_base, repl), place(train_base, repl)
+    build = _carry_fn(model, tuple(cfg["input_shape"]), kfold, pop_s)
+    return masks, build(init_base, train_base, hashes, masks)
 
 
 #: Parent→child weight bank for multi-fidelity warm starts (``warm_start``
@@ -732,19 +790,20 @@ def _warm_bank_deposit(params_f0, hashes) -> None:
         del _WARM_BANK[next(iter(_WARM_BANK))]
 
 
-def _warm_start_overlay(params, hashes):
+def _warm_start_overlay(carries, hashes):
     """Overlay banked lower-rung params onto fresh inits, where shapes match.
 
-    ``params`` leaves are (kfold, P, ...); a banked slot is copied into its
-    slot across the WHOLE fold axis (each fold still sees an independent
-    dropout/batch stream, only the starting point is shared).  A leaf whose
-    shape or dtype disagrees with the bank (the genome was banked under a
-    different static config) keeps its fresh init — partial inheritance is
-    the contract, matching per-layer shape-compatible transfer.  Returns
-    (params, slots_warmed).
+    ``carries`` is :func:`_fold_carries`' list of per-fold ``(params, rng)``;
+    a banked slot is copied into its slot of EVERY fold's params (each fold
+    still sees an independent dropout/batch stream, only the starting point
+    is shared).  A leaf whose shape or dtype disagrees with the bank (the
+    genome was banked under a different static config) keeps its fresh init
+    — partial inheritance is the contract, matching per-layer
+    shape-compatible transfer.  Nothing is fetched unless the bank holds one
+    of ``hashes``.  Returns (carries, slots_warmed).
     """
-    leaves, treedef = jax.tree.flatten(params)
-    host = None
+    treedef = jax.tree.structure(carries[0][0])
+    host = None  # per fold, the params' leaves as host arrays
     warmed = 0
     for i in range(len(hashes)):
         key = (int(hashes[i][0]), int(hashes[i][1]))
@@ -756,11 +815,12 @@ def _warm_start_overlay(params, hashes):
         if b_def != treedef:
             continue
         if host is None:
-            host = [np.array(fetch(leaf)) for leaf in leaves]
+            host = [[np.array(fetch(leaf)) for leaf in jax.tree.leaves(p)] for p, _ in carries]
         hit = False
         for j, bl in enumerate(b_leaves):
-            if bl.shape == host[j].shape[2:] and bl.dtype == host[j].dtype:
-                host[j][:, i] = bl
+            if bl.shape == host[0][j].shape[1:] and bl.dtype == host[0][j].dtype:
+                for fold_leaves in host:
+                    fold_leaves[j][i] = bl
                 hit = True
         if hit:
             warmed += 1
@@ -770,8 +830,11 @@ def _warm_start_overlay(params, hashes):
             _lineage.record(
                 "warm_started", "bank:%x:%x" % key, slot=i)
     if host is None:
-        return params, 0
-    return jax.tree.unflatten(treedef, [jnp.asarray(h) for h in host]), warmed
+        return carries, 0
+    return [
+        (jax.tree.unflatten(treedef, [jnp.asarray(h) for h in fold_leaves]), rng)
+        for fold_leaves, (_, rng) in zip(host, carries)
+    ], warmed
 
 
 #: (id(x_key), id(y_key), fingerprints, seed, n_use, input_shape) →
@@ -1075,17 +1138,19 @@ def _record_cost_calibration(cfg: Dict[str, Any], params, n_slots: int) -> None:
     """Calibrate the dispatch cost model against what jax actually built.
 
     The scheduling plane sizes genomes with ``cnn_genome_cost`` — a static
-    prediction.  Every population init is a free chance to measure how far
-    that prediction sits from reality, so record both sides as
-    ``genome_cost_calibration{size_class,source}`` gauges:
+    prediction.  Every evaluation call is a free chance to measure how far
+    that prediction sits from reality — free because the segmented executor
+    calls this after its first train dispatch, while the device is busy — so
+    record both sides as ``genome_cost_calibration{size_class,source}``
+    gauges:
 
     - ``predicted_param_bytes`` / ``predicted_act_bytes_batch``: the cost
       model's claim (params×3 f32 convention; activations in compute dtype
       for one full batch);
-    - ``measured_param_bytes``: per-genome-slot bytes of the freshly
-      initialised tree × 3 (params + momentum + grads, the same convention
-      the prediction uses), leaves divided by the ``(kfold, P)`` stacking
-      prefix;
+    - ``measured_param_bytes``: per-genome-slot bytes of the parameter tree
+      × 3 (params + momentum + grads, the same convention the prediction
+      uses), leaves divided by the ``n_slots`` of their stacking prefix
+      (``P`` for one fold's tree, ``kfold·P`` for the fused executor's);
     - ``device_bytes_in_use``: the backend allocator's own number when it
       has one (TPU/GPU ``memory_stats``; absent on CPU) — the largest over
       the local devices, since the fullest device is the one that OOMs.
@@ -1210,10 +1275,8 @@ def _prepare_population_setup(cfg: Dict[str, Any], genomes: Sequence[Mapping[str
     _LAST_MESH_SHAPE = (_pop_ax, _data_ax)
     if len(genomes) > n_real:
         _reg.counter("eval_pad_waste_total").inc(len(genomes) - n_real)
-    stacked = [
-        {k: jnp.asarray(v) for k, v in stage.items()}
-        for stage in stack_genome_masks(genomes, cfg["nodes"])
-    ]
+    # One batched upload of the whole mask tree (float32 throughout).
+    stacked = jax.device_put(stack_genome_masks(genomes, cfg["nodes"]))
     model = MaskedGeneticCnn(
         nodes=cfg["nodes"],
         filters=cfg["kernels_per_layer"],
@@ -1458,31 +1521,35 @@ class GeneticCnnModel(GentunModel):
                         [np.ones(fold_size, np.float32), np.zeros(pad, np.float32)]
                     )
 
+            fused = cfg["fold_parallel"]
             with _phase("init_params"):
-                params = _init_population_params(
-                    model, stacked, cfg["input_shape"], pop, kfold, cfg["seed"], hashes
-                )
-                _record_cost_calibration(cfg, params, kfold * pop)
-                # Parent→child weight inheritance (multi-fidelity ladder): overlay
-                # each slot's own lower-rung trained params where shapes match, and
-                # bank fold-0 results for the NEXT rung.  Segmented single-process
-                # path only: the fused fold_parallel program has no per-fold host
-                # boundary to deposit at, and on a multi-process mesh the gather
-                # would stall every rank for a process-local cache — both fall back
-                # to cold starts, which is always correct (pure speedup).
-                warm = cfg["warm_start"] and mesh is None and not cfg["fold_parallel"]
-                if warm:
-                    params, warmed = _warm_start_overlay(params, hashes[:n_real])
-                    if warmed:
-                        logger.debug("warm start: %d/%d slots inherited banked params",
-                                     warmed, n_real)
-                fold_keys = _content_keys(jax.random.PRNGKey(cfg["seed"]), kfold, hashes)
+                if fused:
+                    params = _init_population_params(
+                        model, stacked, cfg["input_shape"], pop, kfold, cfg["seed"], hashes
+                    )
+                    _record_cost_calibration(cfg, params, kfold * pop)
+                    fold_keys = _content_keys(jax.random.PRNGKey(cfg["seed"]), kfold, hashes)
+                else:
+                    masks, carries = _fold_carries(cfg, model, stacked, hashes, kfold, mesh)
+                    # Parent→child weight inheritance (multi-fidelity ladder): overlay
+                    # each slot's own lower-rung trained params where shapes match, and
+                    # bank fold-0 results for the NEXT rung.  Segmented single-process
+                    # path only: the fused fold_parallel program has no per-fold host
+                    # boundary to deposit at, and on a multi-process mesh the gather
+                    # would stall every rank for a process-local cache — both fall back
+                    # to cold starts, which is always correct (pure speedup).
+                    warm = cfg["warm_start"] and mesh is None
+                    if warm:
+                        carries, warmed = _warm_start_overlay(carries, hashes[:n_real])
+                        if warmed:
+                            logger.debug("warm start: %d/%d slots inherited banked params",
+                                         warmed, n_real)
 
             x_dev, y_dev = _device_dataset(x_train, y_train, x, y, perm, cfg, mesh)
 
-            if not cfg["fold_parallel"]:
+            if not fused:
                 accs = _run_segmented(
-                    cfg, stacked, params, fold_keys, x_dev, y_dev,
+                    cfg, masks, carries, x_dev, y_dev,
                     val_idx, val_weight, batch_idx, mesh, batch_size, n_tr,
                     n_val_padded, eval_bs,
                     warm_keys=hashes[:n_real] if warm else None,
@@ -1619,21 +1686,13 @@ class GeneticCnnModel(GentunModel):
         # train_and_score under the search's own seed would replicate the
         # CV fold-0 init/dropout streams bit-for-bit, correlating the
         # holdout estimate with the CV estimate it is supposed to check.
-        params = _init_population_params(
-            model, stacked, cfg["input_shape"], pop, 1, cfg["seed"], hashes,
-            domain=_HOLDOUT_DOMAIN,
-        )
-        _record_cost_calibration(cfg, params, pop)
-        keys = _content_keys(
-            jax.random.fold_in(jax.random.PRNGKey(cfg["seed"]), _HOLDOUT_DOMAIN),
-            1, hashes,
-        )
+        masks, carries = _fold_carries(cfg, model, stacked, hashes, 1, mesh, domain=_HOLDOUT_DOMAIN)
         x_full = np.concatenate([x_tr, x_te], axis=0)
         y_full = np.concatenate([y_tr, y_te], axis=0)
         # The holdout is one "fold"; the segmented executor drives it with
         # the same bounded device calls as CV.
         accs = _run_segmented(
-            cfg, stacked, params, keys, x_full, y_full,
+            cfg, masks, carries, x_full, y_full,
             val_idx, val_weight, batch_idx, mesh, batch_size, n_tr,
             n_val_padded, eval_bs,
         )

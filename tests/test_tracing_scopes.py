@@ -9,7 +9,8 @@ and the trace reader that turns both into seconds per op class.
 - off, ``_phase`` is the spans module's no-op singleton and nothing is
   recorded; on, one ``cv_call`` per chunk with its children in order,
   ``dispatch_s <= dur_s`` on every device span, an ``oom_attempt`` span when
-  the healer splits;
+  the healer splits; a handful of programs asked for before a fresh
+  configuration's first train dispatch (PR 26: the eager head stays gone);
 - ``benchmark/scope_reduce.py``: classification and arithmetic on
   ``benchmark/fixtures/scope_fixture.json``, and its reading of the protobuf
   wire format against a trace this jax writes.
@@ -205,6 +206,39 @@ def test_device_spans_split_dispatch_from_wait(data, telemetry, fold_parallel):
         assert all(r["attrs"]["fused"] for r in device)
     else:
         assert all(r["attrs"]["carry_devices"] >= 1 for r in device if r["attrs"].get("phase", r["kind"]) == "train")
+
+
+#: Programs a fresh configuration may ask the backend for between the start of
+#: its first ``cv_call`` and its first train dispatch (PR 26): the carry builder,
+#: ``init_pop``, and the four tiny ones behind the two base keys (once a
+#: process).  The parent asked for 34 here: a slice per parameter leaf shape and
+#: the eager key chains.
+HEAD_PROGRAMS = 8
+
+
+def test_a_fresh_configuration_asks_for_a_handful_of_programs_before_its_first_train(data, telemetry):
+    import time
+
+    import jax
+
+    compiles = []  # jax has no public way to take a listener back; this one costs an append a compile
+
+    def on_duration(event, duration, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(time.time())
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    # widths no other test of this process uses: every eager op on a parameter leaf would be a program of its own
+    fresh = {**KW, "kernels_per_layer": (5, 7), "dense_units": 11, "mesh": None}
+    GeneticCnnModel.cross_validate_population(*data, GENOMES, **fresh)
+    (call,) = of_kind(telemetry, "cv_call")
+    device = of_kind(telemetry, "compile")
+    assert [r["attrs"]["phase"] for r in device] == ["train", "eval"]  # fold 1 reuses both
+    head = [t for t in compiles if call["t_wall"] <= t <= device[0]["t_wall"]]
+    assert 2 <= len(head) <= HEAD_PROGRAMS and len(compiles) <= HEAD_PROGRAMS + 2
+    first = len(compiles)
+    GeneticCnnModel.cross_validate_population(*data, GENOMES[::-1], **fresh)
+    assert len(compiles) == first  # a shape already seen asks for nothing
 
 
 def test_an_oom_the_healer_cures_leaves_an_oom_attempt_span(data, telemetry, monkeypatch):
