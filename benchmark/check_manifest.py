@@ -2,12 +2,16 @@
 
 Every name, unit and layer against the character rules; every ``moves``
 names an end-to-end metric that every cell of the per-layer metric reports;
-every file a cell needs exists; and ``trace_reduce.reduce`` gives the
-fixture's expected busy time, idle share, top ops and named gaps.
+every file a cell needs exists; every configuration states its model
+``family``, whose directory ``families/<family>/`` lies under ``paths`` and
+holds the family's files and the four functions the harness calls; and
+``trace_reduce.reduce`` gives the fixture's expected busy time, idle share,
+top ops and named gaps.
 """
 
 from __future__ import annotations
 
+import ast
 import json
 import os
 import re
@@ -21,6 +25,47 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 LINE = re.compile(r"^[^\t\n]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+FAMILY_FILES = ("family.py", "reference.py")
+FAMILY_FUNCTIONS = ("make_inputs", "program_side", "after_window", "window_checks")
+
+
+def top_level_names(path: str) -> set:
+    """Names a module binds at its top level, read without importing it (a
+    family's files import jax and the program): defs, imports, assignments."""
+    with open(path, encoding="utf-8") as fh:
+        tree = ast.parse(fh.read())
+    names = set()
+    for node in tree.body:
+        if isinstance(node, ast.FunctionDef):
+            names.add(node.name)
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            names |= {(a.asname or a.name).split(".")[0] for a in node.names}
+        elif isinstance(node, ast.Assign):
+            names |= {t.id for t in node.targets if isinstance(t, ast.Name)}
+    return names
+
+
+def check_family(config_name: str, config_file: str, under_paths) -> list:
+    """The configuration's ``family``: stated (there is no default), one token,
+    a directory under ``paths`` with the contract's files and functions."""
+    with open(config_file, encoding="utf-8") as fh:
+        family = json.load(fh).get("family")
+    if not isinstance(family, str) or not NAME.match(family):
+        return [f"config {config_name}: its file states no 'family' that is one token (got {family!r})"]
+    folder = os.path.join(HERE, "families", family)
+    relative = os.path.relpath(folder, ROOT).replace(os.sep, "/")
+    if not os.path.isdir(folder):
+        return [f"config {config_name}: family {family!r} has no directory {relative}/"]
+    bad = []
+    if not under_paths(relative + "/"):
+        bad.append(f"config {config_name}: family directory {relative}/ is outside paths")
+    missing = [f for f in FAMILY_FILES if not os.path.isfile(os.path.join(folder, f))]
+    if missing:
+        return bad + [f"config {config_name}: family {family!r} lacks {', '.join(missing)}"]
+    lacking = [f for f in FAMILY_FUNCTIONS if f not in top_level_names(os.path.join(folder, "family.py"))]
+    if lacking:
+        bad.append(f"config {config_name}: {relative}/family.py lacks {', '.join(lacking)}")
+    return bad
 
 
 def check(manifest) -> list:
@@ -40,6 +85,8 @@ def check(manifest) -> list:
              f"config {c['name']}: file {c['file']} missing or outside paths")
         need(len(c["reduced"]) <= 16 and all(NAME.match(k) for k in c["reduced"]),
              f"config {c['name']}: reduced keys")
+        if os.path.isfile(os.path.join(ROOT, c["file"])):
+            bad += check_family(c["name"], os.path.join(ROOT, c["file"]), under_paths)
         configs[c["name"]] = c
     cells, pairs = {}, set()
     for w in manifest["workloads"]:

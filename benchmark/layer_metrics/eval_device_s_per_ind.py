@@ -2,7 +2,8 @@
 ``jit_eval_fold`` on the trace's "XLA Modules" line over the individuals of the
 ``cv_call``s traced (``scope_reduce.py``)."""
 import scope_reduce
+import scope_rules as rules
 
 
 def read(run):
-    return scope_reduce.per_individual(run, scope_reduce.EVAL)
+    return scope_reduce.per_individual(run, rules, rules.EVAL)

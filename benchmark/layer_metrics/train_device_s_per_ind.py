@@ -3,7 +3,8 @@
 of the ``cv_call``s traced (``scope_reduce.py``).  Beside ``train_s_per_ind``,
 the fenced span: their difference is what fencing and launching cost."""
 import scope_reduce
+import scope_rules as rules
 
 
 def read(run):
-    return scope_reduce.per_individual(run, scope_reduce.TRAIN)
+    return scope_reduce.per_individual(run, rules, rules.TRAIN)

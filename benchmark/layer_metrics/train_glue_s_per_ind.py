@@ -2,7 +2,8 @@
 sums, node gating, stage merge, pooling, relu and casts around the
 convolutions (``scope_reduce.py``); what ROADMAP S2 (b)-(d) may remove."""
 import scope_reduce
+import scope_rules as rules
 
 
 def read(run):
-    return scope_reduce.per_individual(run, scope_reduce.TRAIN, ("glue",))
+    return scope_reduce.per_individual(run, rules, rules.TRAIN, ("glue",))

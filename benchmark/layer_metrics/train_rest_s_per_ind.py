@@ -1,7 +1,8 @@
 """Self time of ``head`` + ``rest`` (loss, optimizer, batch gather, rng, loop
 bookkeeping) in the train program, per individual traced (``scope_reduce.py``)."""
 import scope_reduce
+import scope_rules as rules
 
 
 def read(run):
-    return scope_reduce.per_individual(run, scope_reduce.TRAIN, ("head", "rest"))
+    return scope_reduce.per_individual(run, rules, rules.TRAIN, ("head", "rest"))

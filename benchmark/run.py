@@ -3,13 +3,16 @@
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: its
-configuration (``configs/<config>.json``), its traffic mix
-(``traffic/<traffic>.json``, which names its kind), the kind's module
+configuration (``configs/<config>.json``), the configuration's model family
+(``families/<family>/``: inputs from the seed, the plain reference, the
+comparison that decides ``correct``, the counts of operations), its traffic
+mix (``traffic/<traffic>.json``, which names its kind), the kind's module
 (``traffic_kinds/<kind>.py``) and, in a traced run, one reader per per-layer
-metric (``layer_metrics/<metric>.py``).  See ``README.md`` beside this file.
+metric (``layer_metrics/<metric>.py``).  The harness itself names no model,
+no gene and no data shape.  See ``README.md`` beside this file.
 
-Set-up (backend, data and genomes from ``--seed``, warm-up of the cell's own
-programs, the train program's half of the correctness check) ends where the
+Set-up (backend, the family's inputs from ``--seed``, warm-up of the cell's
+own programs, the program's half of the correctness check) ends where the
 window opens.  The window starts a new unit of work only while less than
 ``--seconds`` have passed and closes when the unit in flight returns; every
 rate divides by the time that really passed.  The reference's half of the
@@ -29,6 +32,7 @@ import time
 T_START = time.monotonic()
 
 import argparse
+import importlib
 import importlib.util
 import json
 import math
@@ -36,8 +40,6 @@ import os
 import sys
 import traceback
 from typing import Any, Dict, List, Optional
-
-import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -59,6 +61,27 @@ def load_module(folder: str, name: str):
     return module
 
 
+def load_family(name: str):
+    """The model family of the run's configuration: ``families/<name>/family.py``.
+
+    One process runs one cell, so one family: its directory goes first on
+    ``sys.path``, so that its files import each other by bare name (the
+    comparison its copy of the reference) and the family's readers under
+    ``layer_metrics/`` import its counts and rules the same way (``import
+    flops``, ``import scope_rules``).  ``family.py`` there is what
+    the harness calls: ``make_inputs``, ``program_side``, ``after_window``
+    and ``window_checks`` (README.md, "A model family")."""
+    folder = os.path.join(HERE, "families", name)
+    if not os.path.isfile(os.path.join(folder, "family.py")):
+        raise SystemExit(f"no model family {name!r}: {os.path.join(folder, 'family.py')} is missing")
+    loaded = sys.modules.get("family")
+    if loaded is not None and os.path.dirname(os.path.abspath(loaded.__file__)) != folder:
+        raise SystemExit(f"this process has loaded the family at {loaded.__file__}; one process runs one family")
+    if folder not in sys.path:
+        sys.path.insert(0, folder)
+    return importlib.import_module("family")
+
+
 def merge(base: Dict[str, Any], over: Dict[str, Any]) -> Dict[str, Any]:
     out = dict(base)
     for k, v in over.items():
@@ -70,24 +93,7 @@ def applies(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-# -- inputs from the seed ------------------------------------------------------
-
-
-def synthetic_images(data: Dict[str, Any], seed: int):
-    """Class prototypes plus noise (``bench.synthetic_cifar``, any class count)."""
-    rng = np.random.default_rng([seed, 0xDA7A])
-    shape, classes = tuple(data["input_shape"]), data["n_classes"]
-    protos = rng.normal(size=(classes, *shape)).astype(np.float32)
-    y = rng.integers(0, classes, size=data["n"]).astype(np.int32)
-    x = protos[y] + data["noise"] * rng.normal(size=(data["n"], *shape)).astype(np.float32)
-    return x, y
-
-
-def make_pool(nodes, size: int, seed) -> List[Dict[str, tuple]]:
-    """``size`` random genomes: one bit per ordered node pair of each stage."""
-    rng = np.random.default_rng(seed)
-    return [{f"S_{s + 1}": tuple(int(b) for b in rng.integers(0, 2, size=k * (k - 1) // 2))
-             for s, k in enumerate(nodes)} for _ in range(size)]
+# -- the cell, found by name --------------------------------------------------
 
 
 def load_cell(workload: str, rehearsal: bool = False):
@@ -101,20 +107,6 @@ def load_cell(workload: str, rehearsal: bool = False):
     if rehearsal:
         config = merge(config, config.get("rehearsal", {}))
     return manifest, cell, config, load_json(HERE, "traffic", cell["traffic"] + ".json")
-
-
-def make_inputs(config: Dict[str, Any], mix: Dict[str, Any], seed: int, rehearsal: bool = False):
-    """(model parameters, images, labels, pool of genomes), all from the seed
-    but the pool, which a mix may fix so that every seed does the same work."""
-    model = config["model"]
-    params = {k: tuple(v) if isinstance(v, list) else v for k, v in model.items()}
-    params["seed"] = seed % (2**31 - 1)
-    if rehearsal:
-        params["cache_dir"] = False
-    x, y = synthetic_images(config["data"], seed)
-    pool = make_pool(model["nodes"], config["population"] * int(mix.get("pool_populations", 1)),
-                     [int(mix["pool_seed"])] if "pool_seed" in mix else [seed, 0x9001])
-    return params, x, y, pool
 
 
 # -- what jax itself says about compiles ---------------------------------------------
@@ -250,18 +242,16 @@ def run(args) -> Dict[str, Any]:
         spans.set_run_sink(records)
         spans.enable()
 
-    params, x, y, pool = make_inputs(config, mix, args.seed, args.rehearsal)
-    ctx = Ctx(config=config, mix=mix, cell=cell, params=params, x=x, y=y, seed=args.seed,
-              pool=pool, monitor=monitor, records=records, trace=bool(args.trace),
-              rehearsal=args.rehearsal, chips=cell["chips"])
-
-    import correct
+    family = load_family(config["family"])
+    ctx = Ctx(config=config, mix=mix, cell=cell, seed=args.seed, monitor=monitor, records=records,
+              trace=bool(args.trace), rehearsal=args.rehearsal, chips=cell["chips"],
+              **family.make_inputs(config, mix, args.seed, args.rehearsal))
 
     memory = MemoryPeak()
     state = kind.setup(ctx, mix)
     print("info memory_stats after the warm-up call, the window's programs loaded and no other:",
           json.dumps(memory.sample()))
-    program = correct.program_side(ctx)
+    program = family.program_side(ctx)
     setup_requests, setup_hits, setup_compiles = len(monitor.requests), len(monitor.hits), len(monitor.compiles)
 
     # -- the window ------------------------------------------------------------
@@ -306,27 +296,21 @@ def run(args) -> Dict[str, Any]:
 
     # -- what was produced, and is it right ------------------------------------------
     scored = sum(u["scored"] for u in units)
-    fitness = [f for u in units for f in u["fitness"]]
+    trained = sum(u["trained"] for u in units)
     failed = raised + sum(u["failed"] for u in units)
-    floor = config["check"]["fitness_mean_floor"]
-    in_range = all(math.isfinite(f) and 0.0 <= f <= 1.0 for f in fitness)
-    mean = float(np.mean(fitness)) if fitness else float("nan")
-    checks = [
-        {"name": "units_in_window", "value": len(units), "limit": ">=1", "ok": len(units) >= 1 and not raised},
-        {"name": "fitness_in_unit_interval", "value": int(in_range), "limit": 1, "ok": in_range},
-        {"name": "fitness_mean_floor", "value": mean, "limit": f">{floor}",
-         "ok": args.rehearsal or (bool(fitness) and mean > floor)},
-    ]
+    checks = [{"name": "units_in_window", "value": len(units), "limit": ">=1",
+               "ok": len(units) >= 1 and not raised}]
+    checks += family.window_checks(ctx, units)
     checks += kind.checks(ctx, mix, state, units)
     t_ref = time.monotonic()
-    checks += correct.after_window(ctx, program)[0]
+    checks += family.after_window(ctx, program)[0]
     reference_s = time.monotonic() - t_ref
     for c in checks:
         print(f"check {c['name']}: value={c['value']} limit={c['limit']} "
               f"{'ok' if c['ok'] else 'NOT OK'}")
     ok = all(c["ok"] for c in checks) and failed == 0
 
-    print(f"window: {len(units)} units, {scored} individuals scored, {len(fitness)} trained, "
+    print(f"window: {len(units)} units, {scored} individuals scored, {trained} trained, "
           f"elapsed {elapsed:.4f} s of {args.seconds} asked; unit walls "
           f"{[round(u['wall_s'], 3) for u in units]}")
     print(f"set-up: {setup_s:.3f} s (backend {backend_s:.3f} s); jax asked for {setup_requests} "

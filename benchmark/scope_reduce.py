@@ -15,24 +15,23 @@ named ``jit_<function>(<fingerprint>)``, the name the HLO proto is filed under.
 The host plane's "python" line has the program's ``gentun/<kind>`` annotations
 (``models/cnn.py::_phase``) with their scalars as stats (``n_real``, ``fold``).
 
-Classes (``classify``; the vocabulary is docs/OBSERVABILITY.md's): an
-instruction whose ``op_name`` passes through the model (``MaskedGeneticCnn``,
-under ``jvp(`` forward, ``transpose(jvp(`` backward, bare in the eval program)
-is ``conv_fwd``/``conv_bwd`` under a conv module (``stage*_entry|node*|exit``),
-``head`` under ``head``/``Dense_*``/``Dropout_*``, else ``glue``: the
-``stage{s}/mask_sum|gate|merge|pool`` scopes, and what has neither module nor
-scope (relu, casts).  Outside the model it is ``rest`` (``loss``, ``optimizer``,
-``gather``, ``score``, rng, loop bookkeeping).  Without ``op_name``:
-``unattributed``.  Programs served from a compile-cache entry written before
-the scopes existed carry the older names; the same rules then give the same
-five classes, only the detail column cannot tell a mask sum from a pool.
+This file is the trace: the protobuf, matching events to modules, self time,
+the fusion vote, ``per_individual``.  What is the model -- which programs to
+read, the classes, and the rule from an ``op_name`` to a class -- is the
+``rules`` every function here takes: one object of the run's model family
+(``families/<family>/scope_rules.py``, whose docstring has the vocabulary),
+handed over by that family's readers under ``layer_metrics/``.  It has
+``CLASSES`` (``unattributed``, the class of an op no rule can place, among
+them), ``PROGRAMS`` (base names of the jitted programs to read),
+``classify(op_name) -> (class, detail)``, ``SCOPED_DETAILS``, ``SPAN_PROGRAMS``,
+``SPAN_ATTR`` and ``CALL_ANNOTATION``.
 
-A fusion goes to the class of the convolution it contains (XLA:TPU turns the
-dense layers' dots into convolutions too, so that is ``head`` for them), else
-to the class most of its instructions with an ``op_name`` have (parameters,
-constants, bitcasts and tuples do not vote), else to its own ``op_name``'s,
-else ``unattributed``.  Time is self time (``trace_reduce.self_times``): a
-``while`` spans its body's events and keeps only what they leave.
+A fusion goes to the class of the convolution it contains (XLA:TPU turns
+dense layers' dots into convolutions too), else to the class most of its
+instructions with an ``op_name`` have (parameters, constants, bitcasts and
+tuples do not vote), else to its own ``op_name``'s, else ``unattributed``.
+Time is self time (``trace_reduce.self_times``): a ``while`` spans its body's
+events and keeps only what they leave.
 
 ``seconds_per_class`` is the pure arithmetic, checked on
 ``fixtures/scope_fixture.json``; ``read`` turns a trace file into its inputs;
@@ -44,7 +43,9 @@ writes ``op_classes.json`` beside ``inventory.json``.
 from __future__ import annotations
 
 import bisect
+import functools
 import glob
+import importlib.util
 import json
 import os
 import re
@@ -55,62 +56,67 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 import trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-CLASSES = ("conv_fwd", "conv_bwd", "glue", "head", "rest", "unattributed")
-TRAIN, EVAL = "jit_train_segment", "jit_eval_fold"  # the cell's two programs
-MODEL = "MaskedGeneticCnn"
-CONV_MODULE = re.compile(r"^stage\d+_(entry|node\d+|exit)$")
-HEAD_MODULE = re.compile(r"^(head|Dense_\d+|Dropout_\d+)$")
-GLUE_SCOPES = ("mask_sum", "gate", "merge", "pool")
-REST_SCOPES = re.compile(r"\b(loss|optimizer|gather|score)\b")
+UNATTRIBUTED = "unattributed"  # an op no rule can place: in every family's CLASSES
 NO_VOTE = {"parameter", "constant", "bitcast", "tuple", "get-tuple-element"}
 ANNOTATION = "gentun/"
 
 Instruction = Dict[str, Any]  # {"opcode", "op_name", "body": [[opcode, op_name], ...]}
 
+# -- callers older than the families ---------------------------------------------------------
+#
+# ``tests/test_tracing_scopes.py`` (PR 25) calls this module as it was when it
+# held the Genetic-CNN's rules itself: ``scope_reduce.CLASSES``, ``.TRAIN``,
+# ``.EVAL`` and every function without ``rules``.  A benchmark PR may not edit
+# a test outside ``benchmark/``, so for those calls alone ``rules=None`` means
+# the rules of ``LEGACY_FAMILY``, loaded by path.  Nothing under ``benchmark/``
+# leans on it; it goes when that test hands the rules over (PERF.md §7).
+
+LEGACY_FAMILY = "genetic_cnn"
+
+
+@functools.lru_cache(maxsize=None)
+def _legacy_rules():
+    path = os.path.join(HERE, "families", LEGACY_FAMILY, "scope_rules.py")
+    spec = importlib.util.spec_from_file_location(f"bench_scope_rules_{LEGACY_FAMILY}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def __getattr__(name: str):
+    if name in ("CLASSES", "TRAIN", "EVAL"):
+        return getattr(_legacy_rules(), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 # -- classification: strings only ------------------------------------------------------
 
 
-def classify(op_name: str) -> Tuple[str, str]:
-    """(class, detail) of one instruction from its ``op_name``."""
-    if not op_name:
-        return "unattributed", ""
-    parts = op_name.rstrip(":").split("/")
-    at = next((i for i, p in enumerate(parts) if MODEL in p), None)
-    if at is None:
-        scope = REST_SCOPES.search(op_name)
-        return "rest", scope.group(1) if scope else ("rng" if "threefry" in op_name else "other")
-    backward = parts[at].startswith("transpose(")
-    inside = parts[at + 1:]
-    for i, part in enumerate(inside):
-        if CONV_MODULE.match(part):
-            return ("conv_bwd" if backward else "conv_fwd"), part
-        if HEAD_MODULE.match(part):
-            return "head", "head"
-        if re.match(r"^stage\d+$", part) and inside[i + 1:i + 2] and inside[i + 1] in GLUE_SCOPES:
-            return "glue", inside[i + 1]
-    return "glue", "no_scope"
+def classify(op_name: str, rules=None) -> Tuple[str, str]:
+    """(class, detail) of one instruction from its ``op_name``, by the family's rule."""
+    return (rules or _legacy_rules()).classify(op_name)
 
 
-def classify_instruction(ins: Instruction) -> Tuple[str, str]:
+def classify_instruction(ins: Instruction, rules=None) -> Tuple[str, str]:
     """The fusion rule of the module docstring."""
+    rules = rules or _legacy_rules()
     body = ins.get("body") or []
     conv = next((n for op, n in body if op == "convolution" and n), None)
     if conv:
-        return classify(conv)
+        return rules.classify(conv)
     votes: Dict[Tuple[str, str], int] = {}
     for op, n in body:
         if n and op not in NO_VOTE:
-            key = classify(n)
+            key = rules.classify(n)
             votes[key] = votes.get(key, 0) + 1
     if votes:
         per_class: Dict[str, int] = {}
         for (c, _), k in votes.items():
             per_class[c] = per_class.get(c, 0) + k
-        best = max(per_class, key=lambda c: (per_class[c], -CLASSES.index(c)))
+        best = max(per_class, key=lambda c: (per_class[c], -rules.CLASSES.index(c)))
         detail = max((k, d) for (c, d), k in votes.items() if c == best)[1]
         return best, detail
-    return classify(ins.get("op_name", ""))
+    return rules.classify(ins.get("op_name", ""))
 
 
 def instruction_of(hlo_line: str) -> str:
@@ -128,7 +134,7 @@ def base_name(module: str) -> str:
 
 def seconds_per_class(ops: Dict[str, Sequence[Tuple[str, float, float]]],
                       programs: Dict[str, Dict[str, Instruction]],
-                      fallback: Optional[Dict[str, str]] = None) -> Dict[str, Dict[str, Any]]:
+                      fallback: Optional[Dict[str, str]] = None, rules=None) -> Dict[str, Dict[str, Any]]:
     """``ops``: program -> [(HLO line or instruction name, start, end)] of one
     device, seconds; ``programs``: program -> instruction name -> Instruction;
     ``fallback``: HLO line -> ``op_name`` for an op of a program without a
@@ -136,17 +142,18 @@ def seconds_per_class(ops: Dict[str, Sequence[Tuple[str, float, float]]],
     {"class/detail": seconds}, "ops": {instruction: [class, seconds]}}."""
     out: Dict[str, Dict[str, Any]] = {}
     fallback = fallback or {}
+    rules = rules or _legacy_rules()
     for program, intervals in ops.items():
         table = programs.get(program)
-        classes = {c: 0.0 for c in CLASSES}
+        classes = {c: 0.0 for c in rules.CLASSES}
         details: Dict[str, float] = {}
         per_op: Dict[str, List[Any]] = {}
         for line, seconds in trace_reduce.self_times(intervals):
             name = instruction_of(line)
             if table is not None:
-                klass, detail = classify_instruction(table[name]) if name in table else ("unattributed", "")
+                klass, detail = classify_instruction(table[name], rules) if name in table else (UNATTRIBUTED, "")
             else:
-                klass, detail = classify(fallback.get(line, ""))
+                klass, detail = rules.classify(fallback.get(line, ""))
             classes[klass] += seconds
             key = f"{klass}/{detail}" if detail else klass
             details[key] = details.get(key, 0.0) + seconds
@@ -288,13 +295,13 @@ def newest_trace(cell: str) -> Optional[str]:
     return max(filter(None, found), key=os.path.getmtime, default=None)
 
 
-def _entry() -> Dict[str, Any]:
-    zeros = lambda: {c: 0.0 for c in CLASSES}
+def _entry(rules) -> Dict[str, Any]:
+    zeros = lambda: {c: 0.0 for c in rules.CLASSES}
     return {"runs": 0, "device_s": 0.0, "classes": zeros(), "details": {}, "flops": zeros(), "bytes": zeros(),
             "ops": {}}
 
 
-def _metadata(raw: bytes) -> Tuple[Dict[str, Dict[str, Instruction]], Dict[str, Dict[str, Any]]]:
+def _metadata(raw: bytes, rules) -> Tuple[Dict[str, Dict[str, Instruction]], Dict[str, Dict[str, Any]]]:
     """(program -> instruction table from its "Hlo Proto", HLO line -> the
     stats of its event metadata on a device plane)."""
     programs: Dict[str, Dict[str, Instruction]] = {}
@@ -308,19 +315,20 @@ def _metadata(raw: bytes) -> Tuple[Dict[str, Dict[str, Instruction]], Dict[str, 
             if on_device:
                 if "tf_op" in stats or "flops" in stats:
                     costs.setdefault(name, stats)
-            elif isinstance(stats.get("Hlo Proto"), bytes) and base_name(name) in (TRAIN, EVAL):
+            elif isinstance(stats.get("Hlo Proto"), bytes) and base_name(name) in rules.PROGRAMS:
                 programs[name] = hlo_instructions(stats["Hlo Proto"])
     return programs, costs
 
 
-def read(path: str) -> Dict[str, Any]:
+def read(path: str, rules=None) -> Dict[str, Any]:
     """Everything the readers need of one trace file, times in seconds on the
     trace's own clock; sums over devices are divided by the devices traced."""
     import jax
 
+    rules = rules or _legacy_rules()
     with open(path, "rb") as fh:
         raw = fh.read()
-    programs, costs = _metadata(raw)
+    programs, costs = _metadata(raw, rules)
     fallback = {line: (stats.get("tf_op") or b"").decode(errors="replace") for line, stats in costs.items()}
     data = jax.profiler.ProfileData.from_serialized_xspace(raw)
     devices, annotations, anchor = {}, [], None
@@ -347,14 +355,14 @@ def read(path: str) -> Dict[str, Any]:
         ops: Dict[str, List[Tuple[str, float, float]]] = {}
         for name, s, e in lines.get(trace_reduce.OPS_LINE, []):
             i = bisect.bisect_right(starts, s) - 1
-            if i >= 0 and s < modules[i][2] and base_name(modules[i][0]) in (TRAIN, EVAL):
+            if i >= 0 and s < modules[i][2] and base_name(modules[i][0]) in rules.PROGRAMS:
                 ops.setdefault(modules[i][0], []).append((name, s, e))
         for name, s, e in modules:
-            if base_name(name) in (TRAIN, EVAL):
-                entry = per_program.setdefault(name, _entry())
+            if base_name(name) in rules.PROGRAMS:
+                entry = per_program.setdefault(name, _entry(rules))
                 entry["runs"] += 1
                 entry["device_s"] += (e - s) * share
-        for program, got in seconds_per_class(ops, programs, fallback).items():
+        for program, got in seconds_per_class(ops, programs, fallback, rules).items():
             entry = per_program[program]
             for c, t in got["classes"].items():
                 entry["classes"][c] += t * share
@@ -370,7 +378,7 @@ def read(path: str) -> Dict[str, Any]:
                     entry["flops"][klass] += float(stats.get("flops") or 0) * share
                     entry["bytes"][klass] += float(stats.get("bytes_accessed") or 0) * share
     scoped = any("/" + scope in detail for p in per_program.values()
-                 for detail in p["details"] for scope in GLUE_SCOPES)
+                 for detail in p["details"] for scope in rules.SCOPED_DETAILS)
     return {"path": path, "programs": per_program, "annotations": sorted(annotations, key=lambda a: a["start"]),
             "anchor": anchor, "runs": sorted(runs, key=lambda m: m[1]), "devices": len(devices),
             "hlo_tables": sorted(programs), "names": "scopes" if scoped else "modules_only"}
@@ -379,13 +387,15 @@ def read(path: str) -> Dict[str, Any]:
 # -- what the layer_metrics readers call -------------------------------------------------------
 
 
-def individuals_traced(trace: Dict[str, Any], run: Dict[str, Any]) -> int:
-    """Individuals of the ``cv_call``s the trace covers: the ``n_real`` of the
-    ``gentun/cv_call`` annotations; for a program that has no such annotation,
-    the calls the harness counted that started inside the traced stretch."""
-    calls = [a for a in trace["annotations"] if a["kind"] == "cv_call"]
+def individuals_traced(trace: Dict[str, Any], run: Dict[str, Any], rules=None) -> int:
+    """Individuals of the evaluator calls the trace covers: the counting stat
+    of the family's call annotation (the ``n_real`` of the ``gentun/cv_call``
+    annotations); for a program that has no such annotation, the calls the
+    harness counted that started inside the traced stretch."""
+    kind, stat = (rules or _legacy_rules()).CALL_ANNOTATION
+    calls = [a for a in trace["annotations"] if a["kind"] == kind]
     if calls:
-        return int(sum(int(a["stats"].get("n_real", 0)) for a in calls))
+        return int(sum(int(a["stats"].get(stat, 0)) for a in calls))
     if not run.get("trace"):
         return 0
     lo = run["window"][0]
@@ -396,45 +406,47 @@ def individuals_traced(trace: Dict[str, Any], run: Dict[str, Any]) -> int:
 def merged(trace: Dict[str, Any], program: str) -> Dict[str, Any]:
     """The per-program entries of one base name (the deep cell runs its train
     program 16 and 2 wide) added up."""
-    out: Dict[str, Any] = {"runs": 0, "device_s": 0.0, "classes": {c: 0.0 for c in CLASSES}}
+    out: Dict[str, Any] = {"runs": 0, "device_s": 0.0, "classes": {}}
     for name, entry in trace["programs"].items():
         if base_name(name) == program:
             out["runs"] += entry["runs"]
             out["device_s"] += entry["device_s"]
             for c, t in entry["classes"].items():
-                out["classes"][c] += t
+                out["classes"][c] = out["classes"].get(c, 0.0) + t
     return out
 
 
-def table(run: Dict[str, Any]) -> Optional[Dict[str, Any]]:
-    """The newest trace of the run's cell, read; None if there is none.  The
-    first call prints the tables and writes ``op_classes.json``; the result
-    rides on ``run``, which every reader of a run is handed."""
+def table(run: Dict[str, Any], rules) -> Optional[Dict[str, Any]]:
+    """The newest trace of the run's cell, read by the family's ``rules``; None
+    if there is none.  The first call prints the tables and writes
+    ``op_classes.json``; the result rides on ``run``, which every reader of a
+    run is handed."""
     if "scope_table" in run:
         return run["scope_table"]
     path = newest_trace(run["cell"]["name"])
     try:
-        run["scope_table"] = trace = read(path) if path else None
+        run["scope_table"] = trace = read(path, rules) if path else None
     except Exception:  # a reader that cannot read leaves its metrics out; it does not end the run
         traceback.print_exc()
         run["scope_table"] = trace = None
     if trace is None:
         return None
-    trace["individuals"] = n = individuals_traced(trace, run)
+    trace["individuals"] = n = individuals_traced(trace, run, rules)
+    call = rules.CALL_ANNOTATION[0]
     print(f"info op_class trace {os.path.relpath(path, HERE)}: {trace['devices']} device(s), {n} individuals in "
-          f"{sum(a['kind'] == 'cv_call' for a in trace['annotations'])} cv_call annotations, names: "
+          f"{sum(a['kind'] == call for a in trace['annotations'])} {call} annotations, names: "
           f"{trace['names']}, HLO tables for {len(trace['hlo_tables'])} of {len(trace['programs'])} programs")
     for name, p in sorted(trace["programs"].items()):
         busy = sum(p["classes"].values())
         print(f"info op_class {name}: {p['runs']} runs, {p['device_s']:.4f} s on XLA Modules, {busy:.4f} s in ops")
-        for c in CLASSES:
+        for c in rules.CLASSES:
             print(f"info op_class {name} {c}: {p['classes'][c]:.4f} s ({100 * p['classes'][c] / busy if busy else 0:.1f}%), "
                   f"{p['flops'][c] / 1e12:.3f} TFLOP, {p['bytes'][c] / 1e9:.2f} GB accessed")
         for d, t in sorted(p["details"].items(), key=lambda kv: -kv[1])[:24]:
             print(f"info op_class {name} detail {d}: {t:.4f} s")
         for op, (klass, t) in sorted(p["ops"].items(), key=lambda kv: -kv[1][1])[:8]:
             print(f"info op_class {name} op {op} [{klass}]: {t:.4f} s")
-    _print_clocks(trace, run)
+    _print_clocks(trace, run, rules)
     with open(os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(path)))),
                            "op_classes.json"), "w", encoding="utf-8") as fh:
         json.dump({k: trace[k] for k in ("path", "programs", "names", "individuals", "hlo_tables", "annotations")},
@@ -442,10 +454,11 @@ def table(run: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     return trace
 
 
-def _print_clocks(trace: Dict[str, Any], run: Dict[str, Any]) -> None:
-    """Two ``info`` lines: how far the annotations' clock and the harness's
-    anchor shift disagree, and what the fenced ``train``/``eval`` spans hold
-    beyond the program's own run on the device."""
+def _print_clocks(trace: Dict[str, Any], run: Dict[str, Any], rules) -> None:
+    """``info`` lines: how far the annotations' clock and the harness's anchor
+    shift disagree, and what the fenced span of each program (the family's
+    ``SPAN_PROGRAMS``: ``train``/``eval``) holds beyond the program's own run
+    on the device."""
     records = [r for r in run["records"] if r.get("type") == "span"]
     if trace["anchor"] is not None and trace["annotations"]:
         shift = run["window"][0] - trace["anchor"]  # the harness anchors as it opens the window
@@ -458,14 +471,14 @@ def _print_clocks(trace: Dict[str, Any], run: Dict[str, Any]) -> None:
                 worst, matched = max(worst, abs(a["start"] + shift - r["t_wall"])), matched + 1
         print(f"info clocks: {matched} gentun annotations against their span records through the anchor shift: "
               f"largest disagreement {1e3 * worst:.3f} ms")
-    for kind, program in (("train", TRAIN), ("eval", EVAL)):
+    for kind, program in rules.SPAN_PROGRAMS:
         seen = [a for a in trace["annotations"] if a["kind"] == kind]
         if not seen:
             continue
         held = sum(a["end"] - a["start"] for a in seen)
         inside = sum(min(e, a["end"]) - max(s, a["start"]) for a in seen for n, s, e in trace["runs"]
                      if base_name(n) == program and s < a["end"] and e > a["start"])
-        spans = [r for r in records if r["kind"] == kind and "fold" in (r.get("attrs") or {})
+        spans = [r for r in records if r["kind"] == kind and rules.SPAN_ATTR in (r.get("attrs") or {})
                  and r["t_wall"] >= run["window"][0]][:len(seen)]
         dispatch = sum(r["attrs"].get("dispatch_s", 0.0) for r in spans)
         print(f"info span_vs_device {kind}: {len(seen)} annotations hold {held:.4f} s; the program ran "
@@ -473,10 +486,11 @@ def _print_clocks(trace: Dict[str, Any], run: Dict[str, Any]) -> None:
               f"{held - inside:.4f} s with no run of the program on the device")
 
 
-def per_individual(run: Dict[str, Any], program: str, classes: Optional[Sequence[str]] = None) -> Optional[float]:
+def per_individual(run: Dict[str, Any], rules, program: str,
+                   classes: Optional[Sequence[str]] = None) -> Optional[float]:
     """Device seconds of ``program`` (whole runs, or the self time of
     ``classes``) per individual of the calls traced."""
-    trace = table(run)
+    trace = table(run, rules)
     if not trace or not trace.get("individuals"):
         return None
     entry = merged(trace, program)

@@ -42,15 +42,14 @@ def main() -> int:
 
     if default_cache_dir() and not args.rehearsal:
         enable_compilation_cache(default_cache_dir())
-    import correct
+    family = harness.load_family(config["family"])
 
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         t0 = time.monotonic()
-        params, x, y, pool = harness.make_inputs(config, mix, seed, args.rehearsal)
-        ctx = harness.Ctx(config=config, params=params, x=x, y=y, seed=seed, pool=pool)
-        program = correct.program_side(ctx)
+        ctx = harness.Ctx(config=config, seed=seed, **family.make_inputs(config, mix, seed, args.rehearsal))
+        program = family.program_side(ctx)
         t1 = time.monotonic()
-        sound, control = correct.after_window(ctx, program, args.control if i < args.controls else None)
+        sound, control = family.after_window(ctx, program, args.control if i < args.controls else None)
         print(json.dumps({"cell": args.workload, "seed": seed, "device": device["kind"],
                           "fit_rows": config["check"]["fit_rows"],
                           "sound": {c["name"]: c["value"] for c in sound},

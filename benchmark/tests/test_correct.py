@@ -25,10 +25,10 @@ sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.dirname(HERE))
 os.environ.setdefault("GENTUN_TPU_CACHE_DIR", "off")
 
-import correct  # noqa: E402
 import run as harness  # noqa: E402
 
 CELL = "c10_flagship.popeval"
+family = harness.load_family("genetic_cnn")
 
 
 def args(seed: int, cell: str = CELL) -> argparse.Namespace:
@@ -54,9 +54,8 @@ def test_the_fp8_control_is_over_the_limit():
                                     "data": {"n": 320}})
     limit = config["check"]["limits"]["logit_gap"]
     for seed in (31, 32, 33):
-        params, x, y, pool = harness.make_inputs(config, mix, seed, rehearsal=True)
-        ctx = harness.Ctx(config=config, params=params, x=x, y=y, seed=seed, pool=pool)
-        sound, control = correct.after_window(ctx, correct.program_side(ctx), "fp8")
+        ctx = harness.Ctx(config=config, seed=seed, **family.make_inputs(config, mix, seed, rehearsal=True))
+        sound, control = family.after_window(ctx, family.program_side(ctx), "fp8")
         sound = {c["name"]: c["value"] for c in sound}
         assert sound["logit_gap"] <= limit < control["logit_gap"], (seed, sound, control)
         assert sound["eval_flip"] < control["eval_flip"], (seed, sound, control)

@@ -1,7 +1,7 @@
 """Traffic kind ``popeval``: whole populations scored back to back.
 
 Unit of work: one ``GeneticCnnModel.cross_validate_population`` call on the
-cell's pool of genomes (``run.make_pool``), taken in a new order each call.
+cell's pool of genomes (``families/genetic_cnn/family.py::make_pool``), taken in a new order each call.
 The order comes from ``--seed``; the pool comes from the mix, so every seed
 trains the same architectures, in other slots, from other weights.
 """
