@@ -27,9 +27,10 @@ from .genes import (
     IntGene,
     boosting_genome,
     genetic_cnn_genome,
+    lfm2_moe_genome,
     xgboost_genome,
 )
-from .individuals import BoostingIndividual, GeneticCnnIndividual, Individual, XgboostIndividual
+from .individuals import BoostingIndividual, GeneticCnnIndividual, Individual, Lfm2MoeIndividual, XgboostIndividual
 from .populations import GridPopulation, Population
 from .algorithms import GeneticAlgorithm, RussianRouletteGA
 from .algorithms_async import AsyncEvolution
@@ -46,10 +47,12 @@ __all__ = [
     "genetic_cnn_genome",
     "boosting_genome",
     "xgboost_genome",
+    "lfm2_moe_genome",
     "Individual",
     "GeneticCnnIndividual",
     "BoostingIndividual",
     "XgboostIndividual",
+    "Lfm2MoeIndividual",
     "Population",
     "GridPopulation",
     "GeneticAlgorithm",
@@ -68,6 +71,13 @@ try:  # pragma: no cover - exercised implicitly
     from .models.cnn import GeneticCnnModel  # noqa: F401
 
     __all__.append("GeneticCnnModel")
+except ImportError:  # pragma: no cover
+    pass
+
+try:  # pragma: no cover
+    from .models.lfm2_moe import Lfm2MoeModel  # noqa: F401
+
+    __all__.append("Lfm2MoeModel")
 except ImportError:  # pragma: no cover
     pass
 

@@ -35,6 +35,7 @@ __all__ = [
     "genetic_cnn_genome",
     "boosting_genome",
     "xgboost_genome",
+    "lfm2_moe_genome",
 ]
 
 
@@ -365,5 +366,28 @@ def xgboost_genome() -> GenomeSpec:
             FloatGene("lambda", 1.0, 0.0, 10.0),
             FloatGene("alpha", 0.0, 0.0, 10.0),
             FloatGene("scale_pos_weight", 1.0, 0.0, 10.0),
+        ]
+    )
+
+
+def lfm2_moe_genome() -> GenomeSpec:
+    """The training-recipe genome of the LFM2-MoE family (``models/lfm2_moe.py``).
+
+    Every individual is the same published architecture; what evolves is how
+    it is trained: the peak learning rate (as its base-10 logarithm), the share
+    of the steps spent in linear warm-up, AdamW's decoupled weight decay and
+    beta2, and the step of the router bias's load-balancing rule.  Genes are
+    data to one compiled train program, never structure.  The defaults are a
+    recipe that holds over a few steps at the published width: 10^-3.5 with a
+    quarter of the steps in warm-up (10^-3 without warm-up sends the loss from
+    9.4 to 20 at the third step; PERF.md, PR 28).
+    """
+    return GenomeSpec(
+        [
+            FloatGene("log10_lr", -3.5, -4.0, -2.5),
+            FloatGene("warmup_frac", 0.25, 0.0, 0.5),
+            FloatGene("weight_decay", 0.1, 0.0, 0.2),
+            FloatGene("beta2", 0.95, 0.9, 0.999),
+            FloatGene("bias_step", 0.001, 0.0, 0.01),
         ]
     )

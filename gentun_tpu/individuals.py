@@ -24,9 +24,9 @@ from typing import Any, Dict, Mapping, Optional, Type
 
 import numpy as np
 
-from .genes import GenomeSpec, boosting_genome, genetic_cnn_genome, xgboost_genome
+from .genes import GenomeSpec, boosting_genome, genetic_cnn_genome, lfm2_moe_genome, xgboost_genome
 
-__all__ = ["Individual", "GeneticCnnIndividual", "BoostingIndividual", "XgboostIndividual"]
+__all__ = ["Individual", "GeneticCnnIndividual", "BoostingIndividual", "XgboostIndividual", "Lfm2MoeIndividual"]
 
 
 def _freeze(obj: Any) -> Any:
@@ -320,3 +320,41 @@ class XgboostIndividual(BoostingIndividual):
 
     def build_spec(self, **params) -> GenomeSpec:
         return xgboost_genome()
+
+
+class _LazyLfm2MoeModel:
+    """``Lfm2MoeIndividual.model_cls``: resolved on first use, so that jax stays
+    off the GA's import path; a subclass or a test may still assign a class."""
+
+    def __get__(self, obj, owner):
+        from .models.lfm2_moe import Lfm2MoeModel
+
+        return Lfm2MoeModel
+
+
+class Lfm2MoeIndividual(Individual):
+    """Training-recipe search for the LFM2-MoE share (``models/lfm2_moe.py``).
+
+    Genome: :func:`gentun_tpu.genes.lfm2_moe_genome` (learning rate, warm-up,
+    weight decay, beta2, router-bias step).  Fitness: minus the mean
+    validation loss after ``train_steps`` steps, so higher is better and
+    ``maximize``, the fitness cache and the distributed path need nothing new.
+    ``additional_parameters`` are ``Lfm2MoeConfig``'s fields plus ``seed``;
+    ``x_train`` holds token sequences, ``y_train`` the same shifted by one.
+    """
+
+    model_cls = _LazyLfm2MoeModel()
+
+    uses_jax = True
+
+    def build_spec(self, **params) -> GenomeSpec:
+        return lfm2_moe_genome()
+
+    def evaluate(self) -> float:
+        if self.x_train is None or self.y_train is None:
+            raise RuntimeError(
+                "this individual has no training data; in distributed mode "
+                "fitness must be assigned via set_fitness() from a worker reply"
+            )
+        model = self.model_cls(self.x_train, self.y_train, self.genes, **self.additional_parameters)
+        return model.cross_validate()

@@ -56,6 +56,13 @@ except ImportError:  # pragma: no cover
     pass
 
 try:
+    from .lfm2_moe import Lfm2MoeModel  # noqa: F401
+
+    __all__ += ["Lfm2MoeModel"]
+except ImportError:  # pragma: no cover
+    pass
+
+try:
     from .boosting import BoostingModel  # noqa: F401
 
     __all__ += ["BoostingModel"]

@@ -1,0 +1,582 @@
+"""LFM2-MoE fitness model: one expert-parallel rank's share of a routed language model.
+
+The second jax family beside the Genetic-CNN (``models/cnn.py``).  The
+architecture is ``LFM2-24B-A2B`` (``model_type`` ``lfm2_moe``,
+https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json): gated
+short convolutions and grouped-query attention as operators, a dense SwiGLU
+feed-forward in the leading layers and 64 routed experts, 4 per token, in the
+others.  Layer ``l``, input ``x`` of shape (tokens, hidden)::
+
+    h = x + Op_l(RMSNorm(x));   y = h + FFN_l(RMSNorm(h))
+    Op = conv:  B, C, u = split3(W_in x);  W_out (C * causal_depthwise_conv1d_L(B * u))
+    Op = full_attention:  q, k, v = W_q x, W_k x, W_v x; RMSNorm over the head size on q and k;
+         rope on q, k; causal softmax(q k' / sqrt(head)) v, each key-value head serving
+         heads / kv_heads query heads; W_o
+    FFN dense:   W_2 (silu(W_1 x) * W_3 x)
+    FFN routed:  s = sigmoid(W_r x); choose top-k of (s + b); w = s[chosen] / (sum s[chosen] + 1e-6);
+                 out = sum over chosen AND held experts e of  w_e W2_e (silu(W1_e x) * W3_e x)
+    output: RMSNorm, logits over the held rows of the tied embedding, mean next-token cross-entropy
+
+What differs from the CNN family, by design:
+
+- **Genes are data, not structure.**  Every individual is the same
+  architecture; the genome is the training recipe (``genes.lfm2_moe_genome``:
+  learning rate, warm-up, weight decay, Adam's beta2, the router bias's step).
+  One compiled train step and one compiled eval program serve every genome;
+  genes and the step number enter as scalar arguments.
+- **The expert layer is told which experts it holds** (``held_experts``, a
+  range; the router keeps ``num_experts`` outputs).  It routes over all of
+  them, keeps the (row, expert, weight) triples of its own experts in a static
+  buffer sized for the worst case, runs the three grouped products with a
+  kernel whose cost follows the rows present (megablox ``gmm`` on a TPU,
+  ``lax.ragged_dot`` elsewhere), and returns its experts' part of the sum.
+  What the absent experts would add is left out; no code stands in for the
+  other chips.  No assignment is dropped (``dropped`` is counted).
+- **A program is one individual wide.**  One individual of the published cut
+  is ~0.65 B parameters and 16 bytes of training state a parameter
+  (:func:`training_bytes`); two do not fit a 16 GB chip, so a population is
+  scored one after another on the one compiled program, the train state is
+  donated from step to step and freed between individuals.  That is known from
+  a count of bytes before anything compiles, not from a failed attempt.
+- **Fitness is a negative validation loss** (higher is better, like an
+  accuracy), a pure function of genome, configuration and seed: the state is
+  built from the genome's content hash (``cnn._genome_hashes``), the batches
+  from the seed.
+
+Parameters are float32, compute is bfloat16 (router, norms, softmax, logits
+and loss float32).  The router bias ``b`` is not trained by the gradient:
+after each step ``b_e += u * sign(mean load - load_e)`` over all experts
+(arXiv:2408.15664; ``u`` is the ``bias_step`` gene).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict, Mapping, NamedTuple, Optional, Sequence, Tuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from ..telemetry import spans as _tele
+from ..telemetry.registry import get_registry as _get_registry
+from ..utils.jax_state import mark_backend_used
+from ..utils.xla_cache import default_cache_dir, enable_compilation_cache
+from .cnn import _base_keys, _genome_hashes, _phase
+from .generic import GentunModel
+
+__all__ = ["Lfm2MoeModel", "Lfm2MoeConfig", "Lfm2MoePrograms", "GENE_NAMES", "training_bytes"]
+
+#: The recipe's genes in the order the compiled programs take them (one float32 vector).
+GENE_NAMES = ("log10_lr", "warmup_frac", "weight_decay", "beta2", "bias_step")
+ADAM_BETA1, ADAM_EPS, INIT_STD, ROUTE_EPS = 0.9, 1e-8, 0.02, 1e-6
+#: megablox tiles (rows, contraction, columns); the row tile shrinks to divide a small buffer.
+_GMM_TILING = (512, 512, 512)
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """Everything the compiled programs are specialised on (hashable: the
+    lru-cache key of :func:`_programs`).  Defaults are the published widths and
+    the one-chip cut of ``benchmark/configs/lfm2_24b_a2b_ep8.json``."""
+
+    hidden_size: int = 2048
+    layer_types: Tuple[str, ...] = ("conv", "full_attention", "conv", "conv", "conv", "full_attention", "conv")
+    layer_ids: Tuple[int, ...] = (0, 2, 3, 4, 5, 6, 7)  # the published indices, for scope names
+    num_dense_layers: int = 1
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    held_experts: Tuple[int, int] = (0, 8)  # [first, last) of the router's outputs
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    vocab_size: int = 8192  # the rows of the vocabulary held here
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    seq_len: int = 4096
+    batch_sequences: int = 4
+    train_steps: int = 8
+    eval_sequences: int = 8
+    n_sequences: int = 40
+    attn_block: int = 512
+    compute_dtype: str = "bfloat16"
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def n_held(self) -> int:
+        return self.held_experts[1] - self.held_experts[0]
+
+    @property
+    def moe_layers(self) -> Tuple[int, ...]:
+        return tuple(range(self.num_dense_layers, len(self.layer_types)))
+
+    @property
+    def tokens_per_step(self) -> int:
+        return self.batch_sequences * self.seq_len
+
+
+# -- the count of bytes that sets the program's width ---------------------------------------
+
+
+def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
+    """The parameter tree as shapes: what ``init`` fills and the reference mirrors."""
+    h, hd = cfg.hidden_size, cfg.head_dim
+    layers = []
+    for i, kind in enumerate(cfg.layer_types):
+        layer: Dict[str, Any] = {"op_norm": (h,), "ffn_norm": (h,)}
+        if kind == "conv":
+            layer["conv"] = {"in_proj": (h, 3 * h), "kernel": (h, cfg.conv_L_cache), "out_proj": (h, h)}
+        else:
+            layer["attn"] = {"q": (h, cfg.num_attention_heads * hd), "k": (h, cfg.num_key_value_heads * hd),
+                             "v": (h, cfg.num_key_value_heads * hd), "o": (cfg.num_attention_heads * hd, h),
+                             "q_norm": (hd,), "k_norm": (hd,)}
+        if i < cfg.num_dense_layers:
+            f = cfg.intermediate_size
+            layer["dense"] = {"w1": (h, f), "w3": (h, f), "w2": (f, h)}
+        else:
+            e, f = cfg.n_held, cfg.moe_intermediate_size
+            layer["moe"] = {"router": (h, cfg.num_experts), "w1": (e, h, f), "w3": (e, h, f), "w2": (e, f, h)}
+        layers.append(layer)
+    return {"embed": (cfg.vocab_size, h), "final_norm": (h,), "layers": layers}
+
+
+def _is_shape(x) -> bool:
+    return isinstance(x, tuple)
+
+
+def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
+    """Device bytes one individual's training takes, by arithmetic.
+
+    ``state``: float32 weights, gradients and AdamW's two moments, 16 bytes a
+    parameter.  ``activations``: what a step keeps beside them under per-layer
+    rematerialisation -- every layer's input, the widest layer's interior
+    (dense feed-forward or the expert rows' buffer), the float32 logits and
+    their gradient.  An estimate to decide a width by, not a measurement.
+    """
+    n_params = sum(math.prod(s) for s in jax.tree_util.tree_leaves(param_shapes(cfg), is_leaf=_is_shape))
+    t, h = cfg.tokens_per_step, cfg.hidden_size
+    interior = max(6 * t * cfg.intermediate_size if cfg.num_dense_layers else 0,
+                   cfg.num_experts_per_tok * t * (4 * h + 6 * cfg.moe_intermediate_size)) * 2
+    activations = 2 * t * h * (len(cfg.layer_types) + 1) + interior + 2 * 4 * t * cfg.vocab_size
+    return {"params": n_params, "state": 16 * n_params, "activations": activations,
+            "total": 16 * n_params + activations}
+
+
+#: A program of this family is one individual wide, always: the published cut
+#: takes 10.4 of a chip's 16 GB in state alone, and a second width would be a
+#: second compiled train program, which the family promises not to have.
+PROGRAM_WIDTH = 1
+
+
+def _require_fit(cfg: Lfm2MoeConfig) -> None:
+    """Refuse, before anything compiles, a configuration whose one individual
+    cannot fit the device (where the backend says how much memory it has)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit, need = stats.get("bytes_limit"), training_bytes(cfg)
+    if limit and need["total"] > limit:
+        raise ValueError(
+            f"one individual needs ~{need['total'] / 1e9:.1f} GB ({need['params'] / 1e6:.0f} M parameters x 16 B "
+            f"+ {need['activations'] / 1e9:.1f} GB of activations); the device has {limit / 1e9:.1f} GB: cut the "
+            f"depth, the experts held or the tokens a step")
+
+
+# -- the layers ---------------------------------------------------------------------------------
+
+
+def _use_megablox() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _grouped_matmul(rows, weights, group_sizes):
+    """``rows`` (R, K) sorted by group against ``weights`` (G, K, N): group g's
+    rows times ``weights[g]``.  Cost follows ``sum(group_sizes)``; the rows past
+    it are undefined and the caller masks them."""
+    if _use_megablox():
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        tiling = (math.gcd(rows.shape[0], _GMM_TILING[0]),) + _GMM_TILING[1:]
+        return gmm(rows, weights, group_sizes, preferred_element_type=rows.dtype, tiling=tiling)
+    return jax.lax.ragged_dot(rows, weights, group_sizes)
+
+
+def _rms_norm(x, weight, eps):
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps) * weight
+
+
+def _dot(x, w, dtype):
+    return jnp.dot(x.astype(dtype), w.astype(dtype))
+
+
+def _conv_op(p, x, cfg: Lfm2MoeConfig, dtype):
+    """Gated short convolution on (sequences, length, hidden)."""
+    gate_b, gate_c, u = jnp.split(_dot(x, p["in_proj"], dtype), 3, axis=-1)
+    z = gate_b * u
+    taps = cfg.conv_L_cache
+    padded = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    kernel = p["kernel"].astype(dtype)
+    y = sum(kernel[:, j] * padded[:, j:j + z.shape[1]] for j in range(taps))
+    return _dot(gate_c * y, p["out_proj"], dtype)
+
+
+def _rope(x, theta):
+    """Rotary embedding, rotate-half layout, on (sequences, length, heads, head size); float32."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _attention(p, x, cfg: Lfm2MoeConfig, dtype):
+    """Causal GQA in query blocks: no (length x length) score array per head is alive."""
+    s, length, _ = x.shape
+    nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q = _dot(x, p["q"], dtype).reshape(s, length, nh, hd)
+    k = _dot(x, p["k"], dtype).reshape(s, length, nkv, hd)
+    v = _dot(x, p["v"], dtype).reshape(s, length, nkv, hd)
+    q = _rope(_rms_norm(q, p["q_norm"], cfg.norm_eps), cfg.rope_theta).astype(dtype)
+    k = _rope(_rms_norm(k, p["k_norm"], cfg.norm_eps), cfg.rope_theta).astype(dtype)
+    q = q.reshape(s, length, nkv, nh // nkv, hd)
+    block = min(cfg.attn_block, length)
+    if length % block:
+        raise ValueError(f"seq_len {length} is not a multiple of attn_block {block}")
+
+    @jax.checkpoint
+    def one_block(qb, kb, vb, first):
+        scores = jnp.einsum("sqngd,sknd->sngqk", qb, kb, preferred_element_type=jnp.float32) / math.sqrt(hd)
+        seen = (first + jnp.arange(qb.shape[1]))[:, None] >= jnp.arange(kb.shape[1])[None, :]
+        prob = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1).astype(dtype)
+        return jnp.einsum("sngqk,sknd->sqngd", prob, vb)
+
+    out = [one_block(q[:, i:i + block], k[:, :i + block], v[:, :i + block], i) for i in range(0, length, block)]
+    return _dot(jnp.concatenate(out, axis=1).reshape(s, length, nh * hd), p["o"], dtype)
+
+
+def _dense_ffn(p, x, dtype):
+    return _dot(jax.nn.silu(_dot(x, p["w1"], dtype)) * _dot(x, p["w3"], dtype), p["w2"], dtype)
+
+
+def _route(router, bias, x, cfg: Lfm2MoeConfig):
+    """Scores over ALL experts, the chosen top-k (by score + bias) and their
+    normalised weights (of the scores alone); float32."""
+    logits = jnp.dot(x.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(scores + bias, cfg.num_experts_per_tok)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, picked / (picked.sum(-1, keepdims=True) + ROUTE_EPS)
+
+
+def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = None):
+    """The held experts' part of the routed feed-forward on (tokens, hidden).
+
+    Returns ``(out, load, dropped)``: ``load`` counts the tokens each of ALL
+    experts was chosen for (the bias rule needs them all), ``dropped`` the
+    assignments to held experts that found no room in the row buffer.  The
+    buffer holds top-k x tokens rows, the worst case, so nothing is dropped;
+    ``row_buffer`` is there for the test of that arithmetic and no caller sets it."""
+    t, h = x.shape
+    k, n_held = cfg.num_experts_per_tok, cfg.n_held
+    cap = k * t if row_buffer is None else row_buffer
+    with jax.named_scope("router"):
+        chosen, weight = _route(p["router"], bias, x, cfg)
+        load = jnp.sum(chosen[..., None] == jnp.arange(cfg.num_experts), axis=(0, 1), dtype=jnp.int32)
+    with jax.named_scope("dispatch"):
+        local = chosen.reshape(-1) - cfg.held_experts[0]  # assignment a = token a // k, choice a % k
+        held = (local >= 0) & (local < n_held)
+        key = jnp.where(held, local, n_held)
+        order = jnp.argsort(key, stable=True)  # held assignments first, grouped by expert
+        sizes = jnp.sum(key[:, None] == jnp.arange(n_held), axis=0, dtype=jnp.int32)
+        ends = jnp.minimum(jnp.cumsum(sizes), cap)
+        sizes_kept = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        n_rows = ends[-1]
+        dropped = sizes.sum() - n_rows
+        filled = (jnp.arange(cap) < n_rows)[:, None]
+        rows = jnp.where(filled, jnp.take(x, order[:cap] // k, axis=0), 0).astype(dtype)
+    with jax.named_scope("experts"):
+        up = jax.nn.silu(_grouped_matmul(rows, p["w1"].astype(dtype), sizes_kept)) \
+            * _grouped_matmul(rows, p["w3"].astype(dtype), sizes_kept)
+        down = _grouped_matmul(jnp.where(filled, up, 0), p["w2"].astype(dtype), sizes_kept)
+        down = jnp.where(filled, down, 0)
+    with jax.named_scope("combine"):
+        place = jnp.zeros(t * k, jnp.int32).at[order].set(jnp.arange(t * k, dtype=jnp.int32))  # assignment -> row
+        back = jnp.where((place < n_rows)[:, None], jnp.take(down, jnp.minimum(place, cap - 1), axis=0), 0)
+        out = jnp.einsum("tk,tkh->th", weight, back.reshape(t, k, h), preferred_element_type=jnp.float32)
+    return out.astype(dtype), load, dropped
+
+
+def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x):
+    """One layer on (sequences, length, hidden); ``bias`` is the layer's router bias or None."""
+    kind, name = cfg.layer_types[index], f"layer{cfg.layer_ids[index]}"
+    with jax.named_scope(name):
+        normed = _rms_norm(x, p["op_norm"], cfg.norm_eps).astype(dtype)
+        if kind == "conv":
+            with jax.named_scope("conv_op"):
+                h = x + _conv_op(p["conv"], normed, cfg, dtype)
+        else:
+            with jax.named_scope("attention"):
+                h = x + _attention(p["attn"], normed, cfg, dtype)
+        normed = _rms_norm(h, p["ffn_norm"], cfg.norm_eps).astype(dtype)
+        if "dense" in p:
+            with jax.named_scope("dense_ffn"):
+                return h + _dense_ffn(p["dense"], normed, dtype), None
+        with jax.named_scope("moe"):
+            out, load, dropped = _moe_ffn(p["moe"], bias, normed.reshape(-1, normed.shape[-1]), cfg, dtype)
+        return h + out.reshape(h.shape), (load, dropped)
+
+
+def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
+    """Float32 logits (sequences, length, held vocabulary), the load of ALL
+    experts per routed layer (layers, experts) and the dropped assignments."""
+    dtype = jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(dtype)
+    loads, dropped = [], jnp.zeros((), jnp.int32)
+    for i, p in enumerate(params["layers"]):
+        fn = functools.partial(_layer, cfg, i, dtype)
+        moe = i >= cfg.num_dense_layers
+        x, aux = (jax.checkpoint(fn) if remat else fn)(p, bias[i - cfg.num_dense_layers] if moe else None, x)
+        if moe:
+            loads.append(aux[0])
+            dropped = dropped + aux[1]
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
+        logits = jnp.einsum("slh,vh->slv", x, params["embed"].astype(dtype), preferred_element_type=jnp.float32)
+    return logits, jnp.stack(loads), dropped
+
+
+def token_loss(logits, targets):
+    """Next-token cross-entropy per position, float32."""
+    with jax.named_scope("loss"):
+        return jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+
+
+# -- the compiled programs -----------------------------------------------------------------------
+
+
+class Lfm2MoePrograms(NamedTuple):
+    """The compiled callables of one configuration (:meth:`Lfm2MoeModel.compiled_programs`).
+
+    ``init(base_key, genome_hash) -> state``: a fresh train state, a dict of
+    ``params``/``m``/``v`` (one tree each, :func:`param_shapes`), ``bias``
+    (routed layers, experts), ``rows`` (routed layers, held experts: rows
+    routed so far) and ``dropped``.  ``train_step(state, x, y, batch_rows,
+    genes, step) -> (state, loss, load)``: ``state`` is donated; ``x``/``y``
+    are the whole token arrays, ``batch_rows`` (train_steps, batch_sequences)
+    the sequences of every step, ``genes`` the float32 vector in
+    ``GENE_NAMES`` order, ``step`` the step number; ``load`` is this step's
+    rows per held expert per routed layer.  ``eval(params, bias, x, y, rows)
+    -> loss per token`` of the sequences ``rows``."""
+
+    config: Lfm2MoeConfig
+    init: Any
+    train_step: Any
+    eval: Any
+
+
+@functools.lru_cache(maxsize=8)
+def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
+    shapes = param_shapes(cfg)
+    lo, hi = cfg.held_experts
+    n_moe = len(cfg.moe_layers)
+
+    def init(base_key, genome_hash):
+        key = jax.random.fold_in(jax.random.fold_in(base_key, genome_hash[0]), genome_hash[1])
+        leaves, tree = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=_is_shape)
+        params = [jnp.ones(shape, jnp.float32) if "norm" in str(path[-1])
+                  else INIT_STD * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+                  for i, (path, shape) in enumerate(leaves)]
+        params = jax.tree_util.tree_unflatten(tree, params)
+        zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)
+        return {"params": params, "m": zeros(), "v": zeros(),
+                "bias": jnp.zeros((n_moe, cfg.num_experts), jnp.float32),
+                "rows": jnp.zeros((n_moe, cfg.n_held), jnp.int32), "dropped": jnp.zeros((), jnp.int32)}
+
+    def loss_fn(params, bias, x, y):
+        logits, load, dropped = forward(cfg, params, bias, x, remat=True)
+        return token_loss(logits, y).mean(), (load, dropped)
+
+    def train_step(state, x_all, y_all, batch_rows, genes, step):
+        rows = batch_rows[step]
+        (loss, (load, dropped)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state["params"], state["bias"], x_all[rows], y_all[rows])
+        log10_lr, warmup_frac, weight_decay, beta2, bias_step = (genes[i] for i in range(len(GENE_NAMES)))
+        with jax.named_scope("optimizer"):
+            t = (step + 1).astype(jnp.float32)
+            lr = 10.0 ** log10_lr * jnp.minimum(1.0, t / jnp.maximum(warmup_frac * cfg.train_steps, 1.0))
+            c1, c2 = 1.0 - ADAM_BETA1 ** t, 1.0 - beta2 ** t
+
+            def adamw(path, p, m, v, g):
+                m = ADAM_BETA1 * m + (1.0 - ADAM_BETA1) * g
+                v = beta2 * v + (1.0 - beta2) * g * g
+                decay = 0.0 if "norm" in str(path[-1]) else weight_decay
+                return p - lr * ((m / c1) / (jnp.sqrt(v / c2) + ADAM_EPS) + decay * p), m, v
+
+            updated = jax.tree_util.tree_map_with_path(adamw, state["params"], state["m"], state["v"], grads)
+            params, m, v = jax.tree_util.tree_transpose(
+                jax.tree_util.tree_structure(grads), jax.tree_util.tree_structure((0, 0, 0)), updated)
+        with jax.named_scope("bias_update"):
+            mean_load = cfg.tokens_per_step * cfg.num_experts_per_tok / cfg.num_experts
+            bias = state["bias"] + bias_step * jnp.sign(mean_load - load.astype(jnp.float32))
+        held = load[:, lo:hi]
+        new = {"params": params, "m": m, "v": v, "bias": bias,
+               "rows": state["rows"] + held, "dropped": state["dropped"] + dropped}
+        return new, loss, held
+
+    def lm_eval(params, bias, x_all, y_all, rows):
+        logits, _, _ = forward(cfg, params, bias, x_all[rows])
+        return token_loss(logits, y_all[rows])
+
+    train_step.__name__, init.__name__ = "lm_train_step", "lm_init"
+    return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval))
+
+
+# -- configuration, data ------------------------------------------------------------------------------
+
+
+def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig, int, Any]:
+    """(the programs' static configuration, seed, cache_dir) from the keyword soup."""
+    config = dict(config)
+    seed, cache_dir = int(config.pop("seed", 0) or 0), config.pop("cache_dir", None)
+    x = np.asarray(x_train)
+    if x.ndim != 2 or not np.issubdtype(x.dtype, np.integer):
+        raise ValueError(f"x_train must be integer tokens (sequences, length); got {x.dtype} {x.shape}")
+    for key in ("layer_types", "layer_ids", "held_experts"):
+        if key in config:
+            config[key] = tuple(config[key])
+    config.setdefault("layer_ids", tuple(range(len(config.get("layer_types", Lfm2MoeConfig.layer_types)))))
+    cfg = Lfm2MoeConfig(**{**config, "seq_len": x.shape[1], "n_sequences": x.shape[0]})
+    if len(cfg.layer_ids) != len(cfg.layer_types) or set(cfg.layer_types) - {"conv", "full_attention"}:
+        raise ValueError(f"layer_types {cfg.layer_types} / layer_ids {cfg.layer_ids}")
+    if not 0 <= cfg.num_dense_layers < len(cfg.layer_types):
+        raise ValueError("num_dense_layers must leave at least one routed layer")
+    if not 0 <= cfg.held_experts[0] < cfg.held_experts[1] <= cfg.num_experts:
+        raise ValueError(f"held_experts {cfg.held_experts} is no range of the {cfg.num_experts} experts")
+    if cfg.n_sequences < cfg.eval_sequences + cfg.batch_sequences or cfg.eval_sequences % cfg.batch_sequences:
+        raise ValueError(f"{cfg.n_sequences} sequences cannot give {cfg.eval_sequences} held-out ones "
+                         f"(whole batches of {cfg.batch_sequences}) and a train batch")
+    if int(x.max(initial=0)) >= cfg.vocab_size or int(x.min(initial=0)) < 0:
+        raise ValueError(f"token ids must lie in the held slice [0, {cfg.vocab_size})")
+    return cfg, seed, cache_dir
+
+
+def batch_plan(cfg: Lfm2MoeConfig, seed: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(train rows (train_steps, batch_sequences), held-out rows (batches,
+    batch_sequences)): the last ``eval_sequences`` are held out, the others are
+    taken in an order drawn from ``seed``, again from the start if they run out."""
+    n_train = cfg.n_sequences - cfg.eval_sequences
+    order = np.random.default_rng([seed, 0xBA7C]).permutation(n_train)
+    need = cfg.train_steps * cfg.batch_sequences
+    train = np.resize(order, need).reshape(cfg.train_steps, cfg.batch_sequences)
+    held_out = np.arange(n_train, cfg.n_sequences).reshape(-1, cfg.batch_sequences)
+    return train.astype(np.int32), held_out.astype(np.int32)
+
+
+def gene_vector(genome: Mapping[str, Any]) -> np.ndarray:
+    return np.asarray([float(genome[name]) for name in GENE_NAMES], np.float32)
+
+
+class Lfm2MoeModel(GentunModel):
+    """Train the LFM2-MoE share under a recipe genome; fitness = -mean validation loss.
+
+    ``x_train`` holds token sequences (sequences, length), ``y_train`` the same
+    shifted by one.  Keyword arguments are :class:`Lfm2MoeConfig`'s fields
+    (``seq_len`` and ``n_sequences`` come from the data) plus ``seed`` and
+    ``cache_dir`` (as in ``GeneticCnnModel``).
+    """
+
+    def __init__(self, x_train, y_train, genes: Mapping[str, Any], **config):
+        super().__init__(x_train, y_train, genes)
+        self.config = dict(config)
+
+    def cross_validate(self) -> float:
+        return float(self.cross_validate_population(self.x_train, self.y_train, [self.genes], **self.config)[0])
+
+    @classmethod
+    def compiled_programs(cls, x_train, **config) -> Lfm2MoePrograms:
+        """The compiled init, train-step and eval callables of this
+        configuration: the same lru-cached objects ``cross_validate_population``
+        drives, so a caller that warms or checks them warms or checks the
+        evaluator's own executables."""
+        return _programs(_normalize_config(x_train, config)[0])
+
+    @classmethod
+    def cross_validate_population(cls, x_train, y_train, genomes: Sequence[Mapping[str, Any]], **config) -> np.ndarray:
+        """Fitness of each genome, one after another on one compiled program.
+
+        With telemetry on, one ``cv_call`` span whose children are, per
+        individual, ``init_params``, ``train`` (all its steps, fenced once),
+        ``eval`` and ``fetch`` (docs/OBSERVABILITY.md); off, the only wait is
+        the fetch of each individual's losses, which is also what frees its
+        state before the next one's is built."""
+        with _phase("cv_call", {"n_real": len(genomes), "pop": PROGRAM_WIDTH}):
+            with _phase("prepare"):
+                cfg, seed, cache_dir = _normalize_config(x_train, config)
+                if len(genomes) == 0:
+                    return np.zeros((0,), np.float32)
+                if cache_dir is None:
+                    cache_dir = default_cache_dir()
+                if cache_dir and str(cache_dir).strip().lower() not in ("0", "off", "none", "disabled"):
+                    enable_compilation_cache(cache_dir)
+                mark_backend_used()
+                _require_fit(cfg)
+                programs = _programs(cfg)
+                hashes = _genome_hashes(genomes)
+                init_base, _ = _base_keys(seed)
+                train_rows, val_rows = batch_plan(cfg, seed)
+                x, y = jnp.asarray(x_train, jnp.int32), jnp.asarray(y_train, jnp.int32)
+                train_rows, val_rows = jnp.asarray(train_rows), [jnp.asarray(r) for r in val_rows]
+                steps = [np.int32(s) for s in range(cfg.train_steps)]
+            fitness = np.empty(len(genomes), np.float32)
+            for i, genome in enumerate(genomes):
+                fitness[i] = -_score_one(programs, init_base, hashes[i], gene_vector(genome), x, y,
+                                         train_rows, val_rows, steps, i)
+            return fitness
+
+
+def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, train_rows, val_rows, steps,
+               individual: int) -> float:
+    """Mean validation loss of one individual: fresh state, every train step,
+    the held-out batches, one fetch."""
+    cfg = programs.config
+    shape = (cfg.tokens_per_step, cfg.train_steps)
+    with _phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
+        state = sp.fence(programs.init(init_base, genome_hash))
+        genes = jnp.asarray(genes)
+    with _phase("train", {"steps": cfg.train_steps, "tokens": cfg.train_steps * cfg.tokens_per_step,
+                          "pop": PROGRAM_WIDTH, "individual": individual},
+                program=(id(programs.train_step), shape)) as sp:
+        for step in steps:
+            state, _, _ = programs.train_step(state, x, y, train_rows, genes, step)
+        sp.fence(state)
+    with _phase("eval", {"individual": individual, "tokens": len(val_rows) * cfg.tokens_per_step},
+                program=(id(programs.eval), shape)) as sp:
+        losses = sp.fence([programs.eval(state["params"], state["bias"], x, y, rows) for rows in val_rows])
+    with _phase("fetch", {"individual": individual}) as sp:
+        if _tele.enabled():
+            losses, rows, dropped = jax.device_get((losses, state["rows"], state["dropped"]))
+            _count_expert_rows(cfg, rows, int(dropped))
+            sp.set(expert_rows=rows.tolist(), dropped=int(dropped))
+        else:
+            losses = jax.device_get(losses)
+        del state  # the fetch has waited for the device: these 12 bytes a parameter are free again
+    return float(np.mean(np.concatenate([np.ravel(l) for l in losses]), dtype=np.float64))
+
+
+def _count_expert_rows(cfg: Lfm2MoeConfig, rows: np.ndarray, dropped: int) -> None:
+    """``expert_rows{layer, expert}`` and ``dropped_assignments_total`` from what
+    an individual's steps summed on the device (telemetry on only)."""
+    reg = _get_registry()
+    for layer, per_expert in zip(cfg.moe_layers, rows):
+        for expert, n in zip(range(*cfg.held_experts), per_expert):
+            reg.counter("expert_rows", layer=str(cfg.layer_ids[layer]), expert=str(expert)).inc(int(n))
+    reg.counter("dropped_assignments_total").inc(dropped)

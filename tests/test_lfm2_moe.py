@@ -1,0 +1,432 @@
+"""The LFM2-MoE family (``models/lfm2_moe.py``) against its plain reference
+(``benchmark/families/lfm2_moe/reference.py``), at small sizes on the CPU.
+
+System and reference are compared in float32 on seeded weights: per layer kind
+and whole on logits, loss and gradients; over two train steps on loss,
+parameter change and the router bias; the share test ties the expert layer's
+cut (``held_experts``) to the uncut layer; fitness is a pure function of
+genome, configuration and seed in any position of any call, telemetry on or
+off; and the species runs through ``Population`` and ``GeneticAlgorithm``.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from gentun_tpu import GeneticAlgorithm, Lfm2MoeIndividual, Population, lfm2_moe_genome
+from gentun_tpu.models import lfm2_moe as M
+from gentun_tpu.telemetry import spans
+from gentun_tpu.telemetry.registry import get_registry
+
+FAMILY = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "families", "lfm2_moe")
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(f"lfm2_family_{name}", os.path.join(FAMILY, name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+R = _load("reference")
+flops = _load("flops")
+scope_rules = _load("scope_rules")
+
+MODEL = dict(hidden_size=32, layer_types=["conv", "full_attention", "conv"], num_dense_layers=1, intermediate_size=48,
+             moe_intermediate_size=24, num_experts=8, num_experts_per_tok=2, held_experts=[2, 4],
+             num_attention_heads=4, num_key_value_heads=2, vocab_size=64, conv_L_cache=3, norm_eps=1e-5,
+             rope_parameters={"rope_theta": 1e6}, train_steps=3)
+GENES = dict(log10_lr=-2.5, warmup_frac=0.5, weight_decay=0.1, beta2=0.95, bias_step=0.01)
+HIGHEST = jax.default_matmul_precision("highest")
+
+
+def model_kwargs(m=MODEL, **over):
+    kw = {k: v for k, v in m.items() if k != "rope_parameters"}
+    kw.update(rope_theta=m["rope_parameters"]["rope_theta"], batch_sequences=2, eval_sequences=2, attn_block=8,
+              compute_dtype="float32")
+    kw.update(over)
+    return kw
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    tok = np.random.default_rng(0).integers(0, 64, size=(10, 17)).astype(np.int32)
+    return tok[:, :-1], tok[:, 1:]
+
+
+@pytest.fixture(scope="module")
+def bias():
+    return (0.1 * np.random.default_rng(1).normal(size=(2, 8))).astype(np.float32)
+
+
+def config_of(tokens, m=MODEL, **over) -> M.Lfm2MoeConfig:
+    return M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(m, **over)).config
+
+
+def one_layer(kind: str, ffn: str):
+    """A one-layer model of the given operator and feed-forward (a dense layer
+    cannot stand alone: the program needs a routed one, so it leads one)."""
+    types = [kind] if ffn == "moe" else [kind, "conv"]
+    return {**MODEL, "layer_types": types, "num_dense_layers": 0 if ffn == "moe" else 1, "held_experts": [1, 5]}
+
+
+LAYER_CASES = {"conv_moe": one_layer("conv", "moe"), "attention_moe": one_layer("full_attention", "moe"),
+               "conv_dense": one_layer("conv", "dense"), "attention_dense": one_layer("full_attention", "dense"),
+               "whole_cut": {**MODEL, "held_experts": [1, 5]}}
+
+
+@pytest.mark.parametrize("case", sorted(LAYER_CASES))
+def test_logits_loss_and_gradients_match_the_reference(case, tokens):
+    m = LAYER_CASES[case]
+    cfg = config_of(tokens, m)
+    w = R.seeded_weights(m, 7)
+    n_routed = len(m["layer_types"]) - m["num_dense_layers"]
+    b = jnp.asarray(0.1 * np.random.default_rng(2).normal(size=(n_routed, 8)), jnp.float32)
+    x, y = tokens[0][:2], tokens[1][:2]
+
+    def system_loss(params):
+        logits, load, dropped = M.forward(cfg, params, b, x, remat=True)
+        return M.token_loss(logits, y).mean(), (logits, load, dropped)
+
+    def reference_loss(params):
+        out = [R.forward(m, params, b, xs) for xs in x]
+        logits = jnp.stack([o[0] for o in out])
+        return jnp.mean(jnp.stack([R.token_loss(l, ys) for l, ys in zip(logits, y)])), (logits, sum(o[1] for o in out))
+
+    with HIGHEST:
+        (loss, (logits, load, dropped)), grads = jax.jit(jax.value_and_grad(system_loss, has_aux=True))(w)
+        (ref_loss, (ref_logits, ref_load)), ref_grads = jax.jit(jax.value_and_grad(reference_loss, has_aux=True))(w)
+    np.testing.assert_allclose(logits, ref_logits, atol=2e-5)
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
+    np.testing.assert_array_equal(load, ref_load)
+    assert int(dropped) == 0
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
+        np.testing.assert_allclose(g, r, atol=2e-6, rtol=1e-4, err_msg=jax.tree_util.keystr(path))
+        assert float(jnp.abs(r).max()) > 0, f"{jax.tree_util.keystr(path)}: the reference's gradient is all zero"
+
+
+def _program_steps(programs, weights, bias, x, y, rows, steps):
+    state = programs.init(jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
+    state = {**state, "params": jax.tree_util.tree_map(jnp.asarray, weights), "bias": jnp.asarray(bias)}
+    losses, loads = [], []
+    for s in range(steps):
+        state, loss, held = programs.train_step(state, jnp.asarray(x), jnp.asarray(y), jnp.asarray(rows),
+                                                jnp.asarray(M.gene_vector(GENES)), np.int32(s))
+        losses.append(float(loss))
+        loads.append(np.asarray(held))
+    return state, losses, loads
+
+
+def test_two_train_steps_match_the_reference(tokens, bias):
+    x, y = tokens
+    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs())
+    w = R.seeded_weights(MODEL, 5)
+    rows = np.array([[0, 1], [2, 3], [4, 5]], np.int32)
+    with HIGHEST:
+        state, losses, loads = _program_steps(programs, w, bias, x, y, rows, 2)
+        ref = R.train(MODEL, w, [(x[r], y[r]) for r in rows[:2]], GENES, bias=bias)
+    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)
+    for got, want in zip(loads, ref["loads"]):
+        np.testing.assert_array_equal(got, want[:, 2:4])
+    np.testing.assert_array_equal(np.asarray(state["rows"]), sum(l[:, 2:4] for l in ref["loads"]))
+    np.testing.assert_allclose(state["bias"], ref["bias"], atol=1e-7)
+    assert np.abs(np.asarray(state["bias"]) - bias).max() == pytest.approx(0.02, rel=1e-5)  # two steps of 0.01
+    for (path, a), b, start in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
+                                   jax.tree_util.tree_leaves(ref["weights"]), jax.tree_util.tree_leaves(w)):
+        change, ref_change = np.asarray(a) - start, np.asarray(b) - start
+        assert np.abs(ref_change).max() > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(change, ref_change, atol=3e-5, err_msg=jax.tree_util.keystr(path))
+    with HIGHEST:
+        got = programs.eval(state["params"], state["bias"], jnp.asarray(x), jnp.asarray(y), jnp.asarray([8, 9]))
+        want = R.eval_token_loss(MODEL, ref["weights"], ref["bias"], x[8:10], y[8:10])
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(tokens, bias):
+    """8 experts in 4 shares of 2: each share's program computes operator,
+    residual and its own experts' part; the parts, with what every share
+    computes alike counted once, are the uncut reference's layer output."""
+    m = one_layer("conv", "moe")
+    x = tokens[0][:2]
+    uncut = {**m, "held_experts": [0, 8]}
+    w_all = R.seeded_weights(uncut, 11)
+    b = jnp.asarray(bias[:1])
+    embedded = w_all["embed"][x]
+
+    def layer_out(cfg, weights):
+        out, _ = M._layer(cfg, 0, jnp.float32, weights["layers"][0], b[0], jnp.asarray(embedded))
+        return out
+
+    with HIGHEST:
+        whole = jnp.stack([R.layer(uncut, 0, lambda a: a, w_all["layers"][0], b[0], jnp.asarray(e))[0]
+                           for e in embedded])
+        no_experts = dict(w_all["layers"][0], moe={k: (v if k == "router" else v[:0])
+                                                   for k, v in w_all["layers"][0]["moe"].items()})
+        alike = jnp.stack([R.layer({**uncut, "held_experts": [0, 0]}, 0, lambda a: a, no_experts, b[0],
+                                   jnp.asarray(e))[0] for e in embedded])  # operator and residual, no expert
+        total = alike
+        for first in range(0, 8, 2):
+            cfg = config_of(tokens, {**m, "held_experts": [first, first + 2]})
+            share = {"layers": [dict(w_all["layers"][0], moe={
+                k: (v if k == "router" else v[first:first + 2]) for k, v in w_all["layers"][0]["moe"].items()})]}
+            part = layer_out(cfg, share) - alike
+            assert float(jnp.abs(part).max()) > 0
+            total = total + part
+    np.testing.assert_allclose(total, whole, atol=1e-5)
+
+
+def test_no_assignment_is_dropped_when_every_token_goes_to_one_held_expert(tokens):
+    m = one_layer("conv", "moe")
+    cfg = config_of(tokens, m)
+    w = R.seeded_weights(m, 3)["layers"][0]["moe"]
+    forced = jnp.zeros((1, 8), jnp.float32).at[0, 3].set(10.0)  # expert 3 (held) wins every token's first choice
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(32, 32)), jnp.float32)
+    out, load, dropped = M._moe_ffn(w, forced[0], x, cfg, jnp.float32)
+    assert int(load[3]) == 32 and int(dropped) == 0
+    with HIGHEST:
+        ref, _ = R.routed_ffn(w, forced[0], x, m, lambda a: a)
+    np.testing.assert_allclose(out, ref, atol=1e-5)
+    # a buffer below the worst case drops, and counts it
+    _, load, dropped = M._moe_ffn(w, forced[0], x, cfg, jnp.float32, row_buffer=16)
+    assert int(dropped) == int(load[1:5].sum()) - 16 > 0
+
+
+def _pool(n=3):
+    rng = np.random.default_rng(8)
+    return [lfm2_moe_genome().default()] + [lfm2_moe_genome().sample(rng) for _ in range(n - 1)]
+
+
+def test_fitness_is_a_function_of_the_genome_in_any_position_and_with_telemetry_on_or_off(tokens):
+    x, y = tokens
+    kw = model_kwargs(seed=3)
+    pool = _pool()
+    base = M.Lfm2MoeModel.cross_validate_population(x, y, pool, **kw)
+    assert base.dtype == np.float32 and np.all(base < 0) and len(set(base.tolist())) == len(pool)
+    for order in ([2, 0, 1], [1, 2, 0], [1]):
+        again = M.Lfm2MoeModel.cross_validate_population(x, y, [pool[i] for i in order], **kw)
+        np.testing.assert_array_equal(again, base[order])
+    assert M.Lfm2MoeModel(x, y, pool[1], **kw).cross_validate() == base[1]
+    get_registry().reset()
+    records = []
+
+    class Sink:
+        def record(self, rec):
+            records.append(rec)
+
+    spans.set_run_sink(Sink())
+    spans.enable()
+    try:
+        traced = M.Lfm2MoeModel.cross_validate_population(x, y, pool, **kw)
+    finally:
+        spans.disable()
+        spans.set_run_sink(None)
+    np.testing.assert_array_equal(traced, base)
+    kinds = [r["kind"] for r in records if r["type"] == "span"]
+    assert kinds.count("cv_call") == 1 and kinds.count("fetch") == len(pool)
+    device = [r for r in records if r["type"] == "span" and "individual" in r.get("attrs", {})]
+    assert {r["kind"] for r in device} <= {"compile", "init_params", "train", "eval", "fetch"}
+    fetched = [r["attrs"] for r in records if r["type"] == "span" and r["kind"] == "fetch"]
+    rows = {(c["labels"]["layer"], c["labels"]["expert"]): c["value"]
+            for c in get_registry().snapshot()["counters"] if c["name"] == "expert_rows"}
+    assert set(rows) == {(str(l), str(e)) for l in (1, 2) for e in (2, 3)}
+    assert sum(rows.values()) == sum(sum(map(sum, a["expert_rows"])) for a in fetched) > 0
+    assert get_registry().counter("dropped_assignments_total").value == 0
+    assert M.Lfm2MoeModel.cross_validate_population(x, y, [], **kw).shape == (0,)
+    other_seed = M.Lfm2MoeModel.cross_validate_population(x, y, pool[:1], **{**kw, "seed": 4})
+    assert other_seed[0] != base[0]
+
+
+def test_genome_individual_population_and_two_generations(tokens):
+    x, y = tokens
+    spec = lfm2_moe_genome()
+    assert spec.names == list(M.GENE_NAMES)
+    assert spec.default() == dict(log10_lr=-3.5, warmup_frac=0.25, weight_decay=0.1, beta2=0.95, bias_step=0.001)
+    for gene, (lo, hi) in zip(spec.genes, [(-4, -2.5), (0, 0.5), (0, 0.2), (0.9, 0.999), (0, 0.01)]):
+        assert (gene.minimum, gene.maximum) == (lo, hi)
+    assert Lfm2MoeIndividual.model_cls is M.Lfm2MoeModel and Lfm2MoeIndividual.uses_jax
+    assert Lfm2MoeIndividual.fitness_backend() == "Lfm2MoeModel"
+    calls = []
+
+    class Counting(M.Lfm2MoeModel):
+        @classmethod
+        def cross_validate_population(cls, x_train, y_train, genomes, **config):
+            calls.append(len(genomes))
+            return super().cross_validate_population(x_train, y_train, genomes, **config)
+
+    class Species(Lfm2MoeIndividual):
+        model_cls = Counting
+
+    pop = Population(Species, x, y, size=3, seed=0, additional_parameters=model_kwargs(seed=1))
+    ga = GeneticAlgorithm(pop, seed=0)
+    ga.run(2)
+    assert calls and sum(calls) >= 3, "Population.evaluate must reach cross_validate_population"
+    best = ga.population.get_fittest()
+    assert best.get_fitness() < 0 and best.get_fitness() == max(ga.population.get_fitnesses())
+    single = Lfm2MoeIndividual(x, y, genes=best.get_genes(), additional_parameters=model_kwargs(seed=1))
+    assert single.get_fitness() == pytest.approx(best.get_fitness(), abs=0)
+
+
+def test_the_worker_resolves_the_species():
+    from gentun_tpu.distributed.worker import _species
+
+    assert _species("lfm2-moe") is Lfm2MoeIndividual
+    with pytest.raises(SystemExit, match="lfm2-moe"):
+        _species("no-such-species")
+
+
+@pytest.mark.parametrize("bad,why", [
+    (dict(held_experts=(6, 9)), "held_experts"),
+    (dict(num_dense_layers=3), "routed layer"),
+    (dict(eval_sequences=3), "held-out"),
+    (dict(vocab_size=32), "held slice"),
+    (dict(layer_types=("conv", "mamba", "conv")), "layer_types"),
+])
+def test_a_configuration_that_cannot_run_is_refused_before_anything_compiles(tokens, bad, why):
+    with pytest.raises(ValueError, match=why):
+        M.Lfm2MoeModel.cross_validate_population(tokens[0], tokens[1], _pool(1), **model_kwargs(**bad))
+
+
+def test_the_published_cut_is_one_individual_wide_by_arithmetic():
+    need = M.training_bytes(M.Lfm2MoeConfig())
+    assert 0.64e9 < need["params"] < 0.66e9 and need["state"] == 16 * need["params"]
+    assert 16e9 / 2 < need["total"] < 16e9, "one individual fits a 16 GB chip, two do not"
+    assert M.PROGRAM_WIDTH == 1
+
+
+@pytest.mark.parametrize("op_name,klass", [
+    ("jit(lm_train_step)/jvp(layer2)/moe/experts/pallas_call", "expert_mm"),
+    ("jit(lm_train_step)/transpose(jvp(layer2))/moe/experts/mul", "expert_mm"),
+    ("jit(lm_train_step)/jvp(layer5)/moe/dispatch/jit(argsort)/sort", "moe_route"),
+    ("jit(lm_train_step)/checkpoint/rematted_computation/layer3/moe/router/dot_general", "moe_route"),
+    ("jit(lm_eval)/layer6/attention/checkpoint/sngqk,sknd->sqngd/dot_general", "attention"),
+    ("jit(lm_train_step)/jvp(layer0)/conv_op/dot_general", "short_conv"),
+    ("jit(lm_train_step)/transpose(jvp(layer0))/dense_ffn/dot_general", "dense_ffn"),
+    ("jit(lm_train_step)/jvp(head)/slh,vh->slv/dot_general", "head_loss"),
+    ("jit(lm_train_step)/jvp(embed)/jit(_take)/gather", "head_loss"),
+    ("jit(lm_train_step)/optimizer/sqrt", "optimizer"),
+    ("jit(lm_train_step)/bias_update/sign", "optimizer"),
+    ("jit(lm_train_step)/jvp(layer3)/rsqrt", "rest"),
+    ("jit(lm_init)/jit(_normal)/threefry2x32", "rest"),
+    ("", "unattributed"),
+])
+def test_scope_rules_place_an_op_by_its_scopes(op_name, klass):
+    assert scope_rules.classify(op_name)[0] == klass and klass in scope_rules.CLASSES
+
+
+def test_the_lowered_train_step_carries_every_scope(tokens):
+    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(layer_ids=(0, 2, 3)))
+    state = jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
+    text = programs.train_step.lower(state, tokens[0], tokens[1], np.zeros((3, 2), np.int32), np.zeros(5, np.float32),
+                                     np.int32(0)).as_text(debug_info=True)
+    for scope in ("embed", "layer0", "layer2", "layer3", "conv_op", "attention", "dense_ffn", "moe/router",
+                  "moe/dispatch", "moe/experts", "moe/combine", "head", "loss", "optimizer", "bias_update"):
+        assert scope in text, scope
+
+
+def test_executed_flops_of_the_grouped_products_on_a_recorded_load():
+    """A load recorded from a run (rows per routed layer and held expert, 8
+    steps): the count is 3 products of 2 x hidden x width a row and pass, and
+    against any time the chip could have taken it stays under the peak."""
+    m = dict(hidden_size=2048, moe_intermediate_size=1536, held_experts=[0, 8])
+    recorded = np.array([[1511, 702, 1210, 988, 640, 1333, 871, 1009]] * 6) * 8  # six routed layers, 8 steps
+    rows = float(recorded.sum())
+    assert flops.expert_mm_flops(m, rows, 1) == rows * 3 * 2 * 2048 * 1536
+    assert flops.expert_mm_flops(m, rows, flops.TRAIN_PASSES) == 4 * flops.expert_mm_flops(m, rows, 1)
+    peak, bandwidth = 197e12, 819e9
+    least = max(flops.expert_mm_flops(m, rows, 4) / peak, flops.expert_mm_bytes(m, rows, 4, 48) / bandwidth)
+    assert least == flops.expert_mm_flops(m, rows, 4) / peak  # compute-bound at ~1,000 rows an expert
+    assert 100.0 * least / (1.25 * least) < 100.0
+    per = flops.forward_flops_per_token({**MODEL, "hidden_size": 2048, "intermediate_size": 11776, "num_experts": 64,
+                                         "num_attention_heads": 32, "num_key_value_heads": 8, "vocab_size": 8192,
+                                         "layer_types": ["conv", "full_attention", "conv", "conv", "conv",
+                                                         "full_attention", "conv"]}, 4096, 512)
+    total = per["linear"] + per["attention_core"] + per["head"] + 6 * 4 / 8 * 3 * 2 * 2048 * 1536
+    assert 0.40e9 < total < 0.50e9  # the issue's ~0.48 GFLOP a token forward, attention by causal blocks
+
+
+def test_the_expert_load_reader_reads_the_windows_fetch_spans_and_not_set_ups():
+    """``lm_expert_load_max_over_mean`` is a reading of the window: the warm-up
+    call's individuals (before the window) and spans without ``individual`` stay out."""
+    bench = os.path.dirname(os.path.dirname(FAMILY))
+    names = ("lm_spans", "scope_rules", "scope_reduce", "spanlib", "trace_reduce")
+    before = {n: sys.modules.pop(n, None) for n in names}
+    sys.path[:0] = [FAMILY, bench]
+    try:
+        reader = _load(os.path.join("..", "..", "layer_metrics", "lm_expert_load_max_over_mean"))
+        fetch = lambda t, attrs: {"type": "span", "kind": "fetch", "t_wall": t, "dur_s": 0.001, "attrs": attrs}
+        run = {"window": (10.0, 20.0), "records": [
+            fetch(5.0, {"individual": 0, "expert_rows": [[9000, 1], [1, 1]]}),  # set-up's warm-up call
+            fetch(11.0, {"individual": 0, "expert_rows": [[10, 30], [20, 20]]}),
+            fetch(12.0, {"individual": 1, "expert_rows": [[30, 50], [20, 20]]}),
+            fetch(13.0, {"other": 1, "expert_rows": [[7000, 1], [1, 1]]})]}
+        assert reader.read(run) == pytest.approx(80 * 4 / 200)
+        assert reader.read({"window": (10.0, 20.0), "records": run["records"][:1]}) is None
+    finally:
+        del sys.path[:2]
+        for n in names:
+            sys.modules.pop(n, None)
+            if before[n] is not None:
+                sys.modules[n] = before[n]
+
+
+@pytest.fixture()
+def family():
+    """``benchmark/families/lfm2_moe/family.py``, loaded as ``run.py`` loads it
+    (its directory first on ``sys.path``), and unloaded again."""
+    names = ("family", "correct", "reference")
+    before = {n: sys.modules.pop(n, None) for n in names}
+    sys.path.insert(0, FAMILY)
+    try:
+        yield _load("family")
+    finally:
+        sys.path.remove(FAMILY)
+        for n in names:
+            sys.modules.pop(n, None)
+            if before[n] is not None:
+                sys.modules[n] = before[n]
+
+
+def _cell_files():
+    import json
+
+    bench = os.path.dirname(os.path.dirname(FAMILY))
+    with open(os.path.join(bench, "configs", "lfm2_24b_a2b_ep8.json")) as f, \
+            open(os.path.join(bench, "traffic", "lmpopeval_fresh.json")) as g:
+        return json.load(f), json.load(g)
+
+
+def test_the_cells_pool_holds_the_defaults_and_no_recipe_that_diverges(family):
+    """A recipe hotter than the mix's ceiling diverges inside its few steps at
+    the published width, and its rows and loss then follow the seed."""
+    _, mix = _cell_files()
+    ceiling = mix["pool_log10_lr_max"]
+    pool = family.make_pool(4, [mix["pool_seed"]], ceiling)
+    assert pool[0] == lfm2_moe_genome().default() and pool[0]["log10_lr"] <= ceiling
+    assert len(pool) == 4 and all(r["log10_lr"] <= ceiling for r in pool)
+    assert pool == family.make_pool(4, [mix["pool_seed"]], ceiling)
+    spec = lfm2_moe_genome()
+    assert all(g.minimum <= r[g.name] <= g.maximum for r in pool for g in spec.genes)
+    assert any(r["log10_lr"] > ceiling for r in family.make_pool(12, [mix["pool_seed"]], spec.genes[0].maximum))
+
+
+def test_the_cells_tokens_have_many_effective_ids_whatever_the_seed(family):
+    """The work of a routed layer follows which experts the common ids draw:
+    with few effective ids (1 / sum p^2) it follows the seed."""
+    config, _ = _cell_files()
+    for seed in (3, 2**31 + 5):
+        tokens = family.markov_tokens(config["data"], config["vocab_size"], 8, config["data"]["seq_len"], seed)
+        assert tokens.shape == (8, config["data"]["seq_len"] + 1) and tokens.min() >= 0
+        share = np.bincount(tokens.ravel(), minlength=config["vocab_size"]) / tokens.size
+        assert share.max() < 0.03 and 1.0 / np.sum(share**2) > 500
+        # half the steps follow a fixed successor, so the chain can be learned
+        nxt = {}
+        follows = sum(nxt.setdefault(a, b) == b for a, b in zip(tokens[:, :-1].ravel(), tokens[:, 1:].ravel()))
+        assert follows / tokens[:, 1:].size > 0.4
