@@ -26,12 +26,15 @@ What differs from the CNN family, by design:
   genes and the step number enter as scalar arguments.
 - **The expert layer is told which experts it holds** (``held_experts``, a
   range; the router keeps ``num_experts`` outputs).  It routes over all of
-  them, keeps the (row, expert, weight) triples of its own experts in a static
-  buffer sized for the worst case, runs the three grouped products with a
-  kernel whose cost follows the rows present (megablox ``gmm`` on a TPU,
-  ``lax.ragged_dot`` elsewhere), and returns its experts' part of the sum.
-  What the absent experts would add is left out; no code stands in for the
-  other chips.  No assignment is dropped (``dropped`` is counted).
+  them, keeps the (row, expert, weight) triples of its own experts in a row
+  buffer whose height follows the rows present -- 2.75 times the rank's mean
+  share, or, where a call routes more than that here, the worst case, decided
+  on the device from the router's own count (``_moe_ffn``) -- runs the three grouped
+  products with a kernel whose cost follows the rows present (megablox ``gmm``
+  on a TPU, ``lax.ragged_dot`` elsewhere), and returns its experts' part of
+  the sum.  What the absent experts would add is left out; no code stands in
+  for the other chips.  No assignment is dropped at either height
+  (``dropped`` is counted, and so is every layer that took the worst case).
 - **A program is one individual wide.**  One individual of the published cut
   is ~0.65 B parameters and 16 bytes of training state a parameter
   (:func:`training_bytes`); two do not fit a 16 GB chip, so a population is
@@ -75,6 +78,11 @@ GENE_NAMES = ("log10_lr", "warmup_frac", "weight_decay", "beta2", "bias_step")
 ADAM_BETA1, ADAM_EPS, INIT_STD, ROUTE_EPS = 0.9, 1e-8, 0.02, 1e-6
 #: megablox tiles (rows, contraction, columns); the row tile shrinks to divide a small buffer.
 _GMM_TILING = (512, 512, 512)
+#: The narrow row buffer holds this many times the rows a routed layer sends this rank on
+#: average.  On the chip a layer's busiest step reached 2.62 times (93 individuals of the
+#: benchmark's cell, the step after warm-up; 4 of them passed 2.0); each 0.25 costs 0.75% of an
+#: individual's time, a layer-step at the worst-case height instead 0.3-0.5% (PERF.md, PR 29).
+_NARROW_SHARES = 2.75
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,8 +166,10 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
     ``state``: float32 weights, gradients and AdamW's two moments, 16 bytes a
     parameter.  ``activations``: what a step keeps beside them under per-layer
     rematerialisation -- every layer's input, the widest layer's interior
-    (dense feed-forward or the expert rows' buffer), the float32 logits and
-    their gradient.  An estimate to decide a width by, not a measurement.
+    (dense feed-forward, or the expert rows' buffer at its worst-case height:
+    the branch a program must have room for, whichever a call takes), the
+    float32 logits and their gradient.  An estimate to decide a width by, not a
+    measurement.
     """
     n_params = sum(math.prod(s) for s in jax.tree_util.tree_leaves(param_shapes(cfg), is_leaf=_is_shape))
     t, h = cfg.tokens_per_step, cfg.hidden_size
@@ -276,46 +286,120 @@ def _route(router, bias, x, cfg: Lfm2MoeConfig):
     return chosen, picked / (picked.sum(-1, keepdims=True) + ROUTE_EPS)
 
 
+class RowBufferUse(NamedTuple):
+    """What the routed layers' row buffers did with a call's held assignments (int32 scalars)."""
+
+    dropped: Any  # assignments that found no room: 0, either height holds what it is given
+    wide: Any  # routed layers that took the worst-case height
+
+
+def _narrow_rows(cfg: Lfm2MoeConfig, tokens: int) -> int:
+    """The row buffer's narrow height: the smallest multiple of the ``gmm`` row
+    tile that holds ``_NARROW_SHARES`` times the rows this rank gets on average,
+    and no more than the worst case (top-k x tokens), which is then the only
+    height."""
+    full, tile = cfg.num_experts_per_tok * tokens, _GMM_TILING[0]
+    return min(full, tile * math.ceil(_NARROW_SHARES * full * cfg.n_held / (cfg.num_experts * tile)))
+
+
+def _expert_rows(cfg: Lfm2MoeConfig, dtype, cap: int, p, x, weight, order, sizes):
+    """Dispatch, experts and combine on a row buffer of the static height
+    ``cap``: the first ``cap`` assignments of ``order`` (held ones first, grouped
+    by expert; ``sizes`` a held expert) each get a row.  Every pass has the
+    buffer's height.  Returns the tokens' sums and (the rows that found room,
+    ``cap``: the height that ran, for a caller that chose it on the device)."""
+    t, h = x.shape
+    k = cfg.num_experts_per_tok
+    with jax.named_scope("moe"), jax.named_scope("dispatch"):
+        ends = jnp.minimum(jnp.cumsum(sizes), cap)
+        sizes_kept = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        n_rows = ends[-1]
+        filled = (jnp.arange(cap) < n_rows)[:, None]
+        taken = order[:cap]  # the assignment a row holds: token a // k, choice a % k
+        rows = jnp.where(filled, jnp.take(x, taken // k, axis=0), 0).astype(dtype)
+    with jax.named_scope("moe"), jax.named_scope("experts"):
+        up = jax.nn.silu(_grouped_matmul(rows, p["w1"].astype(dtype), sizes_kept)) \
+            * _grouped_matmul(rows, p["w3"].astype(dtype), sizes_kept)
+        down = _grouped_matmul(jnp.where(filled, up, 0), p["w2"].astype(dtype), sizes_kept)
+        down = jnp.where(filled, down, 0)
+    with jax.named_scope("moe"), jax.named_scope("combine"):
+        # a token's at most top-k rows, each times its float32 weight, summed in float32
+        scaled = jnp.take(weight.reshape(-1), taken)[:, None] * down
+        out = jnp.zeros((t, h), jnp.float32).at[taken // k].add(scaled).astype(dtype)
+    return out, (n_rows, jnp.int32(cap))
+
+
+def _expert_rows_by_count(narrow: int, full: int, cfg: Lfm2MoeConfig, dtype):
+    """:func:`_expert_rows` at ``narrow`` rows where the held assignments fit
+    and at ``full`` rows where they do not, decided on the device.
+
+    One ``cond`` forward and one backward, each branch differentiated inside
+    itself from the layer's inputs.  Differentiated from outside, a ``cond``
+    hands its backward pass every array either branch keeps, the absent
+    branch's as zeros of that branch's height: at the published widths 2.8 GB
+    more temporaries a step (TPU compiler's memory analysis, PR 29) and a
+    zero-fill of the worst-case buffers in every narrow pass."""
+
+    def pick(body, sizes, *operands):
+        return jax.lax.cond(sizes.sum() > narrow, functools.partial(body, full), functools.partial(body, narrow),
+                            *operands)
+
+    forward = functools.partial(_expert_rows, cfg, dtype)
+
+    def backward(cap, p, x, weight, order, sizes, g):
+        _, vjp, _ = jax.vjp(lambda p, x, weight: forward(cap, p, x, weight, order, sizes), p, x, weight, has_aux=True)
+        return vjp(g)
+
+    def primal(*operands):
+        return pick(forward, operands[-1], *operands)
+
+    def cotangents(operands, g):  # of the weights, the tokens and the routing weights; order and sizes have none
+        return pick(backward, operands[-1], *operands, g[0]) + (None, None)
+
+    by_count = jax.custom_vjp(primal)
+    by_count.defvjp(lambda *operands: (primal(*operands), operands), cotangents)
+    return by_count
+
+
 def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = None):
     """The held experts' part of the routed feed-forward on (tokens, hidden).
 
-    Returns ``(out, load, dropped)``: ``load`` counts the tokens each of ALL
-    experts was chosen for (the bias rule needs them all), ``dropped`` the
-    assignments to held experts that found no room in the row buffer.  The
-    buffer holds top-k x tokens rows, the worst case, so nothing is dropped;
-    ``row_buffer`` is there for the test of that arithmetic and no caller sets it."""
-    t, h = x.shape
+    Returns ``(out, load, use)``: ``load`` counts the tokens each of ALL experts
+    was chosen for (the bias rule needs them all), ``use`` is this layer's
+    :class:`RowBufferUse`.  The row buffer's height follows the rows present:
+    the held assignments the router counted decide on the device between
+    :func:`_narrow_rows` and, over it, the worst case of top-k x tokens rows;
+    :func:`_expert_rows` is the body of both, so no assignment is ever dropped.
+    ``row_buffer`` stands in for the narrow height in the tests of that
+    arithmetic and no caller sets it.  The ``cond`` is called outside the
+    ``moe`` scope and each branch opens it again: jax names a branch's ops after
+    the scopes open at the call (``layer2/cond/branch_0_fun/moe/experts/...``),
+    and the benchmark's op classes go by what follows the first ``moe``."""
+    t = x.shape[0]
     k, n_held = cfg.num_experts_per_tok, cfg.n_held
-    cap = k * t if row_buffer is None else row_buffer
-    with jax.named_scope("router"):
+    full = k * t
+    narrow = _narrow_rows(cfg, t) if row_buffer is None else row_buffer
+    with jax.named_scope("moe"), jax.named_scope("router"):
         chosen, weight = _route(p["router"], bias, x, cfg)
         load = jnp.sum(chosen[..., None] == jnp.arange(cfg.num_experts), axis=(0, 1), dtype=jnp.int32)
-    with jax.named_scope("dispatch"):
+    with jax.named_scope("moe"), jax.named_scope("dispatch"):
         local = chosen.reshape(-1) - cfg.held_experts[0]  # assignment a = token a // k, choice a % k
         held = (local >= 0) & (local < n_held)
         key = jnp.where(held, local, n_held)
         order = jnp.argsort(key, stable=True)  # held assignments first, grouped by expert
         sizes = jnp.sum(key[:, None] == jnp.arange(n_held), axis=0, dtype=jnp.int32)
-        ends = jnp.minimum(jnp.cumsum(sizes), cap)
-        sizes_kept = jnp.diff(ends, prepend=0).astype(jnp.int32)
-        n_rows = ends[-1]
-        dropped = sizes.sum() - n_rows
-        filled = (jnp.arange(cap) < n_rows)[:, None]
-        rows = jnp.where(filled, jnp.take(x, order[:cap] // k, axis=0), 0).astype(dtype)
-    with jax.named_scope("experts"):
-        up = jax.nn.silu(_grouped_matmul(rows, p["w1"].astype(dtype), sizes_kept)) \
-            * _grouped_matmul(rows, p["w3"].astype(dtype), sizes_kept)
-        down = _grouped_matmul(jnp.where(filled, up, 0), p["w2"].astype(dtype), sizes_kept)
-        down = jnp.where(filled, down, 0)
-    with jax.named_scope("combine"):
-        place = jnp.zeros(t * k, jnp.int32).at[order].set(jnp.arange(t * k, dtype=jnp.int32))  # assignment -> row
-        back = jnp.where((place < n_rows)[:, None], jnp.take(down, jnp.minimum(place, cap - 1), axis=0), 0)
-        out = jnp.einsum("tk,tkh->th", weight, back.reshape(t, k, h), preferred_element_type=jnp.float32)
-    return out.astype(dtype), load, dropped
+        n_held_rows = sizes.sum()
+    operands = ({name: p[name] for name in ("w1", "w3", "w2")}, x, weight, order, sizes)
+    if narrow >= full:  # one height, nothing to fall back from
+        out, (n_rows, _) = _expert_rows(cfg, dtype, full, *operands)
+        return out, load, RowBufferUse(n_held_rows - n_rows, jnp.zeros((), jnp.int32))
+    out, (n_rows, height) = _expert_rows_by_count(narrow, full, cfg, dtype)(*operands)
+    return out, load, RowBufferUse(n_held_rows - n_rows, jnp.int32(height > narrow))
 
 
 def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x):
-    """One layer on (sequences, length, hidden); ``bias`` is the layer's router bias or None."""
+    """One layer on (sequences, length, hidden); ``bias`` is the layer's router
+    bias or None.  Returns the output and, of a routed layer, (load, use)."""
     kind, name = cfg.layer_types[index], f"layer{cfg.layer_ids[index]}"
     with jax.named_scope(name):
         normed = _rms_norm(x, p["op_norm"], cfg.norm_eps).astype(dtype)
@@ -329,29 +413,29 @@ def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x):
         if "dense" in p:
             with jax.named_scope("dense_ffn"):
                 return h + _dense_ffn(p["dense"], normed, dtype), None
-        with jax.named_scope("moe"):
-            out, load, dropped = _moe_ffn(p["moe"], bias, normed.reshape(-1, normed.shape[-1]), cfg, dtype)
-        return h + out.reshape(h.shape), (load, dropped)
+        out, load, use = _moe_ffn(p["moe"], bias, normed.reshape(-1, normed.shape[-1]), cfg, dtype)
+        return h + out.reshape(h.shape), (load, use)
 
 
 def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
     """Float32 logits (sequences, length, held vocabulary), the load of ALL
-    experts per routed layer (layers, experts) and the dropped assignments."""
+    experts per routed layer (layers, experts) and the routed layers'
+    :class:`RowBufferUse`, summed."""
     dtype = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dtype)
-    loads, dropped = [], jnp.zeros((), jnp.int32)
+    loads, use = [], RowBufferUse(jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
     for i, p in enumerate(params["layers"]):
         fn = functools.partial(_layer, cfg, i, dtype)
         moe = i >= cfg.num_dense_layers
         x, aux = (jax.checkpoint(fn) if remat else fn)(p, bias[i - cfg.num_dense_layers] if moe else None, x)
         if moe:
             loads.append(aux[0])
-            dropped = dropped + aux[1]
+            use = jax.tree_util.tree_map(jnp.add, use, aux[1])
     with jax.named_scope("head"):
         x = _rms_norm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
         logits = jnp.einsum("slh,vh->slv", x, params["embed"].astype(dtype), preferred_element_type=jnp.float32)
-    return logits, jnp.stack(loads), dropped
+    return logits, jnp.stack(loads), use
 
 
 def token_loss(logits, targets):
@@ -369,7 +453,8 @@ class Lfm2MoePrograms(NamedTuple):
     ``init(base_key, genome_hash) -> state``: a fresh train state, a dict of
     ``params``/``m``/``v`` (one tree each, :func:`param_shapes`), ``bias``
     (routed layers, experts), ``rows`` (routed layers, held experts: rows
-    routed so far) and ``dropped``.  ``train_step(state, x, y, batch_rows,
+    routed so far), ``dropped`` and ``wide_buffer`` (:class:`RowBufferUse`,
+    summed over the steps so far).  ``train_step(state, x, y, batch_rows,
     genes, step) -> (state, loss, load)``: ``state`` is donated; ``x``/``y``
     are the whole token arrays, ``batch_rows`` (train_steps, batch_sequences)
     the sequences of every step, ``genes`` the float32 vector in
@@ -399,15 +484,16 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)
         return {"params": params, "m": zeros(), "v": zeros(),
                 "bias": jnp.zeros((n_moe, cfg.num_experts), jnp.float32),
-                "rows": jnp.zeros((n_moe, cfg.n_held), jnp.int32), "dropped": jnp.zeros((), jnp.int32)}
+                "rows": jnp.zeros((n_moe, cfg.n_held), jnp.int32), "dropped": jnp.zeros((), jnp.int32),
+                "wide_buffer": jnp.zeros((), jnp.int32)}
 
     def loss_fn(params, bias, x, y):
-        logits, load, dropped = forward(cfg, params, bias, x, remat=True)
-        return token_loss(logits, y).mean(), (load, dropped)
+        logits, load, use = forward(cfg, params, bias, x, remat=True)
+        return token_loss(logits, y).mean(), (load, use)
 
     def train_step(state, x_all, y_all, batch_rows, genes, step):
         rows = batch_rows[step]
-        (loss, (load, dropped)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        (loss, (load, use)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state["params"], state["bias"], x_all[rows], y_all[rows])
         log10_lr, warmup_frac, weight_decay, beta2, bias_step = (genes[i] for i in range(len(GENE_NAMES)))
         with jax.named_scope("optimizer"):
@@ -429,7 +515,8 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
             bias = state["bias"] + bias_step * jnp.sign(mean_load - load.astype(jnp.float32))
         held = load[:, lo:hi]
         new = {"params": params, "m": m, "v": v, "bias": bias,
-               "rows": state["rows"] + held, "dropped": state["dropped"] + dropped}
+               "rows": state["rows"] + held, "dropped": state["dropped"] + use.dropped,
+               "wide_buffer": state["wide_buffer"] + use.wide}
         return new, loss, held
 
     def lm_eval(params, bias, x_all, y_all, rows):
@@ -563,20 +650,23 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
         losses = sp.fence([programs.eval(state["params"], state["bias"], x, y, rows) for rows in val_rows])
     with _phase("fetch", {"individual": individual}) as sp:
         if _tele.enabled():
-            losses, rows, dropped = jax.device_get((losses, state["rows"], state["dropped"]))
-            _count_expert_rows(cfg, rows, int(dropped))
-            sp.set(expert_rows=rows.tolist(), dropped=int(dropped))
+            losses, rows, dropped, wide = jax.device_get(
+                (losses, state["rows"], state["dropped"], state["wide_buffer"]))
+            _count_expert_rows(cfg, rows, int(dropped), int(wide))
+            sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=int(wide))
         else:
             losses = jax.device_get(losses)
         del state  # the fetch has waited for the device: these 12 bytes a parameter are free again
     return float(np.mean(np.concatenate([np.ravel(l) for l in losses]), dtype=np.float64))
 
 
-def _count_expert_rows(cfg: Lfm2MoeConfig, rows: np.ndarray, dropped: int) -> None:
-    """``expert_rows{layer, expert}`` and ``dropped_assignments_total`` from what
-    an individual's steps summed on the device (telemetry on only)."""
+def _count_expert_rows(cfg: Lfm2MoeConfig, rows: np.ndarray, dropped: int, wide: int) -> None:
+    """``expert_rows{layer, expert}``, ``dropped_assignments_total`` and
+    ``row_buffer_wide_total`` from what an individual's steps summed on the
+    device (telemetry on only)."""
     reg = _get_registry()
     for layer, per_expert in zip(cfg.moe_layers, rows):
         for expert, n in zip(range(*cfg.held_experts), per_expert):
             reg.counter("expert_rows", layer=str(cfg.layer_ids[layer]), expert=str(expert)).inc(int(n))
     reg.counter("dropped_assignments_total").inc(dropped)
+    reg.counter("row_buffer_wide_total").inc(wide)

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import importlib.util
 import os
+import re
 import sys
 
 import numpy as np
@@ -93,8 +94,8 @@ def test_logits_loss_and_gradients_match_the_reference(case, tokens):
     x, y = tokens[0][:2], tokens[1][:2]
 
     def system_loss(params):
-        logits, load, dropped = M.forward(cfg, params, b, x, remat=True)
-        return M.token_loss(logits, y).mean(), (logits, load, dropped)
+        logits, load, use = M.forward(cfg, params, b, x, remat=True)
+        return M.token_loss(logits, y).mean(), (logits, load, use)
 
     def reference_loss(params):
         out = [R.forward(m, params, b, xs) for xs in x]
@@ -102,12 +103,12 @@ def test_logits_loss_and_gradients_match_the_reference(case, tokens):
         return jnp.mean(jnp.stack([R.token_loss(l, ys) for l, ys in zip(logits, y)])), (logits, sum(o[1] for o in out))
 
     with HIGHEST:
-        (loss, (logits, load, dropped)), grads = jax.jit(jax.value_and_grad(system_loss, has_aux=True))(w)
+        (loss, (logits, load, use)), grads = jax.jit(jax.value_and_grad(system_loss, has_aux=True))(w)
         (ref_loss, (ref_logits, ref_load)), ref_grads = jax.jit(jax.value_and_grad(reference_loss, has_aux=True))(w)
     np.testing.assert_allclose(logits, ref_logits, atol=2e-5)
     np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
     np.testing.assert_array_equal(load, ref_load)
-    assert int(dropped) == 0
+    assert int(use.dropped) == 0 and int(use.wide) == 0
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
         np.testing.assert_allclose(g, r, atol=2e-6, rtol=1e-4, err_msg=jax.tree_util.keystr(path))
         assert float(jnp.abs(r).max()) > 0, f"{jax.tree_util.keystr(path)}: the reference's gradient is all zero"
@@ -189,14 +190,105 @@ def test_no_assignment_is_dropped_when_every_token_goes_to_one_held_expert(token
     w = R.seeded_weights(m, 3)["layers"][0]["moe"]
     forced = jnp.zeros((1, 8), jnp.float32).at[0, 3].set(10.0)  # expert 3 (held) wins every token's first choice
     x = jnp.asarray(np.random.default_rng(4).normal(size=(32, 32)), jnp.float32)
-    out, load, dropped = M._moe_ffn(w, forced[0], x, cfg, jnp.float32)
-    assert int(load[3]) == 32 and int(dropped) == 0
+    out, load, use = M._moe_ffn(w, forced[0], x, cfg, jnp.float32)
+    assert int(load[3]) == 32 and int(use.dropped) == 0 and int(use.wide) == 0  # one height at this size
     with HIGHEST:
         ref, _ = R.routed_ffn(w, forced[0], x, m, lambda a: a)
     np.testing.assert_allclose(out, ref, atol=1e-5)
-    # a buffer below the worst case drops, and counts it
-    _, load, dropped = M._moe_ffn(w, forced[0], x, cfg, jnp.float32, row_buffer=16)
-    assert int(dropped) == int(load[1:5].sum()) - 16 > 0
+    # more rows than the narrow height holds: the layer takes the worst-case height, and says so
+    out, load, use = jax.jit(lambda: M._moe_ffn(w, forced[0], x, cfg, jnp.float32, row_buffer=16))()
+    assert int(load[1:5].sum()) > 16 and int(use.dropped) == 0 and int(use.wide) == 1
+    np.testing.assert_allclose(out, ref, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 1e-2)])
+def test_the_narrow_and_the_wide_row_buffer_give_the_same_layer(tokens, dtype, tol):
+    """One input whose held rows fit the narrow height: the branch the ``cond``
+    takes then and the worst-case height (the only one without ``row_buffer``
+    at this size) are the same function of the same rows."""
+    m = one_layer("conv", "moe")
+    cfg = config_of(tokens, m)
+    w = R.seeded_weights(m, 3)["layers"][0]["moe"]
+    b = jnp.asarray(0.01 * np.random.default_rng(5).normal(size=8), jnp.float32)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(32, 32)), jnp.float32)
+    probe = jnp.asarray(np.random.default_rng(6).normal(size=(32, 32)), jnp.float32)
+
+    def layer(row_buffer):
+        def value(p, xs):
+            out, load, use = M._moe_ffn(p, b, xs.astype(dtype), cfg, jnp.dtype(dtype), row_buffer)
+            return jnp.sum(out.astype(jnp.float32) * probe), (out, load, use)
+        return jax.jit(jax.value_and_grad(jax.checkpoint(value), argnums=(0, 1), has_aux=True))(w, x)
+
+    with HIGHEST:
+        (_, (wide_out, wide_load, _)), wide_grads = layer(None)
+        (_, (out, load, use)), grads = layer(40)
+    held = int(load[1:5].sum())
+    assert 16 < held <= 40 and int(use.wide) == 0 and int(use.dropped) == 0
+    assert float(jnp.abs(out.astype(jnp.float32)).max()) > 0
+    np.testing.assert_array_equal(load, wide_load)
+    np.testing.assert_allclose(out.astype(jnp.float32), wide_out.astype(jnp.float32), atol=tol, rtol=tol)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(wide_grads)):
+        assert float(jnp.abs(r).max()) > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(g, r, atol=tol * float(jnp.abs(r).max()), err_msg=jax.tree_util.keystr(path))
+
+
+#: A small shape at which the configuration itself gives two heights: 2 x 512
+#: tokens, top-2, 2 of 8 experts held -- 1,536 rows (three ``gmm`` tiles hold 2.75
+#: times the mean share of 512) against the worst case of 2,048.
+TWO_HEIGHTS = {**MODEL, "layer_types": ["conv"], "num_dense_layers": 0}
+
+
+@pytest.fixture(scope="module")
+def long_tokens():
+    tok = np.random.default_rng(9).integers(0, 64, size=(6, 513)).astype(np.int32)
+    return tok[:, :-1], tok[:, 1:]
+
+
+class _Sink:
+    def __init__(self):
+        self.records = []
+
+    def record(self, rec):
+        self.records.append(rec)
+
+
+def test_a_train_step_on_a_forced_bias_counts_the_layers_that_took_the_wide_buffer(long_tokens):
+    x, y = long_tokens
+    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs(TWO_HEIGHTS))
+    cfg = programs.config
+    assert (M._narrow_rows(cfg, cfg.tokens_per_step), 2 * cfg.tokens_per_step) == (1536, 2048)
+    assert M._narrow_rows(M.Lfm2MoeConfig(), 16384) == 22528  # the published cut: 44 tiles against 65,536 rows
+    w = R.seeded_weights(TWO_HEIGHTS, 5)
+    rows = np.array([[0, 1], [2, 3], [0, 2]], np.int32)
+    forced = np.zeros((1, 8), np.float32)
+    forced[0, 2:4] = 10.0  # both choices of every token go to the two held experts: 2,048 rows
+    with HIGHEST:
+        state, losses, loads = _program_steps(programs, w, forced, x, y, rows, 2)
+        ref = R.train(TWO_HEIGHTS, w, [(x[r], y[r]) for r in rows[:2]], GENES, bias=forced)
+        calm, _, calm_loads = _program_steps(programs, w, np.zeros((1, 8), np.float32), x, y, rows, 2)
+    assert all(int(l.sum()) == 2048 for l in loads) and all(int(l.sum()) <= 1536 for l in calm_loads)
+    assert int(state["wide_buffer"]) == 2 and int(state["dropped"]) == 0
+    assert int(calm["wide_buffer"]) == 0 and int(calm["dropped"]) == 0
+    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
+                            jax.tree_util.tree_leaves(ref["weights"])):
+        np.testing.assert_allclose(a, b, atol=3e-5, err_msg=jax.tree_util.keystr(path))
+    # telemetry on: the same count on the individual's fetch span and on the counter
+    forcing = programs._replace(init=lambda key, genome_hash: {**programs.init(key, genome_hash),
+                                                                "bias": jnp.asarray(forced)})
+    get_registry().reset()
+    sink = _Sink()
+    spans.set_run_sink(sink)
+    spans.enable()
+    try:
+        M._score_one(forcing, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32), M.gene_vector(GENES), jnp.asarray(x),
+                     jnp.asarray(y), jnp.asarray(rows), [jnp.asarray([4, 5])], [np.int32(s) for s in range(3)], 0)
+    finally:
+        spans.disable()
+        spans.set_run_sink(None)
+    fetched = [r["attrs"] for r in sink.records if r["type"] == "span" and r["kind"] == "fetch"]
+    assert [a["wide_buffer"] for a in fetched] == [3] and fetched[0]["dropped"] == 0  # 3 steps x 1 routed layer
+    assert get_registry().counter("row_buffer_wide_total").value == 3
 
 
 def _pool(n=3):
@@ -215,13 +307,9 @@ def test_fitness_is_a_function_of_the_genome_in_any_position_and_with_telemetry_
         np.testing.assert_array_equal(again, base[order])
     assert M.Lfm2MoeModel(x, y, pool[1], **kw).cross_validate() == base[1]
     get_registry().reset()
-    records = []
-
-    class Sink:
-        def record(self, rec):
-            records.append(rec)
-
-    spans.set_run_sink(Sink())
+    sink = _Sink()
+    records = sink.records
+    spans.set_run_sink(sink)
     spans.enable()
     try:
         traced = M.Lfm2MoeModel.cross_validate_population(x, y, pool, **kw)
@@ -239,6 +327,7 @@ def test_fitness_is_a_function_of_the_genome_in_any_position_and_with_telemetry_
     assert set(rows) == {(str(l), str(e)) for l in (1, 2) for e in (2, 3)}
     assert sum(rows.values()) == sum(sum(map(sum, a["expert_rows"])) for a in fetched) > 0
     assert get_registry().counter("dropped_assignments_total").value == 0
+    assert get_registry().counter("row_buffer_wide_total").value == 0 == sum(a["wide_buffer"] for a in fetched)
     assert M.Lfm2MoeModel.cross_validate_population(x, y, [], **kw).shape == (0,)
     other_seed = M.Lfm2MoeModel.cross_validate_population(x, y, pool[:1], **{**kw, "seed": 4})
     assert other_seed[0] != base[0]
@@ -304,6 +393,11 @@ def test_the_published_cut_is_one_individual_wide_by_arithmetic():
 @pytest.mark.parametrize("op_name,klass", [
     ("jit(lm_train_step)/jvp(layer2)/moe/experts/pallas_call", "expert_mm"),
     ("jit(lm_train_step)/transpose(jvp(layer2))/moe/experts/mul", "expert_mm"),
+    ("jit(lm_train_step)/jvp(layer2)/cond/branch_0_fun/moe/experts/jit(gmm)/pallas_call", "expert_mm"),
+    ("jit(lm_train_step)/transpose(jvp(jvp()))/checkpoint/layer3/cond/branch_1_fun/transpose(jvp(moe))/experts/"
+     "jit(tgmm)/pallas_call", "expert_mm"),
+    ("jit(lm_train_step)/transpose(jvp(layer2))/cond/branch_1_fun/moe/combine/scatter-add", "moe_route"),
+    ("jit(lm_eval)/layer7/cond/branch_0_fun/moe/dispatch/jit(_take)/gather", "moe_route"),
     ("jit(lm_train_step)/jvp(layer5)/moe/dispatch/jit(argsort)/sort", "moe_route"),
     ("jit(lm_train_step)/checkpoint/rematted_computation/layer3/moe/router/dot_general", "moe_route"),
     ("jit(lm_eval)/layer6/attention/checkpoint/sngqk,sknd->sqngd/dot_general", "attention"),
@@ -321,14 +415,32 @@ def test_scope_rules_place_an_op_by_its_scopes(op_name, klass):
     assert scope_rules.classify(op_name)[0] == klass and klass in scope_rules.CLASSES
 
 
-def test_the_lowered_train_step_carries_every_scope(tokens):
-    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(layer_ids=(0, 2, 3)))
+def _lowered_train_step(programs, x, y) -> str:
     state = jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
-    text = programs.train_step.lower(state, tokens[0], tokens[1], np.zeros((3, 2), np.int32), np.zeros(5, np.float32),
+    return programs.train_step.lower(state, x, y, np.zeros((3, 2), np.int32), np.zeros(5, np.float32),
                                      np.int32(0)).as_text(debug_info=True)
+
+
+def test_the_lowered_train_step_carries_every_scope(tokens, long_tokens):
+    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(layer_ids=(0, 2, 3)))
+    text = _lowered_train_step(programs, *tokens)
     for scope in ("embed", "layer0", "layer2", "layer3", "conv_op", "attention", "dense_ffn", "moe/router",
                   "moe/dispatch", "moe/experts", "moe/combine", "head", "loss", "optimizer", "bias_update"):
         assert scope in text, scope
+    # with two heights (a ``cond``, forward and backward) every op of the expert layer keeps its class
+    two = _lowered_train_step(M.Lfm2MoeModel.compiled_programs(long_tokens[0], **model_kwargs(TWO_HEIGHTS)),
+                              *long_tokens)
+    names, one_height = (set(re.findall(r'loc\("([^"]+)"', t)) for t in (two, text))
+    branches = {n for n in names if "/cond/branch_" in n}
+    assert {n.split("/cond/")[1].split("/")[0] for n in branches} == {"branch_0_fun", "branch_1_fun"}
+    assert any("transpose" in n for n in branches) and len(branches) > 100
+    for part in ("dispatch", "experts", "combine"):
+        assert any(scope_rules.classify(n) == ("expert_mm" if part == "experts" else "moe_route", part)
+                   for n in branches), part
+    assert all(scope_rules.classify(n)[0] == "expert_mm" for n in names | one_height if "experts" in n)
+    other = lambda found: {n.rsplit("/", 1)[-1] for n in found if scope_rules.classify(n) == ("moe_route", "other")}
+    assert other(names) <= other(one_height)
+    assert not [n for n in branches if scope_rules.classify(n)[0] not in ("expert_mm", "moe_route")]
 
 
 def test_executed_flops_of_the_grouped_products_on_a_recorded_load():
