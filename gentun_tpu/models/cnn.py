@@ -31,10 +31,8 @@ TPU-first architecture (NOT how the reference does it — SURVEY.md §7
   ``lax.scan``, eval uses padded index batches with 0/1 weights.
 - **The k-fold axis stays on device** (SURVEY.md §7 "hard parts" #3): the
   dataset lives on device ONCE and folds are expressed as index arrays —
-  no per-fold host round-trips, no per-fold transfers.  With
-  ``fold_parallel=True`` all folds of all genomes train inside a single
-  fused XLA program (``vmap(fold) ∘ vmap(pop)``).
-- **Segmented execution by default**: long schedules run as a host loop of
+  no per-fold host round-trips, no per-fold transfers.
+- **Segmented execution**: long schedules run as a host loop of
   bounded-length jitted calls (``segment_steps`` ≈ tens of seconds each)
   over device-resident carries — params, optimizer state, and the dropout
   rng never leave the device, and the optax schedule continues across
@@ -46,9 +44,7 @@ TPU-first architecture (NOT how the reference does it — SURVEY.md §7
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import hashlib
 import logging
 import time
 import weakref
@@ -70,17 +66,17 @@ from ..parallel.mesh import (
     mesh_axis_sizes,
     pad_population,
     pop_bucket,
-    shard_cv_args,
 )
 from ..parallel.multihost import fetch, place, place_tree
 from ..telemetry import lineage as _lineage
 from ..telemetry import spans as _tele
-from ..utils.jax_state import mark_backend_used
 from ..telemetry.registry import get_registry as _get_registry
-from ..utils.xla_cache import (
-    default_cache_dir,
-    enable_compilation_cache,
-    run_publish_hooks,
+from .evaluation import (
+    base_keys,
+    evaluation_prelude,
+    fold_content_keys,
+    genome_hashes,
+    phase,
 )
 from .generic import GentunModel
 
@@ -181,7 +177,8 @@ class MaskedGeneticCnn(nn.Module):
 # compiles exactly once per (config, fold-shape) pair.
 
 
-def _training_primitives(
+@functools.lru_cache(maxsize=32)
+def _fold_segment_fns(
     nodes: Tuple[int, ...],
     filters: Tuple[int, ...],
     dense_units: int,
@@ -199,8 +196,19 @@ def _training_primitives(
     eval_batch_size: int,
     microbatch: int = 1,
 ):
-    """Shared, unjitted builders both executors compose: the model, the
-    optimizer (staged-LR SGD), a train-segment function, and the fold eval.
+    """Per-fold building blocks of the segmented executor, lru-cached by
+    static config: the arguments are exactly :func:`_static_key`'s tuple.
+
+    Returns ``(init_pop, train_pop, eval_pop)``, each jitted with the
+    population axis vmapped over the model and its staged-LR SGD:
+
+    - ``init_pop(params) -> opt_state``
+    - ``train_pop(params, opt_state, masks, x, y, batch_idx_seg, rng)``
+      runs one bounded segment of train steps and returns the advanced
+      carries; the optax schedule continues across segments through the
+      opt-state step count, so chopping the schedule is semantically
+      invisible.
+    - ``eval_pop(params, masks, x, y, val_idx, val_weight) -> acc``
 
     ``eval_batch_size`` may exceed ``batch_size``: the validation pass is
     forward-only (no optimizer state, no activations kept for backward), so
@@ -217,11 +225,6 @@ def _training_primitives(
     the exact pre-existing step — the ``if`` below is Python-level, so the
     compiled program (and its persistent-cache key) is byte-identical to
     before the knob existed.
-
-    There is exactly ONE definition of the schedule-boundary math, the loss,
-    and the eval weighting — the fused (:func:`_population_cv_fn`) and
-    segmented (:func:`_fold_segment_fns`) paths differ only in how the
-    fold/step axes are driven, never in what a step computes.
     """
     model = MaskedGeneticCnn(
         nodes=nodes,
@@ -315,55 +318,6 @@ def _training_primitives(
         correct, _ = jax.lax.scan(eval_batch, jnp.float32(0.0), starts)
         return correct / jnp.maximum(val_weight.sum(), 1.0)
 
-    return model, tx, train_segment, eval_fold
-
-
-@functools.lru_cache(maxsize=32)
-def _population_cv_fn(*static_key):
-    """FUSED executor (``fold_parallel=True``): one XLA program trains all
-    folds of all genomes concurrently — ``vmap(fold) ∘ vmap(pop)`` with
-    ``kfold·P``-wide matmuls.  Maximum parallelism, kfold× the working set,
-    and one long device execution; prefer it when pop×kfold is small.
-    Static key =
-    :func:`_training_primitives` args.
-    """
-    _, tx, train_segment, eval_fold = _training_primitives(*static_key)
-
-    def train_one(params, masks, x_full, y_full, val_idx, val_weight, batch_idx, rng):
-        opt_state = tx.init(params)
-        params, _, _ = train_segment(params, opt_state, masks, x_full, y_full, batch_idx, rng)
-        return eval_fold(params, masks, x_full, y_full, val_idx, val_weight)
-
-    # Inner vmap — population axis: params, masks, rng per-individual; the
-    # dataset and the fold's index arrays are shared across the population.
-    over_pop = jax.vmap(train_one, in_axes=(0, 0, None, None, None, None, None, 0))
-    # Outer vmap — fold axis: params, rng, index arrays per-fold; masks and
-    # the dataset shared.
-    over_folds = jax.vmap(over_pop, in_axes=(0, None, None, None, 0, 0, 0, 0))
-    return jax.jit(over_folds)
-
-
-@functools.lru_cache(maxsize=32)
-def _fold_segment_fns(*static_key):
-    """Per-fold building blocks for SEGMENTED execution (the default path).
-
-    Returns ``(init_pop, train_pop, eval_pop)``, each jitted with the
-    population axis vmapped:
-
-    - ``init_pop(params) -> opt_state``
-    - ``train_pop(params, opt_state, masks, x, y, batch_idx_seg, rng)``
-      runs one bounded segment of train steps and returns the advanced
-      carries; the optax schedule continues across segments through the
-      opt-state step count, so chopping the schedule is semantically
-      invisible.
-    - ``eval_pop(params, masks, x, y, val_idx, val_weight) -> acc``
-
-    Same lru-cached-by-static-config pattern as :func:`_population_cv_fn`;
-    the two factories share :func:`_training_primitives`, differing only in
-    how the fold/step axes are driven (fused vmap vs host loop).  The
-    static key is exactly :func:`_static_key`'s tuple.
-    """
-    _, tx, train_segment, eval_fold = _training_primitives(*static_key)
     init_pop = jax.jit(jax.vmap(tx.init))
     # Donate the carries: each call consumes the previous segment's params /
     # opt state / rng, halving peak HBM versus keeping both generations.
@@ -395,12 +349,9 @@ def _eval_batch_size(batch_size: int, n_val: int) -> Tuple[int, int]:
 
 def _static_key(cfg: Dict[str, Any], batch_size: int, n_train: int, n_val_padded: int,
                 eval_batch_size: int) -> Tuple:
-    """The ONE definition of the compiled-program static key.
-
-    Both lru-cached factories (:func:`_population_cv_fn`,
-    :func:`_fold_segment_fns`) key on exactly this tuple — a new config knob
-    added here reaches every cache key at once, so the executors can never
-    silently share a program compiled for a different config.
+    """The ONE definition of the compiled-program static key:
+    :func:`_fold_segment_fns` keys on exactly this tuple, so a program
+    compiled for one config can never silently serve another.
     """
     return (
         cfg["nodes"],
@@ -429,64 +380,6 @@ def _segment_bounds(total_steps: int, segment_steps) -> List[Tuple[int, int]]:
         return [(0, total_steps)]
     seg = int(segment_steps)
     return [(s, min(s + seg, total_steps)) for s in range(0, total_steps, seg)]
-
-
-#: Program shapes already executed once in this process — how the telemetry
-#: split labels the FIRST call of a compiled shape `compile` and later calls
-#: `train`/`eval`.  Keys are (callable id, shape signature); the callables
-#: are lru-cached so ids are stable per static config.  "compile" honestly
-#: means compile + first execution (jax offers no portable way to time the
-#: compile alone without a throwaway AOT lower/compile cycle, which would
-#: change the disabled-path behavior this module guarantees).
-_tele_seen_programs: set = set()
-
-
-@contextlib.contextmanager
-def _live_phase(kind: str, attrs: Dict[str, Any], program):
-    """The telemetry-on half of :func:`_phase`."""
-    first = program is not None and program not in _tele_seen_programs
-    if first:
-        attrs["phase"], kind = kind, "compile"
-    # The annotation puts the span into the profiler's own trace, on the
-    # profiler's clock, above the device ops it launched; scalars known at
-    # entry ride along as its stats.
-    with jax.profiler.TraceAnnotation(
-        f"gentun/{kind}", **{k: v for k, v in attrs.items() if isinstance(v, (int, float, str))}
-    ), _tele.span(kind, attrs) as sp:
-        t0 = time.monotonic()
-        try:
-            yield sp
-        except BaseException:
-            if program is not None:
-                # `compile`/`train`/`eval` stay what their readers take them
-                # for, calls that returned: the deep configuration's 50-wide
-                # attempt compiles for ~23 s and then runs out of memory.
-                sp.kind = "call_failed"
-            raise
-        dur = time.monotonic() - t0
-    if first:
-        # First-compile latency histogram (docs/OBSERVABILITY.md): what a
-        # compile-cache hit saves — compile + first execution, as the span.
-        _tele_seen_programs.add(program)
-        _get_registry().histogram("compile_seconds").observe(dur)
-
-
-def _phase(kind: str, attrs: Optional[Dict[str, Any]] = None, program=None):
-    """One named phase of an evaluation call (docs/OBSERVABILITY.md).
-
-    Telemetry off: the spans module's shared no-op, nothing allocated and
-    nothing synchronised.  On: a ``gentun/<kind>`` profiler annotation plus
-    a span record.  A device call passes ``program`` (callable id + shape
-    signature) and fences its result with ``sp.fence(...)``: the span's
-    ``dispatch_s`` is how long the jitted call took to return, the rest of
-    ``dur_s`` the wait for the device — jax dispatch is async, so an honest
-    duration needs the block, and the block costs pipelining, which is why
-    it happens ONLY when telemetry is on.  The first call of a program shape
-    is labelled ``compile`` with the would-have-been kind as ``phase``.
-    """
-    if not _tele.enabled():
-        return _tele.span(kind)
-    return _live_phase(kind, dict(attrs) if attrs else {}, program)
 
 
 def _carry_devices(carries) -> int:
@@ -548,9 +441,7 @@ def _run_segmented(
     and the dataset uploads once — so the only host↔device traffic per
     segment is one tiny index array.  Whatever the first ``train_pop`` does
     not need (cost calibration, validation indices, later folds' index
-    arrays) runs after it is dispatched, while the device is busy.  This is
-    the default executor; the fused single-program path remains available via
-    ``fold_parallel=True``.
+    arrays) runs after it is dispatched, while the device is busy.
     """
     init_pop, train_pop, eval_pop = _fold_segment_fns(
         *_static_key(cfg, batch_size, n_train, n_val_padded, eval_batch_size)
@@ -565,12 +456,12 @@ def _run_segmented(
     mesh_sizes = list(mesh_axis_sizes(mesh))
     accs = []
     for f in range(kfold):
-        with _phase("fold_slice", {"fold": f}):
+        with phase("fold_slice", {"fold": f}):
             p, rng_f = carries[f]
             opt = init_pop(p)
         for s, e in bounds:
             seg = _put(batch_idx[f, s:e], batch_s)
-            with _phase(
+            with phase(
                 "train", {"steps": e - s, "pop": pop_dim, "fold": f, "mesh": mesh_sizes},
                 program=(id(train_pop), e - s, pop_dim, kfold),
             ) as sp:
@@ -585,7 +476,7 @@ def _run_segmented(
         # prepares fold f+1.  jax dispatch is async, so appending the device
         # array keeps the execution queue full across folds; params/opt
         # buffers still die at loop end (acc is tiny).
-        with _phase("eval", {"pop": pop_dim, "fold": f},
+        with phase("eval", {"pop": pop_dim, "fold": f},
                     program=(id(eval_pop), pop_dim, kfold)) as sp:
             accs.append(sp.fence(eval_pop(p, masks, x_full, y_full, vi, vw)))
         if f == 0 and warm_keys is not None:
@@ -597,120 +488,23 @@ def _run_segmented(
     # fetch = np.asarray single-process; an all-gather of the pop-sharded
     # accuracies when the mesh spans processes (every host gets the full
     # vector, keeping the SPMD ranks in lockstep).
-    with _phase("fetch"):
+    with phase("fetch"):
         return np.stack([fetch(a).astype(np.float32) for a in accs])
 
 
 def _init_slot(model: MaskedGeneticCnn, input_shape: Tuple[int, ...], key, masks):
     """One slot's fresh parameters.  ``model.init`` runs a full forward pass;
     unjitted it dispatches op by op (3+ seconds per generation measured on
-    the chip in July 2026), so it is only ever traced: by :func:`_init_fn`
-    and by :func:`_carry_fn`."""
+    the chip in July 2026), so it is only ever traced, by :func:`_carry_fn`."""
     dummy = jnp.zeros((1, *input_shape), dtype=jnp.float32)
     return model.init({"params": key}, dummy, masks, train=False)["params"]
 
 
-@functools.lru_cache(maxsize=32)
-def _init_fn(model: MaskedGeneticCnn, input_shape: Tuple[int, ...]):
-    """Jitted (fold × pop)-vmapped parameter init for one module config: the
-    STACKED ``(kfold, P, ...)`` tree the fused ``fold_parallel`` executor
-    trains from (the segmented executor starts from :func:`_carry_fn`).
-
-    The jitted callable is cached per (module, input_shape) — flax modules
-    are frozen dataclasses, so they hash by config — and jax re-specialises
-    it per (kfold, pop) shape automatically.
-    """
-    over_pop = jax.vmap(functools.partial(_init_slot, model, input_shape), in_axes=(0, 0))
-    return jax.jit(jax.vmap(over_pop, in_axes=(0, None)))
-
-
-def _genome_hashes(genomes: Sequence[Mapping[str, Any]]) -> np.ndarray:
-    """Stable per-genome 64-bit content hash, shape (n, 2) uint32, for PRNG keys.
-
-    Folding each population slot's keys from the genome CONTENT instead of
-    the slot index makes fitness a pure function of (architecture, config,
-    seed): invariant to batch composition, slot order, compile-bucket
-    padding, and OOM chunking (``_chunked_by_cap``).  Without this, an
-    architecture trained speculatively (``Population.speculative_fill``) or
-    in a split chunk draws different init/dropout streams than the same
-    architecture trained in its own generation's batch, so the cached
-    fitness silently steers later selections — measured as a diverged
-    search in the round-5 tailgen study.  (Cross-shape XLA recompilation
-    can still reorder float reductions, but per-slot math is slot-local;
-    in practice fitnesses now match bit-for-bit across batch shapes —
-    asserted by ``tests/test_cnn_model.py::TestBatchCompositionPurity``.)
-
-    blake2b(digest_size=8) rather than CRC32: two distinct architectures
-    colliding share init/dropout streams, and a 31-bit space makes that
-    a ~2% event at 10k genomes (birthday bound).  The 64-bit digest is
-    split into (hi, lo) uint32 words, each folded into the key separately
-    (``_content_keys``), pushing collisions to ~3e-12 at the same scale.
-    Widening the hash changes every measured fitness value, hence
-    ``FITNESS_PROTOCOL`` 3 (utils/fitness_store.py).
-    """
-    out = np.empty((len(genomes), 2), dtype=np.uint32)
-    for i, g in enumerate(genomes):
-        h = hashlib.blake2b(digest_size=8)
-        for k in sorted(g):
-            arr = np.asarray(g[k])
-            arr = arr.astype(np.int64) if arr.dtype.kind in "biu" else arr.astype(np.float64)
-            h.update(str(k).encode())
-            h.update(str(arr.shape).encode())
-            h.update(np.ascontiguousarray(arr).tobytes())
-        digest = int.from_bytes(h.digest(), "little")
-        out[i, 0] = digest >> 32  # hi word
-        out[i, 1] = digest & 0xFFFFFFFF  # lo word
-    return out
-
-
-def _fold_content_keys(base_key, f: int, genome_hashes) -> jnp.ndarray:
-    """(P, 2) PRNG keys of fold ``f``: the fold index, then the 64-bit genome
-    content hash — as two uint32 words — folded into ``base_key``."""
-    k = jax.random.fold_in(base_key, f)
-    return jax.vmap(lambda hh: jax.random.fold_in(jax.random.fold_in(k, hh[0]), hh[1]))(genome_hashes)
-
-
-def _content_keys(base_key, kfold: int, genome_hashes) -> jnp.ndarray:
-    """(kfold, P, 2) PRNG keys, :func:`_fold_content_keys` stacked over the
-    folds.  Eager, a few dispatches a fold: the fused executor's input and
-    the tests' oracle; the segmented executor derives the same keys inside
-    :func:`_carry_fn`'s one program."""
-    h = jnp.asarray(genome_hashes)  # (P, 2) uint32
-    return jnp.stack([_fold_content_keys(base_key, f, h) for f in range(kfold)])
-
-
-#: Domain constants for PRNG stream separation.  _INIT_DOMAIN keeps
-#: parameter-init streams disjoint from train (dropout) streams under one
-#: seed; _HOLDOUT_DOMAIN keeps train_and_score's streams disjoint from CV
-#: fold 0's (same formula, kfold=1 → fold index 0) so a holdout training
-#: under the search's own seed can never bit-replicate the CV training it
-#: is supposed to independently check.
-_INIT_DOMAIN = 0x1217
+#: Keeps train_and_score's streams disjoint from CV fold 0's (same formula,
+#: kfold=1 → fold index 0) so a holdout training under the search's own seed
+#: can never bit-replicate the CV training it is supposed to independently
+#: check.
 _HOLDOUT_DOMAIN = 0x5C04E
-
-
-def _base_keys(seed: int, domain: int = 0):
-    """``(init, train)`` base PRNG keys of one evaluation: the two streams'
-    roots under ``seed``, both moved into ``domain`` when it is non-zero."""
-    train = jax.random.PRNGKey(seed)
-    init = jax.random.fold_in(train, _INIT_DOMAIN)
-    if domain:
-        init, train = jax.random.fold_in(init, domain), jax.random.fold_in(train, domain)
-    return init, train
-
-
-def _init_population_params(model: MaskedGeneticCnn, masks_stacked, input_shape, pop_size, kfold, seed, genome_hashes, domain=0):
-    """Per-(fold, individual) parameter init → shapes carry a (kfold, P) prefix.
-
-    Each fold trains from an independent init (seed folded per fold),
-    matching the reference's fresh model per CV fold; each slot's key is
-    folded from the genome content (``_genome_hashes``), so an
-    architecture's init is independent of where in which batch it trains.
-    ``domain`` separates callers (train_and_score vs CV) that would
-    otherwise replicate each other's fold-0 streams under one seed.
-    """
-    keys = _content_keys(_base_keys(seed, domain)[0], kfold, genome_hashes)
-    return _init_fn(model, tuple(input_shape))(keys, masks_stacked)
 
 
 @functools.lru_cache(maxsize=32)
@@ -719,22 +513,24 @@ def _carry_fn(model: MaskedGeneticCnn, input_shape: Tuple[int, ...], kfold: int,
 
     ``build(init_base, train_base, hashes, masks)`` returns a list of
     ``kfold`` pairs ``(params, rng)`` with a leading population axis: the
-    key derivation of :func:`_fold_content_keys` for both streams, the
-    per-(fold, slot) ``model.init`` and the split by fold all happen inside
-    it, so a call pays one dispatch where it paid about a hundred eager ones
-    at the flagship's 34 leaves (two key chains, then one slice per parameter
-    leaf per fold).  Same integer arithmetic and initialisers as
-    :func:`_init_population_params` / :func:`_content_keys`: bit-identical
-    on CPU (``tests/test_carry_builder.py``).  Cached like :func:`_init_fn`,
-    per (module, input shape, kfold, the mesh's ``pop`` sharding or None);
-    jax re-specialises it per population width.
+    key derivation of :func:`~.evaluation.fold_content_keys` for both
+    streams, the per-(fold, slot) ``model.init`` and the split by fold all
+    happen inside it, so a call pays one dispatch where eager code paid about
+    a hundred at the flagship's 34 leaves (two key chains, then one slice per
+    parameter leaf per fold).  Each fold trains from an independent init
+    (seed folded per fold), matching the reference's fresh model per CV
+    fold.  Bit-identical on CPU to the stacked eager derivation
+    (``tests/test_carry_builder.py`` keeps it as its oracle).  Cached per
+    (module — flax modules are frozen dataclasses, so they hash by config —
+    input shape, kfold, the mesh's ``pop`` sharding or None); jax
+    re-specialises it per population width.
     """
     init_pop_slots = jax.vmap(functools.partial(_init_slot, model, input_shape))
 
     def build(init_base, train_base, hashes, masks):
         return [
-            (init_pop_slots(_fold_content_keys(init_base, f, hashes), masks),
-             _fold_content_keys(train_base, f, hashes))
+            (init_pop_slots(fold_content_keys(init_base, f, hashes), masks),
+             fold_content_keys(train_base, f, hashes))
             for f in range(kfold)
         ]
 
@@ -745,11 +541,11 @@ def _fold_carries(cfg: Dict[str, Any], model: MaskedGeneticCnn, stacked, hashes,
     """``(masks, carries)`` of the segmented executor: the stacked masks on the
     device (``pop``-sharded on a mesh) and :func:`_carry_fn`'s per-fold
     ``(params, rng)`` — born with the ``pop`` sharding, so nothing is
-    re-placed.  ``domain`` separates callers (train_and_score vs CV) as in
-    :func:`_init_population_params`; the base keys are four tiny cached
-    programs, all that is left of the eager head."""
+    re-placed.  ``domain`` separates callers (train_and_score vs CV) that
+    would otherwise replicate each other's fold-0 streams under one seed; the
+    base keys are four tiny cached programs."""
     pop_s, _, repl = _mesh_shardings(mesh)
-    init_base, train_base = _base_keys(cfg["seed"], domain)
+    init_base, train_base = base_keys(cfg["seed"], domain)
     masks = stacked
     if mesh is not None:
         masks = place_tree(stacked, pop_s)
@@ -760,7 +556,7 @@ def _fold_carries(cfg: Dict[str, Any], model: MaskedGeneticCnn, stacked, hashes,
 
 #: Parent→child weight bank for multi-fidelity warm starts (``warm_start``
 #: knob; DISTRIBUTED.md "Multi-fidelity evolution").  Keyed by the 64-bit
-#: genome content hash (both ``_genome_hashes`` words), so a promoted
+#: genome content hash (both ``genome_hashes`` words), so a promoted
 #: genome finds exactly ITS lower-rung parameters — never a sibling's —
 #: regardless of batch composition or slot order.  Values are host-numpy
 #: single-slot param trees (the trained fold-0 carry), insertion-ordered
@@ -879,7 +675,7 @@ def _device_dataset(key_x, key_y, xp: np.ndarray, yp: np.ndarray, perm: np.ndarr
     LRU one-at-a-time, so the hot dataset survives a fifth dataset showing
     up; dead-referent entries are dropped eagerly.
     """
-    with _phase("dataset") as sp:
+    with phase("dataset") as sp:
         xd, yd, found = _dataset_lookup(key_x, key_y, xp, yp, perm, cfg, mesh)
         sp.set(source="found" if found else "uploaded")
         return xd, yd
@@ -960,7 +756,6 @@ def _oom_cap_key(cfg: Dict[str, Any]):
         str(cfg["compute_dtype"]),
         tuple(cfg["input_shape"]),
         int(cfg["n_classes"]),
-        bool(cfg["fold_parallel"]),
         cfg["segment_steps"],
         int(cfg["kfold"]) if cfg.get("kfold") else None,
         int(cfg.get("microbatch", 1) or 1),
@@ -1149,8 +944,7 @@ def _record_cost_calibration(cfg: Dict[str, Any], params, n_slots: int) -> None:
       for one full batch);
     - ``measured_param_bytes``: per-genome-slot bytes of the parameter tree
       × 3 (params + momentum + grads, the same convention the prediction
-      uses), leaves divided by the ``n_slots`` of their stacking prefix
-      (``P`` for one fold's tree, ``kfold·P`` for the fused executor's);
+      uses), leaves divided by the ``n_slots`` (``P``) of one fold's tree;
     - ``device_bytes_in_use``: the backend allocator's own number when it
       has one (TPU/GPU ``memory_stats``; absent on CPU) — the largest over
       the local devices, since the fullest device is the one that OOMs.
@@ -1206,37 +1000,13 @@ _LAST_MESH_SHAPE: Optional[Tuple[int, int]] = None
 
 
 def _prepare_population_setup(cfg: Dict[str, Any], genomes: Sequence[Mapping[str, Any]]):
-    """Shared entry-point setup: enable the persistent compilation cache,
-    resolve the mesh, pad the population to the compile-shape bucket and
+    """Shared entry-point setup: the evaluation prelude (compile cache,
+    publish hooks), resolve the mesh, pad the population to the compile-shape bucket and
     the pop-axis size, stack genome masks, and build the module.  One
     definition for both ``cross_validate_population`` and
     ``train_and_score``.
     """
-    # Persistent XLA compilation cache: a resumed/restarted search reuses
-    # the compiled program from disk (SURVEY.md §7 hard part #1).  ON by
-    # default; cache_dir=False (or "off"/"0"/"none") is the programmatic
-    # opt-out — None means "use the default" (utils/xla_cache.py).  A
-    # JAX_COMPILATION_CACHE_DIR in the environment beats any path given
-    # here: enable_compilation_cache never re-points a cache placed from
-    # outside.
-    cache_dir = cfg["cache_dir"]
-    if cache_dir is None:
-        cache_dir = default_cache_dir()
-    elif cache_dir is False or str(cache_dir).strip().lower() in ("", "0", "off", "none", "disabled"):
-        cache_dir = None
-    if cache_dir:
-        enable_compilation_cache(cache_dir)
-    # Fleet-wide compile cache (distributed/compile_service.py): a worker
-    # with a compile-cache client registered a hook here; this announces
-    # "the previous evaluation may have been a first compile — scan and
-    # publish what it wrote".  With no hooks (the default) this is one
-    # empty-list iteration.
-    run_publish_hooks()
-
-    # Everything below touches devices (auto_mesh → jax.devices()); record
-    # that publicly so the GA's per-chip metric can consult device counts
-    # without ever being the thing that forces backend init (utils/jax_state).
-    mark_backend_used()
+    evaluation_prelude(cfg["cache_dir"])
 
     # Multi-chip: shard the population axis over the mesh (and the train
     # batch over its data axis).  Pad so the pop axis divides evenly;
@@ -1286,7 +1056,7 @@ def _prepare_population_setup(cfg: Dict[str, Any], genomes: Sequence[Mapping[str
         compute_dtype=jnp.dtype(cfg["compute_dtype"]),
         stage_exit_conv=bool(cfg["stage_exit_conv"]),
     )
-    return mesh, genomes, n_real, len(genomes), stacked, model, _genome_hashes(genomes)
+    return mesh, genomes, n_real, len(genomes), stacked, model, genome_hashes(genomes)
 
 
 class GeneticCnnModel(GentunModel):
@@ -1306,9 +1076,8 @@ class GeneticCnnModel(GentunModel):
       ``compute_dtype='bfloat16'``; ``seed=0``.
 
     Execution knobs (rebuild-specific): ``segment_steps=96`` bounds each
-    device call in the default segmented executor (None = one call per
-    fold); ``fold_parallel=True`` switches to the fused single-program
-    vmap-folds path; ``stage_exit_conv`` adds the Xie & Yuille output-node
+    device call of the segmented executor (None = one call per fold);
+    ``stage_exit_conv`` adds the Xie & Yuille output-node
     conv — measured at the full schedule on two workloads, the bare-sum
     default matched or beat it on CV and holdout accuracy, so False stays
     the default (docs/STAGE_EXIT_CONV.md has the table); ``mesh``/
@@ -1348,12 +1117,10 @@ class GeneticCnnModel(GentunModel):
         seed: int = 0,
         mesh="auto",
         cache_dir: Optional[str] = None,
-        fold_parallel: bool = False,
         stage_exit_conv: bool = False,
         segment_steps: Optional[int] = 96,
         pop_padding: bool = True,
         fitness_reps: int = 1,
-        entry_channel_pad: Optional[int] = None,
         device_budget: Optional[int] = None,
         microbatch: int = 1,
     ):
@@ -1375,12 +1142,10 @@ class GeneticCnnModel(GentunModel):
             seed=int(seed),
             mesh=mesh,
             cache_dir=cache_dir,
-            fold_parallel=bool(fold_parallel),
             stage_exit_conv=bool(stage_exit_conv),
             segment_steps=segment_steps,
             pop_padding=bool(pop_padding),
             fitness_reps=int(fitness_reps),
-            entry_channel_pad=entry_channel_pad,
             device_budget=device_budget,
             microbatch=int(microbatch),
         )
@@ -1471,8 +1236,8 @@ class GeneticCnnModel(GentunModel):
         """One chunk of a population through k-fold CV.  With telemetry on,
         one ``cv_call`` span whose children name every host phase of the
         call, in order (docs/OBSERVABILITY.md)."""
-        with _phase("cv_call", {"n_real": len(genomes)}) as call:
-            with _phase("prepare"):
+        with phase("cv_call", {"n_real": len(genomes)}) as call:
+            with phase("prepare"):
                 cfg = _normalize_config(x_train, y_train, config)
                 x, y = _prepare_data(x_train, y_train, cfg)
                 if len(genomes) == 0:
@@ -1487,7 +1252,7 @@ class GeneticCnnModel(GentunModel):
             fold_size = n // kfold
             if fold_size == 0:
                 raise ValueError(f"kfold={kfold} exceeds dataset size {n}")
-            with _phase("index_build"):
+            with phase("index_build"):
                 n_use = fold_size * kfold  # equal folds → one compiled shape
                 rng = np.random.default_rng(cfg["seed"])
                 perm = rng.permutation(n)[:n_use]
@@ -1521,71 +1286,30 @@ class GeneticCnnModel(GentunModel):
                         [np.ones(fold_size, np.float32), np.zeros(pad, np.float32)]
                     )
 
-            fused = cfg["fold_parallel"]
-            with _phase("init_params"):
-                if fused:
-                    params = _init_population_params(
-                        model, stacked, cfg["input_shape"], pop, kfold, cfg["seed"], hashes
-                    )
-                    _record_cost_calibration(cfg, params, kfold * pop)
-                    fold_keys = _content_keys(jax.random.PRNGKey(cfg["seed"]), kfold, hashes)
-                else:
-                    masks, carries = _fold_carries(cfg, model, stacked, hashes, kfold, mesh)
-                    # Parent→child weight inheritance (multi-fidelity ladder): overlay
-                    # each slot's own lower-rung trained params where shapes match, and
-                    # bank fold-0 results for the NEXT rung.  Segmented single-process
-                    # path only: the fused fold_parallel program has no per-fold host
-                    # boundary to deposit at, and on a multi-process mesh the gather
-                    # would stall every rank for a process-local cache — both fall back
-                    # to cold starts, which is always correct (pure speedup).
-                    warm = cfg["warm_start"] and mesh is None
-                    if warm:
-                        carries, warmed = _warm_start_overlay(carries, hashes[:n_real])
-                        if warmed:
-                            logger.debug("warm start: %d/%d slots inherited banked params",
-                                         warmed, n_real)
+            with phase("init_params"):
+                masks, carries = _fold_carries(cfg, model, stacked, hashes, kfold, mesh)
+                # Parent→child weight inheritance (multi-fidelity ladder): overlay
+                # each slot's own lower-rung trained params where shapes match, and
+                # bank fold-0 results for the NEXT rung.  Single-process only: on a
+                # multi-process mesh the gather would stall every rank for a
+                # process-local cache, so it falls back to cold starts, which is
+                # always correct (pure speedup).
+                warm = cfg["warm_start"] and mesh is None
+                if warm:
+                    carries, warmed = _warm_start_overlay(carries, hashes[:n_real])
+                    if warmed:
+                        logger.debug("warm start: %d/%d slots inherited banked params",
+                                     warmed, n_real)
 
             x_dev, y_dev = _device_dataset(x_train, y_train, x, y, perm, cfg, mesh)
 
-            if not fused:
-                accs = _run_segmented(
-                    cfg, masks, carries, x_dev, y_dev,
-                    val_idx, val_weight, batch_idx, mesh, batch_size, n_tr,
-                    n_val_padded, eval_bs,
-                    warm_keys=hashes[:n_real] if warm else None,
-                )
-                return accs.mean(axis=0)[:n_real]
-
-            fn = _population_cv_fn(*_static_key(cfg, batch_size, n_tr, n_val_padded, eval_bs))
-            arrays = dict(
-                x_full=x_dev,
-                y_full=y_dev,
-                val_idx=jnp.asarray(val_idx),
-                val_weight=jnp.asarray(val_weight),
-                batch_idx=jnp.asarray(batch_idx),
+            accs = _run_segmented(
+                cfg, masks, carries, x_dev, y_dev,
+                val_idx, val_weight, batch_idx, mesh, batch_size, n_tr,
+                n_val_padded, eval_bs,
+                warm_keys=hashes[:n_real] if warm else None,
             )
-            masks = stacked
-            if mesh is not None:
-                params, masks, fold_keys, arrays = shard_cv_args(
-                    mesh, params, stacked, fold_keys, arrays
-                )
-            # Fused executor: train + eval are ONE program, so the split
-            # collapses to a single span (`compile` on the first shape).
-            with _phase("train", {"fused": True, "pop": pop, "kfold": kfold},
-                        program=(id(fn), pop, kfold)) as sp:
-                acc = sp.fence(fn(
-                    params,
-                    masks,
-                    arrays["x_full"],
-                    arrays["y_full"],
-                    arrays["val_idx"],
-                    arrays["val_weight"],
-                    arrays["batch_idx"],
-                    fold_keys,
-                ))
-            with _phase("fetch"):
-                return fetch(acc).astype(np.float32).mean(axis=0)[:n_real]
-
+            return accs.mean(axis=0)[:n_real]
 
     # -- final holdout evaluation (not part of the reference's API) --------
 
@@ -1655,8 +1379,6 @@ class GeneticCnnModel(GentunModel):
         expressed as a single "fold" whose train indices cover the train
         block and whose val indices cover the test block of one
         device-resident concatenated array.  Returns P test accuracies.
-        Always runs the segmented executor (``fold_parallel`` is a CV-only
-        knob — with one fold there is nothing to fuse over).
         """
         cfg = _normalize_config(x_train, y_train, config)
         x_tr, y_tr = _prepare_data(x_train, y_train, cfg)
@@ -1718,12 +1440,10 @@ def _normalize_config(x_train, y_train, config: Dict[str, Any]) -> Dict[str, Any
         seed=0,
         mesh="auto",
         cache_dir=None,
-        fold_parallel=False,
         stage_exit_conv=False,
         segment_steps=96,
         pop_padding=True,
         fitness_reps=1,
-        entry_channel_pad=None,
         warm_start=False,
         device_budget=None,
         microbatch=1,
@@ -1755,10 +1475,6 @@ def _normalize_config(x_train, y_train, config: Dict[str, Any]) -> Dict[str, Any
     cfg["microbatch"] = 1 if cfg["microbatch"] is None else int(cfg["microbatch"])
     if cfg["microbatch"] < 1:
         raise ValueError("microbatch must be a positive int")
-    if cfg["entry_channel_pad"] is not None:
-        cfg["entry_channel_pad"] = int(cfg["entry_channel_pad"])
-        if cfg["entry_channel_pad"] < 1:
-            raise ValueError("entry_channel_pad must be a positive int or None")
     x = np.asarray(x_train)
     if cfg["input_shape"] is None:
         if x.ndim == 4:
@@ -1772,16 +1488,6 @@ def _normalize_config(x_train, y_train, config: Dict[str, Any]) -> Dict[str, Any
             )
     else:
         cfg["input_shape"] = tuple(int(d) for d in cfg["input_shape"])
-    # Optional MXU-friendly entry padding (VERDICT r4 item 5): zero-pad the
-    # input CHANNEL dim up to entry_channel_pad at data-prep level.  The
-    # extra channels are all-zero, so they contribute nothing to the entry
-    # conv's outputs — numerically an identity on the computation, but the
-    # (3,3,C_in,F) kernel lands on lane-aligned shapes.  raw_input_shape
-    # keeps the pre-pad shape for flat-input reshaping.
-    cfg["raw_input_shape"] = cfg["input_shape"]
-    if cfg["entry_channel_pad"] and cfg["entry_channel_pad"] > cfg["input_shape"][-1]:
-        h_, w_ = cfg["input_shape"][0], cfg["input_shape"][1]
-        cfg["input_shape"] = (h_, w_, cfg["entry_channel_pad"])
     if cfg["n_classes"] is None:
         cfg["n_classes"] = int(np.max(np.asarray(y_train))) + 1
     cfg["n_classes"] = int(cfg["n_classes"])
@@ -1789,20 +1495,10 @@ def _normalize_config(x_train, y_train, config: Dict[str, Any]) -> Dict[str, Any
 
 
 def _prepare_data(x_train, y_train, cfg: Dict[str, Any]):
-    """float32 NHWC images + int32 labels, reshaping flat inputs if needed.
-
-    Applies the entry_channel_pad zero-padding (channels only) so every
-    consumer — CV, train_and_score, the device-resident dataset cache —
-    sees the padded shape consistently.
-    """
+    """float32 NHWC images + int32 labels, reshaping flat inputs if needed."""
     x = np.asarray(x_train, dtype=np.float32)
     if x.ndim != 4:
-        x = x.reshape((x.shape[0], *cfg.get("raw_input_shape", cfg["input_shape"])))
-    target_c = cfg["input_shape"][-1]
-    if x.shape[-1] < target_c:
-        x = np.concatenate(
-            [x, np.zeros((*x.shape[:-1], target_c - x.shape[-1]), np.float32)], axis=-1
-        )
+        x = x.reshape((x.shape[0], *cfg["input_shape"]))
     y = np.asarray(y_train, dtype=np.int32)
     if x.shape[0] != y.shape[0]:
         raise ValueError(f"x/y length mismatch: {x.shape[0]} vs {y.shape[0]}")

@@ -43,7 +43,7 @@ What differs from the CNN family, by design:
   a count of bytes before anything compiles, not from a failed attempt.
 - **Fitness is a negative validation loss** (higher is better, like an
   accuracy), a pure function of genome, configuration and seed: the state is
-  built from the genome's content hash (``cnn._genome_hashes``), the batches
+  built from the genome's content hash (``evaluation.genome_hashes``), the batches
   from the seed.
 
 Parameters are float32, compute is bfloat16 (router, norms, softmax, logits
@@ -66,9 +66,7 @@ import jax.numpy as jnp
 
 from ..telemetry import spans as _tele
 from ..telemetry.registry import get_registry as _get_registry
-from ..utils.jax_state import mark_backend_used
-from ..utils.xla_cache import default_cache_dir, enable_compilation_cache
-from .cnn import _base_keys, _genome_hashes, _phase
+from .evaluation import base_keys, evaluation_prelude, genome_hashes, phase
 from .generic import GentunModel
 
 __all__ = ["Lfm2MoeModel", "Lfm2MoeConfig", "Lfm2MoePrograms", "GENE_NAMES", "training_bytes"]
@@ -605,20 +603,16 @@ class Lfm2MoeModel(GentunModel):
         ``eval`` and ``fetch`` (docs/OBSERVABILITY.md); off, the only wait is
         the fetch of each individual's losses, which is also what frees its
         state before the next one's is built."""
-        with _phase("cv_call", {"n_real": len(genomes), "pop": PROGRAM_WIDTH}):
-            with _phase("prepare"):
+        with phase("cv_call", {"n_real": len(genomes), "pop": PROGRAM_WIDTH}):
+            with phase("prepare"):
                 cfg, seed, cache_dir = _normalize_config(x_train, config)
                 if len(genomes) == 0:
                     return np.zeros((0,), np.float32)
-                if cache_dir is None:
-                    cache_dir = default_cache_dir()
-                if cache_dir and str(cache_dir).strip().lower() not in ("0", "off", "none", "disabled"):
-                    enable_compilation_cache(cache_dir)
-                mark_backend_used()
+                evaluation_prelude(cache_dir)
                 _require_fit(cfg)
                 programs = _programs(cfg)
-                hashes = _genome_hashes(genomes)
-                init_base, _ = _base_keys(seed)
+                hashes = genome_hashes(genomes)
+                init_base, _ = base_keys(seed)
                 train_rows, val_rows = batch_plan(cfg, seed)
                 x, y = jnp.asarray(x_train, jnp.int32), jnp.asarray(y_train, jnp.int32)
                 train_rows, val_rows = jnp.asarray(train_rows), [jnp.asarray(r) for r in val_rows]
@@ -636,19 +630,19 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     the held-out batches, one fetch."""
     cfg = programs.config
     shape = (cfg.tokens_per_step, cfg.train_steps)
-    with _phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
+    with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)
-    with _phase("train", {"steps": cfg.train_steps, "tokens": cfg.train_steps * cfg.tokens_per_step,
+    with phase("train", {"steps": cfg.train_steps, "tokens": cfg.train_steps * cfg.tokens_per_step,
                           "pop": PROGRAM_WIDTH, "individual": individual},
                 program=(id(programs.train_step), shape)) as sp:
         for step in steps:
             state, _, _ = programs.train_step(state, x, y, train_rows, genes, step)
         sp.fence(state)
-    with _phase("eval", {"individual": individual, "tokens": len(val_rows) * cfg.tokens_per_step},
+    with phase("eval", {"individual": individual, "tokens": len(val_rows) * cfg.tokens_per_step},
                 program=(id(programs.eval), shape)) as sp:
         losses = sp.fence([programs.eval(state["params"], state["bias"], x, y, rows) for rows in val_rows])
-    with _phase("fetch", {"individual": individual}) as sp:
+    with phase("fetch", {"individual": individual}) as sp:
         if _tele.enabled():
             losses, rows, dropped, wide = jax.device_get(
                 (losses, state["rows"], state["dropped"], state["wide_buffer"]))

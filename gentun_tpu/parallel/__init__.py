@@ -13,9 +13,9 @@ classification, ``mesh_factor``, ``host_worker_capacity`` — without
 dragging a backend into the process.
 """
 
-from .mesh import auto_mesh, mesh_axis_sizes, pad_population, shard_cv_args
+from .mesh import auto_mesh, mesh_axis_sizes, pad_population
 
-__all__ = ["auto_mesh", "mesh_axis_sizes", "pad_population", "shard_cv_args", "multihost"]
+__all__ = ["auto_mesh", "mesh_axis_sizes", "pad_population", "multihost"]
 
 
 def __getattr__(name):

@@ -19,9 +19,9 @@ Two named axes:
   collectives.
 
 No function here changes the compiled computation: multi-chip execution is
-driven purely by the shardings of the input arrays (``shard_cv_args``),
-which is what keeps the single-chip and 32-chip paths one and the same
-jitted program.
+driven purely by the shardings of the input arrays
+(``models/cnn.py::_fold_carries``, ``_run_segmented``), which is what keeps
+the single-chip and 32-chip paths one and the same jitted program.
 
 **Big-genome regime** (DISTRIBUTED.md "Big-genome regime"): the pure-math
 half of size-aware scheduling also lives here — a per-genome cost model
@@ -48,7 +48,6 @@ import numpy as np
 __all__ = [
     "auto_mesh",
     "pad_population",
-    "shard_cv_args",
     "mesh_axis_sizes",
     "mesh_factor",
     "pop_bucket",
@@ -128,7 +127,7 @@ def pop_bucket(n: int) -> int:
     The floor is 2, not 1: XLA compiles a singleton population axis to a
     different program (the vmap axis collapses) whose float rounding can
     flip a prediction vs the same genome trained in a wider batch —
-    breaking the batch-composition purity that ``_genome_hashes`` buys
+    breaking the batch-composition purity that ``genome_hashes`` buys
     (measured: one-sample accuracy flip at pop=1 on CPU).  Bucket 2 keeps
     every padded batch on the same multi-slot program family.
 
@@ -516,52 +515,3 @@ def pad_population(genomes: Sequence[Any], multiple: int) -> Tuple[List[Any], in
         return list(genomes), n
     padded = list(genomes) + [genomes[-1]] * (multiple - n % multiple)
     return padded, n
-
-
-def shard_cv_args(
-    mesh: "Any",
-    params,
-    masks_stacked: List[Dict[str, Any]],
-    fold_keys,
-    arrays: Dict[str, Any],
-):
-    """Place the batched-CV inputs onto the mesh.
-
-    Array layouts after the fold-batched redesign (``models/cnn.py``): the
-    fold axis leads ``params (kfold, P, ...)``, ``fold_keys (kfold, P, 2)``,
-    ``batch_idx (kfold, steps, batch)``, ``val_idx``/``val_weight
-    (kfold, n_val_padded)``; masks keep their ``(P, ...)`` leading axis.
-
-    - ``params`` / ``fold_keys``: ``pop`` shards axis 1 (the population);
-      the fold axis and ``data`` are replicated;
-    - ``masks``: ``pop`` shards axis 0;
-    - ``batch_idx``: batch dim (last) over ``data`` — this is what makes
-      each training step data-parallel, because the gathers that consume
-      these indices inherit the sharding and the loss/grad reduce over the
-      batch becomes an ICI all-reduce;
-    - the dataset and val index/weight arrays: replicated.  Workers own
-      their whole data shard by design (SURVEY.md §1), so replication here
-      is within one worker's slice only.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from .multihost import place, place_tree
-
-    pop_spec = NamedSharding(mesh, P("pop"))
-    fold_pop_spec = NamedSharding(mesh, P(None, "pop"))
-    repl = NamedSharding(mesh, P())
-    batch_spec = NamedSharding(mesh, P(None, None, "data"))
-
-    # place/place_tree = device_put single-process; the multi-controller
-    # make_array path when the mesh spans several hosts (multihost.py).
-    params = place_tree(params, fold_pop_spec)
-    masks_stacked = [
-        {k: place(v, pop_spec) for k, v in stage.items()}
-        for stage in masks_stacked
-    ]
-    fold_keys = place(fold_keys, fold_pop_spec)
-    out = dict(arrays)
-    for name in ("x_full", "y_full", "val_idx", "val_weight"):
-        out[name] = place(out[name], repl)
-    out["batch_idx"] = place(out["batch_idx"], batch_spec)
-    return params, masks_stacked, fold_keys, out

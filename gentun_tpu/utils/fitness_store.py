@@ -70,7 +70,7 @@ __all__ = [
 #: content-hash purity work exists to prevent).  History:
 #:   1 — per-slot PRNG keys (``split(PRNGKey(seed+f), pop)``), rounds 1-4:
 #:       fitness depended on batch slot/composition;
-#:   2 — content-hash keys (``models/cnn._genome_hashes``), round 5:
+#:   2 — content-hash keys (``models/evaluation.genome_hashes``), round 5:
 #:       fitness is a pure function of (architecture, config, seed);
 #:   3 — 64-bit content hashes (blake2b split across two fold_in calls),
 #:       round 6: init/dropout streams collision-free at 10k+ genomes.

@@ -579,7 +579,7 @@ def write_markdown(results: dict, out_md: str, args) -> None:
         lines += [
             "Protocol provenance: records span fitness RNG protocol(s) "
             f"{protos} (1 = per-slot keys, rounds 1-4; 2 = content-hash keys, "
-            "round 5 — `models/cnn.py::_genome_hashes`).  Both draw "
+            "round 5 — `models/evaluation.py::genome_hashes`).  Both draw "
             "init/dropout streams from identical distributions, and each "
             "seed's arms run under one protocol, so the paired statistics "
             "are unaffected in expectation; only individual draws differ.",

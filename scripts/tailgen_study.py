@@ -151,7 +151,7 @@ def main(argv=None) -> int:
         fits = {record["variants"][n]["best_fitness"] for n in names}
         if len(fits) > 1:
             spread = max(fits) - min(fits)
-            # Content-hash PRNG keys (models/cnn._genome_hashes) remove all
+            # Content-hash PRNG keys (models/evaluation.genome_hashes) remove all
             # systematic divergence; what can remain on TPU is a rare
             # validation-sample flip when speculation moves an architecture
             # to a different program SHAPE (XLA rounds differently).  A

@@ -1,22 +1,25 @@
 """PR 26: one compiled program builds every fold's starting carry.
 
 - ``_carry_fn``'s per-fold params and train keys are, bit for bit, what the
-  stacked derivation gives (``_init_population_params`` sliced by fold,
-  ``_content_keys``): CV and holdout domains, several widths, off and on a mesh;
+  stacked derivation gives (the eager code the builder replaced, kept here as
+  the oracle: :func:`stacked_params`, :func:`stacked_keys`): CV and holdout
+  domains, several widths, off and on a mesh;
 - the public entry points return the fitness the parent commit (a9ecb14)
-  returned for a seeded population: CV, holdout, a mesh, the fused executor and
-  warm starts.
+  returned for a seeded population: CV, holdout, a mesh and warm starts.
 (The guard against the eager head coming back counts programs between spans:
 ``tests/test_tracing_scopes.py``.)
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gentun_tpu.models import cnn
+from gentun_tpu.models import cnn, evaluation
 from gentun_tpu.models.cnn import GeneticCnnModel
 from gentun_tpu.ops.dag import stack_genome_masks
 
@@ -42,6 +45,21 @@ def data():
 # -- A. the builder against the stacked derivation ---------------------------------------
 
 
+def stacked_keys(base_key, kfold, hashes):
+    """(kfold, P, 2) PRNG keys, ``fold_content_keys`` stacked over the folds:
+    eager, a few dispatches a fold."""
+    h = jnp.asarray(hashes)
+    return jnp.stack([evaluation.fold_content_keys(base_key, f, h) for f in range(kfold)])
+
+
+def stacked_params(model, masks, kfold, seed, hashes, domain=0):
+    """Per-(fold, individual) parameter init with a (kfold, P) prefix: one
+    (fold x pop)-vmapped ``model.init`` over the stacked init keys."""
+    keys = stacked_keys(evaluation.base_keys(seed, domain)[0], kfold, hashes)
+    over_pop = jax.vmap(functools.partial(cnn._init_slot, model, SHAPE), in_axes=(0, 0))
+    return jax.jit(jax.vmap(over_pop, in_axes=(0, None)))(keys, masks)
+
+
 def leaves_equal(a, b):
     la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
     assert len(la) == len(lb) and jax.tree.structure(a) == jax.tree.structure(b)
@@ -62,13 +80,13 @@ def test_carries_are_the_stacked_derivation_bit_for_bit(kfold, domain, width, me
     cfg = {"seed": seed, "input_shape": SHAPE}
     model = cnn.MaskedGeneticCnn(nodes=NODES, filters=FILTERS, dense_units=12, n_classes=5)
     stacked = jax.device_put(stack_genome_masks(genomes, NODES))
-    hashes = cnn._genome_hashes(genomes)
+    hashes = evaluation.genome_hashes(genomes)
 
     masks, carries = cnn._fold_carries(cfg, model, stacked, hashes, kfold, mesh, domain=domain)
 
-    params = cnn._init_population_params(model, stacked, SHAPE, width, kfold, seed, hashes, domain=domain)
+    params = stacked_params(model, stacked, kfold, seed, hashes, domain=domain)
     base = jax.random.PRNGKey(seed)
-    keys = cnn._content_keys(jax.random.fold_in(base, domain) if domain else base, kfold, hashes)
+    keys = stacked_keys(jax.random.fold_in(base, domain) if domain else base, kfold, hashes)
     assert len(carries) == kfold
     for f, (p, rng) in enumerate(carries):
         leaves_equal(p, jax.tree.map(lambda a: a[f], params))
@@ -91,8 +109,6 @@ PARENT = {
     "cv_kfold3": (dict(mesh=None, kfold=3), 3, [0.3125, 0.4583333432674408, 0.3125]),
     "cv_mesh": (dict(mesh="auto"), 4, [0.21875, 0.3541666865348816, 0.34375, 0.2916666865348816]),
     "cv_kfold3_mesh": (dict(mesh="auto", kfold=3), 3, [0.2916666567325592, 0.5416666865348816, 0.3125]),
-    "fused": (dict(mesh=None, fold_parallel=True), 4,
-              [0.2395833432674408, 0.3229166865348816, 0.3333333134651184, 0.3125]),
 }
 
 

@@ -243,38 +243,6 @@ class TestFitnessReps:
         assert 0.0 <= accs[0] <= 1.0
 
 
-class TestEntryChannelPad:
-    """entry_channel_pad (VERDICT r4 item 5): zero-pad input channels at
-    data-prep level so the entry conv kernel lands on lane-aligned shapes;
-    all-zero channels contribute nothing to the conv outputs."""
-
-    def test_padded_run_learns_and_shapes_flow(self, separable_data):
-        x, y = separable_data  # 1-channel 8x8
-        accs = GeneticCnnModel.cross_validate_population(
-            x, y, [{"S_1": (1, 0, 1)}], entry_channel_pad=8, **FAST
-        )
-        assert accs.shape == (1,)
-        assert 0.4 < accs[0] <= 1.0
-
-    def test_flat_input_reshapes_with_raw_shape_then_pads(self, separable_data):
-        x, y = separable_data
-        flat = x.reshape(x.shape[0], -1)
-        m = GeneticCnnModel(
-            flat, y, {"S_1": (1, 0, 1)}, input_shape=(8, 8, 1),
-            entry_channel_pad=4, **{**FAST, "epochs": (4,)}
-        )
-        assert 0.4 < m.cross_validate() <= 1.0
-
-    def test_pad_no_op_when_channels_already_enough(self, separable_data):
-        from gentun_tpu.models.cnn import _normalize_config
-
-        x, y = separable_data
-        cfg = _normalize_config(x, y, dict(entry_channel_pad=1))
-        assert cfg["input_shape"] == (8, 8, 1)  # pad below C: unchanged
-        with pytest.raises(ValueError):
-            _normalize_config(x, y, dict(entry_channel_pad=0))
-
-
 class TestStageExitConv:
     """Optional Xie & Yuille output-node conv (ADVICE r1, cnn.py stage exit)."""
 
@@ -327,14 +295,11 @@ class TestTrainAndScore:
 class TestSegmentedExecution:
     """Default executor: host loop of bounded device calls (watchdog-safe)."""
 
-    def test_segmented_matches_fused_exactly(self, separable_data):
-        """Same schedule, same seeds: segmented (any segment size) and the
-        fused single-program path must produce identical accuracies."""
+    def test_segment_size_does_not_move_the_answer(self, separable_data):
+        """Same schedule, same seeds: one call per fold and two-step
+        segments must produce identical accuracies."""
         x, y = separable_data
         genomes = [{"S_1": (1, 0, 1)}, {"S_1": (1, 1, 1)}]
-        fused = GeneticCnnModel.cross_validate_population(
-            x, y, genomes, **{**FAST, "fold_parallel": True}
-        )
         seg_big = GeneticCnnModel.cross_validate_population(
             x, y, genomes, **{**FAST, "segment_steps": None}
         )
@@ -342,7 +307,6 @@ class TestSegmentedExecution:
             x, y, genomes, **{**FAST, "segment_steps": 2}
         )
         np.testing.assert_allclose(seg_big, seg_tiny, atol=1e-5)
-        np.testing.assert_allclose(fused, seg_big, atol=1e-4)
 
     def test_segment_bounds(self):
         from gentun_tpu.models.cnn import _segment_bounds
@@ -525,7 +489,7 @@ class TestOomChunking:
     def test_chunked_matches_manual_chunks_real_model(self, separable_data):
         """A capped run equals evaluating the same chunks directly — AND
         equals the unchunked run: PRNG keys are content-derived
-        (``_genome_hashes``), so chunking cannot move any fitness
+        (``genome_hashes``), so chunking cannot move any fitness
         (``TestBatchCompositionPurity``)."""
         from gentun_tpu.models import cnn as cnn_mod
         from gentun_tpu.models.cnn import GeneticCnnModel
@@ -555,7 +519,7 @@ class TestOomChunking:
 class TestBatchCompositionPurity:
     """Fitness is a pure function of (architecture, config, seed).
 
-    ``_genome_hashes`` folds each slot's PRNG keys from genome content, so
+    ``genome_hashes`` folds each slot's PRNG keys from genome content, so
     WHERE an architecture trains — slot index, batch composition,
     compile-bucket shape, alone or among others — cannot change its
     fitness.  This is the property the speculative-fill trajectory-identity
@@ -605,50 +569,49 @@ class TestBatchCompositionPurity:
         assert (packed[1], packed[3]) == (solo_b[0], solo_b[1])
 
     def test_hashes_are_content_not_position(self):
-        from gentun_tpu.models.cnn import _genome_hashes
+        from gentun_tpu.models.evaluation import genome_hashes
 
         g1 = {"S_1": (1, 0, 1), "S_2": (0, 1, 1, 0, 0, 1)}
         g2 = {"S_1": (0, 1, 1), "S_2": (0, 1, 1, 0, 0, 1)}
-        h = _genome_hashes([g1, g2, g1])
+        h = genome_hashes([g1, g2, g1])
         assert h.shape == (3, 2) and h.dtype == np.uint32  # 64 bits as two words
         assert tuple(h[0]) == tuple(h[2]) != tuple(h[1])
         # order of evaluation / position in the list is irrelevant
-        assert tuple(_genome_hashes([g2, g1])[1]) == tuple(h[0])
+        assert tuple(genome_hashes([g2, g1])[1]) == tuple(h[0])
 
     def test_key_stream_domains_are_separated(self):
         """Init, CV-train, and holdout streams must never collide for one
         (seed, genome) — without the domain folds, train_and_score under
         the search's own seed would replicate CV fold-0 bit-for-bit and
         correlate the holdout estimate with the CV estimate it checks.
-        Driven through the production constants and the production init
-        path, not re-derived folds."""
+        Driven through the production constants and the production carry
+        builder, not re-derived folds."""
         from gentun_tpu.models import cnn as cnn_mod
-        from gentun_tpu.models.cnn import (
-            MaskedGeneticCnn, _content_keys, _genome_hashes, _init_population_params,
-        )
+        from gentun_tpu.models import evaluation
+        from gentun_tpu.models.cnn import MaskedGeneticCnn
 
-        assert cnn_mod._INIT_DOMAIN and cnn_mod._HOLDOUT_DOMAIN and (
-            cnn_mod._INIT_DOMAIN != cnn_mod._HOLDOUT_DOMAIN
+        assert evaluation._INIT_DOMAIN and cnn_mod._HOLDOUT_DOMAIN and (
+            evaluation._INIT_DOMAIN != cnn_mod._HOLDOUT_DOMAIN
         )
-        base = jax.random.PRNGKey(0)
-        h = _genome_hashes([{"S_1": (1, 0, 1)}])
-        train = np.asarray(_content_keys(base, 1, h))  # CV train keys, fold 0
-        init = np.asarray(_content_keys(jax.random.fold_in(base, cnn_mod._INIT_DOMAIN), 1, h))
-        holdout = np.asarray(_content_keys(
-            jax.random.fold_in(base, cnn_mod._HOLDOUT_DOMAIN), 1, h))
-        assert not (train == init).all()
-        assert not (train == holdout).all()
-        assert not (init == holdout).all()
+        h = evaluation.genome_hashes([{"S_1": (1, 0, 1)}])
+        init_base, train_base = evaluation.base_keys(0)
+        ho_init_base, ho_train_base = evaluation.base_keys(0, cnn_mod._HOLDOUT_DOMAIN)
+        streams = [np.asarray(evaluation.fold_content_keys(base, 0, h))  # fold 0 of each stream
+                   for base in (train_base, init_base, ho_train_base, ho_init_base)]
+        for i, a in enumerate(streams):
+            for b in streams[i + 1:]:
+                assert not (a == b).all()
 
-        # and the init entry point honors domain=: CV-init params vs
-        # holdout-init params differ for the same (seed, genome)
+        # and the carry builder honors domain=: CV carries vs holdout
+        # carries differ for the same (seed, genome), params and train keys
         model = MaskedGeneticCnn(nodes=(3,), filters=(4,), dense_units=8,
                                  n_classes=2, compute_dtype=jnp.float32)
-        masks = [{k: v for k, v in stage.items()}
-                 for stage in stack_genome_masks([{"S_1": (1, 0, 1)}], (3,))]
-        cv_params = _init_population_params(model, masks, (8, 8, 1), 1, 1, 0, h)
-        ho_params = _init_population_params(model, masks, (8, 8, 1), 1, 1, 0, h,
-                                            domain=cnn_mod._HOLDOUT_DOMAIN)
+        masks = jax.device_put(stack_genome_masks([{"S_1": (1, 0, 1)}], (3,)))
+        cfg = {"seed": 0, "input_shape": (8, 8, 1)}
+        _, ((cv_params, cv_rng),) = cnn_mod._fold_carries(cfg, model, masks, h, 1, None)
+        _, ((ho_params, ho_rng),) = cnn_mod._fold_carries(
+            cfg, model, masks, h, 1, None, domain=cnn_mod._HOLDOUT_DOMAIN)
+        assert np.array_equal(cv_rng, streams[0]) and np.array_equal(ho_rng, streams[2])
         leaves_cv = jax.tree.leaves(cv_params)
         leaves_ho = jax.tree.leaves(ho_params)
         assert any(not np.array_equal(a, b) for a, b in zip(leaves_cv, leaves_ho))

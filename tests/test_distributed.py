@@ -689,14 +689,20 @@ class TestWorkerCli:
 
         from gentun_tpu import BoostingIndividual
 
+        job_timeout = 120.0
         with DistributedPopulation(
             BoostingIndividual, size=2, seed=9, port=0,
             additional_parameters={"kfold": 2},
-            job_timeout=120.0,
+            job_timeout=job_timeout,
         ) as pop:
             _, port = pop.broker_address
             repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-            env = dict(os.environ, PYTHONPATH=repo)
+            # One OpenMP thread for the child: HistGradientBoosting opens a
+            # team over every core at each of its many small loops, and where
+            # six test workers already hold the cores every barrier of that
+            # team waits on threads that are not running — two 6 s jobs then
+            # outlast the 120 s barrier (reproduced with six BLAS loops).
+            env = dict(os.environ, PYTHONPATH=repo, OMP_NUM_THREADS="1")
             proc = subprocess.Popen(
                 [sys.executable, "-m", "gentun_tpu.distributed.worker",
                  "--host", "127.0.0.1", "--port", str(port),
@@ -708,7 +714,7 @@ class TestWorkerCli:
                 pop.evaluate()
                 assert all(ind.fitness_evaluated for ind in pop)
                 assert all(0.0 <= ind.get_fitness() <= 1.0 for ind in pop)
-                assert proc.wait(timeout=30) == 0  # exited cleanly at --max-jobs
+                assert proc.wait(timeout=job_timeout) == 0  # exited cleanly at --max-jobs
             finally:
                 if proc.poll() is None:
                     proc.kill()
@@ -1084,7 +1090,7 @@ class TestDistributedFitnessPurity:
 
     The worker trains whatever job batch the broker hands it (capacity
     chunks, arrival order) — compositions the local ``evaluate()`` never
-    produces.  Content-hash PRNG keys (``models/cnn._genome_hashes``)
+    produces.  Content-hash PRNG keys (``models/evaluation.genome_hashes``)
     make fitness a pure function of (architecture, config, seed), so the
     transport layer cannot move a measurement."""
 

@@ -447,25 +447,49 @@ class TestWarmStartBank:
             x, y, [{"S_1": np.array([1, 0, 1])}], **self._cfg())
         assert not cnn_mod._WARM_BANK
 
-    def test_deposit_then_inherit_across_rungs(self):
+    def test_deposit_then_inherit_across_rungs(self, monkeypatch):
+        import jax
+
         from gentun_tpu.models import cnn as cnn_mod
         from gentun_tpu.models.cnn import GeneticCnnModel
 
+        overlays = []  # per call: (fresh params per fold, params the run started from, slots warmed)
+        real = cnn_mod._warm_start_overlay
+
+        def spy(carries, hashes):
+            started, warmed = real(carries, hashes)
+            # host copies: the train program is donated its carries
+            overlays.append(([jax.device_get(p) for p, _ in carries],
+                             [jax.device_get(p) for p, _ in started], warmed, started is carries))
+            return started, warmed
+
+        monkeypatch.setattr(cnn_mod, "_warm_start_overlay", spy)
         cnn_mod._WARM_BANK.clear()
         x, y = self._data()
         genomes = [{"S_1": np.array([1, 0, 1])}, {"S_1": np.array([0, 1, 1])}]
         GeneticCnnModel.cross_validate_population(
             x, y, genomes, **self._cfg(warm_start=True))
-        assert len(cnn_mod._WARM_BANK) == 2
-        # Promotion: same genomes at a longer schedule.  The warm run must
-        # differ from a cold-started identical run — the ONLY difference is
-        # the inherited starting point.
-        warm = GeneticCnnModel.cross_validate_population(
+        assert len(cnn_mod._WARM_BANK) == 2 and overlays[-1][2:] == (0, True)
+        banked = list(cnn_mod._WARM_BANK.values())  # the warm run deposits anew
+        # Promotion: same genomes at a longer schedule.  Every fold of the
+        # warm run starts from the lower rung's trained params, bit for bit,
+        # and those are not a fresh init (a masked-out node's leaves never
+        # got a gradient, hence `any`).  Two accuracies quantised to 1/32
+        # can tie, so the fitness is not what tells warm from cold.
+        GeneticCnnModel.cross_validate_population(
             x, y, genomes, **self._cfg(warm_start=True, epochs=(2,)))
+        fresh, started, warmed, untouched = overlays[-1]
+        assert warmed == 2 and len(started) == 2 and not untouched
+        for fresh_p, started_p in zip(fresh, started):
+            for slot, bank in enumerate(banked):
+                leaves = list(zip(*(jax.tree.leaves(t) for t in (fresh_p, started_p, bank))))
+                assert all(np.array_equal(s[slot], b) for _, s, b in leaves)
+                assert any(not np.array_equal(f[slot], b) for f, _, b in leaves)
+        # With the bank empty the identical call starts cold.
         cnn_mod._WARM_BANK.clear()
-        cold = GeneticCnnModel.cross_validate_population(
+        GeneticCnnModel.cross_validate_population(
             x, y, genomes, **self._cfg(warm_start=True, epochs=(2,)))
-        assert not np.allclose(warm, cold)
+        assert overlays[-1][2:] == (0, True)
 
     def test_overlay_skips_shape_mismatch(self):
         from gentun_tpu.models import cnn as cnn_mod

@@ -6,7 +6,7 @@ and the trace reader that turns both into seconds per op class.
   forward and backward;
 - scopes and spans are metadata: fitness is bit-identical with telemetry on,
   off, and to the values the parent commit gave;
-- off, ``_phase`` is the spans module's no-op singleton and nothing is
+- off, ``phase`` is the spans module's no-op singleton and nothing is
   recorded; on, one ``cv_call`` per chunk with its children in order,
   ``dispatch_s <= dur_s`` on every device span, an ``oom_attempt`` span when
   the healer splits; a handful of programs asked for before a fresh
@@ -33,7 +33,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import scope_reduce  # noqa: E402
 
-from gentun_tpu.models import cnn  # noqa: E402
+from gentun_tpu.models import cnn, evaluation  # noqa: E402
 from gentun_tpu.models.cnn import GeneticCnnModel  # noqa: E402
 from gentun_tpu.telemetry import spans  # noqa: E402
 
@@ -70,7 +70,7 @@ class Records:
 def telemetry():
     """Telemetry on with a sink of its own; everything back as it was after."""
     sink = Records()
-    seen = set(cnn._tele_seen_programs)
+    seen = set(evaluation._seen_programs)
     spans.set_run_sink(sink)
     spans.enable()
     try:
@@ -78,8 +78,8 @@ def telemetry():
     finally:
         spans.disable()
         spans.set_run_sink(None)
-        cnn._tele_seen_programs.clear()
-        cnn._tele_seen_programs.update(seen)
+        evaluation._seen_programs.clear()
+        evaluation._seen_programs.update(seen)
 
 
 def of_kind(sink, *kinds):
@@ -102,8 +102,8 @@ def op_names():
     genomes = GENOMES[:2]
     masks = [{k: jnp.asarray(v) for k, v in stage.items()} for stage in stack_genome_masks(genomes, NODES)]
     model = cnn.MaskedGeneticCnn(nodes=NODES, filters=FILTERS, dense_units=16, n_classes=10)
-    params = cnn._init_population_params(model, masks, (8, 8, 3), 2, 1, 0, cnn._genome_hashes(genomes))
-    p = jax.tree.map(lambda a: a[0], params)
+    _, ((p, _),) = cnn._fold_carries({"seed": 0, "input_shape": (8, 8, 3)}, model, masks,
+                                     evaluation.genome_hashes(genomes), 1, None)
     x, y = jnp.zeros((48, 8, 8, 3)), jnp.zeros((48,), jnp.int32)
     train = train_pop.lower(p, init_pop(p), masks, x, y, jnp.zeros((4, 8), jnp.int32),
                             jnp.zeros((2, 2), jnp.uint32)).compile().as_text()
@@ -163,8 +163,8 @@ def test_fitness_same_with_telemetry_on_off_and_as_the_parent(data, telemetry):
 def test_off_the_helper_is_the_noop_and_nothing_is_recorded(data):
     assert not spans.enabled()
     noop = spans.span("anything")
-    assert cnn._phase("train", {"pop": 2}, program=("never", "seen")) is noop
-    assert ("never", "seen") not in cnn._tele_seen_programs
+    assert evaluation.phase("train", {"pop": 2}, program=("never", "seen")) is noop
+    assert ("never", "seen") not in evaluation._seen_programs
     marker = object()
     assert noop.fence(marker) is marker
     sink = Records()
@@ -192,20 +192,16 @@ def test_one_cv_call_with_its_children_in_order(data, telemetry):
         "train", "eval", "compile", "fold_slice"}
 
 
-@pytest.mark.parametrize("fold_parallel", [False, True])
-def test_device_spans_split_dispatch_from_wait(data, telemetry, fold_parallel):
+def test_device_spans_split_dispatch_from_wait(data, telemetry):
     for _ in range(2):  # the first call of a shape is `compile`, the second `train`/`eval`
-        GeneticCnnModel.cross_validate_population(*data, GENOMES, **{**KW, "fold_parallel": fold_parallel})
+        GeneticCnnModel.cross_validate_population(*data, GENOMES, **KW)
     device = of_kind(telemetry, "train", "eval", "compile")
     assert {r["kind"] for r in device} >= {"compile", "train"}
-    assert len(device) == (2 if fold_parallel else 8)
+    assert len(device) == 8
     for r in device:
         assert 0.0 < r["attrs"]["dispatch_s"] <= r["dur_s"]
         assert ("phase" in r["attrs"]) == (r["kind"] == "compile")
-    if fold_parallel:
-        assert all(r["attrs"]["fused"] for r in device)
-    else:
-        assert all(r["attrs"]["carry_devices"] >= 1 for r in device if r["attrs"].get("phase", r["kind"]) == "train")
+    assert all(r["attrs"]["carry_devices"] >= 1 for r in device if r["attrs"].get("phase", r["kind"]) == "train")
 
 
 #: Programs a fresh configuration may ask the backend for between the start of
@@ -266,14 +262,14 @@ def test_a_device_call_that_raises_is_no_compile_span(telemetry):
     first."""
     program = ("a shape", "never seen")
     with pytest.raises(RuntimeError):
-        with cnn._phase("train", {"pop": 50, "fold": 0}, program=program):
+        with evaluation.phase("train", {"pop": 50, "fold": 0}, program=program):
             raise RuntimeError("RESOURCE_EXHAUSTED (made up by the test)")
     (failed,) = [r for r in telemetry.items if r.get("type") == "span"]
     assert failed["kind"] == "call_failed" and failed["error"] == "RuntimeError"
     assert failed["attrs"] == {"pop": 50, "fold": 0, "phase": "train"}
-    assert program not in cnn._tele_seen_programs
+    assert program not in evaluation._seen_programs
     with pytest.raises(ValueError):  # a host phase keeps its kind
-        with cnn._phase("prepare"):
+        with evaluation.phase("prepare"):
             raise ValueError("bad config")
     assert telemetry.items[-1]["kind"] == "prepare" and telemetry.items[-1]["error"] == "ValueError"
 
