@@ -11,7 +11,10 @@ others.  Layer ``l``, input ``x`` of shape (tokens, hidden)::
     Op = conv:  B, C, u = split3(W_in x);  W_out (C * causal_depthwise_conv1d_L(B * u))
     Op = full_attention:  q, k, v = W_q x, W_k x, W_v x; RMSNorm over the head size on q and k;
          rope on q, k; causal softmax(q k' / sqrt(head)) v, each key-value head serving
-         heads / kv_heads query heads; W_o
+         heads / kv_heads query heads; W_o.  The causal core is one function with two
+         programs: on a TPU, at a length of whole kernel blocks, a fused kernel (splash
+         attention, online softmax, its own backward: no score reaches memory); anywhere
+         else XLA's query blocks of ``attn_block`` (``_attention``)
     FFN dense:   W_2 (silu(W_1 x) * W_3 x)
     FFN routed:  s = sigmoid(W_r x); choose top-k of (s + b); w = s[chosen] / (sum s[chosen] + 1e-6);
                  out = sum over chosen AND held experts e of  w_e W2_e (silu(W1_e x) * W3_e x)
@@ -76,6 +79,11 @@ GENE_NAMES = ("log10_lr", "warmup_frac", "weight_decay", "beta2", "bias_step")
 ADAM_BETA1, ADAM_EPS, INIT_STD, ROUTE_EPS = 0.9, 1e-8, 0.02, 1e-6
 #: megablox tiles (rows, contraction, columns); the row tile shrinks to divide a small buffer.
 _GMM_TILING = (512, 512, 512)
+#: The fused attention kernel's blocks (splash attention): queries x keys a grid step holds and
+#: the keys one product inside it takes, forward, then the same for the one backward kernel
+#: (dk, dv and dq together).  Set by chip runs at the published shape (PERF.md, PR 31).
+_ATTN_KERNEL_BLOCKS = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
+                           block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512)
 #: The narrow row buffer holds this many times the rows a routed layer sends this rank on
 #: average.  On the chip a layer's busiest step reached 2.62 times (93 individuals of the
 #: benchmark's cell, the step after warm-up; 4 of them passed 2.0); each 0.25 costs 0.75% of an
@@ -245,19 +253,57 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention(p, x, cfg: Lfm2MoeConfig, dtype):
-    """Causal GQA in query blocks: no (length x length) score array per head is alive."""
-    s, length, _ = x.shape
-    nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    q = _dot(x, p["q"], dtype).reshape(s, length, nh, hd)
-    k = _dot(x, p["k"], dtype).reshape(s, length, nkv, hd)
-    v = _dot(x, p["v"], dtype).reshape(s, length, nkv, hd)
-    q = _rope(_rms_norm(q, p["q_norm"], cfg.norm_eps), cfg.rope_theta).astype(dtype)
-    k = _rope(_rms_norm(k, p["k_norm"], cfg.norm_eps), cfg.rope_theta).astype(dtype)
-    q = q.reshape(s, length, nkv, nh // nkv, hd)
-    block = min(cfg.attn_block, length)
+def _kernel_blocks(length: int) -> Optional[Dict[str, int]]:
+    """The fused kernel's blocks at this length, none larger than it, or None
+    where the length is not a whole number of each (of 128 lanes at least): the
+    kernel has no ragged last block."""
+    blocks = {name: min(size, length) for name, size in _ATTN_KERNEL_BLOCKS.items()}
+    return blocks if all(length % size == 0 and size % 128 == 0 for size in blocks.values()) else None
+
+
+def _use_attention_kernel(length: int) -> bool:
+    """Whether the causal core of a program traced now runs as the fused
+    kernel: the backend is a TPU, the length is a whole number of the kernel's
+    blocks and this jax ships the kernel."""
+    if jax.default_backend() != "tpu" or _kernel_blocks(length) is None:
+        return False
+    try:
+        from jax.experimental.pallas.ops.tpu import splash_attention  # noqa: F401
+    except ImportError:
+        return False
+    return True
+
+
+def _kernel_core(q, k, v):
+    """The causal core as one fused kernel with its own backward: scores, the
+    running maximum, sum and accumulator in float32 on the chip's fast memory,
+    the output and the log-sum-exp kept for the backward pass, never a score.
+    ``q`` (sequences, length, kv heads, queries a kv head, head size), float32;
+    ``k``, ``v`` (sequences, length, kv heads, head size) in the compute dtype.
+    The scale goes onto ``q`` before its cast (exact at a head size of 64).  The
+    kernel takes one key-value head with its query heads (no copy of K or V);
+    ``vmap`` makes the key-value heads and the sequences its outer grid."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
+
+    length, group = q.shape[1], q.shape[3]
+    q = (q / math.sqrt(q.shape[-1])).astype(k.dtype)
+    kernel = splash.make_splash_mqa_single_device(
+        masks.MultiHeadMask([masks.CausalMask((length, length))] * group),
+        block_sizes=splash.BlockSizes(**_kernel_blocks(length), use_fused_bwd_kernel=True))
+    out = jax.vmap(jax.vmap(kernel))(q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 3, 1, 2, 4)
+
+
+def _blockwise_core(q, k, v, block: int):
+    """The causal core in query blocks of at most ``block`` as XLA programs: no
+    (length x length) score array per head is alive, a block's scores are.
+    Arguments as :func:`_kernel_core`'s."""
+    length, hd, dtype = q.shape[1], q.shape[-1], k.dtype
+    block = min(block, length)
     if length % block:
         raise ValueError(f"seq_len {length} is not a multiple of attn_block {block}")
+    q = q.astype(dtype)
 
     @jax.checkpoint
     def one_block(qb, kb, vb, first):
@@ -267,7 +313,24 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype):
         return jnp.einsum("sngqk,sknd->sqngd", prob, vb)
 
     out = [one_block(q[:, i:i + block], k[:, :i + block], v[:, :i + block], i) for i in range(0, length, block)]
-    return _dot(jnp.concatenate(out, axis=1).reshape(s, length, nh * hd), p["o"], dtype)
+    return jnp.concatenate(out, axis=1)
+
+
+def _attention(p, x, cfg: Lfm2MoeConfig, dtype):
+    """Causal GQA on (sequences, length, hidden).  The core (scores, softmax,
+    values) is the fused kernel where :func:`_use_attention_kernel` says so and
+    XLA's query blocks of ``attn_block`` elsewhere: one function, chosen by
+    backend and shape."""
+    s, length, _ = x.shape
+    nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q = _dot(x, p["q"], dtype).reshape(s, length, nh, hd)
+    k = _dot(x, p["k"], dtype).reshape(s, length, nkv, hd)
+    v = _dot(x, p["v"], dtype).reshape(s, length, nkv, hd)
+    q = _rope(_rms_norm(q, p["q_norm"], cfg.norm_eps), cfg.rope_theta)
+    k = _rope(_rms_norm(k, p["k_norm"], cfg.norm_eps), cfg.rope_theta).astype(dtype)
+    q = q.reshape(s, length, nkv, nh // nkv, hd)
+    out = _kernel_core(q, k, v) if _use_attention_kernel(length) else _blockwise_core(q, k, v, cfg.attn_block)
+    return _dot(out.reshape(s, length, nh * hd), p["o"], dtype)
 
 
 def _dense_ffn(p, x, dtype):
@@ -458,12 +521,15 @@ class Lfm2MoePrograms(NamedTuple):
     the sequences of every step, ``genes`` the float32 vector in
     ``GENE_NAMES`` order, ``step`` the step number; ``load`` is this step's
     rows per held expert per routed layer.  ``eval(params, bias, x, y, rows)
-    -> loss per token`` of the sequences ``rows``."""
+    -> loss per token`` of the sequences ``rows``.  ``attention_kernel_layers``:
+    the attention layers whose core these programs run as the fused kernel
+    (:func:`_use_attention_kernel`, decided when they were built): all or none."""
 
     config: Lfm2MoeConfig
     init: Any
     train_step: Any
     eval: Any
+    attention_kernel_layers: int
 
 
 @functools.lru_cache(maxsize=8)
@@ -522,7 +588,8 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         return token_loss(logits, y_all[rows])
 
     train_step.__name__, init.__name__ = "lm_train_step", "lm_init"
-    return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval))
+    kernel_layers = cfg.layer_types.count("full_attention") if _use_attention_kernel(cfg.seq_len) else 0
+    return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval), kernel_layers)
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -630,11 +697,13 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     the held-out batches, one fetch."""
     cfg = programs.config
     shape = (cfg.tokens_per_step, cfg.train_steps)
+    kernel_layer_steps = programs.attention_kernel_layers * cfg.train_steps
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)
     with phase("train", {"steps": cfg.train_steps, "tokens": cfg.train_steps * cfg.tokens_per_step,
-                          "pop": PROGRAM_WIDTH, "individual": individual},
+                          "pop": PROGRAM_WIDTH, "individual": individual,
+                          "attention_kernel_layer_steps": kernel_layer_steps},
                 program=(id(programs.train_step), shape)) as sp:
         for step in steps:
             state, _, _ = programs.train_step(state, x, y, train_rows, genes, step)
@@ -647,6 +716,7 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
             losses, rows, dropped, wide = jax.device_get(
                 (losses, state["rows"], state["dropped"], state["wide_buffer"]))
             _count_expert_rows(cfg, rows, int(dropped), int(wide))
+            _get_registry().counter("attention_kernel_layer_steps_total").inc(kernel_layer_steps)
             sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=int(wide))
         else:
             losses = jax.device_get(losses)

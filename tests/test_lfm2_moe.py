@@ -6,11 +6,14 @@ and whole on logits, loss and gradients; over two train steps on loss,
 parameter change and the router bias; the share test ties the expert layer's
 cut (``held_experts``) to the uncut layer; fitness is a pure function of
 genome, configuration and seed in any position of any call, telemetry on or
-off; and the species runs through ``Population`` and ``GeneticAlgorithm``.
+off; and the species runs through ``Population`` and ``GeneticAlgorithm``.  The
+fused attention kernel (the TPU's core) runs here in Pallas' interpret mode
+against the blockwise XLA core, the one every other test of this file takes.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import os
 import re
@@ -291,6 +294,154 @@ def test_a_train_step_on_a_forced_bias_counts_the_layers_that_took_the_wide_buff
     assert get_registry().counter("row_buffer_wide_total").value == 3
 
 
+# -- the fused attention kernel (PR 31) --------------------------------------------------------------
+
+
+@pytest.fixture()
+def kernel_on_the_cpu(monkeypatch):
+    """The fused core chosen whatever the backend, its kernels interpreted: the
+    library's own factory is given ``interpret=True``, the program has no such knob."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    monkeypatch.setattr(splash, "make_splash_mqa_single_device",
+                        functools.partial(splash.make_splash_mqa_single_device, interpret=True))
+    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
+    M._programs.cache_clear()
+    yield
+    M._programs.cache_clear()
+
+
+def _attention_case(length: int, group: int, sequences: int = 1, kv_heads: int = 1):
+    """(configuration, attention weights, input) at head size 64."""
+    heads = kv_heads * group
+    cfg = M.Lfm2MoeConfig(hidden_size=64 * heads, num_attention_heads=heads, num_key_value_heads=kv_heads,
+                          seq_len=length, attn_block=256, layer_types=("full_attention",), layer_ids=(0,),
+                          num_dense_layers=0)
+    rng = np.random.default_rng(length + group)
+    shapes = M.param_shapes(cfg)["layers"][0]["attn"]
+    p = {name: jnp.asarray(1.0 + 0.1 * rng.normal(size=shape) if "norm" in name
+                           else rng.normal(size=shape) / np.sqrt(shape[0]), jnp.float32)
+         for name, shape in shapes.items()}
+    x = jnp.asarray(rng.normal(size=(sequences, length, cfg.hidden_size)), jnp.bfloat16)
+    return cfg, p, x
+
+
+def _rel(a, b) -> float:
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("length,group", [(512, 1), (512, 4), (1024, 1), (1024, 4)])
+def test_the_fused_core_is_the_blockwise_core_to_bfloat16(length, group, kernel_on_the_cpu):
+    """One ``_attention`` call by both cores, products in bfloat16: the output
+    within two bfloat16 steps of its size (2^-7 of the largest entry), the
+    gradients of the input, of the three projections and, on the cores alone, of
+    q, k and v within 1% in norm (the cores round their probabilities once each,
+    in different places)."""
+    cfg, p, x = _attention_case(length, group)
+    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
+
+    def value(p, x):
+        out = M._attention(p, x, cfg, jnp.bfloat16)
+        return jnp.sum(out.astype(jnp.float32) * probe), out
+
+    run = lambda: jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
+    (_, out), (dp, dx) = run()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(M, "_use_attention_kernel", lambda length: False)
+        (_, ref), (ref_dp, ref_dx) = run()
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.abs(ref).max() > 0.5 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
+    assert _rel(dx, ref_dx) < 0.01
+    for name in ("q", "k", "v", "o", "q_norm", "k_norm"):
+        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
+    rng = np.random.default_rng(2)
+    q = jnp.asarray(rng.normal(size=(1, length, 1, group, 64)), jnp.float32)
+    k, v, weight = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                    for shape in ((1, length, 1, 64), (1, length, 1, 64), q.shape))
+    cores = (M._kernel_core, functools.partial(M._blockwise_core, block=256))
+    got, want = (jax.jit(jax.grad(lambda q, k, v: jnp.sum((core(q, k, v) * weight).astype(jnp.float32)), (0, 1, 2)))(
+        q, k, v) for core in cores)
+    assert all(_rel(g, w) < 0.01 for g, w in zip(got, want)), [_rel(g, w) for g, w in zip(got, want)]
+
+
+@pytest.mark.parametrize("core", ["kernel", "blockwise"])
+def test_no_output_of_attention_sees_a_later_token(core, kernel_on_the_cpu, monkeypatch):
+    if core == "blockwise":
+        monkeypatch.setattr(M, "_use_attention_kernel", lambda length: False)
+    cfg, p, x = _attention_case(512, 4, sequences=2, kv_heads=2)
+    t = 300  # inside a block, not at its edge
+    later = x.at[:, t + 1:].set(jnp.asarray(np.random.default_rng(3).normal(size=x[:, t + 1:].shape), x.dtype))
+    run = jax.jit(lambda x: M._attention(p, x, cfg, jnp.bfloat16))
+    out, out_later = np.asarray(run(x), np.float32), np.asarray(run(later), np.float32)
+    np.testing.assert_array_equal(out[:, :t + 1], out_later[:, :t + 1])
+    assert np.abs(out[:, t + 1:] - out_later[:, t + 1:]).max() > 0.1
+
+
+def test_the_core_is_chosen_by_backend_and_length(tokens, monkeypatch):
+    """The CPU takes the XLA core and says so; a TPU takes the kernel at whole
+    blocks only; and the published shape, lowered for the TPU here, holds the
+    kernel's custom calls under ``layer{l}/attention``, forward and backward."""
+    assert jax.default_backend() == "cpu" and not M._use_attention_kernel(4096)
+    assert M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs()).attention_kernel_layers == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert M._use_attention_kernel(4096) and M._use_attention_kernel(512) and M._use_attention_kernel(128)
+    assert not M._use_attention_kernel(4096 + 512) and not M._use_attention_kernel(16) \
+        and not M._use_attention_kernel(192)
+    assert M._kernel_blocks(512)["block_q"] == 512 and M._kernel_blocks(4096) == M._ATTN_KERNEL_BLOCKS
+    M._programs.cache_clear()
+    try:
+        programs = M._programs(M.Lfm2MoeConfig())
+        assert programs.attention_kernel_layers == 2
+        cfg = programs.config
+        state = jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
+        x = jax.ShapeDtypeStruct((cfg.n_sequences, cfg.seq_len), jnp.int32)
+        text = programs.train_step.trace(
+            state, x, x, jax.ShapeDtypeStruct((cfg.train_steps, cfg.batch_sequences), jnp.int32),
+            jax.ShapeDtypeStruct((5,), jnp.float32), jax.ShapeDtypeStruct((), jnp.int32),
+        ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    finally:
+        M._programs.cache_clear()
+    # XLA names a custom call "<the call site's scopes>/<the kernel's own name>" (seen in the program compiled for
+    # the chip: ".../jvp(layer2)/attention/vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/.../pallas_call")
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    sites = {n for n in names if n.endswith("jit(_splash_attention)))")}
+    kernels = {n for n in names if n.endswith("pallas_call") and "splash" in n}
+    assert text.count("@tpu_custom_call") >= 2 and any("fwd" in n for n in kernels) and any("dkv" in n for n in kernels)
+    for layer in ("layer2", "layer6"):
+        for stage in (f"jvp({layer})/attention", f"rematted_computation/{layer}/attention",
+                      f"transpose(jvp(jvp()))/checkpoint/{layer}/attention"):
+            assert any(stage in n for n in sites), (stage, sorted(sites))
+    assert len(sites) == 6
+    assert all(scope_rules.classify(f"{site}/{kernel}") == ("attention", re.search(r"layer\d", site)[0])
+               for site in sites for kernel in kernels)
+    assert "sngqk" not in text, "the blockwise core's score product is still in the program"
+
+
+def test_a_train_span_and_the_counter_say_how_many_attention_layers_ran_the_kernel(long_tokens, kernel_on_the_cpu):
+    x, y = long_tokens
+    m = {**MODEL, "hidden_size": 128, "num_attention_heads": 2, "num_key_value_heads": 1,
+         "layer_types": ["full_attention", "conv"], "num_dense_layers": 1}
+    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs(m, compute_dtype="bfloat16"))
+    assert programs.attention_kernel_layers == 1
+    get_registry().reset()
+    sink = _Sink()
+    spans.set_run_sink(sink)
+    spans.enable()
+    try:
+        for individual in range(2):
+            loss = M._score_one(programs, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32), M.gene_vector(GENES),
+                                jnp.asarray(x), jnp.asarray(y), jnp.asarray([[0, 1], [2, 3], [0, 2]], np.int32),
+                                [jnp.asarray([4, 5])], [np.int32(s) for s in range(3)], individual)
+    finally:
+        spans.disable()
+        spans.set_run_sink(None)
+    assert 0 < loss < np.log(64) + 0.5
+    trained = [r["attrs"] for r in sink.records if r["type"] == "span" and r["attrs"].get("steps") == 3]
+    assert [a["attention_kernel_layer_steps"] for a in trained] == [3, 3]  # 1 attention layer x 3 steps, twice
+    assert get_registry().counter("attention_kernel_layer_steps_total").value == 6
+
+
 def _pool(n=3):
     rng = np.random.default_rng(8)
     return [lfm2_moe_genome().default()] + [lfm2_moe_genome().sample(rng) for _ in range(n - 1)]
@@ -328,6 +479,9 @@ def test_fitness_is_a_function_of_the_genome_in_any_position_and_with_telemetry_
     assert sum(rows.values()) == sum(sum(map(sum, a["expert_rows"])) for a in fetched) > 0
     assert get_registry().counter("dropped_assignments_total").value == 0
     assert get_registry().counter("row_buffer_wide_total").value == 0 == sum(a["wide_buffer"] for a in fetched)
+    trained = [r["attrs"] for r in device if r["attrs"].get("steps")]
+    assert len(trained) == len(pool) and all(a["attention_kernel_layer_steps"] == 0 for a in trained)
+    assert get_registry().counter("attention_kernel_layer_steps_total").value == 0
     assert M.Lfm2MoeModel.cross_validate_population(x, y, [], **kw).shape == (0,)
     other_seed = M.Lfm2MoeModel.cross_validate_population(x, y, pool[:1], **{**kw, "seed": 4})
     assert other_seed[0] != base[0]
@@ -464,29 +618,53 @@ def test_executed_flops_of_the_grouped_products_on_a_recorded_load():
     assert 0.40e9 < total < 0.50e9  # the issue's ~0.48 GFLOP a token forward, attention by causal blocks
 
 
-def test_the_expert_load_reader_reads_the_windows_fetch_spans_and_not_set_ups():
-    """``lm_expert_load_max_over_mean`` is a reading of the window: the warm-up
-    call's individuals (before the window) and spans without ``individual`` stay out."""
+@pytest.fixture()
+def layer_metric():
+    """A reader of ``benchmark/layer_metrics/`` by name, loaded as ``run.py``
+    loads it (the family's directory and the harness's on ``sys.path``)."""
     bench = os.path.dirname(os.path.dirname(FAMILY))
     names = ("lm_spans", "scope_rules", "scope_reduce", "spanlib", "trace_reduce")
     before = {n: sys.modules.pop(n, None) for n in names}
     sys.path[:0] = [FAMILY, bench]
     try:
-        reader = _load(os.path.join("..", "..", "layer_metrics", "lm_expert_load_max_over_mean"))
-        fetch = lambda t, attrs: {"type": "span", "kind": "fetch", "t_wall": t, "dur_s": 0.001, "attrs": attrs}
-        run = {"window": (10.0, 20.0), "records": [
-            fetch(5.0, {"individual": 0, "expert_rows": [[9000, 1], [1, 1]]}),  # set-up's warm-up call
-            fetch(11.0, {"individual": 0, "expert_rows": [[10, 30], [20, 20]]}),
-            fetch(12.0, {"individual": 1, "expert_rows": [[30, 50], [20, 20]]}),
-            fetch(13.0, {"other": 1, "expert_rows": [[7000, 1], [1, 1]]})]}
-        assert reader.read(run) == pytest.approx(80 * 4 / 200)
-        assert reader.read({"window": (10.0, 20.0), "records": run["records"][:1]}) is None
+        yield lambda name: _load(os.path.join("..", "..", "layer_metrics", name))
     finally:
         del sys.path[:2]
         for n in names:
             sys.modules.pop(n, None)
             if before[n] is not None:
                 sys.modules[n] = before[n]
+
+
+def _span(kind, t, attrs):
+    return {"type": "span", "kind": kind, "t_wall": t, "dur_s": 0.001, "attrs": attrs}
+
+
+def test_the_expert_load_reader_reads_the_windows_fetch_spans_and_not_set_ups(layer_metric):
+    """``lm_expert_load_max_over_mean`` is a reading of the window: the warm-up
+    call's individuals (before the window) and spans without ``individual`` stay out."""
+    reader = layer_metric("lm_expert_load_max_over_mean")
+    run = {"window": (10.0, 20.0), "records": [
+        _span("fetch", 5.0, {"individual": 0, "expert_rows": [[9000, 1], [1, 1]]}),  # set-up's warm-up call
+        _span("fetch", 11.0, {"individual": 0, "expert_rows": [[10, 30], [20, 20]]}),
+        _span("fetch", 12.0, {"individual": 1, "expert_rows": [[30, 50], [20, 20]]}),
+        _span("fetch", 13.0, {"other": 1, "expert_rows": [[7000, 1], [1, 1]]})]}
+    assert reader.read(run) == pytest.approx(80 * 4 / 200)
+    assert reader.read({"window": (10.0, 20.0), "records": run["records"][:1]}) is None
+
+
+def test_the_kernel_reader_averages_the_windows_train_spans_and_a_program_without_the_attribute_reads_nothing(
+        layer_metric):
+    reader = layer_metric("lm_attention_kernel_layer_steps")
+    train = lambda t, **attrs: _span("train", t, {"individual": 0, "steps": 8, **attrs})
+    window = {"window": (10.0, 20.0)}
+    records = [train(5.0, attention_kernel_layer_steps=0),  # set-up's warm-up call
+               train(11.0, attention_kernel_layer_steps=16), train(12.0, attention_kernel_layer_steps=16),
+               _span("train", 13.0, {"fold": 0, "attention_kernel_layer_steps": 99})]  # no span of this family
+    assert reader.read({**window, "records": records}) == 16
+    assert reader.read({**window, "records": [train(11.0, attention_kernel_layer_steps=0)]}) == 0
+    assert reader.read({**window, "records": [train(11.0)]}) is None  # the parent's program
+    assert reader.read({**window, "records": records[:1]}) is None
 
 
 @pytest.fixture()
