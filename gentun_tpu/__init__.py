@@ -26,11 +26,13 @@ from .genes import (
     GenomeSpec,
     IntGene,
     boosting_genome,
+    deepseek_v2_genome,
     genetic_cnn_genome,
     lfm2_moe_genome,
     xgboost_genome,
 )
-from .individuals import BoostingIndividual, GeneticCnnIndividual, Individual, Lfm2MoeIndividual, XgboostIndividual
+from .individuals import (BoostingIndividual, DeepseekV2Individual, GeneticCnnIndividual, Individual, Lfm2MoeIndividual,
+                          XgboostIndividual)
 from .populations import GridPopulation, Population
 from .algorithms import GeneticAlgorithm, RussianRouletteGA
 from .algorithms_async import AsyncEvolution
@@ -48,11 +50,13 @@ __all__ = [
     "boosting_genome",
     "xgboost_genome",
     "lfm2_moe_genome",
+    "deepseek_v2_genome",
     "Individual",
     "GeneticCnnIndividual",
     "BoostingIndividual",
     "XgboostIndividual",
     "Lfm2MoeIndividual",
+    "DeepseekV2Individual",
     "Population",
     "GridPopulation",
     "GeneticAlgorithm",

@@ -479,6 +479,7 @@ class CompileServiceClient:
         self._known: set = set()
         self._last_dir_mtime_ns = -1
         self._pending: deque = deque(maxlen=max_pending)
+        self._in_flight = 0  # entries of the batch the flusher has popped and is posting
         self._wake = threading.Event()
         self._closed = False
         self._flusher: Optional[threading.Thread] = None
@@ -702,7 +703,9 @@ class CompileServiceClient:
                     return  # closing while degraded: entries stay local
                 time.sleep(min(0.5, self.cooldown))
                 continue
+            self._in_flight = len(self._pending)  # before the pop: ``flush`` never sees both empty mid-batch
             batch = self._drain_batch()
+            self._in_flight = len(batch)
             if batch:
                 out = self._post("/v1/publish", {"entries": [
                     [n, base64.b64encode(d).decode("ascii")] for n, d in batch
@@ -714,16 +717,18 @@ class CompileServiceClient:
                 else:
                     with self._lock:
                         self._published += len(batch)
+                self._in_flight = 0
 
     def flush(self, timeout: float = 5.0) -> bool:
-        """Best-effort wait for the write-behind queue to drain."""
+        """Best-effort wait for the write-behind queue to drain, the batch
+        the flusher is posting included."""
         deadline = time.monotonic() + timeout
         self._wake.set()
-        while self._pending and time.monotonic() < deadline:
+        while (self._pending or self._in_flight) and time.monotonic() < deadline:
             if not self.available():
                 return False
             time.sleep(0.02)
-        return not self._pending
+        return not self._pending and not self._in_flight
 
     def close(self, flush_timeout: float = 2.0) -> None:
         """Final scan + flush what we can, then stop the flusher thread."""

@@ -102,13 +102,15 @@ def _load_dataset(name: str, data_dir=None, n=None):
 
 
 def _species(name: str):
-    from ..individuals import BoostingIndividual, GeneticCnnIndividual, Lfm2MoeIndividual, XgboostIndividual
+    from ..individuals import (BoostingIndividual, DeepseekV2Individual, GeneticCnnIndividual, Lfm2MoeIndividual,
+                               XgboostIndividual)
 
     table = {
         "genetic-cnn": GeneticCnnIndividual,
         "boosting": BoostingIndividual,
         "xgboost": XgboostIndividual,  # reference 11-gene genome
         "lfm2-moe": Lfm2MoeIndividual,  # training-recipe genome of the routed language model
+        "deepseek-v2": DeepseekV2Individual,  # the same model class; aux_alpha in place of bias_step
     }
     if name not in table:
         raise SystemExit(f"unknown species {name!r}; choose from {sorted(table)}")
@@ -131,7 +133,7 @@ def main(argv=None) -> int:
                          "ones.  Overrides --host/--port; a single address "
                          "behaves exactly like --host/--port")
     ap.add_argument("--password", default=None, help="broker shared token")
-    ap.add_argument("--species", default="genetic-cnn", help="genetic-cnn | boosting | xgboost | lfm2-moe")
+    ap.add_argument("--species", default="genetic-cnn", help="genetic-cnn | boosting | xgboost | lfm2-moe | deepseek-v2")
     ap.add_argument("--dataset", default="mnist",
                     help="mnist | cifar10 | cifar100 | uci-wine | uci-binary")
     ap.add_argument("--data-dir", default=None,

@@ -36,6 +36,7 @@ __all__ = [
     "boosting_genome",
     "xgboost_genome",
     "lfm2_moe_genome",
+    "deepseek_v2_genome",
 ]
 
 
@@ -391,3 +392,16 @@ def lfm2_moe_genome() -> GenomeSpec:
             FloatGene("bias_step", 0.001, 0.0, 0.01),
         ]
     )
+
+
+def deepseek_v2_genome() -> GenomeSpec:
+    """The training-recipe genome of the routed family's second architecture
+    (DeepSeek-V2-Lite through ``models/lfm2_moe.py``, ``balance_rule`` ``aux_loss``).
+
+    The first four genes are :func:`lfm2_moe_genome`'s.  The fifth is the weight
+    of the loss's sequence-wise balance term (``aux_loss_alpha``: 0.001 in the
+    model's own config, 0.003 for the expert-level term in the DeepSeek-V2
+    paper) where LFM2 has the router bias's step: this router has no bias, and
+    balance is a matter of the gradient.
+    """
+    return GenomeSpec(list(lfm2_moe_genome().genes[:4]) + [FloatGene("aux_alpha", 0.001, 0.0, 0.01)])

@@ -24,9 +24,11 @@ from typing import Any, Dict, Mapping, Optional, Type
 
 import numpy as np
 
-from .genes import GenomeSpec, boosting_genome, genetic_cnn_genome, lfm2_moe_genome, xgboost_genome
+from .genes import (GenomeSpec, boosting_genome, deepseek_v2_genome, genetic_cnn_genome, lfm2_moe_genome,
+                    xgboost_genome)
 
-__all__ = ["Individual", "GeneticCnnIndividual", "BoostingIndividual", "XgboostIndividual", "Lfm2MoeIndividual"]
+__all__ = ["Individual", "GeneticCnnIndividual", "BoostingIndividual", "XgboostIndividual", "Lfm2MoeIndividual",
+           "DeepseekV2Individual"]
 
 
 def _freeze(obj: Any) -> Any:
@@ -358,3 +360,17 @@ class Lfm2MoeIndividual(Individual):
             )
         model = self.model_cls(self.x_train, self.y_train, self.genes, **self.additional_parameters)
         return model.cross_validate()
+
+
+class DeepseekV2Individual(Lfm2MoeIndividual):
+    """Training-recipe search for a DeepSeek-V2-Lite share: the same model class
+    and evaluator as :class:`Lfm2MoeIndividual`, told the architecture by its
+    ``additional_parameters`` (``layer_types`` of ``latent_attention``,
+    ``scoring_func`` ``softmax``, ``balance_rule`` ``aux_loss``, ...).
+
+    Genome: :func:`gentun_tpu.genes.deepseek_v2_genome` (``aux_alpha``, the
+    balance term's weight, where LFM2 has the router bias's step).
+    """
+
+    def build_spec(self, **params) -> GenomeSpec:
+        return deepseek_v2_genome()

@@ -1,8 +1,15 @@
-"""LFM2-MoE fitness model: one expert-parallel rank's share of a routed language model.
+"""Routed language models as fitness models: one expert-parallel rank's share, trained under a recipe genome.
 
-The second jax family beside the Genetic-CNN (``models/cnn.py``).  The
-architecture is ``LFM2-24B-A2B`` (``model_type`` ``lfm2_moe``,
-https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json): gated
+The second jax family beside the Genetic-CNN (``models/cnn.py``), and two
+architectures of it, told apart by the configuration alone (which operator a
+layer has, how the router scores, whether shared experts stand beside the
+routed ones, whether the head is tied, which balance rule runs): one evaluator,
+one train step builder, one expert layer, one causal core and one optimizer
+serve both.
+
+``LFM2-24B-A2B`` (``model_type`` ``lfm2_moe``,
+https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json; the
+defaults of :class:`Lfm2MoeConfig`): gated
 short convolutions and grouped-query attention as operators, a dense SwiGLU
 feed-forward in the leading layers and 64 routed experts, 4 per token, in the
 others.  Layer ``l``, input ``x`` of shape (tokens, hidden)::
@@ -19,6 +26,26 @@ others.  Layer ``l``, input ``x`` of shape (tokens, hidden)::
     FFN routed:  s = sigmoid(W_r x); choose top-k of (s + b); w = s[chosen] / (sum s[chosen] + 1e-6);
                  out = sum over chosen AND held experts e of  w_e W2_e (silu(W1_e x) * W3_e x)
     output: RMSNorm, logits over the held rows of the tied embedding, mean next-token cross-entropy
+
+``DeepSeek-V2-Lite`` (``model_type`` ``deepseek_v2``,
+https://huggingface.co/deepseek-ai/DeepSeek-V2-Lite/blob/main/config.json): every
+layer's operator is latent attention, every routed layer adds shared experts, the
+router is a softmax whose chosen probabilities are the weights as they are, and
+balance comes from a term of the loss::
+
+    Op = latent_attention:  q = W_q x -> heads x (nope + rope);  [c ; k_pe] = W_kva x -> kv_lora_rank + rope;
+         c = RMSNorm(c);  [k_nope ; v] = W_kvb c -> heads x (nope + v);  rope (YaRN frequencies) on q_pe and
+         on k_pe, which is ONE head shared by every query head;
+         causal softmax((q_nope . k_nope + q_pe . k_pe) * (nope + rope)^-0.5 * m^2) v;  W_o
+         (m = 0.1 * mscale_all_dim * ln factor + 1).  The same two cores as above run it, at a head
+         size of nope + rope for q and k and of v for the values
+    FFN routed:  p = softmax(W_r x) in float32; chosen = top-k of p; w = p[chosen], un-normalised;
+                 out = sum over chosen AND held e of w_e W2_e (silu(W1_e x) * W3_e x)
+                     + W2_s (silu(W1_s x) * W3_s x)          the shared experts, one SwiGLU of their joint width
+    loss = cross-entropy (head untied) + alpha * sum over routed layers of
+           mean over sequences of sum_e f_e P_e, over ALL experts:  f_e = experts / (k L) * #(tokens of the
+           sequence that chose e), a count without a gradient; P_e = the sequence's mean of p_e (``aux_alpha``
+           is the recipe's fifth gene where LFM2 has ``bias_step``; no bias, no rule outside the gradient)
 
 What differs from the CNN family, by design:
 
@@ -72,12 +99,16 @@ from ..telemetry.registry import get_registry as _get_registry
 from .evaluation import base_keys, evaluation_prelude, genome_hashes, phase
 from .generic import GentunModel
 
-__all__ = ["Lfm2MoeModel", "Lfm2MoeConfig", "Lfm2MoePrograms", "GENE_NAMES", "training_bytes"]
+__all__ = ["Lfm2MoeModel", "Lfm2MoeConfig", "Lfm2MoePrograms", "GENE_NAMES", "gene_names", "training_bytes"]
 
-#: The recipe's genes in the order the compiled programs take them (one float32 vector).
+#: The recipe's genes in the order the compiled programs take them (one float32 vector); the
+#: fifth belongs to the balance rule (:func:`gene_names`), and this is the bias rule's form.
 GENE_NAMES = ("log10_lr", "warmup_frac", "weight_decay", "beta2", "bias_step")
+#: The fifth gene by balance rule: the bias's step (arXiv:2408.15664) or the weight of the loss's balance term.
+_BALANCE_GENE = {"bias": "bias_step", "aux_loss": "aux_alpha"}
 ADAM_BETA1, ADAM_EPS, INIT_STD, ROUTE_EPS = 0.9, 1e-8, 0.02, 1e-6
-#: megablox tiles (rows, contraction, columns); the row tile shrinks to divide a small buffer.
+#: megablox tiles (rows, contraction, columns); the row tile shrinks to divide a small buffer
+#: (:func:`_gmm_tiling` follows the shape from here).
 _GMM_TILING = (512, 512, 512)
 #: The fused attention kernel's blocks (splash attention): queries x keys a grid step holds and
 #: the keys one product inside it takes, forward, then the same for the one backward kernel
@@ -119,10 +150,31 @@ class Lfm2MoeConfig:
     n_sequences: int = 40
     attn_block: int = 512
     compute_dtype: str = "bfloat16"
+    # what a second architecture sets (the defaults are LFM2's): a ``latent_attention`` layer's
+    # ranks and head sizes and its rope's scaling (the published block as sorted items, or None)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
+    n_shared_experts: int = 0  # shared experts beside the routed ones, one SwiGLU of their joint width
+    scoring_func: str = "sigmoid"  # or "softmax"
+    norm_topk_prob: bool = True  # the chosen weights divided by their sum
+    balance_rule: str = "bias"  # a router bias stepped outside the gradient, or "aux_loss": a term of the loss
+    tie_word_embeddings: bool = True
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def gene_names(self) -> Tuple[str, ...]:
+        return gene_names(self.balance_rule)
+
+    @property
+    def yarn(self) -> Optional[Dict[str, Any]]:
+        """``rope_scaling`` as the mapping it was given as, or None."""
+        return dict(self.rope_scaling) if self.rope_scaling else None
 
     @property
     def n_held(self) -> int:
@@ -148,6 +200,11 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
         layer: Dict[str, Any] = {"op_norm": (h,), "ffn_norm": (h,)}
         if kind == "conv":
             layer["conv"] = {"in_proj": (h, 3 * h), "kernel": (h, cfg.conv_L_cache), "out_proj": (h, h)}
+        elif kind == "latent_attention":
+            nh, rank, nope, rope, vd = (cfg.num_attention_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                                        cfg.qk_rope_head_dim, cfg.v_head_dim)
+            layer["latent"] = {"q": (h, nh * (nope + rope)), "kva": (h, rank + rope), "kv_norm": (rank,),
+                               "kvb": (rank, nh * (nope + vd)), "o": (nh * vd, h)}
         else:
             layer["attn"] = {"q": (h, cfg.num_attention_heads * hd), "k": (h, cfg.num_key_value_heads * hd),
                              "v": (h, cfg.num_key_value_heads * hd), "o": (cfg.num_attention_heads * hd, h),
@@ -158,8 +215,14 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
         else:
             e, f = cfg.n_held, cfg.moe_intermediate_size
             layer["moe"] = {"router": (h, cfg.num_experts), "w1": (e, h, f), "w3": (e, h, f), "w2": (e, f, h)}
+            if cfg.n_shared_experts:
+                fs = cfg.n_shared_experts * f
+                layer["moe"]["shared"] = {"w1": (h, fs), "w3": (h, fs), "w2": (fs, h)}
         layers.append(layer)
-    return {"embed": (cfg.vocab_size, h), "final_norm": (h,), "layers": layers}
+    tree = {"embed": (cfg.vocab_size, h), "final_norm": (h,), "layers": layers}
+    if not cfg.tie_word_embeddings:
+        tree["head"] = (cfg.vocab_size, h)
+    return tree
 
 
 def _is_shape(x) -> bool:
@@ -186,6 +249,9 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
             "total": 16 * n_params + activations}
 
 
+#: The operators a layer can have (``layer_types``).
+LAYER_KINDS = ("conv", "full_attention", "latent_attention")
+
 #: A program of this family is one individual wide, always: the published cut
 #: takes 10.4 of a chip's 16 GB in state alone, and a second width would be a
 #: second compiled train program, which the family promises not to have.
@@ -211,6 +277,19 @@ def _use_megablox() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """megablox tiles for a product of ``m`` rows, contraction ``k`` and ``n``
+    columns: the forward product, and each of the backward pass's two at its own
+    sizes (the kernels look the tiles up by shape).  The row tile divides the
+    buffer.  A width of up to four tiles that is no whole number of them (1408 =
+    11 x 128) is one tile, whole: a ragged last tile of 512 runs 8% of its
+    columns empty, tiles of 128 read the rows eleven times; on the chip the three
+    products forward and backward took 6.43 ms whole, 7.83 ragged, 11.8 at 128
+    (12,288 rows of a 33,792-row buffer; PERF.md, PR 32)."""
+    fit = lambda size, tile: tile if size % tile == 0 or size > 4 * tile else size
+    return math.gcd(m, _GMM_TILING[0]), fit(k, _GMM_TILING[1]), fit(n, _GMM_TILING[2])
+
+
 def _grouped_matmul(rows, weights, group_sizes):
     """``rows`` (R, K) sorted by group against ``weights`` (G, K, N): group g's
     rows times ``weights[g]``.  Cost follows ``sum(group_sizes)``; the rows past
@@ -218,8 +297,7 @@ def _grouped_matmul(rows, weights, group_sizes):
     if _use_megablox():
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        tiling = (math.gcd(rows.shape[0], _GMM_TILING[0]),) + _GMM_TILING[1:]
-        return gmm(rows, weights, group_sizes, preferred_element_type=rows.dtype, tiling=tiling)
+        return gmm(rows, weights, group_sizes, preferred_element_type=rows.dtype, tiling=_gmm_tiling)
     return jax.lax.ragged_dot(rows, weights, group_sizes)
 
 
@@ -243,12 +321,39 @@ def _conv_op(p, x, cfg: Lfm2MoeConfig, dtype):
     return _dot(gate_c * y, p["out_proj"], dtype)
 
 
-def _rope(x, theta):
-    """Rotary embedding, rotate-half layout, on (sequences, length, heads, head size); float32."""
+def yarn_inv_freq(dim: int, theta: float, scaling: Mapping[str, Any]) -> np.ndarray:
+    """YaRN's ``dim / 2`` rotary frequencies (arXiv:2309.00071, as the deepseek_v2 modelling code
+    blends them): below the correction dimension of ``beta_fast`` the plain frequency
+    ``theta^(-2i/dim)``, above that of ``beta_slow`` the frequency divided by ``factor``, a linear
+    ramp between.  A correction dimension is where a rotation count over the original context is
+    met: ``dim * ln(original / (2 pi beta)) / (2 ln theta)``, rounded outwards."""
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    turn = lambda beta: dim * math.log(scaling["original_max_position_embeddings"] / (beta * 2 * math.pi)) \
+        / (2 * math.log(theta))
+    low, high = max(math.floor(turn(scaling["beta_fast"])), 0), min(math.ceil(turn(scaling["beta_slow"])), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (plain / scaling["factor"] * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
+    """Rotary embedding, rotate-half layout, on (sequences, length, heads, head size); float32.
+    With ``scaling`` (YaRN) the frequencies are :func:`yarn_inv_freq`'s and cos
+    and sin carry ``mscale / mscale_all_dim``."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling is None:
+        inv_freq, amplitude = theta ** (-jnp.arange(half, dtype=jnp.float32) / half), 1.0
+    else:
+        inv_freq = jnp.asarray(yarn_inv_freq(x.shape[-1], theta, scaling))
+        amplitude = yarn_mscale(scaling["factor"], scaling["mscale"]) \
+            / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"])
     angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
     cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
@@ -274,20 +379,26 @@ def _use_attention_kernel(length: int) -> bool:
     return True
 
 
-def _kernel_core(q, k, v):
+def _kernel_core(q, k, v, scale: float):
     """The causal core as one fused kernel with its own backward: scores, the
     running maximum, sum and accumulator in float32 on the chip's fast memory,
     the output and the log-sum-exp kept for the backward pass, never a score.
     ``q`` (sequences, length, kv heads, queries a kv head, head size), float32;
-    ``k``, ``v`` (sequences, length, kv heads, head size) in the compute dtype.
-    The scale goes onto ``q`` before its cast (exact at a head size of 64).  The
+    ``k`` (sequences, length, kv heads, head size) and ``v`` (the same, at a
+    head size of its own) in the compute dtype.  ``scale`` multiplies the scores
+    and goes onto ``q`` before its cast (exact where it is a power of two: a head
+    size of 64).  A head size of q and k over 128 that is no whole number of 128
+    lanes (latent attention's 192) is padded with zero columns, which is exact.  The
     kernel takes one key-value head with its query heads (no copy of K or V);
     ``vmap`` makes the key-value heads and the sequences its outer grid."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
 
     length, group = q.shape[1], q.shape[3]
-    q = (q / math.sqrt(q.shape[-1])).astype(k.dtype)
+    q = (q * scale).astype(k.dtype)
+    pad = -q.shape[-1] % 128 if q.shape[-1] > 128 else 0
+    if pad:  # zero columns add nothing to a score: 192 as 256 took 13.9 ms against 15.4 (PERF.md, PR 32)
+        q, k = (jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)]) for a in (q, k))
     kernel = splash.make_splash_mqa_single_device(
         masks.MultiHeadMask([masks.CausalMask((length, length))] * group),
         block_sizes=splash.BlockSizes(**_kernel_blocks(length), use_fused_bwd_kernel=True))
@@ -295,11 +406,11 @@ def _kernel_core(q, k, v):
     return out.transpose(0, 3, 1, 2, 4)
 
 
-def _blockwise_core(q, k, v, block: int):
+def _blockwise_core(q, k, v, scale: float, block: int):
     """The causal core in query blocks of at most ``block`` as XLA programs: no
     (length x length) score array per head is alive, a block's scores are.
     Arguments as :func:`_kernel_core`'s."""
-    length, hd, dtype = q.shape[1], q.shape[-1], k.dtype
+    length, dtype = q.shape[1], k.dtype
     block = min(block, length)
     if length % block:
         raise ValueError(f"seq_len {length} is not a multiple of attn_block {block}")
@@ -307,7 +418,7 @@ def _blockwise_core(q, k, v, block: int):
 
     @jax.checkpoint
     def one_block(qb, kb, vb, first):
-        scores = jnp.einsum("sqngd,sknd->sngqk", qb, kb, preferred_element_type=jnp.float32) / math.sqrt(hd)
+        scores = jnp.einsum("sqngd,sknd->sngqk", qb, kb, preferred_element_type=jnp.float32) * scale
         seen = (first + jnp.arange(qb.shape[1]))[:, None] >= jnp.arange(kb.shape[1])[None, :]
         prob = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1).astype(dtype)
         return jnp.einsum("sngqk,sknd->sqngd", prob, vb)
@@ -316,11 +427,17 @@ def _blockwise_core(q, k, v, block: int):
     return jnp.concatenate(out, axis=1)
 
 
+def _causal_core(q, k, v, scale: float, cfg: Lfm2MoeConfig):
+    """The causal core (scores, softmax, values) of either attention operator:
+    the fused kernel where :func:`_use_attention_kernel` says so and XLA's query
+    blocks of ``attn_block`` elsewhere: one function, chosen by backend and shape."""
+    if _use_attention_kernel(q.shape[1]):
+        return _kernel_core(q, k, v, scale)
+    return _blockwise_core(q, k, v, scale, cfg.attn_block)
+
+
 def _attention(p, x, cfg: Lfm2MoeConfig, dtype):
-    """Causal GQA on (sequences, length, hidden).  The core (scores, softmax,
-    values) is the fused kernel where :func:`_use_attention_kernel` says so and
-    XLA's query blocks of ``attn_block`` elsewhere: one function, chosen by
-    backend and shape."""
+    """Causal GQA on (sequences, length, hidden); the core is :func:`_causal_core`'s."""
     s, length, _ = x.shape
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     q = _dot(x, p["q"], dtype).reshape(s, length, nh, hd)
@@ -329,8 +446,40 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype):
     q = _rope(_rms_norm(q, p["q_norm"], cfg.norm_eps), cfg.rope_theta)
     k = _rope(_rms_norm(k, p["k_norm"], cfg.norm_eps), cfg.rope_theta).astype(dtype)
     q = q.reshape(s, length, nkv, nh // nkv, hd)
-    out = _kernel_core(q, k, v) if _use_attention_kernel(length) else _blockwise_core(q, k, v, cfg.attn_block)
+    out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg)
     return _dot(out.reshape(s, length, nh * hd), p["o"], dtype)
+
+
+def latent_softmax_scale(cfg: Lfm2MoeConfig) -> float:
+    """``(nope + rope)^-0.5``, times ``m^2`` under YaRN (``m`` from ``mscale_all_dim``)."""
+    m = yarn_mscale(cfg.yarn["factor"], cfg.yarn["mscale_all_dim"]) if cfg.yarn else 1.0
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _latent_attention(p, x, cfg: Lfm2MoeConfig, dtype):
+    """Causal latent attention (MLA, no query latent) on (sequences, length,
+    hidden), as training runs it: keys and values expanded from the latent per
+    head, the rope part of the key one head that every query head shares (a
+    broadcast: its gradient is the sum over the heads), and the same causal core
+    as :func:`_attention` at ``heads`` key-value heads of one query head each."""
+    s, length, _ = x.shape
+    nh, rank, nope, rope, vd = (cfg.num_attention_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                                cfg.qk_rope_head_dim, cfg.v_head_dim)
+    with jax.named_scope("down_proj"):
+        q = _dot(x, p["q"], dtype).reshape(s, length, nh, nope + rope)
+        latent = _dot(x, p["kva"], dtype)
+        c = _rms_norm(latent[..., :rank], p["kv_norm"], cfg.norm_eps).astype(dtype)
+    with jax.named_scope("up_proj"):
+        kv = _dot(c, p["kvb"], dtype).reshape(s, length, nh, nope + vd)
+    with jax.named_scope("rope"):
+        q_pe = _rope(q[..., nope:].astype(jnp.float32), cfg.rope_theta, cfg.yarn)
+        k_pe = _rope(latent[..., None, rank:].astype(jnp.float32), cfg.rope_theta, cfg.yarn)
+        q = jnp.concatenate([q[..., :nope].astype(jnp.float32), q_pe], axis=-1)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_pe.astype(dtype), (s, length, nh, rope))], axis=-1)
+    with jax.named_scope("core"):
+        out = _causal_core(q[:, :, :, None, :], k, kv[..., nope:], latent_softmax_scale(cfg), cfg)
+    with jax.named_scope("out_proj"):
+        return _dot(out.reshape(s, length, nh * vd), p["o"], dtype)
 
 
 def _dense_ffn(p, x, dtype):
@@ -338,20 +487,36 @@ def _dense_ffn(p, x, dtype):
 
 
 def _route(router, bias, x, cfg: Lfm2MoeConfig):
-    """Scores over ALL experts, the chosen top-k (by score + bias) and their
-    normalised weights (of the scores alone); float32."""
+    """Scores over ALL experts (``scoring_func``: a sigmoid each, or one softmax),
+    the chosen top-k (by score, plus the bias where the balance rule is one) and
+    their weights: the chosen scores alone, divided by their sum where
+    ``norm_topk_prob`` says so.  Returns (chosen, weights, scores); float32."""
     logits = jnp.dot(x.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, chosen = jax.lax.top_k(scores + bias, cfg.num_experts_per_tok)
+    scores = jax.nn.sigmoid(logits) if cfg.scoring_func == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    _, chosen = jax.lax.top_k(scores + bias if cfg.balance_rule == "bias" else scores, cfg.num_experts_per_tok)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    return chosen, picked / (picked.sum(-1, keepdims=True) + ROUTE_EPS)
+    return chosen, picked / (picked.sum(-1, keepdims=True) + ROUTE_EPS) if cfg.norm_topk_prob else picked, scores
 
 
-class RowBufferUse(NamedTuple):
-    """What the routed layers' row buffers did with a call's held assignments (int32 scalars)."""
+def _balance_term(scores, chosen, sequences: int, cfg: Lfm2MoeConfig):
+    """The sequence-wise balance term of a routed layer, before its weight:
+    mean over sequences of ``sum_e f_e P_e`` over ALL experts, ``f_e = experts /
+    (k L)`` times the tokens of the sequence that chose ``e`` (a count: no
+    gradient) and ``P_e`` the sequence's mean score.  1 where routing is even."""
+    experts, k = cfg.num_experts, cfg.num_experts_per_tok
+    per_sequence = chosen.reshape(sequences, -1)
+    count = jnp.sum(per_sequence[..., None] == jnp.arange(experts), axis=1, dtype=jnp.float32)
+    f = count * (experts / per_sequence.shape[1])  # L k assignments a sequence
+    mean_score = scores.reshape(sequences, -1, experts).mean(axis=1)
+    return jnp.mean(jnp.sum(jax.lax.stop_gradient(f) * mean_score, axis=-1))
 
-    dropped: Any  # assignments that found no room: 0, either height holds what it is given
-    wide: Any  # routed layers that took the worst-case height
+
+class RoutedStats(NamedTuple):
+    """What the routed layers of a call report beside their loads (scalars that add up over layers)."""
+
+    dropped: Any  # int32: held assignments that found no room in the row buffer: 0, either height holds what it is given
+    wide: Any  # int32: routed layers that took the worst-case height
+    balance: Any  # float32: the balance terms (``aux_loss`` rule; 0 under the bias rule, which has none)
 
 
 def _narrow_rows(cfg: Lfm2MoeConfig, tokens: int) -> int:
@@ -422,12 +587,15 @@ def _expert_rows_by_count(narrow: int, full: int, cfg: Lfm2MoeConfig, dtype):
     return by_count
 
 
-def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = None):
-    """The held experts' part of the routed feed-forward on (tokens, hidden).
+def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = None, sequences: int = 1):
+    """The held experts' part of the routed feed-forward on (tokens, hidden),
+    plus the shared experts where the configuration has them (every rank
+    computes those alike).
 
-    Returns ``(out, load, use)``: ``load`` counts the tokens each of ALL experts
-    was chosen for (the bias rule needs them all), ``use`` is this layer's
-    :class:`RowBufferUse`.  The row buffer's height follows the rows present:
+    Returns ``(out, load, stats)``: ``load`` counts the tokens each of ALL experts
+    was chosen for (the bias rule needs them all), ``stats`` is this layer's
+    :class:`RoutedStats` (its balance term is over ``sequences`` sequences of
+    equal length).  The row buffer's height follows the rows present:
     the held assignments the router counted decide on the device between
     :func:`_narrow_rows` and, over it, the worst case of top-k x tokens rows;
     :func:`_expert_rows` is the body of both, so no assignment is ever dropped.
@@ -441,8 +609,12 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
     full = k * t
     narrow = _narrow_rows(cfg, t) if row_buffer is None else row_buffer
     with jax.named_scope("moe"), jax.named_scope("router"):
-        chosen, weight = _route(p["router"], bias, x, cfg)
+        chosen, weight, scores = _route(p["router"], bias, x, cfg)
         load = jnp.sum(chosen[..., None] == jnp.arange(cfg.num_experts), axis=(0, 1), dtype=jnp.int32)
+    balance = jnp.zeros((), jnp.float32)
+    if cfg.balance_rule == "aux_loss":
+        with jax.named_scope("aux_loss"):
+            balance = _balance_term(scores, chosen, sequences, cfg)
     with jax.named_scope("moe"), jax.named_scope("dispatch"):
         local = chosen.reshape(-1) - cfg.held_experts[0]  # assignment a = token a // k, choice a % k
         held = (local >= 0) & (local < n_held)
@@ -452,21 +624,27 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
         n_held_rows = sizes.sum()
     operands = ({name: p[name] for name in ("w1", "w3", "w2")}, x, weight, order, sizes)
     if narrow >= full:  # one height, nothing to fall back from
-        out, (n_rows, _) = _expert_rows(cfg, dtype, full, *operands)
-        return out, load, RowBufferUse(n_held_rows - n_rows, jnp.zeros((), jnp.int32))
-    out, (n_rows, height) = _expert_rows_by_count(narrow, full, cfg, dtype)(*operands)
-    return out, load, RowBufferUse(n_held_rows - n_rows, jnp.int32(height > narrow))
+        out, (n_rows, height) = _expert_rows(cfg, dtype, full, *operands)
+    else:
+        out, (n_rows, height) = _expert_rows_by_count(narrow, full, cfg, dtype)(*operands)
+    if "shared" in p:
+        with jax.named_scope("moe"), jax.named_scope("shared"):
+            out = out + _dense_ffn(p["shared"], x, dtype)
+    return out, load, RoutedStats(n_held_rows - n_rows, jnp.int32(height > narrow), balance)
 
 
 def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x):
     """One layer on (sequences, length, hidden); ``bias`` is the layer's router
-    bias or None.  Returns the output and, of a routed layer, (load, use)."""
+    bias or None.  Returns the output and, of a routed layer, (load, stats)."""
     kind, name = cfg.layer_types[index], f"layer{cfg.layer_ids[index]}"
     with jax.named_scope(name):
         normed = _rms_norm(x, p["op_norm"], cfg.norm_eps).astype(dtype)
         if kind == "conv":
             with jax.named_scope("conv_op"):
                 h = x + _conv_op(p["conv"], normed, cfg, dtype)
+        elif kind == "latent_attention":
+            with jax.named_scope("latent_attention"):
+                h = x + _latent_attention(p["latent"], normed, cfg, dtype)
         else:
             with jax.named_scope("attention"):
                 h = x + _attention(p["attn"], normed, cfg, dtype)
@@ -474,18 +652,19 @@ def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x):
         if "dense" in p:
             with jax.named_scope("dense_ffn"):
                 return h + _dense_ffn(p["dense"], normed, dtype), None
-        out, load, use = _moe_ffn(p["moe"], bias, normed.reshape(-1, normed.shape[-1]), cfg, dtype)
-        return h + out.reshape(h.shape), (load, use)
+        out, load, stats = _moe_ffn(p["moe"], bias, normed.reshape(-1, normed.shape[-1]), cfg, dtype,
+                                    sequences=normed.shape[0])
+        return h + out.reshape(h.shape), (load, stats)
 
 
 def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
     """Float32 logits (sequences, length, held vocabulary), the load of ALL
     experts per routed layer (layers, experts) and the routed layers'
-    :class:`RowBufferUse`, summed."""
+    :class:`RoutedStats`, summed."""
     dtype = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dtype)
-    loads, use = [], RowBufferUse(jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+    loads, use = [], RoutedStats(jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32))
     for i, p in enumerate(params["layers"]):
         fn = functools.partial(_layer, cfg, i, dtype)
         moe = i >= cfg.num_dense_layers
@@ -495,7 +674,8 @@ def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
             use = jax.tree_util.tree_map(jnp.add, use, aux[1])
     with jax.named_scope("head"):
         x = _rms_norm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
-        logits = jnp.einsum("slh,vh->slv", x, params["embed"].astype(dtype), preferred_element_type=jnp.float32)
+        head = params["embed" if cfg.tie_word_embeddings else "head"]
+        logits = jnp.einsum("slh,vh->slv", x, head.astype(dtype), preferred_element_type=jnp.float32)
     return logits, jnp.stack(loads), use
 
 
@@ -514,16 +694,19 @@ class Lfm2MoePrograms(NamedTuple):
     ``init(base_key, genome_hash) -> state``: a fresh train state, a dict of
     ``params``/``m``/``v`` (one tree each, :func:`param_shapes`), ``bias``
     (routed layers, experts), ``rows`` (routed layers, held experts: rows
-    routed so far), ``dropped`` and ``wide_buffer`` (:class:`RowBufferUse`,
-    summed over the steps so far).  ``train_step(state, x, y, batch_rows,
+    routed so far), ``dropped`` and ``wide_buffer`` (:class:`RoutedStats`,
+    summed over the steps so far) and, under the ``aux_loss`` balance rule,
+    ``aux_loss`` (the routed layers' balance terms before their weight, summed
+    likewise).  ``train_step(state, x, y, batch_rows,
     genes, step) -> (state, loss, load)``: ``state`` is donated; ``x``/``y``
     are the whole token arrays, ``batch_rows`` (train_steps, batch_sequences)
-    the sequences of every step, ``genes`` the float32 vector in
-    ``GENE_NAMES`` order, ``step`` the step number; ``load`` is this step's
-    rows per held expert per routed layer.  ``eval(params, bias, x, y, rows)
+    the sequences of every step, ``genes`` the float32 vector in the
+    configuration's ``gene_names`` order, ``step`` the step number; ``loss`` is
+    what was differentiated (the balance term included where there is one),
+    ``load`` this step's rows per held expert per routed layer.  ``eval(params, bias, x, y, rows)
     -> loss per token`` of the sequences ``rows``.  ``attention_kernel_layers``:
-    the attention layers whose core these programs run as the fused kernel
-    (:func:`_use_attention_kernel`, decided when they were built): all or none."""
+    the attention layers (GQA and latent alike) whose core these programs run as the fused
+    kernel (:func:`_use_attention_kernel`, decided when they were built): all or none."""
 
     config: Lfm2MoeConfig
     init: Any
@@ -537,6 +720,7 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
     shapes = param_shapes(cfg)
     lo, hi = cfg.held_experts
     n_moe = len(cfg.moe_layers)
+    by_loss = cfg.balance_rule == "aux_loss"
 
     def init(base_key, genome_hash):
         key = jax.random.fold_in(jax.random.fold_in(base_key, genome_hash[0]), genome_hash[1])
@@ -546,20 +730,25 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
                   for i, (path, shape) in enumerate(leaves)]
         params = jax.tree_util.tree_unflatten(tree, params)
         zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)
-        return {"params": params, "m": zeros(), "v": zeros(),
-                "bias": jnp.zeros((n_moe, cfg.num_experts), jnp.float32),
-                "rows": jnp.zeros((n_moe, cfg.n_held), jnp.int32), "dropped": jnp.zeros((), jnp.int32),
-                "wide_buffer": jnp.zeros((), jnp.int32)}
+        state = {"params": params, "m": zeros(), "v": zeros(),
+                 "bias": jnp.zeros((n_moe, cfg.num_experts), jnp.float32),
+                 "rows": jnp.zeros((n_moe, cfg.n_held), jnp.int32), "dropped": jnp.zeros((), jnp.int32),
+                 "wide_buffer": jnp.zeros((), jnp.int32)}
+        if by_loss:
+            state["aux_loss"] = jnp.zeros((), jnp.float32)
+        return state
 
-    def loss_fn(params, bias, x, y):
+    def loss_fn(params, bias, x, y, balance_weight):
         logits, load, use = forward(cfg, params, bias, x, remat=True)
-        return token_loss(logits, y).mean(), (load, use)
+        loss = token_loss(logits, y).mean()
+        return (loss + balance_weight * use.balance if by_loss else loss), (load, use)
 
     def train_step(state, x_all, y_all, batch_rows, genes, step):
         rows = batch_rows[step]
+        # the fifth gene belongs to the balance rule: the bias's step, or the balance term's weight
+        log10_lr, warmup_frac, weight_decay, beta2, balance_gene = (genes[i] for i in range(len(GENE_NAMES)))
         (loss, (load, use)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state["params"], state["bias"], x_all[rows], y_all[rows])
-        log10_lr, warmup_frac, weight_decay, beta2, bias_step = (genes[i] for i in range(len(GENE_NAMES)))
+            state["params"], state["bias"], x_all[rows], y_all[rows], balance_gene)
         with jax.named_scope("optimizer"):
             t = (step + 1).astype(jnp.float32)
             lr = 10.0 ** log10_lr * jnp.minimum(1.0, t / jnp.maximum(warmup_frac * cfg.train_steps, 1.0))
@@ -574,13 +763,17 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
             updated = jax.tree_util.tree_map_with_path(adamw, state["params"], state["m"], state["v"], grads)
             params, m, v = jax.tree_util.tree_transpose(
                 jax.tree_util.tree_structure(grads), jax.tree_util.tree_structure((0, 0, 0)), updated)
-        with jax.named_scope("bias_update"):
-            mean_load = cfg.tokens_per_step * cfg.num_experts_per_tok / cfg.num_experts
-            bias = state["bias"] + bias_step * jnp.sign(mean_load - load.astype(jnp.float32))
+        bias = state["bias"]  # all zeros and never read under the ``aux_loss`` rule
+        if not by_loss:
+            with jax.named_scope("bias_update"):
+                mean_load = cfg.tokens_per_step * cfg.num_experts_per_tok / cfg.num_experts
+                bias = bias + balance_gene * jnp.sign(mean_load - load.astype(jnp.float32))
         held = load[:, lo:hi]
         new = {"params": params, "m": m, "v": v, "bias": bias,
                "rows": state["rows"] + held, "dropped": state["dropped"] + use.dropped,
                "wide_buffer": state["wide_buffer"] + use.wide}
+        if by_loss:
+            new["aux_loss"] = state["aux_loss"] + use.balance
         return new, loss, held
 
     def lm_eval(params, bias, x_all, y_all, rows):
@@ -588,7 +781,8 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         return token_loss(logits, y_all[rows])
 
     train_step.__name__, init.__name__ = "lm_train_step", "lm_init"
-    kernel_layers = cfg.layer_types.count("full_attention") if _use_attention_kernel(cfg.seq_len) else 0
+    n_attention = sum(kind in ("full_attention", "latent_attention") for kind in cfg.layer_types)
+    kernel_layers = n_attention if _use_attention_kernel(cfg.seq_len) else 0
     return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval), kernel_layers)
 
 
@@ -605,10 +799,24 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
     for key in ("layer_types", "layer_ids", "held_experts"):
         if key in config:
             config[key] = tuple(config[key])
+    if isinstance(config.get("rope_scaling"), Mapping):
+        config["rope_scaling"] = tuple(sorted(config["rope_scaling"].items()))
     config.setdefault("layer_ids", tuple(range(len(config.get("layer_types", Lfm2MoeConfig.layer_types)))))
     cfg = Lfm2MoeConfig(**{**config, "seq_len": x.shape[1], "n_sequences": x.shape[0]})
-    if len(cfg.layer_ids) != len(cfg.layer_types) or set(cfg.layer_types) - {"conv", "full_attention"}:
-        raise ValueError(f"layer_types {cfg.layer_types} / layer_ids {cfg.layer_ids}")
+    if len(cfg.layer_ids) != len(cfg.layer_types) or set(cfg.layer_types) - set(LAYER_KINDS):
+        raise ValueError(f"layer_types {cfg.layer_types} (each one of {LAYER_KINDS}) / layer_ids {cfg.layer_ids}")
+    if "latent_attention" in cfg.layer_types:
+        sizes = {k: getattr(cfg, k) for k in ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")}
+        if min(sizes.values()) <= 0 or cfg.qk_rope_head_dim % 2:
+            raise ValueError(f"a latent_attention layer needs its rank and head sizes (an even rope size): {sizes}")
+        wanted = {"factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim", "original_max_position_embeddings"}
+        if cfg.yarn is not None and not wanted <= set(cfg.yarn):
+            raise ValueError(f"rope_scaling needs {sorted(wanted)} (YaRN); got {sorted(cfg.yarn)}")
+    if cfg.n_shared_experts < 0 or (cfg.n_shared_experts and cfg.moe_intermediate_size <= 0):
+        raise ValueError(f"{cfg.n_shared_experts} shared experts of width {cfg.moe_intermediate_size}")
+    if cfg.scoring_func not in ("sigmoid", "softmax") or cfg.balance_rule not in _BALANCE_GENE:
+        raise ValueError(f"scoring_func {cfg.scoring_func!r} (sigmoid or softmax) / balance_rule "
+                         f"{cfg.balance_rule!r} (one of {sorted(_BALANCE_GENE)})")
     if not 0 <= cfg.num_dense_layers < len(cfg.layer_types):
         raise ValueError("num_dense_layers must leave at least one routed layer")
     if not 0 <= cfg.held_experts[0] < cfg.held_experts[1] <= cfg.num_experts:
@@ -633,8 +841,17 @@ def batch_plan(cfg: Lfm2MoeConfig, seed: int) -> Tuple[np.ndarray, np.ndarray]:
     return train.astype(np.int32), held_out.astype(np.int32)
 
 
-def gene_vector(genome: Mapping[str, Any]) -> np.ndarray:
-    return np.asarray([float(genome[name]) for name in GENE_NAMES], np.float32)
+def gene_names(balance_rule: str) -> Tuple[str, ...]:
+    """The recipe's genes under ``balance_rule``, in the order the compiled programs take them."""
+    return GENE_NAMES[:-1] + (_BALANCE_GENE[balance_rule],)
+
+
+def gene_vector(genome: Mapping[str, Any], names: Optional[Sequence[str]] = None) -> np.ndarray:
+    """The genome as the programs' float32 vector; without ``names`` the balance
+    rule is read off the genome (whichever fifth gene it carries)."""
+    if names is None:
+        names = next(gene_names(rule) for rule, gene in _BALANCE_GENE.items() if gene in genome)
+    return np.asarray([float(genome[name]) for name in names], np.float32)
 
 
 class Lfm2MoeModel(GentunModel):
@@ -686,7 +903,7 @@ class Lfm2MoeModel(GentunModel):
                 steps = [np.int32(s) for s in range(cfg.train_steps)]
             fitness = np.empty(len(genomes), np.float32)
             for i, genome in enumerate(genomes):
-                fitness[i] = -_score_one(programs, init_base, hashes[i], gene_vector(genome), x, y,
+                fitness[i] = -_score_one(programs, init_base, hashes[i], gene_vector(genome, cfg.gene_names), x, y,
                                          train_rows, val_rows, steps, i)
             return fitness
 
@@ -713,11 +930,15 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
         losses = sp.fence([programs.eval(state["params"], state["bias"], x, y, rows) for rows in val_rows])
     with phase("fetch", {"individual": individual}) as sp:
         if _tele.enabled():
-            losses, rows, dropped, wide = jax.device_get(
-                (losses, state["rows"], state["dropped"], state["wide_buffer"]))
+            losses, rows, dropped, wide, balance = jax.device_get(
+                (losses, state["rows"], state["dropped"], state["wide_buffer"], state.get("aux_loss")))
             _count_expert_rows(cfg, rows, int(dropped), int(wide))
             _get_registry().counter("attention_kernel_layer_steps_total").inc(kernel_layer_steps)
             sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=int(wide))
+            if balance is not None:  # the ``aux_loss`` rule: the term before its weight, a routed layer and step
+                balance = float(balance) / (len(cfg.moe_layers) * cfg.train_steps)
+                _get_registry().counter("aux_loss_total").inc(balance)
+                sp.set(aux_loss=balance)
         else:
             losses = jax.device_get(losses)
         del state  # the fetch has waited for the device: these 12 bytes a parameter are free again

@@ -1,0 +1,726 @@
+"""The routed family's second architecture (DeepSeek-V2-Lite through
+``models/lfm2_moe.py``) against its plain reference
+(``benchmark/families/deepseek_v2/reference.py``), at small sizes on the CPU.
+
+System and reference are compared in float32 on seeded weights: per layer kind
+and whole on logits, loss (with the balance term) and gradients; over two train
+steps; the share test ties the expert layer's cut to the uncut layer with the
+shared experts counted once.  Then what is the architecture's own: ``k_pe`` is
+one head, causality in both cores, the fused core at 192 / 128 in Pallas'
+interpret mode, YaRN's frequencies by hand, the router's rule, where the balance
+term's gradient goes, the row buffer at top-6, refusals, the species, the scopes,
+the counts of ``flops.py`` and the readers of the new per-layer metrics.
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib.util
+import json
+import math
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from gentun_tpu import DeepseekV2Individual, GeneticAlgorithm, Population, deepseek_v2_genome, lfm2_moe_genome
+from gentun_tpu.models import lfm2_moe as M
+from gentun_tpu.telemetry import spans
+from gentun_tpu.telemetry.registry import get_registry
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+FAMILY = os.path.join(BENCH, "families", "deepseek_v2")
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(f"dsv2_family_{os.path.basename(name)}", os.path.join(FAMILY, name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+R = _load("reference")
+flops = _load("flops")
+scope_rules = _load("scope_rules")
+
+YARN = dict(beta_fast=32, beta_slow=1, factor=40, mscale=0.707, mscale_all_dim=0.707,
+            original_max_position_embeddings=4096, type="yarn")
+MODEL = dict(hidden_size=32, num_hidden_layers=3, first_k_dense_replace=1, intermediate_size=48,
+             moe_intermediate_size=24, n_routed_experts=8, num_experts_per_tok=3, n_shared_experts=2,
+             held_experts=[2, 4], num_attention_heads=4, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+             v_head_dim=8, vocab_size=64, rms_norm_eps=1e-6, rope_theta=10000.0,
+             rope_scaling={**YARN, "original_max_position_embeddings": 8}, train_steps=3)
+GENES = dict(log10_lr=-2.5, warmup_frac=0.5, weight_decay=0.1, beta2=0.95, aux_alpha=0.05)
+HIGHEST = jax.default_matmul_precision("highest")
+STD = 0.15  # narrow layers: wider weights, or the operators vanish beside the residual
+
+
+def model_kwargs(m=MODEL, **over):
+    """``Lfm2MoeModel``'s keyword arguments that make it the reference's model ``m``."""
+    kw = {k: m[k] for k in ("hidden_size", "intermediate_size", "moe_intermediate_size", "num_experts_per_tok",
+                            "n_shared_experts", "num_attention_heads", "kv_lora_rank", "qk_nope_head_dim",
+                            "qk_rope_head_dim", "v_head_dim", "vocab_size", "rope_theta", "rope_scaling", "train_steps")}
+    kw.update(layer_types=("latent_attention",) * m["num_hidden_layers"], num_dense_layers=m["first_k_dense_replace"],
+              num_experts=m["n_routed_experts"], held_experts=tuple(m["held_experts"]), norm_eps=m["rms_norm_eps"],
+              scoring_func="softmax", norm_topk_prob=False, balance_rule="aux_loss", tie_word_embeddings=False,
+              batch_sequences=2, eval_sequences=2, attn_block=8, compute_dtype="float32")
+    kw.update(over)
+    return kw
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    tok = np.random.default_rng(0).integers(0, 64, size=(10, 17)).astype(np.int32)
+    return tok[:, :-1], tok[:, 1:]
+
+
+def config_of(tokens, m=MODEL, **over) -> M.Lfm2MoeConfig:
+    return M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(m, **over)).config
+
+
+NO_BIAS = jnp.zeros((8, 8), jnp.float32)  # the state's bias: zeros, never read under the ``aux_loss`` rule
+
+LAYER_CASES = {"latent_routed_shared": {**MODEL, "num_hidden_layers": 1, "first_k_dense_replace": 0, "held_experts": [1, 5]},
+               "latent_dense": {**MODEL, "num_hidden_layers": 2, "held_experts": [1, 5]},  # a dense layer leads a routed one
+               "whole_cut": {**MODEL, "held_experts": [1, 5]}}
+
+
+@pytest.mark.parametrize("case", sorted(LAYER_CASES))
+def test_logits_loss_with_the_balance_term_and_gradients_match_the_reference(case, tokens):
+    m = LAYER_CASES[case]
+    cfg = config_of(tokens, m)
+    w = R.seeded_weights(m, 7, STD)
+    x, y = tokens[0][:2], tokens[1][:2]
+    alpha = 0.05
+
+    def system_loss(params):
+        logits, load, stats = M.forward(cfg, params, NO_BIAS, x, remat=True)
+        return M.token_loss(logits, y).mean() + alpha * stats.balance, (logits, load, stats)
+
+    def reference_loss(params):
+        out = [R.forward(m, params, xs) for xs in x]
+        nll = jnp.mean(jnp.stack([R.token_loss(o[0], ys) for o, ys in zip(out, y)]))
+        balance = sum(o[2] for o in out) / len(out)
+        return nll + alpha * balance, (jnp.stack([o[0] for o in out]), sum(o[1] for o in out), balance)
+
+    with HIGHEST:
+        (loss, (logits, load, stats)), grads = jax.jit(jax.value_and_grad(system_loss, has_aux=True))(w)
+        (ref_loss, (ref_logits, ref_load, ref_balance)), ref_grads = jax.jit(
+            jax.value_and_grad(reference_loss, has_aux=True))(w)
+    np.testing.assert_allclose(logits, ref_logits, atol=2e-5)
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
+    np.testing.assert_allclose(stats.balance, ref_balance, rtol=1e-6)
+    assert float(ref_balance) > 0.9 * (m["num_hidden_layers"] - m["first_k_dense_replace"])  # ~1 a routed layer
+    np.testing.assert_array_equal(load, ref_load)
+    assert int(stats.dropped) == 0 and int(stats.wide) == 0
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
+        np.testing.assert_allclose(g, r, atol=2e-6, rtol=1e-4, err_msg=jax.tree_util.keystr(path))
+        assert float(jnp.abs(r).max()) > 0 or "embed" in str(path), \
+            f"{jax.tree_util.keystr(path)}: the reference's gradient is all zero"
+
+
+def _program_steps(programs, weights, x, y, rows, steps, genes=GENES):
+    state = programs.init(jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
+    state = {**state, "params": jax.tree_util.tree_map(jnp.asarray, weights)}
+    losses, loads = [], []
+    for s in range(steps):
+        state, loss, held = programs.train_step(state, jnp.asarray(x), jnp.asarray(y), jnp.asarray(rows),
+                                                jnp.asarray(M.gene_vector(genes)), np.int32(s))
+        losses.append(float(loss))
+        loads.append(np.asarray(held))
+    return state, losses, loads
+
+
+def test_two_train_steps_match_the_reference(tokens):
+    x, y = tokens
+    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs())
+    assert programs.config.gene_names == tuple(deepseek_v2_genome().names) and "aux_loss" in \
+        jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
+    w = R.seeded_weights(MODEL, 5, STD)
+    rows = np.array([[0, 1], [2, 3], [4, 5]], np.int32)
+    with HIGHEST:
+        state, losses, loads = _program_steps(programs, w, x, y, rows, 2)
+        ref = R.train(MODEL, w, [(x[r], y[r]) for r in rows[:2]], GENES)
+    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)  # the balance term included
+    np.testing.assert_allclose(float(state["aux_loss"]), sum(ref["balances"]), rtol=1e-6)
+    for got, want in zip(loads, ref["loads"]):
+        np.testing.assert_array_equal(got, want[:, 2:4])
+    np.testing.assert_array_equal(np.asarray(state["rows"]), sum(l[:, 2:4] for l in ref["loads"]))
+    assert not np.asarray(state["bias"]).any(), "no bias and no rule outside the gradient"
+    for (path, a), b, start in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
+                                   jax.tree_util.tree_leaves(ref["weights"]), jax.tree_util.tree_leaves(w)):
+        change, ref_change = np.asarray(a) - start, np.asarray(b) - start
+        assert np.abs(ref_change).max() > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(change, ref_change, atol=3e-5, err_msg=jax.tree_util.keystr(path))
+    with HIGHEST:
+        got = programs.eval(state["params"], state["bias"], jnp.asarray(x), jnp.asarray(y), jnp.asarray([8, 9]))
+        want = R.eval_token_loss(MODEL, ref["weights"], x[8:10], y[8:10])
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_eight_shares_and_the_shared_experts_once_add_up_to_the_uncut_layer(tokens):
+    """16 experts in 8 shares of 2: each share's program computes the operator,
+    the residual, the shared experts and its own routed experts' part; the routed
+    parts, with what every share computes alike counted once, are the uncut
+    reference's layer output."""
+    m = {**MODEL, "num_hidden_layers": 1, "first_k_dense_replace": 0, "n_routed_experts": 16, "num_experts_per_tok": 6}
+    x = tokens[0][:2]
+    uncut = {**m, "held_experts": [0, 16]}
+    w_all = R.seeded_weights(uncut, 11, STD)
+    layer_w = w_all["layers"][0]
+    embedded = w_all["embed"][x]
+    share_of = lambda first, last: dict(layer_w, moe={k: (v[first:last] if k in ("w1", "w3", "w2") else v)
+                                                      for k, v in layer_w["moe"].items()})
+    identity = lambda a: a
+    with HIGHEST:
+        whole = jnp.stack([R.layer(uncut, 0, identity, layer_w, jnp.asarray(e))[0] for e in embedded])
+        # operator, residual and shared experts, no routed expert: what every share computes alike
+        alike = jnp.stack([R.layer({**uncut, "held_experts": [0, 0]}, 0, identity, share_of(0, 0), jnp.asarray(e))[0]
+                           for e in embedded])
+        total = alike
+        for first in range(0, 16, 2):
+            cfg = config_of(tokens, {**m, "held_experts": [first, first + 2]})
+            out, _ = M._layer(cfg, 0, jnp.float32, share_of(first, first + 2), None, jnp.asarray(embedded))
+            part = out - alike
+            assert float(jnp.abs(part).max()) > 0
+            total = total + part
+        no_shared = {**layer_w, "moe": {**layer_w["moe"], "shared": jax.tree_util.tree_map(jnp.zeros_like,
+                                                                                           layer_w["moe"]["shared"])}}
+        without = jnp.stack([R.layer(uncut, 0, identity, no_shared, jnp.asarray(e))[0] for e in embedded])
+    np.testing.assert_allclose(total, whole, atol=2e-5)
+    assert float(jnp.abs(whole - without).max()) > 1e-3, "the shared experts are part of the layer"
+
+
+# -- latent attention ---------------------------------------------------------------------------------
+
+
+def _latent_case(length: int, heads: int, sequences: int = 1, nope: int = 128, rope: int = 64, vd: int = 128,
+                 rank: int = 64, hidden: int = 128):
+    """(configuration, latent-attention weights, input) at the published head sizes by default."""
+    cfg = M.Lfm2MoeConfig(hidden_size=hidden, num_attention_heads=heads, kv_lora_rank=rank, qk_nope_head_dim=nope,
+                          qk_rope_head_dim=rope, v_head_dim=vd, rope_scaling=tuple(sorted(YARN.items())),
+                          rope_theta=10000.0, norm_eps=1e-6, seq_len=length, attn_block=256,
+                          layer_types=("latent_attention",), layer_ids=(0,), num_dense_layers=0)
+    rng = np.random.default_rng(length + heads)
+    shapes = M.param_shapes(cfg)["layers"][0]["latent"]
+    p = {name: jnp.asarray(1.0 + 0.1 * rng.normal(size=shape) if "norm" in name
+                           else rng.normal(size=shape) / np.sqrt(shape[0]), jnp.float32)
+         for name, shape in shapes.items()}
+    x = jnp.asarray(rng.normal(size=(sequences, length, hidden)), jnp.bfloat16)
+    return cfg, p, x
+
+
+def test_k_pe_is_one_head_that_every_query_head_shares():
+    """``W_kva``'s rope columns make one key head; every one of the query heads
+    reads it: the gradient that reaches those columns through head ``h`` alone
+    (the other heads' outputs weighted 0) is nonzero for each ``h``, and the
+    heads' gradients add up to the whole one."""
+    cfg, p, x = _latent_case(32, 4, nope=8, rope=4, vd=8, rank=16, hidden=32)
+    x = x.astype(jnp.float32)
+    probe = jnp.asarray(np.random.default_rng(1).normal(size=(1, 32, 4, 8)), jnp.float32)
+    identity_o = {**p, "o": jnp.eye(4 * 8, 32, dtype=jnp.float32)}  # the output is the heads' values, side by side
+
+    def through(heads_mask):
+        def value(kva):
+            out = M._latent_attention({**identity_o, "kva": kva}, x, cfg, jnp.float32).reshape(1, 32, 4, 8)
+            return jnp.sum(out * probe * heads_mask[None, None, :, None])
+        return jax.grad(value)(p["kva"])[:, cfg.kv_lora_rank:]  # the rope columns
+
+    with HIGHEST:
+        whole = through(jnp.ones(4))
+        per_head = [through(jnp.eye(4)[h]) for h in range(4)]
+    assert p["kva"].shape == (32, 16 + 4), "one rope head of 4 columns, not one a query head"
+    assert all(float(jnp.abs(g).max()) > 1e-4 for g in per_head)
+    np.testing.assert_allclose(sum(per_head), whole, rtol=1e-4, atol=1e-6)
+
+
+@pytest.fixture()
+def kernel_on_the_cpu(monkeypatch):
+    """The fused core chosen whatever the backend, its kernels interpreted (as ``tests/test_lfm2_moe.py`` does)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    monkeypatch.setattr(splash, "make_splash_mqa_single_device",
+                        functools.partial(splash.make_splash_mqa_single_device, interpret=True))
+    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
+    M._programs.cache_clear()
+    yield
+    M._programs.cache_clear()
+
+
+def _rel(a, b) -> float:
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def test_the_fused_core_is_the_blockwise_core_to_bfloat16_at_192_and_128(kernel_on_the_cpu):
+    """One ``_latent_attention`` call by both cores at the published head sizes
+    (q and k of 128 + 64, padded to 256 for the kernel; v of 128), products in
+    bfloat16: the output within two bfloat16 steps of its size, the gradients of
+    the input and of every projection within 1% in norm."""
+    cfg, p, x = _latent_case(512, 2)
+    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
+
+    def value(p, x):
+        out = M._latent_attention(p, x, cfg, jnp.bfloat16)
+        return jnp.sum(out.astype(jnp.float32) * probe), out
+
+    run = lambda: jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
+    (_, out), (dp, dx) = run()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(M, "_use_attention_kernel", lambda length: False)
+        (_, ref), (ref_dp, ref_dx) = run()
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.abs(ref).max() > 0.5 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
+    assert _rel(dx, ref_dx) < 0.01
+    for name in ("q", "kva", "kv_norm", "kvb", "o"):
+        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
+
+
+@pytest.mark.parametrize("core", ["kernel", "blockwise"])
+def test_no_output_of_latent_attention_sees_a_later_token(core, kernel_on_the_cpu, monkeypatch):
+    if core == "blockwise":
+        monkeypatch.setattr(M, "_use_attention_kernel", lambda length: False)
+    cfg, p, x = _latent_case(512, 2, sequences=2)
+    t = 300  # inside a block, not at its edge
+    later = x.at[:, t + 1:].set(jnp.asarray(np.random.default_rng(3).normal(size=x[:, t + 1:].shape), x.dtype))
+    run = jax.jit(lambda x: M._latent_attention(p, x, cfg, jnp.bfloat16))
+    out, out_later = np.asarray(run(x), np.float32), np.asarray(run(later), np.float32)
+    np.testing.assert_array_equal(out[:, :t + 1], out_later[:, :t + 1])
+    assert np.abs(out[:, t + 1:] - out_later[:, t + 1:]).max() > 0.1
+
+
+def test_yarn_frequencies_and_the_softmax_scale_against_numbers_worked_by_hand():
+    """Published settings: rope size 64, theta 10000, factor 40, beta_fast 32, beta_slow 1, original 4096.
+    d(b) = 64 ln(4096 / (2 pi b)) / (2 ln 10000): d(32) = 10.47 -> low 10; d(1) = 22.51 -> high 23.  So pairs
+    0-10 keep theta^(-2i/64), pairs 23-31 are divided by 40, and pair i between blends with g = 1 - (i - 10) / 13."""
+    inv = M.yarn_inv_freq(64, 10000.0, YARN)
+    plain = 10000.0 ** (-np.arange(32) / 32.0)
+    assert inv.shape == (32,) and inv.dtype == np.float32
+    np.testing.assert_allclose(inv[:11], plain[:11], rtol=1e-6)
+    np.testing.assert_allclose(inv[23:], plain[23:] / 40.0, rtol=1e-6)
+    g = 1.0 - (16 - 10) / 13.0
+    np.testing.assert_allclose(inv[16], (1 - g) * 0.01 / 40.0 + g * 0.01, rtol=1e-6)  # pair 16: theta^(-1/2) = 0.01
+    np.testing.assert_allclose(inv, R.yarn_frequencies(64, 10000.0, YARN), rtol=1e-6)
+    m = 0.1 * 0.707 * math.log(40.0) + 1.0
+    assert m == pytest.approx(1.2608, abs=5e-5) and M.yarn_mscale(40, 0.707) == pytest.approx(m)
+    cfg = _latent_case(128, 2)[0]
+    assert M.latent_softmax_scale(cfg) == pytest.approx(192 ** -0.5 * 1.2608 ** 2, rel=1e-4) \
+        and R.softmax_scale({"qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "rope_scaling": YARN}) \
+        == pytest.approx(M.latent_softmax_scale(cfg))
+    # cos and sin carry mscale / mscale_all_dim: 1 as published, not 1 where the two differ
+    x = jnp.ones((1, 4, 1, 64), jnp.float32)
+    same, other = M._rope(x, 10000.0, YARN), M._rope(x, 10000.0, {**YARN, "mscale": 1.0})
+    np.testing.assert_allclose(same[0, 0], 1.0)  # position 0: no rotation, amplitude 1
+    np.testing.assert_allclose(other[0, 0], M.yarn_mscale(40, 1.0) / m, rtol=1e-6)
+
+
+# -- the router, the balance term, the row buffer ---------------------------------------------------------
+
+
+def _moe_case(tokens, k=6, experts=16, held=(4, 12)):
+    m = {**MODEL, "num_hidden_layers": 1, "first_k_dense_replace": 0, "n_routed_experts": experts,
+         "num_experts_per_tok": k, "held_experts": list(held)}
+    return m, config_of(tokens, m), R.seeded_weights(m, 3, STD)["layers"][0]["moe"]
+
+
+def test_the_routers_weights_are_unnormalised_probabilities_and_the_choice_ignores_no_expert(tokens):
+    m, cfg, w = _moe_case(tokens)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(64, 32)), jnp.float32)
+    bias = jnp.full((16,), 100.0).at[0].set(-100.0)  # would turn every choice around, if it were read
+    with HIGHEST:
+        chosen, weight, scores = M._route(w["router"], bias, x, cfg)
+        prob = jax.nn.softmax(x @ w["router"], axis=-1)
+    np.testing.assert_allclose(scores, prob, atol=1e-6)
+    np.testing.assert_allclose(scores.sum(-1), 1.0, atol=1e-5)
+    top_p, top_i = jax.lax.top_k(prob, 6)
+    np.testing.assert_array_equal(chosen, top_i)  # greedy top-6 of the probabilities alone
+    np.testing.assert_allclose(weight, top_p, atol=1e-6)
+    assert np.all(np.asarray(weight.sum(-1)) < 0.999), "the six weights are not divided by their sum"
+    assert set(np.unique(np.asarray(chosen))) == set(range(16)), "every expert is chosen by some token"
+    # the other architecture's rule through the same function: sigmoid, the bias in the choice, normalised
+    lfm2 = M.Lfm2MoeConfig(num_experts=16, num_experts_per_tok=6)
+    _, weight2, scores2 = M._route(w["router"], jnp.zeros(16), x, lfm2)
+    np.testing.assert_allclose(weight2.sum(-1), 1.0, atol=1e-4)
+    assert float(scores2.sum(-1).max()) > 1.5
+
+
+def test_the_balance_terms_gradient_reaches_the_router_and_nothing_else_of_the_expert_layer(tokens):
+    m, cfg, w = _moe_case(tokens)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(64, 32)), jnp.float32)
+
+    def balance(p, xs):
+        return M._moe_ffn(p, None, xs, cfg, jnp.float32, sequences=2)[2].balance
+
+    with HIGHEST:
+        value, (dp, dx) = jax.value_and_grad(balance, argnums=(0, 1))(w, x)
+        prob = jax.nn.softmax(x @ w["router"], axis=-1)
+    chosen = jax.lax.top_k(prob, 6)[1].reshape(2, -1)
+    by_hand = np.mean([np.sum(np.bincount(np.asarray(c), minlength=16) * 16 / (6 * 32) * np.asarray(pr).mean(0))
+                       for c, pr in zip(chosen, prob.reshape(2, 32, 16))])
+    assert float(value) == pytest.approx(by_hand, rel=1e-5) and 0.95 < float(value) < 1.6  # 1 where routing is even
+    assert float(jnp.abs(dp["router"]).max()) > 1e-4
+    assert float(jnp.abs(dx).max()) > 0, "through the router's input it reaches the layers below, as published"
+    for path, g in jax.tree_util.tree_flatten_with_path({k: v for k, v in dp.items() if k != "router"})[0]:
+        assert not np.asarray(g).any(), jax.tree_util.keystr(path)
+    # a count carries no gradient: the term is linear in the probabilities' means
+    stats_twice = M._balance_term(2.0 * prob, jax.lax.top_k(prob, 6)[1], 2, cfg)
+    assert float(stats_twice) == pytest.approx(2.0 * float(value), rel=1e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 1e-2)])
+def test_the_narrow_and_the_wide_row_buffer_give_the_same_layer_at_top_6(tokens, dtype, tol):
+    m, cfg, w = _moe_case(tokens)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(32, 32)), jnp.float32)
+    probe = jnp.asarray(np.random.default_rng(6).normal(size=(32, 32)), jnp.float32)
+
+    def layer(row_buffer):
+        def value(p, xs):
+            out, load, stats = M._moe_ffn(p, None, xs.astype(dtype), cfg, jnp.dtype(dtype), row_buffer)
+            return jnp.sum(out.astype(jnp.float32) * probe), (out, load, stats)
+        return jax.jit(jax.value_and_grad(jax.checkpoint(value), argnums=(0, 1), has_aux=True))(w, x)
+
+    with HIGHEST:
+        (_, (wide_out, wide_load, _)), wide_grads = layer(None)  # 6 x 32 = 192 rows: the only height at this size
+        (_, (out, load, stats)), grads = layer(128)
+        (_, (_, _, fell_back)), _ = layer(64)
+    held = int(load[4:12].sum())
+    assert 64 < held <= 128 and int(stats.wide) == 0 and int(stats.dropped) == 0
+    assert int(fell_back.wide) == 1 and int(fell_back.dropped) == 0
+    np.testing.assert_array_equal(load, wide_load)
+    np.testing.assert_allclose(out.astype(jnp.float32), wide_out.astype(jnp.float32), atol=tol, rtol=tol)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(wide_grads)):
+        assert float(jnp.abs(r).max()) > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(g, r, atol=tol * float(jnp.abs(r).max()), err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_grouped_products_tiles_follow_the_shape():
+    """1536 = 3 x 512 keeps LFM2's tiles; 1408 = 11 x 128 is one tile, whole, as
+    contraction (the down product, the backward's) and as columns; the row tile
+    divides the buffer; a width far over four tiles stays tiled."""
+    assert M._gmm_tiling(22528, 2048, 1536) == M._GMM_TILING == (512, 512, 512)
+    assert M._gmm_tiling(33792, 2048, 1408) == (512, 512, 1408)
+    assert M._gmm_tiling(33792, 1408, 2048) == (512, 1408, 512)
+    assert M._gmm_tiling(98304, 2048, 1408)[0] == 512 and M._gmm_tiling(192, 32, 24)[0] == 64
+    assert M._gmm_tiling(512, 2048, 10944) == (512, 512, 512)
+    cfg = M.Lfm2MoeConfig(num_experts_per_tok=6)
+    assert (M._narrow_rows(cfg, 16384), 6 * 16384) == (33792, 98304)  # 2.75 x 12,288, in tiles of 512
+
+
+# -- refusals, arithmetic, the species ------------------------------------------------------------------
+
+
+def _pool(n=3):
+    rng = np.random.default_rng(8)
+    return [deepseek_v2_genome().default()] + [deepseek_v2_genome().sample(rng) for _ in range(n - 1)]
+
+
+@pytest.mark.parametrize("bad,why", [
+    (dict(layer_types=("latent_attention", "linear_attention", "latent_attention")), "layer_types"),
+    (dict(kv_lora_rank=0), "rank and head sizes"),
+    (dict(v_head_dim=0), "rank and head sizes"),
+    (dict(qk_rope_head_dim=3), "even rope size"),
+    (dict(rope_scaling={"factor": 40, "type": "yarn"}), "rope_scaling needs"),
+    (dict(moe_intermediate_size=0), "shared experts of width 0"),
+    (dict(scoring_func="tanh"), "scoring_func"),
+    (dict(balance_rule="none"), "balance_rule"),
+])
+def test_a_configuration_that_cannot_run_is_refused_before_anything_compiles(tokens, bad, why, monkeypatch):
+    monkeypatch.setattr(M, "_programs", lambda cfg: pytest.fail("a program was asked for"))
+    with pytest.raises(ValueError, match=why):
+        M.Lfm2MoeModel.cross_validate_population(tokens[0], tokens[1], _pool(1), **model_kwargs(**bad))
+
+
+def _published():
+    """(the configuration file, the family's module) of the benchmark's cell."""
+    names = ("family", "correct", "reference")
+    before = {n: sys.modules.pop(n, None) for n in names}
+    sys.path.insert(0, FAMILY)
+    try:
+        family = _load("family")
+    finally:
+        sys.path.remove(FAMILY)
+        for n in names:
+            sys.modules.pop(n, None)
+            if before[n] is not None:
+                sys.modules[n] = before[n]
+    with open(os.path.join(BENCH, "configs", "deepseek_v2_lite_ep8.json")) as fh:
+        return json.load(fh), family
+
+
+def test_the_published_cut_is_one_individual_wide_by_arithmetic():
+    config, family = _published()
+    params = family.model_params(config, 1, False)
+    params.pop("seed")
+    x = np.zeros((config["n_sequences"], config["data"]["seq_len"]), np.int32)
+    cfg = M._normalize_config(x, params)[0]
+    need = M.training_bytes(cfg)
+    assert need["params"] == 635_466_752 and need["state"] == 16 * need["params"]  # 635.5 M, 10.17 GB
+    assert round(need["state"] / 1e9, 2) == 10.17
+    assert 16e9 / 2 < need["total"] < 16e9, "one individual fits a 16 GB chip, two do not"
+    shapes = M.param_shapes(cfg)
+    count = lambda tree: sum(math.prod(s) for s in jax.tree_util.tree_leaves(tree, is_leaf=lambda s: isinstance(s, tuple)))
+    routed = shapes["layers"][1]
+    assert count(routed["latent"]) == 13_763_072 and count(routed["moe"]["shared"]) == 17_301_504
+    assert count({k: routed["moe"][k] for k in ("w1", "w3", "w2")}) == 69_206_016
+    assert count(shapes["layers"][0]) == 81_007_104 and shapes["embed"] == shapes["head"] == (12800, 2048)
+    assert cfg.gene_names[-1] == "aux_alpha" and cfg.tokens_per_step == 16384 and len(cfg.moe_layers) == 5
+    # every width as published, and the cut stated
+    for key, value in dict(hidden_size=2048, num_attention_heads=16, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                           v_head_dim=128, kv_lora_rank=512, intermediate_size=10944, moe_intermediate_size=1408,
+                           n_routed_experts=64, num_experts_per_tok=6, n_shared_experts=2, first_k_dense_replace=1,
+                           q_lora_rank=None).items():
+        assert config[key] == value, key
+    assert config["rope_scaling"] == YARN and set(config["reduced"]) == {
+        "num_hidden_layers", "num_experts_held", "vocab_size", "train_steps", "n_sequences"}
+    assert config["published"]["num_hidden_layers"] == 27 and config["published"]["vocab_size"] == 102400
+    pool = family.make_pool(4, [20260928], -3.5)
+    assert pool[0] == deepseek_v2_genome().default() and all(r["log10_lr"] <= -3.5 and "aux_alpha" in r for r in pool)
+
+
+def test_genome_individual_population_and_two_generations(tokens):
+    x, y = tokens
+    spec = deepseek_v2_genome()
+    assert spec.names == list(M.gene_names("aux_loss")) == lfm2_moe_genome().names[:4] + ["aux_alpha"]
+    assert spec.default() == dict(log10_lr=-3.5, warmup_frac=0.25, weight_decay=0.1, beta2=0.95, aux_alpha=0.001)
+    assert (spec.genes[-1].minimum, spec.genes[-1].maximum) == (0.0, 0.01)
+    assert M.gene_names("bias") == M.GENE_NAMES
+    np.testing.assert_array_equal(M.gene_vector(spec.default()), np.float32([-3.5, 0.25, 0.1, 0.95, 0.001]))
+    assert DeepseekV2Individual.model_cls is M.Lfm2MoeModel and DeepseekV2Individual.uses_jax
+    calls = []
+
+    class Counting(M.Lfm2MoeModel):
+        @classmethod
+        def cross_validate_population(cls, x_train, y_train, genomes, **config):
+            calls.append(len(genomes))
+            return super().cross_validate_population(x_train, y_train, genomes, **config)
+
+    class Species(DeepseekV2Individual):
+        model_cls = Counting
+
+    pop = Population(Species, x, y, size=3, seed=0, additional_parameters=model_kwargs(seed=1))
+    ga = GeneticAlgorithm(pop, seed=0)
+    ga.run(2)
+    assert calls and sum(calls) >= 3, "Population.evaluate must reach cross_validate_population"
+    best = ga.population.get_fittest()
+    assert best.get_fitness() < 0 and best.get_fitness() == max(ga.population.get_fitnesses())
+    single = DeepseekV2Individual(x, y, genes=best.get_genes(), additional_parameters=model_kwargs(seed=1))
+    assert single.get_fitness() == pytest.approx(best.get_fitness(), abs=0)
+    # a recipe of the other architecture is refused by name, not trained under a wrong fifth gene
+    with pytest.raises(KeyError, match="aux_alpha"):
+        M.Lfm2MoeModel.cross_validate_population(x, y, [lfm2_moe_genome().default()], **model_kwargs(seed=1))
+
+
+def test_the_worker_resolves_the_species():
+    from gentun_tpu.distributed.worker import _species
+
+    assert _species("deepseek-v2") is DeepseekV2Individual
+    with pytest.raises(SystemExit, match="deepseek-v2"):
+        _species("no-such-species")
+
+
+# -- telemetry ---------------------------------------------------------------------------------------
+
+
+class _Sink:
+    def __init__(self):
+        self.records = []
+
+    def record(self, rec):
+        self.records.append(rec)
+
+
+def test_fitness_is_the_same_with_telemetry_on_and_the_fetch_span_carries_the_balance_term(tokens):
+    x, y = tokens
+    kw = model_kwargs(seed=3)
+    pool = _pool()
+    base = M.Lfm2MoeModel.cross_validate_population(x, y, pool, **kw)
+    assert np.all(base < 0) and len(set(base.tolist())) == len(pool)
+    np.testing.assert_array_equal(M.Lfm2MoeModel.cross_validate_population(x, y, pool[::-1], **kw), base[::-1])
+    get_registry().reset()
+    sink = _Sink()
+    spans.set_run_sink(sink)
+    spans.enable()
+    try:
+        traced = M.Lfm2MoeModel.cross_validate_population(x, y, pool, **kw)
+    finally:
+        spans.disable()
+        spans.set_run_sink(None)
+    np.testing.assert_array_equal(traced, base)
+    fetched = [r["attrs"] for r in sink.records if r["type"] == "span" and r["kind"] == "fetch"]
+    assert len(fetched) == len(pool) and all(0.95 < a["aux_loss"] < 2.0 for a in fetched)  # 1 where routing is even
+    assert all(a["dropped"] == 0 and a["wide_buffer"] == 0 and np.shape(a["expert_rows"]) == (2, 2) for a in fetched)
+    assert get_registry().counter("aux_loss_total").value == pytest.approx(sum(a["aux_loss"] for a in fetched))
+    trained = [r["attrs"] for r in sink.records if r["type"] == "span" and r.get("attrs", {}).get("steps")]
+    assert [a["attention_kernel_layer_steps"] for a in trained] == [0] * len(pool)  # the CPU takes XLA's core
+
+
+def test_a_train_span_counts_the_latent_layers_that_ran_the_fused_core(kernel_on_the_cpu):
+    tok = np.random.default_rng(9).integers(0, 64, size=(6, 129)).astype(np.int32)
+    x, y = tok[:, :-1], tok[:, 1:]
+    m = {**MODEL, "hidden_size": 64, "num_attention_heads": 1, "num_hidden_layers": 2, "qk_nope_head_dim": 128,
+         "qk_rope_head_dim": 64, "v_head_dim": 128}
+    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs(m, compute_dtype="bfloat16"))
+    assert programs.attention_kernel_layers == 2  # a latent layer counts as an attention layer
+    get_registry().reset()
+    sink = _Sink()
+    spans.set_run_sink(sink)
+    spans.enable()
+    try:
+        loss = M._score_one(programs, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32), M.gene_vector(GENES),
+                            jnp.asarray(x), jnp.asarray(y), jnp.asarray([[0, 1], [2, 3], [0, 2]], np.int32),
+                            [jnp.asarray([4, 5])], [np.int32(s) for s in range(3)], 0)
+    finally:
+        spans.disable()
+        spans.set_run_sink(None)
+    assert 0 < loss < np.log(64) + 0.5
+    trained = [r["attrs"] for r in sink.records if r["type"] == "span" and r.get("attrs", {}).get("steps") == 3]
+    assert [a["attention_kernel_layer_steps"] for a in trained] == [6]  # 2 latent layers x 3 steps
+    assert get_registry().counter("attention_kernel_layer_steps_total").value == 6
+
+
+# -- scopes ------------------------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("op_name,placed", [
+    ("jit(lm_train_step)/jvp(layer1)/latent_attention/core/vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/"
+     "pallas_call", ("latent_core", "core")),
+    ("jit(lm_train_step)/transpose(jvp(jvp()))/checkpoint/layer3/latent_attention/core/mul", ("latent_core", "core")),
+    ("jit(lm_eval)/layer0/latent_attention/core/checkpoint/sngqk,sknd->sqngd/dot_general", ("latent_core", "core")),
+    ("jit(lm_train_step)/jvp(layer0)/latent_attention/down_proj/dot_general", ("latent_proj", "down_proj")),
+    ("jit(lm_train_step)/transpose(jvp(layer2))/latent_attention/up_proj/dot_general", ("latent_proj", "up_proj")),
+    ("jit(lm_train_step)/checkpoint/rematted_computation/layer2/latent_attention/rope/cos", ("latent_proj", "rope")),
+    ("jit(lm_eval)/layer5/latent_attention/out_proj/dot_general", ("latent_proj", "out_proj")),
+    ("jit(lm_train_step)/jvp(layer2)/latent_attention/add", ("latent_proj", "other")),
+    ("jit(lm_train_step)/jvp(layer2)/moe/shared/dot_general", ("shared_expert", "shared")),
+    ("jit(lm_train_step)/transpose(jvp(layer4))/moe/shared/mul", ("shared_expert", "shared")),
+    ("jit(lm_train_step)/jvp(layer2)/cond/branch_0_fun/moe/experts/jit(gmm)/pallas_call", ("expert_mm", "experts")),
+    ("jit(lm_train_step)/jvp(layer3)/moe/router/reduce_max", ("moe_route", "router")),
+    ("jit(lm_train_step)/jvp(layer3)/aux_loss/reduce_sum", ("moe_route", "aux_loss")),
+    ("jit(lm_train_step)/transpose(jvp(layer3))/aux_loss/mul", ("moe_route", "aux_loss")),
+    ("jit(lm_eval)/layer5/cond/branch_0_fun/moe/dispatch/jit(_take)/gather", ("moe_route", "dispatch")),
+    ("jit(lm_train_step)/transpose(jvp(layer0))/dense_ffn/dot_general", ("dense_ffn", "layer0")),
+    ("jit(lm_train_step)/jvp(head)/slh,vh->slv/dot_general", ("head_loss", "head")),
+    ("jit(lm_train_step)/optimizer/sqrt", ("optimizer", "optimizer")),
+    ("jit(lm_train_step)/jvp(layer3)/rsqrt", ("rest", "layer3")),
+    ("", ("unattributed", "")),
+])
+def test_scope_rules_place_each_new_scope(op_name, placed):
+    assert scope_rules.classify(op_name) == placed and placed[0] in scope_rules.CLASSES
+
+
+def test_the_lowered_train_step_carries_every_new_scope(tokens):
+    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(layer_ids=(0, 1, 2)))
+    state = jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
+    text = programs.train_step.lower(state, *tokens, np.zeros((3, 2), np.int32), np.zeros(5, np.float32),
+                                     np.int32(0)).as_text(debug_info=True)
+    for scope in ("embed", "layer0", "layer1", "layer2", "latent_attention/down_proj", "latent_attention/up_proj",
+                  "latent_attention/rope", "latent_attention/core", "latent_attention/out_proj", "dense_ffn",
+                  "moe/router", "aux_loss", "moe/dispatch", "moe/experts", "moe/combine", "moe/shared", "head", "loss",
+                  "optimizer"):
+        assert scope in text, scope
+    assert "bias_update" not in text, "the aux_loss rule has no bias to step"
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    placed = {scope_rules.classify(n)[0] for n in names}
+    assert {"latent_core", "latent_proj", "shared_expert", "expert_mm", "moe_route", "dense_ffn", "head_loss",
+            "optimizer"} <= placed
+    assert all(scope_rules.classify(n)[0] == "moe_route" for n in names if "/aux_loss/" in n)
+    # the other architecture's program carries none of them, and keeps its own
+    lfm2 = M.Lfm2MoeModel.compiled_programs(tokens[0], hidden_size=32, layer_types=("conv", "full_attention"),
+                                            num_dense_layers=1, intermediate_size=48, moe_intermediate_size=24,
+                                            num_experts=8, num_experts_per_tok=2, held_experts=(2, 4),
+                                            num_attention_heads=4, num_key_value_heads=2, vocab_size=64, train_steps=3,
+                                            batch_sequences=2, eval_sequences=2, attn_block=8, compute_dtype="float32")
+    state = jax.eval_shape(lfm2.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
+    other = lfm2.train_step.lower(state, *tokens, np.zeros((3, 2), np.int32), np.zeros(5, np.float32),
+                                  np.int32(0)).as_text(debug_info=True)
+    assert "bias_update" in other and "aux_loss" not in state
+    assert not [s for s in ("latent_attention", "aux_loss", "moe/shared") if s in other]
+
+
+# -- the benchmark's counts and readers -----------------------------------------------------------------
+
+
+def test_executed_flops_at_the_published_widths_by_hand():
+    config, family = _published()
+    m = family.model_block(config)
+    assert flops.core_pair_elements(4096) == 10 * 1024 * 1024 and flops.core_pair_elements(512) == 512 * 512
+    forward = flops.core_flops(m, 1, 4096, 1, 0)
+    assert forward == 16 * 10 * 1024 * 1024 * 2 * (192 + 128)  # 16 heads, 10 block pairs, two products
+    assert flops.core_flops(m, 1, 4096, 0, 1) == 16 * 10 * 1024 * 1024 * 2 * (3 * 192 + 2 * 128)
+    per = flops.forward_flops_per_token(m, 4096)
+    assert per["attention_core"] == 6 * forward / 4096 and per["head"] == 2 * 2048 * 12800
+    latent = 2 * (2048 * 16 * 192 + 2048 * 576 + 512 * 16 * 256 + 16 * 128 * 2048)
+    routed = 2 * 2048 * 64 + 6 * 2048 * 2816
+    assert per["linear"] == 6 * latent + 6 * 2048 * 10944 + 5 * routed
+    rows_a_token = 5 * 6 / 8
+    total = sum(per.values()) + rows_a_token * 3 * 2 * 2048 * 1408
+    assert total == pytest.approx(0.7486e9, rel=1e-3)  # the issue's 0.749 GFLOP a token forward
+    step = flops.train_flops(m, 16384, 16384 * rows_a_token, 4096)
+    assert step == pytest.approx(49.74e12, rel=1e-3)
+    # the core is bound by compute, six times over
+    work, moved = flops.core_flops(m, 4, 4096, 2, 1), flops.core_bytes(m, 4, 4096, 2, 1)
+    assert moved == 4 * 16 * 4096 * (2 * (2 * 640 + 4) + 2 * 1280 + 4) and work / 197e12 > 6 * moved / 819e9
+    # the grouped products at 12,288 rows a layer-step: bound by compute too
+    rows = 12288.0 * 40
+    assert flops.expert_mm_flops(m, rows, 4) / 197e12 > flops.expert_mm_bytes(m, rows, 4, 40) / 819e9
+
+
+@pytest.fixture()
+def layer_metric():
+    """A reader of ``benchmark/layer_metrics/`` by name, loaded as ``run.py``
+    loads it (the family's directory and the harness's on ``sys.path``)."""
+    names = ("dsv2_spans", "scope_rules", "scope_reduce", "spanlib", "trace_reduce", "flops", "family", "correct",
+             "reference")
+    before = {n: sys.modules.pop(n, None) for n in names}
+    sys.path[:0] = [FAMILY, BENCH]
+    try:
+        yield lambda name: _load(os.path.join("..", "..", "layer_metrics", name))
+    finally:
+        del sys.path[:2]
+        for n in names:
+            sys.modules.pop(n, None)
+            if before[n] is not None:
+                sys.modules[n] = before[n]
+
+
+def _span(kind, t, attrs):
+    return {"type": "span", "kind": kind, "t_wall": t, "dur_s": 0.001, "attrs": attrs}
+
+
+def test_the_balance_reader_averages_the_windows_fetch_spans_and_a_program_without_the_attribute_reads_nothing(
+        layer_metric):
+    reader = layer_metric("dsv2_aux_loss_mean")
+    window = {"window": (10.0, 20.0)}
+    records = [_span("fetch", 5.0, {"individual": 0, "aux_loss": 9.0}),  # set-up's warm-up call
+               _span("fetch", 11.0, {"individual": 0, "aux_loss": 1.0}), _span("fetch", 12.0, {"individual": 1, "aux_loss": 1.5}),
+               _span("fetch", 13.0, {"other": 1, "aux_loss": 7.0})]
+    assert reader.read({**window, "records": records}) == 1.25
+    assert reader.read({**window, "records": [_span("fetch", 11.0, {"individual": 0, "expert_rows": [[1]]})]}) is None
+    assert reader.read({**window, "records": records[:1]}) is None
+
+
+def test_the_core_roofline_reader_divides_the_kernels_flops_by_the_kernels_own_time(layer_metric):
+    """Two traced individuals of the published cell: 2 x 8 steps x 4 sequences x 6 layers of the core; the time is
+    that of the instructions that carry the kernels' name, not the class's transposes and casts."""
+    reader = layer_metric("dsv2_latent_core_roofline_share")
+    config, family = _published()
+    m = family.model_block(config)
+    work = 6 * flops.core_flops(m, 2 * 8 * 4, 4096, 2, 1)
+    least = work / 197e12
+    table = {"individuals": 2, "programs": {
+        "jit_lm_train_step(1)": {"ops": {"splash_mqa_fwd_residuals.1": ["latent_core", 0.4 * least / 0.5],
+                                         "splash_mqa_dkv_no_residuals.1": ["latent_core", 0.6 * least / 0.5],
+                                         "fusion.7": ["latent_core", 5.0], "fusion.9": ["latent_proj", 3.0]}},
+        "jit_lm_eval(2)": {"ops": {"splash_mqa_fwd_no_residuals.1": ["latent_core", 9.0]}}}}
+    run = {"scope_table": table, "config": config, "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
+    assert reader.read(run) == pytest.approx(50.0)
+    table["programs"]["jit_lm_train_step(1)"]["ops"] = {"fusion.7": ["latent_core", least / 0.25]}  # XLA's core
+    assert reader.read(run) == pytest.approx(25.0)
+    assert reader.read({**run, "scope_table": None}) is None
+    parent = {"individuals": 2, "programs": {"jit_lm_train_step(1)": {"ops": {"fusion.1": ["rest", 1.0]}}}}
+    assert reader.read({**run, "scope_table": parent}) is None  # a program without the scope reports nothing

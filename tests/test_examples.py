@@ -45,6 +45,7 @@ TINY = {
         "--generations", "1", "--population", "4", "--kfold", "2",
     ],
 }
+_TINY_ROUTED = ["--generations", "1", "--population", "3", "--train-steps", "2", "--seq-len", "32", "--n-sequences", "8"]
 
 
 @pytest.mark.parametrize("name", sorted(TINY))
@@ -53,6 +54,15 @@ def test_example_runs_end_to_end(name, capsys):
     mod.main(TINY[name])
     out = capsys.readouterr().out
     assert "best" in out  # every driver prints its best individual
+
+
+@pytest.mark.parametrize("arch,species", [("lfm2", "Lfm2MoeIndividual"), ("deepseek-v2", "DeepseekV2Individual")])
+def test_the_routed_recipe_search_reaches_both_architectures(arch, species, capsys):
+    mod = _load_example("routed_recipe_search")
+    mod.main(["--arch", arch, *_TINY_ROUTED])
+    out = capsys.readouterr().out
+    assert f"species {species}" in out and "best recipe" in out
+    assert ("aux_alpha" if arch == "deepseek-v2" else "bias_step") in out
 
 
 def test_distributed_example_demo_runs(capsys):

@@ -359,7 +359,7 @@ def test_the_fused_core_is_the_blockwise_core_to_bfloat16(length, group, kernel_
     q = jnp.asarray(rng.normal(size=(1, length, 1, group, 64)), jnp.float32)
     k, v, weight = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
                     for shape in ((1, length, 1, 64), (1, length, 1, 64), q.shape))
-    cores = (M._kernel_core, functools.partial(M._blockwise_core, block=256))
+    cores = (functools.partial(M._kernel_core, scale=0.125), functools.partial(M._blockwise_core, scale=0.125, block=256))
     got, want = (jax.jit(jax.grad(lambda q, k, v: jnp.sum((core(q, k, v) * weight).astype(jnp.float32)), (0, 1, 2)))(
         q, k, v) for core in cores)
     assert all(_rel(g, w) < 0.01 for g, w in zip(got, want)), [_rel(g, w) for g, w in zip(got, want)]
