@@ -340,7 +340,8 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
-    """Rotary embedding, rotate-half layout, on (sequences, length, heads, head size); float32.
+    """Rotary embedding, rotate-half layout, on (sequences, length, ..., head size):
+    heads, or key-value heads and their query heads, between; float32.
     With ``scaling`` (YaRN) the frequencies are :func:`yarn_inv_freq`'s and cos
     and sin carry ``mscale / mscale_all_dim``."""
     half = x.shape[-1] // 2
@@ -351,7 +352,8 @@ def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
         amplitude = yarn_mscale(scaling["factor"], scaling["mscale"]) \
             / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"])
     angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    per_position = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
+    cos, sin = jnp.cos(angle).reshape(per_position), jnp.sin(angle).reshape(per_position)
     if amplitude != 1.0:
         cos, sin = cos * amplitude, sin * amplitude
     x1, x2 = x[..., :half], x[..., half:]
@@ -383,22 +385,28 @@ def _kernel_core(q, k, v, scale: float):
     """The causal core as one fused kernel with its own backward: scores, the
     running maximum, sum and accumulator in float32 on the chip's fast memory,
     the output and the log-sum-exp kept for the backward pass, never a score.
-    ``q`` (sequences, length, kv heads, queries a kv head, head size), float32;
-    ``k`` (sequences, length, kv heads, head size) and ``v`` (the same, at a
-    head size of its own) in the compute dtype.  ``scale`` multiplies the scores
-    and goes onto ``q`` before its cast (exact where it is a power of two: a head
-    size of 64).  A head size of q and k over 128 that is no whole number of 128
-    lanes (latent attention's 192) is padded with zero columns, which is exact.  The
+    ``q`` (sequences, length, kv heads, queries a kv head, head size), float32
+    or the compute dtype; ``k`` (sequences, length, kv heads, head size) and
+    ``v`` (the same, at a head size of its own) in the compute dtype.  Those are
+    shapes, not copies: the kernel reads head-major arrays (sequences, kv
+    heads, ..., length, head size), and an operand built from head-major parts
+    (:func:`_head_major`) reaches it without a transposing pass.  ``scale``
+    multiplies the scores and goes onto ``q`` in float32 before its one cast
+    (exact where it is a power of two: a head size of 64).  A head size of q and
+    k over 128 that is no whole number of 128 lanes (latent attention's 192) gets
+    zero columns, which is exact, and gets them FIRST: XLA writes a padding of a
+    concatenation as one pass over the parts, the scale and the cast in it,
+    where a padding of a scaled array is a pass of its own (PERF.md, PR 33).  The
     kernel takes one key-value head with its query heads (no copy of K or V);
     ``vmap`` makes the key-value heads and the sequences its outer grid."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
 
     length, group = q.shape[1], q.shape[3]
-    q = (q * scale).astype(k.dtype)
     pad = -q.shape[-1] % 128 if q.shape[-1] > 128 else 0
     if pad:  # zero columns add nothing to a score: 192 as 256 took 13.9 ms against 15.4 (PERF.md, PR 32)
         q, k = (jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)]) for a in (q, k))
+    q = (q.astype(jnp.float32) * scale).astype(k.dtype)
     kernel = splash.make_splash_mqa_single_device(
         masks.MultiHeadMask([masks.CausalMask((length, length))] * group),
         block_sizes=splash.BlockSizes(**_kernel_blocks(length), use_fused_bwd_kernel=True))
@@ -456,30 +464,52 @@ def latent_softmax_scale(cfg: Lfm2MoeConfig) -> float:
     return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
 
 
+def _head_major(x, w):
+    """``x`` (sequences, length, in) times ``w`` (in, *heads, size), handed on as
+    (sequences, length, *heads, size) and written as (sequences, *heads, length,
+    size): the product emits the order the fused core reads, and the transpose
+    back to the token-major shape is a change of layout that XLA:TPU carries to
+    the consumer, not a pass over memory."""
+    return jnp.moveaxis(jnp.einsum("slh,h...d->s...ld", x.astype(w.dtype), w), -2, 1)
+
+
 def _latent_attention(p, x, cfg: Lfm2MoeConfig, dtype):
     """Causal latent attention (MLA, no query latent) on (sequences, length,
     hidden), as training runs it: keys and values expanded from the latent per
     head, the rope part of the key one head that every query head shares (a
     broadcast: its gradient is the sum over the heads), and the same causal core
-    as :func:`_attention` at ``heads`` key-value heads of one query head each."""
-    s, length, _ = x.shape
+    as :func:`_attention` at ``heads`` key-value heads of one query head each.
+
+    What crosses to :func:`_causal_core` is q (sequences, length, heads, 1,
+    nope + rope) and k (sequences, length, heads, nope + rope), ``[nope | rope]``
+    along the last axis, and v (sequences, length, heads, v size), all in the
+    compute dtype and each assembled once: ``W_q`` and ``W_kvb`` are applied as
+    their nope, rope and value column blocks, each product head-major
+    (:func:`_head_major`); rope is float32 arithmetic on the rope columns alone;
+    the concatenations, the shared key's broadcast, the core's zero columns, scale
+    and cast are one pass an operand (under ``core``)."""
+    s, length, hidden = x.shape
     nh, rank, nope, rope, vd = (cfg.num_attention_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                                 cfg.qk_rope_head_dim, cfg.v_head_dim)
     with jax.named_scope("down_proj"):
-        q = _dot(x, p["q"], dtype).reshape(s, length, nh, nope + rope)
+        # one query head a key-value head: the core's group axis, in the weights' shape so that no reshape follows
+        w_q = p["q"].astype(dtype).reshape(hidden, nh, 1, nope + rope)
+        q_nope, q_pe = _head_major(x, w_q[..., :nope]), _head_major(x, w_q[..., nope:])
         latent = _dot(x, p["kva"], dtype)
         c = _rms_norm(latent[..., :rank], p["kv_norm"], cfg.norm_eps).astype(dtype)
     with jax.named_scope("up_proj"):
-        kv = _dot(c, p["kvb"], dtype).reshape(s, length, nh, nope + vd)
+        w_kvb = p["kvb"].astype(dtype).reshape(rank, nh, nope + vd)
+        k_nope, v = _head_major(c, w_kvb[..., :nope]), _head_major(c, w_kvb[..., nope:])
     with jax.named_scope("rope"):
-        q_pe = _rope(q[..., nope:].astype(jnp.float32), cfg.rope_theta, cfg.yarn)
-        k_pe = _rope(latent[..., None, rank:].astype(jnp.float32), cfg.rope_theta, cfg.yarn)
-        q = jnp.concatenate([q[..., :nope].astype(jnp.float32), q_pe], axis=-1)
-        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_pe.astype(dtype), (s, length, nh, rope))], axis=-1)
+        q_pe = _rope(q_pe.astype(jnp.float32), cfg.rope_theta, cfg.yarn).astype(dtype)
+        k_pe = _rope(latent[..., None, rank:].astype(jnp.float32), cfg.rope_theta, cfg.yarn).astype(dtype)
     with jax.named_scope("core"):
-        out = _causal_core(q[:, :, :, None, :], k, kv[..., nope:], latent_softmax_scale(cfg), cfg)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (s, length, nh, rope))], axis=-1)
+        out = _causal_core(q, k, v, latent_softmax_scale(cfg), cfg)
     with jax.named_scope("out_proj"):
-        return _dot(out.reshape(s, length, nh * vd), p["o"], dtype)
+        w_o = p["o"].astype(dtype).reshape(nh, vd, hidden)
+        return jnp.einsum("slnd,ndh->slh", out.reshape(s, length, nh, vd), w_o)
 
 
 def _dense_ffn(p, x, dtype):

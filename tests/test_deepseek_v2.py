@@ -215,28 +215,38 @@ def _latent_case(length: int, heads: int, sequences: int = 1, nope: int = 128, r
     return cfg, p, x
 
 
-def test_k_pe_is_one_head_that_every_query_head_shares():
+@pytest.mark.parametrize("core", ["blockwise", "kernel"])
+def test_k_pe_is_one_head_that_every_query_head_shares(core, request):
     """``W_kva``'s rope columns make one key head; every one of the query heads
     reads it: the gradient that reaches those columns through head ``h`` alone
-    (the other heads' outputs weighted 0) is nonzero for each ``h``, and the
-    heads' gradients add up to the whole one."""
-    cfg, p, x = _latent_case(32, 4, nope=8, rope=4, vd=8, rank=16, hidden=32)
+    (the other heads' rows of ``W_o`` zeroed) is nonzero for each ``h``, and the
+    heads' gradients add up to the whole one.  By the fused core too (its
+    kernels interpreted, at the published head sizes, which it needs): there the
+    key's rope part is broadcast where the operand is assembled, and what comes
+    back is the sum of ``dk``'s rope columns over the heads."""
+    if core == "kernel":
+        request.getfixturevalue("kernel_on_the_cpu")
+        heads, length, sizes = 3, 128, {}
+    else:
+        heads, length, sizes = 4, 32, dict(nope=8, rope=4, vd=8)
+    cfg, p, x = _latent_case(length, heads, rank=16, hidden=32, **sizes)
     x = x.astype(jnp.float32)
-    probe = jnp.asarray(np.random.default_rng(1).normal(size=(1, 32, 4, 8)), jnp.float32)
-    identity_o = {**p, "o": jnp.eye(4 * 8, 32, dtype=jnp.float32)}  # the output is the heads' values, side by side
+    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
 
     def through(heads_mask):
+        o = p["o"] * jnp.repeat(heads_mask, cfg.v_head_dim)[:, None]  # head h's values reach the output times mask[h]
+
         def value(kva):
-            out = M._latent_attention({**identity_o, "kva": kva}, x, cfg, jnp.float32).reshape(1, 32, 4, 8)
-            return jnp.sum(out * probe * heads_mask[None, None, :, None])
+            return jnp.sum(M._latent_attention({**p, "kva": kva, "o": o}, x, cfg, jnp.float32) * probe)
         return jax.grad(value)(p["kva"])[:, cfg.kv_lora_rank:]  # the rope columns
 
     with HIGHEST:
-        whole = through(jnp.ones(4))
-        per_head = [through(jnp.eye(4)[h]) for h in range(4)]
-    assert p["kva"].shape == (32, 16 + 4), "one rope head of 4 columns, not one a query head"
-    assert all(float(jnp.abs(g).max()) > 1e-4 for g in per_head)
-    np.testing.assert_allclose(sum(per_head), whole, rtol=1e-4, atol=1e-6)
+        whole = through(jnp.ones(heads))
+        per_head = [through(jnp.eye(heads)[h]) for h in range(heads)]
+    assert p["kva"].shape == (32, 16 + cfg.qk_rope_head_dim), "one rope head, not one a query head"
+    largest = float(jnp.abs(whole).max())
+    assert all(float(jnp.abs(g).max()) > 1e-3 * largest for g in per_head)
+    np.testing.assert_allclose(sum(per_head), whole, rtol=1e-4, atol=1e-5 * largest)
 
 
 @pytest.fixture()
@@ -257,28 +267,105 @@ def _rel(a, b) -> float:
     return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
+def _value_and_gradients(operator, p, x):
+    """(output, gradients of the weights, gradient of the input) of ``sum(operator(p, x) * probe)``, jitted."""
+    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
+
+    def value(p, x):
+        out = operator(p, x)
+        return jnp.sum(out.astype(jnp.float32) * probe), out
+
+    (_, out), (dp, dx) = jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
+    return out, dp, dx
+
+
+def _assert_within_bfloat16(got, want):
+    """The output within two bfloat16 steps of its size, the gradients of the
+    input and of every projection within 1% in norm."""
+    (out, dp, dx), (ref, ref_dp, ref_dx) = got, want
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.abs(ref).max() > 0.5 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
+    assert _rel(dx, ref_dx) < 0.01
+    for name in ("q", "kva", "kv_norm", "kvb", "o"):
+        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
+
+
+def _by_the_blockwise_core(operator, p, x):
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(M, "_use_attention_kernel", lambda length: False)
+        return _value_and_gradients(operator, p, x)
+
+
 def test_the_fused_core_is_the_blockwise_core_to_bfloat16_at_192_and_128(kernel_on_the_cpu):
     """One ``_latent_attention`` call by both cores at the published head sizes
     (q and k of 128 + 64, padded to 256 for the kernel; v of 128), products in
     bfloat16: the output within two bfloat16 steps of its size, the gradients of
     the input and of every projection within 1% in norm."""
     cfg, p, x = _latent_case(512, 2)
-    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
+    operator = lambda p, x: M._latent_attention(p, x, cfg, jnp.bfloat16)
+    _assert_within_bfloat16(_value_and_gradients(operator, p, x), _by_the_blockwise_core(operator, p, x))
 
-    def value(p, x):
-        out = M._latent_attention(p, x, cfg, jnp.bfloat16)
-        return jnp.sum(out.astype(jnp.float32) * probe), out
 
-    run = lambda: jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
-    (_, out), (dp, dx) = run()
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(M, "_use_attention_kernel", lambda length: False)
-        (_, ref), (ref_dp, ref_dx) = run()
-    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
-    assert np.abs(ref).max() > 0.5 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
-    assert _rel(dx, ref_dx) < 0.01
-    for name in ("q", "kva", "kv_norm", "kvb", "o"):
-        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
+def _reference_latent(p, x, cfg):
+    """``reference.py::latent_attention`` a sequence, float32, on the operator's own weights."""
+    m = dict(num_attention_heads=cfg.num_attention_heads, kv_lora_rank=cfg.kv_lora_rank,
+             qk_nope_head_dim=cfg.qk_nope_head_dim, qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+             rms_norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta, rope_scaling=YARN)
+    return jnp.stack([R.latent_attention(p, xs, m, lambda a: a) for xs in x])
+
+
+@pytest.mark.parametrize("against", ["blockwise-bfloat16", "reference-float32"])
+def test_latent_attention_and_every_gradient_by_the_fused_core(against, kernel_on_the_cpu):
+    """The operator whole (two sequences, three heads of 128 + 64 / 128: the
+    column blocks of ``W_q`` and ``W_kvb``, the head-major products, the
+    assembly of the kernel's operands and their transposes back) with the fused
+    core interpreted: in bfloat16 against the same call by the blockwise core,
+    in float32 against the plain reference, at the tolerances of the test above."""
+    cfg, p, x = _latent_case(256, 3, sequences=2)
+    dtype = jnp.bfloat16 if against == "blockwise-bfloat16" else jnp.float32
+    x = x.astype(dtype)
+    operator = lambda p, x: M._latent_attention(p, x, cfg, dtype)
+    got = _value_and_gradients(operator, p, x)
+    if against == "blockwise-bfloat16":
+        want = _by_the_blockwise_core(operator, p, x)
+    else:
+        with HIGHEST:
+            want = _value_and_gradients(lambda p, x: _reference_latent(p, x, cfg), p, x)
+    _assert_within_bfloat16(got, want)
+
+
+def _equations(jaxpr, scope=""):
+    """(primitive, the named scopes it was traced under, its outputs' avals) of every equation, nested ones too."""
+    for eqn in jaxpr.eqns:
+        here = "/".join(filter(None, [scope, str(eqn.source_info.name_stack)]))
+        yield eqn.primitive.name, here, [v.aval for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, here)
+
+
+def test_what_reaches_the_fused_core_is_assembled_once_in_the_compute_dtype(monkeypatch):
+    """With the kernel chosen, the traced operator has no float32 array of
+    tokens x heads x (nope + rope) elements or more, and no copy of ``k_pe`` a
+    head, outside the ``core`` scope, where the kernel's operands are assembled
+    (there XLA fuses them into the pass that writes the operand: PERF.md, PR
+    33); and the kernel's three operands are head-major, 256 / 256 / 128 wide."""
+    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
+    cfg, p, x = _latent_case(256, 3, sequences=2)
+    s, length, heads, rope = 2, 256, 3, cfg.qk_rope_head_dim
+    wide = s * length * heads * (cfg.qk_nope_head_dim + rope)
+    traced = list(_equations(jax.make_jaxpr(lambda p, x: M._latent_attention(p, x, cfg, jnp.bfloat16))(p, x).jaxpr))
+    assert any("core" in scope.split("/") for _, scope, _ in traced)
+    outside = [(name, scope, aval) for name, scope, avals in traced for aval in avals
+               if "core" not in scope.split("/") and hasattr(aval, "shape")]
+    assert not [(n, sc, a) for n, sc, a in outside if a.dtype == jnp.float32 and a.size >= wide]
+    assert not [(n, sc, a) for n, sc, a in outside if a.shape[:3] == (s, length, heads) and a.shape[-1] == rope
+                and n == "broadcast_in_dim"]
+    kernel = [avals for name, scope, avals in traced if name == "custom_vjp_call"]
+    assert len(kernel) == 1 and kernel[0][0].shape == (s, heads, 1, length, cfg.v_head_dim)
+    operands = [a for name, scope, avals in traced if name == "transpose" and "core" in scope.split("/") for a in avals]
+    assert sorted(a.shape for a in operands if a.shape[:2] == (s, heads)) == sorted(
+        [(s, heads, 1, length, 256), (s, heads, length, 256), (s, heads, length, cfg.v_head_dim)])
+    assert all(a.dtype == jnp.bfloat16 for a in operands)
 
 
 @pytest.mark.parametrize("core", ["kernel", "blockwise"])
