@@ -7,9 +7,13 @@ a fitness is minus the validation loss after a few AdamW steps
 architecture runs: ``--arch lfm2`` is LFM2-24B-A2B's layer pattern (short
 convolutions, GQA, sigmoid router with a bias rule; species ``lfm2-moe``),
 ``--arch deepseek-v2`` DeepSeek-V2-Lite's (latent attention, shared experts,
-softmax router, balance loss; species ``deepseek-v2``).  The widths here are
-toys; the published widths and their one-chip cut are
-``benchmark/configs/lfm2_24b_a2b_ep8.json`` and ``deepseek_v2_lite_ep8.json``.
+softmax router, balance loss; species ``deepseek-v2``), ``--arch mellum2``
+Mellum2-12B-A2.5B-Instruct's (sliding-window and full attention 3:1, each
+layer type with its own mask and rope, a stated head size, every layer routed,
+weights normalised over the chosen; the same species: its genome is the
+``aux_loss`` balance rule's).  The widths here are toys; the published widths
+and their one-chip cut are ``benchmark/configs/lfm2_24b_a2b_ep8.json``,
+``deepseek_v2_lite_ep8.json`` and ``mellum2_12b_a2p5b_ep8.json``.
 """
 
 import argparse
@@ -37,6 +41,14 @@ ARCHITECTURES = {
                           original_max_position_embeddings=64),
         n_shared_experts=2, scoring_func="softmax", norm_topk_prob=False, balance_rule="aux_loss",
         tie_word_embeddings=False)),
+    "mellum2": (DeepseekV2Individual, dict(
+        _COMMON, num_dense_layers=0, num_experts_per_tok=3, num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",), sliding_window=16, qk_norm=False,
+        rope_parameters={"sliding_attention": dict(rope_type="default", rope_theta=500000),
+                         "full_attention": dict(rope_type="yarn", rope_theta=500000, factor=16, beta_fast=32,
+                                                beta_slow=1, original_max_position_embeddings=32,
+                                                attention_factor=1.2772588722239782)},
+        scoring_func="softmax", norm_topk_prob=True, balance_rule="aux_loss", tie_word_embeddings=False)),
 }
 
 

@@ -369,7 +369,9 @@ class DeepseekV2Individual(Lfm2MoeIndividual):
     ``scoring_func`` ``softmax``, ``balance_rule`` ``aux_loss``, ...).
 
     Genome: :func:`gentun_tpu.genes.deepseek_v2_genome` (``aux_alpha``, the
-    balance term's weight, where LFM2 has the router bias's step).
+    balance term's weight, where LFM2 has the router bias's step).  It is the
+    ``aux_loss`` balance rule's genome, so a Mellum2 share (``layer_types`` of
+    ``sliding_attention`` and ``full_attention``) searches with this species too.
     """
 
     def build_spec(self, **params) -> GenomeSpec:

@@ -1,11 +1,11 @@
 """Routed language models as fitness models: one expert-parallel rank's share, trained under a recipe genome.
 
-The second jax family beside the Genetic-CNN (``models/cnn.py``), and two
+The second jax family beside the Genetic-CNN (``models/cnn.py``), and three
 architectures of it, told apart by the configuration alone (which operator a
-layer has, how the router scores, whether shared experts stand beside the
-routed ones, whether the head is tied, which balance rule runs): one evaluator,
-one train step builder, one expert layer, one causal core and one optimizer
-serve both.
+layer has, which mask and which rope an attention layer's type gives it, how
+the router scores, whether shared experts stand beside the routed ones,
+whether the head is tied, which balance rule runs): one evaluator, one train
+step builder, one expert layer, one causal core and one optimizer serve all.
 
 ``LFM2-24B-A2B`` (``model_type`` ``lfm2_moe``,
 https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json; the
@@ -47,6 +47,25 @@ balance comes from a term of the loss::
            sequence that chose e), a count without a gradient; P_e = the sequence's mean of p_e (``aux_alpha``
            is the recipe's fifth gene where LFM2 has ``bias_step``; no bias, no rule outside the gradient)
 
+``Mellum2-12B-A2.5B-Instruct`` (``model_type`` ``mellum``,
+https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/main/config.json):
+``layer_types`` mixes ``sliding_attention`` and ``full_attention`` 3:1, and the
+layer's type picks its mask and its rope; the head size is stated (32 heads of
+128 on a hidden size of 2304), q and k have no norm, every layer is routed (no
+dense layer, no shared expert), 8 of 64 experts a token with their weights
+normalised over the chosen::
+
+    Op = sliding_attention:  GQA as above without the norm of q and k; key j visible to query i iff
+         0 <= i - j <= sliding_window - 1; rope at theta^(-2c/head) (``rope_parameters.sliding_attention``)
+    Op = full_attention:     key j visible iff j <= i; YaRN's frequencies, cos and sin times ``attention_factor``
+         (``rope_parameters.full_attention``).  The same two cores run both masks: the fused kernel is handed
+         the library's ``LocalMask`` or ``CausalMask`` and visits only the block pairs that hold a visible key
+         (15 of 64 against 36 at 8,192 positions and 1,024 x 1,024 blocks: :func:`_kernel_visits`); XLA's
+         query blocks are handed the window's keys alone
+    FFN routed:  p = softmax(W_r x); chosen = top-k; w = p[chosen] / sum p[chosen]; the held experts' part
+    loss = cross-entropy (head untied) + alpha * the balance term above: the *recipe's* (``aux_alpha``), the
+           published config gives it no weight
+
 What differs from the CNN family, by design:
 
 - **Genes are data, not structure.**  Every individual is the same
@@ -84,6 +103,7 @@ after each step ``b_e += u * sign(mean load - load_e)`` over all experts
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -113,6 +133,11 @@ _GMM_TILING = (512, 512, 512)
 #: The fused attention kernel's blocks (splash attention): queries x keys a grid step holds and
 #: the keys one product inside it takes, forward, then the same for the one backward kernel
 #: (dk, dv and dq together).  Set by chip runs at the published shape (PERF.md, PR 31).
+#: A layer whose mask is a window takes the same blocks: the kernel visits the block pairs that
+#: hold a visible key, and smaller blocks waste less of a 1,024-key window (45 of 256 pairs at
+#: 512 x 512 against 15 of 64, a quarter less area) but pay more grid steps for it: forward and
+#: backward of one layer-step at Mellum2's shape took 16.8 ms at these blocks, 20.4 at 512 /
+#: 512 / 512, 18.0-21.2 at four other shapes (PERF.md, PR 34).
 _ATTN_KERNEL_BLOCKS = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
                            block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512)
 #: The narrow row buffer holds this many times the rows a routed layer sends this rank on
@@ -162,10 +187,16 @@ class Lfm2MoeConfig:
     norm_topk_prob: bool = True  # the chosen weights divided by their sum
     balance_rule: str = "bias"  # a router bias stepped outside the gradient, or "aux_loss": a term of the loss
     tie_word_embeddings: bool = True
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    # what a third architecture sets (Mellum2): a head size that is stated (0: hidden_size over the heads), no
+    # per-head norm of q and k, ``sliding_attention`` layers' window (a query's own position counts) and rope
+    # by layer type (``rope_parameters``: (layer type, the published block as sorted items) pairs, or None)
+    head_dim: int = 0
+    qk_norm: bool = True
+    sliding_window: int = 0
+    rope_parameters: Optional[Tuple[Tuple[str, Tuple[Tuple[str, Any], ...]], ...]] = None
+    def __post_init__(self):
+        if not self.head_dim:  # frozen: the stated size takes the place of the implied one once, here
+            object.__setattr__(self, "head_dim", self.hidden_size // self.num_attention_heads)
 
     @property
     def gene_names(self) -> Tuple[str, ...]:
@@ -175,6 +206,24 @@ class Lfm2MoeConfig:
     def yarn(self) -> Optional[Dict[str, Any]]:
         """``rope_scaling`` as the mapping it was given as, or None."""
         return dict(self.rope_scaling) if self.rope_scaling else None
+
+    def rope_of(self, kind: str) -> Tuple[float, Optional[Dict[str, Any]]]:
+        """(theta, YaRN's block or None) of a layer of type ``kind``: ``rope_parameters``' entry for
+        it where the configuration keys its rope by layer type, else ``rope_theta`` and no scaling."""
+        block = dict(dict(self.rope_parameters or ()).get(kind, ()))
+        if not block:
+            return self.rope_theta, None
+        return float(block["rope_theta"]), block if block.get("rope_type", "default") == "yarn" else None
+
+    def window_of(self, kind: str) -> Optional[int]:
+        """The keys a query of a ``kind`` layer sees, its own position counted, or None for all before it."""
+        return self.sliding_window if kind == "sliding_attention" else None
+
+    @property
+    def typed_attention(self) -> bool:
+        """Whether attention layers are told apart by type (their scope is the type's name, with
+        ``proj``, ``rope`` and ``core`` inside); LFM2's one kind keeps its one ``attention`` scope."""
+        return "sliding_attention" in self.layer_types
 
     @property
     def n_held(self) -> int:
@@ -207,8 +256,9 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
                                "kvb": (rank, nh * (nope + vd)), "o": (nh * vd, h)}
         else:
             layer["attn"] = {"q": (h, cfg.num_attention_heads * hd), "k": (h, cfg.num_key_value_heads * hd),
-                             "v": (h, cfg.num_key_value_heads * hd), "o": (cfg.num_attention_heads * hd, h),
-                             "q_norm": (hd,), "k_norm": (hd,)}
+                             "v": (h, cfg.num_key_value_heads * hd), "o": (cfg.num_attention_heads * hd, h)}
+            if cfg.qk_norm:
+                layer["attn"].update(q_norm=(hd,), k_norm=(hd,))
         if i < cfg.num_dense_layers:
             f = cfg.intermediate_size
             layer["dense"] = {"w1": (h, f), "w3": (h, f), "w2": (f, h)}
@@ -250,7 +300,11 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
 
 
 #: The operators a layer can have (``layer_types``).
-LAYER_KINDS = ("conv", "full_attention", "latent_attention")
+LAYER_KINDS = ("conv", "full_attention", "sliding_attention", "latent_attention")
+#: Those of them that are attention, whose causal core is :func:`_causal_core`'s.
+ATTENTION_KINDS = LAYER_KINDS[1:]
+#: The masks the core has, as the spans and the counter name them.
+MASKS = ("causal", "window")
 
 #: A program of this family is one individual wide, always: the published cut
 #: takes 10.4 of a chip's 16 GB in state alone, and a second width would be a
@@ -281,12 +335,22 @@ def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """megablox tiles for a product of ``m`` rows, contraction ``k`` and ``n``
     columns: the forward product, and each of the backward pass's two at its own
     sizes (the kernels look the tiles up by shape).  The row tile divides the
-    buffer.  A width of up to four tiles that is no whole number of them (1408 =
-    11 x 128) is one tile, whole: a ragged last tile of 512 runs 8% of its
-    columns empty, tiles of 128 read the rows eleven times; on the chip the three
-    products forward and backward took 6.43 ms whole, 7.83 ragged, 11.8 at 128
-    (12,288 rows of a 33,792-row buffer; PERF.md, PR 32)."""
-    fit = lambda size, tile: tile if size % tile == 0 or size > 4 * tile else size
+    buffer.  A size that is no whole number of tiles takes the widest tile of
+    whole 128 lanes, up to four tiles, that divides it: 1408 = 11 x 128 whole,
+    2304 = 4.5 x 512 as two of 1152; no tile runs ragged.  On the chip the three
+    products forward and backward at width 1408 took 6.43 ms whole, 7.83 at a
+    ragged 512 (8% of its columns empty), 11.8 at 128 (the rows read eleven
+    times; 12,288 rows of a 33,792-row buffer; PERF.md, PR 32), and at 2304 x 896
+    5.14 ms at 1152, 5.26 at 768, 5.69 at 384, 5.80 at a ragged 512, while 2304
+    whole runs the weight-gradient kernel out of VMEM (16,384 rows of a
+    45,056-row buffer; PERF.md, PR 34).  A size with no such tile is one tile,
+    whole, up to four tiles, and ragged beyond."""
+    def fit(size: int, tile: int) -> int:
+        if size % tile == 0:
+            return tile
+        whole = size if size <= 4 * tile else tile
+        return next((t for t in range(min(size, 4 * tile) // 128 * 128, 0, -128) if size % t == 0), whole)
+
     return math.gcd(m, _GMM_TILING[0]), fit(k, _GMM_TILING[1]), fit(n, _GMM_TILING[2])
 
 
@@ -339,18 +403,28 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
+def yarn_amplitude(scaling: Mapping[str, Any]) -> float:
+    """What YaRN multiplies cos and sin by (so a score carries its square), in either published
+    form: an explicit ``attention_factor`` (Mellum2's ``rope_parameters``), or ``mscale`` over
+    ``mscale_all_dim`` (DeepSeek-V2's ``rope_scaling``, which puts its ``m^2`` on the softmax
+    scale: :func:`latent_softmax_scale`); with neither, ``0.1 ln factor + 1``."""
+    if "attention_factor" in scaling:
+        return float(scaling["attention_factor"])
+    if "mscale" in scaling and "mscale_all_dim" in scaling:
+        return yarn_mscale(scaling["factor"], scaling["mscale"]) / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"])
+    return yarn_mscale(scaling["factor"], 1.0)
+
+
 def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
     """Rotary embedding, rotate-half layout, on (sequences, length, ..., head size):
     heads, or key-value heads and their query heads, between; float32.
     With ``scaling`` (YaRN) the frequencies are :func:`yarn_inv_freq`'s and cos
-    and sin carry ``mscale / mscale_all_dim``."""
+    and sin carry :func:`yarn_amplitude`."""
     half = x.shape[-1] // 2
     if scaling is None:
         inv_freq, amplitude = theta ** (-jnp.arange(half, dtype=jnp.float32) / half), 1.0
     else:
-        inv_freq = jnp.asarray(yarn_inv_freq(x.shape[-1], theta, scaling))
-        amplitude = yarn_mscale(scaling["factor"], scaling["mscale"]) \
-            / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"])
+        inv_freq, amplitude = jnp.asarray(yarn_inv_freq(x.shape[-1], theta, scaling)), yarn_amplitude(scaling)
     angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
     per_position = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
     cos, sin = jnp.cos(angle).reshape(per_position), jnp.sin(angle).reshape(per_position)
@@ -381,7 +455,41 @@ def _use_attention_kernel(length: int) -> bool:
     return True
 
 
-def _kernel_core(q, k, v, scale: float):
+def _splash_kernel(length: int, group: int, window: Optional[int]):
+    """The fused kernel of one key-value head and its ``group`` query heads over
+    ``length`` positions.  The mask is an object of the library: ``CausalMask``,
+    or, with ``window``, ``LocalMask`` reaching ``window - 1`` keys back and none
+    ahead (a query's own position counts into the window).  The library turns it
+    into a table of the (query block, key block) pairs that hold a visible key
+    and runs its grid over those alone, forward and backward, so a windowed
+    layer's cost follows its window."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
+
+    shape = (length, length)
+    mask = masks.CausalMask(shape) if window is None else masks.LocalMask(shape, window_size=(window - 1, 0), offset=0)
+    return splash.make_splash_mqa_single_device(
+        masks.MultiHeadMask([mask] * group),
+        block_sizes=splash.BlockSizes(**_kernel_blocks(length), use_fused_bwd_kernel=True))
+
+
+@functools.lru_cache(maxsize=16)
+def _kernel_visits(length: int, window: Optional[int]) -> Dict[str, int]:
+    """The block pairs the fused kernel visits for one head and sequence, read
+    from the kernel's own table (:func:`_splash_kernel`), never from a formula
+    beside it: ``pairs`` and their area ``elements`` of the forward kernel,
+    ``pairs_bwd`` and ``elements_bwd`` of the backward one.  What a roofline's
+    count of the executed work reads (the ``train`` span carries it)."""
+    with jax.ensure_compile_time_eval():
+        kernel = _splash_kernel(length, 1, window)
+    blocks = _kernel_blocks(length)
+    visited = lambda info: int(np.count_nonzero(np.asarray(info.block_mask)[0]))  # one table serves every head
+    forward, backward = visited(kernel.fwd_mask_info), visited(kernel.dkv_mask_info)
+    return {"pairs": forward, "elements": forward * blocks["block_q"] * blocks["block_kv"],
+            "pairs_bwd": backward, "elements_bwd": backward * blocks["block_q_dkv"] * blocks["block_kv_dkv"]}
+
+
+def _kernel_core(q, k, v, scale: float, window: Optional[int] = None):
     """The causal core as one fused kernel with its own backward: scores, the
     running maximum, sum and accumulator in float32 on the chip's fast memory,
     the output and the log-sum-exp kept for the backward pass, never a score.
@@ -398,26 +506,25 @@ def _kernel_core(q, k, v, scale: float):
     concatenation as one pass over the parts, the scale and the cast in it,
     where a padding of a scaled array is a pass of its own (PERF.md, PR 33).  The
     kernel takes one key-value head with its query heads (no copy of K or V);
-    ``vmap`` makes the key-value heads and the sequences its outer grid."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
-
+    ``vmap`` makes the key-value heads and the sequences its outer grid.  With
+    ``window`` a query sees that many keys, its own the last (:func:`_splash_kernel`)."""
     length, group = q.shape[1], q.shape[3]
     pad = -q.shape[-1] % 128 if q.shape[-1] > 128 else 0
     if pad:  # zero columns add nothing to a score: 192 as 256 took 13.9 ms against 15.4 (PERF.md, PR 32)
         q, k = (jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)]) for a in (q, k))
     q = (q.astype(jnp.float32) * scale).astype(k.dtype)
-    kernel = splash.make_splash_mqa_single_device(
-        masks.MultiHeadMask([masks.CausalMask((length, length))] * group),
-        block_sizes=splash.BlockSizes(**_kernel_blocks(length), use_fused_bwd_kernel=True))
+    kernel = _splash_kernel(length, group, window)
     out = jax.vmap(jax.vmap(kernel))(q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     return out.transpose(0, 3, 1, 2, 4)
 
 
-def _blockwise_core(q, k, v, scale: float, block: int):
+def _blockwise_core(q, k, v, scale: float, block: int, window: Optional[int] = None):
     """The causal core in query blocks of at most ``block`` as XLA programs: no
     (length x length) score array per head is alive, a block's scores are.
-    Arguments as :func:`_kernel_core`'s."""
+    Arguments as :func:`_kernel_core`'s.  With ``window`` a block is handed the
+    keys from the last whole block that its first query still sees, and the mask
+    hides what lies ``window`` or more positions back: the same function as the
+    kernel's, at a cost that follows the window too."""
     length, dtype = q.shape[1], k.dtype
     block = min(block, length)
     if length % block:
@@ -425,37 +532,53 @@ def _blockwise_core(q, k, v, scale: float, block: int):
     q = q.astype(dtype)
 
     @jax.checkpoint
-    def one_block(qb, kb, vb, first):
+    def one_block(qb, kb, vb, first, first_key):
         scores = jnp.einsum("sqngd,sknd->sngqk", qb, kb, preferred_element_type=jnp.float32) * scale
-        seen = (first + jnp.arange(qb.shape[1]))[:, None] >= jnp.arange(kb.shape[1])[None, :]
+        back = (first + jnp.arange(qb.shape[1]))[:, None] - (first_key + jnp.arange(kb.shape[1]))[None, :]
+        seen = back >= 0 if window is None else (back >= 0) & (back < window)
         prob = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1).astype(dtype)
         return jnp.einsum("sngqk,sknd->sqngd", prob, vb)
 
-    out = [one_block(q[:, i:i + block], k[:, :i + block], v[:, :i + block], i) for i in range(0, length, block)]
+    out = []
+    for i in range(0, length, block):
+        lo = 0 if window is None else max(0, (i - window + 1) // block * block)
+        out.append(one_block(q[:, i:i + block], k[:, lo:i + block], v[:, lo:i + block], i, lo))
     return jnp.concatenate(out, axis=1)
 
 
-def _causal_core(q, k, v, scale: float, cfg: Lfm2MoeConfig):
-    """The causal core (scores, softmax, values) of either attention operator:
+def _causal_core(q, k, v, scale: float, cfg: Lfm2MoeConfig, window: Optional[int] = None):
+    """The causal core (scores, softmax, values) of every attention operator:
     the fused kernel where :func:`_use_attention_kernel` says so and XLA's query
-    blocks of ``attn_block`` elsewhere: one function, chosen by backend and shape."""
+    blocks of ``attn_block`` elsewhere: one function, chosen by backend and shape.
+    ``window``: the keys a query sees, its own position the last of them (a
+    ``sliding_attention`` layer); None: every key up to its own."""
     if _use_attention_kernel(q.shape[1]):
-        return _kernel_core(q, k, v, scale)
-    return _blockwise_core(q, k, v, scale, cfg.attn_block)
+        return _kernel_core(q, k, v, scale, window)
+    return _blockwise_core(q, k, v, scale, cfg.attn_block, window)
 
 
-def _attention(p, x, cfg: Lfm2MoeConfig, dtype):
-    """Causal GQA on (sequences, length, hidden); the core is :func:`_causal_core`'s."""
+def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
+    """Causal GQA on (sequences, length, hidden); the core is :func:`_causal_core`'s.
+    The layer's type ``kind`` decides its mask (:meth:`Lfm2MoeConfig.window_of`)
+    and its rope (:meth:`Lfm2MoeConfig.rope_of`); where the configuration tells
+    attention layers apart by type, ``proj``, ``rope`` and ``core`` are scopes."""
     s, length, _ = x.shape
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    q = _dot(x, p["q"], dtype).reshape(s, length, nh, hd)
-    k = _dot(x, p["k"], dtype).reshape(s, length, nkv, hd)
-    v = _dot(x, p["v"], dtype).reshape(s, length, nkv, hd)
-    q = _rope(_rms_norm(q, p["q_norm"], cfg.norm_eps), cfg.rope_theta)
-    k = _rope(_rms_norm(k, p["k_norm"], cfg.norm_eps), cfg.rope_theta).astype(dtype)
-    q = q.reshape(s, length, nkv, nh // nkv, hd)
-    out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg)
-    return _dot(out.reshape(s, length, nh * hd), p["o"], dtype)
+    part = jax.named_scope if cfg.typed_attention else (lambda name: contextlib.nullcontext())
+    theta, scaling = cfg.rope_of(kind)
+    with part("proj"):
+        q = _dot(x, p["q"], dtype).reshape(s, length, nh, hd)
+        k = _dot(x, p["k"], dtype).reshape(s, length, nkv, hd)
+        v = _dot(x, p["v"], dtype).reshape(s, length, nkv, hd)
+    with part("rope"):
+        normed = lambda a, weight: _rms_norm(a, p[weight], cfg.norm_eps) if cfg.qk_norm else a
+        q = _rope(normed(q, "q_norm"), theta, scaling)
+        k = _rope(normed(k, "k_norm"), theta, scaling).astype(dtype)
+    with part("core"):
+        q = q.reshape(s, length, nkv, nh // nkv, hd)
+        out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg, cfg.window_of(kind))
+    with part("proj"):
+        return _dot(out.reshape(s, length, nh * hd), p["o"], dtype)
 
 
 def latent_softmax_scale(cfg: Lfm2MoeConfig) -> float:
@@ -525,7 +648,9 @@ def _route(router, bias, x, cfg: Lfm2MoeConfig):
     scores = jax.nn.sigmoid(logits) if cfg.scoring_func == "sigmoid" else jax.nn.softmax(logits, axis=-1)
     _, chosen = jax.lax.top_k(scores + bias if cfg.balance_rule == "bias" else scores, cfg.num_experts_per_tok)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    return chosen, picked / (picked.sum(-1, keepdims=True) + ROUTE_EPS) if cfg.norm_topk_prob else picked, scores
+    if cfg.norm_topk_prob:  # LFM2's published rule guards its sum of sigmoids; a sum of softmax shares needs none
+        picked = picked / (picked.sum(-1, keepdims=True) + (ROUTE_EPS if cfg.scoring_func == "sigmoid" else 0.0))
+    return chosen, picked, scores
 
 
 def _balance_term(scores, chosen, sequences: int, cfg: Lfm2MoeConfig):
@@ -676,8 +801,8 @@ def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x):
             with jax.named_scope("latent_attention"):
                 h = x + _latent_attention(p["latent"], normed, cfg, dtype)
         else:
-            with jax.named_scope("attention"):
-                h = x + _attention(p["attn"], normed, cfg, dtype)
+            with jax.named_scope(kind if cfg.typed_attention else "attention"):
+                h = x + _attention(p["attn"], normed, cfg, dtype, kind)
         normed = _rms_norm(h, p["ffn_norm"], cfg.norm_eps).astype(dtype)
         if "dense" in p:
             with jax.named_scope("dense_ffn"):
@@ -736,13 +861,19 @@ class Lfm2MoePrograms(NamedTuple):
     ``load`` this step's rows per held expert per routed layer.  ``eval(params, bias, x, y, rows)
     -> loss per token`` of the sequences ``rows``.  ``attention_kernel_layers``:
     the attention layers (GQA and latent alike) whose core these programs run as the fused
-    kernel (:func:`_use_attention_kernel`, decided when they were built): all or none."""
+    kernel (:func:`_use_attention_kernel`, decided when they were built): all or none.
+    ``kernel_layers_by_mask``: the same by the core's mask, ``(("causal", n), ("window", n))``, a mask the
+    configuration has no layer of left out;
+    ``kernel_visits``: per mask that runs as the kernel, the block pairs the kernel visits a head
+    and sequence (:func:`_kernel_visits`, as sorted items)."""
 
     config: Lfm2MoeConfig
     init: Any
     train_step: Any
     eval: Any
     attention_kernel_layers: int
+    kernel_layers_by_mask: Tuple[Tuple[str, int], ...] = ()
+    kernel_visits: Tuple[Tuple[str, Tuple[Tuple[str, int], ...]], ...] = ()
 
 
 @functools.lru_cache(maxsize=8)
@@ -811,9 +942,19 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         return token_loss(logits, y_all[rows])
 
     train_step.__name__, init.__name__ = "lm_train_step", "lm_init"
-    n_attention = sum(kind in ("full_attention", "latent_attention") for kind in cfg.layer_types)
-    kernel_layers = n_attention if _use_attention_kernel(cfg.seq_len) else 0
-    return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval), kernel_layers)
+    by_mask, visits = [], []
+    for mask in MASKS:
+        window = cfg.sliding_window if mask == "window" else None
+        layers = sum(kind in ATTENTION_KINDS and (cfg.window_of(kind) is None) == (window is None)
+                     for kind in cfg.layer_types)
+        if not layers:
+            continue
+        engaged = _use_attention_kernel(cfg.seq_len)
+        by_mask.append((mask, layers if engaged else 0))
+        if engaged:
+            visits.append((mask, tuple(sorted(_kernel_visits(cfg.seq_len, window).items()))))
+    return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval),
+                           sum(n for _, n in by_mask), tuple(by_mask), tuple(visits))
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -831,6 +972,9 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
             config[key] = tuple(config[key])
     if isinstance(config.get("rope_scaling"), Mapping):
         config["rope_scaling"] = tuple(sorted(config["rope_scaling"].items()))
+    if isinstance(config.get("rope_parameters"), Mapping):
+        config["rope_parameters"] = tuple(sorted((kind, tuple(sorted(block.items())))
+                                                 for kind, block in config["rope_parameters"].items()))
     config.setdefault("layer_ids", tuple(range(len(config.get("layer_types", Lfm2MoeConfig.layer_types)))))
     cfg = Lfm2MoeConfig(**{**config, "seq_len": x.shape[1], "n_sequences": x.shape[0]})
     if len(cfg.layer_ids) != len(cfg.layer_types) or set(cfg.layer_types) - set(LAYER_KINDS):
@@ -842,6 +986,18 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
         wanted = {"factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim", "original_max_position_embeddings"}
         if cfg.yarn is not None and not wanted <= set(cfg.yarn):
             raise ValueError(f"rope_scaling needs {sorted(wanted)} (YaRN); got {sorted(cfg.yarn)}")
+    if "sliding_attention" in cfg.layer_types and cfg.sliding_window <= 0:
+        raise ValueError(f"a sliding_attention layer needs its sliding_window; got {cfg.sliding_window}")
+    for kind, block in cfg.rope_parameters or ():
+        block, yarn = dict(block), {"factor", "beta_fast", "beta_slow", "original_max_position_embeddings"}
+        if kind not in ATTENTION_KINDS or "rope_theta" not in block or block.get("rope_type", "default") not in \
+                ("default", "yarn") or (block.get("rope_type") == "yarn" and not yarn <= set(block)):
+            raise ValueError(f"rope_parameters[{kind!r}] = {block}: a layer type of {ATTENTION_KINDS} with its "
+                             f"rope_theta and a rope_type of default or yarn (yarn needs {sorted(yarn)})")
+    if set(cfg.layer_types) & {"full_attention", "sliding_attention"} and \
+            (cfg.head_dim % 2 or cfg.num_attention_heads % cfg.num_key_value_heads):
+        raise ValueError(f"head_dim {cfg.head_dim} must be even and {cfg.num_attention_heads} heads a whole number "
+                         f"of query heads to each of the {cfg.num_key_value_heads} key-value heads")
     if cfg.n_shared_experts < 0 or (cfg.n_shared_experts and cfg.moe_intermediate_size <= 0):
         raise ValueError(f"{cfg.n_shared_experts} shared experts of width {cfg.moe_intermediate_size}")
     if cfg.scoring_func not in ("sigmoid", "softmax") or cfg.balance_rule not in _BALANCE_GENE:
@@ -945,12 +1101,16 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     cfg = programs.config
     shape = (cfg.tokens_per_step, cfg.train_steps)
     kernel_layer_steps = programs.attention_kernel_layers * cfg.train_steps
+    by_mask = {mask: layers * cfg.train_steps for mask, layers in programs.kernel_layers_by_mask}
+    kernel_attrs = {f"attention_kernel_layer_steps_{mask}": n for mask, n in by_mask.items()}
+    for mask, visits in programs.kernel_visits:  # static: what the mask's kernel visits a layer, head and sequence
+        kernel_attrs.update({f"attention_kernel_{name}_{mask}": n for name, n in visits})
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)
     with phase("train", {"steps": cfg.train_steps, "tokens": cfg.train_steps * cfg.tokens_per_step,
                           "pop": PROGRAM_WIDTH, "individual": individual,
-                          "attention_kernel_layer_steps": kernel_layer_steps},
+                          "attention_kernel_layer_steps": kernel_layer_steps, **kernel_attrs},
                 program=(id(programs.train_step), shape)) as sp:
         for step in steps:
             state, _, _ = programs.train_step(state, x, y, train_rows, genes, step)
@@ -963,7 +1123,8 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
             losses, rows, dropped, wide, balance = jax.device_get(
                 (losses, state["rows"], state["dropped"], state["wide_buffer"], state.get("aux_loss")))
             _count_expert_rows(cfg, rows, int(dropped), int(wide))
-            _get_registry().counter("attention_kernel_layer_steps_total").inc(kernel_layer_steps)
+            for mask, n in by_mask.items():
+                _get_registry().counter("attention_kernel_layer_steps_total", mask=mask).inc(n)
             sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=int(wide))
             if balance is not None:  # the ``aux_loss`` rule: the term before its weight, a routed layer and step
                 balance = float(balance) / (len(cfg.moe_layers) * cfg.train_steps)

@@ -667,7 +667,7 @@ def test_a_train_span_counts_the_latent_layers_that_ran_the_fused_core(kernel_on
     assert 0 < loss < np.log(64) + 0.5
     trained = [r["attrs"] for r in sink.records if r["type"] == "span" and r.get("attrs", {}).get("steps") == 3]
     assert [a["attention_kernel_layer_steps"] for a in trained] == [6]  # 2 latent layers x 3 steps
-    assert get_registry().counter("attention_kernel_layer_steps_total").value == 6
+    assert get_registry().counter("attention_kernel_layer_steps_total", mask="causal").value == 6
 
 
 # -- scopes ------------------------------------------------------------------------------------------

@@ -56,13 +56,14 @@ def test_example_runs_end_to_end(name, capsys):
     assert "best" in out  # every driver prints its best individual
 
 
-@pytest.mark.parametrize("arch,species", [("lfm2", "Lfm2MoeIndividual"), ("deepseek-v2", "DeepseekV2Individual")])
+@pytest.mark.parametrize("arch,species", [("lfm2", "Lfm2MoeIndividual"), ("deepseek-v2", "DeepseekV2Individual"),
+                                          ("mellum2", "DeepseekV2Individual")])
 def test_the_routed_recipe_search_reaches_both_architectures(arch, species, capsys):
     mod = _load_example("routed_recipe_search")
     mod.main(["--arch", arch, *_TINY_ROUTED])
     out = capsys.readouterr().out
     assert f"species {species}" in out and "best recipe" in out
-    assert ("aux_alpha" if arch == "deepseek-v2" else "bias_step") in out
+    assert ("bias_step" if arch == "lfm2" else "aux_alpha") in out
 
 
 def test_distributed_example_demo_runs(capsys):

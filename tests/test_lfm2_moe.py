@@ -439,7 +439,7 @@ def test_a_train_span_and_the_counter_say_how_many_attention_layers_ran_the_kern
     assert 0 < loss < np.log(64) + 0.5
     trained = [r["attrs"] for r in sink.records if r["type"] == "span" and r["attrs"].get("steps") == 3]
     assert [a["attention_kernel_layer_steps"] for a in trained] == [3, 3]  # 1 attention layer x 3 steps, twice
-    assert get_registry().counter("attention_kernel_layer_steps_total").value == 6
+    assert get_registry().counter("attention_kernel_layer_steps_total", mask="causal").value == 6
 
 
 def _pool(n=3):
@@ -481,7 +481,7 @@ def test_fitness_is_a_function_of_the_genome_in_any_position_and_with_telemetry_
     assert get_registry().counter("row_buffer_wide_total").value == 0 == sum(a["wide_buffer"] for a in fetched)
     trained = [r["attrs"] for r in device if r["attrs"].get("steps")]
     assert len(trained) == len(pool) and all(a["attention_kernel_layer_steps"] == 0 for a in trained)
-    assert get_registry().counter("attention_kernel_layer_steps_total").value == 0
+    assert get_registry().counter("attention_kernel_layer_steps_total", mask="causal").value == 0
     assert M.Lfm2MoeModel.cross_validate_population(x, y, [], **kw).shape == (0,)
     other_seed = M.Lfm2MoeModel.cross_validate_population(x, y, pool[:1], **{**kw, "seed": 4})
     assert other_seed[0] != base[0]

@@ -1,0 +1,718 @@
+"""The routed family's third architecture (Mellum2-12B-A2.5B-Instruct through
+``models/lfm2_moe.py``) against its plain reference
+(``benchmark/families/mellum/reference.py``), at small sizes on the CPU.
+
+System and reference are compared in float32 on seeded weights: per layer kind
+(a windowed layer, a full one, one period) and whole on logits, loss (with the
+balance term) and gradients; over two train steps; the share test ties the
+expert layer's cut to the uncut layer with attention counted once.  Then what is
+the architecture's own: the window through both cores (XLA's query blocks, and
+the fused kernel in Pallas' interpret mode) at a length of several windows and
+at one shorter than the window; the kernel's mask object against the
+reference's 0/1 array entry by entry; the block pairs the kernel visits against
+``flops.py``'s arithmetic; rope by layer type by hand; the grouped products'
+tiles at a contraction of 2304; refusals; the scopes, the spans and the labelled
+counter; the readers of the new per-layer metrics; and that the two
+architectures that were there still build what they built.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import math
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from gentun_tpu import deepseek_v2_genome
+from gentun_tpu.models import lfm2_moe as M
+from gentun_tpu.telemetry import spans
+from gentun_tpu.telemetry.registry import get_registry
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+FAMILY = os.path.join(BENCH, "families", "mellum")
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(f"mel_family_{os.path.basename(name)}", os.path.join(FAMILY, name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+R = _load("reference")
+flops = _load("flops")
+scope_rules = _load("scope_rules")
+
+ROPE = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                           "original_max_position_embeddings": 8192, "beta_fast": 32, "beta_slow": 1,
+                           "attention_factor": 1.2772588722239782},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
+PERIOD = ["sliding_attention", "sliding_attention", "sliding_attention", "full_attention"]
+MODEL = dict(hidden_size=40, head_dim=16, num_attention_heads=4, num_key_value_heads=2, moe_intermediate_size=24,
+             num_experts=8, num_experts_per_tok=3, held_experts=[2, 4], num_hidden_layers=4, layer_types=PERIOD,
+             vocab_size=64, rms_norm_eps=1e-6, rope_parameters=ROPE, sliding_window=6, train_steps=3)
+GENES = dict(log10_lr=-2.5, warmup_frac=0.5, weight_decay=0.1, beta2=0.95, aux_alpha=0.05)
+HIGHEST = jax.default_matmul_precision("highest")
+STD = 0.15  # narrow layers: wider weights, or the operators vanish beside the residual
+
+
+def model_kwargs(m=MODEL, **over):
+    """``Lfm2MoeModel``'s keyword arguments that make it the reference's model ``m``: the published keys."""
+    kw = {k: m[k] for k in ("hidden_size", "head_dim", "moe_intermediate_size", "num_experts", "num_experts_per_tok",
+                            "num_attention_heads", "num_key_value_heads", "vocab_size", "rope_parameters",
+                            "sliding_window", "train_steps")}
+    kw.update(layer_types=tuple(m["layer_types"]), num_dense_layers=0, held_experts=tuple(m["held_experts"]),
+              norm_eps=m["rms_norm_eps"], qk_norm=False, scoring_func="softmax", norm_topk_prob=True,
+              balance_rule="aux_loss", tie_word_embeddings=False, batch_sequences=2, eval_sequences=2, attn_block=8,
+              compute_dtype="float32")
+    kw.update(over)
+    return kw
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    tok = np.random.default_rng(0).integers(0, 64, size=(10, 25)).astype(np.int32)  # 24 positions: four windows of 6
+    return tok[:, :-1], tok[:, 1:]
+
+
+def config_of(tokens, m=MODEL, **over) -> M.Lfm2MoeConfig:
+    return M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(m, **over)).config
+
+
+NO_BIAS = jnp.zeros((8, 8), jnp.float32)  # the state's bias: zeros, never read under the ``aux_loss`` rule
+
+LAYER_CASES = {"a_windowed_layer": {**MODEL, "num_hidden_layers": 1, "layer_types": ["sliding_attention"], "held_experts": [1, 5]},
+               "a_full_layer": {**MODEL, "num_hidden_layers": 1, "layer_types": ["full_attention"], "held_experts": [1, 5]},
+               "one_period": {**MODEL, "held_experts": [1, 5]},
+               "two_periods": {**MODEL, "num_hidden_layers": 8, "layer_types": PERIOD * 2}}
+
+
+@pytest.mark.parametrize("case", sorted(LAYER_CASES))
+def test_logits_loss_with_the_balance_term_and_gradients_match_the_reference(case, tokens):
+    m = LAYER_CASES[case]
+    cfg = config_of(tokens, m)
+    assert cfg.head_dim == 16 != cfg.hidden_size // cfg.num_attention_heads and cfg.num_dense_layers == 0
+    w = R.seeded_weights(m, 7, STD)
+    assert "q_norm" not in w["layers"][0]["attn"] and M.param_shapes(cfg)["layers"][0]["attn"].keys() == \
+        w["layers"][0]["attn"].keys()
+    x, y = tokens[0][:2], tokens[1][:2]
+    alpha = 0.05
+
+    def system_loss(params):
+        logits, load, stats = M.forward(cfg, params, NO_BIAS, x, remat=True)
+        return M.token_loss(logits, y).mean() + alpha * stats.balance, (logits, load, stats)
+
+    def reference_loss(params):
+        out = [R.forward(m, params, xs) for xs in x]
+        nll = jnp.mean(jnp.stack([R.token_loss(o[0], ys) for o, ys in zip(out, y)]))
+        balance = sum(o[2] for o in out) / len(out)
+        return nll + alpha * balance, (jnp.stack([o[0] for o in out]), sum(o[1] for o in out), balance)
+
+    with HIGHEST:
+        (loss, (logits, load, stats)), grads = jax.jit(jax.value_and_grad(system_loss, has_aux=True))(w)
+        (ref_loss, (ref_logits, ref_load, ref_balance)), ref_grads = jax.jit(
+            jax.value_and_grad(reference_loss, has_aux=True))(w)
+    np.testing.assert_allclose(logits, ref_logits, atol=2e-5)
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
+    np.testing.assert_allclose(stats.balance, ref_balance, rtol=1e-6)
+    assert float(ref_balance) > 0.9 * m["num_hidden_layers"]  # ~1 a routed layer, and every layer is routed
+    np.testing.assert_array_equal(load, ref_load)
+    assert int(stats.dropped) == 0
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
+        np.testing.assert_allclose(g, r, atol=2e-6, rtol=1e-4, err_msg=jax.tree_util.keystr(path))
+        assert float(jnp.abs(r).max()) > 0 or "embed" in str(path), \
+            f"{jax.tree_util.keystr(path)}: the reference's gradient is all zero"
+
+
+def _program_steps(programs, weights, x, y, rows, steps, genes=GENES):
+    state = programs.init(jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
+    state = {**state, "params": jax.tree_util.tree_map(jnp.asarray, weights)}
+    losses, loads = [], []
+    for s in range(steps):
+        state, loss, held = programs.train_step(state, jnp.asarray(x), jnp.asarray(y), jnp.asarray(rows),
+                                                jnp.asarray(M.gene_vector(genes)), np.int32(s))
+        losses.append(float(loss))
+        loads.append(np.asarray(held))
+    return state, losses, loads
+
+
+def test_two_train_steps_match_the_reference(tokens):
+    x, y = tokens
+    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs())
+    assert programs.config.gene_names == tuple(deepseek_v2_genome().names)
+    w = R.seeded_weights(MODEL, 5, STD)
+    rows = np.array([[0, 1], [2, 3], [4, 5]], np.int32)
+    with HIGHEST:
+        state, losses, loads = _program_steps(programs, w, x, y, rows, 2)
+        ref = R.train(MODEL, w, [(x[r], y[r]) for r in rows[:2]], GENES)
+    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)  # the balance term included
+    np.testing.assert_allclose(float(state["aux_loss"]), sum(ref["balances"]), rtol=1e-6)
+    for got, want in zip(loads, ref["loads"]):
+        np.testing.assert_array_equal(got, want[:, 2:4])
+    for (path, a), b, start in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
+                                   jax.tree_util.tree_leaves(ref["weights"]), jax.tree_util.tree_leaves(w)):
+        change, ref_change = np.asarray(a) - start, np.asarray(b) - start
+        assert np.abs(ref_change).max() > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(change, ref_change, atol=3e-5, err_msg=jax.tree_util.keystr(path))
+    with HIGHEST:
+        got = programs.eval(state["params"], state["bias"], jnp.asarray(x), jnp.asarray(y), jnp.asarray([8, 9]))
+        want = R.eval_token_loss(MODEL, ref["weights"], x[8:10], y[8:10])
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_the_eight_shares_with_attention_counted_once_add_up_to_the_uncut_layer(kind, tokens):
+    """16 experts in 8 shares of 2, 8 a token: each share's program computes the
+    attention, the residual and its own routed experts' part, the weights
+    normalised over all the chosen eight; the routed parts, with what every
+    share computes alike counted once, are the uncut reference's layer output."""
+    m = {**MODEL, "num_hidden_layers": 1, "layer_types": [kind], "num_experts": 16, "num_experts_per_tok": 8}
+    x = tokens[0][:2]
+    uncut = {**m, "held_experts": [0, 16]}
+    w_all = R.seeded_weights(uncut, 11, STD)
+    layer_w = w_all["layers"][0]
+    embedded = w_all["embed"][x]
+    share_of = lambda first, last: dict(layer_w, moe={k: (v[first:last] if k in ("w1", "w3", "w2") else v)
+                                                      for k, v in layer_w["moe"].items()})
+    identity = lambda a: a
+    with HIGHEST:
+        whole = jnp.stack([R.layer(uncut, 0, identity, layer_w, jnp.asarray(e))[0] for e in embedded])
+        # attention and residual, no routed expert: what every share computes alike
+        alike = jnp.stack([R.layer({**uncut, "held_experts": [0, 0]}, 0, identity, share_of(0, 0), jnp.asarray(e))[0]
+                           for e in embedded])
+        total = alike
+        for first in range(0, 16, 2):
+            cfg = config_of(tokens, {**m, "held_experts": [first, first + 2]})
+            out, _ = M._layer(cfg, 0, jnp.float32, share_of(first, first + 2), None, jnp.asarray(embedded))
+            part = out - alike
+            assert float(jnp.abs(part).max()) > 0
+            total = total + part
+    np.testing.assert_allclose(total, whole, atol=2e-5)
+    assert float(jnp.abs(whole - alike).max()) > 1e-3, "the routed experts are part of the layer"
+
+
+# -- the window, through both cores ---------------------------------------------------------------------
+
+
+def _core_case(length: int, seed: int = 0, sequences: int = 2, kv_heads: int = 2, group: int = 2, head: int = 16):
+    rng = np.random.default_rng([seed, length])
+    q = jnp.asarray(rng.normal(size=(sequences, length, kv_heads, group, head)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(sequences, length, kv_heads, head)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(sequences, length, kv_heads, head)), jnp.float32)
+    return q, k, v
+
+
+def _reference_core(q, k, v, scale, kind, window):
+    """softmax over the keys with ``R.visible`` == 1, by the reference's explicit 0/1 array."""
+    length = q.shape[1]
+    i = jnp.arange(length)
+    mask = R.visible(i[:, None], i[None, :], kind, {"sliding_window": window})
+    scores = jnp.einsum("sqngd,sknd->sngqk", q, k) * scale
+    prob = jax.nn.softmax(jnp.where(mask == 1, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("sngqk,sknd->sqngd", prob, v)
+
+
+@pytest.mark.parametrize("length,window,block", [(96, 16, 8), (96, 16, 32), (64, 20, 16), (32, 48, 8), (24, 24, 8),
+                                                 (24, 1, 8)])
+def test_the_blockwise_core_under_a_window_is_the_references_masked_softmax(length, window, block):
+    """Several windows long (at blocks smaller and larger than the window, and a
+    window that is no whole number of blocks), shorter than the window (where it
+    is the causal core), and a window of one key (a query sees itself alone)."""
+    q, k, v = _core_case(length)
+    with HIGHEST:
+        got = M._blockwise_core(q, k, v, 0.25, block, window)
+        want = _reference_core(q, k, v, 0.25, "sliding_attention", window)
+        causal = M._blockwise_core(q, k, v, 0.25, block)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    np.testing.assert_allclose(causal, _reference_core(q, k, v, 0.25, "full_attention", window), atol=2e-6)
+    if window >= length:
+        np.testing.assert_allclose(got, causal, atol=2e-6)
+    else:
+        assert float(jnp.abs(got - causal).max()) > 1e-3
+    if window == 1:
+        np.testing.assert_allclose(got, jnp.broadcast_to(v[:, :, :, None, :], got.shape), atol=2e-6)
+
+
+def test_the_blockwise_cores_cost_follows_the_window():
+    """A query block is handed the keys from the last whole block its first query sees, not every earlier key."""
+    q, k, v = _core_case(128)
+    text = str(jax.make_jaxpr(lambda q, k, v: M._blockwise_core(q, k, v, 0.25, 16, 32))(q, k, v))
+    widths = {int(n) for n in re.findall(r"f32\[2,2,2,16,(\d+)\]", text)} - {1}  # (sequences, kv heads, group, queries, keys)
+    assert widths == {16, 32, 48}, widths  # 16 and 32 at the start, then the two blocks back and the block itself
+
+
+@pytest.fixture()
+def kernel_on_the_cpu(monkeypatch):
+    """The fused core chosen whatever the backend, its kernels interpreted: the
+    library's own factory is given ``interpret=True``, the program has no such knob."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    monkeypatch.setattr(splash, "make_splash_mqa_single_device",
+                        __import__("functools").partial(splash.make_splash_mqa_single_device, interpret=True))
+    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
+    M._programs.cache_clear()
+    M._kernel_visits.cache_clear()
+    yield
+    M._programs.cache_clear()
+    M._kernel_visits.cache_clear()
+
+
+@pytest.mark.parametrize("length,window", [(512, 128), (256, 384)])
+def test_the_fused_core_under_a_window_is_the_blockwise_core_to_bfloat16(length, window, kernel_on_the_cpu, monkeypatch):
+    """Four windows long, and shorter than the window; forward and gradients, at head size 128 with 2 query
+    heads a key-value head."""
+    monkeypatch.setattr(M, "_ATTN_KERNEL_BLOCKS", dict(block_q=128, block_kv=128, block_kv_compute=128,
+                                                       block_q_dkv=128, block_kv_dkv=128, block_kv_dkv_compute=128))
+    q, k, v = (a.astype(jnp.bfloat16) for a in _core_case(length, sequences=1, kv_heads=1, group=2, head=128))
+    scale = 1.0 / math.sqrt(128)
+
+    def value(core):
+        return lambda q, k, v: (core(q, k, v).astype(jnp.float32) ** 2).sum()
+
+    kernel = lambda q, k, v: M._kernel_core(q, k, v, scale, window)
+    blockwise = lambda q, k, v: M._blockwise_core(q, k, v, scale, 64, window)
+    got = jax.jit(jax.value_and_grad(value(kernel), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.value_and_grad(value(blockwise), argnums=(0, 1, 2)))(q, k, v)
+    rel = lambda a, b: float(np.linalg.norm(np.asarray(a, np.float32) - np.asarray(b, np.float32))
+                             / np.linalg.norm(np.asarray(b, np.float32)))
+    assert rel(got[0], want[0]) < 2e-2
+    for a, b in zip(got[1], want[1]):
+        assert rel(a, b) < 3e-2
+    if window < length:  # and it is not the causal core
+        causal = jax.jit(value(lambda q, k, v: M._kernel_core(q, k, v, scale)))(q, k, v)
+        assert rel(causal, want[0]) > 5e-2
+
+
+@pytest.mark.parametrize("length,window", [(1024, 256), (1024, 257), (512, 1024), (8192, 1024)])
+def test_the_kernels_mask_object_is_the_references_array_entry_by_entry(length, window):
+    """What the fused kernel is handed (the library's ``LocalMask`` / ``CausalMask``, evaluated on the host: no
+    TPU) against ``reference.visible``'s 0/1 array: every entry, in slices of rows at the published length."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    real = splash.make_splash_mqa_single_device
+    try:
+        splash.make_splash_mqa_single_device = lambda mask, **kw: mask.masks  # what the kernel would be made from
+        windowed, causal = M._splash_kernel(length, 2, window), M._splash_kernel(length, 2, None)
+    finally:
+        splash.make_splash_mqa_single_device = real
+    assert len(windowed) == len(causal) == 2
+    j = np.arange(length)[None, :]
+    for first in range(0, length, 1024):
+        rows = slice(first, min(first + 1024, length))
+        i = np.arange(length)[rows, None]
+        for head in windowed:
+            np.testing.assert_array_equal(np.asarray(head[rows, :]).astype(np.int32),
+                                          np.asarray(R.visible(i, j, "sliding_attention", {"sliding_window": window})))
+        np.testing.assert_array_equal(np.asarray(causal[0][rows, :]).astype(np.int32),
+                                      np.asarray(R.visible(i, j, "full_attention", {})))
+    row = length - 1  # the last query sees ``window`` keys, its own the last of them
+    assert int(np.asarray(windowed[0][row:row + 1, :]).sum()) == min(window, length)
+
+
+def test_a_windowed_layers_kernel_visits_fewer_block_pairs_and_flops_py_counts_the_same():
+    """The kernel's own table at the published length and blocks: 15 of 64 pairs under the window, 36 under the
+    causal mask; ``flops.block_visits`` is the same count by arithmetic."""
+    m = {"sliding_window": 1024}
+    window, causal = M._kernel_visits(8192, 1024), M._kernel_visits(8192, None)
+    assert (window["pairs"], causal["pairs"]) == (15, 36) and window["elements"] == 15 * 1024 * 1024
+    assert window == flops.block_visits(m, "sliding_attention", 8192) and causal == flops.block_visits(m, "full_attention", 8192)
+    for kind in ("sliding_attention", "full_attention"):  # one set of blocks serves both masks
+        assert flops.KERNEL_BLOCKS[kind] == (M._ATTN_KERNEL_BLOCKS["block_q"], M._ATTN_KERNEL_BLOCKS["block_kv"])
+    for length, reach in ((4096, 1024), (2048, 4096), (1024, 300)):
+        assert M._kernel_visits(length, reach) == flops.block_visits({"sliding_window": reach}, "sliding_attention", length)
+    mm = {"head_dim": 128, "num_attention_heads": 32, "num_key_value_heads": 4}
+    assert flops.core_flops(mm, window, 2, 2, 1) == 2 * 32 * 15 * 2**20 * (2 * 4 * 128 + 10 * 128)
+    assert flops.core_flops(mm, window, 1, 1, 0) / flops.core_flops(mm, causal, 1, 1, 0) == 15 / 36
+
+
+# -- rope by layer type -----------------------------------------------------------------------------------
+
+
+def test_rope_parameters_choose_frequencies_and_amplitude_by_layer_type():
+    cfg = M.Lfm2MoeConfig(rope_parameters=tuple(sorted((k, tuple(sorted(v.items()))) for k, v in ROPE.items())),
+                          layer_types=tuple(PERIOD), layer_ids=(0, 1, 2, 3), sliding_window=8)
+    theta, scaling = cfg.rope_of("sliding_attention")
+    assert theta == 5e5 and scaling is None and cfg.window_of("sliding_attention") == 8
+    theta, scaling = cfg.rope_of("full_attention")
+    assert theta == 5e5 and scaling["factor"] == 16 and cfg.window_of("full_attention") is None
+    assert M.yarn_amplitude(scaling) == 1.2772588722239782 == pytest.approx(0.1 * math.log(16) + 1)
+    assert M.yarn_amplitude({"factor": 16}) == pytest.approx(1.2772588722239782)
+    assert M.yarn_amplitude({"factor": 40, "mscale": 0.707, "mscale_all_dim": 0.707}) == 1.0  # DeepSeek-V2's form
+    # by hand at head size 128: below the correction dimension of beta_fast the plain frequency, above that of
+    # beta_slow the frequency over 16; d(b) = 128 ln(8192 / (2 pi b)) / (2 ln 5e5)
+    low, high = math.floor(128 * math.log(8192 / (2 * math.pi * 32)) / (2 * math.log(5e5))), \
+        math.ceil(128 * math.log(8192 / (2 * math.pi)) / (2 * math.log(5e5)))
+    assert (low, high) == (18, 35)
+    freq = M.yarn_inv_freq(128, 5e5, scaling)
+    plain = 5e5 ** (-np.arange(64) / 64.0)
+    np.testing.assert_allclose(freq[:19], plain[:19], rtol=1e-6)
+    np.testing.assert_allclose(freq[35:], plain[35:] / 16, rtol=1e-6)
+    np.testing.assert_allclose(freq, R.rope_frequencies(128, ROPE["full_attention"])[0], rtol=1e-6)
+    np.testing.assert_allclose(R.rope_frequencies(128, ROPE["sliding_attention"])[0], plain, rtol=1e-12)
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 12, 3, 128)), jnp.float32)
+    for kind in PERIOD[2:]:
+        np.testing.assert_allclose(M._rope(x, *cfg.rope_of(kind))[0], R.rope(x[0], ROPE[kind]), atol=1e-5)
+    full, plain_rope = M._rope(x, *cfg.rope_of("full_attention")), M._rope(x, *cfg.rope_of("sliding_attention"))
+    np.testing.assert_allclose(np.linalg.norm(full[0, 0]), 1.2772588722239782 * np.linalg.norm(plain_rope[0, 0]), rtol=1e-6)
+
+
+# -- the grouped products' tiles ---------------------------------------------------------------------------
+
+
+def test_gmm_tiling_divides_a_contraction_of_2304_and_keeps_the_other_configurations_tiles():
+    assert M._gmm_tiling(45056, 2304, 896) == (512, 1152, 896) and 2304 % 1152 == 0
+    assert M._gmm_tiling(45056, 896, 2304) == (512, 896, 1152)  # the backward product's
+    assert M._gmm_tiling(33792, 2048, 1536) == (512, 512, 512)  # LFM2's
+    assert M._gmm_tiling(33792, 2048, 1408) == (512, 512, 1408) and M._gmm_tiling(33792, 1408, 2048) == (512, 1408, 512)
+    assert M._gmm_tiling(96, 64, 48) == (32, 64, 48)
+    assert M._gmm_tiling(512, 2048, 10944) == (512, 512, 512)  # no tile of whole lanes divides it: ragged, as it was
+    for size in range(128, 8192 + 1, 128):  # whatever the width in whole lanes: a tile of up to four that divides it
+        tile = M._gmm_tiling(512, size, size)[1]
+        assert size % tile == 0 and tile <= 4 * M._GMM_TILING[1], (size, tile)
+
+
+def test_the_grouped_product_at_2304_by_896_is_the_plain_one():
+    """The published contraction and width against a loop over the groups (CPU: ``lax.ragged_dot``; the tiles
+    are the TPU kernel's and are held to divide the sizes above)."""
+    rng = np.random.default_rng(3)
+    sizes = np.array([100, 0, 37, 63, 1, 55, 0, 128], np.int32)
+    rows = jnp.asarray(rng.normal(size=(512, 2304)), jnp.float32)
+    weights = jnp.asarray(rng.normal(size=(8, 2304, 896)) / 48.0, jnp.float32)
+    with HIGHEST:
+        got = M._grouped_matmul(rows, weights, jnp.asarray(sizes))
+        want, start = np.zeros((512, 896), np.float32), 0
+        for g, n in enumerate(sizes):
+            want[start:start + n] = np.asarray(rows[start:start + n] @ weights[g])
+            start += n
+    np.testing.assert_allclose(np.asarray(got)[:start], want[:start], atol=1e-4)
+
+
+# -- refusals, scopes, spans, counters --------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("over,message", [
+    (dict(sliding_window=0), "sliding_window"),
+    (dict(rope_parameters={"full_attention": {"rope_type": "yarn", "rope_theta": 5e5}}), "yarn needs"),
+    (dict(rope_parameters={"conv": {"rope_theta": 5e5}}), "rope_parameters"),
+    (dict(rope_parameters={"full_attention": {"rope_type": "linear", "rope_theta": 5e5}}), "rope_type"),
+    (dict(head_dim=15), "head_dim"),
+    (dict(num_key_value_heads=3), "key-value heads"),
+])
+def test_a_configuration_that_cannot_be_this_architecture_is_refused(over, message, tokens):
+    with pytest.raises(ValueError, match=message):
+        M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(**over))
+
+
+def _scopes(fn, *args) -> set:
+    """Every scope path an equation of ``fn``'s jaxpr carries, jax's transformation wrappers stripped."""
+    found = set()
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            stack = "/".join(filter(None, (outer, re.sub(r"[A-Za-z_]+\(|\)", "", str(eqn.source_info.name_stack)))))
+            if stack:
+                found.add(stack)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, stack)  # a sub-jaxpr's stacks are relative to the equation that holds it
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, "")
+    return found
+
+
+def test_each_layer_type_has_its_own_scope_with_proj_rope_and_core_inside(tokens):
+    cfg = config_of(tokens)
+    w = jax.tree_util.tree_map(jnp.asarray, R.seeded_weights(MODEL, 1, STD))
+    scopes = _scopes(lambda p: M.forward(cfg, p, NO_BIAS, tokens[0][:2])[0], w)
+    for layer, kind in enumerate(PERIOD):
+        for part in ("proj", "rope", "core"):
+            assert any(s.startswith(f"layer{layer}/{kind}/{part}") for s in scopes), (layer, kind, part)
+        other = "full_attention" if kind == "sliding_attention" else "sliding_attention"
+        assert not any(s.startswith(f"layer{layer}/{other}") or s.startswith(f"layer{layer}/attention") for s in scopes)
+    assert scope_rules.classify("jit(lm_train_step)/transpose(jvp(layer2))/sliding_attention/core/splash") == \
+        ("window_core", "core")
+    assert scope_rules.classify("jit(lm_train_step)/jvp(layer3)/full_attention/core/dot") == ("full_core", "core")
+    assert scope_rules.classify("layer3/full_attention/rope/mul") == ("attention_proj", "rope")
+    assert scope_rules.classify("layer0/sliding_attention/proj/dot_general") == ("attention_proj", "proj")
+    assert scope_rules.classify("layer0/cond/branch_1_fun/moe/experts/gmm") == ("expert_mm", "experts")
+    assert scope_rules.classify("layer0/aux_loss/mul") == ("moe_route", "aux_loss")
+    assert scope_rules.classify("layer0/moe/router/dot") == ("moe_route", "router")
+    assert scope_rules.classify("optimizer/add") == ("optimizer", "optimizer") and scope_rules.classify("") == \
+        ("unattributed", "")
+    assert {scope_rules.classify(s)[0] for s in scopes} <= set(scope_rules.CLASSES)
+
+
+def _parent_tree_and_scopes():
+    """What an LFM2 and a DeepSeek-V2 configuration built at the parent commit (PR 33): the attention leaves of
+    the parameter tree and the scopes under a layer's operator."""
+    return {"lfm2": ({"q", "k", "v", "o", "q_norm", "k_norm"}, {"layer1/attention"}),
+            "deepseek_v2": ({"q", "kva", "kv_norm", "kvb", "o"},
+                            {f"layer0/latent_attention/{p}" for p in ("down_proj", "up_proj", "rope", "core", "out_proj")})}
+
+
+@pytest.mark.parametrize("arch", ["lfm2", "deepseek_v2"])
+def test_the_architectures_that_were_there_build_the_tree_and_the_scopes_they_built(arch):
+    """``head_dim``, the q/k norm and the mask are now stated, not implied: an LFM2 and a DeepSeek-V2
+    configuration, given as before, get the implied head size, the norm, the causal mask, their one rope, and
+    the scopes their benchmark families' rules read."""
+    leaves, operator_scopes = _parent_tree_and_scopes()[arch]
+    x = np.zeros((6, 16), np.int32)
+    if arch == "lfm2":
+        kw = dict(hidden_size=32, layer_types=("conv", "full_attention"), num_dense_layers=1, intermediate_size=48,
+                  moe_intermediate_size=24, num_experts=8, num_experts_per_tok=2, held_experts=(0, 2),
+                  num_attention_heads=4, num_key_value_heads=2, vocab_size=64)
+        key, layer = "attn", 1
+    else:
+        kw = dict(hidden_size=32, layer_types=("latent_attention",) * 2, num_dense_layers=1, intermediate_size=48,
+                  moe_intermediate_size=24, num_experts=8, num_experts_per_tok=3, held_experts=(0, 2), n_shared_experts=2,
+                  num_attention_heads=4, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+                  vocab_size=64, scoring_func="softmax", norm_topk_prob=False, balance_rule="aux_loss",
+                  tie_word_embeddings=False,
+                  rope_scaling=dict(beta_fast=32, beta_slow=1, factor=40, mscale=0.707, mscale_all_dim=0.707,
+                                    original_max_position_embeddings=8, type="yarn"))
+        key, layer = "latent", 0
+    programs = M.Lfm2MoeModel.compiled_programs(x, batch_sequences=2, eval_sequences=2, attn_block=8,
+                                                compute_dtype="float32", **kw)
+    cfg = programs.config
+    assert cfg.head_dim == 8 == cfg.hidden_size // cfg.num_attention_heads and cfg.qk_norm and not cfg.typed_attention
+    assert cfg.rope_parameters is None and cfg.sliding_window == 0 and cfg.window_of(cfg.layer_types[layer]) is None
+    assert cfg.rope_of(cfg.layer_types[layer]) == (cfg.rope_theta, None)
+    shapes = M.param_shapes(cfg)
+    assert set(shapes["layers"][layer][key]) == leaves
+    assert programs.kernel_layers_by_mask == (("causal", 0),) and programs.kernel_visits == ()
+    params = jax.tree_util.tree_map(lambda s: jnp.zeros(s, jnp.float32), shapes, is_leaf=M._is_shape)
+    bias = jnp.zeros((1, 8), jnp.float32)
+    scopes = _scopes(lambda p: M.forward(cfg, p, bias, x[:2])[0], params)
+    under = {s for s in scopes if re.match(rf"layer{layer}/(latent_)?attention", s)}
+    depth = 3 if arch == "deepseek_v2" else 2
+    assert {"/".join(s.split("/")[:depth]) for s in under if len(s.split("/")) >= depth} == operator_scopes
+    assert not any("sliding_attention" in s or "full_attention" in s for s in scopes)
+    top = {s.split("/")[0] for s in scopes}
+    assert top == {"embed", "layer0", "layer1", "head"}, top
+
+
+def test_spans_and_the_labelled_counter_split_the_kernels_layer_steps_by_mask(tokens, kernel_on_the_cpu, monkeypatch):
+    """Two periods at 128 positions with a window of 32, the kernel interpreted: 6 windowed and 2 full layers x 3
+    steps an individual on the ``train`` span and on ``attention_kernel_layer_steps_total{mask}``, and the block
+    pairs each mask's kernel visits as static attributes."""
+    monkeypatch.setattr(M, "_ATTN_KERNEL_BLOCKS", dict(block_q=128, block_kv=128, block_kv_compute=128,
+                                                       block_q_dkv=128, block_kv_dkv=128, block_kv_dkv_compute=128))
+    m = {**MODEL, "num_hidden_layers": 8, "layer_types": PERIOD * 2, "head_dim": 128, "num_attention_heads": 2,
+         "num_key_value_heads": 1, "sliding_window": 100}
+    tok = np.random.default_rng(1).integers(0, 64, size=(6, 513)).astype(np.int32)
+    x, y = tok[:, :-1], tok[:, 1:]
+    kw = model_kwargs(m, compute_dtype="bfloat16", attn_block=128, cache_dir=False)
+    programs = M.Lfm2MoeModel.compiled_programs(x, **kw)
+    assert programs.attention_kernel_layers == 8 and programs.kernel_layers_by_mask == (("causal", 2), ("window", 6))
+    visits = {mask: dict(v) for mask, v in programs.kernel_visits}
+    assert visits["causal"]["pairs"] == 10 and visits["window"]["pairs"] == 7  # of 16 at 4 x 4 blocks of 128
+
+    class Sink:
+        def __init__(self):
+            self.records = []
+
+        def record(self, rec):
+            self.records.append(rec)
+
+    sink = Sink()
+    get_registry().reset()
+    spans.set_run_sink(sink)
+    spans.enable()
+    try:
+        fitness = M.Lfm2MoeModel.cross_validate_population(x, y, [deepseek_v2_genome().default()], **kw)
+    finally:
+        spans.disable()
+        spans.set_run_sink(None)
+    assert np.isfinite(fitness).all()
+    trained = [r["attrs"] for r in sink.records if r["type"] == "span" and (r.get("attrs") or {}).get("steps") == 3]
+    assert len(trained) == 1
+    attrs = trained[0]
+    assert attrs["attention_kernel_layer_steps"] == 24 and attrs["attention_kernel_layer_steps_window"] == 18 \
+        and attrs["attention_kernel_layer_steps_causal"] == 6
+    assert attrs["attention_kernel_pairs_window"] == 7 and attrs["attention_kernel_pairs_causal"] == 10
+    assert attrs["attention_kernel_elements_window"] == 7 * 128 * 128 == attrs["attention_kernel_elements_bwd_window"]
+    counter = get_registry().counter
+    assert counter("attention_kernel_layer_steps_total", mask="window").value == 18
+    assert counter("attention_kernel_layer_steps_total", mask="causal").value == 6
+
+
+def test_every_matrix_starts_at_the_one_deviation_of_the_routed_family(tokens):
+    """No configuration says how a run starts: norm weights at 1, every matrix at 0.02, the other routed
+    configurations' start (what else was tried, and what it did to the routing, is PERF.md's, PR 34)."""
+    key, h = jax.random.PRNGKey(3), jnp.asarray([1, 2], jnp.uint32)
+    big = {**MODEL, "hidden_size": 128, "moe_intermediate_size": 64, "vocab_size": 64}
+    params = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(big)).init(key, h)["params"]
+    for path, a in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if "norm" in path[-1].key:
+            assert float(jnp.abs(a - 1).max()) == 0
+        else:
+            assert float(jnp.std(a)) == pytest.approx(M.INIT_STD, rel=0.1), jax.tree_util.keystr(path)
+
+
+def test_on_the_cpu_every_core_falls_back_and_the_spans_say_so(tokens):
+    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs())
+    assert programs.attention_kernel_layers == 0 and programs.kernel_layers_by_mask == (("causal", 0), ("window", 0))
+    assert programs.kernel_visits == ()
+
+
+# -- the benchmark's family: configuration file, counts, readers ------------------------------------------
+
+
+def _config_file():
+    with open(os.path.join(BENCH, "configs", "mellum2_12b_a2p5b_ep8.json")) as fh:
+        return json.load(fh)
+
+
+def test_the_configuration_file_holds_the_catalogs_numbers_and_the_cut_is_the_bytes_it_says():
+    config = _config_file()
+    published = dict(hidden_size=2304, head_dim=128, num_attention_heads=32, num_key_value_heads=4,
+                     moe_intermediate_size=896, intermediate_size=7168, num_experts=64, num_experts_per_tok=8,
+                     sliding_window=1024, max_position_embeddings=131072, rms_norm_eps=1e-6, max_window_layers=0)
+    for key, value in published.items():
+        assert config[key] == value, key
+    assert config["layer_types"] == PERIOD * 7 and config["mlp_layer_types"] == ["sparse"] * 28
+    assert config["rope_parameters"] == ROPE and config["norm_topk_prob"] is True
+    assert set(config["reduced"]) == {"num_hidden_layers", "num_experts_held", "vocab_size", "train_steps", "n_sequences"}
+    assert config["vocab_size"] * 8 == config["published"]["vocab_size"] == 98304 and config["num_hidden_layers"] == 8
+    names = ("family", "correct", "reference")
+    before = {n: sys.modules.pop(n, None) for n in names}
+    sys.path.insert(0, FAMILY)
+    try:
+        family = _load("family")
+        params = family.model_params(config, 5, rehearsal=False)
+        m = family.model_block(config)
+    finally:
+        sys.path.remove(FAMILY)
+        for n in names:
+            sys.modules.pop(n, None)
+            if before[n] is not None:
+                sys.modules[n] = before[n]
+    assert m["layer_types"] == PERIOD * 2
+    params.pop("seed")
+    cfg = M._normalize_config(np.zeros((config["n_sequences"], config["data"]["seq_len"]), np.int32), params)[0]
+    need = M.training_bytes(cfg)
+    assert need["params"] == 624_072_960 and need["state"] == 9_985_167_360
+    assert cfg.tokens_per_step == 16384 and M._narrow_rows(cfg, cfg.tokens_per_step) == 45056
+    assert cfg.head_dim == 128 and not cfg.qk_norm and cfg.typed_attention and cfg.sliding_window == 1024
+    shapes = M.param_shapes(cfg)
+    assert shapes["layers"][0]["attn"] == {"q": (2304, 4096), "k": (2304, 512), "v": (2304, 512), "o": (4096, 2304)}
+    assert shapes["layers"][3]["moe"]["w1"] == (8, 2304, 896) and shapes["head"] == (12288, 2304)
+    # the executed FLOPs of a step, by flops.py: the count PERF.md's prediction rests on
+    rows = 16384 * 8 / 8 * 8  # 2,048 rows a held expert, 8 experts, 8 layers
+    total = flops.train_flops(m, 16384, rows, 8192)
+    assert 50e12 < total < 62e12, total  # 56.7 TFLOP a step
+
+
+def test_the_cell_runs_the_accepted_mix_as_it_is():
+    """``lmpopeval_fresh`` unchanged: the genome's defaults first, at their own learning rate, and draws no
+    hotter than the mix's cap; the pool the other ``aux_loss`` configuration's cell scores, recipe for recipe.
+    The configuration file has no say in the traffic."""
+    config = _config_file()
+    with open(os.path.join(BENCH, "traffic", "lmpopeval_fresh.json")) as f:
+        mix = json.load(f)
+    names = ("family", "correct", "reference")
+    before = {n: sys.modules.pop(n, None) for n in names}
+    pools = {}
+    try:
+        for name in ("mellum", "deepseek_v2"):
+            directory = os.path.join(BENCH, "families", name)
+            sys.path.insert(0, directory)
+            try:
+                spec = importlib.util.spec_from_file_location("family", os.path.join(directory, "family.py"))
+                family = importlib.util.module_from_spec(spec)
+                sys.modules["family"] = family
+                spec.loader.exec_module(family)
+                pools[name] = family.make_pool(4, [int(mix["pool_seed"])], float(mix["pool_log10_lr_max"]))
+                if name == "mellum":
+                    small = {**config, "n_sequences": 2, "data": {**config["data"], "seq_len": 8}}
+                    assert family.make_inputs(small, mix, 3)["pool"] == pools[name]
+            finally:
+                sys.path.remove(directory)
+                for n in names:
+                    sys.modules.pop(n, None)
+    finally:
+        for n in names:
+            if before[n] is not None:
+                sys.modules[n] = before[n]
+    pool = pools["mellum"]
+    assert "pool_log10_lr_max" not in config and mix["pool_log10_lr_max"] == -3.5
+    assert len(pool) == config["population"] == 4 and pool == pools["deepseek_v2"]
+    assert pool[0] == {"log10_lr": -3.5, "warmup_frac": 0.25, "weight_decay": 0.1, "beta2": 0.95, "aux_alpha": 0.001}
+    assert all(r["log10_lr"] <= -3.5 and 0.0 <= r["aux_alpha"] <= 0.01 for r in pool)
+
+
+@pytest.fixture()
+def layer_metric():
+    """A reader of ``benchmark/layer_metrics/`` by name, loaded as ``run.py``
+    loads it (the family's directory and the harness's on ``sys.path``)."""
+    names = ("mel_spans", "scope_rules", "scope_reduce", "spanlib", "trace_reduce", "flops", "family", "correct",
+             "reference")
+    before = {n: sys.modules.pop(n, None) for n in names}
+    sys.path[:0] = [FAMILY, BENCH]
+    try:
+        yield lambda name: _load(os.path.join("..", "..", "layer_metrics", name))
+    finally:
+        del sys.path[:2]
+        for n in names:
+            sys.modules.pop(n, None)
+            if before[n] is not None:
+                sys.modules[n] = before[n]
+
+
+def test_every_seed_gives_the_window_the_same_work_and_the_check_its_own_inputs(layer_metric):
+    """The window's pool is one fixed pool, whole: the recipes from the mix's ``pool_seed``, the seed of their starting
+    weights and the tokens from the configuration's ``window_seed``, so that no seed's routing gives its run more rows
+    than another's (PERF.md, PR 34: the check's refusal); the order of a call is ``--seed``'s (``traffic_kinds/lmpopeval.py``), and so are the tokens
+    the comparison that decides ``correct`` runs on, with its weights and batches (``correct.check_inputs``)."""
+    family = _load("family")
+    with open(os.path.join(BENCH, "traffic", "lmpopeval_fresh.json")) as f:
+        mix = json.load(f)
+    config = _config_file()
+    small = {**config, "n_sequences": 6, "data": {**config["data"], "seq_len": 16}}
+    a, b, again = (family.make_inputs(small, mix, seed) for seed in (3, 2147484001, 3))
+    for key in ("x", "y"):
+        assert np.array_equal(a[key], b[key]) and a[key].shape == (6, 16)
+    assert a["params"] == b["params"] and a["pool"] == b["pool"] and a["model"] == b["model"]
+    assert config["window_seed"] == 3400000507 and a["params"]["seed"] == config["window_seed"] % (2**31 - 1)
+    assert np.array_equal(a["x"], family.markov_tokens(small["data"], small["vocab_size"], 6, 16, config["window_seed"])[:, :-1])
+    assert not np.array_equal(a["check_x"], b["check_x"]) and not np.array_equal(a["check_x"], a["x"])
+    assert np.array_equal(a["check_x"], again["check_x"]) and np.array_equal(a["check_y"], again["check_y"])
+    assert np.array_equal(a["check_x"][:, 1:], a["check_y"][:, :-1]) and np.array_equal(a["x"][:, 1:], a["y"][:, :-1])
+
+
+def _span(kind, t, attrs):
+    return {"type": "span", "kind": kind, "t_wall": t, "dur_s": 0.001, "attrs": attrs}
+
+
+def test_the_kernel_readers_split_the_windows_train_spans_by_mask_and_the_parent_reads_nothing(layer_metric):
+    window_reader, full_reader = layer_metric("mel_window_kernel_layer_steps"), layer_metric("mel_full_kernel_layer_steps")
+    train = lambda t, **attrs: _span("train", t, {"individual": 0, "steps": 8, **attrs})
+    window = {"window": (10.0, 20.0)}
+    records = [train(5.0, attention_kernel_layer_steps_window=0, attention_kernel_layer_steps_causal=0),  # set-up
+               train(11.0, attention_kernel_layer_steps_window=48, attention_kernel_layer_steps_causal=16),
+               train(12.0, attention_kernel_layer_steps_window=48, attention_kernel_layer_steps_causal=16),
+               _span("train", 13.0, {"fold": 0, "attention_kernel_layer_steps_window": 99})]  # no span of this family
+    assert window_reader.read({**window, "records": records}) == 48
+    assert full_reader.read({**window, "records": records}) == 16
+    assert window_reader.read({**window, "records": [train(11.0, attention_kernel_layer_steps=64)]}) is None  # the parent
+    assert full_reader.read({**window, "records": records[:1]}) is None
+
+
+def test_every_mel_metric_of_the_manifest_has_a_reader_and_reads_nothing_from_an_empty_run(layer_metric):
+    """A program that lacks the spans (the parent's, on the new cell's readers) makes no reader raise."""
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as fh:
+        manifest = json.load(fh)
+    cell = "mellum2_12b_a2p5b_ep8.popeval"
+    names = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [cell]]
+    assert len(names) == 26 and all(n.startswith("mel_") for n in names), names
+    empty = {"config": _config_file(), "cell": {"name": cell}, "chips": 1, "units": [], "records": [],
+             "window": (0.0, 1.0), "elapsed": 1.0, "monitor": None, "trace": None, "memory_peak_bytes": 0, "peak": None}
+    for name in names:
+        assert layer_metric(name).read(dict(empty)) is None, name
