@@ -415,11 +415,11 @@ def yarn_amplitude(scaling: Mapping[str, Any]) -> float:
     return yarn_mscale(scaling["factor"], 1.0)
 
 
-def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
-    """Rotary embedding, rotate-half layout, on (sequences, length, ..., head size):
-    heads, or key-value heads and their query heads, between; float32.
-    With ``scaling`` (YaRN) the frequencies are :func:`yarn_inv_freq`'s and cos
-    and sin carry :func:`yarn_amplitude`."""
+def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]]):
+    """cos and sin of the positions of ``x`` (sequences, length, ..., head size), float32, one
+    column a rotated pair (half the head size) and shaped to broadcast against ``x``'s halves.
+    With ``scaling`` (YaRN) the frequencies are :func:`yarn_inv_freq`'s and both carry
+    :func:`yarn_amplitude`."""
     half = x.shape[-1] // 2
     if scaling is None:
         inv_freq, amplitude = theta ** (-jnp.arange(half, dtype=jnp.float32) / half), 1.0
@@ -430,8 +430,41 @@ def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
     cos, sin = jnp.cos(angle).reshape(per_position), jnp.sin(angle).reshape(per_position)
     if amplitude != 1.0:
         cos, sin = cos * amplitude, sin * amplitude
+    return cos, sin
+
+
+def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
+    """Rotary embedding, rotate-half layout, on (sequences, length, ..., head size):
+    heads, or key-value heads and their query heads, between; float32.  The two
+    halves are sliced and concatenated: the form for a few columns (latent
+    attention's 64 rope columns a head, concatenated to the rest anyway)."""
+    cos, sin = _rope_tables(x, theta, scaling)
+    half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None):
+    """:func:`_rope`'s function on whole heads without a slice or a concatenation:
+    ``x * [cos | cos] + (x @ T) * [sin | sin]``, where ``T`` is the signed
+    permutation that sends ``[x1 | x2]`` to ``[-x2 | x1]``.  Every product with
+    ``T`` has one term, so the float32 result and its cotangent are
+    :func:`_rope`'s to the last bit on the chip (HIGHEST: a float32 ``x``, the
+    normed q and k and every cotangent, crosses the MXU in pieces that add up to
+    it exactly).  Why: XLA:TPU writes a concatenation as a pass of its own over
+    float32 halves, and writes this form as ONE fusion an operand, the product
+    with ``T`` at its heart and the norm, cos and sin, the core's scale and the
+    cast to the compute dtype around it, forward and backward (PERF.md, PR 36:
+    8.56 GB a layer-step outside products and kernels at Mellum2's shape
+    against 3.56; 45.5 ms against 35.6)."""
+    cos, sin = (jnp.concatenate([table, table], axis=-1) for table in _rope_tables(x, theta, scaling))
+    half = x.shape[-1] // 2
+    turn = np.zeros((2 * half, 2 * half), np.float32)
+    turn[np.arange(half) + half, np.arange(half)] = -1.0  # column j < half takes -x[half + j]
+    turn[np.arange(half), np.arange(half) + half] = 1.0  # column half + j takes x[j]
+    turned = jnp.einsum("...d,de->...e", x, jnp.asarray(turn, x.dtype), preferred_element_type=jnp.float32,
+                        precision=jax.lax.Precision.HIGHEST)
+    return x * cos + turned * sin
 
 
 def _kernel_blocks(length: int) -> Optional[Dict[str, int]]:
@@ -561,24 +594,31 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
     """Causal GQA on (sequences, length, hidden); the core is :func:`_causal_core`'s.
     The layer's type ``kind`` decides its mask (:meth:`Lfm2MoeConfig.window_of`)
     and its rope (:meth:`Lfm2MoeConfig.rope_of`); where the configuration tells
-    attention layers apart by type, ``proj``, ``rope`` and ``core`` are scopes."""
-    s, length, _ = x.shape
+    attention layers apart by type, ``proj``, ``rope`` and ``core`` are scopes.
+
+    Each operand of the fused core is written once, in the order the kernel
+    reads and in the compute dtype: the q, k and v products emit head-major
+    (:func:`_head_major`, the key-value heads and their query heads in the
+    weights' shape, so no reshape follows); the q/k norm, rope
+    (:func:`_rope_whole_heads`), the core's scale and the one cast are float32
+    arithmetic inside one fusion an operand; the output product contracts the
+    kernel's head-major output as it is."""
+    hidden = x.shape[-1]
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     part = jax.named_scope if cfg.typed_attention else (lambda name: contextlib.nullcontext())
     theta, scaling = cfg.rope_of(kind)
     with part("proj"):
-        q = _dot(x, p["q"], dtype).reshape(s, length, nh, hd)
-        k = _dot(x, p["k"], dtype).reshape(s, length, nkv, hd)
-        v = _dot(x, p["v"], dtype).reshape(s, length, nkv, hd)
+        q = _head_major(x, p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, hd))
+        k = _head_major(x, p["k"].astype(dtype).reshape(hidden, nkv, hd))
+        v = _head_major(x, p["v"].astype(dtype).reshape(hidden, nkv, hd))
     with part("rope"):
         normed = lambda a, weight: _rms_norm(a, p[weight], cfg.norm_eps) if cfg.qk_norm else a
-        q = _rope(normed(q, "q_norm"), theta, scaling)
-        k = _rope(normed(k, "k_norm"), theta, scaling).astype(dtype)
+        q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling)
+        k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling).astype(dtype)
     with part("core"):
-        q = q.reshape(s, length, nkv, nh // nkv, hd)
         out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg, cfg.window_of(kind))
     with part("proj"):
-        return _dot(out.reshape(s, length, nh * hd), p["o"], dtype)
+        return jnp.einsum("slngd,ngdh->slh", out, p["o"].astype(dtype).reshape(nkv, nh // nkv, hd, hidden))
 
 
 def latent_softmax_scale(cfg: Lfm2MoeConfig) -> float:

@@ -365,6 +365,58 @@ def test_the_fused_core_is_the_blockwise_core_to_bfloat16(length, group, kernel_
     assert all(_rel(g, w) < 0.01 for g, w in zip(got, want)), [_rel(g, w) for g, w in zip(got, want)]
 
 
+def test_attention_and_every_gradient_by_the_fused_core_match_the_float32_reference(kernel_on_the_cpu):
+    """``_attention`` whole at LFM2's form (two sequences, two key-value heads of four query heads at head size
+    64, the norm of q and k over the head: head-major products, norm, rope as a product with the signed
+    permutation, scale, the kernel interpreted, the output product over its head-major output), in float32
+    against ``reference.attention`` a sequence: the output within two bfloat16 steps of its size, the gradients
+    of the input and of every weight, the two norms' among them, within 1% in norm."""
+    cfg, p, x = _attention_case(256, 4, sequences=2, kv_heads=2)
+    x = x.astype(jnp.float32)
+    m = dict(num_attention_heads=8, num_key_value_heads=2, norm_eps=cfg.norm_eps,
+             rope_parameters={"rope_theta": cfg.rope_theta})
+    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
+
+    def run(operator):
+        def value(p, x):
+            out = operator(p, x)
+            return jnp.sum(out * probe), out
+        return jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
+
+    (_, out), (dp, dx) = run(lambda p, x: M._attention(p, x, cfg, jnp.float32))
+    with HIGHEST:
+        (_, ref), (ref_dp, ref_dx) = run(lambda p, x: jnp.stack([R.attention(p, xs, m, lambda a: a) for xs in x]))
+    out, ref = np.asarray(out), np.asarray(ref)
+    assert np.abs(ref).max() > 0.5 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
+    assert _rel(dx, ref_dx) < 0.01
+    for name in ("q", "k", "v", "o", "q_norm", "k_norm"):
+        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
+
+
+def test_the_kernels_operands_are_head_major_from_the_products_on(monkeypatch):
+    """With the kernel chosen, LFM2's operator (no scopes inside ``attention``) hands the kernel three head-major
+    operands in the compute dtype, its products emit that order (the heads are in the weights' shape: no token-major
+    product is reshaped by head), and there is one kernel call."""
+    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
+    cfg, p, x = _attention_case(256, 4, sequences=2, kv_heads=2)
+    s, length, nkv, group, hd = 2, 256, 2, 4, 64
+    def equations(jaxpr):  # nested ones too: the kernel's call sits inside the library's jit
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    jaxpr = jax.make_jaxpr(lambda p, x: M._attention(p, x, cfg, jnp.bfloat16))(p, x).jaxpr
+    avals = [(eqn.primitive.name, v.aval) for eqn in equations(jaxpr) for v in eqn.outvars if hasattr(v.aval, "shape")]
+    assert not [a for name, a in avals if name == "reshape" and a.ndim >= 4 and a.shape[:2] == (s, length)], \
+        "no product is re-cut by head"
+    kernel = [a for name, a in avals if name == "custom_vjp_call"]
+    assert len(kernel) == 1 and kernel[0].shape == (s, nkv, group, length, hd)
+    head_major = [a for name, a in avals if name == "transpose" and a.shape[:2] == (s, nkv) and a.dtype == jnp.bfloat16]
+    wanted = [(s, nkv, group, length, hd), (s, nkv, length, hd), (s, nkv, length, hd)]
+    assert sorted(a.shape for a in head_major) == sorted(wanted * 2), "each operand once by its product, once at the kernel"
+
+
 @pytest.mark.parametrize("core", ["kernel", "blockwise"])
 def test_no_output_of_attention_sees_a_later_token(core, kernel_on_the_cpu, monkeypatch):
     if core == "blockwise":

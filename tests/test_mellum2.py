@@ -265,6 +265,11 @@ def kernel_on_the_cpu(monkeypatch):
     M._kernel_visits.cache_clear()
 
 
+def _rel(a, b) -> float:
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
 @pytest.mark.parametrize("length,window", [(512, 128), (256, 384)])
 def test_the_fused_core_under_a_window_is_the_blockwise_core_to_bfloat16(length, window, kernel_on_the_cpu, monkeypatch):
     """Four windows long, and shorter than the window; forward and gradients, at head size 128 with 2 query
@@ -281,14 +286,129 @@ def test_the_fused_core_under_a_window_is_the_blockwise_core_to_bfloat16(length,
     blockwise = lambda q, k, v: M._blockwise_core(q, k, v, scale, 64, window)
     got = jax.jit(jax.value_and_grad(value(kernel), argnums=(0, 1, 2)))(q, k, v)
     want = jax.jit(jax.value_and_grad(value(blockwise), argnums=(0, 1, 2)))(q, k, v)
-    rel = lambda a, b: float(np.linalg.norm(np.asarray(a, np.float32) - np.asarray(b, np.float32))
-                             / np.linalg.norm(np.asarray(b, np.float32)))
-    assert rel(got[0], want[0]) < 2e-2
+    assert _rel(got[0], want[0]) < 2e-2
     for a, b in zip(got[1], want[1]):
-        assert rel(a, b) < 3e-2
+        assert _rel(a, b) < 3e-2
     if window < length:  # and it is not the causal core
         causal = jax.jit(value(lambda q, k, v: M._kernel_core(q, k, v, scale)))(q, k, v)
-        assert rel(causal, want[0]) > 5e-2
+        assert _rel(causal, want[0]) > 5e-2
+
+
+# -- the operator whole: what reaches the fused core, and what comes back (PR 36) ----------------------------
+
+
+def _attention_case(kind: str, length: int = 256, sequences: int = 2, window: int = 96):
+    """(configuration, attention weights, input) of one layer of type ``kind`` at the published head size: two
+    key-value heads of two query heads each, 128 columns a head, no norm of q and k, rope by layer type."""
+    cfg = M.Lfm2MoeConfig(hidden_size=64, head_dim=128, num_attention_heads=4, num_key_value_heads=2, qk_norm=False,
+                          sliding_window=window, norm_eps=1e-6, seq_len=length, attn_block=64,
+                          rope_parameters=tuple(sorted((k, tuple(sorted(b.items()))) for k, b in ROPE.items())),
+                          layer_types=("sliding_attention", "full_attention"), layer_ids=(0, 1), num_dense_layers=0)
+    rng = np.random.default_rng([length, kind == "full_attention"])
+    shapes = M.param_shapes(cfg)["layers"][0]["attn"]
+    p = {name: jnp.asarray(rng.normal(size=shape) / np.sqrt(shape[0]), jnp.float32) for name, shape in shapes.items()}
+    x = jnp.asarray(rng.normal(size=(sequences, length, cfg.hidden_size)), jnp.bfloat16)
+    return cfg, p, x
+
+
+def _value_and_gradients(operator, p, x):
+    """(output, gradients of the weights, gradient of the input) of ``sum(operator(p, x) * probe)``, jitted."""
+    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
+
+    def value(p, x):
+        out = operator(p, x)
+        return jnp.sum(out.astype(jnp.float32) * probe), out
+
+    (_, out), (dp, dx) = jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
+    return out, dp, dx
+
+
+@pytest.mark.parametrize("against", ["blockwise-bfloat16", "reference-float32"])
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_attention_and_every_gradient_by_the_fused_core(kind, against, kernel_on_the_cpu, monkeypatch):
+    """``_attention`` whole (two sequences; the head-major products, rope as a product with the signed permutation
+    under the layer type's frequencies and amplitude, scale and cast, the kernel under the type's mask, the output
+    product over the kernel's head-major output) with the fused core interpreted, two blocks a side: in bfloat16
+    against the same call by the blockwise core, in float32 against ``reference.attention`` a sequence; the output
+    within two bfloat16 steps of its size, the gradients of the input and of every weight within 1% in norm (the
+    bounds of ``test_deepseek_v2.py``'s latent operator)."""
+    monkeypatch.setattr(M, "_ATTN_KERNEL_BLOCKS", dict(block_q=128, block_kv=128, block_kv_compute=128,
+                                                       block_q_dkv=128, block_kv_dkv=128, block_kv_dkv_compute=128))
+    cfg, p, x = _attention_case(kind)
+    dtype = jnp.bfloat16 if against == "blockwise-bfloat16" else jnp.float32
+    x = x.astype(dtype)
+    operator = lambda p, x: M._attention(p, x, cfg, dtype, kind)
+    out, dp, dx = _value_and_gradients(operator, p, x)
+    if against == "blockwise-bfloat16":
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(M, "_use_attention_kernel", lambda length: False)
+            ref, ref_dp, ref_dx = _value_and_gradients(operator, p, x)
+    else:
+        m = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=128, rope_parameters=ROPE, sliding_window=96)
+        with HIGHEST:
+            ref, ref_dp, ref_dx = _value_and_gradients(
+                lambda p, x: jnp.stack([R.attention(p, xs, m, kind, lambda a: a) for xs in x]), p, x)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.abs(ref).max() > 0.5 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
+    assert _rel(dx, ref_dx) < 0.01
+    for name in ("q", "k", "v", "o"):
+        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
+    other = "full_attention" if kind == "sliding_attention" else "sliding_attention"
+    assert _rel(jax.jit(lambda p, x: M._attention(p, x, cfg, dtype, other))(p, x), ref) > 0.05, "the type decides"
+
+
+def test_rope_on_whole_heads_is_the_sliced_rope_to_the_last_bit():
+    """Rotate-half as a product with the signed permutation against the two slices and their concatenation: equal
+    values, forward and cotangent, in float32 (a normed q) and from the compute dtype (a product's output), under
+    plain frequencies and under YaRN's with its amplitude, at both published head sizes."""
+    for head, (theta, scaling) in ((128, (5e5, None)), (128, (5e5, ROPE["full_attention"])), (64, (1e6, None))):
+        rng = np.random.default_rng(head)
+        for dtype in (jnp.float32, jnp.bfloat16):
+            x = jnp.asarray(rng.normal(size=(2, 48, 2, 3, head)), dtype)
+            g = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+            want, back = jax.vjp(lambda x: M._rope(x, theta, scaling), x)
+            got, back_whole = jax.vjp(lambda x: M._rope_whole_heads(x, theta, scaling), x)
+            assert got.dtype == want.dtype == jnp.float32
+            np.testing.assert_array_equal(got, want)
+            np.testing.assert_array_equal(np.asarray(back_whole(g)[0], np.float32), np.asarray(back(g)[0], np.float32))
+
+
+def _equations(jaxpr, scope=""):
+    """(primitive, the named scopes it was traced under, its outputs' avals) of every equation, nested ones too."""
+    for eqn in jaxpr.eqns:
+        here = "/".join(filter(None, [scope, str(eqn.source_info.name_stack)]))
+        yield eqn.primitive.name, here, [v.aval for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, here)
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_what_reaches_the_fused_core_is_written_once_head_major_in_the_compute_dtype(kind, monkeypatch):
+    """With the kernel chosen, the traced operator has no float32 array of tokens x key-value heads x head size
+    elements or more outside the ``rope`` and ``core`` scopes (inside them XLA:TPU fuses norm, rope, scale and cast
+    into the one pass that writes an operand: PERF.md, PR 36), no token-major array of the heads anywhere before
+    the kernel (the products emit (sequences, heads, length, size)), the kernel's three operands head-major and
+    in the compute dtype, and one kernel call a layer."""
+    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
+    cfg, p, x = _attention_case(kind)
+    s, length, nkv, group, hd = 2, 256, 2, 2, 128
+    traced = list(_equations(jax.make_jaxpr(lambda p, x: M._attention(p, x, cfg, jnp.bfloat16, kind))(p, x).jaxpr))
+    scopes = lambda scope: set(scope.split("/"))
+    assert all(any(part in scopes(scope) for _, scope, _ in traced) for part in ("proj", "rope", "core"))
+    arrays = [(name, scope, aval) for name, scope, avals in traced for aval in avals if hasattr(aval, "shape")]
+    assert not [(n, sc, a) for n, sc, a in arrays if not {"rope", "core"} & scopes(sc)
+                and a.dtype == jnp.float32 and a.size >= s * length * nkv * hd]
+    products = [a for n, sc, a in arrays if "proj" in scopes(sc) and n == "transpose" and a.shape[0] == s]
+    assert sorted(a.shape for a in products if a.shape[1] == nkv) == sorted(
+        [(s, nkv, group, length, hd), (s, nkv, length, hd), (s, nkv, length, hd)])
+    assert all(a.dtype == jnp.bfloat16 for a in products)
+    assert not [(n, sc, a) for n, sc, a in arrays if a.shape in ((s, length, nkv * group * hd), (s, length, nkv * hd))]
+    kernel = [avals for name, _, avals in traced if name == "custom_vjp_call"]
+    assert len(kernel) == 1 and kernel[0][0].shape == (s, nkv, group, length, hd)
+    operands = [a for n, sc, a in arrays if n == "transpose" and "core" in scopes(sc) and a.shape[:2] == (s, nkv)]
+    assert sorted(a.shape for a in operands) == sorted(
+        [(s, nkv, group, length, hd), (s, nkv, length, hd), (s, nkv, length, hd)])
+    assert all(a.dtype == jnp.bfloat16 for a in operands)
 
 
 @pytest.mark.parametrize("length,window", [(1024, 256), (1024, 257), (512, 1024), (8192, 1024)])
