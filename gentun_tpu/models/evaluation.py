@@ -180,6 +180,12 @@ def evaluation_prelude(cache_dir) -> None:
     # publish what it wrote".  With no hooks (the default) this is one
     # empty-list iteration.
     run_publish_hooks()
+    if _tele.enabled():
+        # The host sampler (telemetry/sampler.py): ticks beside this call's
+        # spans, until ``spans.disable()``.  Off: this one bool read.
+        from ..telemetry import sampler
+
+        sampler.ensure_started()
 
     # Everything after this touches devices; record that publicly so the
     # GA's per-chip metric can consult device counts without ever being the

@@ -74,6 +74,13 @@ _sink_lock = threading.Lock()
 # must see worker-side captured spans too.  One attribute load when off.
 _flight_sink = None
 
+# The host sampler (telemetry/sampler.py), once a traced evaluation has
+# started it; ``disable`` ends it.  None until then: nothing is imported.
+# The lock is held around every change of it, so that a prelude racing a
+# ``disable`` leaves no thread behind.
+_sampler = None
+_sampler_lock = threading.Lock()
+
 
 def enabled() -> bool:
     """The one guard every instrumentation site checks."""
@@ -86,8 +93,12 @@ def enable() -> None:
 
 
 def disable() -> None:
-    global _ENABLED
+    global _ENABLED, _sampler
     _ENABLED = False
+    with _sampler_lock:
+        sampler, _sampler = _sampler, None
+    if sampler is not None:
+        sampler.stop()
 
 
 def set_run_sink(sink) -> None:
@@ -202,12 +213,17 @@ class _Span:
     def fence(self, result):
         """Wait for ``result``, the value a jitted call just returned, inside
         the span: ``dispatch_s`` is how long the call took to return, the
-        rest of ``dur_s`` is the wait for the device.  Only a caller that
-        holds a device result gets here, so jax is already imported."""
+        rest of ``dur_s`` is the wait for the device, and ``wait_cpu_s`` the
+        CPU time this process (all its threads) burned during that wait: a
+        runtime that spun and a process that slept are different stalls.
+        Only a caller that holds a device result gets here, so jax is
+        already imported."""
         self.attrs["dispatch_s"] = time.monotonic() - self._t0
         import jax
 
+        cpu0 = time.process_time()
         jax.block_until_ready(result)
+        self.attrs["wait_cpu_s"] = time.process_time() - cpu0
         return result
 
     def __enter__(self) -> "_Span":

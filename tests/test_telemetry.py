@@ -15,6 +15,7 @@ Covers the acceptance criteria of the telemetry subsystem
   to a telemetry-disabled run.
 """
 
+import contextlib
 import json
 import math
 import threading
@@ -351,7 +352,10 @@ def _run_search(telemetry_path=None):
     """One deterministic distributed search; returns its trajectory."""
     from gentun_tpu.distributed import DistributedPopulation, GentunClient
 
-    with DistributedPopulation(OneMax, size=8, seed=6, port=0) as pop:
+    with DistributedPopulation(OneMax, size=8, seed=6, port=0) as pop, contextlib.ExitStack() as tele:
+        # Telemetry goes on BEFORE the workers connect: the broker sets
+        # ``broker_workers_connected`` on a connect, telemetry on only.
+        run = tele.enter_context(RunTelemetry(telemetry_path, label="e2e")) if telemetry_path is not None else None
         _, port = pop.broker_address
         stops = []
         for i in range(2):
@@ -367,13 +371,9 @@ def _run_search(telemetry_path=None):
             stops.append(stop)
         try:
             ga = GeneticAlgorithm(pop, seed=6)
-            if telemetry_path is not None:
-                with RunTelemetry(telemetry_path, label="e2e") as run:
-                    best = ga.run(3)
-                summary = run.summary()
-            else:
-                best = ga.run(3)
-                summary = None
+            best = ga.run(3)
+            tele.close()
+            summary = run.summary() if run is not None else None
             trajectory = [
                 (h["generation"], h["best_fitness"], h["best_genes"])
                 for h in ga.history

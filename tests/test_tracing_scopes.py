@@ -348,7 +348,8 @@ def test_reader_against_a_trace_this_jax_writes(data, telemetry, tmp_path):
     trace = scope_reduce.read(path)
     # (one table per program shape this process has loaded, not only this call's)
     assert {scope_reduce.base_name(n) for n in trace["hlo_tables"]} == {scope_reduce.EVAL, scope_reduce.TRAIN}
-    assert [a["kind"] for a in trace["annotations"]][:5] == [
+    # (since PR 38 the host sampler's ``gentun/tick`` annotations lie among the call's, every 20 ms)
+    assert [a["kind"] for a in trace["annotations"] if a["kind"] != "tick"][:5] == [
         "cv_call", "prepare", "index_build", "init_params", "dataset"]
     assert scope_reduce.individuals_traced(trace, {}) == 3
     with open(path, "rb") as fh:
