@@ -76,14 +76,16 @@ What differs from the CNN family, by design:
 - **The expert layer is told which experts it holds** (``held_experts``, a
   range; the router keeps ``num_experts`` outputs).  It routes over all of
   them, keeps the (row, expert, weight) triples of its own experts in a row
-  buffer whose height follows the rows present -- 2.75 times the rank's mean
-  share, or, where a call routes more than that here, the worst case, decided
-  on the device from the router's own count (``_moe_ffn``) -- runs the three grouped
-  products with a kernel whose cost follows the rows present (megablox ``gmm``
-  on a TPU, ``lax.ragged_dot`` elsewhere), and returns its experts' part of
-  the sum.  What the absent experts would add is left out; no code stands in
-  for the other chips.  No assignment is dropped at either height
-  (``dropped`` is counted, and so is every layer that took the worst case).
+  buffer whose height follows the rows present -- the shortest of a ladder of
+  heights that holds them (``_ROW_BUFFER_SHARES`` times the rank's mean share,
+  then the worst case of top-k x tokens), decided on the device from the
+  router's own count (``_moe_ffn``): gather, masks and the float32 scatter-add
+  all run at the buffer's height, so a rung nearer the rows saves their time --
+  runs the three grouped products with a kernel whose cost follows the rows
+  present (megablox ``gmm`` on a TPU, ``lax.ragged_dot`` elsewhere), and
+  returns its experts' part of the sum.  What the absent experts would add is
+  left out; no code stands in for the other chips.  No assignment is dropped at
+  any height (``dropped`` is counted, and so is the height every layer took).
 - **A program is one individual wide.**  One individual of the published cut
   is ~0.65 B parameters and 16 bytes of training state a parameter
   (:func:`training_bytes`); two do not fit a 16 GB chip, so a population is
@@ -140,11 +142,16 @@ _GMM_TILING = (512, 512, 512)
 #: 512 / 512, 18.0-21.2 at four other shapes (PERF.md, PR 34).
 _ATTN_KERNEL_BLOCKS = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
                            block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512)
-#: The narrow row buffer holds this many times the rows a routed layer sends this rank on
-#: average.  On the chip a layer's busiest step reached 2.62 times (93 individuals of the
-#: benchmark's cell, the step after warm-up; 4 of them passed 2.0); each 0.25 costs 0.75% of an
-#: individual's time, a layer-step at the worst-case height instead 0.3-0.5% (PERF.md, PR 29).
-_NARROW_SHARES = 2.75
+#: The row buffer's heights below the worst case (top-k x tokens, always the last rung), in
+#: shares: times the rows a routed layer sends this rank on average.  A layer-step runs at the
+#: first that holds its rows; dispatch, combine and the experts' masks cost their height.
+#: A rung is also one more copy of the experts' kernels in every routed layer, forward and
+#: backward: 0.11-0.22 GB of device memory for its code, 20-30 s of a cold set-up and, until a
+#: rung's body was traced once a program, 4-6 s of a warm one.  So the ladder has the one rung
+#: below PR 29's 2.75 that the rows asked for: six heights (1, 1.5, 2, 2.75, 4 shares, the worst
+#: case) showed 96% of LFM2's layer-steps and 64% of Mellum2's under 1.25 shares, and ran 60 and
+#: 100 shares an individual where these run 62 and 123 and PR 29's two ran 132 and 185 (PERF.md).
+_ROW_BUFFER_SHARES = (1.25, 2.75)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -707,28 +714,34 @@ def _balance_term(scores, chosen, sequences: int, cfg: Lfm2MoeConfig):
 
 
 class RoutedStats(NamedTuple):
-    """What the routed layers of a call report beside their loads (scalars that add up over layers)."""
+    """What the routed layers of a call report beside their loads (each adds up over layers)."""
 
-    dropped: Any  # int32: held assignments that found no room in the row buffer: 0, either height holds what it is given
-    wide: Any  # int32: routed layers that took the worst-case height
+    dropped: Any  # int32: held assignments that found no room in the row buffer: 0, the height taken holds them
+    heights: Any  # int32 (rungs,): routed layers that took each of :func:`_row_buffer_heights`, shortest first
     balance: Any  # float32: the balance terms (``aux_loss`` rule; 0 under the bias rule, which has none)
 
+    @property
+    def wide(self):
+        """int32: routed layers that took the worst-case height where there is a shorter one."""
+        return self.heights[-1] * (len(self.heights) > 1)
 
-def _narrow_rows(cfg: Lfm2MoeConfig, tokens: int) -> int:
-    """The row buffer's narrow height: the smallest multiple of the ``gmm`` row
-    tile that holds ``_NARROW_SHARES`` times the rows this rank gets on average,
-    and no more than the worst case (top-k x tokens), which is then the only
-    height."""
+
+def _row_buffer_heights(cfg: Lfm2MoeConfig, tokens: int) -> Tuple[int, ...]:
+    """The row buffer's heights, shortest first: the smallest multiples of the
+    ``gmm`` row tile that hold ``_ROW_BUFFER_SHARES`` times the rows this rank
+    gets on average, those under the worst case (top-k x tokens), and the worst
+    case, which a small shape has alone."""
     full, tile = cfg.num_experts_per_tok * tokens, _GMM_TILING[0]
-    return min(full, tile * math.ceil(_NARROW_SHARES * full * cfg.n_held / (cfg.num_experts * tile)))
+    share = full * cfg.n_held / cfg.num_experts
+    below = sorted({tile * math.ceil(s * share / tile) for s in _ROW_BUFFER_SHARES})
+    return tuple(h for h in below if h < full) + (full,)
 
 
 def _expert_rows(cfg: Lfm2MoeConfig, dtype, cap: int, p, x, weight, order, sizes):
     """Dispatch, experts and combine on a row buffer of the static height
     ``cap``: the first ``cap`` assignments of ``order`` (held ones first, grouped
     by expert; ``sizes`` a held expert) each get a row.  Every pass has the
-    buffer's height.  Returns the tokens' sums and (the rows that found room,
-    ``cap``: the height that ran, for a caller that chose it on the device)."""
+    buffer's height.  Returns the tokens' sums and the rows that found room."""
     t, h = x.shape
     k = cfg.num_experts_per_tok
     with jax.named_scope("moe"), jax.named_scope("dispatch"):
@@ -747,42 +760,70 @@ def _expert_rows(cfg: Lfm2MoeConfig, dtype, cap: int, p, x, weight, order, sizes
         # a token's at most top-k rows, each times its float32 weight, summed in float32
         scaled = jnp.take(weight.reshape(-1), taken)[:, None] * down
         out = jnp.zeros((t, h), jnp.float32).at[taken // k].add(scaled).astype(dtype)
-    return out, (n_rows, jnp.int32(cap))
+    return out, n_rows
 
 
-def _expert_rows_by_count(narrow: int, full: int, cfg: Lfm2MoeConfig, dtype):
-    """:func:`_expert_rows` at ``narrow`` rows where the held assignments fit
-    and at ``full`` rows where they do not, decided on the device.
+def _traced_once(body):
+    """``body`` for callers that hand it operands of one shape again and again
+    within one trace of a program (the routed layers of a model): the first
+    call traces it, every later one replays the jaxpr under the scopes open at
+    that call.  ``body`` closes over no array."""
+    traced = {}
 
-    One ``cond`` forward and one backward, each branch differentiated inside
-    itself from the layer's inputs.  Differentiated from outside, a ``cond``
-    hands its backward pass every array either branch keeps, the absent
-    branch's as zeros of that branch's height: at the published widths 2.8 GB
-    more temporaries a step (TPU compiler's memory analysis, PR 29) and a
-    zero-fill of the worst-case buffers in every narrow pass."""
+    def replay(*operands):
+        flat, tree = jax.tree_util.tree_flatten(operands)
+        key = (tree, tuple((a.shape, a.dtype) for a in flat))
+        if key not in traced:
+            closed, out = jax.make_jaxpr(body, return_shape=True)(*operands)
+            traced[key] = closed, jax.tree_util.tree_structure(out)
+        closed, out_tree = traced[key]
+        return jax.tree_util.tree_unflatten(out_tree, jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *flat))
 
-    def pick(body, sizes, *operands):
-        return jax.lax.cond(sizes.sum() > narrow, functools.partial(body, full), functools.partial(body, narrow),
-                            *operands)
+    return replay
 
-    forward = functools.partial(_expert_rows, cfg, dtype)
 
-    def backward(cap, p, x, weight, order, sizes, g):
-        _, vjp, _ = jax.vjp(lambda p, x, weight: forward(cap, p, x, weight, order, sizes), p, x, weight, has_aux=True)
-        return vjp(g)
+def _expert_rows_by_count(heights: Tuple[int, ...], cfg: Lfm2MoeConfig, dtype):
+    """:func:`_expert_rows` at the height of ``heights`` that the first operand,
+    a rung's index, names: decided on the device.
 
-    def primal(*operands):
-        return pick(forward, operands[-1], *operands)
+    One ``switch`` forward and one backward, each branch differentiated inside
+    itself from the layer's inputs.  Differentiated from outside, a conditional
+    hands its backward pass every array any branch keeps, an absent branch's as
+    zeros of that branch's height: at the published widths 2.8 GB more
+    temporaries a step for one more branch (TPU compiler's memory analysis,
+    PR 29) and a zero-fill of the taller buffers in every shorter pass.
 
-    def cotangents(operands, g):  # of the weights, the tokens and the routing weights; order and sizes have none
-        return pick(backward, operands[-1], *operands, g[0]) + (None, None)
+    A height's body, forward and backward, is traced once for the callers that
+    share what this returns (:func:`forward` hands one to all its layers): the
+    trace of the grouped products' kernels is most of what a height costs a
+    process before it compiles or loads anything, and the routed layers of a
+    model have one shape (PERF.md, PR 39)."""
+
+    def bodies(cap):
+        forward = functools.partial(_expert_rows, cfg, dtype, cap)
+
+        def backward(p, x, weight, order, sizes, g):
+            _, vjp, _ = jax.vjp(lambda p, x, weight: forward(p, x, weight, order, sizes), p, x, weight, has_aux=True)
+            return vjp(g)
+
+        return _traced_once(forward), _traced_once(backward)
+
+    forwards, backwards = zip(*map(bodies, heights))
+
+    def primal(rung, *operands):
+        return jax.lax.switch(rung, forwards, *operands)
+
+    def cotangents(operands, g):  # of the weights, the tokens and the routing weights; rung, order and sizes have none
+        rung, *operands = operands
+        return (None,) + jax.lax.switch(rung, backwards, *operands, g[0]) + (None, None)
 
     by_count = jax.custom_vjp(primal)
     by_count.defvjp(lambda *operands: (primal(*operands), operands), cotangents)
     return by_count
 
 
-def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = None, sequences: int = 1):
+def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = None, sequences: int = 1,
+             by_count=_expert_rows_by_count):
     """The held experts' part of the routed feed-forward on (tokens, hidden),
     plus the shared experts where the configuration has them (every rank
     computes those alike).
@@ -791,18 +832,21 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
     was chosen for (the bias rule needs them all), ``stats`` is this layer's
     :class:`RoutedStats` (its balance term is over ``sequences`` sequences of
     equal length).  The row buffer's height follows the rows present:
-    the held assignments the router counted decide on the device between
-    :func:`_narrow_rows` and, over it, the worst case of top-k x tokens rows;
-    :func:`_expert_rows` is the body of both, so no assignment is ever dropped.
-    ``row_buffer`` stands in for the narrow height in the tests of that
-    arithmetic and no caller sets it.  The ``cond`` is called outside the
-    ``moe`` scope and each branch opens it again: jax names a branch's ops after
-    the scopes open at the call (``layer2/cond/branch_0_fun/moe/experts/...``),
-    and the benchmark's op classes go by what follows the first ``moe``."""
+    the held assignments the router counted pick on the device the first of
+    :func:`_row_buffer_heights` that holds them, the worst case of top-k x
+    tokens rows at the latest; :func:`_expert_rows` is the body at every
+    height, so no assignment is ever dropped.  ``row_buffer`` stands in for
+    the heights below the worst case in the tests of that arithmetic and no
+    caller sets it; ``by_count`` builds the function that chooses, and a
+    caller with several layers hands in one that builds it once
+    (:func:`_expert_rows_by_count`).  The ``switch`` is called outside the ``moe`` scope and
+    each branch opens it again: jax names a branch's ops after the scopes open
+    at the call (``layer2/cond/branch_0_fun/moe/experts/...``: a ``switch`` is
+    the same conditional), and the benchmark's op classes go by what follows
+    the first ``moe``."""
     t = x.shape[0]
     k, n_held = cfg.num_experts_per_tok, cfg.n_held
-    full = k * t
-    narrow = _narrow_rows(cfg, t) if row_buffer is None else row_buffer
+    heights = _row_buffer_heights(cfg, t) if row_buffer is None else tuple(sorted({min(row_buffer, k * t), k * t}))
     with jax.named_scope("moe"), jax.named_scope("router"):
         chosen, weight, scores = _route(p["router"], bias, x, cfg)
         load = jnp.sum(chosen[..., None] == jnp.arange(cfg.num_experts), axis=(0, 1), dtype=jnp.int32)
@@ -817,20 +861,23 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
         order = jnp.argsort(key, stable=True)  # held assignments first, grouped by expert
         sizes = jnp.sum(key[:, None] == jnp.arange(n_held), axis=0, dtype=jnp.int32)
         n_held_rows = sizes.sum()
+        rung = jnp.sum(n_held_rows > jnp.asarray(heights[:-1], jnp.int32), dtype=jnp.int32)  # the first that holds them
     operands = ({name: p[name] for name in ("w1", "w3", "w2")}, x, weight, order, sizes)
-    if narrow >= full:  # one height, nothing to fall back from
-        out, (n_rows, height) = _expert_rows(cfg, dtype, full, *operands)
+    if len(heights) == 1:  # nothing to choose
+        out, n_rows = _expert_rows(cfg, dtype, heights[0], *operands)
     else:
-        out, (n_rows, height) = _expert_rows_by_count(narrow, full, cfg, dtype)(*operands)
+        out, n_rows = by_count(heights, cfg, dtype)(rung, *operands)
     if "shared" in p:
         with jax.named_scope("moe"), jax.named_scope("shared"):
             out = out + _dense_ffn(p["shared"], x, dtype)
-    return out, load, RoutedStats(n_held_rows - n_rows, jnp.int32(height > narrow), balance)
+    taken = (jnp.arange(len(heights)) == rung).astype(jnp.int32)
+    return out, load, RoutedStats(n_held_rows - n_rows, taken, balance)
 
 
-def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x):
+def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x, by_count=_expert_rows_by_count):
     """One layer on (sequences, length, hidden); ``bias`` is the layer's router
-    bias or None.  Returns the output and, of a routed layer, (load, stats)."""
+    bias or None; ``by_count`` is :func:`_moe_ffn`'s.  Returns the output and,
+    of a routed layer, (load, stats)."""
     kind, name = cfg.layer_types[index], f"layer{cfg.layer_ids[index]}"
     with jax.named_scope(name):
         normed = _rms_norm(x, p["op_norm"], cfg.norm_eps).astype(dtype)
@@ -848,7 +895,7 @@ def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x):
             with jax.named_scope("dense_ffn"):
                 return h + _dense_ffn(p["dense"], normed, dtype), None
         out, load, stats = _moe_ffn(p["moe"], bias, normed.reshape(-1, normed.shape[-1]), cfg, dtype,
-                                    sequences=normed.shape[0])
+                                    sequences=normed.shape[0], by_count=by_count)
         return h + out.reshape(h.shape), (load, stats)
 
 
@@ -859,9 +906,11 @@ def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
     dtype = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dtype)
-    loads, use = [], RoutedStats(jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32))
+    rungs = len(_row_buffer_heights(cfg, tokens.size))
+    loads, use = [], RoutedStats(jnp.zeros((), jnp.int32), jnp.zeros(rungs, jnp.int32), jnp.zeros((), jnp.float32))
+    by_count = functools.lru_cache(maxsize=None)(_expert_rows_by_count)  # one for the layers of this trace
     for i, p in enumerate(params["layers"]):
-        fn = functools.partial(_layer, cfg, i, dtype)
+        fn = functools.partial(_layer, cfg, i, dtype, by_count=by_count)
         moe = i >= cfg.num_dense_layers
         x, aux = (jax.checkpoint(fn) if remat else fn)(p, bias[i - cfg.num_dense_layers] if moe else None, x)
         if moe:
@@ -889,10 +938,10 @@ class Lfm2MoePrograms(NamedTuple):
     ``init(base_key, genome_hash) -> state``: a fresh train state, a dict of
     ``params``/``m``/``v`` (one tree each, :func:`param_shapes`), ``bias``
     (routed layers, experts), ``rows`` (routed layers, held experts: rows
-    routed so far), ``dropped`` and ``wide_buffer`` (:class:`RoutedStats`,
-    summed over the steps so far) and, under the ``aux_loss`` balance rule,
-    ``aux_loss`` (the routed layers' balance terms before their weight, summed
-    likewise).  ``train_step(state, x, y, batch_rows,
+    routed so far), ``dropped`` and ``row_buffer_heights`` (:class:`RoutedStats`,
+    summed over the steps so far: routed layers x steps at each height) and,
+    under the ``aux_loss`` balance rule, ``aux_loss`` (the routed layers'
+    balance terms before their weight, summed likewise).  ``train_step(state, x, y, batch_rows,
     genes, step) -> (state, loss, load)``: ``state`` is donated; ``x``/``y``
     are the whole token arrays, ``batch_rows`` (train_steps, batch_sequences)
     the sequences of every step, ``genes`` the float32 vector in the
@@ -934,7 +983,7 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         state = {"params": params, "m": zeros(), "v": zeros(),
                  "bias": jnp.zeros((n_moe, cfg.num_experts), jnp.float32),
                  "rows": jnp.zeros((n_moe, cfg.n_held), jnp.int32), "dropped": jnp.zeros((), jnp.int32),
-                 "wide_buffer": jnp.zeros((), jnp.int32)}
+                 "row_buffer_heights": jnp.zeros(len(_row_buffer_heights(cfg, cfg.tokens_per_step)), jnp.int32)}
         if by_loss:
             state["aux_loss"] = jnp.zeros((), jnp.float32)
         return state
@@ -972,7 +1021,7 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         held = load[:, lo:hi]
         new = {"params": params, "m": m, "v": v, "bias": bias,
                "rows": state["rows"] + held, "dropped": state["dropped"] + use.dropped,
-               "wide_buffer": state["wide_buffer"] + use.wide}
+               "row_buffer_heights": state["row_buffer_heights"] + use.heights}
         if by_loss:
             new["aux_loss"] = state["aux_loss"] + use.balance
         return new, loss, held
@@ -1160,12 +1209,15 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
         losses = sp.fence([programs.eval(state["params"], state["bias"], x, y, rows) for rows in val_rows])
     with phase("fetch", {"individual": individual}) as sp:
         if _tele.enabled():
-            losses, rows, dropped, wide, balance = jax.device_get(
-                (losses, state["rows"], state["dropped"], state["wide_buffer"], state.get("aux_loss")))
-            _count_expert_rows(cfg, rows, int(dropped), int(wide))
+            losses, rows, dropped, taken, balance = jax.device_get(
+                (losses, state["rows"], state["dropped"], state["row_buffer_heights"], state.get("aux_loss")))
+            wide = int(RoutedStats(dropped, taken, balance).wide)
+            by_height = list(zip(_row_buffer_heights(cfg, cfg.tokens_per_step), taken.tolist()))
+            _count_expert_rows(cfg, rows, int(dropped), wide, by_height)
             for mask, n in by_mask.items():
                 _get_registry().counter("attention_kernel_layer_steps_total", mask=mask).inc(n)
-            sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=int(wide))
+            sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=wide,
+                   row_buffer_heights=[list(pair) for pair in by_height])
             if balance is not None:  # the ``aux_loss`` rule: the term before its weight, a routed layer and step
                 balance = float(balance) / (len(cfg.moe_layers) * cfg.train_steps)
                 _get_registry().counter("aux_loss_total").inc(balance)
@@ -1176,13 +1228,16 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     return float(np.mean(np.concatenate([np.ravel(l) for l in losses]), dtype=np.float64))
 
 
-def _count_expert_rows(cfg: Lfm2MoeConfig, rows: np.ndarray, dropped: int, wide: int) -> None:
-    """``expert_rows{layer, expert}``, ``dropped_assignments_total`` and
-    ``row_buffer_wide_total`` from what an individual's steps summed on the
-    device (telemetry on only)."""
+def _count_expert_rows(cfg: Lfm2MoeConfig, rows: np.ndarray, dropped: int, wide: int,
+                       by_height: Sequence[Tuple[int, int]]) -> None:
+    """``expert_rows{layer, expert}``, ``dropped_assignments_total``,
+    ``row_buffer_wide_total`` and ``row_buffer_height_total{rows}`` from what an
+    individual's steps summed on the device (telemetry on only)."""
     reg = _get_registry()
     for layer, per_expert in zip(cfg.moe_layers, rows):
         for expert, n in zip(range(*cfg.held_experts), per_expert):
             reg.counter("expert_rows", layer=str(cfg.layer_ids[layer]), expert=str(expert)).inc(int(n))
     reg.counter("dropped_assignments_total").inc(dropped)
     reg.counter("row_buffer_wide_total").inc(wide)
+    for height, layer_steps in by_height:
+        reg.counter("row_buffer_height_total", rows=str(height)).inc(layer_steps)

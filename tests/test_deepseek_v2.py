@@ -28,6 +28,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import routed_ladder
 from gentun_tpu import DeepseekV2Individual, GeneticAlgorithm, Population, deepseek_v2_genome, lfm2_moe_genome
 from gentun_tpu.models import lfm2_moe as M
 from gentun_tpu.telemetry import spans
@@ -459,30 +460,19 @@ def test_the_balance_terms_gradient_reaches_the_router_and_nothing_else_of_the_e
     assert float(stats_twice) == pytest.approx(2.0 * float(value), rel=1e-5)
 
 
+#: 1,024 tokens, top-6, 8 of 32 experts held: a mean share of 1,536 rows; 1.25 and 2.75 shares in tiles of 512, and the
+#: worst case at 4 shares
+LADDER_HEIGHTS = (2048, 4608, 6144)
+
+
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 1e-2)])
-def test_the_narrow_and_the_wide_row_buffer_give_the_same_layer_at_top_6(tokens, dtype, tol):
-    m, cfg, w = _moe_case(tokens)
-    x = jnp.asarray(np.random.default_rng(4).normal(size=(32, 32)), jnp.float32)
-    probe = jnp.asarray(np.random.default_rng(6).normal(size=(32, 32)), jnp.float32)
-
-    def layer(row_buffer):
-        def value(p, xs):
-            out, load, stats = M._moe_ffn(p, None, xs.astype(dtype), cfg, jnp.dtype(dtype), row_buffer)
-            return jnp.sum(out.astype(jnp.float32) * probe), (out, load, stats)
-        return jax.jit(jax.value_and_grad(jax.checkpoint(value), argnums=(0, 1), has_aux=True))(w, x)
-
-    with HIGHEST:
-        (_, (wide_out, wide_load, _)), wide_grads = layer(None)  # 6 x 32 = 192 rows: the only height at this size
-        (_, (out, load, stats)), grads = layer(128)
-        (_, (_, _, fell_back)), _ = layer(64)
-    held = int(load[4:12].sum())
-    assert 64 < held <= 128 and int(stats.wide) == 0 and int(stats.dropped) == 0
-    assert int(fell_back.wide) == 1 and int(fell_back.dropped) == 0
-    np.testing.assert_array_equal(load, wide_load)
-    np.testing.assert_allclose(out.astype(jnp.float32), wide_out.astype(jnp.float32), atol=tol, rtol=tol)
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(wide_grads)):
-        assert float(jnp.abs(r).max()) > 0, jax.tree_util.keystr(path)
-        np.testing.assert_allclose(g, r, atol=tol * float(jnp.abs(r).max()), err_msg=jax.tree_util.keystr(path))
+@pytest.mark.parametrize("count,rung", routed_ladder.counts_at_the_rungs(LADDER_HEIGHTS))
+def test_the_narrow_and_the_wide_row_buffer_give_the_same_layer_at_top_6(tokens, count, rung, dtype, tol):
+    """Every rung of a small ladder filled to its last row, and one row more,
+    with the shared experts beside: what the worst-case height alone gives."""
+    m, cfg, w = _moe_case(tokens, experts=32)
+    assert M._row_buffer_heights(cfg, 1024) == LADDER_HEIGHTS and "shared" in w
+    routed_ladder.assert_the_ladders_layer_is_the_worst_case_heights(cfg, w, None, 1024, count, rung, dtype, tol)
 
 
 def test_the_grouped_products_tiles_follow_the_shape():
@@ -494,8 +484,8 @@ def test_the_grouped_products_tiles_follow_the_shape():
     assert M._gmm_tiling(33792, 1408, 2048) == (512, 1408, 512)
     assert M._gmm_tiling(98304, 2048, 1408)[0] == 512 and M._gmm_tiling(192, 32, 24)[0] == 64
     assert M._gmm_tiling(512, 2048, 10944) == (512, 512, 512)
-    cfg = M.Lfm2MoeConfig(num_experts_per_tok=6)
-    assert (M._narrow_rows(cfg, 16384), 6 * 16384) == (33792, 98304)  # 2.75 x 12,288, in tiles of 512
+    cfg = M.Lfm2MoeConfig(num_experts_per_tok=6)  # a mean share of 12,288 rows: 1.25 and 2.75 of them, in tiles of 512
+    assert M._row_buffer_heights(cfg, 16384) == (15360, 33792, 98304)
 
 
 # -- refusals, arithmetic, the species ------------------------------------------------------------------
@@ -789,6 +779,12 @@ def test_the_balance_reader_averages_the_windows_fetch_spans_and_a_program_witho
     assert reader.read({**window, "records": records}) == 1.25
     assert reader.read({**window, "records": [_span("fetch", 11.0, {"individual": 0, "expert_rows": [[1]]})]}) is None
     assert reader.read({**window, "records": records[:1]}) is None
+
+
+def test_the_row_buffer_reader_divides_the_rows_the_heights_ran_by_the_rows_routed_and_the_parent_reads_nothing(
+        layer_metric):
+    reader = layer_metric("dsv2_row_buffer_rows_per_routed_row")
+    routed_ladder.assert_the_reader_divides_the_rows_run_by_the_rows_routed(reader)
 
 
 def test_the_core_roofline_reader_divides_the_kernels_flops_by_the_kernels_own_time(layer_metric):

@@ -25,6 +25,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import routed_ladder
 from gentun_tpu import GeneticAlgorithm, Lfm2MoeIndividual, Population, lfm2_moe_genome
 from gentun_tpu.models import lfm2_moe as M
 from gentun_tpu.telemetry import spans
@@ -204,41 +205,26 @@ def test_no_assignment_is_dropped_when_every_token_goes_to_one_held_expert(token
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
+#: A small shape at which the configuration itself gives the ladder's three heights:
+#: 2 x 512 tokens, top-2, 2 of 8 experts held -- 1,024 and 1,536 rows (two and three
+#: ``gmm`` tiles hold 1.25 and 2.75 times the mean share of 512) under the worst
+#: case of 2,048.
+LADDER = {**MODEL, "layer_types": ["conv"], "num_dense_layers": 0}
+LADDER_HEIGHTS = (1024, 1536, 2048)
+
+
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 1e-2)])
-def test_the_narrow_and_the_wide_row_buffer_give_the_same_layer(tokens, dtype, tol):
-    """One input whose held rows fit the narrow height: the branch the ``cond``
-    takes then and the worst-case height (the only one without ``row_buffer``
-    at this size) are the same function of the same rows."""
-    m = one_layer("conv", "moe")
-    cfg = config_of(tokens, m)
-    w = R.seeded_weights(m, 3)["layers"][0]["moe"]
+@pytest.mark.parametrize("count,rung", routed_ladder.counts_at_the_rungs(LADDER_HEIGHTS))
+def test_the_narrow_and_the_wide_row_buffer_give_the_same_layer(long_tokens, count, rung, dtype, tol):
+    """Rows that fill a rung of the ladder to its last row, and one row more
+    (the next rung engages): the branch the ``switch`` takes and the worst-case
+    height alone are the same function of the same rows, value and gradients."""
+    cfg = config_of(long_tokens, LADDER)
+    assert M._row_buffer_heights(cfg, cfg.tokens_per_step) == LADDER_HEIGHTS
+    w = R.seeded_weights(LADDER, 3)["layers"][0]["moe"]
     b = jnp.asarray(0.01 * np.random.default_rng(5).normal(size=8), jnp.float32)
-    x = jnp.asarray(np.random.default_rng(4).normal(size=(32, 32)), jnp.float32)
-    probe = jnp.asarray(np.random.default_rng(6).normal(size=(32, 32)), jnp.float32)
-
-    def layer(row_buffer):
-        def value(p, xs):
-            out, load, use = M._moe_ffn(p, b, xs.astype(dtype), cfg, jnp.dtype(dtype), row_buffer)
-            return jnp.sum(out.astype(jnp.float32) * probe), (out, load, use)
-        return jax.jit(jax.value_and_grad(jax.checkpoint(value), argnums=(0, 1), has_aux=True))(w, x)
-
-    with HIGHEST:
-        (_, (wide_out, wide_load, _)), wide_grads = layer(None)
-        (_, (out, load, use)), grads = layer(40)
-    held = int(load[1:5].sum())
-    assert 16 < held <= 40 and int(use.wide) == 0 and int(use.dropped) == 0
-    assert float(jnp.abs(out.astype(jnp.float32)).max()) > 0
-    np.testing.assert_array_equal(load, wide_load)
-    np.testing.assert_allclose(out.astype(jnp.float32), wide_out.astype(jnp.float32), atol=tol, rtol=tol)
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(wide_grads)):
-        assert float(jnp.abs(r).max()) > 0, jax.tree_util.keystr(path)
-        np.testing.assert_allclose(g, r, atol=tol * float(jnp.abs(r).max()), err_msg=jax.tree_util.keystr(path))
-
-
-#: A small shape at which the configuration itself gives two heights: 2 x 512
-#: tokens, top-2, 2 of 8 experts held -- 1,536 rows (three ``gmm`` tiles hold 2.75
-#: times the mean share of 512) against the worst case of 2,048.
-TWO_HEIGHTS = {**MODEL, "layer_types": ["conv"], "num_dense_layers": 0}
+    routed_ladder.assert_the_ladders_layer_is_the_worst_case_heights(cfg, w, b, cfg.tokens_per_step, count, rung,
+                                                                     dtype, tol)
 
 
 @pytest.fixture(scope="module")
@@ -257,41 +243,49 @@ class _Sink:
 
 def test_a_train_step_on_a_forced_bias_counts_the_layers_that_took_the_wide_buffer(long_tokens):
     x, y = long_tokens
-    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs(TWO_HEIGHTS))
+    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs(LADDER))
     cfg = programs.config
-    assert (M._narrow_rows(cfg, cfg.tokens_per_step), 2 * cfg.tokens_per_step) == (1536, 2048)
-    assert M._narrow_rows(M.Lfm2MoeConfig(), 16384) == 22528  # the published cut: 44 tiles against 65,536 rows
-    w = R.seeded_weights(TWO_HEIGHTS, 5)
+    assert M._row_buffer_heights(cfg, cfg.tokens_per_step) == LADDER_HEIGHTS
+    # the published cut: 20 and 44 tiles under the worst case (44 tiles are the 2.75 shares of PR 29)
+    assert M._row_buffer_heights(M.Lfm2MoeConfig(), 16384) == (10240, 22528, 65536)
+    w = R.seeded_weights(LADDER, 5)
     rows = np.array([[0, 1], [2, 3], [0, 2]], np.int32)
     forced = np.zeros((1, 8), np.float32)
     forced[0, 2:4] = 10.0  # both choices of every token go to the two held experts: 2,048 rows
     with HIGHEST:
         state, losses, loads = _program_steps(programs, w, forced, x, y, rows, 2)
-        ref = R.train(TWO_HEIGHTS, w, [(x[r], y[r]) for r in rows[:2]], GENES, bias=forced)
+        ref = R.train(LADDER, w, [(x[r], y[r]) for r in rows[:2]], GENES, bias=forced)
         calm, _, calm_loads = _program_steps(programs, w, np.zeros((1, 8), np.float32), x, y, rows, 2)
     assert all(int(l.sum()) == 2048 for l in loads) and all(int(l.sum()) <= 1536 for l in calm_loads)
-    assert int(state["wide_buffer"]) == 2 and int(state["dropped"]) == 0
-    assert int(calm["wide_buffer"]) == 0 and int(calm["dropped"]) == 0
+    assert state["row_buffer_heights"].tolist() == [0, 0, 2] and int(state["dropped"]) == 0
+    rungs = [int(np.searchsorted(LADDER_HEIGHTS, int(l.sum()))) for l in calm_loads]  # the first height that holds them
+    assert calm["row_buffer_heights"].tolist() == np.bincount(rungs, minlength=3).tolist() and int(calm["dropped"]) == 0
+    assert calm["row_buffer_heights"][-1] == 0 and int(calm["row_buffer_heights"].sum()) == 2  # 1 routed layer x 2 steps
     np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
                             jax.tree_util.tree_leaves(ref["weights"])):
         np.testing.assert_allclose(a, b, atol=3e-5, err_msg=jax.tree_util.keystr(path))
-    # telemetry on: the same count on the individual's fetch span and on the counter
+    # telemetry on: the same counts on the individual's fetch span and on the counters
     forcing = programs._replace(init=lambda key, genome_hash: {**programs.init(key, genome_hash),
                                                                 "bias": jnp.asarray(forced)})
-    get_registry().reset()
-    sink = _Sink()
-    spans.set_run_sink(sink)
-    spans.enable()
-    try:
-        M._score_one(forcing, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32), M.gene_vector(GENES), jnp.asarray(x),
-                     jnp.asarray(y), jnp.asarray(rows), [jnp.asarray([4, 5])], [np.int32(s) for s in range(3)], 0)
-    finally:
-        spans.disable()
-        spans.set_run_sink(None)
-    fetched = [r["attrs"] for r in sink.records if r["type"] == "span" and r["kind"] == "fetch"]
-    assert [a["wide_buffer"] for a in fetched] == [3] and fetched[0]["dropped"] == 0  # 3 steps x 1 routed layer
-    assert get_registry().counter("row_buffer_wide_total").value == 3
+    for scored, by_height in ((forcing, [0, 0, 3]), (programs, None)):
+        get_registry().reset()
+        sink = _Sink()
+        spans.set_run_sink(sink)
+        spans.enable()
+        try:
+            M._score_one(scored, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32), M.gene_vector(GENES), jnp.asarray(x),
+                         jnp.asarray(y), jnp.asarray(rows), [jnp.asarray([4, 5])], [np.int32(s) for s in range(3)], 0)
+        finally:
+            spans.disable()
+            spans.set_run_sink(None)
+        (fetched,) = [r["attrs"] for r in sink.records if r["type"] == "span" and r["kind"] == "fetch"]
+        heights, taken = zip(*fetched["row_buffer_heights"])
+        assert heights == LADDER_HEIGHTS and sum(taken) == 3 and fetched["dropped"] == 0  # 3 steps x 1 routed layer
+        assert by_height is None or list(taken) == by_height
+        assert fetched["wide_buffer"] == taken[-1] == get_registry().counter("row_buffer_wide_total").value
+        assert [get_registry().counter("row_buffer_height_total", rows=str(h)).value for h in heights] == list(taken)
+    assert taken[-1] == 0 and fetched["wide_buffer"] == 0  # the model's own start routes under the worst case
 
 
 # -- the fused attention kernel (PR 31) --------------------------------------------------------------
@@ -633,12 +627,12 @@ def test_the_lowered_train_step_carries_every_scope(tokens, long_tokens):
     for scope in ("embed", "layer0", "layer2", "layer3", "conv_op", "attention", "dense_ffn", "moe/router",
                   "moe/dispatch", "moe/experts", "moe/combine", "head", "loss", "optimizer", "bias_update"):
         assert scope in text, scope
-    # with two heights (a ``cond``, forward and backward) every op of the expert layer keeps its class
-    two = _lowered_train_step(M.Lfm2MoeModel.compiled_programs(long_tokens[0], **model_kwargs(TWO_HEIGHTS)),
+    # with the ladder's three heights (a ``switch``, forward and backward) every op of the expert layer keeps its class
+    two = _lowered_train_step(M.Lfm2MoeModel.compiled_programs(long_tokens[0], **model_kwargs(LADDER)),
                               *long_tokens)
     names, one_height = (set(re.findall(r'loc\("([^"]+)"', t)) for t in (two, text))
     branches = {n for n in names if "/cond/branch_" in n}
-    assert {n.split("/cond/")[1].split("/")[0] for n in branches} == {"branch_0_fun", "branch_1_fun"}
+    assert {n.split("/cond/")[1].split("/")[0] for n in branches} == {f"branch_{i}_fun" for i in range(3)}
     assert any("transpose" in n for n in branches) and len(branches) > 100
     for part in ("dispatch", "experts", "combine"):
         assert any(scope_rules.classify(n) == ("expert_mm" if part == "experts" else "moe_route", part)
@@ -703,6 +697,12 @@ def test_the_expert_load_reader_reads_the_windows_fetch_spans_and_not_set_ups(la
         _span("fetch", 13.0, {"other": 1, "expert_rows": [[7000, 1], [1, 1]]})]}
     assert reader.read(run) == pytest.approx(80 * 4 / 200)
     assert reader.read({"window": (10.0, 20.0), "records": run["records"][:1]}) is None
+
+
+def test_the_row_buffer_reader_divides_the_rows_the_heights_ran_by_the_rows_routed_and_the_parent_reads_nothing(
+        layer_metric):
+    reader = layer_metric("lm_row_buffer_rows_per_routed_row")
+    routed_ladder.assert_the_reader_divides_the_rows_run_by_the_rows_routed(reader)
 
 
 def test_the_kernel_reader_averages_the_windows_train_spans_and_a_program_without_the_attribute_reads_nothing(

@@ -31,6 +31,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import routed_ladder
 from gentun_tpu import deepseek_v2_genome
 from gentun_tpu.models import lfm2_moe as M
 from gentun_tpu.telemetry import spans
@@ -515,6 +516,26 @@ def test_the_grouped_product_at_2304_by_896_is_the_plain_one():
     np.testing.assert_allclose(np.asarray(got)[:start], want[:start], atol=1e-4)
 
 
+# -- the row buffer's ladder at this architecture's routing -------------------------------------------------
+
+
+#: The published routing at a small width: 64 experts, 8 a token, 8 held, weights normalised over the chosen.  At 512
+#: tokens the mean share is 512 rows; 1.25 and 2.75 shares in tiles of 512, and the worst case of 8.
+TOP_8 = {**MODEL, "num_hidden_layers": 1, "layer_types": ["full_attention"], "num_experts": 64, "num_experts_per_tok": 8,
+         "held_experts": [8, 16]}
+LADDER_HEIGHTS = (1024, 1536, 4096)
+
+
+@pytest.mark.parametrize("count,rung", routed_ladder.counts_at_the_rungs(LADDER_HEIGHTS))
+def test_the_row_buffers_ladder_gives_the_worst_case_heights_layer_at_top_8(tokens, count, rung):
+    """Every rung filled to its last row, and one row more: the height the ``switch`` takes gives the value and
+    the gradients of the worst-case height alone, and drops nothing."""
+    cfg = config_of(tokens, TOP_8)
+    assert M._row_buffer_heights(cfg, 512) == LADDER_HEIGHTS and cfg.norm_topk_prob and cfg.scoring_func == "softmax"
+    w = R.seeded_weights(TOP_8, 3, STD)["layers"][0]["moe"]
+    routed_ladder.assert_the_ladders_layer_is_the_worst_case_heights(cfg, w, None, 512, count, rung, "float32", 1e-6)
+
+
 # -- refusals, scopes, spans, counters --------------------------------------------------------------------
 
 
@@ -719,7 +740,8 @@ def test_the_configuration_file_holds_the_catalogs_numbers_and_the_cut_is_the_by
     cfg = M._normalize_config(np.zeros((config["n_sequences"], config["data"]["seq_len"]), np.int32), params)[0]
     need = M.training_bytes(cfg)
     assert need["params"] == 624_072_960 and need["state"] == 9_985_167_360
-    assert cfg.tokens_per_step == 16384 and M._narrow_rows(cfg, cfg.tokens_per_step) == 45056
+    assert cfg.tokens_per_step == 16384  # a mean share of 16,384 rows; 45,056 are the 2.75 shares of PR 29
+    assert M._row_buffer_heights(cfg, 16384) == (20480, 45056, 131072)
     assert cfg.head_dim == 128 and not cfg.qk_norm and cfg.typed_attention and cfg.sliding_window == 1024
     shapes = M.param_shapes(cfg)
     assert shapes["layers"][0]["attn"] == {"q": (2304, 4096), "k": (2304, 512), "v": (2304, 512), "o": (4096, 2304)}
@@ -825,13 +847,19 @@ def test_the_kernel_readers_split_the_windows_train_spans_by_mask_and_the_parent
     assert full_reader.read({**window, "records": records[:1]}) is None
 
 
+def test_the_row_buffer_reader_divides_the_rows_the_heights_ran_by_the_rows_routed_and_the_parent_reads_nothing(
+        layer_metric):
+    reader = layer_metric("mel_row_buffer_rows_per_routed_row")
+    routed_ladder.assert_the_reader_divides_the_rows_run_by_the_rows_routed(reader)
+
+
 def test_every_mel_metric_of_the_manifest_has_a_reader_and_reads_nothing_from_an_empty_run(layer_metric):
     """A program that lacks the spans (the parent's, on the new cell's readers) makes no reader raise."""
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as fh:
         manifest = json.load(fh)
     cell = "mellum2_12b_a2p5b_ep8.popeval"
     names = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [cell]]
-    assert len(names) == 26 and all(n.startswith("mel_") for n in names), names
+    assert len(names) == 27 and all(n.startswith("mel_") for n in names), names  # 26 of PR 34, the row buffer's of PR 39
     empty = {"config": _config_file(), "cell": {"name": cell}, "chips": 1, "units": [], "records": [],
              "window": (0.0, 1.0), "elapsed": 1.0, "monitor": None, "trace": None, "memory_peak_bytes": 0, "peak": None}
     for name in names:
