@@ -1,11 +1,12 @@
 """Routed language models as fitness models: one expert-parallel rank's share, trained under a recipe genome.
 
-The second jax family beside the Genetic-CNN (``models/cnn.py``), and three
+The second jax family beside the Genetic-CNN (``models/cnn.py``), and four
 architectures of it, told apart by the configuration alone (which operator a
 layer has, which mask and which rope an attention layer's type gives it, how
-the router scores, whether shared experts stand beside the routed ones,
-whether the head is tied, which balance rule runs): one evaluator, one train
-step builder, one expert layer, one causal core and one optimizer serve all.
+the router scores, whether shared experts stand beside the routed ones and
+behind a gate, whether the head is tied, which balance rule runs): one
+evaluator, one train step builder, one expert layer, one causal core and one
+optimizer serve all.
 
 ``LFM2-24B-A2B`` (``model_type`` ``lfm2_moe``,
 https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json; the
@@ -66,6 +67,32 @@ normalised over the chosen::
     loss = cross-entropy (head untied) + alpha * the balance term above: the *recipe's* (``aux_alpha``), the
            published config gives it no weight
 
+``Qwen3-Next-80B-A3B-Instruct`` (``model_type`` ``qwen3_next``,
+https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json):
+``linear_attention`` layers (Gated DeltaNet) three to one with gated
+``full_attention`` at a head size of 256, every layer routed (512 experts, 10 a
+token, their weights normalised over the chosen) with one shared expert behind
+a sigmoid gate::
+
+    Op = linear_attention:  [q ; k ; v ; z] = W_qkvz x (key heads' q and k, value heads' v and z, blocks of
+         columns);  [b ; a] = W_ba x, one of each a value head;  [q ; k ; v] = silu(causal depthwise conv of
+         ``linear_conv_kernel_dim`` taps, zeros before position 0);  beta = sigmoid(b),
+         g = -exp(A_log) softplus(a + dt_bias) <= 0;  q = l2norm(q) / sqrt(key size), k = l2norm(k), each key head
+         serving value heads / key heads value heads;  per value head from S_0 = 0, float32:
+             S' = exp(g_t) S_{t-1};   S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T;   o_t = S_t^T q_t
+         o = RMSNorm(o; w_n) * silu(z) a head;  W_out.  The rule runs in chunks of ``delta_chunk`` positions
+         (:func:`_delta_core`): a chunk's updates are one unit lower-triangular system, solved once for all chunks,
+         and a ``scan`` carries the state from chunk to chunk; the same program on every backend
+    Op = full_attention (gated):  [q ; gate] = W_q x, a head's columns [its query | its gate];  k, v as above;
+         RMSNorm on q and k;  rope on the leading ``partial_rotary_factor`` of a head's columns (rotate-half inside
+         them), the others pass;  the causal core;  W_o (o * sigmoid(gate))
+    FFN routed:  Mellum2's rule over 512 experts, 10 a token, the held experts' part
+                 + sigmoid(w_g . x) * W2_s (silu(W1_s x) * W3_s x)      one shared expert, one gate scalar a token
+    loss = cross-entropy (head untied) + alpha * the balance term above (the recipe's)
+    ``A_log`` starts at ln u, u uniform on (0, 16), ``dt_bias`` and norm weights at 1; none of the three takes
+    weight decay.  The published norms are ``x_hat (1 + w)`` with w from 0: the same function and updates as
+    :func:`_rms_norm`'s ``x_hat w`` from 1, which is kept
+
 What differs from the CNN family, by design:
 
 - **Genes are data, not structure.**  Every individual is the same
@@ -98,7 +125,9 @@ What differs from the CNN family, by design:
   from the seed.
 
 Parameters are float32, compute is bfloat16 (router, norms, softmax, logits
-and loss float32).  The router bias ``b`` is not trained by the gradient:
+and loss float32; of a ``linear_attention`` layer also its gates, its
+convolution's arithmetic, the l2 norms, the state and every product of the
+delta rule's core).  The router bias ``b`` is not trained by the gradient:
 after each step ``b_e += u * sign(mean load - load_e)`` over all experts
 (arXiv:2408.15664; ``u`` is the ``bias_step`` gene).
 """
@@ -129,6 +158,10 @@ GENE_NAMES = ("log10_lr", "warmup_frac", "weight_decay", "beta2", "bias_step")
 #: The fifth gene by balance rule: the bias's step (arXiv:2408.15664) or the weight of the loss's balance term.
 _BALANCE_GENE = {"bias": "bias_step", "aux_loss": "aux_alpha"}
 ADAM_BETA1, ADAM_EPS, INIT_STD, ROUTE_EPS = 0.9, 1e-8, 0.02, 1e-6
+#: Under the root of a ``linear_attention`` layer's l2 norm of q and k; the largest decay rate ``exp(A_log)`` starts at.
+L2_EPS, DECAY_RATE_MAX = 1e-6, 16.0
+#: Leaves that are no matrix: they start from a value of their own (:func:`_init_leaf`) and take no weight decay.
+_UNDECAYED = ("norm", "A_log", "dt_bias")
 #: megablox tiles (rows, contraction, columns); the row tile shrinks to divide a small buffer
 #: (:func:`_gmm_tiling` follows the shape from here).
 _GMM_TILING = (512, 512, 512)
@@ -142,6 +175,11 @@ _GMM_TILING = (512, 512, 512)
 #: 512 / 512, 18.0-21.2 at four other shapes (PERF.md, PR 34).
 _ATTN_KERNEL_BLOCKS = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
                            block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512)
+#: The columns of a head of q (as wide as k's, zero columns counted) and of v together up to which the
+#: backward kernel holds those blocks in the chip's fast memory: 256 + 128 (latent attention) does, 256 + 256
+#: (a head size of 256) asks for 16.57 MB of the 16 it may take, and its query block halves
+#: (:func:`_kernel_blocks`).  The key block stays: the fused backward writes a partial dq a key block.
+_ATTN_KERNEL_COLUMNS = 384
 #: The row buffer's heights below the worst case (top-k x tokens, always the last rung), in
 #: shares: times the rows a routed layer sends this rank on average.  A layer-step runs at the
 #: first that holds its rows; dispatch, combine and the experts' masks cost their height.
@@ -201,6 +239,20 @@ class Lfm2MoeConfig:
     qk_norm: bool = True
     sliding_window: int = 0
     rope_parameters: Optional[Tuple[Tuple[str, Tuple[Tuple[str, Any], ...]], ...]] = None
+    # what a fourth architecture sets (Qwen3-Next): a ``linear_attention`` layer's heads (key heads, each serving
+    # value heads / key heads value heads), their sizes, its convolution's taps and the positions a chunk of its
+    # scan holds; the share of a head's columns that rope turns; a sigmoid gate on the attention's output (the q
+    # projection is twice as wide) and one on the shared expert (a scalar a token)
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    delta_chunk: int = 64
+    partial_rotary_factor: float = 1.0
+    attn_output_gate: bool = False
+    shared_expert_gate: bool = False
+
     def __post_init__(self):
         if not self.head_dim:  # frozen: the stated size takes the place of the implied one once, here
             object.__setattr__(self, "head_dim", self.hidden_size // self.num_attention_heads)
@@ -230,7 +282,12 @@ class Lfm2MoeConfig:
     def typed_attention(self) -> bool:
         """Whether attention layers are told apart by type (their scope is the type's name, with
         ``proj``, ``rope`` and ``core`` inside); LFM2's one kind keeps its one ``attention`` scope."""
-        return "sliding_attention" in self.layer_types
+        return bool({"sliding_attention", "linear_attention"} & set(self.layer_types))
+
+    @property
+    def rotary_dim(self) -> int:
+        """The leading columns of a head of q and k that rope turns (all of them at a factor of 1)."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def n_held(self) -> int:
@@ -261,8 +318,15 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
                                         cfg.qk_rope_head_dim, cfg.v_head_dim)
             layer["latent"] = {"q": (h, nh * (nope + rope)), "kva": (h, rank + rope), "kv_norm": (rank,),
                                "kvb": (rank, nh * (nope + vd)), "o": (nh * vd, h)}
+        elif kind == "linear_attention":
+            nv, keys, values = cfg.linear_num_value_heads, *_delta_widths(cfg)
+            layer["delta"] = {"qkvz": (h, 2 * keys + 2 * values), "ba": (h, 2 * nv),
+                              "kernel": (2 * keys + values, cfg.linear_conv_kernel_dim), "A_log": (nv,),
+                              "dt_bias": (nv,), "norm": (cfg.linear_value_head_dim,), "out": (values, h)}
         else:
-            layer["attn"] = {"q": (h, cfg.num_attention_heads * hd), "k": (h, cfg.num_key_value_heads * hd),
+            # with an output gate a head's columns of ``q`` are [its query | its gate], twice the head size
+            layer["attn"] = {"q": (h, cfg.num_attention_heads * hd * (2 if cfg.attn_output_gate else 1)),
+                             "k": (h, cfg.num_key_value_heads * hd),
                              "v": (h, cfg.num_key_value_heads * hd), "o": (cfg.num_attention_heads * hd, h)}
             if cfg.qk_norm:
                 layer["attn"].update(q_norm=(hd,), k_norm=(hd,))
@@ -275,6 +339,8 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
             if cfg.n_shared_experts:
                 fs = cfg.n_shared_experts * f
                 layer["moe"]["shared"] = {"w1": (h, fs), "w3": (h, fs), "w2": (fs, h)}
+                if cfg.shared_expert_gate:
+                    layer["moe"]["shared_gate"] = (h,)
         layers.append(layer)
     tree = {"embed": (cfg.vocab_size, h), "final_norm": (h,), "layers": layers}
     if not cfg.tie_word_embeddings:
@@ -286,30 +352,50 @@ def _is_shape(x) -> bool:
     return isinstance(x, tuple)
 
 
+def _delta_widths(cfg: Lfm2MoeConfig) -> Tuple[int, int]:
+    """Of a ``linear_attention`` layer: (the columns of q, which k has too; those of v, which z has too)."""
+    return (cfg.linear_num_key_heads * cfg.linear_key_head_dim, cfg.linear_num_value_heads * cfg.linear_value_head_dim)
+
+
 def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
     """Device bytes one individual's training takes, by arithmetic.
 
     ``state``: float32 weights, gradients and AdamW's two moments, 16 bytes a
     parameter.  ``activations``: what a step keeps beside them under per-layer
-    rematerialisation -- every layer's input, the widest layer's interior
-    (dense feed-forward, or the expert rows' buffer at its worst-case height:
-    the branch a program must have room for, whichever a call takes), the
-    float32 logits and their gradient.  An estimate to decide a width by, not a
-    measurement.
+    rematerialisation -- every layer's input and the larger of the two things
+    that are never alive together: the float32 logits with their gradient (the
+    head's backward pass, before any layer's), and the widest layer's interior
+    (dense feed-forward; the expert rows' buffer at its worst-case height: the
+    branch a program must have room for, whichever a call takes; a
+    ``linear_attention`` layer's: its in-projection in the compute dtype, the
+    float32 q, k, v and gates of every value head, the solved chunk systems,
+    and what the scan's backward pass keeps, a float32 state a head and chunk
+    and that chunk's corrected values -- each once forward and once as a
+    cotangent).  An estimate to decide a width by, not a measurement.
     """
     n_params = sum(math.prod(s) for s in jax.tree_util.tree_leaves(param_shapes(cfg), is_leaf=_is_shape))
     t, h = cfg.tokens_per_step, cfg.hidden_size
     interior = max(6 * t * cfg.intermediate_size if cfg.num_dense_layers else 0,
                    cfg.num_experts_per_tok * t * (4 * h + 6 * cfg.moe_intermediate_size)) * 2
-    activations = 2 * t * h * (len(cfg.layer_types) + 1) + interior + 2 * 4 * t * cfg.vocab_size
+    if "linear_attention" in cfg.layer_types:
+        keys, values = _delta_widths(cfg)
+        nv, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        in_proj = 2 * (2 * keys + 2 * values)  # a token, compute dtype
+        operands = 4 * nv * (2 * dk + dv + 2)  # float32 q, k, v, g and beta of every value head
+        systems = 4 * nv * (2 * (dk + dv) + 2 * cfg.delta_chunk)  # what a chunk wrote and its solution; the system's and q k' D's rows
+        states = 4 * nv * dk * dv * -(-t // cfg.delta_chunk)
+        interior = max(interior, 2 * (t * (in_proj + operands + systems) + states))
+    activations = 2 * t * h * (len(cfg.layer_types) + 1) + max(interior, 2 * 4 * t * cfg.vocab_size)
     return {"params": n_params, "state": 16 * n_params, "activations": activations,
             "total": 16 * n_params + activations}
 
 
 #: The operators a layer can have (``layer_types``).
-LAYER_KINDS = ("conv", "full_attention", "sliding_attention", "latent_attention")
-#: Those of them that are attention, whose causal core is :func:`_causal_core`'s.
-ATTENTION_KINDS = LAYER_KINDS[1:]
+LAYER_KINDS = ("conv", "linear_attention", "full_attention", "sliding_attention", "latent_attention")
+#: Those of them that are attention over keys under a mask, whose causal core is :func:`_causal_core`'s.
+ATTENTION_KINDS = ("full_attention", "sliding_attention", "latent_attention")
+#: The programs the delta rule's core has, as the spans and the counter name them (one today).
+LINEAR_CORE_PROGRAMS = ("chunked",)
 #: The masks the core has, as the spans and the counter name them.
 MASKS = ("causal", "window")
 
@@ -422,16 +508,18 @@ def yarn_amplitude(scaling: Mapping[str, Any]) -> float:
     return yarn_mscale(scaling["factor"], 1.0)
 
 
-def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]]):
+def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]], rotary: Optional[int] = None):
     """cos and sin of the positions of ``x`` (sequences, length, ..., head size), float32, one
-    column a rotated pair (half the head size) and shaped to broadcast against ``x``'s halves.
+    column a rotated pair (half the head size, or half of ``rotary``, the leading columns that
+    turn) and shaped to broadcast against ``x``'s halves.
     With ``scaling`` (YaRN) the frequencies are :func:`yarn_inv_freq`'s and both carry
     :func:`yarn_amplitude`."""
-    half = x.shape[-1] // 2
+    turning = x.shape[-1] if rotary is None else rotary
+    half = turning // 2
     if scaling is None:
         inv_freq, amplitude = theta ** (-jnp.arange(half, dtype=jnp.float32) / half), 1.0
     else:
-        inv_freq, amplitude = jnp.asarray(yarn_inv_freq(x.shape[-1], theta, scaling)), yarn_amplitude(scaling)
+        inv_freq, amplitude = jnp.asarray(yarn_inv_freq(turning, theta, scaling)), yarn_amplitude(scaling)
     angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
     per_position = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
     cos, sin = jnp.cos(angle).reshape(per_position), jnp.sin(angle).reshape(per_position)
@@ -451,7 +539,7 @@ def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None):
+def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None, rotary: Optional[int] = None):
     """:func:`_rope`'s function on whole heads without a slice or a concatenation:
     ``x * [cos | cos] + (x @ T) * [sin | sin]``, where ``T`` is the signed
     permutation that sends ``[x1 | x2]`` to ``[-x2 | x1]``.  Every product with
@@ -463,10 +551,18 @@ def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None):
     with ``T`` at its heart and the norm, cos and sin, the core's scale and the
     cast to the compute dtype around it, forward and backward (PERF.md, PR 36:
     8.56 GB a layer-step outside products and kernels at Mellum2's shape
-    against 3.56; 45.5 ms against 35.6)."""
-    cos, sin = (jnp.concatenate([table, table], axis=-1) for table in _rope_tables(x, theta, scaling))
-    half = x.shape[-1] // 2
-    turn = np.zeros((2 * half, 2 * half), np.float32)
+    against 3.56; 45.5 ms against 35.6).  With ``rotary`` under the head size
+    only the leading ``rotary`` columns turn (pairs ``(c, c + rotary / 2)``
+    inside them): the tables read cos 1 and sin 0 on the columns that pass, and
+    ``T`` has no entry for them -- the same one fusion."""
+    size = x.shape[-1]
+    turning = size if rotary is None else rotary
+    cos, sin = (jnp.concatenate([table, table], axis=-1) for table in _rope_tables(x, theta, scaling, rotary))
+    if turning < size:
+        passing = [(0, 0)] * (cos.ndim - 1) + [(0, size - turning)]
+        cos, sin = jnp.pad(cos, passing, constant_values=1.0), jnp.pad(sin, passing)
+    half = turning // 2
+    turn = np.zeros((size, size), np.float32)
     turn[np.arange(half) + half, np.arange(half)] = -1.0  # column j < half takes -x[half + j]
     turn[np.arange(half), np.arange(half) + half] = 1.0  # column half + j takes x[j]
     turned = jnp.einsum("...d,de->...e", x, jnp.asarray(turn, x.dtype), preferred_element_type=jnp.float32,
@@ -474,12 +570,23 @@ def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None):
     return x * cos + turned * sin
 
 
-def _kernel_blocks(length: int) -> Optional[Dict[str, int]]:
+def _kernel_blocks(length: int, columns: int = 0) -> Optional[Dict[str, int]]:
     """The fused kernel's blocks at this length, none larger than it, or None
     where the length is not a whole number of each (of 128 lanes at least): the
-    kernel has no ragged last block."""
-    blocks = {name: min(size, length) for name, size in _ATTN_KERNEL_BLOCKS.items()}
+    kernel has no ragged last block.  ``columns``: those of a head of q and of v
+    together; past ``_ATTN_KERNEL_COLUMNS`` the backward kernel's query block is
+    half as tall -- a rule by shape, as :func:`_gmm_tiling`'s."""
+    blocks = dict(_ATTN_KERNEL_BLOCKS)
+    if columns > _ATTN_KERNEL_COLUMNS:
+        blocks["block_q_dkv"] //= 2
+    blocks = {name: min(size, length) for name, size in blocks.items()}
     return blocks if all(length % size == 0 and size % 128 == 0 for size in blocks.values()) else None
+
+
+def _core_columns(qk: int, v: int) -> int:
+    """The columns the fused core holds a head: q's (k's the same; over 128 a whole number of 128 lanes,
+    :func:`_kernel_core` pads them) and v's."""
+    return (qk + (-qk % 128 if qk > 128 else 0)) + v
 
 
 def _use_attention_kernel(length: int) -> bool:
@@ -495,7 +602,7 @@ def _use_attention_kernel(length: int) -> bool:
     return True
 
 
-def _splash_kernel(length: int, group: int, window: Optional[int]):
+def _splash_kernel(length: int, group: int, window: Optional[int], columns: int = 0):
     """The fused kernel of one key-value head and its ``group`` query heads over
     ``length`` positions.  The mask is an object of the library: ``CausalMask``,
     or, with ``window``, ``LocalMask`` reaching ``window - 1`` keys back and none
@@ -510,19 +617,19 @@ def _splash_kernel(length: int, group: int, window: Optional[int]):
     mask = masks.CausalMask(shape) if window is None else masks.LocalMask(shape, window_size=(window - 1, 0), offset=0)
     return splash.make_splash_mqa_single_device(
         masks.MultiHeadMask([mask] * group),
-        block_sizes=splash.BlockSizes(**_kernel_blocks(length), use_fused_bwd_kernel=True))
+        block_sizes=splash.BlockSizes(**_kernel_blocks(length, columns), use_fused_bwd_kernel=True))
 
 
 @functools.lru_cache(maxsize=16)
-def _kernel_visits(length: int, window: Optional[int]) -> Dict[str, int]:
+def _kernel_visits(length: int, window: Optional[int], columns: int = 0) -> Dict[str, int]:
     """The block pairs the fused kernel visits for one head and sequence, read
     from the kernel's own table (:func:`_splash_kernel`), never from a formula
     beside it: ``pairs`` and their area ``elements`` of the forward kernel,
     ``pairs_bwd`` and ``elements_bwd`` of the backward one.  What a roofline's
     count of the executed work reads (the ``train`` span carries it)."""
     with jax.ensure_compile_time_eval():
-        kernel = _splash_kernel(length, 1, window)
-    blocks = _kernel_blocks(length)
+        kernel = _splash_kernel(length, 1, window, columns)
+    blocks = _kernel_blocks(length, columns)
     visited = lambda info: int(np.count_nonzero(np.asarray(info.block_mask)[0]))  # one table serves every head
     forward, backward = visited(kernel.fwd_mask_info), visited(kernel.dkv_mask_info)
     return {"pairs": forward, "elements": forward * blocks["block_q"] * blocks["block_kv"],
@@ -549,11 +656,12 @@ def _kernel_core(q, k, v, scale: float, window: Optional[int] = None):
     ``vmap`` makes the key-value heads and the sequences its outer grid.  With
     ``window`` a query sees that many keys, its own the last (:func:`_splash_kernel`)."""
     length, group = q.shape[1], q.shape[3]
+    columns = _core_columns(q.shape[-1], v.shape[-1])
     pad = -q.shape[-1] % 128 if q.shape[-1] > 128 else 0
     if pad:  # zero columns add nothing to a score: 192 as 256 took 13.9 ms against 15.4 (PERF.md, PR 32)
         q, k = (jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)]) for a in (q, k))
     q = (q.astype(jnp.float32) * scale).astype(k.dtype)
-    kernel = _splash_kernel(length, group, window)
+    kernel = _splash_kernel(length, group, window, columns)
     out = jax.vmap(jax.vmap(kernel))(q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     return out.transpose(0, 3, 1, 2, 4)
 
@@ -615,15 +723,22 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
     part = jax.named_scope if cfg.typed_attention else (lambda name: contextlib.nullcontext())
     theta, scaling = cfg.rope_of(kind)
     with part("proj"):
-        q = _head_major(x, p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, hd))
+        if cfg.attn_output_gate:  # a head's columns are [query | gate]: two products, as the latent operator's blocks
+            w_q = p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, 2 * hd)
+            q, gate = _head_major(x, w_q[..., :hd]), _head_major(x, w_q[..., hd:])
+        else:
+            q = _head_major(x, p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, hd))
         k = _head_major(x, p["k"].astype(dtype).reshape(hidden, nkv, hd))
         v = _head_major(x, p["v"].astype(dtype).reshape(hidden, nkv, hd))
     with part("rope"):
         normed = lambda a, weight: _rms_norm(a, p[weight], cfg.norm_eps) if cfg.qk_norm else a
-        q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling)
-        k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling).astype(dtype)
+        q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling, cfg.rotary_dim)
+        k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling, cfg.rotary_dim).astype(dtype)
     with part("core"):
         out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg, cfg.window_of(kind))
+    if cfg.attn_output_gate:
+        with part("gate"):
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
     with part("proj"):
         return jnp.einsum("slngd,ngdh->slh", out, p["o"].astype(dtype).reshape(nkv, nh // nkv, hd, hidden))
 
@@ -680,6 +795,121 @@ def _latent_attention(p, x, cfg: Lfm2MoeConfig, dtype):
     with jax.named_scope("out_proj"):
         w_o = p["o"].astype(dtype).reshape(nh, vd, hidden)
         return jnp.einsum("slnd,ndh->slh", out.reshape(s, length, nh, vd), w_o)
+
+
+_EXACT = dict(precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _delta_core(q, k, v, g, beta, chunk: int):
+    """The gated delta rule in chunks.  Per value head, from ``S_0 = 0``::
+
+        S' = exp(g_t) S_{t-1};   S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T;   o_t = S_t^T q_t
+
+    ``q``, ``k`` (sequences, length, key heads, key size), already normed and
+    scaled; ``v`` (sequences, length, key heads, value heads a key head, value
+    size); ``g <= 0`` and ``beta`` (sequences, length, key heads, value heads a
+    key head); all float32, and so is every product here (HIGHEST: the state
+    and what multiplies it never pass through the compute dtype).  Returns
+    ``o`` in ``v``'s shape.
+
+    Inside a chunk of ``chunk`` positions, with ``G_i`` the running sum of ``g``
+    from the chunk's start (so ``G`` falls) and ``D_ij = exp(G_i - G_j)`` for
+    ``j <= i``, the updates of the chunk are one unit lower-triangular system
+    (the WY / UT form): ``(I + tril(beta_i (k_i . k_j) D_ij, -1)) [U | W] =
+    [beta v | beta exp(G) k]`` -- ``U`` the values each position writes if the
+    chunk started from an empty state, ``W`` what it reads of the state it did
+    start from -- solved once for all chunks.  Between chunks a ``scan``
+    carries the state: ``V = U - W S``; ``o = (exp(G) q) S + tril((q . k) D) V``;
+    ``S <- exp(G_last) S + (exp(G_last - G) k)^T V``.  Every exponent is a
+    difference ``G_i - G_j`` with ``j <= i``, a ``G_i`` or ``G_last - G_i``:
+    none is positive, so nothing overflows however strong the decay (an
+    ``exp(-G)`` on its own would).  A length that is no whole number of chunks
+    is padded with positions that write nothing (``beta = 0``, ``g = 0``).
+
+    The backward pass is jax's transpose of the scan, its body rematerialised:
+    what it keeps is the state that entered each chunk (float32, key size x
+    value size a head) beside the scan's own operands; a chunk's ``V`` and its
+    decayed q and k are computed again when the chunk is differentiated.
+    """
+    s, length, n, dk = q.shape
+    r, dv = v.shape[3], v.shape[4]
+    pad = -length % chunk
+    if pad:
+        q, k, v, g, beta = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2)) for a in (q, k, v, g, beta))
+    chunks = (length + pad) // chunk
+    # (chunks, sequences, key heads, [value heads a key head,] positions, size): the chunk leads from here on, so
+    # that the scan takes its operands as they lie and XLA copies none of them into place
+    q, k = (a.reshape(s, chunks, chunk, n, dk).transpose(1, 0, 3, 2, 4) for a in (q, k))
+    v = v.reshape(s, chunks, chunk, n, r, dv).transpose(1, 0, 3, 4, 2, 5)
+    g, beta = (a.reshape(s, chunks, chunk, n, r).transpose(1, 0, 3, 4, 2) for a in (g, beta))
+    fall = jnp.cumsum(g, axis=-1)
+    at_or_before = jnp.tril(jnp.ones((chunk, chunk), bool))
+    apart = fall[..., :, None] - fall[..., None, :]
+    decay = jnp.where(at_or_before, jnp.exp(jnp.where(at_or_before, apart, 0.0)), 0.0)
+    kk = jnp.einsum("Nsnid,Nsnjd->Nsnij", k, k, **_EXACT)[:, :, :, None]
+    qk = jnp.einsum("Nsnid,Nsnjd->Nsnij", q, k, **_EXACT)[:, :, :, None] * decay
+    system = jnp.eye(chunk, dtype=jnp.float32) + jnp.tril(beta[..., None] * kk * decay, -1)
+    wrote = jnp.concatenate([beta[..., None] * v, (beta * jnp.exp(fall))[..., None] * k[:, :, :, None]], axis=-1)
+    solved = jax.lax.linalg.triangular_solve(system, wrote, left_side=True, lower=True, unit_diagonal=True)
+
+    @jax.checkpoint
+    def one_chunk(state, xs):
+        u_w, qk_i, q_i, k_i, fall_i = xs
+        q_fallen = jnp.exp(fall_i)[..., None] * q_i[:, :, None]
+        k_to_end = jnp.exp(fall_i[..., -1:] - fall_i)[..., None] * k_i[:, :, None]
+        values = u_w[..., :dv] - jnp.einsum("snrcd,snrde->snrce", u_w[..., dv:], state, **_EXACT)
+        out = jnp.einsum("snrcd,snrde->snrce", q_fallen, state, **_EXACT) \
+            + jnp.einsum("snrij,snrje->snrie", qk_i, values, **_EXACT)
+        state = jnp.exp(fall_i[..., -1])[..., None, None] * state \
+            + jnp.einsum("snrcd,snrce->snrde", k_to_end, values, **_EXACT)
+        return state, out
+
+    _, out = jax.lax.scan(one_chunk, jnp.zeros((s, n, r, dk, dv), jnp.float32), (solved, qk, q, k, fall))
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(s, chunks * chunk, n, r, dv)  # (chunks, s, n, r, positions, dv) back
+    return out[:, :length]
+
+
+def _unit_rows(a):
+    """``a`` over its l2 norm along the last axis (``a / sqrt(sum a^2 + L2_EPS)``), float32."""
+    return a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + L2_EPS)
+
+
+def _linear_attention(p, x, cfg: Lfm2MoeConfig, dtype):
+    """Gated DeltaNet on (sequences, length, hidden): one in-projection to
+    ``[q | k | v | z]`` (the key heads' q and k, the value heads' v and output
+    gate z, each a block of columns) and one to ``[b | a]`` (a write strength
+    and a decay a value head, float32 like a router's scores); a causal
+    depthwise convolution with SiLU over the q, k and v columns (float32
+    arithmetic on the compute dtype's product, zeros before position 0);
+    ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``; q and k
+    l2-normed a head, q over the root of the key size; the delta rule
+    (:func:`_delta_core`), each key head serving ``value heads / key heads``
+    value heads; ``RMSNorm(o) * silu(z)`` a head, one norm weight for all; the
+    out-projection.  Scopes: ``proj``, ``conv``, ``gates``, ``core``, ``norm_gate``."""
+    s, length, _ = x.shape
+    nk, nv, dk, dv = (cfg.linear_num_key_heads, cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                      cfg.linear_value_head_dim)
+    keys, values = _delta_widths(cfg)
+    with jax.named_scope("proj"):
+        qkvz = _dot(x, p["qkvz"], dtype)
+    with jax.named_scope("gates"):
+        ba = jnp.dot(x.astype(jnp.float32), p["ba"], precision=jax.lax.Precision.HIGHEST).reshape(s, length, 2, nk, nv // nk)
+        beta = jax.nn.sigmoid(ba[:, :, 0])
+        g = -jnp.exp(p["A_log"]).reshape(nk, -1) * jax.nn.softplus(ba[:, :, 1] + p["dt_bias"].reshape(nk, -1))
+    with jax.named_scope("conv"):
+        taps = cfg.linear_conv_kernel_dim
+        padded = jnp.pad(qkvz[..., :2 * keys + values], ((0, 0), (taps - 1, 0), (0, 0)))
+        mixed = jax.nn.silu(sum(p["kernel"][:, j] * padded[:, j:j + length].astype(jnp.float32) for j in range(taps)))
+    with jax.named_scope("core"):
+        q = _unit_rows(mixed[..., :keys].reshape(s, length, nk, dk)) * dk ** -0.5
+        k = _unit_rows(mixed[..., keys:2 * keys].reshape(s, length, nk, dk))
+        v = mixed[..., 2 * keys:].reshape(s, length, nk, nv // nk, dv)
+        out = _delta_core(q, k, v, g, beta, cfg.delta_chunk)
+    with jax.named_scope("norm_gate"):
+        z = qkvz[..., 2 * keys + values:].reshape(out.shape).astype(jnp.float32)
+        out = (_rms_norm(out, p["norm"], cfg.norm_eps) * jax.nn.silu(z)).astype(dtype)
+    with jax.named_scope("proj"):
+        return _dot(out.reshape(s, length, values), p["out"], dtype)
 
 
 def _dense_ffn(p, x, dtype):
@@ -869,27 +1099,37 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
         out, n_rows = by_count(heights, cfg, dtype)(rung, *operands)
     if "shared" in p:
         with jax.named_scope("moe"), jax.named_scope("shared"):
-            out = out + _dense_ffn(p["shared"], x, dtype)
+            shared = _dense_ffn(p["shared"], x, dtype)
+            if "shared_gate" in p:  # one sigmoid a token, float32 as the router's scores are
+                opened = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["shared_gate"],
+                                                precision=jax.lax.Precision.HIGHEST))
+                shared = (opened[:, None] * shared).astype(dtype)
+            out = out + shared
     taken = (jnp.arange(len(heights)) == rung).astype(jnp.int32)
     return out, load, RoutedStats(n_held_rows - n_rows, taken, balance)
 
 
-def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x, by_count=_expert_rows_by_count):
-    """One layer on (sequences, length, hidden); ``bias`` is the layer's router
-    bias or None; ``by_count`` is :func:`_moe_ffn`'s.  Returns the output and,
-    of a routed layer, (load, stats)."""
+def _mixer(cfg: Lfm2MoeConfig, index: int, dtype, p, x):
+    """The first half of a layer, ``x + Op(RMSNorm(x))``, on (sequences, length, hidden)."""
     kind, name = cfg.layer_types[index], f"layer{cfg.layer_ids[index]}"
     with jax.named_scope(name):
         normed = _rms_norm(x, p["op_norm"], cfg.norm_eps).astype(dtype)
         if kind == "conv":
             with jax.named_scope("conv_op"):
-                h = x + _conv_op(p["conv"], normed, cfg, dtype)
+                return x + _conv_op(p["conv"], normed, cfg, dtype)
         elif kind == "latent_attention":
             with jax.named_scope("latent_attention"):
-                h = x + _latent_attention(p["latent"], normed, cfg, dtype)
-        else:
-            with jax.named_scope(kind if cfg.typed_attention else "attention"):
-                h = x + _attention(p["attn"], normed, cfg, dtype, kind)
+                return x + _latent_attention(p["latent"], normed, cfg, dtype)
+        elif kind == "linear_attention":
+            with jax.named_scope("linear_attention"):
+                return x + _linear_attention(p["delta"], normed, cfg, dtype)
+        with jax.named_scope(kind if cfg.typed_attention else "attention"):
+            return x + _attention(p["attn"], normed, cfg, dtype, kind)
+
+
+def _ffn(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, h, by_count=_expert_rows_by_count):
+    """The second half, ``h + FFN(RMSNorm(h))``: the output and, of a routed layer, (load, stats)."""
+    with jax.named_scope(f"layer{cfg.layer_ids[index]}"):
         normed = _rms_norm(h, p["ffn_norm"], cfg.norm_eps).astype(dtype)
         if "dense" in p:
             with jax.named_scope("dense_ffn"):
@@ -897,6 +1137,13 @@ def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x, by_count=_expert_r
         out, load, stats = _moe_ffn(p["moe"], bias, normed.reshape(-1, normed.shape[-1]), cfg, dtype,
                                     sequences=normed.shape[0], by_count=by_count)
         return h + out.reshape(h.shape), (load, stats)
+
+
+def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x, by_count=_expert_rows_by_count):
+    """One layer on (sequences, length, hidden); ``bias`` is the layer's router
+    bias or None; ``by_count`` is :func:`_moe_ffn`'s.  Returns the output and,
+    of a routed layer, (load, stats)."""
+    return _ffn(cfg, index, dtype, p, bias, _mixer(cfg, index, dtype, p, x), by_count)
 
 
 def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
@@ -912,7 +1159,15 @@ def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
     for i, p in enumerate(params["layers"]):
         fn = functools.partial(_layer, cfg, i, dtype, by_count=by_count)
         moe = i >= cfg.num_dense_layers
-        x, aux = (jax.checkpoint(fn) if remat else fn)(p, bias[i - cfg.num_dense_layers] if moe else None, x)
+        layer_bias = bias[i - cfg.num_dense_layers] if moe else None
+        if remat and cfg.layer_types[i] == "linear_attention":
+            # the halves of this layer are rematerialised apart: what the scan's backward pass keeps (a state a
+            # chunk and head) would else lie beside the expert rows' buffer at its worst-case height while the
+            # feed-forward half is differentiated -- 2 GB past the chip at the published cut (TPU compiler)
+            h = jax.checkpoint(functools.partial(_mixer, cfg, i, dtype))(p, x)
+            x, aux = jax.checkpoint(functools.partial(_ffn, cfg, i, dtype, by_count=by_count))(p, layer_bias, h)
+        else:
+            x, aux = (jax.checkpoint(fn) if remat else fn)(p, layer_bias, x)
         if moe:
             loads.append(aux[0])
             use = jax.tree_util.tree_map(jnp.add, use, aux[1])
@@ -954,7 +1209,9 @@ class Lfm2MoePrograms(NamedTuple):
     ``kernel_layers_by_mask``: the same by the core's mask, ``(("causal", n), ("window", n))``, a mask the
     configuration has no layer of left out;
     ``kernel_visits``: per mask that runs as the kernel, the block pairs the kernel visits a head
-    and sequence (:func:`_kernel_visits`, as sorted items)."""
+    and sequence (:func:`_kernel_visits`, as sorted items).
+    ``linear_core_layers``: the ``linear_attention`` layers by the program their delta core runs as
+    (``LINEAR_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none."""
 
     config: Lfm2MoeConfig
     init: Any
@@ -963,6 +1220,20 @@ class Lfm2MoePrograms(NamedTuple):
     attention_kernel_layers: int
     kernel_layers_by_mask: Tuple[Tuple[str, int], ...] = ()
     kernel_visits: Tuple[Tuple[str, Tuple[Tuple[str, int], ...]], ...] = ()
+    linear_core_layers: Tuple[Tuple[str, int], ...] = ()
+
+
+def _init_leaf(name: str, key, index: int, shape):
+    """The start of the ``index``-th leaf, drawn from its own fold of ``key``: norm weights and
+    ``dt_bias`` 1; ``A_log`` the log of a decay rate uniform on (0.001, ``DECAY_RATE_MAX``) (the
+    ``qwen3_next`` model type's initialiser, whose rates start at 0); every matrix, the convolution
+    kernels and the embedding among them, normal with deviation ``INIT_STD``."""
+    if "norm" in name or "dt_bias" in name:
+        return jnp.ones(shape, jnp.float32)
+    key = jax.random.fold_in(key, index)
+    if "A_log" in name:
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, minval=1e-3, maxval=DECAY_RATE_MAX))
+    return INIT_STD * jax.random.normal(key, shape, jnp.float32)
 
 
 @functools.lru_cache(maxsize=8)
@@ -975,9 +1246,7 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
     def init(base_key, genome_hash):
         key = jax.random.fold_in(jax.random.fold_in(base_key, genome_hash[0]), genome_hash[1])
         leaves, tree = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=_is_shape)
-        params = [jnp.ones(shape, jnp.float32) if "norm" in str(path[-1])
-                  else INIT_STD * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
-                  for i, (path, shape) in enumerate(leaves)]
+        params = [_init_leaf(str(path[-1]), key, i, shape) for i, (path, shape) in enumerate(leaves)]
         params = jax.tree_util.tree_unflatten(tree, params)
         zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)
         state = {"params": params, "m": zeros(), "v": zeros(),
@@ -1007,7 +1276,7 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
             def adamw(path, p, m, v, g):
                 m = ADAM_BETA1 * m + (1.0 - ADAM_BETA1) * g
                 v = beta2 * v + (1.0 - beta2) * g * g
-                decay = 0.0 if "norm" in str(path[-1]) else weight_decay
+                decay = 0.0 if any(name in str(path[-1]) for name in _UNDECAYED) else weight_decay
                 return p - lr * ((m / c1) / (jnp.sqrt(v / c2) + ADAM_EPS) + decay * p), m, v
 
             updated = jax.tree_util.tree_map_with_path(adamw, state["params"], state["m"], state["v"], grads)
@@ -1041,9 +1310,14 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         engaged = _use_attention_kernel(cfg.seq_len)
         by_mask.append((mask, layers if engaged else 0))
         if engaged:
-            visits.append((mask, tuple(sorted(_kernel_visits(cfg.seq_len, window).items()))))
+            latent = "latent_attention" in cfg.layer_types  # a configuration's attention layers have one head shape
+            columns = _core_columns(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim) if latent \
+                else _core_columns(cfg.head_dim, cfg.head_dim)
+            visits.append((mask, tuple(sorted(_kernel_visits(cfg.seq_len, window, columns).items()))))
+    linear = cfg.layer_types.count("linear_attention")
     return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval),
-                           sum(n for _, n in by_mask), tuple(by_mask), tuple(visits))
+                           sum(n for _, n in by_mask), tuple(by_mask), tuple(visits),
+                           ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else ())
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -1075,6 +1349,17 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
         wanted = {"factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim", "original_max_position_embeddings"}
         if cfg.yarn is not None and not wanted <= set(cfg.yarn):
             raise ValueError(f"rope_scaling needs {sorted(wanted)} (YaRN); got {sorted(cfg.yarn)}")
+    if "linear_attention" in cfg.layer_types:
+        sizes = {k: getattr(cfg, k) for k in ("linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
+                                              "linear_value_head_dim", "linear_conv_kernel_dim", "delta_chunk")}
+        if min(sizes.values()) <= 0 or cfg.linear_num_value_heads % cfg.linear_num_key_heads:
+            raise ValueError(f"a linear_attention layer needs its heads (value heads a whole number to each key "
+                             f"head), their sizes, its taps and its chunk: {sizes}")
+    if not 0.0 < cfg.partial_rotary_factor <= 1.0 or (cfg.partial_rotary_factor < 1.0 and cfg.rotary_dim % 2):
+        raise ValueError(f"partial_rotary_factor {cfg.partial_rotary_factor} of head_dim {cfg.head_dim} must leave "
+                         f"an even number of columns to turn")
+    if cfg.shared_expert_gate and not cfg.n_shared_experts:
+        raise ValueError("shared_expert_gate needs a shared expert to gate")
     if "sliding_attention" in cfg.layer_types and cfg.sliding_window <= 0:
         raise ValueError(f"a sliding_attention layer needs its sliding_window; got {cfg.sliding_window}")
     for kind, block in cfg.rope_parameters or ():
@@ -1194,6 +1479,10 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     kernel_attrs = {f"attention_kernel_layer_steps_{mask}": n for mask, n in by_mask.items()}
     for mask, visits in programs.kernel_visits:  # static: what the mask's kernel visits a layer, head and sequence
         kernel_attrs.update({f"attention_kernel_{name}_{mask}": n for name, n in visits})
+    by_linear = {program: layers * cfg.train_steps for program, layers in programs.linear_core_layers}
+    kernel_attrs.update({f"linear_core_layer_steps_{program}": n for program, n in by_linear.items()})
+    if by_linear:
+        kernel_attrs["linear_core_chunk"] = cfg.delta_chunk
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)
@@ -1216,6 +1505,8 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
             _count_expert_rows(cfg, rows, int(dropped), wide, by_height)
             for mask, n in by_mask.items():
                 _get_registry().counter("attention_kernel_layer_steps_total", mask=mask).inc(n)
+            for program, n in by_linear.items():
+                _get_registry().counter("linear_core_layer_steps_total", program=program).inc(n)
             sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=wide,
                    row_buffer_heights=[list(pair) for pair in by_height])
             if balance is not None:  # the ``aux_loss`` rule: the term before its weight, a routed layer and step

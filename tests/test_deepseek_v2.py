@@ -497,7 +497,7 @@ def _pool(n=3):
 
 
 @pytest.mark.parametrize("bad,why", [
-    (dict(layer_types=("latent_attention", "linear_attention", "latent_attention")), "layer_types"),
+    (dict(layer_types=("latent_attention", "state_space", "latent_attention")), "layer_types"),
     (dict(kv_lora_rank=0), "rank and head sizes"),
     (dict(v_head_dim=0), "rank and head sizes"),
     (dict(qk_rope_head_dim=3), "even rope size"),
