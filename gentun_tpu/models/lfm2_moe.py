@@ -81,8 +81,10 @@ a sigmoid gate::
          serving value heads / key heads value heads;  per value head from S_0 = 0, float32:
              S' = exp(g_t) S_{t-1};   S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T;   o_t = S_t^T q_t
          o = RMSNorm(o; w_n) * silu(z) a head;  W_out.  The rule runs in chunks of ``delta_chunk`` positions
-         (:func:`_delta_core`): a chunk's updates are one unit lower-triangular system, solved once for all chunks,
-         and a ``scan`` carries the state from chunk to chunk; the same program on every backend
+         (:func:`_delta_core`): a chunk's updates are one unit lower-triangular system, solved once for all chunks;
+         a chunk then maps the state that enters it to ``A S + B``, with ``A``, ``B`` and the outputs' operands
+         batched products over all chunks, and a ``scan`` carries the state and nothing else from chunk to chunk
+         (one product a step, forward and backward: :func:`_affine_scan`); the same program on every backend
     Op = full_attention (gated):  [q ; gate] = W_q x, a head's columns [its query | its gate];  k, v as above;
          RMSNorm on q and k;  rope on the leading ``partial_rotary_factor`` of a head's columns (rotate-half inside
          them), the others pass;  the causal core;  W_o (o * sigmoid(gate))
@@ -367,11 +369,16 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
     head's backward pass, before any layer's), and the widest layer's interior
     (dense feed-forward; the expert rows' buffer at its worst-case height: the
     branch a program must have room for, whichever a call takes; a
-    ``linear_attention`` layer's: its in-projection in the compute dtype, the
-    float32 q, k, v and gates of every value head, the solved chunk systems,
-    and what the scan's backward pass keeps, a float32 state a head and chunk
-    and that chunk's corrected values -- each once forward and once as a
-    cotangent).  An estimate to decide a width by, not a measurement.
+    ``linear_attention`` layer's, at the widest point of its backward pass, the
+    delta core's scan rule (:func:`_affine_scan`): what the forward pass keeps
+    -- the in-projection in the compute dtype, the float32 q, k, v and gates of
+    every value head, what each chunk wrote and its solved system ``[U | W]``,
+    the system's and ``P``'s rows, ``K`` and ``exp(G) q - P W`` a position, the
+    state that entered each chunk and ``A`` a head and chunk (``B`` and ``P U``
+    are used up where they are made) -- and, beside it, the three stacked
+    cotangents of that rule: what the outputs sent to each state, the ``dS`` it
+    emits, which is ``dB``, and ``dA``).  An estimate to decide a width by, not
+    a measurement.
     """
     n_params = sum(math.prod(s) for s in jax.tree_util.tree_leaves(param_shapes(cfg), is_leaf=_is_shape))
     t, h = cfg.tokens_per_step, cfg.hidden_size
@@ -383,8 +390,11 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
         in_proj = 2 * (2 * keys + 2 * values)  # a token, compute dtype
         operands = 4 * nv * (2 * dk + dv + 2)  # float32 q, k, v, g and beta of every value head
         systems = 4 * nv * (2 * (dk + dv) + 2 * cfg.delta_chunk)  # what a chunk wrote and its solution; the system's and q k' D's rows
-        states = 4 * nv * dk * dv * -(-t // cfg.delta_chunk)
-        interior = max(interior, 2 * (t * (in_proj + operands + systems) + states))
+        passes = 4 * nv * 2 * dk  # K and exp(G) q - P W: the batched products' operands that are no operand of the rule itself
+        chunks = -(-t // cfg.delta_chunk)
+        stacks = 4 * nv * dk * (dv + dk) * chunks  # the state that entered each chunk, and A
+        sent = 4 * nv * dk * (2 * dv + dk) * chunks  # the cotangents of the entering states, of the states left (dB) and of A
+        interior = max(interior, t * (in_proj + operands + systems + passes) + stacks + sent)
     activations = 2 * t * h * (len(cfg.layer_types) + 1) + max(interior, 2 * 4 * t * cfg.vocab_size)
     return {"params": n_params, "state": 16 * n_params, "activations": activations,
             "total": 16 * n_params + activations}
@@ -396,6 +406,9 @@ LAYER_KINDS = ("conv", "linear_attention", "full_attention", "sliding_attention"
 ATTENTION_KINDS = ("full_attention", "sliding_attention", "latent_attention")
 #: The programs the delta rule's core has, as the spans and the counter name them (one today).
 LINEAR_CORE_PROGRAMS = ("chunked",)
+#: The products a chunk step of :func:`_delta_core`'s scan runs in sequence on the state, forward and backward
+#: alike (the ``train`` spans' ``linear_core_chain_products``): everything else of the rule is batched over all chunks.
+LINEAR_CORE_CHAIN_PRODUCTS = 1
 #: The masks the core has, as the spans and the counter name them.
 MASKS = ("causal", "window")
 
@@ -800,6 +813,39 @@ def _latent_attention(p, x, cfg: Lfm2MoeConfig, dtype):
 _EXACT = dict(precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
 
 
+@jax.custom_vjp
+def _affine_scan(a, b):
+    """The state that *entered* each step of ``S_i = a_i S_{i-1} + b_i`` from an
+    empty one: ``a`` (steps, ..., n, n), ``b`` and the result (steps, ..., n, m),
+    float32.  One product and one add a step, forward and backward: the reverse
+    scan carries ``dS_{i-1} = a_i^T dS_i + (what was sent to the state that
+    entered step i)`` and stacks ``dS``, which is ``db``; ``da_i = dS_i
+    S_{i-1}^T`` is one batched product after it.  Kept for the backward pass:
+    ``a`` and the stacked states, which are the result itself."""
+    def step(state, a_b):
+        return jnp.einsum("...de,...ef->...df", a_b[0], state, **_EXACT) + a_b[1], state
+
+    return jax.lax.scan(step, jnp.zeros_like(b[0]), (a, b))[1]
+
+
+def _affine_scan_fwd(a, b):
+    entered = _affine_scan(a, b)
+    return entered, (a, entered)
+
+
+def _affine_scan_bwd(kept, sent):
+    a, entered = kept
+
+    def step(left, a_sent):  # ``left``: the cotangent of the state that left this step
+        return jnp.einsum("...de,...df->...ef", a_sent[0], left, **_EXACT) + a_sent[1], left
+
+    left = jax.lax.scan(step, jnp.zeros_like(sent[0]), (a, sent), reverse=True)[1]
+    return jnp.einsum("...df,...ef->...de", left, entered, **_EXACT), left
+
+
+_affine_scan.defvjp(_affine_scan_fwd, _affine_scan_bwd)
+
+
 def _delta_core(q, k, v, g, beta, chunk: int):
     """The gated delta rule in chunks.  Per value head, from ``S_0 = 0``::
 
@@ -818,18 +864,28 @@ def _delta_core(q, k, v, g, beta, chunk: int):
     (the WY / UT form): ``(I + tril(beta_i (k_i . k_j) D_ij, -1)) [U | W] =
     [beta v | beta exp(G) k]`` -- ``U`` the values each position writes if the
     chunk started from an empty state, ``W`` what it reads of the state it did
-    start from -- solved once for all chunks.  Between chunks a ``scan``
-    carries the state: ``V = U - W S``; ``o = (exp(G) q) S + tril((q . k) D) V``;
-    ``S <- exp(G_last) S + (exp(G_last - G) k)^T V``.  Every exponent is a
-    difference ``G_i - G_j`` with ``j <= i``, a ``G_i`` or ``G_last - G_i``:
-    none is positive, so nothing overflows however strong the decay (an
-    ``exp(-G)`` on its own would).  A length that is no whole number of chunks
-    is padded with positions that write nothing (``beta = 0``, ``g = 0``).
+    start from -- solved once for all chunks.  With ``K = exp(G_last - G) k``
+    and ``P = tril((q . k) D)`` a chunk maps the state ``S`` that enters it to::
 
-    The backward pass is jax's transpose of the scan, its body rematerialised:
-    what it keeps is the state that entered each chunk (float32, key size x
-    value size a head) beside the scan's own operands; a chunk's ``V`` and its
-    decayed q and k are computed again when the chunk is differentiated.
+        S <- exp(G_last) S + K^T (U - W S)  =  A S + B,   A = exp(G_last) I - K^T W,   B = K^T U
+        o  = exp(G) q S + P (U - W S)       =  (exp(G) q - P W) S + P U
+
+    ``A`` and ``B`` (key size x key size and key size x value size a head and
+    chunk), ``exp(G) q - P W`` and ``P U`` need no state: they are batched
+    products over all chunks, each written once.  Only ``S <- A S + B`` runs in
+    sequence (:func:`_affine_scan`: ``LINEAR_CORE_CHAIN_PRODUCTS`` product a
+    step, no ``exp``), and the outputs are one more batched product with the
+    stacked states.  Every exponent is a difference ``G_i - G_j`` with ``j <=
+    i``, a ``G_i`` or ``G_last - G_i``: none is positive, so nothing overflows
+    however strong the decay (an ``exp(-G)`` on its own would).  A length that
+    is no whole number of chunks is padded with positions that write nothing
+    (``beta = 0``, ``g = 0``).
+
+    The backward pass has the same shape: jax differentiates the batched
+    passes as they are, and the scan's own rule carries one cotangent of the
+    state back through the chunks.  It keeps the state that entered each chunk
+    (float32, key size x value size a head) and ``A`` beside the batched
+    passes' operands (``[U | W]``, ``K``, ``exp(G) q - P W``, ``P``).
     """
     s, length, n, dk = q.shape
     r, dv = v.shape[3], v.shape[4]
@@ -851,20 +907,16 @@ def _delta_core(q, k, v, g, beta, chunk: int):
     system = jnp.eye(chunk, dtype=jnp.float32) + jnp.tril(beta[..., None] * kk * decay, -1)
     wrote = jnp.concatenate([beta[..., None] * v, (beta * jnp.exp(fall))[..., None] * k[:, :, :, None]], axis=-1)
     solved = jax.lax.linalg.triangular_solve(system, wrote, left_side=True, lower=True, unit_diagonal=True)
-
-    @jax.checkpoint
-    def one_chunk(state, xs):
-        u_w, qk_i, q_i, k_i, fall_i = xs
-        q_fallen = jnp.exp(fall_i)[..., None] * q_i[:, :, None]
-        k_to_end = jnp.exp(fall_i[..., -1:] - fall_i)[..., None] * k_i[:, :, None]
-        values = u_w[..., :dv] - jnp.einsum("snrcd,snrde->snrce", u_w[..., dv:], state, **_EXACT)
-        out = jnp.einsum("snrcd,snrde->snrce", q_fallen, state, **_EXACT) \
-            + jnp.einsum("snrij,snrje->snrie", qk_i, values, **_EXACT)
-        state = jnp.exp(fall_i[..., -1])[..., None, None] * state \
-            + jnp.einsum("snrcd,snrce->snrde", k_to_end, values, **_EXACT)
-        return state, out
-
-    _, out = jax.lax.scan(one_chunk, jnp.zeros((s, n, r, dk, dv), jnp.float32), (solved, qk, q, k, fall))
+    values, reads = solved[..., :dv], solved[..., dv:]  # U and W
+    # what needs no state, for all chunks at once: A, B and the outputs' two operands, each product written once
+    k_to_end = jnp.exp(fall[..., -1:] - fall)[..., None] * k[:, :, :, None]
+    kept = jnp.exp(fall[..., -1])[..., None, None] * jnp.eye(dk, dtype=jnp.float32) \
+        - jnp.einsum("Nsnrcd,Nsnrce->Nsnrde", k_to_end, reads, **_EXACT)
+    added = jnp.einsum("Nsnrcd,Nsnrce->Nsnrde", k_to_end, values, **_EXACT)
+    q_seen = jnp.exp(fall)[..., None] * q[:, :, :, None] - jnp.einsum("Nsnrij,Nsnrjd->Nsnrid", qk, reads, **_EXACT)
+    entered = _affine_scan(kept, added)
+    out = jnp.einsum("Nsnrcd,Nsnrde->Nsnrce", q_seen, entered, **_EXACT) \
+        + jnp.einsum("Nsnrij,Nsnrje->Nsnrie", qk, values, **_EXACT)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(s, chunks * chunk, n, r, dv)  # (chunks, s, n, r, positions, dv) back
     return out[:, :length]
 
@@ -1483,6 +1535,7 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     kernel_attrs.update({f"linear_core_layer_steps_{program}": n for program, n in by_linear.items()})
     if by_linear:
         kernel_attrs["linear_core_chunk"] = cfg.delta_chunk
+        kernel_attrs["linear_core_chain_products"] = LINEAR_CORE_CHAIN_PRODUCTS
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)

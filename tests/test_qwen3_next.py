@@ -314,6 +314,95 @@ def test_the_state_crosses_chunk_boundaries():
     assert float(jnp.abs(moved - base)[:, 8:].max()) > 1e-3 and float(jnp.abs(moved - base)[:, :3].max()) == 0
 
 
+# -- the delta rule's scan: the state's own recurrence and nothing else ---------------------------------------------
+
+
+@pytest.mark.parametrize("steps,lead,n,m", [(5, (2,), 4, 6), (3, (2, 1, 2), 8, 5)])
+def test_the_affine_scans_rule_is_jax_grad_of_a_loop_over_the_steps(steps, lead, n, m):
+    """A cotangent on every emitted state, not only the last: ``da`` and ``db`` against a plain Python loop."""
+    rng = np.random.default_rng([steps, n, m])
+    a = jnp.asarray(0.5 * rng.normal(size=(steps, *lead, n, n)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(steps, *lead, n, m)), jnp.float32)
+    probe = jnp.asarray(rng.normal(size=b.shape), jnp.float32)
+
+    def loop(a, b):
+        state, entered = jnp.zeros_like(b[0]), []
+        for a_i, b_i in zip(a, b):
+            entered.append(state)
+            state = jnp.matmul(a_i, state) + b_i
+        return jnp.stack(entered)
+
+    with HIGHEST:
+        np.testing.assert_allclose(M._affine_scan(a, b), loop(a, b), rtol=1e-5, atol=1e-5)
+        got = jax.jit(jax.grad(lambda a, b: jnp.sum(M._affine_scan(a, b) * probe), argnums=(0, 1)))(a, b)
+        want = jax.grad(lambda a, b: jnp.sum(loop(a, b) * probe), argnums=(0, 1))(a, b)
+    for name, x, y in zip(("da", "db"), got, want):
+        assert float(jnp.abs(y[:-1]).max()) > 1e-2 and float(jnp.abs(x[-1]).max()) == 0, name  # nothing reads the last step
+        np.testing.assert_allclose(x, y, rtol=1e-4, atol=1e-5 * float(jnp.abs(y).max()), err_msg=name)
+
+
+def _scan_bodies(jaxpr):
+    """The primitives of every ``scan``'s body in ``jaxpr``, nested calls' included: one list a scan."""
+    def names(jaxpr):
+        return [name for eqn in jaxpr.eqns
+                for name in [eqn.primitive.name] + [n for sub in jax.core.jaxprs_in_params(eqn.params) for n in names(sub)]]
+
+    found = []
+    for eqn in jaxpr.eqns:
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if eqn.primitive.name == "scan":
+            found.extend(names(sub) for sub in subs)
+        else:
+            found.extend(body for sub in subs for body in _scan_bodies(sub))
+    return found
+
+
+@pytest.mark.parametrize("traced,scans", [("forward", 1), ("gradient", 2)])
+def test_a_chunk_step_holds_the_chain_products_and_no_exp(traced, scans):
+    """Work that slides back into the loop (an output product, a gate's ``exp``) fails here."""
+    args = _delta_case(41, 5, strong=False)
+    core = lambda *a: M._delta_core(*a, 16)
+    fn = core if traced == "forward" else jax.grad(lambda *a: jnp.sum(core(*a) ** 2), argnums=(0, 1, 2, 3, 4))
+    bodies = _scan_bodies(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert len(bodies) == scans
+    for body in bodies:
+        assert body.count("dot_general") == M.LINEAR_CORE_CHAIN_PRODUCTS, body
+        assert "exp" not in body and "exp2" not in body and not any("checkpoint" in name or "remat" in name for name in body), body
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["mild-decays", "decays-exp-minus-sum-cannot-hold"])
+def test_the_batched_passes_hand_the_scan_finite_operands_and_its_states_are_the_recurrences(strong, monkeypatch):
+    """``A``, ``B`` and the stacked states under decays whose ``exp(-G)`` float32 cannot hold; the state that
+    enters chunk ``i`` is the recurrence's after ``16 i`` positions; every gradient stays finite."""
+    chunk, length = 16, 41
+    q, k, v, g, beta = args = _delta_case(length, 7, strong)
+    handed = {}
+    scan = M._affine_scan
+
+    def watched(a, b):
+        handed["a"], handed["b"], handed["entered"] = a, b, scan(a, b)
+        return handed["entered"]
+
+    monkeypatch.setattr(M, "_affine_scan", watched)
+    with HIGHEST:
+        M._delta_core(*args, chunk)
+    monkeypatch.undo()
+    a, b, entered = (np.asarray(handed[name]) for name in ("a", "b", "entered"))
+    assert a.shape == (3, 2, 2, 2, 8, 8) and b.shape == entered.shape == (3, 2, 2, 2, 8, 12)
+    assert np.isfinite(a).all() and np.isfinite(b).all() and np.abs(b).max() > 1e-3
+    state = np.zeros((2, 2, 2, 8, 12))  # (sequences, key heads, value heads a key head, key size, value size), float64
+    q, k, v, g, beta = (np.asarray(x, np.float64) for x in args)
+    for t in range(2 * chunk + 1):
+        if t % chunk == 0:
+            np.testing.assert_allclose(entered[t // chunk], state, atol=2e-5 * max(np.abs(state).max(), 1e-2))
+        state = np.exp(g[:, t])[..., None, None] * state
+        wrote = beta[:, t][..., None] * (v[:, t] - np.einsum("snrde,snd->snre", state, k[:, t]))
+        state = state + np.einsum("snd,snre->snrde", k[:, t], wrote)
+    with HIGHEST:
+        grads = jax.grad(lambda *x: jnp.sum(M._delta_core(*x, chunk) ** 2), argnums=(0, 1, 2, 3, 4))(*args)
+    assert all(np.isfinite(np.asarray(d)).all() for d in grads)
+
+
 # -- partial rope, the blocks by shape ------------------------------------------------------------------------------
 
 
@@ -420,11 +509,13 @@ def test_each_layer_type_has_its_own_scope_with_its_parts_inside(tokens):
     assert {classify(s)[0] for s in scopes} <= set(scope_rules.CLASSES)
 
 
-def test_spans_and_the_labelled_counter_say_which_program_the_delta_core_ran_as(tokens):
+@pytest.fixture(scope="module")
+def two_traced_individuals(tokens):
+    """Two individuals of two delta layers and a full one scored with telemetry on: (programs, the ``train``
+    spans' attributes, the labelled counter's value, the fitnesses)."""
     x, y = tokens
     kw = model_kwargs({**MODEL, "num_hidden_layers": 3, "layer_types": PERIOD[1:]}, cache_dir=False)
     programs = M.Lfm2MoeModel.compiled_programs(x, **kw)
-    assert programs.linear_core_layers == (("chunked", 2),) and programs.kernel_layers_by_mask == (("causal", 0),)
 
     class Sink:
         def __init__(self):
@@ -442,17 +533,30 @@ def test_spans_and_the_labelled_counter_say_which_program_the_delta_core_ran_as(
     finally:
         spans.disable()
         spans.set_run_sink(None)
-    assert np.isfinite(fitness).all() and fitness[0] == fitness[1]
     trained = [r["attrs"] for r in sink.records if r["type"] == "span" and (r.get("attrs") or {}).get("steps") == 3]
+    return programs, trained, get_registry().counter("linear_core_layer_steps_total", program="chunked").value, fitness
+
+
+def test_spans_and_the_labelled_counter_say_which_program_the_delta_core_ran_as(two_traced_individuals):
+    programs, trained, counted, fitness = two_traced_individuals
+    assert programs.linear_core_layers == (("chunked", 2),) and programs.kernel_layers_by_mask == (("causal", 0),)
+    assert np.isfinite(fitness).all() and fitness[0] == fitness[1]
     assert [a["linear_core_layer_steps_chunked"] for a in trained] == [6, 6] and trained[0]["linear_core_chunk"] == 8
     assert trained[0]["attention_kernel_layer_steps_causal"] == 0
-    assert get_registry().counter("linear_core_layer_steps_total", program="chunked").value == 12
+    assert counted == 12
     lfm2 = M.Lfm2MoeModel.compiled_programs(np.zeros((6, 16), np.int32), hidden_size=32, layer_types=("conv", "full_attention"),
                                             num_dense_layers=1, intermediate_size=48, moe_intermediate_size=24,
                                             num_experts=8, num_experts_per_tok=2, held_experts=(0, 2), num_attention_heads=4,
                                             num_key_value_heads=2, vocab_size=64, batch_sequences=2, eval_sequences=2,
                                             attn_block=8, compute_dtype="float32")
     assert lfm2.linear_core_layers == ()
+
+
+def test_the_train_span_says_how_many_products_a_chunk_step_runs_in_sequence(two_traced_individuals):
+    """``linear_core_chain_products`` beside ``linear_core_chunk``: a trace says which form of the core ran."""
+    _, trained, _, _ = two_traced_individuals
+    assert [(a["linear_core_chunk"], a["linear_core_chain_products"]) for a in trained] == [(8, 1)] * 2
+    assert M.LINEAR_CORE_PROGRAMS == ("chunked",)
 
 
 # -- the benchmark's family: configuration file, counts, readers ----------------------------------------------------
