@@ -1,10 +1,11 @@
 """Routed language models as fitness models: one expert-parallel rank's share, trained under a recipe genome.
 
-The second jax family beside the Genetic-CNN (``models/cnn.py``), and four
+The second jax family beside the Genetic-CNN (``models/cnn.py``), and five
 architectures of it, told apart by the configuration alone (which operator a
-layer has, which mask and which rope an attention layer's type gives it, how
-the router scores, whether shared experts stand beside the routed ones and
-behind a gate, whether the head is tied, which balance rule runs): one
+layer has, which mask, which rope on how many of a head's columns and how many
+query heads an attention layer's type gives it, whether its output passes a
+gate, how the router scores, whether shared experts stand beside the routed
+ones and behind a gate, whether the head is tied, which balance rule runs): one
 evaluator, one train step builder, one expert layer, one causal core and one
 optimizer serve all.
 
@@ -94,6 +95,29 @@ a sigmoid gate::
     ``A_log`` starts at ln u, u uniform on (0, 16), ``dt_bias`` and norm weights at 1; none of the three takes
     weight decay.  The published norms are ``x_hat (1 + w)`` with w from 0: the same function and updates as
     :func:`_rms_norm`'s ``x_hat w`` from 1, which is kept
+
+``Laguna-XS.2`` (``model_type`` ``laguna``,
+https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json): ``full_attention``
+and ``sliding_attention`` 1:3, and the layer's type picks its mask, its rope, the
+share of a head that rope turns AND its query heads (``num_attention_heads_per_layer``:
+48 in a full layer, 64 in a sliding one, 8 key-value heads in both); every head's
+output passes a gate of one scalar a head and token; the leading layer's feed-forward
+is dense, the others route 8 of 256 experts by LFM2's rule, scale the routed sum and
+add one shared expert::
+
+    Op = sliding_attention:  Mellum2's, at ``n_l`` query heads (``n_l / kv_heads`` to a key-value head);
+         rope on every column at theta^(-2c/head)
+    Op = full_attention:     key j visible iff j <= i; rope on the leading ``partial_rotary_factor`` of a head's
+         columns (``rope_parameters.full_attention``; rotate-half inside them, YaRN's frequencies over those
+         columns, cos and sin times ``attention_factor``), the others pass
+    both:  o_head = o_head * sigmoid(w_head . x)   ``x`` the layer's normed input, ``W_g`` (hidden, n_l) a leaf of
+         its own (``gate``), the product and the sigmoid float32;  W_o.  The fused kernel is built a key-value
+         head with its group of query heads, so one program holds it at two groups under two masks
+    FFN dense (layer 0):  W_2 (silu(W_1 x) * W_3 x)
+    FFN routed:  LFM2's rule (a sigmoid each, top-k of s + b, w = s[chosen] / (sum + 1e-6)) over 256 experts;
+                 out = ``routed_scaling_factor`` * sum over chosen AND held e of w_e W2_e (silu(W1_e x) * W3_e x)
+                     + W2_s (silu(W1_s x) * W3_s x)          the shared expert, unscaled, whole on every rank
+    loss = cross-entropy (head untied); the bias ``b`` steps outside the gradient as LFM2's does
 
 What differs from the CNN family, by design:
 
@@ -254,6 +278,14 @@ class Lfm2MoeConfig:
     partial_rotary_factor: float = 1.0
     attn_output_gate: bool = False
     shared_expert_gate: bool = False
+    # what a fifth architecture sets (Laguna-XS.2): query heads by layer (one count a layer of ``layer_types``, or
+    # None: ``num_attention_heads`` in each; the key-value heads are one count); the share of a head that rope turns
+    # by layer type (a ``partial_rotary_factor`` inside a ``rope_parameters`` block, :meth:`rotary_of`); a sigmoid
+    # gate of one scalar a head and token on the attention's output, from a projection of its own (``gate``: hidden
+    # x heads); the factor on the routed experts' sum (the shared experts' output is added unscaled)
+    num_attention_heads_per_layer: Optional[Tuple[int, ...]] = None
+    attn_head_gate: bool = False
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
         if not self.head_dim:  # frozen: the stated size takes the place of the implied one once, here
@@ -291,6 +323,17 @@ class Lfm2MoeConfig:
         """The leading columns of a head of q and k that rope turns (all of them at a factor of 1)."""
         return int(self.head_dim * self.partial_rotary_factor)
 
+    def rotary_of(self, kind: str) -> int:
+        """The leading columns of a head that the rope of a ``kind`` layer turns: the share its
+        ``rope_parameters`` block states (``partial_rotary_factor``), else the configuration's one share."""
+        share = dict(dict(self.rope_parameters or ()).get(kind, ())).get("partial_rotary_factor")
+        return self.rotary_dim if share is None else int(self.head_dim * share)
+
+    def heads_of(self, index: int) -> int:
+        """The query heads of the ``index``-th layer kept (its attention's; every layer's where one count serves all)."""
+        per_layer = self.num_attention_heads_per_layer
+        return self.num_attention_heads if per_layer is None else per_layer[index]
+
     @property
     def n_held(self) -> int:
         return self.held_experts[1] - self.held_experts[0]
@@ -327,11 +370,14 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
                               "dt_bias": (nv,), "norm": (cfg.linear_value_head_dim,), "out": (values, h)}
         else:
             # with an output gate a head's columns of ``q`` are [its query | its gate], twice the head size
-            layer["attn"] = {"q": (h, cfg.num_attention_heads * hd * (2 if cfg.attn_output_gate else 1)),
+            nh = cfg.heads_of(i)
+            layer["attn"] = {"q": (h, nh * hd * (2 if cfg.attn_output_gate else 1)),
                              "k": (h, cfg.num_key_value_heads * hd),
-                             "v": (h, cfg.num_key_value_heads * hd), "o": (cfg.num_attention_heads * hd, h)}
+                             "v": (h, cfg.num_key_value_heads * hd), "o": (nh * hd, h)}
             if cfg.qk_norm:
                 layer["attn"].update(q_norm=(hd,), k_norm=(hd,))
+            if cfg.attn_head_gate:  # one scalar a head and token: a projection of its own
+                layer["attn"]["gate"] = (h, nh)
         if i < cfg.num_dense_layers:
             f = cfg.intermediate_size
             layer["dense"] = {"w1": (h, f), "w3": (h, f), "w2": (f, h)}
@@ -363,7 +409,10 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
     """Device bytes one individual's training takes, by arithmetic.
 
     ``state``: float32 weights, gradients and AdamW's two moments, 16 bytes a
-    parameter.  ``activations``: what a step keeps beside them under per-layer
+    parameter, counted off :func:`param_shapes` -- so every attention layer at
+    its own query heads, its head gates with it (a configuration whose layer
+    types differ in their heads: 48 and 64 of 128 columns over 2,048 channels
+    are 29.5 M and 37.9 M parameters a layer).  ``activations``: what a step keeps beside them under per-layer
     rematerialisation -- every layer's input and the larger of the two things
     that are never alive together: the float32 logits with their gradient (the
     head's backward pass, before any layer's), and the widest layer's interior
@@ -720,9 +769,14 @@ def _causal_core(q, k, v, scale: float, cfg: Lfm2MoeConfig, window: Optional[int
 
 def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
     """Causal GQA on (sequences, length, hidden); the core is :func:`_causal_core`'s.
-    The layer's type ``kind`` decides its mask (:meth:`Lfm2MoeConfig.window_of`)
-    and its rope (:meth:`Lfm2MoeConfig.rope_of`); where the configuration tells
-    attention layers apart by type, ``proj``, ``rope`` and ``core`` are scopes.
+    The layer's type ``kind`` decides its mask (:meth:`Lfm2MoeConfig.window_of`),
+    its rope (:meth:`Lfm2MoeConfig.rope_of`) and the columns of a head that rope
+    turns (:meth:`Lfm2MoeConfig.rotary_of`); the layer's query heads are what
+    its output projection holds (``o``: heads x head size rows, as
+    :func:`param_shapes` gave it from :meth:`Lfm2MoeConfig.heads_of`), each
+    key-value head serving ``heads / kv heads`` of them; where the
+    configuration tells attention layers apart by type, ``proj``, ``rope``,
+    ``core`` and ``gate`` are scopes.
 
     Each operand of the fused core is written once, in the order the kernel
     reads and in the compute dtype: the q, k and v products emit head-major
@@ -732,9 +786,10 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
     arithmetic inside one fusion an operand; the output product contracts the
     kernel's head-major output as it is."""
     hidden = x.shape[-1]
-    nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    nkv, hd = cfg.num_key_value_heads, cfg.head_dim
+    nh = p["o"].shape[0] // hd  # a matter of the layer: its parameters say
     part = jax.named_scope if cfg.typed_attention else (lambda name: contextlib.nullcontext())
-    theta, scaling = cfg.rope_of(kind)
+    (theta, scaling), rotary = cfg.rope_of(kind), cfg.rotary_of(kind)
     with part("proj"):
         if cfg.attn_output_gate:  # a head's columns are [query | gate]: two products, as the latent operator's blocks
             w_q = p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, 2 * hd)
@@ -745,12 +800,16 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
         v = _head_major(x, p["v"].astype(dtype).reshape(hidden, nkv, hd))
     with part("rope"):
         normed = lambda a, weight: _rms_norm(a, p[weight], cfg.norm_eps) if cfg.qk_norm else a
-        q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling, cfg.rotary_dim)
-        k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling, cfg.rotary_dim).astype(dtype)
+        q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling, rotary)
+        k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling, rotary).astype(dtype)
     with part("core"):
         out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg, cfg.window_of(kind))
-    if cfg.attn_output_gate:
+    if cfg.attn_output_gate or cfg.attn_head_gate:
         with part("gate"):
+            if cfg.attn_head_gate:  # one scalar a head and token, a float32 product as the router's; head-major as the core's output
+                gate = jnp.einsum("slh,hng->sngl", x.astype(jnp.float32), p["gate"].reshape(hidden, nkv, nh // nkv),
+                                  precision=jax.lax.Precision.HIGHEST)
+                gate = jnp.moveaxis(gate, -1, 1)[..., None]
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
     with part("proj"):
         return jnp.einsum("slngd,ngdh->slh", out, p["o"].astype(dtype).reshape(nkv, nh // nkv, hd, hidden))
@@ -1107,8 +1166,8 @@ def _expert_rows_by_count(heights: Tuple[int, ...], cfg: Lfm2MoeConfig, dtype):
 def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = None, sequences: int = 1,
              by_count=_expert_rows_by_count):
     """The held experts' part of the routed feed-forward on (tokens, hidden),
-    plus the shared experts where the configuration has them (every rank
-    computes those alike).
+    times ``routed_scaling_factor``, plus the shared experts where the
+    configuration has them (every rank computes those alike).
 
     Returns ``(out, load, stats)``: ``load`` counts the tokens each of ALL experts
     was chosen for (the bias rule needs them all), ``stats`` is this layer's
@@ -1131,6 +1190,8 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
     heights = _row_buffer_heights(cfg, t) if row_buffer is None else tuple(sorted({min(row_buffer, k * t), k * t}))
     with jax.named_scope("moe"), jax.named_scope("router"):
         chosen, weight, scores = _route(p["router"], bias, x, cfg)
+        if cfg.routed_scaling_factor != 1.0:  # on the routed sum alone, as float32 weights: the shared experts' output is added as it is
+            weight = weight * cfg.routed_scaling_factor
         load = jnp.sum(chosen[..., None] == jnp.arange(cfg.num_experts), axis=(0, 1), dtype=jnp.int32)
     balance = jnp.zeros((), jnp.float32)
     if cfg.balance_rule == "aux_loss":
@@ -1263,7 +1324,10 @@ class Lfm2MoePrograms(NamedTuple):
     ``kernel_visits``: per mask that runs as the kernel, the block pairs the kernel visits a head
     and sequence (:func:`_kernel_visits`, as sorted items).
     ``linear_core_layers``: the ``linear_attention`` layers by the program their delta core runs as
-    (``LINEAR_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none."""
+    (``LINEAR_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none.
+    ``heads_by_mask``, ``rotary_by_mask``: per mask, the query heads of each of its layers and the columns
+    of a head that its rope turns (a kernel's visits are a head's: the work of a mask's layers is theirs
+    times these heads), whichever core runs."""
 
     config: Lfm2MoeConfig
     init: Any
@@ -1273,6 +1337,8 @@ class Lfm2MoePrograms(NamedTuple):
     kernel_layers_by_mask: Tuple[Tuple[str, int], ...] = ()
     kernel_visits: Tuple[Tuple[str, Tuple[Tuple[str, int], ...]], ...] = ()
     linear_core_layers: Tuple[Tuple[str, int], ...] = ()
+    heads_by_mask: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
+    rotary_by_mask: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
 
 
 def _init_leaf(name: str, key, index: int, shape):
@@ -1352,24 +1418,25 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         return token_loss(logits, y_all[rows])
 
     train_step.__name__, init.__name__ = "lm_train_step", "lm_init"
-    by_mask, visits = [], []
+    by_mask, visits, heads, rotary = [], [], [], []
+    engaged, latent = _use_attention_kernel(cfg.seq_len), "latent_attention" in cfg.layer_types
+    columns = _core_columns(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim) if latent \
+        else _core_columns(cfg.head_dim, cfg.head_dim)  # a head's columns are the configuration's; its layers' heads are not
     for mask in MASKS:
         window = cfg.sliding_window if mask == "window" else None
-        layers = sum(kind in ATTENTION_KINDS and (cfg.window_of(kind) is None) == (window is None)
-                     for kind in cfg.layer_types)
+        layers = [i for i, kind in enumerate(cfg.layer_types)
+                  if kind in ATTENTION_KINDS and (cfg.window_of(kind) is None) == (window is None)]
         if not layers:
             continue
-        engaged = _use_attention_kernel(cfg.seq_len)
-        by_mask.append((mask, layers if engaged else 0))
+        by_mask.append((mask, len(layers) if engaged else 0))
+        heads.append((mask, tuple(cfg.heads_of(i) for i in layers)))
+        rotary.append((mask, tuple(cfg.qk_rope_head_dim if latent else cfg.rotary_of(cfg.layer_types[i]) for i in layers)))
         if engaged:
-            latent = "latent_attention" in cfg.layer_types  # a configuration's attention layers have one head shape
-            columns = _core_columns(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim) if latent \
-                else _core_columns(cfg.head_dim, cfg.head_dim)
             visits.append((mask, tuple(sorted(_kernel_visits(cfg.seq_len, window, columns).items()))))
     linear = cfg.layer_types.count("linear_attention")
     return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval),
                            sum(n for _, n in by_mask), tuple(by_mask), tuple(visits),
-                           ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else ())
+                           ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else (), tuple(heads), tuple(rotary))
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -1382,8 +1449,8 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
     x = np.asarray(x_train)
     if x.ndim != 2 or not np.issubdtype(x.dtype, np.integer):
         raise ValueError(f"x_train must be integer tokens (sequences, length); got {x.dtype} {x.shape}")
-    for key in ("layer_types", "layer_ids", "held_experts"):
-        if key in config:
+    for key in ("layer_types", "layer_ids", "held_experts", "num_attention_heads_per_layer"):
+        if config.get(key) is not None:
             config[key] = tuple(config[key])
     if isinstance(config.get("rope_scaling"), Mapping):
         config["rope_scaling"] = tuple(sorted(config["rope_scaling"].items()))
@@ -1407,9 +1474,8 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
         if min(sizes.values()) <= 0 or cfg.linear_num_value_heads % cfg.linear_num_key_heads:
             raise ValueError(f"a linear_attention layer needs its heads (value heads a whole number to each key "
                              f"head), their sizes, its taps and its chunk: {sizes}")
-    if not 0.0 < cfg.partial_rotary_factor <= 1.0 or (cfg.partial_rotary_factor < 1.0 and cfg.rotary_dim % 2):
-        raise ValueError(f"partial_rotary_factor {cfg.partial_rotary_factor} of head_dim {cfg.head_dim} must leave "
-                         f"an even number of columns to turn")
+    if not 0.0 < cfg.partial_rotary_factor <= 1.0:
+        raise ValueError(f"partial_rotary_factor {cfg.partial_rotary_factor} is no share of a head")
     if cfg.shared_expert_gate and not cfg.n_shared_experts:
         raise ValueError("shared_expert_gate needs a shared expert to gate")
     if "sliding_attention" in cfg.layer_types and cfg.sliding_window <= 0:
@@ -1420,10 +1486,22 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
                 ("default", "yarn") or (block.get("rope_type") == "yarn" and not yarn <= set(block)):
             raise ValueError(f"rope_parameters[{kind!r}] = {block}: a layer type of {ATTENTION_KINDS} with its "
                              f"rope_theta and a rope_type of default or yarn (yarn needs {sorted(yarn)})")
-    if set(cfg.layer_types) & {"full_attention", "sliding_attention"} and \
-            (cfg.head_dim % 2 or cfg.num_attention_heads % cfg.num_key_value_heads):
-        raise ValueError(f"head_dim {cfg.head_dim} must be even and {cfg.num_attention_heads} heads a whole number "
-                         f"of query heads to each of the {cfg.num_key_value_heads} key-value heads")
+    per_layer = cfg.num_attention_heads_per_layer
+    if per_layer is not None and len(per_layer) != len(cfg.layer_types):
+        raise ValueError(f"num_attention_heads_per_layer {per_layer} against {len(cfg.layer_types)} layer_types")
+    for i, kind in enumerate(cfg.layer_types):  # each attention layer by its own type's rope and its own heads
+        if kind not in ("full_attention", "sliding_attention"):
+            continue
+        name, heads, rotary = f"layer {cfg.layer_ids[i]} ({kind})", cfg.heads_of(i), cfg.rotary_of(kind)
+        if cfg.head_dim % 2 or heads <= 0 or heads % cfg.num_key_value_heads:
+            raise ValueError(f"{name}: head_dim {cfg.head_dim} must be even and its {heads} heads a whole number of "
+                             f"query heads to each of the {cfg.num_key_value_heads} key-value heads")
+        if not 0 < rotary <= cfg.head_dim or rotary % 2:
+            raise ValueError(f"{name}: rope turns {rotary} of a head's {cfg.head_dim} columns (partial_rotary_factor); "
+                             f"an even number of them, at most all")
+    if cfg.attn_output_gate and cfg.attn_head_gate:
+        raise ValueError("attn_output_gate (a gate a column, inside W_q) and attn_head_gate (a gate a head, a "
+                         "projection of its own) are two forms of one gate: a configuration has one")
     if cfg.n_shared_experts < 0 or (cfg.n_shared_experts and cfg.moe_intermediate_size <= 0):
         raise ValueError(f"{cfg.n_shared_experts} shared experts of width {cfg.moe_intermediate_size}")
     if cfg.scoring_func not in ("sigmoid", "softmax") or cfg.balance_rule not in _BALANCE_GENE:
@@ -1531,6 +1609,8 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     kernel_attrs = {f"attention_kernel_layer_steps_{mask}": n for mask, n in by_mask.items()}
     for mask, visits in programs.kernel_visits:  # static: what the mask's kernel visits a layer, head and sequence
         kernel_attrs.update({f"attention_kernel_{name}_{mask}": n for name, n in visits})
+    kernel_attrs.update({f"attention_heads_{mask}": list(heads) for mask, heads in programs.heads_by_mask})
+    kernel_attrs.update({f"attention_rotary_columns_{mask}": list(columns) for mask, columns in programs.rotary_by_mask})
     by_linear = {program: layers * cfg.train_steps for program, layers in programs.linear_core_layers}
     kernel_attrs.update({f"linear_core_layer_steps_{program}": n for program, n in by_linear.items()})
     if by_linear:
