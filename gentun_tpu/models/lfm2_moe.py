@@ -85,7 +85,9 @@ a sigmoid gate::
          (:func:`_delta_core`): a chunk's updates are one unit lower-triangular system, solved once for all chunks;
          a chunk then maps the state that enters it to ``A S + B``, with ``A``, ``B`` and the outputs' operands
          batched products over all chunks, and a ``scan`` carries the state and nothing else from chunk to chunk
-         (one product a step, forward and backward: :func:`_affine_scan`); the same program on every backend
+         (one product a step, forward and backward: :func:`_affine_scan`); on a TPU at widths of whole lanes the
+         same chunks run as two fused kernels that hold a chunk in fast memory and carry the state
+         (:mod:`gentun_tpu.models.delta_kernel`), XLA's ops elsewhere
     Op = full_attention (gated):  [q ; gate] = W_q x, a head's columns [its query | its gate];  k, v as above;
          RMSNorm on q and k;  rope on the leading ``partial_rotary_factor`` of a head's columns (rotate-half inside
          them), the others pass;  the causal core;  W_o (o * sigmoid(gate))
@@ -455,9 +457,12 @@ LAYER_KINDS = ("conv", "linear_attention", "full_attention", "sliding_attention"
 ATTENTION_KINDS = ("full_attention", "sliding_attention", "latent_attention")
 #: The programs the delta rule's core has, as the spans and the counter name them (one today).
 LINEAR_CORE_PROGRAMS = ("chunked",)
-#: The products a chunk step of :func:`_delta_core`'s scan runs in sequence on the state, forward and backward
+#: The products a chunk step of :func:`_delta_core_xla`'s scan runs in sequence on the state, forward and backward
 #: alike (the ``train`` spans' ``linear_core_chain_products``): everything else of the rule is batched over all chunks.
 LINEAR_CORE_CHAIN_PRODUCTS = 1
+#: The same of the fused kernels (:mod:`gentun_tpu.models.delta_kernel`), which hold no ``A`` and ``B``: ``W S``, then
+#: ``K' (U - W S)`` forward; ``K dS``, then ``W' dV`` backward.
+LINEAR_CORE_KERNEL_CHAIN_PRODUCTS = 2
 #: The masks the core has, as the spans and the counter name them.
 MASKS = ("causal", "window")
 
@@ -905,8 +910,8 @@ def _affine_scan_bwd(kept, sent):
 _affine_scan.defvjp(_affine_scan_fwd, _affine_scan_bwd)
 
 
-def _delta_core(q, k, v, g, beta, chunk: int):
-    """The gated delta rule in chunks.  Per value head, from ``S_0 = 0``::
+def _delta_core_xla(q, k, v, g, beta, chunk: int):
+    """The gated delta rule in chunks, as XLA's ops.  Per value head, from ``S_0 = 0``::
 
         S' = exp(g_t) S_{t-1};   S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T;   o_t = S_t^T q_t
 
@@ -978,6 +983,32 @@ def _delta_core(q, k, v, g, beta, chunk: int):
         + jnp.einsum("Nsnrij,Nsnrje->Nsnrie", qk, values, **_EXACT)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(s, chunks * chunk, n, r, dv)  # (chunks, s, n, r, positions, dv) back
     return out[:, :length]
+
+
+def _use_delta_kernel(dk: int, dv: int, chunk: int, heads: int) -> bool:
+    """Whether the delta rule's core of a program traced now runs as the fused
+    kernels (:mod:`gentun_tpu.models.delta_kernel`): the backend is a TPU, a
+    head's key and value columns are whole lanes, a chunk whole sublanes, a
+    chunk of a key head's ``heads`` value heads fits the chip's fast memory,
+    and this jax ships Pallas.  A rule by backend and shape, as :func:`_use_attention_kernel`'s."""
+    if jax.default_backend() != "tpu":
+        return False
+    try:
+        from . import delta_kernel
+    except ImportError:
+        return False
+    return delta_kernel.fits(dk, dv, chunk, heads)
+
+
+def _delta_core(q, k, v, g, beta, chunk: int):
+    """The delta rule's core (:func:`_delta_core_xla`'s arguments and result):
+    the fused kernels where :func:`_use_delta_kernel` says so, XLA's ops
+    elsewhere: one function, chosen by backend and shape.  Both are the
+    ``chunked`` program: the rule in chunks with a state carried between them."""
+    if _use_delta_kernel(q.shape[-1], v.shape[-1], chunk, v.shape[3]):
+        from . import delta_kernel
+        return delta_kernel.delta_core(q, k, v, g, beta, chunk)
+    return _delta_core_xla(q, k, v, g, beta, chunk)
 
 
 def _unit_rows(a):
@@ -1324,7 +1355,9 @@ class Lfm2MoePrograms(NamedTuple):
     ``kernel_visits``: per mask that runs as the kernel, the block pairs the kernel visits a head
     and sequence (:func:`_kernel_visits`, as sorted items).
     ``linear_core_layers``: the ``linear_attention`` layers by the program their delta core runs as
-    (``LINEAR_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none.
+    (``LINEAR_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none;
+    ``linear_core_kernel_layers``: those of them whose core these programs run as the fused kernels
+    (:func:`_use_delta_kernel`, decided when they were built): all or none.
     ``heads_by_mask``, ``rotary_by_mask``: per mask, the query heads of each of its layers and the columns
     of a head that its rope turns (a kernel's visits are a head's: the work of a mask's layers is theirs
     times these heads), whichever core runs."""
@@ -1339,6 +1372,7 @@ class Lfm2MoePrograms(NamedTuple):
     linear_core_layers: Tuple[Tuple[str, int], ...] = ()
     heads_by_mask: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
     rotary_by_mask: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
+    linear_core_kernel_layers: int = 0
 
 
 def _init_leaf(name: str, key, index: int, shape):
@@ -1436,7 +1470,10 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
     linear = cfg.layer_types.count("linear_attention")
     return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval),
                            sum(n for _, n in by_mask), tuple(by_mask), tuple(visits),
-                           ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else (), tuple(heads), tuple(rotary))
+                           ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else (), tuple(heads), tuple(rotary),
+                           linear if linear and _use_delta_kernel(
+                               cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.delta_chunk,
+                               cfg.linear_num_value_heads // cfg.linear_num_key_heads) else 0)
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -1615,7 +1652,9 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     kernel_attrs.update({f"linear_core_layer_steps_{program}": n for program, n in by_linear.items()})
     if by_linear:
         kernel_attrs["linear_core_chunk"] = cfg.delta_chunk
-        kernel_attrs["linear_core_chain_products"] = LINEAR_CORE_CHAIN_PRODUCTS
+        kernel_attrs["linear_core_kernel_layer_steps"] = linear_kernel_steps = programs.linear_core_kernel_layers * cfg.train_steps
+        kernel_attrs["linear_core_chain_products"] = (LINEAR_CORE_KERNEL_CHAIN_PRODUCTS if linear_kernel_steps
+                                                      else LINEAR_CORE_CHAIN_PRODUCTS)
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)
@@ -1640,6 +1679,8 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
                 _get_registry().counter("attention_kernel_layer_steps_total", mask=mask).inc(n)
             for program, n in by_linear.items():
                 _get_registry().counter("linear_core_layer_steps_total", program=program).inc(n)
+            if by_linear:
+                _get_registry().counter("linear_core_kernel_layer_steps_total").inc(linear_kernel_steps)
             sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=wide,
                    row_buffer_heights=[list(pair) for pair in by_height])
             if balance is not None:  # the ``aux_loss`` rule: the term before its weight, a routed layer and step

@@ -18,6 +18,7 @@ programs they traced.
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -314,6 +315,99 @@ def test_the_state_crosses_chunk_boundaries():
     assert float(jnp.abs(moved - base)[:, 8:].max()) > 1e-3 and float(jnp.abs(moved - base)[:, :3].max()) == 0
 
 
+# -- the delta rule's core as fused kernels (interpreted on the CPU) against XLA's ops and the recurrence ------------
+
+
+def _repeated_keys_case(length: int, seed: int):
+    """``beta`` within 0.002 of 1 on keys that repeat in runs of 5 to 12 positions under a decay of ~0.001 a
+    position: the chunk's system has entries at 1, where a solve by powers of the system loses every digit."""
+    q, k, v, g, beta = _delta_case(length, seed, strong=False)
+    rng = np.random.default_rng([seed, length, 1])
+    starts = np.concatenate([[0], np.cumsum(rng.integers(5, 13, size=length))])
+    run_of = np.searchsorted(starts, np.arange(length), side="right") - 1
+    k = k[:, starts[run_of]]
+    beta = jnp.asarray(1.0 - rng.uniform(0.0, 0.002, size=beta.shape), jnp.float32)
+    return q, k, v, 0.002 * g, beta
+
+
+KERNEL_CASES = {  # name: (operands, chunk)
+    "mild-decays-state-over-four-boundaries": lambda: (_delta_case(80, 11, strong=False), 16),
+    "strong-decays": lambda: (_delta_case(64, 12, strong=True), 16),
+    "beta-near-1-on-repeated-keys": lambda: (_repeated_keys_case(64, 13), 32),
+    "no-whole-number-of-chunks": lambda: (_delta_case(41, 14, strong=False), 16),
+    "one-value-head-a-key-head": lambda: (_delta_case(40, 15, strong=False, r=1), 8),
+    "three-value-heads-chunks-of-24": lambda: (_delta_case(72, 16, strong=False, sequences=1, r=3), 24),
+}
+
+
+@pytest.fixture(scope="module")
+def kernel_readings():
+    """Output and every gradient of a case by the kernels, by XLA's ops and by the recurrence: computed once a case."""
+    from gentun_tpu.models import delta_kernel
+
+    done = {}
+
+    def readings(case):
+        if case not in done:
+            args, chunk = KERNEL_CASES[case]()
+            probe = jnp.asarray(np.random.default_rng(2).normal(size=args[2].shape), jnp.float32)
+            cores = {"kernel": lambda *a: delta_kernel.delta_core(*a, chunk, interpret=True),
+                     "xla": lambda *a: M._delta_core_xla(*a, chunk), "recurrence": _recurrence}
+            with HIGHEST:
+                done[case] = {name: jax.jit(lambda *a, core=core: (core(*a), jax.grad(
+                    lambda *b: jnp.sum(core(*b) * probe), argnums=(0, 1, 2, 3, 4))(*a)))(*args) for name, core in cores.items()}
+        return done[case]
+
+    return readings
+
+
+@pytest.mark.parametrize("oracle", ["xla", "recurrence"])
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_fused_delta_kernels_are_the_chunked_rule_forward_and_every_gradient(case, oracle, kernel_readings):
+    (got, got_grads), (want, want_grads) = kernel_readings(case)["kernel"], kernel_readings(case)[oracle]
+    assert np.isfinite(np.asarray(got)).all() and float(jnp.abs(want).max()) > 1e-2
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()) + 1e-6)
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), got_grads, want_grads):
+        scale = float(jnp.abs(b).max())
+        assert scale > 0 and np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(a, b, atol=5e-5 * scale, err_msg=name)
+
+
+def test_the_fused_delta_kernels_state_crosses_chunk_boundaries_and_grid_steps():
+    """A value written at position 3 moves the outputs of later chunks and of the next grid step (chunks of 8, four a
+    grid step), none before it; and without a backward pass to follow the forward kernel writes no state."""
+    from gentun_tpu.models import delta_kernel
+
+    q, k, v, g, beta = _delta_case(64, 6, strong=False)
+    core = jax.jit(lambda *a: delta_kernel.delta_core(*a, 8, interpret=True))
+    base, moved = core(q, k, v, g, beta), core(q, k, v.at[:, 3].add(1.0), g, beta)
+    assert delta_kernel.MAX_STEPS == 4 and float(jnp.abs(moved - base)[:, 32:].max()) > 1e-3
+    assert float(jnp.abs(moved - base)[:, 8:32].max()) > 1e-3 and float(jnp.abs(moved - base)[:, :3].max()) == 0
+    def written(jaxpr):  # the arrays each kernel call of a program writes, nested calls' included
+        return [n for eqn in jaxpr.eqns for n in ([len(eqn.outvars)] if eqn.primitive.name == "pallas_call" else
+                                                  [m for sub in jax.core.jaxprs_in_params(eqn.params) for m in written(sub)])]
+
+    forward = lambda *a: delta_kernel.delta_core(*a, 8, interpret=True)
+    assert written(jax.make_jaxpr(forward)(q, k, v, g, beta).jaxpr) == [1]
+    both = jax.grad(lambda *a: jnp.sum(forward(*a)), argnums=(0, 1, 2, 3, 4))
+    assert written(jax.make_jaxpr(both)(q, k, v, g, beta).jaxpr) == [2, 4]  # o and the states; dq, dk, dv and the gates'
+
+
+@pytest.mark.parametrize("backend,dk,dv,chunk,heads,kernel", [
+    ("cpu", 128, 128, 64, 2, False), ("tpu", 16, 24, 16, 2, False), ("tpu", 128, 128, 60, 2, False),
+    ("tpu", 128, 128, 64, 2, True), ("tpu", 256, 128, 8, 1, True),
+    ("tpu", 256, 256, 32, 4, True), ("tpu", 256, 256, 64, 8, False), ("tpu", 512, 512, 64, 4, False)])  # what fast memory holds
+def test_the_delta_cores_path_follows_the_backend_and_the_shape(backend, dk, dv, chunk, heads, kernel, monkeypatch):
+    """The CPU and the rehearsal's widths trace XLA's ops, the published widths on a TPU backend the kernels."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert M._use_delta_kernel(dk, dv, chunk, heads) is kernel
+    length = 2 * chunk
+    shapes = [jax.ShapeDtypeStruct(shape, jnp.float32) for shape in
+              ((1, length, 1, dk), (1, length, 1, dk), (1, length, 1, heads, dv), (1, length, 1, heads), (1, length, 1, heads))]
+    traced = str(jax.make_jaxpr(lambda *a: M._delta_core(*a, chunk))(*shapes))
+    assert ("pallas_call" in traced) is kernel and ("triangular_solve" in traced) is not kernel
+
+
 # -- the delta rule's scan: the state's own recurrence and nothing else ---------------------------------------------
 
 
@@ -509,6 +603,14 @@ def test_each_layer_type_has_its_own_scope_with_its_parts_inside(tokens):
     assert {classify(s)[0] for s in scopes} <= set(scope_rules.CLASSES)
 
 
+class _Sink:
+    def __init__(self):
+        self.records = []
+
+    def record(self, rec):
+        self.records.append(rec)
+
+
 @pytest.fixture(scope="module")
 def two_traced_individuals(tokens):
     """Two individuals of two delta layers and a full one scored with telemetry on: (programs, the ``train``
@@ -517,14 +619,7 @@ def two_traced_individuals(tokens):
     kw = model_kwargs({**MODEL, "num_hidden_layers": 3, "layer_types": PERIOD[1:]}, cache_dir=False)
     programs = M.Lfm2MoeModel.compiled_programs(x, **kw)
 
-    class Sink:
-        def __init__(self):
-            self.records = []
-
-        def record(self, rec):
-            self.records.append(rec)
-
-    sink = Sink()
+    sink = _Sink()
     get_registry().reset()
     spans.set_run_sink(sink)
     spans.enable()
@@ -534,11 +629,12 @@ def two_traced_individuals(tokens):
         spans.disable()
         spans.set_run_sink(None)
     trained = [r["attrs"] for r in sink.records if r["type"] == "span" and (r.get("attrs") or {}).get("steps") == 3]
-    return programs, trained, get_registry().counter("linear_core_layer_steps_total", program="chunked").value, fitness
+    by_kernel = get_registry().counter("linear_core_kernel_layer_steps_total").value
+    return programs, trained, get_registry().counter("linear_core_layer_steps_total", program="chunked").value, fitness, by_kernel
 
 
 def test_spans_and_the_labelled_counter_say_which_program_the_delta_core_ran_as(two_traced_individuals):
-    programs, trained, counted, fitness = two_traced_individuals
+    programs, trained, counted, fitness, _ = two_traced_individuals
     assert programs.linear_core_layers == (("chunked", 2),) and programs.kernel_layers_by_mask == (("causal", 0),)
     assert np.isfinite(fitness).all() and fitness[0] == fitness[1]
     assert [a["linear_core_layer_steps_chunked"] for a in trained] == [6, 6] and trained[0]["linear_core_chunk"] == 8
@@ -554,9 +650,44 @@ def test_spans_and_the_labelled_counter_say_which_program_the_delta_core_ran_as(
 
 def test_the_train_span_says_how_many_products_a_chunk_step_runs_in_sequence(two_traced_individuals):
     """``linear_core_chain_products`` beside ``linear_core_chunk``: a trace says which form of the core ran."""
-    _, trained, _, _ = two_traced_individuals
+    _, trained, _, _, _ = two_traced_individuals
     assert [(a["linear_core_chunk"], a["linear_core_chain_products"]) for a in trained] == [(8, 1)] * 2
     assert M.LINEAR_CORE_PROGRAMS == ("chunked",)
+
+
+def test_the_train_span_says_whether_the_delta_core_ran_as_the_fused_kernels(two_traced_individuals, tokens, monkeypatch):
+    """On the CPU XLA's ops run: ``linear_core_kernel_layer_steps`` is there and 0, and what the benchmark reads
+    (``linear_core_layer_steps_chunked``, ``linear_core_chunk``) keeps its values; with the kernels chosen (and
+    interpreted) the same programs report their layers x steps, two products on the chain, the same fitness."""
+    from gentun_tpu.models import delta_kernel
+
+    programs, trained, _, fitness, by_kernel = two_traced_individuals
+    assert programs.linear_core_kernel_layers == 0 and by_kernel == 0
+    assert [(a["linear_core_kernel_layer_steps"], a["linear_core_layer_steps_chunked"], a["linear_core_chunk"])
+            for a in trained] == [(0, 6, 8)] * 2
+    monkeypatch.setattr(M, "_use_delta_kernel", lambda dk, dv, chunk, heads: True)
+    monkeypatch.setattr(delta_kernel, "delta_core", functools.partial(delta_kernel.delta_core, interpret=True))
+    M._programs.cache_clear()
+    x, y = tokens
+    kw = model_kwargs({**MODEL, "num_hidden_layers": 3, "layer_types": PERIOD[1:]}, cache_dir=False)
+    sink = _Sink()
+    get_registry().reset()
+    spans.set_run_sink(sink)
+    spans.enable()
+    try:
+        by_kernels = M.Lfm2MoeModel.cross_validate_population(x, y, [deepseek_v2_genome().default()], **kw)
+        engaged = M.Lfm2MoeModel.compiled_programs(x, **kw)
+    finally:
+        spans.disable()
+        spans.set_run_sink(None)
+        M._programs.cache_clear()
+    attrs = next(r["attrs"] for r in sink.records if r["type"] == "span" and (r.get("attrs") or {}).get("steps") == 3)
+    assert engaged.linear_core_kernel_layers == 2 and engaged.linear_core_layers == (("chunked", 2),)
+    assert (attrs["linear_core_kernel_layer_steps"], attrs["linear_core_layer_steps_chunked"], attrs["linear_core_chunk"],
+            attrs["linear_core_chain_products"]) == (6, 6, 8, M.LINEAR_CORE_KERNEL_CHAIN_PRODUCTS)
+    assert get_registry().counter("linear_core_kernel_layer_steps_total").value == 6
+    assert get_registry().counter("linear_core_layer_steps_total", program="chunked").value == 6
+    np.testing.assert_allclose(by_kernels[0], fitness[0], rtol=1e-5)
 
 
 # -- the benchmark's family: configuration file, counts, readers ----------------------------------------------------
