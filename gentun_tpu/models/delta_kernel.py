@@ -17,13 +17,21 @@ difference ``G_i - G_j`` with ``j <= i``, a ``G_i`` or ``G_last - G_i``: none is
 
 The system is inverted by block substitution, doubling: with ``T_b`` the inverse of the diagonal blocks of ``b`` rows
 and ``C_b`` the system's entries inside the blocks of ``2 b`` rows but outside those of ``b``, ``T_2b = T_b - T_b C_b
-T_b`` (``[[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]``), from ``T_2 = I - C_1`` up to the chunk.  That
-keeps the conditioning of substitution: the product form ``(I - N)(I + N^2)(I + N^4)...`` is exact on paper and loses
-every digit when ``beta`` is near 1 on repeated keys under a weak decay (the powers of ``N`` grow like binomials).
-Its ten products a system wait on each other, and a product of 128 rows is far shorter than its latency, so what a
-chunk needs that no state enters (the inverse above all) is computed for all the chunks of a grid step together,
-level by level (:func:`_unit_lower_inverses`), before the state walks them: alone a layer's forward took 17.3 ms with
-one chunk's chain at a time (PERF.md section 6, PR 43).
+T_b`` (``[[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]``), up to the chunk.  That keeps the conditioning of
+substitution: the product form ``(I - N)(I + N^2)(I + N^4)...`` is exact on paper and loses every digit when ``beta``
+is near 1 on repeated keys under a weak decay (the powers of ``N`` grow like binomials).  A dense level is two
+products of rows x rows x rows that multiply mostly zeros (PR 43 paid ten a system, 21 M multiply-adds for what
+substitution does in 87 k), so each level takes the cheapest form its shape allows (:func:`_inverse_forms`): the
+blocks of ``CLOSED_ROWS`` rows are substitution written out on the vector unit, with no product at all
+(:func:`_closed_inverse`); a level whose ``b`` is whole sublanes runs both products at the ``rows / 2`` rows of
+``C_b`` that are not zero, which are the only rows ``T_b C_b T_b`` has; a level that aligns with nothing keeps the
+dense form.  At the published shape (two value heads, chunks of 64: 128 rows) that is the closed form at 4 rows, the
+level of 4 dense, the levels of 8, 16 and 32 at half the rows: five product-equivalents a system
+(:func:`inverse_products`, the ``train`` spans' ``linear_core_inverse_products``).  The products of a system wait on
+each other, and a product of 128 rows is far shorter than its latency, so what a chunk needs that no state enters
+(the inverse above all) is computed for all the chunks of a grid step together, level by level
+(:func:`_unit_lower_inverses`), before the state walks them: alone a layer's forward took 17.3 ms with one chunk's
+chain at a time (PERF.md section 6, PR 43).
 """
 import functools
 from typing import NamedTuple
@@ -40,6 +48,10 @@ MAX_STEPS = 4
 #: Of the chip's 16 MB of fast memory, what a grid step's pipelined blocks may take; the rest is a chunk's intermediates,
 #: a dozen or two arrays of rows x rows and rows x a head's columns, which is why a key head's value heads x chunk is bounded.
 BLOCK_BYTES, MAX_ROWS = 6 * 2 ** 20, 256
+#: The diagonal blocks of a chunk's system that are inverted in closed form on the vector unit (a power of two; alone
+#: on the chip blocks of 8 lost to the two products they save, PERF.md section 6, PR 44), and the rows a slice has to
+#: be whole numbers of for a doubling level to run at its live rows alone (a float32 tile's).
+CLOSED_ROWS, SUBLANES = 4, 8
 
 
 class Dims(NamedTuple):
@@ -97,8 +109,28 @@ class _Masks(NamedTuple):
     below: jax.Array  # strictly below the diagonal, within a head
     at_or_below: jax.Array
     last: jax.Array  # a row's own head's last column
-    pairs: jax.Array  # within the diagonal blocks of 2 rows
-    corners: tuple  # for b = 2, 4, ...: within the diagonal blocks of 2 b rows and outside those of b
+    diagonals: tuple  # for s = 1, 2, ...: the s-th diagonal below the main one, within the diagonal blocks of CLOSED_ROWS rows
+    levels: tuple  # for b = CLOSED_ROWS, 2 b, ...: (b, form, within the diagonal blocks of 2 b rows and outside those of b: of a halved level's live rows alone)
+
+
+def _inverse_forms(chunk: int, heads: int):
+    """How the inverse of a chunk's system is built, by what can be seen of the shape: ``(b, form)`` of each level in
+    turn.  ``closed``: the diagonal blocks of ``b`` rows by substitution written out on the vector unit; then the
+    doubling levels ``b -> 2 b``, ``halved`` (both products at the ``rows / 2`` rows that are not zero) where those
+    rows are whole sublanes in every block of ``2 b``, ``dense`` (two products of rows x rows x rows) where not."""
+    rows = heads * chunk
+    forms, b = [(CLOSED_ROWS, "closed")], CLOSED_ROWS
+    while b < rows:
+        if b % chunk:  # blocks that are whole heads have nothing between them
+            forms.append((b, "halved" if b % SUBLANES == 0 and rows % (2 * b) == 0 else "dense"))
+        b *= 2
+    return tuple(forms)
+
+
+def inverse_products(chunk: int, heads: int) -> float:
+    """The products of rows x rows x rows a chunk's inverse costs as :func:`_inverse_forms` builds it (the ``train``
+    spans' ``linear_core_inverse_products``): two a dense level, two halves a halved one, none in closed form."""
+    return sum({"closed": 0.0, "halved": 1.0, "dense": 2.0}[form] for _, form in _inverse_forms(chunk, heads))
 
 
 def _masks(d: Dims) -> _Masks:
@@ -110,23 +142,62 @@ def _masks(d: Dims) -> _Masks:
         return functools.reduce(lambda of, h: jnp.where(at >= h * d.chunk, h, of), range(1, d.heads), jnp.zeros_like(at))
 
     same = head(i) == head(j)
-    corners, level, block = [], 1, 2
-    while block < rows:
-        if block % d.chunk:  # blocks that are whole heads have nothing between them
-            corners.append(((i >> (level + 1)) == (j >> (level + 1))) & ((i >> level) != (j >> level)))
-        level, block = level + 1, 2 * block
+    closed = CLOSED_ROWS.bit_length() - 1
+    levels = []
+    for b, form in _inverse_forms(d.chunk, d.heads)[1:]:
+        level = b.bit_length() - 1
+        if form == "halved":  # of the live rows alone: row r of them is row b + r % b of block r // b
+            r = jax.lax.broadcasted_iota(jnp.int32, (rows // 2, rows), 0)
+            levels.append((b, form, (jax.lax.broadcasted_iota(jnp.int32, (rows // 2, rows), 1) >> level) == ((r >> level) << 1)))
+        else:
+            levels.append((b, form, ((i >> (level + 1)) == (j >> (level + 1))) & ((i >> level) != (j >> level))))
     return _Masks(eye=i == j, below=same & (j < i), at_or_below=same & (j <= i), last=j == (head(i) + 1) * d.chunk - 1,
-                  pairs=(i >> 1) == (j >> 1), corners=tuple(corners))
+                  diagonals=tuple(((i >> closed) == (j >> closed)) & (i - j == s) for s in range(1, CLOSED_ROWS)),
+                  levels=tuple(levels))
+
+
+def _closed_inverse(system, m: _Masks):
+    """``(I + system)^-1`` within the diagonal blocks of ``CLOSED_ROWS`` rows, by substitution written out: ``T (I +
+    system) = I`` column by column is ``T_s = -P_s - sum_t roll(T_(s - t), t columns) c_t`` for the ``s``-th diagonal
+    of ``T`` below the main one, ``P_s`` that diagonal of the system and ``c_t`` its ``t``-th as a row (a product with
+    a ``c_t`` lands on the ``s``-th diagonal of a block and nowhere else, so nothing needs a mask but ``P_s``).  Rolls
+    along the lanes, sums along the sublanes and elementwise float32 arithmetic: no product of the matrix unit."""
+    rows = system.shape[0]
+    found, as_rows = [], []  # T_1, T_2, ... and c_1, c_2, ...
+    for s, diagonal in enumerate(m.diagonals, start=1):
+        below = jnp.where(diagonal, system, 0.0)
+        found.append(-below - sum(pltpu.roll(found[s - t - 1], rows - t, 1) * as_rows[t - 1] for t in range(1, s)))
+        as_rows.append(jnp.sum(below, axis=0, keepdims=True))
+    return sum(found, jnp.where(m.eye, 1.0, 0.0))
+
+
+def _halves(a, b: int):
+    """(the upper, the lower) ``b`` rows of every block of ``2 b`` rows of ``a``, one block's after the other's:
+    slices of whole sublanes."""
+    blocks = range(0, a.shape[0], 2 * b)
+    return jnp.concatenate([a[at:at + b] for at in blocks], axis=0), jnp.concatenate([a[at + b:at + 2 * b] for at in blocks], axis=0)
+
+
+def _interleaved(upper, lower, b: int):
+    """:func:`_halves` back in place."""
+    return jnp.concatenate([part for at in range(0, upper.shape[0], b) for part in (upper[at:at + b], lower[at:at + b])], axis=0)
 
 
 def _unit_lower_inverses(systems, m: _Masks):
     """``(I + system)^-1`` of each of ``systems``, strictly lower triangular (rows x rows, zero between heads), by
-    block substitution, doubling (the module's docstring).  The systems advance level by level together: a level's two
-    products wait on each other, those of different systems do not, so the matrix units always have one to run."""
-    inverses = [jnp.where(m.eye, 1.0, 0.0) - jnp.where(m.pairs, system, 0.0) for system in systems]
-    for corner in m.corners:
-        halves = [_mm(jnp.where(corner, system, 0.0), inverse) for system, inverse in zip(systems, inverses)]
-        inverses = [inverse - _mm(inverse, half) for inverse, half in zip(inverses, halves)]
+    block substitution, doubling (the module's docstring), each level in the form :func:`_inverse_forms` gave it.
+    The systems advance level by level together: a level's two products wait on each other, those of different
+    systems do not, so the matrix units always have one to run."""
+    inverses = [_closed_inverse(system, m) for system in systems]
+    for b, form, corner in m.levels:
+        if form == "halved":  # C_b has entries in the lower b rows of a block of 2 b alone, and so have C_b T_b and T_b C_b T_b
+            split = [_halves(inverse, b) for inverse in inverses]
+            halves = [_mm(jnp.where(corner, _halves(system, b)[1], 0.0), inverse) for system, inverse in zip(systems, inverses)]
+            moved = [_mm(lower, _interleaved(jnp.zeros_like(half), half, b)) for (_, lower), half in zip(split, halves)]
+            inverses = [_interleaved(upper, lower - move, b) for (upper, lower), move in zip(split, moved)]
+        else:
+            halves = [_mm(jnp.where(corner, system, 0.0), inverse) for system, inverse in zip(systems, inverses)]
+            inverses = [inverse - _mm(inverse, half) for inverse, half in zip(inverses, halves)]
     return inverses
 
 

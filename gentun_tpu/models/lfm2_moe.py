@@ -1357,7 +1357,11 @@ class Lfm2MoePrograms(NamedTuple):
     ``linear_core_layers``: the ``linear_attention`` layers by the program their delta core runs as
     (``LINEAR_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none;
     ``linear_core_kernel_layers``: those of them whose core these programs run as the fused kernels
-    (:func:`_use_delta_kernel`, decided when they were built): all or none.
+    (:func:`_use_delta_kernel`, decided when they were built): all or none;
+    ``linear_core_inverse_products``: the products of rows x rows x rows those kernels spend on a chunk's
+    triangular inverse (:func:`gentun_tpu.models.delta_kernel.inverse_products`, the function that chose
+    each level's form: a level at half the rows counts a half, one in closed form nothing), 0 where XLA's
+    ops run, whose solve is a substitution.
     ``heads_by_mask``, ``rotary_by_mask``: per mask, the query heads of each of its layers and the columns
     of a head that its rope turns (a kernel's visits are a head's: the work of a mask's layers is theirs
     times these heads), whichever core runs."""
@@ -1373,6 +1377,7 @@ class Lfm2MoePrograms(NamedTuple):
     heads_by_mask: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
     rotary_by_mask: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
     linear_core_kernel_layers: int = 0
+    linear_core_inverse_products: float = 0.0
 
 
 def _init_leaf(name: str, key, index: int, shape):
@@ -1468,12 +1473,16 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         if engaged:
             visits.append((mask, tuple(sorted(_kernel_visits(cfg.seq_len, window, columns).items()))))
     linear = cfg.layer_types.count("linear_attention")
+    by_kernels, inverse_products = 0, 0.0
+    if linear:
+        per_key = cfg.linear_num_value_heads // cfg.linear_num_key_heads
+        if _use_delta_kernel(cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.delta_chunk, per_key):
+            from . import delta_kernel
+            by_kernels, inverse_products = linear, delta_kernel.inverse_products(cfg.delta_chunk, per_key)
     return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval),
                            sum(n for _, n in by_mask), tuple(by_mask), tuple(visits),
                            ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else (), tuple(heads), tuple(rotary),
-                           linear if linear and _use_delta_kernel(
-                               cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.delta_chunk,
-                               cfg.linear_num_value_heads // cfg.linear_num_key_heads) else 0)
+                           by_kernels, inverse_products)
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -1655,6 +1664,7 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
         kernel_attrs["linear_core_kernel_layer_steps"] = linear_kernel_steps = programs.linear_core_kernel_layers * cfg.train_steps
         kernel_attrs["linear_core_chain_products"] = (LINEAR_CORE_KERNEL_CHAIN_PRODUCTS if linear_kernel_steps
                                                       else LINEAR_CORE_CHAIN_PRODUCTS)
+        kernel_attrs["linear_core_inverse_products"] = programs.linear_core_inverse_products
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)

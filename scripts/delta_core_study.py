@@ -4,15 +4,22 @@ forward, forward + backward and *as the mixer runs it* under ``forward``'s check
 backward), with how far the kernels' output and gradients lie from XLA's.  No benchmark cell runs this: it is the
 instrument for the next change to this class (PERF.md section 7 (b)).
 
-    chiprun --chips 1 -- python scripts/delta_core_study.py [--cores xla,kernel] [--steps 1,2,4,8] [--one-pass] [--profile]
+    chiprun --chips 1 -- python scripts/delta_core_study.py [--cores xla,kernel] [--steps 1,2,4,8] \\
+        [--inverse 2+dense,4+dense,2,8] [--one-pass] [--profile]
 
-``--steps`` also times the kernels at other counts of chunks a grid step; ``--profile`` prints each core's
-instructions by self time from a profiler trace of the mixer's form (the kernels by name).  A line of JSON a result,
+``--steps`` also times the kernels at other counts of chunks a grid step; ``--inverse`` at other forms of a chunk's
+triangular inverse than the shipped one: ``<rows>`` the diagonal blocks inverted in closed form on the vector unit
+(``delta_kernel.CLOSED_ROWS``, 4 as shipped; 2 is PR 43's start), ``+dense`` every doubling level as two dense
+products, none at its live rows alone (``2+dense`` is PR 43's form, ten products a chunk; ``4+dense`` ISSUE 44's
+step 1 alone, ``2`` its step 2 alone);
+``--one-pass`` adds to every form of the kernels its diagnosis at one bfloat16 pass a product; ``--profile`` prints
+each core's instructions by self time from a profiler trace of the mixer's form (the kernels by name).  A line of JSON a result,
 on the output and in ``chiprun_out/delta_core_study.jsonl``.  On the CPU (``JAX_PLATFORMS=cpu``) it runs a small
 shape with the kernels interpreted and marks every line ``"rehearsal": true``: a check of the script, never a time.
 """
 import argparse
 import collections
+import contextlib
 import json
 import os
 import sys
@@ -92,6 +99,8 @@ def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--cores", default="xla,kernel")
     parser.add_argument("--steps", default="", help="counts of chunks a grid step to time the kernels at besides the rule's own")
+    parser.add_argument("--inverse", default="", help="forms of the chunk's inverse to time the kernels at besides the shipped one: "
+                        "<rows in closed form>[+dense], as 2+dense (PR 43's), 4+dense, 2, 8")
     parser.add_argument("--profile", action="store_true")
     parser.add_argument("--one-pass", action="store_true", help="also time the kernels' products at one bfloat16 pass (wrong results: a diagnosis)")
     parser.add_argument("--calls", type=int, default=10)
@@ -102,32 +111,45 @@ def main():
     device = jax.devices()[0]
     say(device=device.device_kind, platform=device.platform, shape=[1, length, key_heads, heads, dk, dv], chunk=chunk, **mark)
     args = operands(length, key_heads, heads, dk, dv)
-    cores = {}
-    if "xla" in cli.cores.split(","):
-        cores["xla"] = lambda *a: M._delta_core_xla(*a, chunk)
-    if "kernel" in cli.cores.split(","):
-        cores["kernel"] = lambda *a: delta_kernel.delta_core(*a, chunk, interpret=rehearsal)
-    def patched(name, value):
-        """The kernels traced with ``delta_kernel.<name>`` at ``value``."""
-        def core(*a):
-            saved = getattr(delta_kernel, name)
+    @contextlib.contextmanager
+    def patched(**values):
+        """``delta_kernel.<name>`` at its value, each, while a form of the kernels is traced (the backward kernel is
+        traced when jax transposes the program, after the core has returned: the whole first call runs inside)."""
+        saved = {name: getattr(delta_kernel, name) for name in values}
+        for name, value in values.items():
             setattr(delta_kernel, name, value)
-            try:
-                return delta_kernel.delta_core(*a, chunk, interpret=rehearsal)
-            finally:
-                setattr(delta_kernel, name, saved)
-        return core
-
-    for steps in (int(t) for t in cli.steps.split(",") if t):
-        cores[f"kernel_steps_{steps}"] = patched("MAX_STEPS", steps)
-    if cli.one_pass:  # a diagnosis, never a candidate: how much of the kernels' time the six passes of a float32 product are
-        cores["kernel_one_bf16_pass_DIAGNOSIS"] = patched("_EXACT", dict(preferred_element_type=jnp.float32))
-    results = {}
-    for name, core in cores.items():
         try:
-            fwd, both, mixer = forms(core)
-            results[name] = (np.asarray(fwd(*args)), [np.asarray(x) for x in both(*args)])
-            say(core=name, fwd_ms=timed(fwd, *args, n=cli.calls), fwd_bwd_ms=timed(both, *args, n=max(cli.calls // 2, 1)),
+            yield
+        finally:
+            for name, value in saved.items():
+                setattr(delta_kernel, name, value)
+
+    def kernel():  # a function of its own a form: jax keeps what it traced by the function's identity
+        return lambda *a: delta_kernel.delta_core(*a, chunk, interpret=rehearsal)
+
+    cores = {}  # name: (the core, what of ``delta_kernel`` it is traced with)
+    if "xla" in cli.cores.split(","):
+        cores["xla"] = (lambda *a: M._delta_core_xla(*a, chunk), {})
+    variants = {"kernel": {}} if "kernel" in cli.cores.split(",") else {}
+    for steps in (int(t) for t in cli.steps.split(",") if t):
+        variants[f"kernel_steps_{steps}"] = dict(MAX_STEPS=steps)
+    for form in (f for f in cli.inverse.split(",") if f):
+        closed, _, dense = form.partition("+")
+        variants[f"kernel_inverse_closed{closed}" + ("_dense" if dense else "")] = dict(
+            CLOSED_ROWS=int(closed), **({"SUBLANES": 2 ** 30} if dense else {}))
+    for name, values in variants.items():
+        cores[name] = (kernel(), values)
+        if cli.one_pass:  # a diagnosis, never a candidate: how much of the kernels' time the six passes of a float32 product are
+            cores[name + "_one_bf16_pass_DIAGNOSIS"] = (kernel(), dict(values, _EXACT=dict(preferred_element_type=jnp.float32)))
+    results = {}
+    for name, (core, values) in cores.items():
+        try:
+            with patched(**values):
+                fwd, both, mixer = forms(core)
+                results[name] = (np.asarray(fwd(*args)), [np.asarray(x) for x in both(*args)])
+                jax.block_until_ready(mixer(*args))
+                products = {} if name == "xla" else {"inverse_products": delta_kernel.inverse_products(chunk, heads)}
+            say(core=name, **products, fwd_ms=timed(fwd, *args, n=cli.calls), fwd_bwd_ms=timed(both, *args, n=max(cli.calls // 2, 1)),
                 fwd_remat_bwd_ms=timed(mixer, *args, n=max(cli.calls // 2, 1)), **mark)
             if cli.profile and not rehearsal:
                 say(core=name, self_ms_fwd=self_times(fwd, args), self_ms_mixer=self_times(mixer, args))

@@ -655,6 +655,17 @@ def test_the_train_span_says_how_many_products_a_chunk_step_runs_in_sequence(two
     assert M.LINEAR_CORE_PROGRAMS == ("chunked",)
 
 
+def test_the_train_span_says_what_a_chunks_inverse_costs_in_products(two_traced_individuals):
+    """``linear_core_inverse_products``: nothing where XLA's ops solve the system by substitution; where the kernels
+    run, what the function that chose each level's form counts (the engaged programs' span is read in the next test):
+    under PR 43's ten at the published shape, ten where no level's live rows are whole sublanes."""
+    from gentun_tpu.models import delta_kernel
+
+    programs, trained, _, _, _ = two_traced_individuals
+    assert programs.linear_core_inverse_products == 0 and [a["linear_core_inverse_products"] for a in trained] == [0, 0]
+    assert delta_kernel.inverse_products(64, 2) == 5 and delta_kernel.inverse_products(24, 3) == 10
+
+
 def test_the_train_span_says_whether_the_delta_core_ran_as_the_fused_kernels(two_traced_individuals, tokens, monkeypatch):
     """On the CPU XLA's ops run: ``linear_core_kernel_layer_steps`` is there and 0, and what the benchmark reads
     (``linear_core_layer_steps_chunked``, ``linear_core_chunk``) keeps its values; with the kernels chosen (and
@@ -685,6 +696,7 @@ def test_the_train_span_says_whether_the_delta_core_ran_as_the_fused_kernels(two
     assert engaged.linear_core_kernel_layers == 2 and engaged.linear_core_layers == (("chunked", 2),)
     assert (attrs["linear_core_kernel_layer_steps"], attrs["linear_core_layer_steps_chunked"], attrs["linear_core_chunk"],
             attrs["linear_core_chain_products"]) == (6, 6, 8, M.LINEAR_CORE_KERNEL_CHAIN_PRODUCTS)
+    assert attrs["linear_core_inverse_products"] == engaged.linear_core_inverse_products == delta_kernel.inverse_products(8, 2) == 2
     assert get_registry().counter("linear_core_kernel_layer_steps_total").value == 6
     assert get_registry().counter("linear_core_layer_steps_total", program="chunked").value == 6
     np.testing.assert_allclose(by_kernels[0], fitness[0], rtol=1e-5)
