@@ -124,13 +124,13 @@ class _Worker:
 
     __slots__ = ("worker_id", "writer", "capacity", "prefetch_depth", "credit",
                  "in_flight", "last_seen", "n_chips", "backend", "draining",
-                 "mesh", "caps", "preemptible", "homes", "device")
+                 "mesh", "caps", "preemptible", "device")
 
     def __init__(self, worker_id: str, writer: asyncio.StreamWriter, capacity: int,
                  n_chips: int = 1, backend: Optional[str] = None,
                  prefetch_depth: int = 0, mesh: Optional[Dict[str, int]] = None,
                  caps: frozenset = frozenset(), preemptible: bool = False,
-                 homes: int = 1, device: Optional[Dict[str, Any]] = None):
+                 device: Optional[Dict[str, Any]] = None):
         self.worker_id = worker_id
         self.writer = writer
         self.capacity = capacity
@@ -157,14 +157,6 @@ class _Worker:
         #: fleet is mixed; absent/malformed on the wire degrades to False
         #: (stable), the conservative default.
         self.preemptible = preemptible
-        #: Multi-home advertisement (protocol.py "Multi-home field"): how
-        #: many broker shards this worker connected to.  Informational —
-        #: this broker already advertised the worker's FULL window through
-        #: the normal credit path (the worker meters per-broker credit
-        #: itself) — but operators need it to read per-shard /statusz
-        #: capacity sums correctly: a 2-homed capacity-8 worker shows 8 on
-        #: BOTH shards.  1 for every single-homed (old) worker.
-        self.homes = homes
         #: Device advertisement (protocol.py "Device field"):
         #: {"platform", "kind", "count"} as the worker's jax reports them;
         #: None for non-jax species and workers that never sent one.
@@ -1323,18 +1315,6 @@ class JobBroker:
         return max(0, min(depth, 4 * capacity))
 
     @staticmethod
-    def _parse_homes(hello: Dict[str, Any]) -> int:
-        """The worker's OPTIONAL multi-home advertisement (protocol.py
-        "Multi-home field"): how many broker shards it joined.  Missing
-        (every single-homed worker — the field is only sent when >1) or
-        malformed degrades to 1, never a dropped connection."""
-        try:
-            homes = int(hello.get("homes", 1))
-        except (TypeError, ValueError):
-            return 1
-        return max(1, homes)
-
-    @staticmethod
     def _parse_device(hello: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         """The worker's OPTIONAL device advertisement, validated.
 
@@ -2150,7 +2130,6 @@ class JobBroker:
             "preemptible": w.preemptible,
             "mesh": w.mesh,
             "wire_caps": sorted(w.caps),
-            "homes": w.homes,
             "device": w.device,
         } for w in list(self._workers.values())]
         return {
@@ -2243,7 +2222,6 @@ class JobBroker:
                 # Strict literal check — absent/malformed degrades to
                 # stable, the conservative placement default.
                 preemptible=hello.get("preemptible") is True,
-                homes=self._parse_homes(hello),
                 device=self._parse_device(hello),
             )
             # Heterogeneous-fleet check (ADVICE r3): two workers scoring one
@@ -2269,11 +2247,6 @@ class JobBroker:
                 if worker.preemptible or self._seen_preemptible:
                     self._seen_preemptible = True
                     reg.gauge("preemptible_members").set(self.fleet_preemptible())
-                # Series appears only for multi-homed workers (ISSUE 18) —
-                # a single-broker fleet's metric snapshot gains nothing.
-                if worker.homes > 1:
-                    reg.gauge("worker_homes",
-                              worker=worker.worker_id).set(worker.homes)
             _tele.record_event("worker_joined", {
                 "worker_id": worker.worker_id, "capacity": worker.capacity,
                 "prefetch_depth": worker.prefetch_depth,
@@ -2509,8 +2482,8 @@ class JobBroker:
                     for job in msg.get("jobs") or ():
                         job = dict(job)
                         job_id = str(job.pop("job_id", "") or self.new_job_id())
-                        # Resubmit dedup (ISSUE 18): a sharded master whose
-                        # submit ack died with the link retries the SAME ids
+                        # Resubmit dedup: a wire tenant whose submit ack
+                        # died with the link retries the SAME ids
                         # after reconnect — ids still open here were already
                         # enqueued, so scheduling them again would double-run
                         # the job.  (Ids already TERMINAL re-run instead; the
@@ -2524,10 +2497,10 @@ class JobBroker:
                 elif mtype == "cancel":
                     self._cancel_ids({str(j) for j in msg.get("jobs") or ()})
                 elif mtype == "session_stats":
-                    # Sizing snapshot for WIRE tenants (ISSUE 18): sharded
-                    # masters read their session's capacity/prefetch share
-                    # and the fleet's mesh/chip facts over the wire instead
-                    # of an embedded broker reference.  OPTIONAL message —
+                    # Sizing snapshot for WIRE tenants: they read their
+                    # session's capacity/prefetch share and the fleet's
+                    # mesh/chip facts over the wire instead of an
+                    # embedded broker reference.  OPTIONAL message —
                     # old clients never send it, old brokers never see it.
                     sid = str(msg.get("session") or DEFAULT_SESSION)
                     if msg.get("reset_chips") is True:

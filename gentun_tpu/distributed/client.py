@@ -83,42 +83,6 @@ class _ReconnectBackoff:
         return d
 
 
-class _ShardConn:
-    """One worker↔shard connection (multi-homed worker, ISSUE 18).
-
-    Everything a single-homed ``GentunClient`` keeps as instance state —
-    socket, read stream, granted caps, boot epoch — lives HERE per shard,
-    plus the pieces that make shard independence real:
-
-    - ``backoff``: this connection's OWN reconnect backoff (the satellite
-      fix — one flapping shard inflating its delay toward the cap must
-      never slow redials to healthy shards), seeded per (worker, shard)
-      so a fleet's reconnects stay decorrelated per shard too.
-    - ``gen``: redial generation.  Batches are enqueued tagged with the
-      gen that received them; a batch whose gen is stale by evaluation
-      time came from a dead connection — the broker already requeued
-      those jobs at disconnect, so evaluating them would only duplicate
-      work the fleet is already redoing.
-    """
-
-    __slots__ = ("host", "port", "shard", "sock", "rfile", "write_lock",
-                 "handshaken", "boot_id", "caps", "backoff", "gen", "dead")
-
-    def __init__(self, host: str, port: int, backoff: _ReconnectBackoff):
-        self.host, self.port = host, int(port)
-        self.shard = f"{host}:{port}"
-        self.sock: Optional[socket.socket] = None
-        self.rfile = None
-        self.write_lock = threading.Lock()
-        self.handshaken = False
-        self.boot_id: Optional[str] = None
-        self.caps: frozenset = frozenset()
-        self.backoff = backoff
-        self.gen = 0
-        #: terminal auth rejection — never redialed again.
-        self.dead = False
-
-
 class GentunClient:
     """Connects to the master's broker and evaluates individuals forever.
 
@@ -206,7 +170,6 @@ class GentunClient:
         fault_injector=None,
         wire_caps: Optional[tuple] = None,
         preemptible: bool = False,
-        broker_urls: Optional[list] = None,
     ):
         self.species = species
         self.x_train = x_train
@@ -374,35 +337,6 @@ class GentunClient:
         # (returning queued-but-unstarted jobs), and work() exits cleanly.
         self._drain_req = threading.Event()
         self._work_stop: Optional[threading.Event] = None
-        # Multi-homing (ISSUE 18, horizontal broker sharding): with
-        # ``broker_urls=[...]`` of length >1 this worker holds ONE
-        # connection per shard — per-connection receive threads, per-shard
-        # credit windows and backoff — so a stalled or dead shard can
-        # never block dispatch on healthy shards.  A one-element list
-        # collapses to the plain host/port path, wire byte-identical.
-        self._addrs: Optional[List[tuple]] = None
-        self._conns: List[_ShardConn] = []
-        if broker_urls:
-            from .shard import parse_broker_urls
-
-            addrs = parse_broker_urls(broker_urls)
-            self.host, self.port = addrs[0]
-            if len(addrs) > 1:
-                if self.multihost:
-                    # One leader connection is the multihost contract —
-                    # followers replay ITS batches; two shards' interleaved
-                    # windows would diverge the ranks' compiled programs.
-                    raise ValueError(
-                        "broker_urls multi-homing is not supported for "
-                        "multihost workers")
-                if self._injector is not None:
-                    # Frame-counted fault schedules assume one connection;
-                    # shard chaos drills kill brokers instead (chaos_run.py
-                    # shard_kill).
-                    raise ValueError(
-                        "fault_injector is not supported with multi-shard "
-                        "broker_urls")
-                self._addrs = addrs
 
     # -- host-mesh capacity ------------------------------------------------
 
@@ -660,135 +594,6 @@ class GentunClient:
             msg = self._injector.client_recv(self, msg)  # may delay or raise
         return msg
 
-    # -- multi-home connection plumbing (ISSUE 18) --------------------------
-
-    def _send_conn(self, conn: _ShardConn, msg: Dict[str, Any]) -> None:
-        """Send one frame on ONE shard's connection (manager threads,
-        heartbeats, credit replenish — anything that must not depend on
-        which conn the evaluator currently has bound)."""
-        data = encode(msg)
-        with conn.write_lock:
-            sock = conn.sock
-            if sock is None:
-                raise OSError("not connected")
-            sock.sendall(data)
-        mtype = str(msg.get("type"))
-        handles = self._wire_counters.get(mtype)
-        if handles is None:
-            reg = _get_registry()
-            handles = (reg.counter("wire_bytes_sent_total", type=mtype),
-                       reg.counter("wire_frames_sent_total", type=mtype))
-            self._wire_counters[mtype] = handles
-        handles[0].inc(len(data))
-        handles[1].inc()
-
-    def _bind_conn(self, conn: _ShardConn) -> None:
-        """Point the shared send path (``_send``/``_raw_send`` and the
-        boot-epoch echo in ``_evaluate_batch``) at ONE shard for the
-        duration of a batch.  Safe because the evaluator is the only
-        thread that touches ``self._sock`` in multi-home mode — managers
-        and heartbeats use conn-scoped sends."""
-        self._sock = conn.sock
-        self._rfile = conn.rfile
-        self._boot_id = conn.boot_id
-        self._broker_caps = conn.caps
-
-    def _connect_conn(self, conn: _ShardConn) -> None:
-        """Dial + handshake one shard (the multi-home mirror of
-        :meth:`_connect`), with the OPTIONAL ``homes`` hello rider so the
-        shard's ``/statusz`` reads this worker's capacity correctly."""
-        n_chips = self._fleet_chips()  # before the socket: may compile-init jax
-        device = self._device_advert()
-        sock = socket.create_connection((conn.host, conn.port), timeout=10.0)
-        sock.settimeout(None)
-        rfile = sock.makefile("rb")
-        try:
-            backend = self.species.fitness_backend()
-        except Exception:  # never let an advisory field block the handshake
-            backend = None
-        hello = {
-            "type": "hello",
-            "worker_id": self.worker_id,
-            "token": self.token,
-            "capacity": self.capacity,
-            "prefetch_depth": self.prefetch_depth,
-            "n_chips": n_chips,
-            "backend": backend,
-            # OPTIONAL multi-home advertisement (protocol.py "Multi-home
-            # field"): only multi-homed workers send it.
-            "homes": len(self._addrs or ()) or 1,
-        }
-        if device is not None:
-            hello["device"] = device
-        mesh = self._mesh_advert()
-        if mesh is not None:
-            hello["mesh"] = mesh
-        if self.preemptible:
-            hello["preemptible"] = True
-        if self._wire_caps:
-            hello["caps"] = list(self._wire_caps)
-        try:
-            sock.sendall(encode(hello))
-            line = rfile.readline(MAX_MESSAGE_BYTES + 2)
-            if not line:
-                raise ConnectionError(f"shard {conn.shard} closed during handshake")
-            reply = decode(line)
-        except BaseException:
-            try:
-                sock.close()
-            except OSError:
-                pass
-            raise
-        if reply.get("type") != "welcome":
-            try:
-                sock.close()
-            except OSError:
-                pass
-            if reply.get("type") == "error" and reply.get("code") == "auth":
-                raise AuthError(
-                    f"shard {conn.shard} rejected credentials: {reply.get('reason')}")
-            raise ConnectionError(f"shard {conn.shard} rejected worker: {reply}")
-        conn.caps = parse_caps(reply)
-        conn.boot_id = reply.get("boot_id")
-        with conn.write_lock:
-            conn.sock, conn.rfile = sock, rfile
-        conn.gen += 1
-        conn.handshaken = True
-        self._handshaken.set()
-        self._last_batch_end = None  # reconnect gap ≠ dispatch bubble
-        logger.info("worker %s connected to shard %s", self.worker_id, conn.shard)
-
-    def _close_conn(self, conn: _ShardConn) -> None:
-        conn.handshaken = False
-        with conn.write_lock:
-            sock, conn.sock, conn.rfile = conn.sock, None, None
-        if sock is not None:
-            try:
-                sock.close()
-            except OSError:
-                pass
-
-    def _graceful_close_conn(self, conn: _ShardConn) -> None:
-        """Teardown close for one shard: FIN, drain, close — the same
-        RST-avoidance dance as :meth:`_graceful_close`."""
-        conn.handshaken = False
-        with conn.write_lock:
-            sock, conn.sock, conn.rfile = conn.sock, None, None
-        if sock is None:
-            return
-        try:
-            sock.shutdown(socket.SHUT_WR)
-            sock.settimeout(2.0)
-            while sock.recv(4096):
-                pass
-        except OSError:
-            pass
-        finally:
-            try:
-                sock.close()
-            except OSError:
-                pass
-
     def _heartbeat_loop(self) -> None:
         """Pings from a side thread keep liveness visible during training.
 
@@ -798,24 +603,6 @@ class GentunClient:
         """
         while not self._stop.is_set():
             time.sleep(self.heartbeat_interval)
-            if self._conns:
-                # Multi-home fan-out: ping every live shard on ITS OWN
-                # connection (liveness is per-shard — one stalled shard
-                # must not mark this worker stale everywhere).  Beat on
-                # any delivered ping: the worker process is alive iff at
-                # least one shard can hear it.
-                delivered = False
-                for conn in list(self._conns):
-                    if conn.dead or not conn.handshaken:
-                        continue
-                    try:
-                        self._send_conn(conn, {"type": "ping"})
-                    except Exception:
-                        continue  # that shard's manager will redial
-                    delivered = True
-                if delivered:
-                    _health.beat("worker_heartbeat")
-                continue
             if not self._handshaken.is_set():
                 continue
             inj = self._injector
@@ -883,13 +670,26 @@ class GentunClient:
             register_publish_hook(self._compile_client.publish_hook)
         backoff = _ReconnectBackoff(self.reconnect_delay, self.reconnect_max_delay, self.worker_id)
         try:
-            if self._addrs is not None:
-                # Multi-homed consume (ISSUE 18): one manager thread per
-                # shard feeds a shared ready-queue; reconnect/backoff state
-                # lives per connection inside each _ShardConn.
-                self._work_multihome(stop, max_jobs)
-            else:
-                self._work_single(stop, max_jobs, backoff)
+            while (not stop.is_set() and not self._drain_req.is_set()
+                   and (max_jobs is None or self._jobs_done < max_jobs)):
+                try:
+                    self._connect()
+                    backoff.reset()  # a completed handshake re-arms the base delay
+                    self._consume(stop, max_jobs)
+                except AuthError:
+                    # Deterministic rejection: reconnecting with the same
+                    # token can never succeed, so fail loudly instead of
+                    # spinning in the reconnect loop forever.
+                    logger.error("worker %s: broker rejected credentials; giving up", self.worker_id)
+                    raise
+                except (ConnectionError, OSError, ProtocolError) as e:
+                    if (stop.is_set() or self._drain_req.is_set()
+                            or (max_jobs is not None and self._jobs_done >= max_jobs)):
+                        break
+                    delay = backoff.next_delay()
+                    logger.info("worker %s reconnecting in %.2gs after: %s", self.worker_id, delay, e)
+                    self._close()
+                    time.sleep(delay)
         finally:
             self._stop.set()
             self._graceful_close()
@@ -909,170 +709,6 @@ class GentunClient:
             if self.multihost:
                 self._mh.broadcast_payload(None)  # release the followers
         return self._jobs_done
-
-    def _work_single(self, stop: threading.Event, max_jobs: Optional[int],
-                     backoff: _ReconnectBackoff) -> None:
-        """The single-connection consume/reconnect loop — the historical
-        ``work()`` body, bit-identical frame flow."""
-        while (not stop.is_set() and not self._drain_req.is_set()
-               and (max_jobs is None or self._jobs_done < max_jobs)):
-            try:
-                self._connect()
-                backoff.reset()  # a completed handshake re-arms the base delay
-                self._consume(stop, max_jobs)
-            except AuthError:
-                # Deterministic rejection: reconnecting with the same
-                # token can never succeed, so fail loudly instead of
-                # spinning in the reconnect loop forever.
-                logger.error("worker %s: broker rejected credentials; giving up", self.worker_id)
-                raise
-            except (ConnectionError, OSError, ProtocolError) as e:
-                if (stop.is_set() or self._drain_req.is_set()
-                        or (max_jobs is not None and self._jobs_done >= max_jobs)):
-                    break
-                delay = backoff.next_delay()
-                logger.info("worker %s reconnecting in %.2gs after: %s", self.worker_id, delay, e)
-                self._close()
-                time.sleep(delay)
-
-    def _work_multihome(self, stop: threading.Event,
-                        max_jobs: Optional[int]) -> None:
-        """Multi-homed consume (ISSUE 18): one manager thread per shard.
-
-        Each :class:`_ShardConn` gets a daemon manager that owns its
-        connect/receive/redial cycle end to end and feeds decoded batches
-        into ONE shared ready-queue tagged ``(conn, gen, batch)``; this
-        thread evaluates from the queue, acks each batch's credit back to
-        the shard that dispatched it, and never blocks on any single
-        shard's link — the per-shard independence the sharding design
-        requires (a SIGKILLed shard costs only its own in-flight window,
-        which its journal requeues).
-        """
-        import queue as _queue
-
-        ready_q: "_queue.Queue" = _queue.Queue()
-        self._conns = [
-            _ShardConn(host, port, _ReconnectBackoff(
-                self.reconnect_delay, self.reconnect_max_delay,
-                f"{self.worker_id}:{host}:{port}"))
-            for host, port in (self._addrs or ())
-        ]
-        _get_registry().gauge(
-            "worker_homes", worker=self.worker_id).set(len(self._conns))
-        for conn in self._conns:
-            threading.Thread(
-                target=self._shard_manager, args=(conn, stop, ready_q),
-                name=f"gentun-shard-{conn.shard}", daemon=True).start()
-        try:
-            self._consume_multihome(stop, max_jobs, ready_q)
-        finally:
-            self._stop.set()  # managers: no more redials
-            for conn in self._conns:
-                self._graceful_close_conn(conn)
-            # The shared send path may still point at a closed shard
-            # socket; null it so work()'s _graceful_close is a no-op.
-            self._sock = None
-            self._rfile = None
-
-    def _shard_manager(self, conn: _ShardConn, stop: threading.Event,
-                       ready_q) -> None:
-        """Own one shard's connection: dial, handshake, advertise the full
-        credit window, then pump decoded batches into the shared queue.
-        Redials under the conn's OWN backoff — a flapping shard inflates
-        only its own delay (satellite regression: test_shard.py)."""
-        while not (stop.is_set() or self._stop.is_set()
-                   or self._drain_req.is_set()):
-            try:
-                self._connect_conn(conn)
-                conn.backoff.reset()
-                # Per-broker credit (ISSUE 18): each shard gets this
-                # worker's FULL window — the worker picks work first-ready
-                # across shards, so per-shard under-use costs nothing,
-                # while a partitioned advertisement would idle the worker
-                # whenever one shard had no tenants.
-                self._send_conn(conn, {
-                    "type": "ready",
-                    "credit": self.capacity + self.prefetch_depth})
-                gen = conn.gen
-                rfile = conn.rfile  # pin: never read a future connection
-                while True:
-                    msg = self._recv(rfile=rfile)
-                    if msg["type"] in ("jobs", "jobs2"):
-                        jobs = (list(msg["jobs"]) if msg["type"] == "jobs"
-                                else expand_jobs2(msg))
-                        for chunk in self._chunk_jobs(jobs):
-                            ready_q.put((conn, gen, chunk))
-                    elif msg["type"] != "welcome":
-                        logger.warning("unexpected message %r", msg["type"])
-            except AuthError as e:
-                # Terminal for THIS shard only: a healthy shard keeps this
-                # worker alive; the consume loop raises only when every
-                # shard has rejected us.
-                conn.dead = True
-                logger.error("worker %s: shard %s rejected credentials",
-                             self.worker_id, conn.shard)
-                ready_q.put((conn, conn.gen, e))
-                return
-            except (ConnectionError, OSError, ProtocolError) as e:
-                if (stop.is_set() or self._stop.is_set()
-                        or self._drain_req.is_set()):
-                    break
-                self._close_conn(conn)
-                delay = conn.backoff.next_delay()
-                logger.info("worker %s reconnecting to shard %s in %.2gs after: %s",
-                            self.worker_id, conn.shard, delay, e)
-                if stop.wait(delay):
-                    break
-
-    def _consume_multihome(self, stop: threading.Event,
-                           max_jobs: Optional[int], ready_q) -> None:
-        import queue as _queue
-
-        while not stop.is_set() and (max_jobs is None or self._jobs_done < max_jobs):
-            _health.beat("worker_consume")
-            if self._drain_req.is_set():
-                # Drain fan-out: hand every locally-queued batch back to
-                # the shard that dispatched it, and announce the drain on
-                # EVERY live connection so no shard redispatches here.
-                returned: Dict[str, List[str]] = {}
-                while True:
-                    try:
-                        conn, gen, item = ready_q.get_nowait()
-                    except _queue.Empty:
-                        break
-                    if isinstance(item, list) and gen == conn.gen:
-                        returned.setdefault(conn.shard, []).extend(
-                            str(j["job_id"]) for j in item if "job_id" in j)
-                for conn in self._conns:
-                    if conn.dead or not conn.handshaken:
-                        continue
-                    self._announce_drain(returned.get(conn.shard, []), conn=conn)
-                return
-            try:
-                conn, gen, item = ready_q.get(timeout=0.25)
-            except _queue.Empty:
-                continue
-            if isinstance(item, BaseException):
-                if all(c.dead for c in self._conns):
-                    raise item  # every shard rejected this worker
-                continue
-            if gen != conn.gen or conn.sock is None:
-                # Stale batch from a dead connection: the broker already
-                # requeued these jobs at disconnect — evaluating them here
-                # would only duplicate work the fleet is redoing.
-                continue
-            self._bind_conn(conn)
-            try:
-                self._evaluate_batch(item)
-                # Replenish exactly this batch's credit AT ITS SHARD.
-                self._send_conn(conn, {"type": "ready", "credit": len(item)})
-            except (ConnectionError, OSError, ProtocolError) as e:
-                logger.info("worker %s: shard %s link lost mid-batch: %s",
-                            self.worker_id, conn.shard, e)
-                if conn.gen == gen:
-                    # Gen guard: the manager may have redialed already —
-                    # never close a NEWER connection than the one we used.
-                    self._close_conn(conn)
 
     def _ops_status(self) -> Dict[str, Any]:
         """The ``/statusz`` "worker" block when the ops plane runs inside
@@ -1108,15 +744,6 @@ class GentunClient:
             out["fitness_service"] = self._cache_client.stats()
         if self._compile_client is not None:
             out["compile_cache"] = self._compile_client.stats()
-        if self._conns:
-            # Multi-home panel (ISSUE 18): one row per shard connection.
-            out["homes"] = [{
-                "shard": c.shard,
-                "connected": c.handshaken,
-                "dead": c.dead,
-                "boot_id": c.boot_id,
-                "wire_caps_granted": sorted(c.caps),
-            } for c in self._conns]
         return out
 
     # -- elastic membership -------------------------------------------------
@@ -1184,11 +811,9 @@ class GentunClient:
         except OSError:
             pass  # reconnect hello re-advertises everything anyway
 
-    def _announce_drain(self, unstarted_job_ids: List[str],
-                        conn: Optional[_ShardConn] = None) -> None:
+    def _announce_drain(self, unstarted_job_ids: List[str]) -> None:
         """Send the ``drain`` frame; never raises (broker death during a
-        drain just means the disconnect requeue does the whole job).
-        ``conn`` routes the frame to ONE shard in multi-home mode."""
+        drain just means the disconnect requeue does the whole job)."""
         frame: Dict[str, Any] = {"type": "drain",
                                  "requeue": list(unstarted_job_ids)}
         if self._drain_reason != "drain":
@@ -1196,15 +821,11 @@ class GentunClient:
             # operator drain's frame is byte-identical to before.
             frame["reason"] = self._drain_reason
         try:
-            if conn is not None:
-                self._send_conn(conn, frame)
-            else:
-                self._send(frame)
+            self._send(frame)
         except OSError:
             pass
-        logger.info("worker %s draining: returned %d queued job(s)%s",
-                    self.worker_id, len(unstarted_job_ids),
-                    f" to shard {conn.shard}" if conn is not None else "")
+        logger.info("worker %s draining: returned %d queued job(s)",
+                    self.worker_id, len(unstarted_job_ids))
 
     def _work_follower(self) -> int:
         """Non-leader ranks: evaluate what the leader broadcasts, reply never.

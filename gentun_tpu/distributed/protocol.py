@@ -106,21 +106,6 @@ load-bearing for correctness):
   worker that never sends the field behaves — and is dispatched to —
   exactly as before.
 
-Multi-home field (same OPTIONAL convention — pure observability, never
-load-bearing for correctness):
-
-- ``hello`` may carry ``homes`` (int): how many broker SHARDS this
-  worker multi-homed to (horizontal sharding, ISSUE 18 — DISTRIBUTED.md
-  "Horizontal broker sharding").  Only sent when > 1, so a single-homed
-  worker's hello stays byte-identical.  The broker records it per worker
-  (``/statusz`` fleet table, ``worker_homes{worker}`` gauge) so
-  operators reading per-shard capacity sums know a 2-homed capacity-8
-  worker legitimately shows 8 on BOTH shards.  Credit stays per
-  connection exactly as before — each shard grants against the window
-  the worker advertised to IT, and the worker replenishes each batch's
-  credit at the shard that dispatched it.  Absent or malformed degrades
-  to 1, never a dropped connection.
-
 Multi-fidelity field (same OPTIONAL-with-conservative-default convention):
 
 - each ``jobs`` entry may carry ``fidelity`` {v, rung, fingerprint}: the

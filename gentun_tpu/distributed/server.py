@@ -175,7 +175,6 @@ class DistributedPopulation(Population):
         session_quota: Optional[int] = None,
         cache_namespace: Optional[str] = None,
         aggregator_url: Optional[str] = None,
-        broker_urls: Optional[list] = None,
     ):
         if failed_policy not in ("raise", "penalize"):
             raise ValueError(f"unknown failed_policy {failed_policy!r}")
@@ -244,29 +243,9 @@ class DistributedPopulation(Population):
         #: populated by every evaluate() call: {"attempts", "retries",
         #: "penalized"} — the GA merges it into the generation history.
         self.eval_stats: Dict[str, int] = {}
-        if broker is not None and broker_urls:
-            raise ValueError("pass broker= OR broker_urls=, not both")
         if broker is not None:
             self.broker = broker
             self._owns_broker = False
-        elif broker_urls:
-            # Horizontal sharding (ISSUE 18): this master is a TENANT of
-            # N operator-run broker shards — its session is consistent-
-            # hashed to ONE home shard and every broker call goes over
-            # the wire through the ShardedBroker facade.  Broker-process
-            # knobs (heartbeat_timeout, max_attempts, stragglers, fault
-            # injection) belong to the shard operators, not this ctor.
-            if fault_injector is not None:
-                raise ValueError(
-                    "fault_injector requires an embedded broker, not broker_urls")
-            from .shard import ShardedBroker
-
-            self.broker = ShardedBroker(
-                broker_urls, token=password,
-                retry_window=max(60.0, float(job_timeout or 0.0)))
-            # "Owns" the facade (close() must drop its shard connections);
-            # the shard broker PROCESSES are operator-owned and outlive us.
-            self._owns_broker = True
         else:
             self.broker = JobBroker(
                 host=host,
@@ -317,17 +296,10 @@ class DistributedPopulation(Population):
                 # Flush the write-behind queue so the LAST generation's
                 # measurements reach the service too, then stop the flusher.
                 self._cache_client.close()
-            from .shard import ShardedBroker
-
-            if self._session_arg is not None and (
-                    not self._owns_broker
-                    or isinstance(self.broker, ShardedBroker)):
+            if self._session_arg is not None and not self._owns_broker:
                 # Release this tenant's slot on the SHARED broker so its
                 # fair-share weight stops diluting the neighbors.  (An
-                # owned broker is stopping anyway; idempotent either way.
-                # A ShardedBroker facade is "owned" but the shard broker
-                # PROCESSES are shared — the session must close remotely
-                # or its weight dilutes the shard's other tenants forever.)
+                # owned broker is stopping anyway; idempotent either way.)
                 self.broker.close_session(self._session_arg)
             if self._owns_broker:
                 self.broker.stop()

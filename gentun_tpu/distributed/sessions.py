@@ -443,53 +443,13 @@ class SessionClient:
     re-attach, which also flushes any results that parked broker-side
     during the gap).  Only jobs submitted DURING the outage are lost to
     the caller (``submit`` raises), matching at-least-once semantics.
-
-    With ``broker_urls=[...]`` (ISSUE 18, horizontal sharding) the client
-    becomes a ROUTER over N broker shards: each session is homed on
-    ``ShardRing.home(session_id)`` and every call for that session goes to
-    one lazily-dialed child ``SessionClient`` per shard.  A one-element
-    ``broker_urls`` collapses to the plain single-socket path — wire
-    byte-identical to passing ``host``/``port`` directly (asserted by
-    ``scripts/shard_study.py``).
     """
 
-    def __init__(self, host: Optional[str] = None, port: int = 0,
+    def __init__(self, host: str, port: int,
                  token: Optional[str] = None,
                  timeout: float = 10.0, reconnect: bool = False,
                  reconnect_window: float = 60.0,
-                 reconnect_max_delay: float = 5.0,
-                 broker_urls: Optional[list] = None):
-        if broker_urls:
-            from .shard import ShardRing, ShardRouter, parse_broker_urls, shard_id
-
-            if host is not None:
-                raise ValueError("pass host/port OR broker_urls, not both")
-            addrs = parse_broker_urls(broker_urls)
-            if len(addrs) == 1:
-                # Single-URL deployment: fall through to the exact
-                # host/port path below — no ring, no router, no behavior
-                # or wire-byte difference from today.
-                host, port = addrs[0]
-            else:
-                self.host, self.port, self.token = None, 0, token
-                self._timeout = float(timeout)
-                self._reconnect = bool(reconnect)
-                self._reconnect_window = float(reconnect_window)
-                self._reconnect_max_delay = float(reconnect_max_delay)
-                self._by_shard = {shard_id(a): a for a in addrs}
-                self._ring = ShardRing(list(self._by_shard))
-                self._router = ShardRouter(self._ring)
-                self._children: Dict[str, "SessionClient"] = {}
-                self._child_lock = threading.Lock()
-                #: session -> home shard label (router placements).
-                self._session_home: Dict[str, str] = {}
-                #: job -> home shard label, for wait_any/cancel routing.
-                self._job_home: Dict[str, str] = {}
-                self._user_closed = False
-                return
-        elif host is None:
-            raise TypeError("SessionClient needs host/port or broker_urls")
-        self._ring = None  # single-broker mode marker
+                 reconnect_max_delay: float = 5.0):
         self.host, self.port, self.token = host, int(port), token
         self._timeout = float(timeout)
         self._reconnect = bool(reconnect)
@@ -686,65 +646,11 @@ class SessionClient:
                     raise TimeoutError(f"no {rtype!r} reply within {timeout}s")
                 self._cond.wait(timeout=min(remaining, 0.5))
 
-    # -- shard routing (ISSUE 18) ------------------------------------------
-
-    def _child(self, shard: str) -> "SessionClient":
-        """The lazily-dialed child client for one shard (router mode).  A
-        child whose reconnect window expired is permanently closed — drop
-        it so the next call dials fresh (the shard may be back by now)."""
-        with self._child_lock:
-            child = self._children.get(shard)
-            if child is not None and child._closed and not child._user_closed:
-                try:
-                    child.close()
-                except OSError:
-                    pass
-                child = None
-            if child is None:
-                host, port = self._by_shard[shard]
-                child = SessionClient(
-                    host, port, token=self.token, timeout=self._timeout,
-                    reconnect=self._reconnect,
-                    reconnect_window=self._reconnect_window,
-                    reconnect_max_delay=self._reconnect_max_delay)
-                self._children[shard] = child
-            return child
-
-    def _home_of(self, session_id: str) -> str:
-        sid = str(session_id)
-        home = self._session_home.get(sid)
-        if home is None:
-            home = self._router.place(sid)
-            self._session_home[sid] = home
-        return home
-
-    def _jobs_by_shard(self, job_ids: List[str]) -> Dict[str, List[str]]:
-        groups: Dict[str, List[str]] = {}
-        for j in job_ids:
-            shard = self._job_home.get(str(j))
-            if shard is None:
-                # Unknown id (another client submitted it): ask every
-                # DIALED shard — at most a wasted table lookup each.
-                with self._child_lock:
-                    dialed = list(self._children)
-                for s in dialed or list(self._by_shard):
-                    groups.setdefault(s, []).append(str(j))
-            else:
-                groups.setdefault(shard, []).append(str(j))
-        return groups
-
     # -- tenant API --------------------------------------------------------
 
     def open_session(self, session_id: Optional[str] = None, weight: float = 1.0,
                      max_in_flight: Optional[int] = None,
                      tag: Optional[str] = None) -> str:
-        if self._ring is not None:
-            # Mint the id client-side when absent: placement needs the id
-            # before the wire does.
-            sid = str(session_id) if session_id else f"s-{uuid.uuid4().hex[:12]}"
-            self._child(self._home_of(sid)).open_session(
-                sid, weight=weight, max_in_flight=max_in_flight, tag=tag)
-            return sid
         msg: Dict[str, Any] = {"type": "session_open", "weight": float(weight)}
         if session_id:
             msg["session"] = str(session_id)
@@ -767,13 +673,6 @@ class SessionClient:
         return sid
 
     def close_session(self, session_id: str) -> None:
-        if self._ring is not None:
-            sid = str(session_id)
-            shard = self._session_home.pop(sid, None)
-            self._router.forget(sid)
-            if shard is not None:
-                self._child(shard).close_session(sid)
-            return
         with self._cond:
             since = self._error_seq
         self._send({"type": "session_close", "session": str(session_id)})
@@ -783,9 +682,6 @@ class SessionClient:
     def detach(self, session_id: str) -> None:
         """Stop receiving this session's results (they park broker-side in
         the session's bounded undelivered queue until someone re-attaches)."""
-        if self._ring is not None:
-            self._child(self._home_of(session_id)).detach(session_id)
-            return
         with self._cond:
             since = self._error_seq
         self._send({"type": "session_detach", "session": str(session_id)})
@@ -795,12 +691,6 @@ class SessionClient:
         """Ship jobs into a session; returns the job ids (caller-supplied
         keys).  A rejected session surfaces via :meth:`wait_any` failures
         or :meth:`last_error` — the error frame is asynchronous."""
-        if self._ring is not None:
-            shard = self._home_of(session_id)
-            ids = self._child(shard).submit(session_id, payloads)
-            for j in ids:
-                self._job_home[j] = shard
-            return ids
         jobs = [{"job_id": job_id, **payload} for job_id, payload in payloads.items()]
         self._send({"type": "submit", "session": str(session_id), "jobs": jobs})
         return [str(j["job_id"]) for j in jobs]
@@ -808,15 +698,6 @@ class SessionClient:
     def cancel(self, job_ids: List[str]) -> None:
         """Best-effort cancel of not-yet-dispatched jobs (the broker's
         ``cancel`` frame; fire-and-forget, like the in-process call)."""
-        if self._ring is not None:
-            for shard, ids in self._jobs_by_shard(job_ids).items():
-                try:
-                    self._child(shard).cancel(ids)
-                except (ConnectionError, OSError):
-                    continue  # a dead shard's queue dies with it
-                for j in ids:
-                    self._job_home.pop(j, None)
-            return
         self._send({"type": "cancel", "jobs": [str(j) for j in job_ids]})
 
     def session_stats(self, session_id: Optional[str] = None,
@@ -826,10 +707,6 @@ class SessionClient:
         ``prefetch`` are the session's weighted fleet share; ``mesh_pop``
         and ``chips`` are fleet-wide facts.  ``reset_chips=True`` starts a
         fresh chips-seen observation window broker-side first."""
-        if self._ring is not None:
-            sid = str(session_id) if session_id else DEFAULT_SESSION
-            return self._child(self._home_of(sid)).session_stats(
-                sid, reset_chips=reset_chips)
         msg: Dict[str, Any] = {"type": "session_stats"}
         if session_id:
             msg["session"] = str(session_id)
@@ -846,8 +723,6 @@ class SessionClient:
                  ) -> Tuple[Dict[str, float], Dict[str, str]]:
         """Block until ≥1 of ``job_ids`` is terminal; ``(results, failures)``
         drained from the client table (same contract as the broker's)."""
-        if self._ring is not None:
-            return self._wait_any_routed(job_ids, timeout)
         deadline = None if timeout is None else time.monotonic() + timeout
         want = set(job_ids)
         with self._cond:
@@ -865,53 +740,14 @@ class SessionClient:
                     return {}, {}
                 self._cond.wait(timeout=min(remaining, 0.5) if remaining is not None else 0.5)
 
-    def _wait_any_routed(self, job_ids: List[str],
-                         timeout: Optional[float] = None
-                         ) -> Tuple[Dict[str, float], Dict[str, str]]:
-        """Router-mode wait_any.  One session's jobs live on ONE shard, so
-        the common case is a single group and a full-timeout delegate; ids
-        spanning shards poll each home in short slices."""
-        deadline = None if timeout is None else time.monotonic() + timeout
-        groups = self._jobs_by_shard(job_ids)
-        if not groups:
-            return {}, {}
-        while True:
-            for shard, ids in groups.items():
-                if len(groups) == 1:
-                    slice_t = (None if deadline is None
-                               else max(0.0, deadline - time.monotonic()))
-                else:
-                    slice_t = 0.05
-                r, f = self._child(shard).wait_any(ids, timeout=slice_t)
-                if r or f:
-                    for j in list(r) + list(f):
-                        self._job_home.pop(j, None)
-                    return r, f
-            if deadline is not None and time.monotonic() >= deadline:
-                return {}, {}
-
     def last_error(self) -> Optional[Dict[str, Any]]:
         """The most recent structured ``error`` frame, if any (satellite:
         unknown-session submits answer with one instead of silence)."""
-        if self._ring is not None:
-            with self._child_lock:
-                children = list(self._children.values())
-            for child in children:
-                err = child.last_error()
-                if err is not None:
-                    return err
-            return None
         with self._cond:
             return self._errors[-1] if self._errors else None
 
     def close(self) -> None:
         self._user_closed = True
-        if self._ring is not None:
-            with self._child_lock:
-                children, self._children = dict(self._children), {}
-            for child in children.values():
-                child.close()
-            return
         try:
             self._sock.close()
         except OSError:

@@ -124,14 +124,6 @@ def main(argv=None) -> int:
     )
     ap.add_argument("--host", default="127.0.0.1", help="master broker host")
     ap.add_argument("--port", type=int, default=5672, help="master broker port")
-    ap.add_argument("--broker-urls", default=None, metavar="HOST:PORT,...",
-                    help="comma-separated broker shard addresses (horizontal "
-                         "sharding — DISTRIBUTED.md 'Horizontal broker "
-                         "sharding').  The worker multi-homes: one "
-                         "connection, credit window, and backoff per shard, "
-                         "so a dead shard never blocks dispatch from healthy "
-                         "ones.  Overrides --host/--port; a single address "
-                         "behaves exactly like --host/--port")
     ap.add_argument("--password", default=None, help="broker shared token")
     ap.add_argument("--species", default="genetic-cnn", help="genetic-cnn | boosting | xgboost | lfm2-moe | deepseek-v2")
     ap.add_argument("--dataset", default="mnist",
@@ -383,8 +375,6 @@ def main(argv=None) -> int:
             fault_injector=injector,
             wire_caps=() if args.wire_v1 else None,
             preemptible=args.preempt,
-            broker_urls=([u.strip() for u in args.broker_urls.split(",") if u.strip()]
-                         if args.broker_urls else None),
         )
     except ValueError as e:
         # Config errors the CLI could not pre-validate — notably a --mesh

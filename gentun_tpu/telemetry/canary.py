@@ -192,16 +192,35 @@ class _Handler(BaseHTTPRequestHandler):
 # -- the daemon --------------------------------------------------------------
 
 
+def _parse_broker(broker) -> Tuple[str, int]:
+    """``(host, port)`` of the one broker address the daemon is given."""
+    if isinstance(broker, (tuple, list)) and len(broker) == 2 and not isinstance(broker[1], str):
+        host, port = str(broker[0]), broker[1]
+    elif isinstance(broker, str):
+        if "," in broker:
+            raise ValueError(f"one broker serves a fleet: {broker!r} names more than one address")
+        address = broker[6:] if broker.startswith("tcp://") else broker
+        host, _, port = address.strip().rpartition(":")
+    else:
+        raise ValueError(f"broker {broker!r} is not 'host:port' or (host, port)")
+    try:
+        port = int(port)
+    except (TypeError, ValueError):
+        raise ValueError(f"broker {broker!r} has a non-integer port") from None
+    if not host or not 0 < port < 65536:
+        raise ValueError(f"broker {broker!r} is not 'host:port'")
+    return host, port
+
+
 class CanaryDaemon:
     """Continuously probes a broker fleet with golden genomes.
 
     Parameters
     ----------
-    broker_urls:
-        Broker address list (``["tcp://h:p", ...]`` or ``"h:p,h:p"``) —
-        handed to :class:`~gentun_tpu.distributed.sessions.SessionClient`
-        verbatim, so a multi-shard list probes through the same
-        consistent-hash router tenants use.
+    broker:
+        The fleet's one broker: ``"host:port"`` (an optional ``tcp://``
+        scheme is tolerated) or a ``(host, port)`` pair.  A malformed
+        address, or more than one, is a ``ValueError``.
     probes:
         Known-answer probe payloads: each a dict with ``genes`` and
         (optionally) ``additional_parameters`` the fleet's species can
@@ -222,7 +241,7 @@ class CanaryDaemon:
 
     def __init__(
         self,
-        broker_urls,
+        broker,
         probes: List[Dict[str, Any]],
         space_key: str = "default",
         aggregator_url: Optional[str] = None,
@@ -236,11 +255,7 @@ class CanaryDaemon:
     ):
         if not probes:
             raise ValueError("CanaryDaemon needs at least one probe payload")
-        if isinstance(broker_urls, str):
-            broker_urls = [u for u in broker_urls.split(",") if u.strip()]
-        self.broker_urls = list(broker_urls)
-        if not self.broker_urls:
-            raise ValueError("CanaryDaemon needs at least one broker url")
+        self.broker_host, self.broker_port = _parse_broker(broker)
         self.probes = [dict(p) for p in probes]
         self.space_key = str(space_key)
         self.probe_interval = float(probe_interval)
@@ -296,8 +311,8 @@ class CanaryDaemon:
             target=self._loop, name="canary", daemon=True)
         self._thread.start()
         logger.info(
-            "canary serving on %s (brokers %s, %d probe(s), every %.1fs)",
-            self.url or "<no http>", ",".join(self.broker_urls),
+            "canary serving on %s (broker %s:%d, %d probe(s), every %.1fs)",
+            self.url or "<no http>", self.broker_host, self.broker_port,
             len(self.probes), self.probe_interval)
         return self
 
@@ -349,7 +364,7 @@ class CanaryDaemon:
         with self._client_lock:
             if self._client is None:
                 self._client = SessionClient(
-                    broker_urls=self.broker_urls, token=self.token,
+                    self.broker_host, self.broker_port, token=self.token,
                     timeout=min(10.0, self.probe_timeout), reconnect=True,
                     reconnect_window=self.probe_timeout)
             return self._client
@@ -532,7 +547,7 @@ class CanaryDaemon:
             "status": "ok",
             **self.stats(),
             "config": {
-                "broker_urls": self.broker_urls,
+                "broker": f"{self.broker_host}:{self.broker_port}",
                 "space_key": self.space_key,
                 "probes": len(self.probes),
                 "probe_interval": self.probe_interval,
@@ -565,10 +580,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=9093,
                     help="ops plane bind port (/healthz /statusz /canaryz)")
-    ap.add_argument("--broker-urls", required=True, metavar="URLS",
-                    help="comma-separated broker addresses, e.g. "
-                         "tcp://b0:5672,tcp://b1:5672 — multi-shard lists "
-                         "probe through the tenants' consistent-hash router")
+    ap.add_argument("--broker", required=True, metavar="HOST:PORT",
+                    help="the fleet's broker, e.g. tcp://b0:5672")
     ap.add_argument("--aggregator-url", default=None, metavar="URL",
                     help="fleet aggregator to push canary SLIs to (the "
                          "stock canary_error_burn/canary_latency/"
@@ -606,7 +619,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
             agg_url = parse_aggregator_url(args.aggregator_url)
         daemon = CanaryDaemon(
-            args.broker_urls, probes,
+            args.broker, probes,
             space_key=args.space_key,
             aggregator_url=agg_url,
             host=args.host, port=args.port,

@@ -819,7 +819,7 @@ def _measure_broker_rate(broker, n_jobs: int, n_workers: int,
     """Jobs/sec through ONE live broker with its own fresh workers.
 
     Workers are joined (not just signalled) before returning so the next
-    shard measured in a serial-isolation sweep gets the whole core."""
+    broker measured gets the whole core."""
     data = (np.zeros(1, np.float32), np.zeros(1, np.float32))
     rng = np.random.default_rng(0)
     payloads = {
@@ -858,106 +858,6 @@ def _measure_broker_rate(broker, n_jobs: int, n_workers: int,
             t.join(timeout=10.0)
 
 
-def run_shard_curve(n_jobs: int = 800, n_workers: int = 2,
-                    capacity: int = 16) -> dict:
-    """Aggregate throughput at 1/2/4 broker shards (DISTRIBUTED.md
-    "Horizontal broker sharding").
-
-    Every shard of a rung is RESIDENT simultaneously — asyncio loop,
-    listener socket and scheduler threads all alive — but each shard is
-    LOADED in serial isolation with its own fresh workers, and the
-    rung's aggregate is the sum of per-shard rates.  Rationale: this
-    host has very few cores (``nproc`` recorded below); wall-clock
-    concurrent shard stacks just timeslice one core (measured 1.03× for
-    two concurrent stacks), which would falsely report "sharding does
-    not scale".  Shards share no lock, event loop, socket or journal —
-    the broker has zero cross-shard coordination by construction — so
-    the sum of isolated rates is the aggregate a deployment with a core
-    per shard gets, while measuring with all shards resident still
-    charges each rate for its neighbours' memory and thread footprint.
-
-    Balance is the ring's own census over 512 synthetic session ids —
-    the placement skew a real fleet of masters would see."""
-    from gentun_tpu.distributed.shard import ShardRing, shard_id
-
-    out: dict = {
-        "methodology": (
-            "serial-isolation: all shards resident, each loaded alone "
-            "with fresh workers; aggregate = sum of per-shard rates "
-            "(shards share no state; concurrent wall-clock measurement "
-            "on a near-single-core host only measures timeslicing)"),
-        "nproc": os.cpu_count(),
-        "n_jobs_per_shard": n_jobs,
-        "n_workers_per_shard": n_workers,
-        "capacity": capacity,
-        "rungs": [],
-    }
-    n_keys = 512
-    for n_shards in (1, 2, 4):
-        brokers = [JobBroker(port=0).start() for _ in range(n_shards)]
-        try:
-            rates = [
-                _measure_broker_rate(b, n_jobs, n_workers, capacity)
-                for b in brokers
-            ]
-            ring = ShardRing([shard_id(b.address) for b in brokers])
-            shares = sorted(
-                ring.census(f"s-{i:04d}" for i in range(n_keys)).values())
-            out["rungs"].append({
-                "shards": n_shards,
-                "per_shard_jobs_per_sec": [round(r, 1) for r in rates],
-                "aggregate_jobs_per_sec": round(sum(rates), 1),
-                "ring_balance_min_share": round(shares[0] / n_keys, 3),
-                "ring_balance_max_share": round(shares[-1] / n_keys, 3),
-            })
-        finally:
-            for b in brokers:
-                b.stop()
-    r1 = out["rungs"][0]["aggregate_jobs_per_sec"]
-    r2 = out["rungs"][1]["aggregate_jobs_per_sec"]
-    out["scale_1_to_2"] = round(r2 / r1, 2)
-    out["gate_min_scale"] = 1.8
-    out["within_gate"] = out["scale_1_to_2"] >= 1.8
-    return out
-
-
-def run_shard_route_gate(per_job_dispatch_us: float) -> dict:
-    """Session→shard routing cost on the sharded submit path, micro-timed.
-
-    A sharded master hashes the session id onto the consistent-hash ring
-    (one blake2b digest + one bisect over the sorted vnode points) to
-    pick the home broker.  In production that happens once per submit
-    *batch*, but the gate bills it once per *job* — the conservative
-    worst case of single-job submits — and requires it to stay <=2% of
-    the measured per-job dispatch cost.  Same instrument as the other
-    gates: batched min-of-repeats divided by the forensics gate's
-    dispatch denominator."""
-    from gentun_tpu.distributed.shard import ShardRing
-
-    ring = ShardRing([f"10.0.0.{i}:7777" for i in range(4)])
-    keys = [f"s-{i:04d}" for i in range(2000)]
-    for k in keys:
-        ring.home(k)  # warm (allocator, bisect module, digest dispatch)
-
-    def _loop():
-        for k in keys:
-            ring.home(k)
-
-    reps, inner = 5, 10
-    t_s = min(timeit.repeat(_loop, number=inner, repeat=reps)) / (
-        inner * len(keys))
-    per_job_added_us = round(t_s * 1e6, 3)
-    overhead_pct = round(per_job_added_us / per_job_dispatch_us * 100.0, 3)
-    return {
-        "ring_shards": 4,
-        "per_job_added_us": per_job_added_us,
-        "per_job_dispatch_us": per_job_dispatch_us,
-        "overhead_pct": overhead_pct,
-        "gate_max_pct": 2.0,
-        "within_gate": overhead_pct <= 2.0,
-    }
-
-
 #: The control planes whose per-job cost rides the dispatch hot path and
 #: is therefore held to the 2% gate.  (artifact key, display name) —
 #: each `out[key]` block carries `per_job_added_us` / `overhead_pct`.
@@ -969,7 +869,6 @@ HOT_PATH_GATED_PLANES = (
     ("aggregator_push", "aggregator push scan"),
     ("journal", "dispatch journal (on)"),
     ("placement", "placement class check"),
-    ("shard_route", "shard route (ring home)"),
     ("packing", "window packer (pack on)"),
 )
 
@@ -1149,17 +1048,6 @@ def main() -> dict:
         f"({out['placement']['per_job_added_us']}us added on "
         f"{out['placement']['per_job_dispatch_us']}us/job dispatch)")
 
-    # Shard-route gate (DISTRIBUTED.md "Horizontal broker sharding"):
-    # the consistent-hash home() a sharded master pays per submit must
-    # also stay <=2% of per-job dispatch cost.  Same denominator again.
-    out["shard_route"] = run_shard_route_gate(
-        out["forensics"]["per_job_dispatch_us"])
-    assert out["shard_route"]["within_gate"], (
-        f"shard-route overhead {out['shard_route']['overhead_pct']}% "
-        f"exceeds the 2% gate ({out['shard_route']['per_job_added_us']}us "
-        f"added on {out['shard_route']['per_job_dispatch_us']}us/job "
-        f"dispatch)")
-
     # Window-packing gate (DISTRIBUTED.md "Cross-session window
     # packing"): the per-job pack-key + packer add/take bookkeeping a
     # pack_windows=True broker adds to the dispatch hot path must also
@@ -1169,16 +1057,6 @@ def main() -> dict:
         f"window-packer overhead {out['packing']['overhead_pct']}% "
         f"exceeds the 2% gate ({out['packing']['per_job_added_us']}us "
         f"added on {out['packing']['per_job_dispatch_us']}us/job dispatch)")
-
-    # Horizontal shard curve (DISTRIBUTED.md "Horizontal broker
-    # sharding"): aggregate throughput at 1/2/4 resident shards, each
-    # measured in serial isolation (see run_shard_curve's docstring for
-    # why wall-clock concurrency is the wrong instrument on this host).
-    # Gated at >=1.8x aggregate going 1 -> 2 shards.
-    out["shard_curve"] = run_shard_curve()
-    assert out["shard_curve"]["within_gate"], (
-        f"1->2 shard aggregate scaling {out['shard_curve']['scale_1_to_2']}x "
-        f"below the 1.8x gate: {out['shard_curve']['rungs']}")
 
     out["hot_path_table"] = hot_path_table(out)
     assert out["hot_path_table"]["within_gate"], (

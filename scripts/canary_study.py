@@ -134,7 +134,7 @@ def _probes(species=OneMax):
 
 
 def _daemon(port, probes, timeout=PROBE_TIMEOUT):
-    return CanaryDaemon([f"127.0.0.1:{port}"], probes, space_key="study",
+    return CanaryDaemon(f"127.0.0.1:{port}", probes, space_key="study",
                         probe_interval=999, probe_timeout=timeout,
                         serve_http=False)
 
@@ -501,7 +501,7 @@ def run_bit_identity() -> dict:
         try:
             # Free-running canary against the tenant's own broker — real
             # scheduler contention, not a staged one.
-            cn = CanaryDaemon([f"127.0.0.1:{port}"], _probes(),
+            cn = CanaryDaemon(f"127.0.0.1:{port}", _probes(),
                               space_key="study-bi", probe_interval=0.02,
                               probe_timeout=10.0, serve_http=False).start()
             ga = GeneticAlgorithm(pop, seed=GA_SEED)

@@ -1362,165 +1362,6 @@ def run_broker_kill() -> dict:
     }
 
 
-def run_shard_kill() -> dict:
-    """Shard-kill act (ISSUE 18, DISTRIBUTED.md "Horizontal broker
-    sharding"): two journaled broker shards, two concurrent generational
-    searches whose sessions the ring homes on DIFFERENT shards, two
-    multi-homed workers serving both — then the shard homing the first
-    search is SIGKILLed (``kill()``: journal buffer abandoned, not
-    flushed) mid-swarm and restarted on its port from its journal.
-    Proofs: the kill fired while work was in flight, the victim came
-    back at epoch 2, BOTH searches finish bit-identical to their no-kill
-    single-process references (zero lost searches — the healthy shard's
-    search must not even hiccup), and neither shard leaks state."""
-    from gentun_tpu.distributed.shard import (
-        ShardRing,
-        parse_broker_urls,
-        shard_id,
-    )
-
-    # -- no-kill references: one single-process run per concurrent search
-    pop_seeds = (POP_SEED, POP_SEED + 1)
-    clean_snaps = []
-    for seed in pop_seeds:
-        clean = GeneticAlgorithm(
-            Population(OneMax, *DATA, size=POP_SIZE, seed=seed), seed=GA_SEED)
-        clean.run(GENERATIONS)
-        clean_snaps.append(_snapshot(clean))
-
-    script_dir = os.path.dirname(os.path.abspath(__file__))
-    brokers, jpaths = [], []
-    for tag in ("shard0", "shard1"):
-        path = os.path.join(script_dir, f".chaos_shard_{tag}.journal")
-        for p in (path, path + ".snap"):
-            if os.path.exists(p):
-                os.unlink(p)
-        port = _free_port()  # fixed port: the restart must rebind it
-        brokers.append(JobBroker(port=port, journal_path=path,
-                                 journal_fsync_interval=0.01).start())
-        jpaths.append(path)
-    urls = [f"127.0.0.1:{b.address[1]}" for b in brokers]
-    by_shard = {shard_id(a): b
-                for a, b in zip(parse_broker_urls(urls), brokers)}
-
-    # Sessions the ring homes on DIFFERENT shards; the first search's
-    # home is the kill victim.
-    ring = ShardRing(list(by_shard))
-    homes = {}
-    for i in range(10_000):
-        sid = f"chaos-sess-{i:05d}"
-        homes.setdefault(ring.home(sid), sid)
-        if len(homes) == 2:
-            break
-    assert len(homes) == 2, "ring never split 10k keys across 2 shards"
-    sessions = [homes[s] for s in sorted(homes)]
-    victim = by_shard[ring.home(sessions[0])]
-    victim_url = ring.home(sessions[0])
-
-    def _mh_worker(worker_id):
-        stop = threading.Event()
-        client = GentunClient(
-            SlowishOneMax, *DATA, broker_urls=urls, worker_id=worker_id,
-            heartbeat_interval=0.2, reconnect_delay=0.05,
-            reconnect_max_delay=0.5,
-        )
-        t = threading.Thread(target=lambda: client.work(stop_event=stop),
-                             daemon=True)
-        t.start()
-        return stop
-
-    kill_info: dict = {}
-
-    def _kill_victim():
-        def _n():
-            jrn = victim._journal
-            return (jrn.status()["records_total"].get("c", 0)
-                    if jrn is not None else -1)
-        deadline = time.monotonic() + 60
-        while time.monotonic() < deadline and _n() < 6:
-            time.sleep(0.005)
-        kill_info["completes_at_kill"] = _n()
-        t_kill = time.monotonic()
-        victim.kill()
-        victim.start()
-        kill_info["restart_wall_s"] = round(time.monotonic() - t_kill, 3)
-
-    stops = [_mh_worker("shkill-w0"), _mh_worker("shkill-w1")]
-    search_errs: list = []
-    chaos_snaps: list = [None, None]
-    t0 = time.monotonic()
-    try:
-        pops = [
-            DistributedPopulation(
-                OneMax, size=POP_SIZE, seed=seed, broker_urls=urls,
-                session=sid, job_timeout=120)
-            for seed, sid in zip(pop_seeds, sessions)
-        ]
-        try:
-            killer = threading.Thread(target=_kill_victim, daemon=True)
-            killer.start()
-
-            def _search(idx):
-                try:
-                    ga = GeneticAlgorithm(pops[idx], seed=GA_SEED)
-                    ga.run(GENERATIONS)
-                    chaos_snaps[idx] = _snapshot(ga)
-                except BaseException as e:
-                    search_errs.append(f"search {idx}: {e!r}")
-
-            searchers = [threading.Thread(target=_search, args=(i,))
-                         for i in range(len(pops))]
-            for t in searchers:
-                t.start()
-            for t in searchers:
-                t.join(timeout=300)
-            killer.join(timeout=60)
-            wall = time.monotonic() - t0
-            assert not any(t.is_alive() for t in searchers), "search hung"
-            leaked = {u: b.outstanding() for u, b in zip(urls, brokers)}
-            victim_ops = victim._ops_status()
-        finally:
-            for pop in pops:
-                pop.close()
-    finally:
-        for s in stops:
-            s.set()
-        for b in brokers:
-            b.stop()
-        for path in jpaths:
-            for p in (path, path + ".snap"):
-                if os.path.exists(p):
-                    os.unlink(p)
-
-    assert search_errs == [], f"lost searches: {search_errs}"
-    assert "restart_wall_s" in kill_info, "shard kill never fired"
-    assert victim_ops["epoch"] == 2 and victim_ops["restarts"] == 1, victim_ops
-    identical = [c == s for c, s in zip(clean_snaps, chaos_snaps)]
-    assert all(identical), (
-        f"shard-kill run diverged from no-kill references: {identical}")
-    for url, out in leaked.items():
-        assert all(v == 0 for v in out.values()), \
-            f"leaked state on shard {url}: {out}"
-
-    return {
-        "generations": GENERATIONS,
-        "population_size": POP_SIZE,
-        "seeds": {"populations": list(pop_seeds), "ga": GA_SEED},
-        "shards": urls,
-        "sessions": sessions,
-        "victim_shard": victim_url,
-        "workers_multihomed": 2,
-        "kill": kill_info,
-        "victim_epoch_after_restart": victim_ops["epoch"],
-        "victim_restarts": victim_ops["restarts"],
-        "searches": len(sessions),
-        "searches_lost": 0,
-        "bit_identical_to_no_kill_references": identical,
-        "broker_state_after_final_gather": leaked,
-        "wall_s": round(wall, 3),
-    }
-
-
 def run_preemption_act() -> dict:
     """Preemption chaos act (DISTRIBUTED.md "Autoscaling & preemptible
     capacity"): a mostly-preemptible fleet under the full storm — two
@@ -1880,8 +1721,8 @@ def run_canary_act() -> dict:
       ``drift`` (detection latency 1 cycle);
     - **hang** — the worker hangs holding the probe job: the probe
       times out at stage ``result`` within 1 cycle of the hang;
-    - **broker kill** — the probe's home shard dies: stage ``open``
-      error within 1 cycle, and after a restarted shard + fresh worker
+    - **broker kill** — the broker dies: stage ``open``
+      error within 1 cycle, and after a restarted broker + fresh worker
       the canary self-recovers to ``ok`` (probe sessions are transient
       by design — nothing to re-adopt).
     """
@@ -1955,7 +1796,7 @@ def run_canary_act() -> dict:
     broker.stop()
     dead = cn.probe_once()
     assert dead["result"] == "error" and dead["stage"] == "open", dead
-    broker2 = JobBroker(port=port).start()  # shard restarted on its port
+    broker2 = JobBroker(port=port).start()  # restarted on its port
     stop = _worker(port, worker_id="cn-w3")
     recovered = None
     recovery_cycles = 0
@@ -2001,7 +1842,6 @@ if __name__ == "__main__":
     out["wire"] = run_wire_act()
     out["obs_agg"] = run_obs_agg()
     out["broker_kill"] = run_broker_kill()
-    out["shard_kill"] = run_shard_kill()
     out["preemption"] = run_preemption_act()
     out["packing"] = run_packing_act()
     out["canary"] = run_canary_act()

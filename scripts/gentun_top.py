@@ -243,11 +243,6 @@ def render(base: str, healthz, statusz, metrics_text, color: bool) -> str:
                     f"{w.get('backend') or '-'}"
                     + (f"  {Y}v1-wire{X}" if w.get("wire_caps") == [] else "")
                     + (f"  {D}PRE{X}" if w.get("preemptible") else "")
-                    # Multi-homed workers (horizontal sharding): this
-                    # shard sees the worker's FULL window, so divide the
-                    # capacity sums by ×N before totaling a campus.
-                    + (f"  {D}×{w['homes']}-homed{X}"
-                       if w.get("homes", 1) > 1 else "")
                     + (f"  {Y}DRAINING{X}" if w.get("draining") else ""))
         for s in fleet.get("stragglers", []):
             lines.append(f"  {Y}~ straggler {s['job_id']} on {s['worker_id']} "
@@ -314,35 +309,6 @@ def render(base: str, healthz, statusz, metrics_text, color: bool) -> str:
                      f"done {worker.get('jobs_done')}  "
                      f"{'connected' if worker.get('connected') else 'DISCONNECTED'}"
                      + (f"  {Y}DRAINING{X}" if worker.get("draining") else ""))
-        homes = worker.get("homes")
-        if homes:
-            # Per-shard panel (DISTRIBUTED.md "Horizontal broker
-            # sharding"): one row per home of a multi-homed worker — the
-            # per-shard link health a single "connected" flag flattens.
-            lines.append(f"  {D}{'shard':<22}{'link':>6}  boot{X}")
-            for h in homes:
-                link = (f"{R}DEAD{X}" if h.get("dead")
-                        else (f"{G}up{X}" if h.get("connected")
-                              else f"{Y}down{X}"))
-                lines.append(
-                    f"  {str(h.get('shard', '?'))[:22]:<22}{link:>6}  "
-                    f"{h.get('boot_id') or '-'}"
-                    + (f"  {Y}v1-wire{X}"
-                       if h.get("wire_caps_granted") == [] else ""))
-
-    # Router shard panel (sharded master): per-shard session homes from
-    # the shard_sessions gauge, plus placement churn — present only when
-    # a ShardRouter runs in this process.
-    shard_sessions = _parse_labeled(metrics_text or "", "shard_sessions",
-                                    "shard")
-    if shard_sessions:
-        moved = _parse_counters(metrics_text or "").get(
-            "shard_rebalances_total", 0)
-        per = "  ".join(f"{s}={shard_sessions[s]:g}"
-                        for s in sorted(shard_sessions))
-        lines.append(f"{B}shards{X}  {len(shard_sessions)} in ring  "
-                     f"sessions {per}"
-                     + (f"  {Y}rebalanced {moved:g}{X}" if moved else ""))
 
     # Mesh panel (host-level mesh workers, DISTRIBUTED.md): the local
     # evaluation mesh's axis sizes — from the worker's /statusz block when

@@ -18,24 +18,66 @@ existing = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in existing:
     os.environ["XLA_FLAGS"] = (existing + " --xla_force_host_platform_device_count=8").strip()
 
+# The driver runs six xdist workers at once, and some tests start processes of their own.  OpenMP's and OpenBLAS's
+# pools (sklearn's HistGradientBoosting under ``models/boosting.py``, numpy) take every core each and spin at their
+# barriers: under that load ``tests/test_boosting_model.py`` took 1,019 s against 18 s alone (PR 45's junit tables in
+# CHANGES.md).  One thread a pool is what a worker can have; child processes inherit it.  setdefault: a developer
+# running one file alone may ask for more.
+os.environ.setdefault("OMP_NUM_THREADS", "1")
+
 import numpy as np
 import pytest
 
 
-def pytest_collection_modifyitems(config, items):
-    """Run the multi-process cluster tests (tests/test_multihost.py) LAST.
+#: The files that take about 100 s or more under the driver's command, longest first, with the seconds each took
+#: there (PR 45's second whole run on its builder's machine, 8 cores; CHANGES.md has the table of the last one).  A plain tuple: every xdist worker
+#: must collect the same order, so nothing here is measured at run time.
+LONGEST_FIRST = (
+    "test_benchmark_mel_faults.py",   # 378
+    "test_benchmark_lag_faults.py",   # 356
+    "test_benchmark_q3n_faults.py",   # 311
+    "test_carry_builder.py",          # 274
+    "test_benchmark_dsv2_faults.py",  # 260
+    "test_multihost.py",              # 227
+    "test_benchmark_q3n_correct.py",  # 216
+    "test_benchmark_lag_correct.py",  # 204
+    "test_examples.py",               # 196
+    "test_cnn_model.py",              # 182
+    "test_routed_family.py",          # 148
+    "test_mellum2.py",                # 141
+    "test_routed_family_steps.py",    # 130
+    "test_routed_family_shares.py",   # 126
+    "test_lfm2_moe.py",               # 115
+    "test_qwen3_next.py",             # ~110 (220 before its delta rule's tests got a file of their own)
+    "test_qwen3_next_delta.py",       # ~110
+    "test_routed_family_laguna.py",   # ~107 (200 with Mellum2's cases, which are now the next file)
+    "test_benchmark_mel_correct.py",  # 101
+    "test_deepseek_v2.py",            # 99
+    "test_laguna.py",                 # 98
+    "test_benchmark_dsv2_correct.py", # 95
+    "test_routed_family_mellum2.py",  # ~94
+    "test_parallel.py",               # 88
+)
 
-    They dominate tier-1 wall time (each spawns a real N-process jax CPU
-    cluster, ~2 min healthy and up to its 480 s join timeout when the box
-    is contended), and tier-1's 870 s budget (`scripts/run_tier1.sh`)
-    deliberately truncates the suite.  With alphabetical ordering the
-    truncation lands mid-cluster and silently kills the entire fast tail
-    (test_ops … test_xla_cache, >150 tests); slowest-last means the
-    budget truncates only the cluster tests themselves, and DOTS_PASSED
-    stays a meaningful floor for everything else.  Relative order within
-    each group is untouched.
+
+def pytest_configure(config):
+    """xdist hands a run's files out in the order they were collected, which ``LONGEST_FIRST`` decides; left to
+    itself (3.x) it sorts them by their number of tests first, which starts a file of six two-minute tests last."""
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(config, items):
+    """The longest files are collected first, longest first.
+
+    The driver runs ``pytest tests/ -p xdist -n 6 --dist loadfile`` under a time limit: a file is the unit a
+    worker takes.  Whatever starts last runs with the other workers idle, so the run ends soonest when the longest
+    file starts first (``test_multihost.py``'s real 2- and 4-process clusters are one such file; started last, as
+    it was, the run ended with one worker on it and five waiting).  The order within a file, and of the files not
+    named, is untouched.
     """
-    items.sort(key=lambda item: item.fspath.basename == "test_multihost.py")
+    rank = {name: i for i, name in enumerate(LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.fspath.basename, len(rank)))
 
 
 @pytest.fixture

@@ -329,7 +329,7 @@ class TestCanaryDaemon:
         with _broker() as broker:
             port = broker.address[1]
             _, stop, _ = _spawn_worker(OneMax, port, "cd-w0")
-            cn = CanaryDaemon([f"127.0.0.1:{port}"], _probes(1),
+            cn = CanaryDaemon(f"127.0.0.1:{port}", _probes(1),
                               space_key="onemax", probe_interval=999,
                               probe_timeout=15, serve_http=False)
             try:
@@ -353,7 +353,7 @@ class TestCanaryDaemon:
             port = broker.address[1]
             _, stop, _ = _spawn_worker(OneMax, port, "cd-w1",
                                        fault_injector=inj)
-            cn = CanaryDaemon([f"127.0.0.1:{port}"], _probes(1),
+            cn = CanaryDaemon(f"127.0.0.1:{port}", _probes(1),
                               space_key="onemax", probe_interval=999,
                               probe_timeout=15, serve_http=False)
             try:
@@ -369,7 +369,7 @@ class TestCanaryDaemon:
     def test_workerless_fleet_probes_error_not_hang(self):
         with _broker() as broker:
             port = broker.address[1]
-            cn = CanaryDaemon([f"127.0.0.1:{port}"], _probes(1),
+            cn = CanaryDaemon(f"127.0.0.1:{port}", _probes(1),
                               probe_interval=999, probe_timeout=0.5,
                               serve_http=False)
             try:
@@ -388,7 +388,7 @@ class TestCanaryDaemon:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
         s.close()
-        cn = CanaryDaemon([f"127.0.0.1:{port}"], _probes(1),
+        cn = CanaryDaemon(f"127.0.0.1:{port}", _probes(1),
                           probe_interval=999, probe_timeout=0.5,
                           serve_http=False)
         try:
@@ -402,7 +402,7 @@ class TestCanaryDaemon:
         with _broker() as broker:
             port = broker.address[1]
             _, stop, _ = _spawn_worker(OneMax, port, "cd-w2")
-            cn = CanaryDaemon([f"127.0.0.1:{port}"], _probes(1),
+            cn = CanaryDaemon(f"127.0.0.1:{port}", _probes(1),
                               probe_interval=999, probe_timeout=15,
                               serve_http=True)
             cn.start()
@@ -431,7 +431,7 @@ class TestCanaryDaemon:
             port = broker.address[1]
             _, stop, _ = _spawn_worker(OneMax, port, "cd-w3")
             try:
-                cn = CanaryDaemon([f"127.0.0.1:{port}"], _probes(1),
+                cn = CanaryDaemon(f"127.0.0.1:{port}", _probes(1),
                                   space_key="onemax", probe_interval=999,
                                   probe_timeout=15, golden_path=path,
                                   serve_http=False)
@@ -440,7 +440,7 @@ class TestCanaryDaemon:
                 cn.stop()
                 # A NEW daemon must verify against the persisted seal,
                 # not re-seal.
-                cn2 = CanaryDaemon([f"127.0.0.1:{port}"], _probes(1),
+                cn2 = CanaryDaemon(f"127.0.0.1:{port}", _probes(1),
                                    space_key="onemax", probe_interval=999,
                                    probe_timeout=15, golden_path=path,
                                    serve_http=False)
@@ -465,7 +465,7 @@ class TestCanaryDaemon:
             port = broker.address[1]
             _, stop, _ = _spawn_worker(OneMax, port, "cd-w4",
                                        fault_injector=inj)
-            cn = CanaryDaemon([f"127.0.0.1:{port}"], _probes(1),
+            cn = CanaryDaemon(f"127.0.0.1:{port}", _probes(1),
                               probe_interval=999, probe_timeout=15,
                               serve_http=False)
             try:
@@ -485,6 +485,6 @@ class TestCanaryDaemon:
 
     def test_needs_probes_and_brokers(self):
         with pytest.raises(ValueError):
-            CanaryDaemon(["127.0.0.1:1"], [], serve_http=False)
+            CanaryDaemon("127.0.0.1:1", [], serve_http=False)
         with pytest.raises(ValueError):
             CanaryDaemon([], _probes(1), serve_http=False)

@@ -1,26 +1,19 @@
-"""The routed family's second architecture (DeepSeek-V2-Lite through
-``models/lfm2_moe.py``) against its plain reference
-(``benchmark/families/deepseek_v2/reference.py``), at small sizes on the CPU.
+"""What is DeepSeek-V2-Lite's own among the routed family's tests (the second architecture through
+``models/lfm2_moe.py``, against ``benchmark/families/deepseek_v2/reference.py``, at small sizes on the CPU); what
+every architecture is held to (logits, loss with the balance term and gradients, two train steps, the shares with
+the shared experts counted once, the row buffer at top-6, refusals, the species, the scope rules) is in
+``test_routed_family*.py`` under ``deepseek_v2-`` ids.
 
-System and reference are compared in float32 on seeded weights: per layer kind
-and whole on logits, loss (with the balance term) and gradients; over two train
-steps; the share test ties the expert layer's cut to the uncut layer with the
-shared experts counted once.  Then what is the architecture's own: ``k_pe`` is
-one head, causality in both cores, the fused core at 192 / 128 in Pallas'
-interpret mode, YaRN's frequencies by hand, the router's rule, where the balance
-term's gradient goes, the row buffer at top-6, refusals, the species, the scopes,
-the counts of ``flops.py`` and the readers of the new per-layer metrics.
+Here: ``k_pe`` is one head, causality in both cores, the fused core at 192 / 128 in Pallas' interpret mode, YaRN's
+frequencies by hand, the router's rule, where the balance term's gradient goes, the grouped products' tiles, the
+published cut's arithmetic, the spans, the scopes of the lowered train step, the counts of ``flops.py`` and the
+readers of the per-layer metrics.
 """
 
 from __future__ import annotations
 
-import functools
-import importlib.util
-import json
 import math
-import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -28,173 +21,20 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import routed_ladder
-from gentun_tpu import DeepseekV2Individual, GeneticAlgorithm, Population, deepseek_v2_genome, lfm2_moe_genome
+import routed_family as F
+from gentun_tpu import deepseek_v2_genome
 from gentun_tpu.models import lfm2_moe as M
-from gentun_tpu.telemetry import spans
 from gentun_tpu.telemetry.registry import get_registry
+from routed_family import HIGHEST, STD, YARN, kernel_on_the_cpu  # noqa: F401  (the fixture)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
-FAMILY = os.path.join(BENCH, "families", "deepseek_v2")
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(f"dsv2_family_{os.path.basename(name)}", os.path.join(FAMILY, name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-R = _load("reference")
-flops = _load("flops")
-scope_rules = _load("scope_rules")
-
-YARN = dict(beta_fast=32, beta_slow=1, factor=40, mscale=0.707, mscale_all_dim=0.707,
-            original_max_position_embeddings=4096, type="yarn")
-MODEL = dict(hidden_size=32, num_hidden_layers=3, first_k_dense_replace=1, intermediate_size=48,
-             moe_intermediate_size=24, n_routed_experts=8, num_experts_per_tok=3, n_shared_experts=2,
-             held_experts=[2, 4], num_attention_heads=4, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
-             v_head_dim=8, vocab_size=64, rms_norm_eps=1e-6, rope_theta=10000.0,
-             rope_scaling={**YARN, "original_max_position_embeddings": 8}, train_steps=3)
-GENES = dict(log10_lr=-2.5, warmup_frac=0.5, weight_decay=0.1, beta2=0.95, aux_alpha=0.05)
-HIGHEST = jax.default_matmul_precision("highest")
-STD = 0.15  # narrow layers: wider weights, or the operators vanish beside the residual
-
-
-def model_kwargs(m=MODEL, **over):
-    """``Lfm2MoeModel``'s keyword arguments that make it the reference's model ``m``."""
-    kw = {k: m[k] for k in ("hidden_size", "intermediate_size", "moe_intermediate_size", "num_experts_per_tok",
-                            "n_shared_experts", "num_attention_heads", "kv_lora_rank", "qk_nope_head_dim",
-                            "qk_rope_head_dim", "v_head_dim", "vocab_size", "rope_theta", "rope_scaling", "train_steps")}
-    kw.update(layer_types=("latent_attention",) * m["num_hidden_layers"], num_dense_layers=m["first_k_dense_replace"],
-              num_experts=m["n_routed_experts"], held_experts=tuple(m["held_experts"]), norm_eps=m["rms_norm_eps"],
-              scoring_func="softmax", norm_topk_prob=False, balance_rule="aux_loss", tie_word_embeddings=False,
-              batch_sequences=2, eval_sequences=2, attn_block=8, compute_dtype="float32")
-    kw.update(over)
-    return kw
+A = F.ARCHS["deepseek_v2"]
+R, flops, scope_rules = A.R, A.flops, A.scope_rules
+MODEL, GENES = A.model, A.genes
 
 
 @pytest.fixture(scope="module")
 def tokens():
-    tok = np.random.default_rng(0).integers(0, 64, size=(10, 17)).astype(np.int32)
-    return tok[:, :-1], tok[:, 1:]
-
-
-def config_of(tokens, m=MODEL, **over) -> M.Lfm2MoeConfig:
-    return M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(m, **over)).config
-
-
-NO_BIAS = jnp.zeros((8, 8), jnp.float32)  # the state's bias: zeros, never read under the ``aux_loss`` rule
-
-LAYER_CASES = {"latent_routed_shared": {**MODEL, "num_hidden_layers": 1, "first_k_dense_replace": 0, "held_experts": [1, 5]},
-               "latent_dense": {**MODEL, "num_hidden_layers": 2, "held_experts": [1, 5]},  # a dense layer leads a routed one
-               "whole_cut": {**MODEL, "held_experts": [1, 5]}}
-
-
-@pytest.mark.parametrize("case", sorted(LAYER_CASES))
-def test_logits_loss_with_the_balance_term_and_gradients_match_the_reference(case, tokens):
-    m = LAYER_CASES[case]
-    cfg = config_of(tokens, m)
-    w = R.seeded_weights(m, 7, STD)
-    x, y = tokens[0][:2], tokens[1][:2]
-    alpha = 0.05
-
-    def system_loss(params):
-        logits, load, stats = M.forward(cfg, params, NO_BIAS, x, remat=True)
-        return M.token_loss(logits, y).mean() + alpha * stats.balance, (logits, load, stats)
-
-    def reference_loss(params):
-        out = [R.forward(m, params, xs) for xs in x]
-        nll = jnp.mean(jnp.stack([R.token_loss(o[0], ys) for o, ys in zip(out, y)]))
-        balance = sum(o[2] for o in out) / len(out)
-        return nll + alpha * balance, (jnp.stack([o[0] for o in out]), sum(o[1] for o in out), balance)
-
-    with HIGHEST:
-        (loss, (logits, load, stats)), grads = jax.jit(jax.value_and_grad(system_loss, has_aux=True))(w)
-        (ref_loss, (ref_logits, ref_load, ref_balance)), ref_grads = jax.jit(
-            jax.value_and_grad(reference_loss, has_aux=True))(w)
-    np.testing.assert_allclose(logits, ref_logits, atol=2e-5)
-    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
-    np.testing.assert_allclose(stats.balance, ref_balance, rtol=1e-6)
-    assert float(ref_balance) > 0.9 * (m["num_hidden_layers"] - m["first_k_dense_replace"])  # ~1 a routed layer
-    np.testing.assert_array_equal(load, ref_load)
-    assert int(stats.dropped) == 0 and int(stats.wide) == 0
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
-        np.testing.assert_allclose(g, r, atol=2e-6, rtol=1e-4, err_msg=jax.tree_util.keystr(path))
-        assert float(jnp.abs(r).max()) > 0 or "embed" in str(path), \
-            f"{jax.tree_util.keystr(path)}: the reference's gradient is all zero"
-
-
-def _program_steps(programs, weights, x, y, rows, steps, genes=GENES):
-    state = programs.init(jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
-    state = {**state, "params": jax.tree_util.tree_map(jnp.asarray, weights)}
-    losses, loads = [], []
-    for s in range(steps):
-        state, loss, held = programs.train_step(state, jnp.asarray(x), jnp.asarray(y), jnp.asarray(rows),
-                                                jnp.asarray(M.gene_vector(genes)), np.int32(s))
-        losses.append(float(loss))
-        loads.append(np.asarray(held))
-    return state, losses, loads
-
-
-def test_two_train_steps_match_the_reference(tokens):
-    x, y = tokens
-    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs())
-    assert programs.config.gene_names == tuple(deepseek_v2_genome().names) and "aux_loss" in \
-        jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
-    w = R.seeded_weights(MODEL, 5, STD)
-    rows = np.array([[0, 1], [2, 3], [4, 5]], np.int32)
-    with HIGHEST:
-        state, losses, loads = _program_steps(programs, w, x, y, rows, 2)
-        ref = R.train(MODEL, w, [(x[r], y[r]) for r in rows[:2]], GENES)
-    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)  # the balance term included
-    np.testing.assert_allclose(float(state["aux_loss"]), sum(ref["balances"]), rtol=1e-6)
-    for got, want in zip(loads, ref["loads"]):
-        np.testing.assert_array_equal(got, want[:, 2:4])
-    np.testing.assert_array_equal(np.asarray(state["rows"]), sum(l[:, 2:4] for l in ref["loads"]))
-    assert not np.asarray(state["bias"]).any(), "no bias and no rule outside the gradient"
-    for (path, a), b, start in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
-                                   jax.tree_util.tree_leaves(ref["weights"]), jax.tree_util.tree_leaves(w)):
-        change, ref_change = np.asarray(a) - start, np.asarray(b) - start
-        assert np.abs(ref_change).max() > 0, jax.tree_util.keystr(path)
-        np.testing.assert_allclose(change, ref_change, atol=3e-5, err_msg=jax.tree_util.keystr(path))
-    with HIGHEST:
-        got = programs.eval(state["params"], state["bias"], jnp.asarray(x), jnp.asarray(y), jnp.asarray([8, 9]))
-        want = R.eval_token_loss(MODEL, ref["weights"], x[8:10], y[8:10])
-    np.testing.assert_allclose(got, want, atol=2e-5)
-
-
-def test_the_eight_shares_and_the_shared_experts_once_add_up_to_the_uncut_layer(tokens):
-    """16 experts in 8 shares of 2: each share's program computes the operator,
-    the residual, the shared experts and its own routed experts' part; the routed
-    parts, with what every share computes alike counted once, are the uncut
-    reference's layer output."""
-    m = {**MODEL, "num_hidden_layers": 1, "first_k_dense_replace": 0, "n_routed_experts": 16, "num_experts_per_tok": 6}
-    x = tokens[0][:2]
-    uncut = {**m, "held_experts": [0, 16]}
-    w_all = R.seeded_weights(uncut, 11, STD)
-    layer_w = w_all["layers"][0]
-    embedded = w_all["embed"][x]
-    share_of = lambda first, last: dict(layer_w, moe={k: (v[first:last] if k in ("w1", "w3", "w2") else v)
-                                                      for k, v in layer_w["moe"].items()})
-    identity = lambda a: a
-    with HIGHEST:
-        whole = jnp.stack([R.layer(uncut, 0, identity, layer_w, jnp.asarray(e))[0] for e in embedded])
-        # operator, residual and shared experts, no routed expert: what every share computes alike
-        alike = jnp.stack([R.layer({**uncut, "held_experts": [0, 0]}, 0, identity, share_of(0, 0), jnp.asarray(e))[0]
-                           for e in embedded])
-        total = alike
-        for first in range(0, 16, 2):
-            cfg = config_of(tokens, {**m, "held_experts": [first, first + 2]})
-            out, _ = M._layer(cfg, 0, jnp.float32, share_of(first, first + 2), None, jnp.asarray(embedded))
-            part = out - alike
-            assert float(jnp.abs(part).max()) > 0
-            total = total + part
-        no_shared = {**layer_w, "moe": {**layer_w["moe"], "shared": jax.tree_util.tree_map(jnp.zeros_like,
-                                                                                           layer_w["moe"]["shared"])}}
-        without = jnp.stack([R.layer(uncut, 0, identity, no_shared, jnp.asarray(e))[0] for e in embedded])
-    np.testing.assert_allclose(total, whole, atol=2e-5)
-    assert float(jnp.abs(whole - without).max()) > 1e-3, "the shared experts are part of the layer"
+    return A.tokens
 
 
 # -- latent attention ---------------------------------------------------------------------------------
@@ -250,51 +90,7 @@ def test_k_pe_is_one_head_that_every_query_head_shares(core, request):
     np.testing.assert_allclose(sum(per_head), whole, rtol=1e-4, atol=1e-5 * largest)
 
 
-@pytest.fixture()
-def kernel_on_the_cpu(monkeypatch):
-    """The fused core chosen whatever the backend, its kernels interpreted (as ``tests/test_lfm2_moe.py`` does)."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
-
-    monkeypatch.setattr(splash, "make_splash_mqa_single_device",
-                        functools.partial(splash.make_splash_mqa_single_device, interpret=True))
-    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
-    M._programs.cache_clear()
-    yield
-    M._programs.cache_clear()
-
-
-def _rel(a, b) -> float:
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
-
-
-def _value_and_gradients(operator, p, x):
-    """(output, gradients of the weights, gradient of the input) of ``sum(operator(p, x) * probe)``, jitted."""
-    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
-
-    def value(p, x):
-        out = operator(p, x)
-        return jnp.sum(out.astype(jnp.float32) * probe), out
-
-    (_, out), (dp, dx) = jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
-    return out, dp, dx
-
-
-def _assert_within_bfloat16(got, want):
-    """The output within two bfloat16 steps of its size, the gradients of the
-    input and of every projection within 1% in norm."""
-    (out, dp, dx), (ref, ref_dp, ref_dx) = got, want
-    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
-    assert np.abs(ref).max() > 0.5 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
-    assert _rel(dx, ref_dx) < 0.01
-    for name in ("q", "kva", "kv_norm", "kvb", "o"):
-        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
-
-
-def _by_the_blockwise_core(operator, p, x):
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(M, "_use_attention_kernel", lambda length: False)
-        return _value_and_gradients(operator, p, x)
+LATENT_WEIGHTS = ("q", "kva", "kv_norm", "kvb", "o")
 
 
 def test_the_fused_core_is_the_blockwise_core_to_bfloat16_at_192_and_128(kernel_on_the_cpu):
@@ -304,7 +100,7 @@ def test_the_fused_core_is_the_blockwise_core_to_bfloat16_at_192_and_128(kernel_
     the input and of every projection within 1% in norm."""
     cfg, p, x = _latent_case(512, 2)
     operator = lambda p, x: M._latent_attention(p, x, cfg, jnp.bfloat16)
-    _assert_within_bfloat16(_value_and_gradients(operator, p, x), _by_the_blockwise_core(operator, p, x))
+    F.assert_within_bfloat16(F.value_and_gradients(operator, p, x), F.by_the_blockwise_core(operator, p, x), LATENT_WEIGHTS)
 
 
 def _reference_latent(p, x, cfg):
@@ -326,22 +122,13 @@ def test_latent_attention_and_every_gradient_by_the_fused_core(against, kernel_o
     dtype = jnp.bfloat16 if against == "blockwise-bfloat16" else jnp.float32
     x = x.astype(dtype)
     operator = lambda p, x: M._latent_attention(p, x, cfg, dtype)
-    got = _value_and_gradients(operator, p, x)
+    got = F.value_and_gradients(operator, p, x)
     if against == "blockwise-bfloat16":
-        want = _by_the_blockwise_core(operator, p, x)
+        want = F.by_the_blockwise_core(operator, p, x)
     else:
         with HIGHEST:
-            want = _value_and_gradients(lambda p, x: _reference_latent(p, x, cfg), p, x)
-    _assert_within_bfloat16(got, want)
-
-
-def _equations(jaxpr, scope=""):
-    """(primitive, the named scopes it was traced under, its outputs' avals) of every equation, nested ones too."""
-    for eqn in jaxpr.eqns:
-        here = "/".join(filter(None, [scope, str(eqn.source_info.name_stack)]))
-        yield eqn.primitive.name, here, [v.aval for v in eqn.outvars]
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(sub, here)
+            want = F.value_and_gradients(lambda p, x: _reference_latent(p, x, cfg), p, x)
+    F.assert_within_bfloat16(got, want, LATENT_WEIGHTS)
 
 
 def test_what_reaches_the_fused_core_is_assembled_once_in_the_compute_dtype(monkeypatch):
@@ -354,7 +141,7 @@ def test_what_reaches_the_fused_core_is_assembled_once_in_the_compute_dtype(monk
     cfg, p, x = _latent_case(256, 3, sequences=2)
     s, length, heads, rope = 2, 256, 3, cfg.qk_rope_head_dim
     wide = s * length * heads * (cfg.qk_nope_head_dim + rope)
-    traced = list(_equations(jax.make_jaxpr(lambda p, x: M._latent_attention(p, x, cfg, jnp.bfloat16))(p, x).jaxpr))
+    traced = list(F.equations(jax.make_jaxpr(lambda p, x: M._latent_attention(p, x, cfg, jnp.bfloat16))(p, x).jaxpr))
     assert any("core" in scope.split("/") for _, scope, _ in traced)
     outside = [(name, scope, aval) for name, scope, avals in traced for aval in avals
                if "core" not in scope.split("/") and hasattr(aval, "shape")]
@@ -413,7 +200,7 @@ def test_yarn_frequencies_and_the_softmax_scale_against_numbers_worked_by_hand()
 def _moe_case(tokens, k=6, experts=16, held=(4, 12)):
     m = {**MODEL, "num_hidden_layers": 1, "first_k_dense_replace": 0, "n_routed_experts": experts,
          "num_experts_per_tok": k, "held_experts": list(held)}
-    return m, config_of(tokens, m), R.seeded_weights(m, 3, STD)["layers"][0]["moe"]
+    return m, A.config_of(m), R.seeded_weights(m, 3, STD)["layers"][0]["moe"]
 
 
 def test_the_routers_weights_are_unnormalised_probabilities_and_the_choice_ignores_no_expert(tokens):
@@ -460,21 +247,6 @@ def test_the_balance_terms_gradient_reaches_the_router_and_nothing_else_of_the_e
     assert float(stats_twice) == pytest.approx(2.0 * float(value), rel=1e-5)
 
 
-#: 1,024 tokens, top-6, 8 of 32 experts held: a mean share of 1,536 rows; 1.25 and 2.75 shares in tiles of 512, and the
-#: worst case at 4 shares
-LADDER_HEIGHTS = (2048, 4608, 6144)
-
-
-@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 1e-2)])
-@pytest.mark.parametrize("count,rung", routed_ladder.counts_at_the_rungs(LADDER_HEIGHTS))
-def test_the_narrow_and_the_wide_row_buffer_give_the_same_layer_at_top_6(tokens, count, rung, dtype, tol):
-    """Every rung of a small ladder filled to its last row, and one row more,
-    with the shared experts beside: what the worst-case height alone gives."""
-    m, cfg, w = _moe_case(tokens, experts=32)
-    assert M._row_buffer_heights(cfg, 1024) == LADDER_HEIGHTS and "shared" in w
-    routed_ladder.assert_the_ladders_layer_is_the_worst_case_heights(cfg, w, None, 1024, count, rung, dtype, tol)
-
-
 def test_the_grouped_products_tiles_follow_the_shape():
     """1536 = 3 x 512 keeps LFM2's tiles; 1408 = 11 x 128 is one tile, whole, as
     contraction (the down product, the backward's) and as columns; the row tile
@@ -496,37 +268,9 @@ def _pool(n=3):
     return [deepseek_v2_genome().default()] + [deepseek_v2_genome().sample(rng) for _ in range(n - 1)]
 
 
-@pytest.mark.parametrize("bad,why", [
-    (dict(layer_types=("latent_attention", "state_space", "latent_attention")), "layer_types"),
-    (dict(kv_lora_rank=0), "rank and head sizes"),
-    (dict(v_head_dim=0), "rank and head sizes"),
-    (dict(qk_rope_head_dim=3), "even rope size"),
-    (dict(rope_scaling={"factor": 40, "type": "yarn"}), "rope_scaling needs"),
-    (dict(moe_intermediate_size=0), "shared experts of width 0"),
-    (dict(scoring_func="tanh"), "scoring_func"),
-    (dict(balance_rule="none"), "balance_rule"),
-])
-def test_a_configuration_that_cannot_run_is_refused_before_anything_compiles(tokens, bad, why, monkeypatch):
-    monkeypatch.setattr(M, "_programs", lambda cfg: pytest.fail("a program was asked for"))
-    with pytest.raises(ValueError, match=why):
-        M.Lfm2MoeModel.cross_validate_population(tokens[0], tokens[1], _pool(1), **model_kwargs(**bad))
-
-
 def _published():
     """(the configuration file, the family's module) of the benchmark's cell."""
-    names = ("family", "correct", "reference")
-    before = {n: sys.modules.pop(n, None) for n in names}
-    sys.path.insert(0, FAMILY)
-    try:
-        family = _load("family")
-    finally:
-        sys.path.remove(FAMILY)
-        for n in names:
-            sys.modules.pop(n, None)
-            if before[n] is not None:
-                sys.modules[n] = before[n]
-    with open(os.path.join(BENCH, "configs", "deepseek_v2_lite_ep8.json")) as fh:
-        return json.load(fh), family
+    return F.config_file("deepseek_v2_lite_ep8"), F.family_module(A.family)
 
 
 def test_the_published_cut_is_one_individual_wide_by_arithmetic():
@@ -559,80 +303,24 @@ def test_the_published_cut_is_one_individual_wide_by_arithmetic():
     assert pool[0] == deepseek_v2_genome().default() and all(r["log10_lr"] <= -3.5 and "aux_alpha" in r for r in pool)
 
 
-def test_genome_individual_population_and_two_generations(tokens):
-    x, y = tokens
-    spec = deepseek_v2_genome()
-    assert spec.names == list(M.gene_names("aux_loss")) == lfm2_moe_genome().names[:4] + ["aux_alpha"]
-    assert spec.default() == dict(log10_lr=-3.5, warmup_frac=0.25, weight_decay=0.1, beta2=0.95, aux_alpha=0.001)
-    assert (spec.genes[-1].minimum, spec.genes[-1].maximum) == (0.0, 0.01)
-    assert M.gene_names("bias") == M.GENE_NAMES
-    np.testing.assert_array_equal(M.gene_vector(spec.default()), np.float32([-3.5, 0.25, 0.1, 0.95, 0.001]))
-    assert DeepseekV2Individual.model_cls is M.Lfm2MoeModel and DeepseekV2Individual.uses_jax
-    calls = []
-
-    class Counting(M.Lfm2MoeModel):
-        @classmethod
-        def cross_validate_population(cls, x_train, y_train, genomes, **config):
-            calls.append(len(genomes))
-            return super().cross_validate_population(x_train, y_train, genomes, **config)
-
-    class Species(DeepseekV2Individual):
-        model_cls = Counting
-
-    pop = Population(Species, x, y, size=3, seed=0, additional_parameters=model_kwargs(seed=1))
-    ga = GeneticAlgorithm(pop, seed=0)
-    ga.run(2)
-    assert calls and sum(calls) >= 3, "Population.evaluate must reach cross_validate_population"
-    best = ga.population.get_fittest()
-    assert best.get_fitness() < 0 and best.get_fitness() == max(ga.population.get_fitnesses())
-    single = DeepseekV2Individual(x, y, genes=best.get_genes(), additional_parameters=model_kwargs(seed=1))
-    assert single.get_fitness() == pytest.approx(best.get_fitness(), abs=0)
-    # a recipe of the other architecture is refused by name, not trained under a wrong fifth gene
-    with pytest.raises(KeyError, match="aux_alpha"):
-        M.Lfm2MoeModel.cross_validate_population(x, y, [lfm2_moe_genome().default()], **model_kwargs(seed=1))
-
-
-def test_the_worker_resolves_the_species():
-    from gentun_tpu.distributed.worker import _species
-
-    assert _species("deepseek-v2") is DeepseekV2Individual
-    with pytest.raises(SystemExit, match="deepseek-v2"):
-        _species("no-such-species")
-
-
 # -- telemetry ---------------------------------------------------------------------------------------
-
-
-class _Sink:
-    def __init__(self):
-        self.records = []
-
-    def record(self, rec):
-        self.records.append(rec)
 
 
 def test_fitness_is_the_same_with_telemetry_on_and_the_fetch_span_carries_the_balance_term(tokens):
     x, y = tokens
-    kw = model_kwargs(seed=3)
+    kw = A.model_kwargs(seed=3)
     pool = _pool()
     base = M.Lfm2MoeModel.cross_validate_population(x, y, pool, **kw)
     assert np.all(base < 0) and len(set(base.tolist())) == len(pool)
     np.testing.assert_array_equal(M.Lfm2MoeModel.cross_validate_population(x, y, pool[::-1], **kw), base[::-1])
-    get_registry().reset()
-    sink = _Sink()
-    spans.set_run_sink(sink)
-    spans.enable()
-    try:
+    with F.traced() as records:
         traced = M.Lfm2MoeModel.cross_validate_population(x, y, pool, **kw)
-    finally:
-        spans.disable()
-        spans.set_run_sink(None)
     np.testing.assert_array_equal(traced, base)
-    fetched = [r["attrs"] for r in sink.records if r["type"] == "span" and r["kind"] == "fetch"]
+    fetched = F.span_attrs(records, "fetch")
     assert len(fetched) == len(pool) and all(0.95 < a["aux_loss"] < 2.0 for a in fetched)  # 1 where routing is even
     assert all(a["dropped"] == 0 and a["wide_buffer"] == 0 and np.shape(a["expert_rows"]) == (2, 2) for a in fetched)
     assert get_registry().counter("aux_loss_total").value == pytest.approx(sum(a["aux_loss"] for a in fetched))
-    trained = [r["attrs"] for r in sink.records if r["type"] == "span" and r.get("attrs", {}).get("steps")]
+    trained = [a for a in F.span_attrs(records) if a.get("steps")]
     assert [a["attention_kernel_layer_steps"] for a in trained] == [0] * len(pool)  # the CPU takes XLA's core
 
 
@@ -641,21 +329,12 @@ def test_a_train_span_counts_the_latent_layers_that_ran_the_fused_core(kernel_on
     x, y = tok[:, :-1], tok[:, 1:]
     m = {**MODEL, "hidden_size": 64, "num_attention_heads": 1, "num_hidden_layers": 2, "qk_nope_head_dim": 128,
          "qk_rope_head_dim": 64, "v_head_dim": 128}
-    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs(m, compute_dtype="bfloat16"))
+    programs = M.Lfm2MoeModel.compiled_programs(x, **A.model_kwargs(m, compute_dtype="bfloat16"))
     assert programs.attention_kernel_layers == 2  # a latent layer counts as an attention layer
-    get_registry().reset()
-    sink = _Sink()
-    spans.set_run_sink(sink)
-    spans.enable()
-    try:
-        loss = M._score_one(programs, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32), M.gene_vector(GENES),
-                            jnp.asarray(x), jnp.asarray(y), jnp.asarray([[0, 1], [2, 3], [0, 2]], np.int32),
-                            [jnp.asarray([4, 5])], [np.int32(s) for s in range(3)], 0)
-    finally:
-        spans.disable()
-        spans.set_run_sink(None)
+    with F.traced() as records:
+        loss = F.score_one(programs, x, y, GENES)
     assert 0 < loss < np.log(64) + 0.5
-    trained = [r["attrs"] for r in sink.records if r["type"] == "span" and r.get("attrs", {}).get("steps") == 3]
+    trained = F.span_attrs(records, steps=3)
     assert [a["attention_kernel_layer_steps"] for a in trained] == [6]  # 2 latent layers x 3 steps
     assert get_registry().counter("attention_kernel_layer_steps_total", mask="causal").value == 6
 
@@ -663,35 +342,8 @@ def test_a_train_span_counts_the_latent_layers_that_ran_the_fused_core(kernel_on
 # -- scopes ------------------------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("op_name,placed", [
-    ("jit(lm_train_step)/jvp(layer1)/latent_attention/core/vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/"
-     "pallas_call", ("latent_core", "core")),
-    ("jit(lm_train_step)/transpose(jvp(jvp()))/checkpoint/layer3/latent_attention/core/mul", ("latent_core", "core")),
-    ("jit(lm_eval)/layer0/latent_attention/core/checkpoint/sngqk,sknd->sqngd/dot_general", ("latent_core", "core")),
-    ("jit(lm_train_step)/jvp(layer0)/latent_attention/down_proj/dot_general", ("latent_proj", "down_proj")),
-    ("jit(lm_train_step)/transpose(jvp(layer2))/latent_attention/up_proj/dot_general", ("latent_proj", "up_proj")),
-    ("jit(lm_train_step)/checkpoint/rematted_computation/layer2/latent_attention/rope/cos", ("latent_proj", "rope")),
-    ("jit(lm_eval)/layer5/latent_attention/out_proj/dot_general", ("latent_proj", "out_proj")),
-    ("jit(lm_train_step)/jvp(layer2)/latent_attention/add", ("latent_proj", "other")),
-    ("jit(lm_train_step)/jvp(layer2)/moe/shared/dot_general", ("shared_expert", "shared")),
-    ("jit(lm_train_step)/transpose(jvp(layer4))/moe/shared/mul", ("shared_expert", "shared")),
-    ("jit(lm_train_step)/jvp(layer2)/cond/branch_0_fun/moe/experts/jit(gmm)/pallas_call", ("expert_mm", "experts")),
-    ("jit(lm_train_step)/jvp(layer3)/moe/router/reduce_max", ("moe_route", "router")),
-    ("jit(lm_train_step)/jvp(layer3)/aux_loss/reduce_sum", ("moe_route", "aux_loss")),
-    ("jit(lm_train_step)/transpose(jvp(layer3))/aux_loss/mul", ("moe_route", "aux_loss")),
-    ("jit(lm_eval)/layer5/cond/branch_0_fun/moe/dispatch/jit(_take)/gather", ("moe_route", "dispatch")),
-    ("jit(lm_train_step)/transpose(jvp(layer0))/dense_ffn/dot_general", ("dense_ffn", "layer0")),
-    ("jit(lm_train_step)/jvp(head)/slh,vh->slv/dot_general", ("head_loss", "head")),
-    ("jit(lm_train_step)/optimizer/sqrt", ("optimizer", "optimizer")),
-    ("jit(lm_train_step)/jvp(layer3)/rsqrt", ("rest", "layer3")),
-    ("", ("unattributed", "")),
-])
-def test_scope_rules_place_each_new_scope(op_name, placed):
-    assert scope_rules.classify(op_name) == placed and placed[0] in scope_rules.CLASSES
-
-
 def test_the_lowered_train_step_carries_every_new_scope(tokens):
-    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(layer_ids=(0, 1, 2)))
+    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **A.model_kwargs(layer_ids=(0, 1, 2)))
     state = jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
     text = programs.train_step.lower(state, *tokens, np.zeros((3, 2), np.int32), np.zeros(5, np.float32),
                                      np.int32(0)).as_text(debug_info=True)
@@ -749,24 +401,12 @@ def test_executed_flops_at_the_published_widths_by_hand():
 
 @pytest.fixture()
 def layer_metric():
-    """A reader of ``benchmark/layer_metrics/`` by name, loaded as ``run.py``
-    loads it (the family's directory and the harness's on ``sys.path``)."""
-    names = ("dsv2_spans", "scope_rules", "scope_reduce", "spanlib", "trace_reduce", "flops", "family", "correct",
-             "reference")
-    before = {n: sys.modules.pop(n, None) for n in names}
-    sys.path[:0] = [FAMILY, BENCH]
-    try:
-        yield lambda name: _load(os.path.join("..", "..", "layer_metrics", name))
-    finally:
-        del sys.path[:2]
-        for n in names:
-            sys.modules.pop(n, None)
-            if before[n] is not None:
-                sys.modules[n] = before[n]
+    """A reader of ``benchmark/layer_metrics/`` by name, loaded as ``run.py`` loads it."""
+    with F.as_run_py_loads(A.family) as load:
+        yield lambda name: load(f"layer_metrics/{name}")
 
 
-def _span(kind, t, attrs):
-    return {"type": "span", "kind": kind, "t_wall": t, "dur_s": 0.001, "attrs": attrs}
+_span = F.span
 
 
 def test_the_balance_reader_averages_the_windows_fetch_spans_and_a_program_without_the_attribute_reads_nothing(
@@ -779,12 +419,6 @@ def test_the_balance_reader_averages_the_windows_fetch_spans_and_a_program_witho
     assert reader.read({**window, "records": records}) == 1.25
     assert reader.read({**window, "records": [_span("fetch", 11.0, {"individual": 0, "expert_rows": [[1]]})]}) is None
     assert reader.read({**window, "records": records[:1]}) is None
-
-
-def test_the_row_buffer_reader_divides_the_rows_the_heights_ran_by_the_rows_routed_and_the_parent_reads_nothing(
-        layer_metric):
-    reader = layer_metric("dsv2_row_buffer_rows_per_routed_row")
-    routed_ladder.assert_the_reader_divides_the_rows_run_by_the_rows_routed(reader)
 
 
 def test_the_core_roofline_reader_divides_the_kernels_flops_by_the_kernels_own_time(layer_metric):

@@ -1166,3 +1166,102 @@ class TestCleanShutdown:
             assert broker._workers == {}
         finally:
             s.close()
+
+
+class TestReconnectBackoff:
+    """The worker's one connection redials under a capped, jittered backoff."""
+
+    def test_reconnect_backoff_grows_to_the_cap_and_a_handshake_resets_it(self, monkeypatch):
+        from gentun_tpu.distributed import client as client_module
+
+        delays, resets = [], []
+        next_delay, reset = client_module._ReconnectBackoff.next_delay, client_module._ReconnectBackoff.reset
+
+        def watched_delay(self):
+            delays.append(next_delay(self))
+            return delays[-1]
+
+        def watched_reset(self):
+            resets.append(len(delays))
+            reset(self)
+
+        monkeypatch.setattr(client_module._ReconnectBackoff, "next_delay", watched_delay)
+        monkeypatch.setattr(client_module._ReconnectBackoff, "reset", watched_reset)
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]  # free, and nobody listens on it yet
+        client = GentunClient(OneMax, *DATA, port=port, capacity=1, heartbeat_interval=0.2, reconnect_delay=0.01,
+                              reconnect_max_delay=0.08, worker_id="backoff-w0")
+        stop = threading.Event()
+        thread = threading.Thread(target=client.work, kwargs={"stop_event": stop}, daemon=True)
+        thread.start()
+        broker = None
+        try:
+            deadline = time.monotonic() + 10.0
+            while len(delays) < 8 and time.monotonic() < deadline:
+                time.sleep(0.02)
+            refused = list(delays)
+            assert len(refused) >= 8 and not resets, "no broker: every dial is refused, nothing re-arms the delay"
+            assert refused[0] == 0.01 and all(0.01 <= d <= 0.08 for d in refused) and max(refused) > 0.03
+            assert 0.08 in refused, "the delay reaches the cap and stays under it"
+            broker = JobBroker(host="127.0.0.1", port=port).start()
+            while not resets and time.monotonic() < deadline:
+                time.sleep(0.02)
+            assert resets, "a completed handshake re-arms the base delay"
+            at_handshake = len(delays)
+            broker.stop()
+            broker = None
+            while len(delays) == at_handshake and time.monotonic() < deadline:
+                time.sleep(0.02)
+            assert delays[at_handshake] == 0.01, "the first redial after a handshake waits the base delay again"
+        finally:
+            stop.set()
+            client.shutdown()
+            if broker is not None:
+                broker.stop()
+            thread.join(timeout=10.0)
+
+    def test_backoff_seed_is_the_workers_own(self, monkeypatch):
+        """Decorrelated jitter must not march in lockstep across a fleet: one
+        worker's sequence is its own (reproducible), another's differs, and
+        ``work()`` seeds its connection's backoff from the worker id."""
+        from gentun_tpu.distributed import client as client_module
+
+        def sequence(seed):
+            backoff = client_module._ReconnectBackoff(0.05, 5.0, seed)
+            return [backoff.next_delay() for _ in range(6)]
+
+        assert sequence("w0") == sequence("w0") and sequence("w0") != sequence("w1")
+        assert sequence("w0")[0] == 0.05 and max(sequence("w0")) <= 5.0
+        seeds = []
+        init = client_module._ReconnectBackoff.__init__
+        monkeypatch.setattr(client_module._ReconnectBackoff, "__init__",
+                            lambda self, base, cap, seed: (seeds.append((base, cap, seed)), init(self, base, cap, seed))[1])
+        with DistributedPopulation(OneMax, size=2, seed=0, port=0) as pop:
+            client = GentunClient(OneMax, *DATA, port=pop.broker_address[1], reconnect_delay=0.07,
+                                  reconnect_max_delay=3.0, worker_id="seeded-w7")
+            assert client.work(max_jobs=0) == 0
+        assert seeds == [(0.07, 3.0, "seeded-w7")]
+
+
+class TestOneBrokerServesAFleet:
+    """Horizontal broker sharding went with PR 45: no party takes a list of brokers."""
+
+    def test_session_client_refuses_broker_urls(self):
+        from gentun_tpu.distributed.sessions import SessionClient
+
+        with pytest.raises(TypeError, match="broker_urls"):
+            SessionClient(broker_urls=["127.0.0.1:1", "127.0.0.1:2"])
+        with pytest.raises(TypeError, match="host"):
+            SessionClient()  # host and port are required
+
+    def test_distributed_population_refuses_broker_urls(self):
+        with pytest.raises(TypeError, match="broker_urls"):
+            DistributedPopulation(OneMax, size=2, seed=0, broker_urls=["127.0.0.1:1", "127.0.0.1:2"])
+
+    def test_worker_cli_rejects_broker_urls(self, capsys):
+        from gentun_tpu.distributed.worker import main as worker_main
+
+        with pytest.raises(SystemExit) as refused:
+            worker_main(["--species", "boosting", "--dataset", "uci-binary", "--broker-urls", "127.0.0.1:1,127.0.0.1:2"])
+        assert refused.value.code == 2 and "--broker-urls" in capsys.readouterr().err

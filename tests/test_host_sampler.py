@@ -453,18 +453,19 @@ def test_a_late_tick_through_which_the_process_ran_is_gil_held_not_host_late():
 # -- the manifest ---------------------------------------------------------------------------------
 
 
-def test_the_manifest_checks_with_128_per_layer_metrics():
+def test_the_manifest_checks_and_lists_this_modules_metrics_for_the_cells_that_read_them():
     import check_manifest
 
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
         manifest = json.load(fh)
     assert check_manifest.check(manifest) == []
-    # 91 with this module's, the three ``*_row_buffer_rows_per_routed_row`` (PR 39) and the sixth cell's 34 ``q3n_*`` (PR 40)
-    assert len(manifest["per_layer"]) == 128
-    cells = [w["name"] for w in manifest["workloads"]][:5]  # the cells there were; the sixth reads them as ``q3n_*``
-    new = {m["name"]: m for m in manifest["per_layer"][91 - len(NUMBERS):91]}
-    assert tuple(new) == NUMBERS
-    seventh = ["laguna_xs2_ep8.popeval"]  # PR 42's cell, appended: the manifest was full, so it reads the accepted entries
-    for m in new.values():
-        assert m["workloads"] == cells + seventh and m["better"] == "lower" and m["moves"] == "individuals_per_hour_per_chip"
-    assert {m["layer"] for m in new.values()} == {"device", "host_runtime"}
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert set(NUMBERS) <= set(by_name)
+    # the five cells there were (the sixth reads them as ``q3n_*``) and PR 42's, which reads the accepted entries
+    cells = ["c10_flagship.popeval", "c100_deep.popeval", "lfm2_24b_a2b_ep8.popeval", "deepseek_v2_lite_ep8.popeval",
+             "mellum2_12b_a2p5b_ep8.popeval", "laguna_xs2_ep8.popeval"]
+    assert set(cells) <= {w["name"] for w in manifest["workloads"]}
+    for name in NUMBERS:
+        m = by_name[name]
+        assert m["workloads"] == cells and m["better"] == "lower" and m["moves"] == "individuals_per_hour_per_chip", name
+    assert {by_name[name]["layer"] for name in NUMBERS} == {"device", "host_runtime"}

@@ -1,29 +1,22 @@
-"""The routed family's fifth architecture (Laguna-XS.2 through
-``models/lfm2_moe.py``) against its plain reference
-(``benchmark/families/laguna/reference.py``), at small sizes on the CPU.
+"""What is Laguna-XS.2's own among the routed family's tests (the fifth architecture through
+``models/lfm2_moe.py``, against ``benchmark/families/laguna/reference.py``, at small sizes on the CPU); what every
+architecture is held to (logits, loss and every gradient under a router bias that changes the choice: a layer of
+each head count alone, the dense layer, the cut whole, two periods; two train steps with the bias's step; the eight
+shares of the scaled routed sum with the shared expert counted once; refusals that name the layer; the manifest's
+readers) is in ``test_routed_family*.py`` under ``laguna-`` ids.
 
-System and reference are compared in float32 on seeded weights: a layer of each
-head count alone, the dense layer, the cut whole (logits, loss, every gradient,
-under a router bias that changes the choice); two train steps with the bias's
-step; the eight shares of the scaled routed sum with the shared expert counted
-once against the uncut layer.  Then what is the architecture's own: the gate a
-head against the reference and against zero gates; rope on half a head under
-YaRN against a table built from the definition; the window's mask object entry by
-entry at a window of 512, one key short and one key long failing; both cores at
-both groupings (XLA's blocks; the kernel's table of visits at group 6 and 8; the
-kernel interpreted with both groups and both masks in one program); refusals that
-name the layer; the scopes, the spans, the counter; the configuration file, the
-counts and the accepted readers that read the new cell; and that the four
-architectures that were there build the trees and the bytes they built.
+Here: the gate a head against the reference and against zero gates; rope on half a head under YaRN against a table
+built from the definition; the window's mask object entry by entry at a window of 512, one key short and one key
+long failing; both cores at both groupings (XLA's blocks; the kernel's table of visits at group 6 and 8; the kernel
+interpreted with both groups and both masks in one program); the scopes, the spans, the counter; the configuration
+file, the counts and the accepted readers that read the new cell; and that the four architectures that were there
+build the trees and the bytes they built.
 """
 
 from __future__ import annotations
 
-import importlib.util
-import json
 import math
 import os
-import re
 import sys
 
 import numpy as np
@@ -32,201 +25,23 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import routed_family as F
 from gentun_tpu import lfm2_moe_genome
 from gentun_tpu.models import lfm2_moe as M
-from gentun_tpu.telemetry import spans
 from gentun_tpu.telemetry.registry import get_registry
+from routed_family import HIGHEST, STD, kernel_on_the_cpu, small_kernel_blocks  # noqa: F401  (the fixtures)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
-FAMILY = os.path.join(BENCH, "families", "laguna")
+A = F.ARCHS["laguna"]
+R, flops, scope_rules = A.R, A.flops, A.scope_rules
 CELL = "laguna_xs2_ep8.popeval"
-
-
-def _load(name, directory=FAMILY):
-    spec = importlib.util.spec_from_file_location(f"lag_family_{os.path.basename(name)}", os.path.join(directory, name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-R = _load("reference")
-flops = _load("flops")
-scope_rules = _load("scope_rules")
-
-ROPE = {"full_attention": {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
-                           "original_max_position_embeddings": 4096, "beta_slow": 1, "beta_fast": 64,
-                           "attention_factor": 1.4158883083359672, "partial_rotary_factor": 0.5},
-        "sliding_attention": {"rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1}}
-#: The cut's shape at toy widths: the dense layer under full attention, a sparse layer under each attention type;
-#: 4 query heads in a full layer and 6 in a sliding one over 2 key-value heads (2 and 3 to each), a window of 6.
-CUT = dict(hidden_size=40, head_dim=16, intermediate_size=56, moe_intermediate_size=24, shared_expert_intermediate_size=24,
-           num_experts=8, num_experts_per_tok=3, held_experts=[2, 4], num_key_value_heads=2, num_hidden_layers=3,
-           layer_types=["full_attention", "sliding_attention", "full_attention"], mlp_layer_types=["dense", "sparse", "sparse"],
-           num_attention_heads_per_layer=[4, 6, 4], vocab_size=64, rms_norm_eps=1e-6, rope_parameters=ROPE, sliding_window=6,
-           moe_routed_scaling_factor=2.5, train_steps=3)
-GENES = dict(log10_lr=-2.5, warmup_frac=0.5, weight_decay=0.1, beta2=0.95, bias_step=0.01)
-HIGHEST = jax.default_matmul_precision("highest")
-STD = 0.15  # narrow layers: wider weights, or the operators vanish beside the residual
-
-
-def model_kwargs(m=CUT, **over):
-    """``Lfm2MoeModel``'s keyword arguments that make it the reference's model ``m``: the published keys."""
-    kw = {k: m[k] for k in ("hidden_size", "head_dim", "intermediate_size", "moe_intermediate_size", "num_experts",
-                            "num_experts_per_tok", "num_key_value_heads", "vocab_size", "rope_parameters",
-                            "sliding_window", "train_steps")}
-    kw.update(layer_types=tuple(m["layer_types"]), num_attention_heads=4,
-              num_attention_heads_per_layer=tuple(m["num_attention_heads_per_layer"]),
-              num_dense_layers=m["mlp_layer_types"].count("dense"), held_experts=tuple(m["held_experts"]),
-              norm_eps=m["rms_norm_eps"], qk_norm=False, attn_head_gate=True, n_shared_experts=1, scoring_func="sigmoid",
-              norm_topk_prob=True, balance_rule="bias", routed_scaling_factor=m["moe_routed_scaling_factor"],
-              tie_word_embeddings=False, batch_sequences=2, eval_sequences=2, attn_block=8, compute_dtype="float32")
-    kw.update(over)
-    return kw
+CUT, ROPE = A.model, F.LAGUNA_ROPE
+one_layer = F.laguna_one_layer
+_rel = F.rel
 
 
 @pytest.fixture(scope="module")
 def tokens():
-    tok = np.random.default_rng(0).integers(0, 64, size=(10, 25)).astype(np.int32)  # 24 positions: four windows of 6
-    return tok[:, :-1], tok[:, 1:]
-
-
-def config_of(tokens, m=CUT, **over) -> M.Lfm2MoeConfig:
-    return M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(m, **over)).config
-
-
-def bias_of(m, seed=3, std=0.2):
-    """A router bias large enough that ignoring it changes the choice."""
-    return (std * np.random.default_rng(seed).standard_normal((len(R.routed_layers(m)), m["num_experts"]))).astype(np.float32)
-
-
-def one_layer(kind, heads, ffn="sparse", **over):
-    return {**CUT, "num_hidden_layers": 1, "layer_types": [kind], "mlp_layer_types": [ffn],
-            "num_attention_heads_per_layer": [heads], "held_experts": [1, 5], **over}
-
-
-LAYER_CASES = {"a_full_layer_of_4_heads": one_layer("full_attention", 4),
-               "a_sliding_layer_of_6_heads": one_layer("sliding_attention", 6),
-               "a_sliding_layer_of_8_heads": one_layer("sliding_attention", 8),
-               "the_dense_layer_before_a_sparse_one": {**CUT, "num_hidden_layers": 2, "layer_types": CUT["layer_types"][:2],
-                                                        "mlp_layer_types": ["dense", "sparse"],
-                                                        "num_attention_heads_per_layer": [4, 6]},
-               "the_cut": CUT,
-               "two_periods": {**CUT, "num_hidden_layers": 5, "layer_types": ["full_attention"] + ["sliding_attention"] * 3
-                               + ["full_attention"], "mlp_layer_types": ["dense"] + ["sparse"] * 4,
-                               "num_attention_heads_per_layer": [4, 6, 6, 6, 4]}}
-
-
-@pytest.mark.parametrize("case", sorted(LAYER_CASES))
-def test_logits_loss_and_every_gradient_match_the_reference(case, tokens):
-    m = LAYER_CASES[case]
-    cfg = config_of(tokens, m)
-    assert cfg.head_dim == 16 and cfg.attn_head_gate and not cfg.attn_output_gate and cfg.routed_scaling_factor == 2.5
-    assert [cfg.heads_of(i) for i in range(len(cfg.layer_types))] == m["num_attention_heads_per_layer"]
-    w = R.seeded_weights(m, 7, STD, router_gain=3.0, gate_std=0.5)
-    shapes = M.param_shapes(cfg)
-    assert jax.tree_util.tree_map(lambda a: a.shape, w) == jax.tree_util.tree_map(lambda s: s, shapes, is_leaf=M._is_shape)
-    x, y = tokens[0][:2], tokens[1][:2]
-    bias = jnp.asarray(bias_of(m))
-
-    def system_loss(params):
-        logits, load, stats = M.forward(cfg, params, bias, x, remat=True)
-        return M.token_loss(logits, y).mean(), (logits, load, stats)
-
-    def reference_loss(params):
-        out = [R.forward(m, params, bias, xs) for xs in x]
-        nll = jnp.mean(jnp.stack([R.token_loss(o[0], ys) for o, ys in zip(out, y)]))
-        return nll, (jnp.stack([o[0] for o in out]), sum(o[1] for o in out))
-
-    with HIGHEST:
-        (loss, (logits, load, stats)), grads = jax.jit(jax.value_and_grad(system_loss, has_aux=True))(w)
-        (ref_loss, (ref_logits, ref_load)), ref_grads = jax.jit(jax.value_and_grad(reference_loss, has_aux=True))(w)
-        unbiased = jax.jit(lambda p: M.forward(cfg, p, jnp.zeros_like(bias), x)[1])(w)
-    np.testing.assert_allclose(logits, ref_logits, atol=3e-5)
-    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
-    np.testing.assert_array_equal(load, ref_load)
-    assert not np.array_equal(load, unbiased), "the bias changes the choice"
-    assert int(stats.dropped) == 0 and float(stats.balance) == 0.0
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
-        np.testing.assert_allclose(g, r, atol=3e-6, rtol=1e-4, err_msg=jax.tree_util.keystr(path))
-        assert float(jnp.abs(r).max()) > 0 or "embed" in str(path), \
-            f"{jax.tree_util.keystr(path)}: the reference's gradient is all zero"
-
-
-def _program_steps(programs, weights, bias, x, y, rows, steps, genes=GENES):
-    state = programs.init(jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
-    state = {**state, "params": jax.tree_util.tree_map(jnp.asarray, weights), "bias": jnp.asarray(bias)}
-    losses, loads = [], []
-    for s in range(steps):
-        state, loss, held = programs.train_step(state, jnp.asarray(x), jnp.asarray(y), jnp.asarray(rows),
-                                                jnp.asarray(M.gene_vector(genes)), np.int32(s))
-        losses.append(float(loss))
-        loads.append(np.asarray(held))
-    return state, losses, loads
-
-
-def test_two_train_steps_and_the_biass_step_match_the_reference(tokens):
-    x, y = tokens
-    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs())
-    assert programs.config.gene_names == tuple(lfm2_moe_genome().names)
-    w, bias = R.seeded_weights(CUT, 5, STD, router_gain=3.0, gate_std=0.5), bias_of(CUT)
-    rows = np.array([[0, 1], [2, 3], [4, 5]], np.int32)
-    with HIGHEST:
-        state, losses, loads = _program_steps(programs, w, bias, x, y, rows, 2)
-        ref = R.train(CUT, w, [(x[r], y[r]) for r in rows[:2]], GENES, bias=bias)
-    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)
-    for got, want in zip(loads, ref["loads"]):
-        np.testing.assert_array_equal(got, want[:, 2:4])
-    np.testing.assert_allclose(state["bias"], ref["bias"], atol=1e-7)
-    moved = np.abs(ref["bias"] - bias) / GENES["bias_step"]
-    assert moved.max() == pytest.approx(2.0, abs=1e-3) and (moved[:, [0, 1, 5, 6, 7]] > 0.5).any(axis=0).all(), \
-        "every expert's bias steps, held here or not"
-    for (path, a), b, start in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
-                                   jax.tree_util.tree_leaves(ref["weights"]), jax.tree_util.tree_leaves(w)):
-        change, ref_change = np.asarray(a) - start, np.asarray(b) - start
-        assert np.abs(ref_change).max() > 0, jax.tree_util.keystr(path)
-        np.testing.assert_allclose(change, ref_change, atol=3e-5, err_msg=jax.tree_util.keystr(path))
-    with HIGHEST:
-        got = programs.eval(state["params"], state["bias"], jnp.asarray(x), jnp.asarray(y), jnp.asarray([8, 9]))
-        want = R.eval_token_loss(CUT, ref["weights"], ref["bias"], x[8:10], y[8:10])
-    np.testing.assert_allclose(got, want, atol=3e-5)
-
-
-@pytest.mark.parametrize("kind,heads", [("sliding_attention", 6), ("full_attention", 4)])
-def test_the_eight_shares_each_times_the_factor_with_the_shared_expert_once_add_up_to_the_uncut_layer(kind, heads, tokens):
-    """16 experts in 8 shares of 2, 8 a token: each share's program computes the
-    attention, the residual, the shared expert and 2.5 times its own routed
-    experts' part, the weights normalised over all the chosen eight; the routed
-    parts, with what every share computes alike (the shared expert among it)
-    counted once, are the uncut reference's layer output."""
-    m = one_layer(kind, heads, num_experts=16, num_experts_per_tok=8)
-    x = tokens[0][:2]
-    uncut = {**m, "held_experts": [0, 16]}
-    w_all = R.seeded_weights(uncut, 11, STD, router_gain=3.0, gate_std=0.5)
-    layer_w, bias = w_all["layers"][0], jnp.asarray(bias_of(uncut)[0])
-    embedded = w_all["embed"][x]
-    share_of = lambda first, last: dict(layer_w, moe={k: (v[first:last] if k in ("w1", "w3", "w2") else v)
-                                                      for k, v in layer_w["moe"].items()})
-    identity = lambda a: a
-    with HIGHEST:
-        whole = jnp.stack([R.layer(uncut, 0, identity, layer_w, bias, jnp.asarray(e))[0] for e in embedded])
-        # attention, residual and the shared expert, no routed expert: what every share computes alike
-        alike = jnp.stack([R.layer({**uncut, "held_experts": [0, 0]}, 0, identity, share_of(0, 0), bias, jnp.asarray(e))[0]
-                           for e in embedded])
-        no_shared = jnp.stack([R.layer({**uncut, "held_experts": [0, 0], "shared_expert": False}, 0, identity,
-                                       share_of(0, 0), bias, jnp.asarray(e))[0] for e in embedded])
-        unscaled = jnp.stack([R.layer({**uncut, "moe_routed_scaling_factor": 1.0}, 0, identity, layer_w, bias,
-                                      jnp.asarray(e))[0] for e in embedded])
-        total = alike
-        for first in range(0, 16, 2):
-            cfg = config_of(tokens, {**m, "held_experts": [first, first + 2]})
-            out, _ = M._layer(cfg, 0, jnp.float32, share_of(first, first + 2), bias, jnp.asarray(embedded))
-            part = out - alike
-            assert float(jnp.abs(part).max()) > 0
-            total = total + part
-    np.testing.assert_allclose(total, whole, atol=3e-5)
-    assert float(jnp.abs(alike - no_shared).max()) > 1e-3, "the shared expert is part of what the shares compute alike"
-    np.testing.assert_allclose(whole - alike, 2.5 * (unscaled - alike), atol=3e-5)  # the factor is on the routed sum alone
-    assert float(jnp.abs(whole - alike).max()) > 1e-3, "the routed experts are part of the layer"
+    return A.tokens
 
 
 # -- the gate a head ---------------------------------------------------------------------------------------
@@ -239,7 +54,7 @@ def _attention_weights(m, index=0, seed=2, gate_std=0.5):
 @pytest.mark.parametrize("kind,heads", [("sliding_attention", 6), ("full_attention", 4)])
 def test_the_gate_a_head_is_the_references_and_zero_gates_halve_the_output(kind, heads, tokens):
     m = one_layer(kind, heads)
-    cfg = config_of(tokens, m)
+    cfg = A.config_of(m)
     p = _attention_weights(m)
     x = jnp.asarray(np.random.default_rng(4).normal(size=(2, 24, 40)), jnp.float32)
     identity = lambda a: a
@@ -269,7 +84,7 @@ def test_the_gate_a_head_is_the_references_and_zero_gates_halve_the_output(kind,
 
 def test_partial_yarn_rope_is_a_table_built_from_the_definition_and_columns_64_to_127_pass():
     r = ROPE["full_attention"]
-    cfg = M.Lfm2MoeConfig(rope_parameters=tuple(sorted((k, tuple(sorted(b.items()))) for k, b in ROPE.items())),
+    cfg = M.Lfm2MoeConfig(rope_parameters=F.rope_table(ROPE),
                           head_dim=128, num_attention_heads=48, layer_types=("full_attention", "sliding_attention"),
                           layer_ids=(0, 1), sliding_window=512)
     assert cfg.rotary_of("full_attention") == 64 and cfg.rotary_of("sliding_attention") == 128
@@ -309,15 +124,7 @@ def test_partial_yarn_rope_is_a_table_built_from_the_definition_and_columns_64_t
 # -- the window's mask, and both cores at both groupings ----------------------------------------------------------
 
 
-def _masks_handed_to_the_kernel(length, group, window):
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
-
-    real = splash.make_splash_mqa_single_device
-    try:
-        splash.make_splash_mqa_single_device = lambda mask, **kw: mask.masks  # what the kernel would be made from
-        return M._splash_kernel(length, group, window)
-    finally:
-        splash.make_splash_mqa_single_device = real
+_masks_handed_to_the_kernel = F.masks_handed_to_the_kernel
 
 
 @pytest.mark.parametrize("length", [512, 1024, 4096, 8192])
@@ -330,16 +137,17 @@ def test_the_windows_mask_object_at_512_is_the_references_array_entry_by_entry(l
     assert (len(windowed), len(causal)) == (8, 6)
     j = np.arange(length)[None, :]
     differ = {"short": 0, "long": 0}
+    seen = lambda mask, rows: np.asarray(mask[rows, :]).astype(bool)
+    heads = [windowed[0]] + ([] if windowed[-1] is windowed[0] else [windowed[-1]])  # one mask object serves every head
     for first in range(0, length, 1024):
         rows = slice(first, min(first + 1024, length))
         i = np.arange(length)[rows, None]
-        want = np.asarray(R.visible(i, j, "sliding_attention", {"sliding_window": 512}))
-        for head in (windowed[0], windowed[-1]):  # one mask object serves every head of the group
-            np.testing.assert_array_equal(np.asarray(head[rows, :]).astype(np.int32), want)
-        np.testing.assert_array_equal(np.asarray(causal[0][rows, :]).astype(np.int32),
-                                      np.asarray(R.visible(i, j, "full_attention", {})))
-        differ["short"] += int((np.asarray(short[0][rows, :]).astype(np.int32) != want).sum())
-        differ["long"] += int((np.asarray(long[0][rows, :]).astype(np.int32) != want).sum())
+        want = np.asarray(R.visible(i, j, "sliding_attention", {"sliding_window": 512})) == 1
+        for head in heads:
+            np.testing.assert_array_equal(seen(head, rows), want)
+        np.testing.assert_array_equal(seen(causal[0], rows), np.asarray(R.visible(i, j, "full_attention", {})) == 1)
+        differ["short"] += np.count_nonzero(seen(short[0], rows) != want)
+        differ["long"] += np.count_nonzero(seen(long[0], rows) != want)
     assert all(windowed[0] is head or windowed[0] == head for head in windowed)
     assert differ["short"] == max(length - 511, 0) and differ["long"] == max(length - 512, 0)  # one key a row that has it
     assert int(np.asarray(windowed[0][length - 1:length, :]).sum()) == min(512, length)
@@ -405,42 +213,19 @@ def test_the_kernels_table_of_visits_is_one_for_every_head_of_either_group_and_f
         assert flops.KERNEL_BLOCKS[kind] == (M._ATTN_KERNEL_BLOCKS["block_q"], M._ATTN_KERNEL_BLOCKS["block_kv"])
 
 
-@pytest.fixture()
-def kernel_on_the_cpu(monkeypatch):
-    """The fused core chosen whatever the backend, its kernels interpreted: the
-    library's own factory is given ``interpret=True``, the program has no such knob."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
-
-    monkeypatch.setattr(splash, "make_splash_mqa_single_device",
-                        __import__("functools").partial(splash.make_splash_mqa_single_device, interpret=True))
-    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
-    monkeypatch.setattr(M, "_ATTN_KERNEL_BLOCKS", dict(block_q=128, block_kv=128, block_kv_compute=128,
-                                                       block_q_dkv=128, block_kv_dkv=128, block_kv_dkv_compute=128))
-    M._programs.cache_clear()
-    M._kernel_visits.cache_clear()
-    yield
-    M._programs.cache_clear()
-    M._kernel_visits.cache_clear()
-
-
-def _rel(a, b) -> float:
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
-
-
 #: One key-value head at the published head size: 3 query heads to it under the window, 2 under the causal mask.
 KERNEL_MODEL = dict(hidden_size=64, head_dim=128, num_key_value_heads=1, rope_parameters=ROPE, sliding_window=96)
 
 
 @pytest.mark.parametrize("kind,heads", [("sliding_attention", 3), ("full_attention", 2)])
-def test_attention_and_every_gradient_by_the_fused_core_at_its_own_group(kind, heads, kernel_on_the_cpu):
+def test_attention_and_every_gradient_by_the_fused_core_at_its_own_group(kind, heads, kernel_on_the_cpu, small_kernel_blocks):
     """``_attention`` whole with the kernel interpreted (two blocks a side), float32, against
     ``reference.attention`` a sequence: the layer type's mask, rope on its share of a head, its own number of query
     heads to the key-value head, the gate a head; the output within two bfloat16 steps of its size, the gradients of
     the input and of every weight within 1% in norm."""
     cfg = M.Lfm2MoeConfig(hidden_size=64, head_dim=128, num_attention_heads=2, num_key_value_heads=1, qk_norm=False,
                           attn_head_gate=True, sliding_window=96, norm_eps=1e-6, seq_len=256, attn_block=64,
-                          rope_parameters=tuple(sorted((k, tuple(sorted(b.items()))) for k, b in ROPE.items())),
+                          rope_parameters=F.rope_table(ROPE),
                           layer_types=("sliding_attention", "full_attention"), layer_ids=(0, 1),
                           num_attention_heads_per_layer=(3, 2), num_dense_layers=0)
     index = cfg.layer_types.index(kind)
@@ -449,66 +234,23 @@ def test_attention_and_every_gradient_by_the_fused_core_at_its_own_group(kind, h
     assert shapes["q"] == (64, heads * 128) and shapes["gate"] == (64, heads) and shapes["k"] == (64, 128)
     p = {name: jnp.asarray(rng.normal(size=shape) / np.sqrt(shape[0]), jnp.float32) for name, shape in shapes.items()}
     x = jnp.asarray(rng.normal(size=(2, 256, 64)), jnp.float32)
-    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
-
-    def value_and_gradients(operator):
-        def value(p, x):
-            out = operator(p, x)
-            return jnp.sum(out * probe), out
-        (_, out), (dp, dx) = jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
-        return out, dp, dx
-
-    out, dp, dx = value_and_gradients(lambda p, x: M._attention(p, x, cfg, jnp.float32, kind))
+    got = F.value_and_gradients(lambda p, x: M._attention(p, x, cfg, jnp.float32, kind), p, x)
     with HIGHEST:
-        ref, ref_dp, ref_dx = value_and_gradients(
-            lambda p, x: jnp.stack([R.attention(p, xs, KERNEL_MODEL, kind, heads, lambda a: a) for xs in x]))
-    out, ref = np.asarray(out), np.asarray(ref)
-    assert np.abs(ref).max() > 0.2 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
-    assert _rel(dx, ref_dx) < 0.01
-    for name in ("q", "k", "v", "o", "gate"):
-        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
+        want = F.value_and_gradients(
+            lambda p, x: jnp.stack([R.attention(p, xs, KERNEL_MODEL, kind, heads, lambda a: a) for xs in x]), p, x)
+    F.assert_within_bfloat16(got, want, ("q", "k", "v", "o", "gate"), floor=0.2)
 
 
 # -- refusals, scopes, spans, counters ----------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("over,message", [
-    (dict(num_attention_heads_per_layer=(4, 5, 4)), r"layer 1 \(sliding_attention\).*5 heads"),
-    (dict(num_attention_heads_per_layer=(4, 6)), "num_attention_heads_per_layer"),
-    (dict(num_attention_heads_per_layer=(0, 6, 4)), r"layer 0 \(full_attention\)"),
-    (dict(rope_parameters={**ROPE, "full_attention": {**ROPE["full_attention"], "partial_rotary_factor": 0.45}}),
-     r"layer 0 \(full_attention\): rope turns 7 "),
-    (dict(rope_parameters={**ROPE, "sliding_attention": {**ROPE["sliding_attention"], "partial_rotary_factor": 1.5}}),
-     r"layer 1 \(sliding_attention\): rope turns 24 "),
-    (dict(attn_output_gate=True), "two forms of one gate"),
-    (dict(sliding_window=0), "sliding_window"),
-    (dict(layer_ids=(0, 7, 8), num_attention_heads_per_layer=(4, 6, 3)), r"layer 8 \(full_attention\).*3 heads"),
-])
-def test_a_configuration_that_cannot_be_this_architecture_is_refused_by_the_layer_at_fault(over, message, tokens):
-    with pytest.raises(ValueError, match=message):
-        M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(**over))
-
-
-def _scopes(fn, *args) -> set:
-    """Every scope path an equation of ``fn``'s jaxpr carries, jax's transformation wrappers stripped."""
-    found = set()
-
-    def walk(jaxpr, outer):
-        for eqn in jaxpr.eqns:
-            stack = "/".join(filter(None, (outer, re.sub(r"[A-Za-z_]+\(|\)", "", str(eqn.source_info.name_stack)))))
-            if stack:
-                found.add(stack)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub, stack)  # a sub-jaxpr's stacks are relative to the equation that holds it
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr, "")
-    return found
+_scopes = F.scopes
 
 
 def test_each_layer_type_has_its_scope_with_proj_rope_core_and_gate_and_the_rules_class_them(tokens):
-    cfg = config_of(tokens)
+    cfg = A.config_of()
     w = jax.tree_util.tree_map(jnp.asarray, R.seeded_weights(CUT, 1, STD))
-    scopes = _scopes(lambda p: M.forward(cfg, p, jnp.asarray(bias_of(CUT)), tokens[0][:2])[0], w)
+    scopes = _scopes(lambda p: M.forward(cfg, p, jnp.asarray(A.bias_of(CUT)), tokens[0][:2])[0], w)
     for layer, kind in enumerate(CUT["layer_types"]):
         for part in ("proj", "rope", "core", "gate"):
             assert any(s.startswith(f"layer{layer}/{kind}/{part}") for s in scopes), (layer, kind, part)
@@ -532,46 +274,31 @@ def test_each_layer_type_has_its_scope_with_proj_rope_core_and_gate_and_the_rule
                                                "shared_expert", "expert_mm", "moe_route", "head_loss"}
 
 
-class _Sink:
-    def __init__(self):
-        self.records = []
-
-    def record(self, rec):
-        self.records.append(rec)
-
-
 def _traced_individual(x, y, kw):
-    sink = _Sink()
-    get_registry().reset()
-    spans.set_run_sink(sink)
-    spans.enable()
-    try:
+    with F.traced() as records:
         fitness = M.Lfm2MoeModel.cross_validate_population(x, y, [lfm2_moe_genome().default()], **kw)
-    finally:
-        spans.disable()
-        spans.set_run_sink(None)
     assert np.isfinite(fitness).all()
-    trained = [r["attrs"] for r in sink.records if r["type"] == "span" and (r.get("attrs") or {}).get("steps") == kw["train_steps"]]
+    trained = F.span_attrs(records, steps=kw["train_steps"])
     assert len(trained) == 1
-    return trained[0], sink.records
+    return trained[0], records
 
 
 def test_the_train_span_states_each_masks_heads_and_rotated_columns_on_the_cpu_too(tokens):
-    attrs, _ = _traced_individual(*tokens, model_kwargs(cache_dir=False))
+    attrs, _ = _traced_individual(*tokens, A.model_kwargs(cache_dir=False))
     assert attrs["attention_heads_causal"] == [4, 4] and attrs["attention_heads_window"] == [6]
     assert attrs["attention_rotary_columns_causal"] == [8, 8] and attrs["attention_rotary_columns_window"] == [16]
     assert attrs["attention_kernel_layer_steps"] == 0 and attrs["attention_kernel_layer_steps_window"] == 0
     assert "attention_kernel_pairs_window" not in attrs, "no kernel, no table of visits"
 
 
-def test_one_program_holds_the_kernel_at_two_groups_under_two_masks_and_the_spans_and_the_counter_say_so(kernel_on_the_cpu):
+def test_one_program_holds_the_kernel_at_two_groups_under_two_masks_and_the_spans_and_the_counter_say_so(kernel_on_the_cpu, small_kernel_blocks):
     """The cut's three layers at the published head size over 256 positions with a window of 100, the kernel
     interpreted: 1 windowed layer at 3 query heads to the key-value head and 2 full ones at 2, 2 steps."""
     m = {**CUT, "head_dim": 128, "num_key_value_heads": 1, "num_attention_heads_per_layer": [2, 3, 2], "sliding_window": 100,
          "train_steps": 2}
     tok = np.random.default_rng(1).integers(0, 64, size=(6, 257)).astype(np.int32)
     x, y = tok[:, :-1], tok[:, 1:]
-    kw = model_kwargs(m, compute_dtype="bfloat16", attn_block=128, cache_dir=False)
+    kw = A.model_kwargs(m, compute_dtype="bfloat16", attn_block=128, cache_dir=False)
     programs = M.Lfm2MoeModel.compiled_programs(x, **kw)
     assert programs.attention_kernel_layers == 3 and programs.kernel_layers_by_mask == (("causal", 2), ("window", 1))
     assert programs.heads_by_mask == (("causal", (2, 2)), ("window", (3,)))
@@ -599,34 +326,7 @@ ACCEPTED = {"lfm2_moe": ("lfm2_24b_a2b_ep8", 647_819_520, 10_365_112_320, 2_852_
             "qwen3_next": ("qwen3_next_80b_a3b_ep16", 625_667_136, 10_010_674_176, 6_111_100_928)}
 
 
-def _family_of(name):
-    """``families/<name>/family.py`` loaded as ``run.py`` loads it, and put away again."""
-    names = ("family", "correct", "reference")
-    before = {n: sys.modules.pop(n, None) for n in names}
-    directory = os.path.join(BENCH, "families", name)
-    sys.path.insert(0, directory)
-    try:
-        spec = importlib.util.spec_from_file_location("family", os.path.join(directory, "family.py"))
-        family = importlib.util.module_from_spec(spec)
-        sys.modules["family"] = family
-        spec.loader.exec_module(family)
-        return family
-    finally:
-        sys.path.remove(directory)
-        for n in names:
-            sys.modules.pop(n, None)
-            if before[n] is not None:
-                sys.modules[n] = before[n]
-
-
-def _published_cfg(family_name, config_name):
-    with open(os.path.join(BENCH, "configs", config_name + ".json")) as fh:
-        config = json.load(fh)
-    family = _family_of(family_name)
-    params = family.model_params(config, 5, False)
-    params.pop("seed")
-    cfg = M._normalize_config(np.zeros((config["n_sequences"], config["data"]["seq_len"]), np.int32), params)[0]
-    return config, family, cfg
+_family_of, _published_cfg = F.family_module, F.published_cfg
 
 
 @pytest.mark.parametrize("name", sorted(ACCEPTED))
@@ -647,8 +347,7 @@ def test_an_accepted_configuration_builds_the_tree_and_the_bytes_it_built(name):
 
 
 def _config_file():
-    with open(os.path.join(BENCH, "configs", "laguna_xs2_ep8.json")) as fh:
-        return json.load(fh)
+    return F.config_file("laguna_xs2_ep8")
 
 
 def test_the_configuration_file_holds_the_catalogs_numbers_and_the_cut_is_the_bytes_it_says():
@@ -694,8 +393,7 @@ def test_the_configuration_file_holds_the_catalogs_numbers_and_the_cut_is_the_by
 
 def test_the_cell_runs_the_accepted_mix_as_it_is_under_the_bias_rules_genome():
     config = _config_file()
-    with open(os.path.join(BENCH, "traffic", "lmpopeval_fresh.json")) as f:
-        mix = json.load(f)
+    mix = F.traffic_mix()
     pools = {name: _family_of(name).make_pool(4, [int(mix["pool_seed"])], float(mix["pool_log10_lr_max"]))
              for name in ("laguna", "lfm2_moe")}
     pool = pools["laguna"]
@@ -716,24 +414,12 @@ def test_the_cell_runs_the_accepted_mix_as_it_is_under_the_bias_rules_genome():
 
 @pytest.fixture()
 def layer_metric():
-    """A reader of ``benchmark/layer_metrics/`` by name, loaded as ``run.py``
-    loads it (the family's directory and the harness's on ``sys.path``)."""
-    names = ("mel_spans", "scope_rules", "scope_reduce", "stall_reduce", "spanlib", "trace_reduce", "flops", "family",
-             "correct", "reference")
-    before = {n: sys.modules.pop(n, None) for n in names}
-    sys.path[:0] = [FAMILY, BENCH]
-    try:
-        yield lambda name: _load(os.path.join("..", "..", "layer_metrics", name))
-    finally:
-        del sys.path[:2]
-        for n in names:
-            sys.modules.pop(n, None)
-            if before[n] is not None:
-                sys.modules[n] = before[n]
+    """A reader of ``benchmark/layer_metrics/`` by name, loaded as ``run.py`` loads it."""
+    with F.as_run_py_loads(A.family) as load:
+        yield lambda name: load(f"layer_metrics/{name}")
 
 
-def _span(kind, t, attrs):
-    return {"type": "span", "kind": kind, "t_wall": t, "dur_s": 0.001, "attrs": attrs}
+_span = F.span
 
 
 def test_the_kernel_readers_split_the_train_spans_by_mask_and_the_parent_reads_nothing(layer_metric):
@@ -753,25 +439,8 @@ def test_the_kernel_readers_split_the_train_spans_by_mask_and_the_parent_reads_n
     assert window_reader.read({**run, "records": [train(11.0, attention_kernel_layer_steps=40)]}) is None  # the parent
     assert full_reader.read({**run, "records": records[:1]}) is None
     helper = sys.modules["mel_spans"]
-    assert os.path.dirname(helper.__file__) == FAMILY, "the readers' helper is this family's"
+    assert os.path.dirname(helper.__file__) == A.directory, "the readers' helper is this family's"
     assert helper.core_heads({**run, "records": records}, "sliding_attention") == [64, 64, 64]
     assert helper.core_visits({**run, "records": records}, "sliding_attention")["pairs"] == 15
     assert helper.core_visits({**run, "records": records}, "full_attention") is None
     assert helper.core_heads({**run, "records": records[:1]}, "full_attention") is None
-
-
-def test_every_metric_of_the_cell_has_a_reader_that_reads_nothing_from_an_empty_run_and_the_manifest_is_full(layer_metric):
-    """A program that lacks the spans (the parent's, on the new cell) makes no reader raise.  The manifest holds its
-    most, 128 per-layer metrics: the cell adds none and is appended to accepted ones."""
-    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as fh:
-        manifest = json.load(fh)
-    assert len(manifest["per_layer"]) == 128
-    names = [m["name"] for m in manifest["per_layer"] if CELL in m.get("workloads", ())]
-    assert len(names) == 30 and sum(n.startswith("mel_") for n in names) == 26 and "mel_aux_loss_mean" not in names, names
-    assert {"device_stall_s", "stall_between_programs_s", "stall_host_late_s", "host_tick_late_max_ms"} < set(names)
-    assert all(m["moves"] == ("setup_s" if m["name"] == "mel_first_call_s" else "individuals_per_hour_per_chip")
-               and m["workloads"][-1] == CELL for m in manifest["per_layer"] if m["name"] in names)
-    empty = {"config": _config_file(), "cell": {"name": CELL}, "chips": 1, "units": [], "records": [],
-             "window": (0.0, 1.0), "elapsed": 1.0, "monitor": None, "trace": None, "memory_peak_bytes": 0, "peak": None}
-    for name in names:
-        assert layer_metric(name).read(dict(empty)) is None, name

@@ -1,29 +1,21 @@
-"""The routed family's third architecture (Mellum2-12B-A2.5B-Instruct through
-``models/lfm2_moe.py``) against its plain reference
-(``benchmark/families/mellum/reference.py``), at small sizes on the CPU.
+"""What is Mellum2-12B-A2.5B-Instruct's own among the routed family's tests (the third architecture through
+``models/lfm2_moe.py``, against ``benchmark/families/mellum/reference.py``, at small sizes on the CPU); what every
+architecture is held to (logits, loss and gradients a windowed layer, a full one, one and two periods; two train
+steps; the shares with attention counted once; the row buffer at top-8; refusals; the manifest's readers) is in
+``test_routed_family*.py`` under ``mellum2-`` ids.
 
-System and reference are compared in float32 on seeded weights: per layer kind
-(a windowed layer, a full one, one period) and whole on logits, loss (with the
-balance term) and gradients; over two train steps; the share test ties the
-expert layer's cut to the uncut layer with attention counted once.  Then what is
-the architecture's own: the window through both cores (XLA's query blocks, and
-the fused kernel in Pallas' interpret mode) at a length of several windows and
-at one shorter than the window; the kernel's mask object against the
-reference's 0/1 array entry by entry; the block pairs the kernel visits against
-``flops.py``'s arithmetic; rope by layer type by hand; the grouped products'
-tiles at a contraction of 2304; refusals; the scopes, the spans and the labelled
-counter; the readers of the new per-layer metrics; and that the two
-architectures that were there still build what they built.
+Here: the window through both cores (XLA's query blocks, and the fused kernel in Pallas' interpret mode) at a
+length of several windows and at one shorter than the window; the kernel's mask object against the reference's 0/1
+array entry by entry; the block pairs the kernel visits against ``flops.py``'s arithmetic; rope by layer type by
+hand; the grouped products' tiles at a contraction of 2304; the scopes, the spans and the labelled counter; the
+configuration file, the mix and the readers; and that the two architectures that were there still build what they
+built.
 """
 
 from __future__ import annotations
 
-import importlib.util
-import json
 import math
-import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -31,173 +23,22 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import routed_ladder
+import routed_family as F
 from gentun_tpu import deepseek_v2_genome
 from gentun_tpu.models import lfm2_moe as M
-from gentun_tpu.telemetry import spans
 from gentun_tpu.telemetry.registry import get_registry
+from routed_family import HIGHEST, STD, kernel_on_the_cpu, small_kernel_blocks  # noqa: F401  (the fixtures)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
-FAMILY = os.path.join(BENCH, "families", "mellum")
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(f"mel_family_{os.path.basename(name)}", os.path.join(FAMILY, name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-R = _load("reference")
-flops = _load("flops")
-scope_rules = _load("scope_rules")
-
-ROPE = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
-                           "original_max_position_embeddings": 8192, "beta_fast": 32, "beta_slow": 1,
-                           "attention_factor": 1.2772588722239782},
-        "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
-PERIOD = ["sliding_attention", "sliding_attention", "sliding_attention", "full_attention"]
-MODEL = dict(hidden_size=40, head_dim=16, num_attention_heads=4, num_key_value_heads=2, moe_intermediate_size=24,
-             num_experts=8, num_experts_per_tok=3, held_experts=[2, 4], num_hidden_layers=4, layer_types=PERIOD,
-             vocab_size=64, rms_norm_eps=1e-6, rope_parameters=ROPE, sliding_window=6, train_steps=3)
-GENES = dict(log10_lr=-2.5, warmup_frac=0.5, weight_decay=0.1, beta2=0.95, aux_alpha=0.05)
-HIGHEST = jax.default_matmul_precision("highest")
-STD = 0.15  # narrow layers: wider weights, or the operators vanish beside the residual
-
-
-def model_kwargs(m=MODEL, **over):
-    """``Lfm2MoeModel``'s keyword arguments that make it the reference's model ``m``: the published keys."""
-    kw = {k: m[k] for k in ("hidden_size", "head_dim", "moe_intermediate_size", "num_experts", "num_experts_per_tok",
-                            "num_attention_heads", "num_key_value_heads", "vocab_size", "rope_parameters",
-                            "sliding_window", "train_steps")}
-    kw.update(layer_types=tuple(m["layer_types"]), num_dense_layers=0, held_experts=tuple(m["held_experts"]),
-              norm_eps=m["rms_norm_eps"], qk_norm=False, scoring_func="softmax", norm_topk_prob=True,
-              balance_rule="aux_loss", tie_word_embeddings=False, batch_sequences=2, eval_sequences=2, attn_block=8,
-              compute_dtype="float32")
-    kw.update(over)
-    return kw
+A = F.ARCHS["mellum2"]
+R, flops, scope_rules = A.R, A.flops, A.scope_rules
+MODEL, ROPE, PERIOD = A.model, F.MELLUM_ROPE, F.MELLUM_PERIOD
+NO_BIAS = jnp.zeros((8, 8), jnp.float32)  # the state's bias: zeros, never read under the ``aux_loss`` rule
+_rel = F.rel
 
 
 @pytest.fixture(scope="module")
 def tokens():
-    tok = np.random.default_rng(0).integers(0, 64, size=(10, 25)).astype(np.int32)  # 24 positions: four windows of 6
-    return tok[:, :-1], tok[:, 1:]
-
-
-def config_of(tokens, m=MODEL, **over) -> M.Lfm2MoeConfig:
-    return M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(m, **over)).config
-
-
-NO_BIAS = jnp.zeros((8, 8), jnp.float32)  # the state's bias: zeros, never read under the ``aux_loss`` rule
-
-LAYER_CASES = {"a_windowed_layer": {**MODEL, "num_hidden_layers": 1, "layer_types": ["sliding_attention"], "held_experts": [1, 5]},
-               "a_full_layer": {**MODEL, "num_hidden_layers": 1, "layer_types": ["full_attention"], "held_experts": [1, 5]},
-               "one_period": {**MODEL, "held_experts": [1, 5]},
-               "two_periods": {**MODEL, "num_hidden_layers": 8, "layer_types": PERIOD * 2}}
-
-
-@pytest.mark.parametrize("case", sorted(LAYER_CASES))
-def test_logits_loss_with_the_balance_term_and_gradients_match_the_reference(case, tokens):
-    m = LAYER_CASES[case]
-    cfg = config_of(tokens, m)
-    assert cfg.head_dim == 16 != cfg.hidden_size // cfg.num_attention_heads and cfg.num_dense_layers == 0
-    w = R.seeded_weights(m, 7, STD)
-    assert "q_norm" not in w["layers"][0]["attn"] and M.param_shapes(cfg)["layers"][0]["attn"].keys() == \
-        w["layers"][0]["attn"].keys()
-    x, y = tokens[0][:2], tokens[1][:2]
-    alpha = 0.05
-
-    def system_loss(params):
-        logits, load, stats = M.forward(cfg, params, NO_BIAS, x, remat=True)
-        return M.token_loss(logits, y).mean() + alpha * stats.balance, (logits, load, stats)
-
-    def reference_loss(params):
-        out = [R.forward(m, params, xs) for xs in x]
-        nll = jnp.mean(jnp.stack([R.token_loss(o[0], ys) for o, ys in zip(out, y)]))
-        balance = sum(o[2] for o in out) / len(out)
-        return nll + alpha * balance, (jnp.stack([o[0] for o in out]), sum(o[1] for o in out), balance)
-
-    with HIGHEST:
-        (loss, (logits, load, stats)), grads = jax.jit(jax.value_and_grad(system_loss, has_aux=True))(w)
-        (ref_loss, (ref_logits, ref_load, ref_balance)), ref_grads = jax.jit(
-            jax.value_and_grad(reference_loss, has_aux=True))(w)
-    np.testing.assert_allclose(logits, ref_logits, atol=2e-5)
-    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
-    np.testing.assert_allclose(stats.balance, ref_balance, rtol=1e-6)
-    assert float(ref_balance) > 0.9 * m["num_hidden_layers"]  # ~1 a routed layer, and every layer is routed
-    np.testing.assert_array_equal(load, ref_load)
-    assert int(stats.dropped) == 0
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
-        np.testing.assert_allclose(g, r, atol=2e-6, rtol=1e-4, err_msg=jax.tree_util.keystr(path))
-        assert float(jnp.abs(r).max()) > 0 or "embed" in str(path), \
-            f"{jax.tree_util.keystr(path)}: the reference's gradient is all zero"
-
-
-def _program_steps(programs, weights, x, y, rows, steps, genes=GENES):
-    state = programs.init(jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
-    state = {**state, "params": jax.tree_util.tree_map(jnp.asarray, weights)}
-    losses, loads = [], []
-    for s in range(steps):
-        state, loss, held = programs.train_step(state, jnp.asarray(x), jnp.asarray(y), jnp.asarray(rows),
-                                                jnp.asarray(M.gene_vector(genes)), np.int32(s))
-        losses.append(float(loss))
-        loads.append(np.asarray(held))
-    return state, losses, loads
-
-
-def test_two_train_steps_match_the_reference(tokens):
-    x, y = tokens
-    programs = M.Lfm2MoeModel.compiled_programs(x, **model_kwargs())
-    assert programs.config.gene_names == tuple(deepseek_v2_genome().names)
-    w = R.seeded_weights(MODEL, 5, STD)
-    rows = np.array([[0, 1], [2, 3], [4, 5]], np.int32)
-    with HIGHEST:
-        state, losses, loads = _program_steps(programs, w, x, y, rows, 2)
-        ref = R.train(MODEL, w, [(x[r], y[r]) for r in rows[:2]], GENES)
-    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-6)  # the balance term included
-    np.testing.assert_allclose(float(state["aux_loss"]), sum(ref["balances"]), rtol=1e-6)
-    for got, want in zip(loads, ref["loads"]):
-        np.testing.assert_array_equal(got, want[:, 2:4])
-    for (path, a), b, start in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
-                                   jax.tree_util.tree_leaves(ref["weights"]), jax.tree_util.tree_leaves(w)):
-        change, ref_change = np.asarray(a) - start, np.asarray(b) - start
-        assert np.abs(ref_change).max() > 0, jax.tree_util.keystr(path)
-        np.testing.assert_allclose(change, ref_change, atol=3e-5, err_msg=jax.tree_util.keystr(path))
-    with HIGHEST:
-        got = programs.eval(state["params"], state["bias"], jnp.asarray(x), jnp.asarray(y), jnp.asarray([8, 9]))
-        want = R.eval_token_loss(MODEL, ref["weights"], x[8:10], y[8:10])
-    np.testing.assert_allclose(got, want, atol=2e-5)
-
-
-@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
-def test_the_eight_shares_with_attention_counted_once_add_up_to_the_uncut_layer(kind, tokens):
-    """16 experts in 8 shares of 2, 8 a token: each share's program computes the
-    attention, the residual and its own routed experts' part, the weights
-    normalised over all the chosen eight; the routed parts, with what every
-    share computes alike counted once, are the uncut reference's layer output."""
-    m = {**MODEL, "num_hidden_layers": 1, "layer_types": [kind], "num_experts": 16, "num_experts_per_tok": 8}
-    x = tokens[0][:2]
-    uncut = {**m, "held_experts": [0, 16]}
-    w_all = R.seeded_weights(uncut, 11, STD)
-    layer_w = w_all["layers"][0]
-    embedded = w_all["embed"][x]
-    share_of = lambda first, last: dict(layer_w, moe={k: (v[first:last] if k in ("w1", "w3", "w2") else v)
-                                                      for k, v in layer_w["moe"].items()})
-    identity = lambda a: a
-    with HIGHEST:
-        whole = jnp.stack([R.layer(uncut, 0, identity, layer_w, jnp.asarray(e))[0] for e in embedded])
-        # attention and residual, no routed expert: what every share computes alike
-        alike = jnp.stack([R.layer({**uncut, "held_experts": [0, 0]}, 0, identity, share_of(0, 0), jnp.asarray(e))[0]
-                           for e in embedded])
-        total = alike
-        for first in range(0, 16, 2):
-            cfg = config_of(tokens, {**m, "held_experts": [first, first + 2]})
-            out, _ = M._layer(cfg, 0, jnp.float32, share_of(first, first + 2), None, jnp.asarray(embedded))
-            part = out - alike
-            assert float(jnp.abs(part).max()) > 0
-            total = total + part
-    np.testing.assert_allclose(total, whole, atol=2e-5)
-    assert float(jnp.abs(whole - alike).max()) > 1e-3, "the routed experts are part of the layer"
+    return A.tokens
 
 
 # -- the window, through both cores ---------------------------------------------------------------------
@@ -250,33 +91,10 @@ def test_the_blockwise_cores_cost_follows_the_window():
     assert widths == {16, 32, 48}, widths  # 16 and 32 at the start, then the two blocks back and the block itself
 
 
-@pytest.fixture()
-def kernel_on_the_cpu(monkeypatch):
-    """The fused core chosen whatever the backend, its kernels interpreted: the
-    library's own factory is given ``interpret=True``, the program has no such knob."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
-
-    monkeypatch.setattr(splash, "make_splash_mqa_single_device",
-                        __import__("functools").partial(splash.make_splash_mqa_single_device, interpret=True))
-    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
-    M._programs.cache_clear()
-    M._kernel_visits.cache_clear()
-    yield
-    M._programs.cache_clear()
-    M._kernel_visits.cache_clear()
-
-
-def _rel(a, b) -> float:
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
-
-
 @pytest.mark.parametrize("length,window", [(512, 128), (256, 384)])
-def test_the_fused_core_under_a_window_is_the_blockwise_core_to_bfloat16(length, window, kernel_on_the_cpu, monkeypatch):
+def test_the_fused_core_under_a_window_is_the_blockwise_core_to_bfloat16(length, window, kernel_on_the_cpu, small_kernel_blocks):
     """Four windows long, and shorter than the window; forward and gradients, at head size 128 with 2 query
     heads a key-value head."""
-    monkeypatch.setattr(M, "_ATTN_KERNEL_BLOCKS", dict(block_q=128, block_kv=128, block_kv_compute=128,
-                                                       block_q_dkv=128, block_kv_dkv=128, block_kv_dkv_compute=128))
     q, k, v = (a.astype(jnp.bfloat16) for a in _core_case(length, sequences=1, kv_heads=1, group=2, head=128))
     scale = 1.0 / math.sqrt(128)
 
@@ -303,7 +121,7 @@ def _attention_case(kind: str, length: int = 256, sequences: int = 2, window: in
     key-value heads of two query heads each, 128 columns a head, no norm of q and k, rope by layer type."""
     cfg = M.Lfm2MoeConfig(hidden_size=64, head_dim=128, num_attention_heads=4, num_key_value_heads=2, qk_norm=False,
                           sliding_window=window, norm_eps=1e-6, seq_len=length, attn_block=64,
-                          rope_parameters=tuple(sorted((k, tuple(sorted(b.items()))) for k, b in ROPE.items())),
+                          rope_parameters=F.rope_table(ROPE),
                           layer_types=("sliding_attention", "full_attention"), layer_ids=(0, 1), num_dense_layers=0)
     rng = np.random.default_rng([length, kind == "full_attention"])
     shapes = M.param_shapes(cfg)["layers"][0]["attn"]
@@ -312,48 +130,29 @@ def _attention_case(kind: str, length: int = 256, sequences: int = 2, window: in
     return cfg, p, x
 
 
-def _value_and_gradients(operator, p, x):
-    """(output, gradients of the weights, gradient of the input) of ``sum(operator(p, x) * probe)``, jitted."""
-    probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
-
-    def value(p, x):
-        out = operator(p, x)
-        return jnp.sum(out.astype(jnp.float32) * probe), out
-
-    (_, out), (dp, dx) = jax.jit(jax.value_and_grad(value, argnums=(0, 1), has_aux=True))(p, x)
-    return out, dp, dx
-
-
 @pytest.mark.parametrize("against", ["blockwise-bfloat16", "reference-float32"])
 @pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
-def test_attention_and_every_gradient_by_the_fused_core(kind, against, kernel_on_the_cpu, monkeypatch):
+def test_attention_and_every_gradient_by_the_fused_core(kind, against, kernel_on_the_cpu, small_kernel_blocks):
     """``_attention`` whole (two sequences; the head-major products, rope as a product with the signed permutation
     under the layer type's frequencies and amplitude, scale and cast, the kernel under the type's mask, the output
     product over the kernel's head-major output) with the fused core interpreted, two blocks a side: in bfloat16
     against the same call by the blockwise core, in float32 against ``reference.attention`` a sequence; the output
     within two bfloat16 steps of its size, the gradients of the input and of every weight within 1% in norm (the
     bounds of ``test_deepseek_v2.py``'s latent operator)."""
-    monkeypatch.setattr(M, "_ATTN_KERNEL_BLOCKS", dict(block_q=128, block_kv=128, block_kv_compute=128,
-                                                       block_q_dkv=128, block_kv_dkv=128, block_kv_dkv_compute=128))
     cfg, p, x = _attention_case(kind)
     dtype = jnp.bfloat16 if against == "blockwise-bfloat16" else jnp.float32
     x = x.astype(dtype)
     operator = lambda p, x: M._attention(p, x, cfg, dtype, kind)
-    out, dp, dx = _value_and_gradients(operator, p, x)
+    got = F.value_and_gradients(operator, p, x)
     if against == "blockwise-bfloat16":
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(M, "_use_attention_kernel", lambda length: False)
-            ref, ref_dp, ref_dx = _value_and_gradients(operator, p, x)
+        want = F.by_the_blockwise_core(operator, p, x)
     else:
         m = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=128, rope_parameters=ROPE, sliding_window=96)
         with HIGHEST:
-            ref, ref_dp, ref_dx = _value_and_gradients(
+            want = F.value_and_gradients(
                 lambda p, x: jnp.stack([R.attention(p, xs, m, kind, lambda a: a) for xs in x]), p, x)
-    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
-    assert np.abs(ref).max() > 0.5 and np.abs(out - ref).max() <= 2.0 ** -7 * np.abs(ref).max()
-    assert _rel(dx, ref_dx) < 0.01
-    for name in ("q", "k", "v", "o"):
-        assert float(jnp.abs(ref_dp[name]).max()) > 0 and _rel(dp[name], ref_dp[name]) < 0.01, name
+    F.assert_within_bfloat16(got, want, ("q", "k", "v", "o"))
+    ref = want[0]
     other = "full_attention" if kind == "sliding_attention" else "sliding_attention"
     assert _rel(jax.jit(lambda p, x: M._attention(p, x, cfg, dtype, other))(p, x), ref) > 0.05, "the type decides"
 
@@ -374,15 +173,6 @@ def test_rope_on_whole_heads_is_the_sliced_rope_to_the_last_bit():
             np.testing.assert_array_equal(np.asarray(back_whole(g)[0], np.float32), np.asarray(back(g)[0], np.float32))
 
 
-def _equations(jaxpr, scope=""):
-    """(primitive, the named scopes it was traced under, its outputs' avals) of every equation, nested ones too."""
-    for eqn in jaxpr.eqns:
-        here = "/".join(filter(None, [scope, str(eqn.source_info.name_stack)]))
-        yield eqn.primitive.name, here, [v.aval for v in eqn.outvars]
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(sub, here)
-
-
 @pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
 def test_what_reaches_the_fused_core_is_written_once_head_major_in_the_compute_dtype(kind, monkeypatch):
     """With the kernel chosen, the traced operator has no float32 array of tokens x key-value heads x head size
@@ -393,7 +183,7 @@ def test_what_reaches_the_fused_core_is_written_once_head_major_in_the_compute_d
     monkeypatch.setattr(M, "_use_attention_kernel", lambda length: True)
     cfg, p, x = _attention_case(kind)
     s, length, nkv, group, hd = 2, 256, 2, 2, 128
-    traced = list(_equations(jax.make_jaxpr(lambda p, x: M._attention(p, x, cfg, jnp.bfloat16, kind))(p, x).jaxpr))
+    traced = list(F.equations(jax.make_jaxpr(lambda p, x: M._attention(p, x, cfg, jnp.bfloat16, kind))(p, x).jaxpr))
     scopes = lambda scope: set(scope.split("/"))
     assert all(any(part in scopes(scope) for _, scope, _ in traced) for part in ("proj", "rope", "core"))
     arrays = [(name, scope, aval) for name, scope, avals in traced for aval in avals if hasattr(aval, "shape")]
@@ -416,14 +206,7 @@ def test_what_reaches_the_fused_core_is_written_once_head_major_in_the_compute_d
 def test_the_kernels_mask_object_is_the_references_array_entry_by_entry(length, window):
     """What the fused kernel is handed (the library's ``LocalMask`` / ``CausalMask``, evaluated on the host: no
     TPU) against ``reference.visible``'s 0/1 array: every entry, in slices of rows at the published length."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
-
-    real = splash.make_splash_mqa_single_device
-    try:
-        splash.make_splash_mqa_single_device = lambda mask, **kw: mask.masks  # what the kernel would be made from
-        windowed, causal = M._splash_kernel(length, 2, window), M._splash_kernel(length, 2, None)
-    finally:
-        splash.make_splash_mqa_single_device = real
+    windowed, causal = F.masks_handed_to_the_kernel(length, 2, window), F.masks_handed_to_the_kernel(length, 2, None)
     assert len(windowed) == len(causal) == 2
     j = np.arange(length)[None, :]
     for first in range(0, length, 1024):
@@ -458,7 +241,7 @@ def test_a_windowed_layers_kernel_visits_fewer_block_pairs_and_flops_py_counts_t
 
 
 def test_rope_parameters_choose_frequencies_and_amplitude_by_layer_type():
-    cfg = M.Lfm2MoeConfig(rope_parameters=tuple(sorted((k, tuple(sorted(v.items()))) for k, v in ROPE.items())),
+    cfg = M.Lfm2MoeConfig(rope_parameters=F.rope_table(ROPE),
                           layer_types=tuple(PERIOD), layer_ids=(0, 1, 2, 3), sliding_window=8)
     theta, scaling = cfg.rope_of("sliding_attention")
     assert theta == 5e5 and scaling is None and cfg.window_of("sliding_attention") == 8
@@ -516,60 +299,14 @@ def test_the_grouped_product_at_2304_by_896_is_the_plain_one():
     np.testing.assert_allclose(np.asarray(got)[:start], want[:start], atol=1e-4)
 
 
-# -- the row buffer's ladder at this architecture's routing -------------------------------------------------
-
-
-#: The published routing at a small width: 64 experts, 8 a token, 8 held, weights normalised over the chosen.  At 512
-#: tokens the mean share is 512 rows; 1.25 and 2.75 shares in tiles of 512, and the worst case of 8.
-TOP_8 = {**MODEL, "num_hidden_layers": 1, "layer_types": ["full_attention"], "num_experts": 64, "num_experts_per_tok": 8,
-         "held_experts": [8, 16]}
-LADDER_HEIGHTS = (1024, 1536, 4096)
-
-
-@pytest.mark.parametrize("count,rung", routed_ladder.counts_at_the_rungs(LADDER_HEIGHTS))
-def test_the_row_buffers_ladder_gives_the_worst_case_heights_layer_at_top_8(tokens, count, rung):
-    """Every rung filled to its last row, and one row more: the height the ``switch`` takes gives the value and
-    the gradients of the worst-case height alone, and drops nothing."""
-    cfg = config_of(tokens, TOP_8)
-    assert M._row_buffer_heights(cfg, 512) == LADDER_HEIGHTS and cfg.norm_topk_prob and cfg.scoring_func == "softmax"
-    w = R.seeded_weights(TOP_8, 3, STD)["layers"][0]["moe"]
-    routed_ladder.assert_the_ladders_layer_is_the_worst_case_heights(cfg, w, None, 512, count, rung, "float32", 1e-6)
-
-
 # -- refusals, scopes, spans, counters --------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("over,message", [
-    (dict(sliding_window=0), "sliding_window"),
-    (dict(rope_parameters={"full_attention": {"rope_type": "yarn", "rope_theta": 5e5}}), "yarn needs"),
-    (dict(rope_parameters={"conv": {"rope_theta": 5e5}}), "rope_parameters"),
-    (dict(rope_parameters={"full_attention": {"rope_type": "linear", "rope_theta": 5e5}}), "rope_type"),
-    (dict(head_dim=15), "head_dim"),
-    (dict(num_key_value_heads=3), "key-value heads"),
-])
-def test_a_configuration_that_cannot_be_this_architecture_is_refused(over, message, tokens):
-    with pytest.raises(ValueError, match=message):
-        M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(**over))
-
-
-def _scopes(fn, *args) -> set:
-    """Every scope path an equation of ``fn``'s jaxpr carries, jax's transformation wrappers stripped."""
-    found = set()
-
-    def walk(jaxpr, outer):
-        for eqn in jaxpr.eqns:
-            stack = "/".join(filter(None, (outer, re.sub(r"[A-Za-z_]+\(|\)", "", str(eqn.source_info.name_stack)))))
-            if stack:
-                found.add(stack)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub, stack)  # a sub-jaxpr's stacks are relative to the equation that holds it
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr, "")
-    return found
+_scopes = F.scopes
 
 
 def test_each_layer_type_has_its_own_scope_with_proj_rope_and_core_inside(tokens):
-    cfg = config_of(tokens)
+    cfg = A.config_of()
     w = jax.tree_util.tree_map(jnp.asarray, R.seeded_weights(MODEL, 1, STD))
     scopes = _scopes(lambda p: M.forward(cfg, p, NO_BIAS, tokens[0][:2])[0], w)
     for layer, kind in enumerate(PERIOD):
@@ -639,49 +376,32 @@ def test_the_architectures_that_were_there_build_the_tree_and_the_scopes_they_bu
     assert top == {"embed", "layer0", "layer1", "head"}, top
 
 
-def test_spans_and_the_labelled_counter_split_the_kernels_layer_steps_by_mask(tokens, kernel_on_the_cpu, monkeypatch):
-    """Two periods at 128 positions with a window of 32, the kernel interpreted: 6 windowed and 2 full layers x 3
+def test_spans_and_the_labelled_counter_split_the_kernels_layer_steps_by_mask(kernel_on_the_cpu, small_kernel_blocks):
+    """One period at 512 positions with a window of 100, the kernel interpreted (the split is by layer type, a
+    period has both; PR 45 cut the second period, half the test's 73 s): 3 windowed layers and 1 full one x 3
     steps an individual on the ``train`` span and on ``attention_kernel_layer_steps_total{mask}``, and the block
     pairs each mask's kernel visits as static attributes."""
-    monkeypatch.setattr(M, "_ATTN_KERNEL_BLOCKS", dict(block_q=128, block_kv=128, block_kv_compute=128,
-                                                       block_q_dkv=128, block_kv_dkv=128, block_kv_dkv_compute=128))
-    m = {**MODEL, "num_hidden_layers": 8, "layer_types": PERIOD * 2, "head_dim": 128, "num_attention_heads": 2,
-         "num_key_value_heads": 1, "sliding_window": 100}
+    m = {**MODEL, "head_dim": 128, "num_attention_heads": 2, "num_key_value_heads": 1, "sliding_window": 100}
     tok = np.random.default_rng(1).integers(0, 64, size=(6, 513)).astype(np.int32)
     x, y = tok[:, :-1], tok[:, 1:]
-    kw = model_kwargs(m, compute_dtype="bfloat16", attn_block=128, cache_dir=False)
+    kw = A.model_kwargs(m, compute_dtype="bfloat16", attn_block=128, cache_dir=False)
     programs = M.Lfm2MoeModel.compiled_programs(x, **kw)
-    assert programs.attention_kernel_layers == 8 and programs.kernel_layers_by_mask == (("causal", 2), ("window", 6))
+    assert programs.attention_kernel_layers == 4 and programs.kernel_layers_by_mask == (("causal", 1), ("window", 3))
     visits = {mask: dict(v) for mask, v in programs.kernel_visits}
     assert visits["causal"]["pairs"] == 10 and visits["window"]["pairs"] == 7  # of 16 at 4 x 4 blocks of 128
-
-    class Sink:
-        def __init__(self):
-            self.records = []
-
-        def record(self, rec):
-            self.records.append(rec)
-
-    sink = Sink()
-    get_registry().reset()
-    spans.set_run_sink(sink)
-    spans.enable()
-    try:
+    with F.traced() as records:
         fitness = M.Lfm2MoeModel.cross_validate_population(x, y, [deepseek_v2_genome().default()], **kw)
-    finally:
-        spans.disable()
-        spans.set_run_sink(None)
     assert np.isfinite(fitness).all()
-    trained = [r["attrs"] for r in sink.records if r["type"] == "span" and (r.get("attrs") or {}).get("steps") == 3]
+    trained = F.span_attrs(records, steps=3)
     assert len(trained) == 1
     attrs = trained[0]
-    assert attrs["attention_kernel_layer_steps"] == 24 and attrs["attention_kernel_layer_steps_window"] == 18 \
-        and attrs["attention_kernel_layer_steps_causal"] == 6
+    assert attrs["attention_kernel_layer_steps"] == 12 and attrs["attention_kernel_layer_steps_window"] == 9 \
+        and attrs["attention_kernel_layer_steps_causal"] == 3
     assert attrs["attention_kernel_pairs_window"] == 7 and attrs["attention_kernel_pairs_causal"] == 10
     assert attrs["attention_kernel_elements_window"] == 7 * 128 * 128 == attrs["attention_kernel_elements_bwd_window"]
     counter = get_registry().counter
-    assert counter("attention_kernel_layer_steps_total", mask="window").value == 18
-    assert counter("attention_kernel_layer_steps_total", mask="causal").value == 6
+    assert counter("attention_kernel_layer_steps_total", mask="window").value == 9
+    assert counter("attention_kernel_layer_steps_total", mask="causal").value == 3
 
 
 def test_every_matrix_starts_at_the_one_deviation_of_the_routed_family(tokens):
@@ -689,7 +409,7 @@ def test_every_matrix_starts_at_the_one_deviation_of_the_routed_family(tokens):
     configurations' start (what else was tried, and what it did to the routing, is PERF.md's, PR 34)."""
     key, h = jax.random.PRNGKey(3), jnp.asarray([1, 2], jnp.uint32)
     big = {**MODEL, "hidden_size": 128, "moe_intermediate_size": 64, "vocab_size": 64}
-    params = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs(big)).init(key, h)["params"]
+    params = M.Lfm2MoeModel.compiled_programs(tokens[0], **A.model_kwargs(big)).init(key, h)["params"]
     for path, a in jax.tree_util.tree_flatten_with_path(params)[0]:
         if "norm" in path[-1].key:
             assert float(jnp.abs(a - 1).max()) == 0
@@ -698,7 +418,7 @@ def test_every_matrix_starts_at_the_one_deviation_of_the_routed_family(tokens):
 
 
 def test_on_the_cpu_every_core_falls_back_and_the_spans_say_so(tokens):
-    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **model_kwargs())
+    programs = M.Lfm2MoeModel.compiled_programs(tokens[0], **A.model_kwargs())
     assert programs.attention_kernel_layers == 0 and programs.kernel_layers_by_mask == (("causal", 0), ("window", 0))
     assert programs.kernel_visits == ()
 
@@ -707,8 +427,7 @@ def test_on_the_cpu_every_core_falls_back_and_the_spans_say_so(tokens):
 
 
 def _config_file():
-    with open(os.path.join(BENCH, "configs", "mellum2_12b_a2p5b_ep8.json")) as fh:
-        return json.load(fh)
+    return F.config_file("mellum2_12b_a2p5b_ep8")
 
 
 def test_the_configuration_file_holds_the_catalogs_numbers_and_the_cut_is_the_bytes_it_says():
@@ -722,22 +441,9 @@ def test_the_configuration_file_holds_the_catalogs_numbers_and_the_cut_is_the_by
     assert config["rope_parameters"] == ROPE and config["norm_topk_prob"] is True
     assert set(config["reduced"]) == {"num_hidden_layers", "num_experts_held", "vocab_size", "train_steps", "n_sequences"}
     assert config["vocab_size"] * 8 == config["published"]["vocab_size"] == 98304 and config["num_hidden_layers"] == 8
-    names = ("family", "correct", "reference")
-    before = {n: sys.modules.pop(n, None) for n in names}
-    sys.path.insert(0, FAMILY)
-    try:
-        family = _load("family")
-        params = family.model_params(config, 5, rehearsal=False)
-        m = family.model_block(config)
-    finally:
-        sys.path.remove(FAMILY)
-        for n in names:
-            sys.modules.pop(n, None)
-            if before[n] is not None:
-                sys.modules[n] = before[n]
+    _, family, cfg = F.published_cfg(A.family, "mellum2_12b_a2p5b_ep8")
+    m = family.model_block(config)
     assert m["layer_types"] == PERIOD * 2
-    params.pop("seed")
-    cfg = M._normalize_config(np.zeros((config["n_sequences"], config["data"]["seq_len"]), np.int32), params)[0]
     need = M.training_bytes(cfg)
     assert need["params"] == 624_072_960 and need["state"] == 9_985_167_360
     assert cfg.tokens_per_step == 16384  # a mean share of 16,384 rows; 45,056 are the 2.75 shares of PR 29
@@ -757,32 +463,15 @@ def test_the_cell_runs_the_accepted_mix_as_it_is():
     hotter than the mix's cap; the pool the other ``aux_loss`` configuration's cell scores, recipe for recipe.
     The configuration file has no say in the traffic."""
     config = _config_file()
-    with open(os.path.join(BENCH, "traffic", "lmpopeval_fresh.json")) as f:
-        mix = json.load(f)
-    names = ("family", "correct", "reference")
-    before = {n: sys.modules.pop(n, None) for n in names}
+    mix = F.traffic_mix()
     pools = {}
-    try:
-        for name in ("mellum", "deepseek_v2"):
-            directory = os.path.join(BENCH, "families", name)
-            sys.path.insert(0, directory)
-            try:
-                spec = importlib.util.spec_from_file_location("family", os.path.join(directory, "family.py"))
-                family = importlib.util.module_from_spec(spec)
-                sys.modules["family"] = family
-                spec.loader.exec_module(family)
-                pools[name] = family.make_pool(4, [int(mix["pool_seed"])], float(mix["pool_log10_lr_max"]))
-                if name == "mellum":
-                    small = {**config, "n_sequences": 2, "data": {**config["data"], "seq_len": 8}}
-                    assert family.make_inputs(small, mix, 3)["pool"] == pools[name]
-            finally:
-                sys.path.remove(directory)
-                for n in names:
-                    sys.modules.pop(n, None)
-    finally:
-        for n in names:
-            if before[n] is not None:
-                sys.modules[n] = before[n]
+    for name in ("mellum", "deepseek_v2"):
+        with F.as_run_py_loads(name) as load:
+            family = load("family")
+            pools[name] = family.make_pool(4, [int(mix["pool_seed"])], float(mix["pool_log10_lr_max"]))
+            if name == "mellum":
+                small = {**config, "n_sequences": 2, "data": {**config["data"], "seq_len": 8}}
+                assert family.make_inputs(small, mix, 3)["pool"] == pools[name]
     pool = pools["mellum"]
     assert "pool_log10_lr_max" not in config and mix["pool_log10_lr_max"] == -3.5
     assert len(pool) == config["population"] == 4 and pool == pools["deepseek_v2"]
@@ -792,20 +481,9 @@ def test_the_cell_runs_the_accepted_mix_as_it_is():
 
 @pytest.fixture()
 def layer_metric():
-    """A reader of ``benchmark/layer_metrics/`` by name, loaded as ``run.py``
-    loads it (the family's directory and the harness's on ``sys.path``)."""
-    names = ("mel_spans", "scope_rules", "scope_reduce", "spanlib", "trace_reduce", "flops", "family", "correct",
-             "reference")
-    before = {n: sys.modules.pop(n, None) for n in names}
-    sys.path[:0] = [FAMILY, BENCH]
-    try:
-        yield lambda name: _load(os.path.join("..", "..", "layer_metrics", name))
-    finally:
-        del sys.path[:2]
-        for n in names:
-            sys.modules.pop(n, None)
-            if before[n] is not None:
-                sys.modules[n] = before[n]
+    """A reader of ``benchmark/layer_metrics/`` by name, or a file of the family, loaded as ``run.py`` loads it."""
+    with F.as_run_py_loads(A.family) as load:
+        yield lambda name: load(name if name == "family" else f"layer_metrics/{name}")
 
 
 def test_every_seed_gives_the_window_the_same_work_and_the_check_its_own_inputs(layer_metric):
@@ -813,9 +491,7 @@ def test_every_seed_gives_the_window_the_same_work_and_the_check_its_own_inputs(
     weights and the tokens from the configuration's ``window_seed``, so that no seed's routing gives its run more rows
     than another's (PERF.md, PR 34: the check's refusal); the order of a call is ``--seed``'s (``traffic_kinds/lmpopeval.py``), and so are the tokens
     the comparison that decides ``correct`` runs on, with its weights and batches (``correct.check_inputs``)."""
-    family = _load("family")
-    with open(os.path.join(BENCH, "traffic", "lmpopeval_fresh.json")) as f:
-        mix = json.load(f)
+    family, mix = layer_metric("family"), F.traffic_mix()
     config = _config_file()
     small = {**config, "n_sequences": 6, "data": {**config["data"], "seq_len": 16}}
     a, b, again = (family.make_inputs(small, mix, seed) for seed in (3, 2147484001, 3))
@@ -829,8 +505,7 @@ def test_every_seed_gives_the_window_the_same_work_and_the_check_its_own_inputs(
     assert np.array_equal(a["check_x"][:, 1:], a["check_y"][:, :-1]) and np.array_equal(a["x"][:, 1:], a["y"][:, :-1])
 
 
-def _span(kind, t, attrs):
-    return {"type": "span", "kind": kind, "t_wall": t, "dur_s": 0.001, "attrs": attrs}
+_span = F.span
 
 
 def test_the_kernel_readers_split_the_windows_train_spans_by_mask_and_the_parent_reads_nothing(layer_metric):
@@ -845,26 +520,3 @@ def test_the_kernel_readers_split_the_windows_train_spans_by_mask_and_the_parent
     assert full_reader.read({**window, "records": records}) == 16
     assert window_reader.read({**window, "records": [train(11.0, attention_kernel_layer_steps=64)]}) is None  # the parent
     assert full_reader.read({**window, "records": records[:1]}) is None
-
-
-def test_the_row_buffer_reader_divides_the_rows_the_heights_ran_by_the_rows_routed_and_the_parent_reads_nothing(
-        layer_metric):
-    reader = layer_metric("mel_row_buffer_rows_per_routed_row")
-    routed_ladder.assert_the_reader_divides_the_rows_run_by_the_rows_routed(reader)
-
-
-def test_every_mel_metric_of_the_manifest_has_a_reader_and_reads_nothing_from_an_empty_run(layer_metric):
-    """A program that lacks the spans (the parent's, on the new cell's readers) makes no reader raise."""
-    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as fh:
-        manifest = json.load(fh)
-    cell = "mellum2_12b_a2p5b_ep8.popeval"
-    mine = [m for m in manifest["per_layer"] if m.get("workloads", [None])[0] == cell]
-    names = [m["name"] for m in mine]
-    assert len(names) == 27 and all(n.startswith("mel_") for n in names), names  # 26 of PR 34, the row buffer's of PR 39
-    # since PR 42 (the manifest full at 128) a second cell with window and full attention mixed reads all of them but the balance term's
-    assert {m["name"] for m in mine if m["workloads"] == [cell]} == {"mel_aux_loss_mean"}
-    assert all(m["workloads"] == [cell, "laguna_xs2_ep8.popeval"] for m in mine if m["name"] != "mel_aux_loss_mean")
-    empty = {"config": _config_file(), "cell": {"name": cell}, "chips": 1, "units": [], "records": [],
-             "window": (0.0, 1.0), "elapsed": 1.0, "monitor": None, "trace": None, "memory_peak_bytes": 0, "peak": None}
-    for name in names:
-        assert layer_metric(name).read(dict(empty)) is None, name
