@@ -1,13 +1,14 @@
 """Routed language models as fitness models: one expert-parallel rank's share, trained under a recipe genome.
 
-The second jax family beside the Genetic-CNN (``models/cnn.py``), and five
+The second jax family beside the Genetic-CNN (``models/cnn.py``), and six
 architectures of it, told apart by the configuration alone (which operator a
-layer has, which mask, which rope on how many of a head's columns and how many
-query heads an attention layer's type gives it, whether its output passes a
-gate, how the router scores, whether shared experts stand beside the routed
-ones and behind a gate, whether the head is tied, which balance rule runs): one
-evaluator, one train step builder, one expert layer, one causal core and one
-optimizer serve all.
+layer has -- or whether it is an operator alone or a feed-forward alone --,
+which mask, which rope on how many of a head's columns and how many query heads
+an attention layer's type gives it, whether its output passes a gate, how the
+router scores, whether the experts are gated and in which state they work,
+whether shared experts stand beside the routed ones and behind a gate, whether
+the head is tied, which balance rule runs): one evaluator, one train step
+builder, one expert layer, one causal core and one optimizer serve all.
 
 ``LFM2-24B-A2B`` (``model_type`` ``lfm2_moe``,
 https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json; the
@@ -121,6 +122,37 @@ add one shared expert::
                      + W2_s (silu(W1_s x) * W3_s x)          the shared expert, unscaled, whole on every rank
     loss = cross-entropy (head untied); the bias ``b`` steps outside the gradient as LFM2's does
 
+``NVIDIA-Nemotron-3-Super-120B-A12B`` (``model_type`` ``nemotron_h``,
+https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16/blob/main/config.json):
+a block is ONE of a Mamba-2 mixer, an attention and a routed feed-forward, with one norm
+and one residual add (``layer_types`` of ``mamba2``, ``full_attention`` and ``routed``: a
+``routed`` entry makes every layer of the model one half, :meth:`Lfm2MoeConfig.halves_of`);
+the mixer is held by a share of its heads, whole groups (``held_mamba_heads``), as the
+expert layer by a share of its experts; the experts are two matrices under a squared ReLU
+and work in a latent state narrower than the residual stream::
+
+    x <- x + Mix(RMSNorm(x))
+    Mix = mamba2:  [z | x | B | C | dt] = W_in u, the held heads' z, x and dt and their groups' B and C (head h
+         reads group h // (heads / groups));  [x | B | C] = silu(causal depthwise conv of ``mamba_conv_kernel``
+         taps + b_conv);  D_t = softplus(dt_t + dt_bias), a_t = exp(-exp(A_log) D_t) a head;  per head from
+         S_0 = 0 in R^(head size x state size), float32:
+             S_t = a_t S_{t-1} + D_t x_t B_t';   y_t = S_t C_t + D x_t
+         y = RMSNorm(y * silu(z); w_n) over each group's channels, the gate first;  W_out[the held heads' rows] y.
+         The recurrence runs in chunks of ``mamba_chunk`` positions (:func:`_state_space_core`): with L the
+         running sum of ``D_t A`` inside a chunk, Y = ((C B') o exp(L_i - L_j)[i >= j]) (D x) + exp(L_i) C_i S_in,
+         and a chunk adds sum_j exp(L_end - L_j) D_j x_j B_j' to exp(L_end) S_in: :func:`_affine_scan`'s
+         ``S <- a S + b`` under one scalar ``a`` a head and chunk, everything else batched over all chunks
+    Mix = full_attention:  GQA as above, 16 query heads to a key-value head, no norm of q and k and NO
+         positional encoding (``positional_encoding`` ``none``: the order reaches the model through the mixers)
+    Mix = routed:  s = sigmoid(W_r u) over all experts; chosen = top-k of (s + b);
+         w = ``routed_scaling_factor`` * s[chosen] / (sum s[chosen] + 1e-20);  l = W_down u in R^``moe_latent_size``;
+         out = W_up (sum over chosen AND held e of w_e W2_e relu2(W1_e l)) + W2_s relu2(W1_s u)
+         (relu2(a) = max(a, 0)^2; the router and the shared expert, of its own width, read the full state;
+         the row buffer's worst case is min(k, held) x tokens rows: 8 held of 512 under a top-22 is 8 a token)
+    loss = cross-entropy (head untied); the bias ``b`` steps outside the gradient as LFM2's does
+    ``A_log`` starts at ln u, u uniform on (1, 16), ``D`` at 1, the convolution's bias at 0, ``dt_bias`` at the
+    inverse softplus of a step log-uniform on (0.001, 0.1); none of them, nor a norm weight, takes weight decay
+
 What differs from the CNN family, by design:
 
 - **Genes are data, not structure.**  Every individual is the same
@@ -155,7 +187,8 @@ What differs from the CNN family, by design:
 Parameters are float32, compute is bfloat16 (router, norms, softmax, logits
 and loss float32; of a ``linear_attention`` layer also its gates, its
 convolution's arithmetic, the l2 norms, the state and every product of the
-delta rule's core).  The router bias ``b`` is not trained by the gradient:
+delta rule's core; of a ``mamba2`` layer its step and decay, its convolution's
+arithmetic, the state and every product of its core, the gate and its norm).  The router bias ``b`` is not trained by the gradient:
 after each step ``b_e += u * sign(mean load - load_e)`` over all experts
 (arXiv:2408.15664; ``u`` is the ``bias_step`` gene).
 """
@@ -188,8 +221,12 @@ _BALANCE_GENE = {"bias": "bias_step", "aux_loss": "aux_alpha"}
 ADAM_BETA1, ADAM_EPS, INIT_STD, ROUTE_EPS = 0.9, 1e-8, 0.02, 1e-6
 #: Under the root of a ``linear_attention`` layer's l2 norm of q and k; the largest decay rate ``exp(A_log)`` starts at.
 L2_EPS, DECAY_RATE_MAX = 1e-6, 16.0
-#: Leaves that are no matrix: they start from a value of their own (:func:`_init_leaf`) and take no weight decay.
-_UNDECAYED = ("norm", "A_log", "dt_bias")
+#: Leaves that are no matrix: they start from a value of their own (:func:`_init_leaf`) and take no weight decay
+#: (a ``mamba2`` layer's skip ``D`` and its convolution's bias among them; matched against a leaf's own key).
+_UNDECAYED = ("norm", "A_log", "dt_bias", "conv_bias", "['D']")
+#: A ``mamba2`` layer's start: the decay rate ``exp(A_log)`` uniform on (1, ``DECAY_RATE_MAX``); the step
+#: ``softplus(dt_bias)`` log-uniform on (``time_step_min``, ``time_step_max``), floored at ``time_step_floor``.
+TIME_STEP_MIN, TIME_STEP_MAX, TIME_STEP_FLOOR = 1e-3, 0.1, 1e-4
 #: megablox tiles (rows, contraction, columns); the row tile shrinks to divide a small buffer
 #: (:func:`_gmm_tiling` follows the shape from here).
 _GMM_TILING = (512, 512, 512)
@@ -288,6 +325,27 @@ class Lfm2MoeConfig:
     num_attention_heads_per_layer: Optional[Tuple[int, ...]] = None
     attn_head_gate: bool = False
     routed_scaling_factor: float = 1.0
+    # what a sixth architecture sets (Nemotron-H): layers that are a mixer ALONE or a feed-forward ALONE (a
+    # ``layer_types`` entry of ``routed`` is a routed feed-forward with its one norm and no mixer, and every other
+    # layer of such a model a mixer with its one norm and no feed-forward: :meth:`halves_of`); a ``mamba2`` layer's
+    # published heads, groups and sizes and the heads of them held here (``held_mamba_heads``, whole groups, as
+    # ``held_experts`` is a range of the router's outputs); attention without a positional encoding; experts of two
+    # matrices under a squared ReLU (``mlp_hidden_act`` ``relu2``: no gate; ``silu`` is the SwiGLU) that work in a
+    # latent state of ``moe_latent_size`` channels between a down- and an up-projection (0: in the residual
+    # stream's); a shared expert of a width of its own (0: ``n_shared_experts`` x ``moe_intermediate_size``); what
+    # guards the sum the chosen sigmoids are divided by
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 0
+    ssm_state_size: int = 0
+    mamba_conv_kernel: int = 4
+    mamba_chunk: int = 128
+    held_mamba_heads: Optional[Tuple[int, int]] = None  # [first, last) of the published heads; None: all
+    positional_encoding: str = "rope"  # or "none"
+    mlp_hidden_act: str = "silu"  # or "relu2"
+    moe_latent_size: int = 0
+    shared_expert_intermediate_size: int = 0
+    route_eps: float = ROUTE_EPS
 
     def __post_init__(self):
         if not self.head_dim:  # frozen: the stated size takes the place of the implied one once, here
@@ -318,7 +376,7 @@ class Lfm2MoeConfig:
     def typed_attention(self) -> bool:
         """Whether attention layers are told apart by type (their scope is the type's name, with
         ``proj``, ``rope`` and ``core`` inside); LFM2's one kind keeps its one ``attention`` scope."""
-        return bool({"sliding_attention", "linear_attention"} & set(self.layer_types))
+        return bool({"sliding_attention", "linear_attention", "mamba2", "routed"} & set(self.layer_types))
 
     @property
     def rotary_dim(self) -> int:
@@ -342,7 +400,38 @@ class Lfm2MoeConfig:
 
     @property
     def moe_layers(self) -> Tuple[int, ...]:
+        """The layers kept (by their place among them) whose feed-forward is routed, in order."""
+        if self.single_half_layers:
+            return tuple(i for i, kind in enumerate(self.layer_types) if kind == "routed")
         return tuple(range(self.num_dense_layers, len(self.layer_types)))
+
+    @property
+    def single_half_layers(self) -> bool:
+        """Whether a layer is ONE of a mixer and a feed-forward under one norm (a model with ``routed`` layers)."""
+        return "routed" in self.layer_types
+
+    def halves_of(self, index: int) -> Tuple[str, ...]:
+        """What the ``index``-th layer kept has of ``("mixer", "ffn")``: both, or the one its type names."""
+        if not self.single_half_layers:
+            return ("mixer", "ffn")
+        return ("ffn",) if self.layer_types[index] == "routed" else ("mixer",)
+
+    @property
+    def gated_experts(self) -> bool:
+        """Whether a feed-forward is a SwiGLU (three matrices) or ``W_2 relu2(W_1 x)`` (two)."""
+        return self.mlp_hidden_act == "silu"
+
+    @property
+    def mamba_heads(self) -> Tuple[int, int]:
+        """The ``mamba2`` heads held here as [first, last) of the published ones."""
+        return self.held_mamba_heads or (0, self.mamba_num_heads)
+
+    @property
+    def mamba_held(self) -> Tuple[int, int]:
+        """(heads held, groups held) of a ``mamba2`` layer: a group is ``mamba_num_heads / mamba_n_groups`` heads
+        with their one B and C."""
+        heads = self.mamba_heads[1] - self.mamba_heads[0]
+        return heads, heads * self.mamba_n_groups // self.mamba_num_heads
 
     @property
     def tokens_per_step(self) -> int:
@@ -357,8 +446,11 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
     h, hd = cfg.hidden_size, cfg.head_dim
     layers = []
     for i, kind in enumerate(cfg.layer_types):
-        layer: Dict[str, Any] = {"op_norm": (h,), "ffn_norm": (h,)}
-        if kind == "conv":
+        halves = cfg.halves_of(i)  # a layer has a norm for each half it has
+        layer: Dict[str, Any] = {f"{'op' if half == 'mixer' else 'ffn'}_norm": (h,) for half in halves}
+        if "mixer" not in halves:
+            pass
+        elif kind == "conv":
             layer["conv"] = {"in_proj": (h, 3 * h), "kernel": (h, cfg.conv_L_cache), "out_proj": (h, h)}
         elif kind == "latent_attention":
             nh, rank, nope, rope, vd = (cfg.num_attention_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
@@ -370,6 +462,14 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
             layer["delta"] = {"qkvz": (h, 2 * keys + 2 * values), "ba": (h, 2 * nv),
                               "kernel": (2 * keys + values, cfg.linear_conv_kernel_dim), "A_log": (nv,),
                               "dt_bias": (nv,), "norm": (cfg.linear_value_head_dim,), "out": (values, h)}
+        elif kind == "mamba2":
+            # the share's: the held heads' z, x and dt columns of ``W_in`` with their groups' B and C, the
+            # convolution over x, B and C, a decay rate, a skip and a step bias a head, the gated norm's weight a
+            # channel and the held heads' rows of ``W_out``
+            heads, inner, mixed = cfg.mamba_held[0], *_mamba_widths(cfg)
+            layer["mamba"] = {"in_proj": (h, inner + mixed + heads), "kernel": (mixed, cfg.mamba_conv_kernel),
+                              "conv_bias": (mixed,), "A_log": (heads,), "D": (heads,), "dt_bias": (heads,),
+                              "norm": (inner,), "out": (inner, h)}
         else:
             # with an output gate a head's columns of ``q`` are [its query | its gate], twice the head size
             nh = cfg.heads_of(i)
@@ -380,15 +480,20 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
                 layer["attn"].update(q_norm=(hd,), k_norm=(hd,))
             if cfg.attn_head_gate:  # one scalar a head and token: a projection of its own
                 layer["attn"]["gate"] = (h, nh)
-        if i < cfg.num_dense_layers:
-            f = cfg.intermediate_size
-            layer["dense"] = {"w1": (h, f), "w3": (h, f), "w2": (f, h)}
+        ffn = lambda width, f: {"w1": (width, f), "w2": (f, width), **({"w3": (width, f)} if cfg.gated_experts else {})}
+        if "ffn" not in halves:
+            pass
+        elif i < cfg.num_dense_layers:
+            layer["dense"] = ffn(h, cfg.intermediate_size)
         else:
-            e, f = cfg.n_held, cfg.moe_intermediate_size
-            layer["moe"] = {"router": (h, cfg.num_experts), "w1": (e, h, f), "w3": (e, h, f), "w2": (e, f, h)}
+            # the experts work in the latent state where there is one, between ``latent_in`` and ``latent_out``
+            e, f, width = cfg.n_held, cfg.moe_intermediate_size, cfg.moe_latent_size or h
+            layer["moe"] = {"router": (h, cfg.num_experts),
+                            **{name: (e,) + shape for name, shape in ffn(width, f).items()}}
+            if cfg.moe_latent_size:
+                layer["moe"].update(latent_in=(h, width), latent_out=(width, h))
             if cfg.n_shared_experts:
-                fs = cfg.n_shared_experts * f
-                layer["moe"]["shared"] = {"w1": (h, fs), "w3": (h, fs), "w2": (fs, h)}
+                layer["moe"]["shared"] = ffn(h, cfg.shared_expert_intermediate_size or cfg.n_shared_experts * f)
                 if cfg.shared_expert_gate:
                     layer["moe"]["shared_gate"] = (h,)
         layers.append(layer)
@@ -405,6 +510,14 @@ def _is_shape(x) -> bool:
 def _delta_widths(cfg: Lfm2MoeConfig) -> Tuple[int, int]:
     """Of a ``linear_attention`` layer: (the columns of q, which k has too; those of v, which z has too)."""
     return (cfg.linear_num_key_heads * cfg.linear_key_head_dim, cfg.linear_num_value_heads * cfg.linear_value_head_dim)
+
+
+def _mamba_widths(cfg: Lfm2MoeConfig) -> Tuple[int, int]:
+    """Of a ``mamba2`` layer's share: (the channels of x, which z has too: heads held x head size; those the
+    convolution mixes: x and the held groups' B and C)."""
+    heads, groups = cfg.mamba_held
+    inner = heads * cfg.mamba_head_dim
+    return inner, inner + 2 * groups * cfg.ssm_state_size
 
 
 def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
@@ -428,13 +541,20 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
     state that entered each chunk and ``A`` a head and chunk (``B`` and ``P U``
     are used up where they are made) -- and, beside it, the three stacked
     cotangents of that rule: what the outputs sent to each state, the ``dS`` it
-    emits, which is ``dB``, and ``dA``).  An estimate to decide a width by, not
+    emits, which is ``dB``, and ``dA``; a ``mamba2`` layer's share: the
+    in-projection in the compute dtype, the float32 x, B, C, step and gate of
+    the heads held, a chunk's masked ``C B'`` rows a head, and the state that
+    entered each chunk with its cotangent).  The row buffer's worst case is
+    ``min(top-k, experts held) x tokens`` rows -- a token reaches a held expert
+    once -- of the width the experts work in, and a layer that is a mixer alone
+    or a feed-forward alone has one norm.  An estimate to decide a width by, not
     a measurement.
     """
     n_params = sum(math.prod(s) for s in jax.tree_util.tree_leaves(param_shapes(cfg), is_leaf=_is_shape))
     t, h = cfg.tokens_per_step, cfg.hidden_size
+    per_row = 4 * (cfg.moe_latent_size or h) + (6 if cfg.gated_experts else 4) * cfg.moe_intermediate_size
     interior = max(6 * t * cfg.intermediate_size if cfg.num_dense_layers else 0,
-                   cfg.num_experts_per_tok * t * (4 * h + 6 * cfg.moe_intermediate_size)) * 2
+                   min(cfg.num_experts_per_tok, cfg.n_held) * t * per_row) * 2
     if "linear_attention" in cfg.layer_types:
         keys, values = _delta_widths(cfg)
         nv, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
@@ -446,13 +566,21 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
         stacks = 4 * nv * dk * (dv + dk) * chunks  # the state that entered each chunk, and A
         sent = 4 * nv * dk * (2 * dv + dk) * chunks  # the cotangents of the entering states, of the states left (dB) and of A
         interior = max(interior, t * (in_proj + operands + systems + passes) + stacks + sent)
+    if "mamba2" in cfg.layer_types:
+        (heads, _), (inner, mixed) = cfg.mamba_held, _mamba_widths(cfg)
+        in_proj = 2 * (inner + mixed)  # a token, compute dtype
+        operands = 4 * (2 * inner + mixed + 2 * heads)  # float32 x, B and C, the gated output, the step and its decay
+        rows = 4 * heads * cfg.mamba_chunk  # a position's row of a chunk's masked C B', a head
+        states = 2 * 4 * heads * cfg.mamba_head_dim * cfg.ssm_state_size * -(-t // cfg.mamba_chunk)  # and their cotangents
+        interior = max(interior, t * (in_proj + operands + rows) + states)
     activations = 2 * t * h * (len(cfg.layer_types) + 1) + max(interior, 2 * 4 * t * cfg.vocab_size)
     return {"params": n_params, "state": 16 * n_params, "activations": activations,
             "total": 16 * n_params + activations}
 
 
-#: The operators a layer can have (``layer_types``).
-LAYER_KINDS = ("conv", "linear_attention", "full_attention", "sliding_attention", "latent_attention")
+#: What a layer can be (``layer_types``): its operator, or ``routed`` -- a routed feed-forward alone, which makes
+#: every other layer of the model its operator alone (:meth:`Lfm2MoeConfig.halves_of`).
+LAYER_KINDS = ("conv", "linear_attention", "mamba2", "full_attention", "sliding_attention", "latent_attention", "routed")
 #: Those of them that are attention over keys under a mask, whose causal core is :func:`_causal_core`'s.
 ATTENTION_KINDS = ("full_attention", "sliding_attention", "latent_attention")
 #: The programs the delta rule's core has, as the spans and the counter name them (one today).
@@ -463,6 +591,8 @@ LINEAR_CORE_CHAIN_PRODUCTS = 1
 #: The same of the fused kernels (:mod:`gentun_tpu.models.delta_kernel`), which hold no ``A`` and ``B``: ``W S``, then
 #: ``K' (U - W S)`` forward; ``K dS``, then ``W' dV`` backward.
 LINEAR_CORE_KERNEL_CHAIN_PRODUCTS = 2
+#: The programs a ``mamba2`` layer's core has, as the spans and the counter name them (one: XLA's ops in chunks).
+STATE_SPACE_CORE_PROGRAMS = ("chunked",)
 #: The masks the core has, as the spans and the counter name them.
 MASKS = ("causal", "window")
 
@@ -805,8 +935,11 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
         v = _head_major(x, p["v"].astype(dtype).reshape(hidden, nkv, hd))
     with part("rope"):
         normed = lambda a, weight: _rms_norm(a, p[weight], cfg.norm_eps) if cfg.qk_norm else a
-        q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling, rotary)
-        k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling, rotary).astype(dtype)
+        if cfg.positional_encoding == "none":  # the order of the tokens reaches such a model through its other layers
+            q, k = normed(q, "q_norm"), normed(k, "k_norm").astype(dtype)
+        else:
+            q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling, rotary)
+            k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling, rotary).astype(dtype)
     with part("core"):
         out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg, cfg.window_of(kind))
     if cfg.attn_output_gate or cfg.attn_head_gate:
@@ -885,11 +1018,20 @@ def _affine_scan(a, b):
     scan carries ``dS_{i-1} = a_i^T dS_i + (what was sent to the state that
     entered step i)`` and stacks ``dS``, which is ``db``; ``da_i = dS_i
     S_{i-1}^T`` is one batched product after it.  Kept for the backward pass:
-    ``a`` and the stacked states, which are the result itself."""
+    ``a`` and the stacked states, which are the result itself.  ``a`` may be one
+    scalar a state, (steps, ...): the product is then a scaling, and ``da_i``
+    the sum of ``dS_i * S_{i-1}`` over the state (a ``mamba2`` layer's decay)."""
     def step(state, a_b):
-        return jnp.einsum("...de,...ef->...df", a_b[0], state, **_EXACT) + a_b[1], state
+        return _decayed(a_b[0], state, "...de,...ef->...df") + a_b[1], state
 
     return jax.lax.scan(step, jnp.zeros_like(b[0]), (a, b))[1]
+
+
+def _decayed(a, state, product: str):
+    """``a`` applied to ``state``: ``product`` where it is a matrix a state, a scaling where it is a scalar."""
+    if a.ndim == state.ndim:
+        return jnp.einsum(product, a, state, **_EXACT)
+    return a[..., None, None] * state
 
 
 def _affine_scan_fwd(a, b):
@@ -901,9 +1043,11 @@ def _affine_scan_bwd(kept, sent):
     a, entered = kept
 
     def step(left, a_sent):  # ``left``: the cotangent of the state that left this step
-        return jnp.einsum("...de,...df->...ef", a_sent[0], left, **_EXACT) + a_sent[1], left
+        return _decayed(a_sent[0], left, "...de,...df->...ef") + a_sent[1], left
 
     left = jax.lax.scan(step, jnp.zeros_like(sent[0]), (a, sent), reverse=True)[1]
+    if a.ndim < entered.ndim:
+        return jnp.sum(left * entered, axis=(-2, -1)), left
     return jnp.einsum("...df,...ef->...de", left, entered, **_EXACT), left
 
 
@@ -1054,7 +1198,104 @@ def _linear_attention(p, x, cfg: Lfm2MoeConfig, dtype):
         return _dot(out.reshape(s, length, values), p["out"], dtype)
 
 
+def _state_space_core(x, b, c, step, rate, chunk: int):
+    """The Mamba-2 recurrence (state-space duality, arXiv:2405.21060) in chunks, as XLA's ops.  Per head, from
+    ``S_0 = 0`` in R^(head size x state size), with ``a_t = exp(step_t * rate)``, ``rate < 0``::
+
+        S_t = a_t S_{t-1} + step_t x_t B_t';     y_t = S_t C_t
+
+    ``x`` (sequences, length, groups, heads a group, head size); ``b``, ``c`` (sequences, length, groups, state
+    size), one pair a group of heads; ``step > 0`` (sequences, length, groups, heads a group); ``rate`` (groups,
+    heads a group); all float32, and so is every product here (HIGHEST).  Returns ``y`` in ``x``'s shape.
+
+    Inside a chunk of ``chunk`` positions, with ``L_i`` the running sum of ``step * rate`` from the chunk's start (so
+    ``L`` falls), a chunk maps the state ``S`` that enters it to::
+
+        S <- exp(L_end) S + sum_j exp(L_end - L_j) step_j x_j B_j'            one scalar a head, and what the chunk adds
+        y_i = exp(L_i) C_i S + sum_{j <= i} (C_i . B_j) exp(L_i - L_j) step_j x_j
+
+    What a chunk adds needs no state: one batched product over all chunks.  Only ``S <- a S + b`` runs in sequence
+    (:func:`_affine_scan` with a scalar ``a``: a scaling and an add a step, forward and backward), and the outputs
+    are two more batched products made AFTER it, where they are used: ``C B'`` (once a group) masked by the decays
+    is (chunks, heads, chunk, chunk) float32 and is never alive across the scan (PERF.md, PR 41: XLA's forms of a
+    chunked scan pay for passes over such stacks, not for the steps).  Every exponent is a difference ``L_i - L_j``
+    with ``j <= i``, an ``L_i`` or ``L_end - L_j``: none is positive.  A length that is no whole number of chunks is
+    padded with positions that write nothing and decay nothing (``step = 0``)."""
+    s, length, g, r, size = x.shape
+    n = b.shape[-1]
+    pad = -length % chunk
+    if pad:
+        x, b, c, step = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2)) for a in (x, b, c, step))
+    chunks = (length + pad) // chunk
+    # (chunks, sequences, groups, [heads a group,] positions, size): the chunk leads, as the scan takes its operands
+    x = x.reshape(s, chunks, chunk, g, r, size).transpose(1, 0, 3, 4, 2, 5)
+    b, c = (a.reshape(s, chunks, chunk, g, n).transpose(1, 0, 3, 2, 4) for a in (b, c))
+    step = step.reshape(s, chunks, chunk, g, r).transpose(1, 0, 3, 4, 2)
+    fall = jnp.cumsum(step * rate[..., None], axis=-1)  # L: (chunks, s, g, r, positions)
+    written = step[..., None] * x  # what a position writes, before its decay
+    to_end = jnp.exp(fall[..., -1:] - fall)[..., None] * written
+    added = jnp.einsum("Nsgrcp,Nsgcn->Nsgrpn", to_end, b, **_EXACT)
+    entered = _affine_scan(jnp.exp(fall[..., -1]), added)
+    at_or_before = jnp.tril(jnp.ones((chunk, chunk), bool))
+    apart = fall[..., :, None] - fall[..., None, :]
+    decay = jnp.where(at_or_before, jnp.exp(jnp.where(at_or_before, apart, 0.0)), 0.0)
+    seen = jnp.einsum("Nsgin,Nsgjn->Nsgij", c, b, **_EXACT)[:, :, :, None] * decay  # once a group, masked a head
+    out = jnp.einsum("Nsgrij,Nsgrjp->Nsgrip", seen, written, **_EXACT) \
+        + jnp.exp(fall)[..., None] * jnp.einsum("Nsgin,Nsgrpn->Nsgrip", c, entered, **_EXACT)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(s, chunks * chunk, g, r, size)
+    return out[:, :length]
+
+
+def _gated_norm(y, z, weight, eps):
+    """``RMSNorm(y * silu(z); weight)`` over the last axis, a group's channels: the gate FIRST, then the norm."""
+    return _rms_norm(y * jax.nn.silu(z), weight, eps)
+
+
+def _state_space(p, x, cfg: Lfm2MoeConfig, dtype):
+    """The share of a Mamba-2 mixer that the held heads give, on (sequences, length, hidden): one in-projection
+    to ``[z | x | B | C | dt]`` (the held heads' gate and input, their groups' B and C -- head ``h`` reads group
+    ``h // (heads / groups)`` -- and a step a head; the step's columns a float32 product of their own, like a
+    router's scores); a causal depthwise convolution with a bias and SiLU over x, B and C (float32 arithmetic on
+    the compute dtype's product, zeros before position 0); ``step = softplus(dt + dt_bias)``, the decay a position
+    ``exp(step * -exp(A_log))``; the recurrence (:func:`_state_space_core`) plus the skip ``D x``;
+    ``RMSNorm(y * silu(z))`` over each group's channels -- the gate first -- and the out-projection's rows of the
+    held heads.  What the absent heads would add to the sum is left out.  Scopes: ``proj``, ``conv``, ``gates``,
+    ``core``, ``norm_gate``."""
+    s, length, _ = x.shape
+    (heads, groups), (inner, mixed) = cfg.mamba_held, _mamba_widths(cfg)
+    size, state = cfg.mamba_head_dim, cfg.ssm_state_size
+    with jax.named_scope("proj"):
+        zxbc = _dot(x, p["in_proj"][:, :inner + mixed], dtype)
+    with jax.named_scope("gates"):
+        dt = jnp.dot(x.astype(jnp.float32), p["in_proj"][:, inner + mixed:], precision=jax.lax.Precision.HIGHEST)
+        step = jax.nn.softplus(dt + p["dt_bias"]).reshape(s, length, groups, heads // groups)
+        rate = -jnp.exp(p["A_log"]).reshape(groups, heads // groups)
+    with jax.named_scope("conv"):
+        taps = cfg.mamba_conv_kernel
+        padded = jnp.pad(zxbc[..., inner:], ((0, 0), (taps - 1, 0), (0, 0)))
+        conv = sum(p["kernel"][:, j] * padded[:, j:j + length].astype(jnp.float32) for j in range(taps))
+        conv = jax.nn.silu(conv + p["conv_bias"])
+    with jax.named_scope("core"):
+        u = conv[..., :inner].reshape(s, length, groups, heads // groups, size)
+        b, c = (conv[..., lo:lo + groups * state].reshape(s, length, groups, state)
+                for lo in (inner, inner + groups * state))
+        out = _state_space_core(u, b, c, step, rate, cfg.mamba_chunk) + p["D"].reshape(groups, -1, 1) * u
+    with jax.named_scope("norm_gate"):
+        by_group = (s, length, groups, inner // groups)
+        out = _gated_norm(out.reshape(by_group), zxbc[..., :inner].astype(jnp.float32).reshape(by_group),
+                          p["norm"].reshape(by_group[2:]), cfg.norm_eps).astype(dtype)
+    with jax.named_scope("proj"):
+        return _dot(out.reshape(s, length, inner), p["out"], dtype)
+
+
+def _relu2(a):
+    return jnp.square(jax.nn.relu(a))
+
+
 def _dense_ffn(p, x, dtype):
+    """A SwiGLU of three matrices, or, of a tree without the gate's ``w3``, ``W_2 relu2(W_1 x)``."""
+    if "w3" not in p:
+        return _dot(_relu2(_dot(x, p["w1"], dtype)), p["w2"], dtype)
     return _dot(jax.nn.silu(_dot(x, p["w1"], dtype)) * _dot(x, p["w3"], dtype), p["w2"], dtype)
 
 
@@ -1068,7 +1309,7 @@ def _route(router, bias, x, cfg: Lfm2MoeConfig):
     _, chosen = jax.lax.top_k(scores + bias if cfg.balance_rule == "bias" else scores, cfg.num_experts_per_tok)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:  # LFM2's published rule guards its sum of sigmoids; a sum of softmax shares needs none
-        picked = picked / (picked.sum(-1, keepdims=True) + (ROUTE_EPS if cfg.scoring_func == "sigmoid" else 0.0))
+        picked = picked / (picked.sum(-1, keepdims=True) + (cfg.route_eps if cfg.scoring_func == "sigmoid" else 0.0))
     return chosen, picked, scores
 
 
@@ -1101,10 +1342,12 @@ class RoutedStats(NamedTuple):
 def _row_buffer_heights(cfg: Lfm2MoeConfig, tokens: int) -> Tuple[int, ...]:
     """The row buffer's heights, shortest first: the smallest multiples of the
     ``gmm`` row tile that hold ``_ROW_BUFFER_SHARES`` times the rows this rank
-    gets on average, those under the worst case (top-k x tokens), and the worst
-    case, which a small shape has alone."""
-    full, tile = cfg.num_experts_per_tok * tokens, _GMM_TILING[0]
-    share = full * cfg.n_held / cfg.num_experts
+    gets on average, those under the worst case, and the worst case, which a
+    small shape has alone: ``min(top-k, experts held) x tokens`` rows, for a
+    token's choices are distinct experts and reach a held one once (8 held of
+    512 under a top-22: 8 rows a token, not 22)."""
+    full, tile = min(cfg.num_experts_per_tok, cfg.n_held) * tokens, _GMM_TILING[0]
+    share = cfg.num_experts_per_tok * tokens * cfg.n_held / cfg.num_experts
     below = sorted({tile * math.ceil(s * share / tile) for s in _ROW_BUFFER_SHARES})
     return tuple(h for h in below if h < full) + (full,)
 
@@ -1113,7 +1356,9 @@ def _expert_rows(cfg: Lfm2MoeConfig, dtype, cap: int, p, x, weight, order, sizes
     """Dispatch, experts and combine on a row buffer of the static height
     ``cap``: the first ``cap`` assignments of ``order`` (held ones first, grouped
     by expert; ``sizes`` a held expert) each get a row.  Every pass has the
-    buffer's height.  Returns the tokens' sums and the rows that found room."""
+    buffer's height.  ``x`` is what the experts read and their sum's width: the
+    residual stream's state, or the latent one.  Returns the tokens' sums and
+    the rows that found room."""
     t, h = x.shape
     k = cfg.num_experts_per_tok
     with jax.named_scope("moe"), jax.named_scope("dispatch"):
@@ -1124,8 +1369,11 @@ def _expert_rows(cfg: Lfm2MoeConfig, dtype, cap: int, p, x, weight, order, sizes
         taken = order[:cap]  # the assignment a row holds: token a // k, choice a % k
         rows = jnp.where(filled, jnp.take(x, taken // k, axis=0), 0).astype(dtype)
     with jax.named_scope("moe"), jax.named_scope("experts"):
-        up = jax.nn.silu(_grouped_matmul(rows, p["w1"].astype(dtype), sizes_kept)) \
-            * _grouped_matmul(rows, p["w3"].astype(dtype), sizes_kept)
+        if cfg.gated_experts:
+            up = jax.nn.silu(_grouped_matmul(rows, p["w1"].astype(dtype), sizes_kept)) \
+                * _grouped_matmul(rows, p["w3"].astype(dtype), sizes_kept)
+        else:  # two matrices an expert and no gate
+            up = _relu2(_grouped_matmul(rows, p["w1"].astype(dtype), sizes_kept))
         down = _grouped_matmul(jnp.where(filled, up, 0), p["w2"].astype(dtype), sizes_kept)
         down = jnp.where(filled, down, 0)
     with jax.named_scope("moe"), jax.named_scope("combine"):
@@ -1198,7 +1446,11 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
              by_count=_expert_rows_by_count):
     """The held experts' part of the routed feed-forward on (tokens, hidden),
     times ``routed_scaling_factor``, plus the shared experts where the
-    configuration has them (every rank computes those alike).
+    configuration has them (every rank computes those alike).  With
+    ``moe_latent_size`` the router and the shared expert read the full state,
+    and the routed experts a down-projection of it (``moe/latent_in``, before
+    dispatch); their weighted sum is formed in that latent state and projected
+    up once (``moe/latent_out``): both projections are whole on every rank.
 
     Returns ``(out, load, stats)``: ``load`` counts the tokens each of ALL experts
     was chosen for (the bias rule needs them all), ``stats`` is this layer's
@@ -1218,7 +1470,8 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
     the first ``moe``."""
     t = x.shape[0]
     k, n_held = cfg.num_experts_per_tok, cfg.n_held
-    heights = _row_buffer_heights(cfg, t) if row_buffer is None else tuple(sorted({min(row_buffer, k * t), k * t}))
+    worst = min(k, n_held) * t
+    heights = _row_buffer_heights(cfg, t) if row_buffer is None else tuple(sorted({min(row_buffer, worst), worst}))
     with jax.named_scope("moe"), jax.named_scope("router"):
         chosen, weight, scores = _route(p["router"], bias, x, cfg)
         if cfg.routed_scaling_factor != 1.0:  # on the routed sum alone, as float32 weights: the shared experts' output is added as it is
@@ -1236,11 +1489,19 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
         sizes = jnp.sum(key[:, None] == jnp.arange(n_held), axis=0, dtype=jnp.int32)
         n_held_rows = sizes.sum()
         rung = jnp.sum(n_held_rows > jnp.asarray(heights[:-1], jnp.int32), dtype=jnp.int32)  # the first that holds them
-    operands = ({name: p[name] for name in ("w1", "w3", "w2")}, x, weight, order, sizes)
+    seen = x
+    if cfg.moe_latent_size:  # the experts read, and add up in, a narrower state: routed on ``x``, dispatched from here
+        with jax.named_scope("moe"), jax.named_scope("latent_in"):
+            seen = _dot(x, p["latent_in"], dtype)
+    operands = ({name: p[name] for name in (("w1", "w3", "w2") if cfg.gated_experts else ("w1", "w2"))},
+                seen, weight, order, sizes)
     if len(heights) == 1:  # nothing to choose
         out, n_rows = _expert_rows(cfg, dtype, heights[0], *operands)
     else:
         out, n_rows = by_count(heights, cfg, dtype)(rung, *operands)
+    if cfg.moe_latent_size:
+        with jax.named_scope("moe"), jax.named_scope("latent_out"):
+            out = _dot(out, p["latent_out"], dtype)
     if "shared" in p:
         with jax.named_scope("moe"), jax.named_scope("shared"):
             shared = _dense_ffn(p["shared"], x, dtype)
@@ -1254,7 +1515,8 @@ def _moe_ffn(p, bias, x, cfg: Lfm2MoeConfig, dtype, row_buffer: Optional[int] = 
 
 
 def _mixer(cfg: Lfm2MoeConfig, index: int, dtype, p, x):
-    """The first half of a layer, ``x + Op(RMSNorm(x))``, on (sequences, length, hidden)."""
+    """The first half of a layer, ``x + Op(RMSNorm(x))``, on (sequences, length, hidden); the whole of a layer
+    that is a mixer alone."""
     kind, name = cfg.layer_types[index], f"layer{cfg.layer_ids[index]}"
     with jax.named_scope(name):
         normed = _rms_norm(x, p["op_norm"], cfg.norm_eps).astype(dtype)
@@ -1267,12 +1529,16 @@ def _mixer(cfg: Lfm2MoeConfig, index: int, dtype, p, x):
         elif kind == "linear_attention":
             with jax.named_scope("linear_attention"):
                 return x + _linear_attention(p["delta"], normed, cfg, dtype)
+        elif kind == "mamba2":
+            with jax.named_scope("mamba2"):
+                return x + _state_space(p["mamba"], normed, cfg, dtype)
         with jax.named_scope(kind if cfg.typed_attention else "attention"):
             return x + _attention(p["attn"], normed, cfg, dtype, kind)
 
 
 def _ffn(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, h, by_count=_expert_rows_by_count):
-    """The second half, ``h + FFN(RMSNorm(h))``: the output and, of a routed layer, (load, stats)."""
+    """The second half, ``h + FFN(RMSNorm(h))``: the output and, of a routed layer, (load, stats); the whole of
+    a layer that is a feed-forward alone."""
     with jax.named_scope(f"layer{cfg.layer_ids[index]}"):
         normed = _rms_norm(h, p["ffn_norm"], cfg.norm_eps).astype(dtype)
         if "dense" in p:
@@ -1286,8 +1552,13 @@ def _ffn(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, h, by_count=_expert_row
 def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x, by_count=_expert_rows_by_count):
     """One layer on (sequences, length, hidden); ``bias`` is the layer's router
     bias or None; ``by_count`` is :func:`_moe_ffn`'s.  Returns the output and,
-    of a routed layer, (load, stats)."""
-    return _ffn(cfg, index, dtype, p, bias, _mixer(cfg, index, dtype, p, x), by_count)
+    of a routed layer, (load, stats).  A layer of a model whose layers are one
+    half each (:meth:`Lfm2MoeConfig.halves_of`) is that half: its one norm, its
+    one residual add."""
+    halves = cfg.halves_of(index)
+    if "mixer" in halves:
+        x = _mixer(cfg, index, dtype, p, x)
+    return _ffn(cfg, index, dtype, p, bias, x, by_count) if "ffn" in halves else (x, None)
 
 
 def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
@@ -1300,10 +1571,11 @@ def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
     rungs = len(_row_buffer_heights(cfg, tokens.size))
     loads, use = [], RoutedStats(jnp.zeros((), jnp.int32), jnp.zeros(rungs, jnp.int32), jnp.zeros((), jnp.float32))
     by_count = functools.lru_cache(maxsize=None)(_expert_rows_by_count)  # one for the layers of this trace
+    routed = cfg.moe_layers
     for i, p in enumerate(params["layers"]):
         fn = functools.partial(_layer, cfg, i, dtype, by_count=by_count)
-        moe = i >= cfg.num_dense_layers
-        layer_bias = bias[i - cfg.num_dense_layers] if moe else None
+        moe = i in routed
+        layer_bias = bias[routed.index(i)] if moe else None
         if remat and cfg.layer_types[i] == "linear_attention":
             # the halves of this layer are rematerialised apart: what the scan's backward pass keeps (a state a
             # chunk and head) would else lie beside the expert rows' buffer at its worst-case height while the
@@ -1364,7 +1636,9 @@ class Lfm2MoePrograms(NamedTuple):
     ops run, whose solve is a substitution.
     ``heads_by_mask``, ``rotary_by_mask``: per mask, the query heads of each of its layers and the columns
     of a head that its rope turns (a kernel's visits are a head's: the work of a mask's layers is theirs
-    times these heads), whichever core runs."""
+    times these heads; 0 columns where the configuration has no positional encoding), whichever core runs.
+    ``state_space_core_layers``: the ``mamba2`` layers by the program their core runs as
+    (``STATE_SPACE_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none."""
 
     config: Lfm2MoeConfig
     init: Any
@@ -1378,13 +1652,28 @@ class Lfm2MoePrograms(NamedTuple):
     rotary_by_mask: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
     linear_core_kernel_layers: int = 0
     linear_core_inverse_products: float = 0.0
+    state_space_core_layers: Tuple[Tuple[str, int], ...] = ()
 
 
 def _init_leaf(name: str, key, index: int, shape):
-    """The start of the ``index``-th leaf, drawn from its own fold of ``key``: norm weights and
-    ``dt_bias`` 1; ``A_log`` the log of a decay rate uniform on (0.001, ``DECAY_RATE_MAX``) (the
-    ``qwen3_next`` model type's initialiser, whose rates start at 0); every matrix, the convolution
-    kernels and the embedding among them, normal with deviation ``INIT_STD``."""
+    """The start of the ``index``-th leaf (``name``: its key after its parent's), drawn from its own
+    fold of ``key``: norm weights and ``dt_bias`` 1; ``A_log`` the log of a decay rate uniform on
+    (0.001, ``DECAY_RATE_MAX``) (the ``qwen3_next`` model type's initialiser, whose rates start at
+    0); every matrix, the convolution kernels and the embedding among them, normal with deviation
+    ``INIT_STD``.  Of a ``mamba2`` layer (the ``nemotron_h`` model type's initialiser): ``A_log``
+    the log of a rate uniform on (1, ``DECAY_RATE_MAX``), the skip ``D`` 1, the convolution's bias 0
+    and ``dt_bias`` the inverse softplus of a step log-uniform on (``TIME_STEP_MIN``,
+    ``TIME_STEP_MAX``), floored at ``TIME_STEP_FLOOR``."""
+    if "['mamba']" in name and "norm" not in name and len(shape) == 1:
+        key = jax.random.fold_in(key, index)
+        if "A_log" in name:
+            return jnp.log(jax.random.uniform(key, shape, jnp.float32, minval=1.0, maxval=DECAY_RATE_MAX))
+        if "dt_bias" in name:
+            step = jnp.exp(jax.random.uniform(key, shape, jnp.float32, minval=math.log(TIME_STEP_MIN),
+                                              maxval=math.log(TIME_STEP_MAX)))
+            step = jnp.maximum(step, TIME_STEP_FLOOR)
+            return step + jnp.log(-jnp.expm1(-step))  # softplus of this is the step
+        return jnp.ones(shape, jnp.float32) if "['D']" in name else jnp.zeros(shape, jnp.float32)
     if "norm" in name or "dt_bias" in name:
         return jnp.ones(shape, jnp.float32)
     key = jax.random.fold_in(key, index)
@@ -1403,7 +1692,7 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
     def init(base_key, genome_hash):
         key = jax.random.fold_in(jax.random.fold_in(base_key, genome_hash[0]), genome_hash[1])
         leaves, tree = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=_is_shape)
-        params = [_init_leaf(str(path[-1]), key, i, shape) for i, (path, shape) in enumerate(leaves)]
+        params = [_init_leaf("".join(map(str, path[-2:])), key, i, shape) for i, (path, shape) in enumerate(leaves)]
         params = jax.tree_util.tree_unflatten(tree, params)
         zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)
         state = {"params": params, "m": zeros(), "v": zeros(),
@@ -1469,7 +1758,8 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
             continue
         by_mask.append((mask, len(layers) if engaged else 0))
         heads.append((mask, tuple(cfg.heads_of(i) for i in layers)))
-        rotary.append((mask, tuple(cfg.qk_rope_head_dim if latent else cfg.rotary_of(cfg.layer_types[i]) for i in layers)))
+        rotary.append((mask, tuple(0 if cfg.positional_encoding == "none" else cfg.qk_rope_head_dim if latent
+                                   else cfg.rotary_of(cfg.layer_types[i]) for i in layers)))
         if engaged:
             visits.append((mask, tuple(sorted(_kernel_visits(cfg.seq_len, window, columns).items()))))
     linear = cfg.layer_types.count("linear_attention")
@@ -1479,10 +1769,12 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         if _use_delta_kernel(cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.delta_chunk, per_key):
             from . import delta_kernel
             by_kernels, inverse_products = linear, delta_kernel.inverse_products(cfg.delta_chunk, per_key)
+    state_space = cfg.layer_types.count("mamba2")
     return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval),
                            sum(n for _, n in by_mask), tuple(by_mask), tuple(visits),
                            ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else (), tuple(heads), tuple(rotary),
-                           by_kernels, inverse_products)
+                           by_kernels, inverse_products,
+                           ((STATE_SPACE_CORE_PROGRAMS[0], state_space),) if state_space else ())
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -1495,7 +1787,7 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
     x = np.asarray(x_train)
     if x.ndim != 2 or not np.issubdtype(x.dtype, np.integer):
         raise ValueError(f"x_train must be integer tokens (sequences, length); got {x.dtype} {x.shape}")
-    for key in ("layer_types", "layer_ids", "held_experts", "num_attention_heads_per_layer"):
+    for key in ("layer_types", "layer_ids", "held_experts", "num_attention_heads_per_layer", "held_mamba_heads"):
         if config.get(key) is not None:
             config[key] = tuple(config[key])
     if isinstance(config.get("rope_scaling"), Mapping):
@@ -1520,6 +1812,24 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
         if min(sizes.values()) <= 0 or cfg.linear_num_value_heads % cfg.linear_num_key_heads:
             raise ValueError(f"a linear_attention layer needs its heads (value heads a whole number to each key "
                              f"head), their sizes, its taps and its chunk: {sizes}")
+    if "mamba2" in cfg.layer_types:
+        sizes = {k: getattr(cfg, k) for k in ("mamba_num_heads", "mamba_head_dim", "mamba_n_groups", "ssm_state_size",
+                                              "mamba_conv_kernel", "mamba_chunk")}
+        if min(sizes.values()) <= 0 or cfg.mamba_num_heads % cfg.mamba_n_groups:
+            raise ValueError(f"a mamba2 layer needs its heads (a whole number to each group), their sizes, its taps "
+                             f"and its chunk: {sizes}")
+        (first, last), per_group = cfg.mamba_heads, cfg.mamba_num_heads // cfg.mamba_n_groups
+        if not 0 <= first < last <= cfg.mamba_num_heads or first % per_group or last % per_group:
+            raise ValueError(f"held_mamba_heads {cfg.held_mamba_heads} is no range of whole groups ({per_group} heads "
+                             f"each, with their one B and C) of the {cfg.mamba_num_heads} heads")
+    if cfg.mlp_hidden_act not in ("silu", "relu2") or cfg.positional_encoding not in ("rope", "none"):
+        raise ValueError(f"mlp_hidden_act {cfg.mlp_hidden_act!r} (silu: a SwiGLU; relu2: two matrices, no gate) / "
+                         f"positional_encoding {cfg.positional_encoding!r} (rope or none)")
+    if cfg.moe_latent_size < 0 or (cfg.moe_latent_size and cfg.gated_experts):
+        raise ValueError(f"moe_latent_size {cfg.moe_latent_size}: the latent expert layer is built for experts of two "
+                         f"matrices (mlp_hidden_act relu2), not for a gated expert ({cfg.mlp_hidden_act})")
+    if cfg.single_half_layers and cfg.num_dense_layers:
+        raise ValueError("a model whose layers are a mixer alone or a routed feed-forward alone has no dense layer")
     if not 0.0 < cfg.partial_rotary_factor <= 1.0:
         raise ValueError(f"partial_rotary_factor {cfg.partial_rotary_factor} is no share of a head")
     if cfg.shared_expert_gate and not cfg.n_shared_experts:
@@ -1542,13 +1852,14 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
         if cfg.head_dim % 2 or heads <= 0 or heads % cfg.num_key_value_heads:
             raise ValueError(f"{name}: head_dim {cfg.head_dim} must be even and its {heads} heads a whole number of "
                              f"query heads to each of the {cfg.num_key_value_heads} key-value heads")
-        if not 0 < rotary <= cfg.head_dim or rotary % 2:
+        if cfg.positional_encoding != "none" and (not 0 < rotary <= cfg.head_dim or rotary % 2):
             raise ValueError(f"{name}: rope turns {rotary} of a head's {cfg.head_dim} columns (partial_rotary_factor); "
                              f"an even number of them, at most all")
     if cfg.attn_output_gate and cfg.attn_head_gate:
         raise ValueError("attn_output_gate (a gate a column, inside W_q) and attn_head_gate (a gate a head, a "
                          "projection of its own) are two forms of one gate: a configuration has one")
-    if cfg.n_shared_experts < 0 or (cfg.n_shared_experts and cfg.moe_intermediate_size <= 0):
+    if cfg.n_shared_experts < 0 or cfg.shared_expert_intermediate_size < 0 or \
+            (cfg.n_shared_experts and cfg.moe_intermediate_size <= 0):
         raise ValueError(f"{cfg.n_shared_experts} shared experts of width {cfg.moe_intermediate_size}")
     if cfg.scoring_func not in ("sigmoid", "softmax") or cfg.balance_rule not in _BALANCE_GENE:
         raise ValueError(f"scoring_func {cfg.scoring_func!r} (sigmoid or softmax) / balance_rule "
@@ -1665,6 +1976,13 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
         kernel_attrs["linear_core_chain_products"] = (LINEAR_CORE_KERNEL_CHAIN_PRODUCTS if linear_kernel_steps
                                                       else LINEAR_CORE_CHAIN_PRODUCTS)
         kernel_attrs["linear_core_inverse_products"] = programs.linear_core_inverse_products
+    by_state_space = {program: layers * cfg.train_steps for program, layers in programs.state_space_core_layers}
+    kernel_attrs.update({f"state_space_core_layer_steps_{program}": n for program, n in by_state_space.items()})
+    if by_state_space:
+        kernel_attrs["state_space_core_chunk"] = cfg.mamba_chunk
+        kernel_attrs["state_space_heads_held"] = cfg.mamba_held[0]
+    if cfg.moe_latent_size:
+        kernel_attrs["latent_experts_width"] = cfg.moe_latent_size
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)
@@ -1691,6 +2009,8 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
                 _get_registry().counter("linear_core_layer_steps_total", program=program).inc(n)
             if by_linear:
                 _get_registry().counter("linear_core_kernel_layer_steps_total").inc(linear_kernel_steps)
+            for program, n in by_state_space.items():
+                _get_registry().counter("state_space_core_layer_steps_total", program=program).inc(n)
             sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=wide,
                    row_buffer_heights=[list(pair) for pair in by_height])
             if balance is not None:  # the ``aux_loss`` rule: the term before its weight, a routed layer and step

@@ -36,11 +36,15 @@ LONGEST_FIRST = (
     "test_benchmark_mel_faults.py",   # 378
     "test_benchmark_lag_faults.py",   # 356
     "test_benchmark_q3n_faults.py",   # 311
+    "test_benchmark_nmh_faults.py",   # 326 (PR 46's whole run)
     "test_carry_builder.py",          # 274
     "test_benchmark_dsv2_faults.py",  # 260
     "test_multihost.py",              # 227
+    "test_benchmark_nmh_correct.py",  # 246 (PR 46)
     "test_benchmark_q3n_correct.py",  # 216
+    "test_delta_kernel_compiles.py",  # 223 since PR 46: the Nemotron-H cut's whole train step compiled for the described chip
     "test_benchmark_lag_correct.py",  # 204
+    "test_benchmark_nmh_mixer_faults.py",  # 184 (PR 46)
     "test_examples.py",               # 196
     "test_cnn_model.py",              # 182
     "test_routed_family.py",          # 148
@@ -51,6 +55,7 @@ LONGEST_FIRST = (
     "test_qwen3_next.py",             # ~110 (220 before its delta rule's tests got a file of their own)
     "test_qwen3_next_delta.py",       # ~110
     "test_routed_family_laguna.py",   # ~107 (200 with Mellum2's cases, which are now the next file)
+    "test_routed_family_nemotron_h.py",  # ~105 (PR 46)
     "test_benchmark_mel_correct.py",  # 101
     "test_deepseek_v2.py",            # 99
     "test_laguna.py",                 # 98

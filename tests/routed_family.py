@@ -1,9 +1,9 @@
 """What the tests of the routed family's architectures share (``test_routed_family*.py`` and the five
-``test_<architecture>.py``): each architecture's table (its reference, its toy model, how the model's keys become
+``test_<architecture>.py``; the sixth's is ``test_nemotron_h.py``): each architecture's table (its reference, its toy model, how the model's keys become
 ``Lfm2MoeModel``'s keyword arguments, its cases and tolerances), the loaders of ``benchmark/families/<name>/``, the
 span sink, the kernels interpreted on the CPU, and the small functions every file wrote out for itself.
 
-A sixth architecture adds an ``Arch`` here and cases to the family modules; its own file holds only what no other
+A seventh architecture adds an ``Arch`` here and cases to the family modules; its own file holds only what no other
 architecture has.
 """
 
@@ -341,13 +341,16 @@ class Arch:
 
     def routed_layers(self, m) -> int:
         kw = self.model_kwargs(m)
+        if "routed" in kw["layer_types"]:  # a model whose blocks are a mixer alone or a routed feed-forward alone
+            return kw["layer_types"].count("routed")
         return len(kw["layer_types"]) - kw["num_dense_layers"]
 
     def bias_of(self, m, seed=3, std=0.2):
         """A router bias large enough that ignoring it changes the choice (None where no rule reads one)."""
         if self.rule != "bias":
             return None
-        return (std * np.random.default_rng(seed).standard_normal((self.routed_layers(m), m["num_experts"]))).astype(np.float32)
+        experts = m["num_experts"] if "num_experts" in m else m["n_routed_experts"]
+        return (std * np.random.default_rng(seed).standard_normal((self.routed_layers(m), experts))).astype(np.float32)
 
     def tolerance(self, name: str) -> float:
         return self.tol.get(name, TOLERANCES[name])
@@ -408,6 +411,24 @@ _LAGUNA = dict(hidden_size=40, head_dim=16, intermediate_size=56, moe_intermedia
                mlp_layer_types=["dense", "sparse", "sparse"], num_attention_heads_per_layer=[4, 6, 4], vocab_size=64,
                rms_norm_eps=1e-6, rope_parameters=LAGUNA_ROPE, sliding_window=6, moe_routed_scaling_factor=2.5,
                train_steps=3)
+
+
+#: The cut's shape at toy widths, ``M E * E M``: 4 Mamba-2 heads of 8 in 2 groups with group 1 (heads 2-3) held, a
+#: state of 6, chunks of 8; 4 query heads over 2 key-value heads, no rope; 16 experts of two matrices in a latent
+#: state of 16 under a hidden size of 40, 2 held and 3 a token, the routed sum times 5; a shared expert of width 36.
+NMH_BLOCKS = ["mamba2", "routed", "full_attention", "routed", "mamba2"]
+_NMH = dict(hidden_size=40, head_dim=16, num_attention_heads=4, num_key_value_heads=2, mamba_num_heads=4, mamba_head_dim=8,
+            n_groups=2, ssm_state_size=6, conv_kernel=4, chunk_size=8, n_routed_experts=16, num_experts_per_tok=3,
+            held_experts=[2, 4], held_mamba_heads=[2, 4], moe_intermediate_size=24, moe_latent_size=16,
+            moe_shared_expert_intermediate_size=36, routed_scaling_factor=5.0, num_hidden_layers=5, layer_types=NMH_BLOCKS,
+            vocab_size=64, layer_norm_epsilon=1e-5, rope_theta=10000, time_step_min=0.001, time_step_max=0.1,
+            time_step_floor=1e-4, train_steps=3)
+
+
+def nmh_blocks(*kinds, **over):
+    """The toy model cut to the blocks ``kinds`` (a program needs a routed one: one follows a mixer that stands alone)."""
+    kinds = list(kinds) if "routed" in kinds else list(kinds) + ["routed"]
+    return {**_NMH, "num_hidden_layers": len(kinds), "layer_types": kinds, "held_experts": [1, 5], **over}
 
 
 def laguna_one_layer(kind, heads, ffn="sparse", **over):
@@ -496,6 +517,25 @@ ARCHS = {arch.name: arch for arch in (
                                       "mlp_layer_types": ["dense"] + ["sparse"] * 4,
                                       "num_attention_heads_per_layer": [4, 6, 6, 6, 4]}},
          tol=dict(logits=3e-5, gradient=3e-6, eval=3e-5, shares=3e-5)),
+    Arch(name="nemotron_h", family="nemotron_h", model=_NMH,
+         copied=("hidden_size", "head_dim", "num_attention_heads", "num_key_value_heads", "mamba_num_heads", "mamba_head_dim",
+                 "ssm_state_size", "num_experts_per_tok", "moe_intermediate_size", "moe_latent_size", "routed_scaling_factor",
+                 "vocab_size", "rope_theta", "train_steps"),
+         extras=lambda m: dict(layer_types=tuple(m["layer_types"]), num_dense_layers=0, num_experts=m["n_routed_experts"],
+                               held_experts=tuple(m["held_experts"]), mamba_n_groups=m["n_groups"],
+                               mamba_conv_kernel=m["conv_kernel"], mamba_chunk=m["chunk_size"],
+                               held_mamba_heads=tuple(m["held_mamba_heads"]), norm_eps=m["layer_norm_epsilon"], qk_norm=False,
+                               positional_encoding="none", mlp_hidden_act="relu2", n_shared_experts=1,
+                               shared_expert_intermediate_size=m["moe_shared_expert_intermediate_size"],
+                               scoring_func="sigmoid", norm_topk_prob=True, balance_rule="bias", route_eps=1e-20,
+                               tie_word_embeddings=False, attn_block=7),
+         genes=_BIAS_GENES, genome="lfm2_moe_genome", species="", positions=28,  # three and a half chunks of 8
+         weights=dict(std=STD, router_gain=3.0, conv_std=0.5), rule="bias",
+         layer_cases={"a_mamba2_block": nmh_blocks("mamba2"), "an_attention_block": nmh_blocks("full_attention"),
+                      "a_routed_block_alone": nmh_blocks("routed"),
+                      "a_mamba2_block_of_both_groups": nmh_blocks("mamba2", held_mamba_heads=[0, 4]),
+                      "the_cut": _NMH, "two_periods": nmh_blocks(*NMH_BLOCKS * 2)},
+         tol=dict(logits=3e-5, gradient=3e-6, step=5e-5, eval=3e-5, shares=1e-4)),  # 64 shares added up in float32
 )}
 def long_tokens():
     """6 sequences of 512 positions: the smallest at which a configuration's row buffer has the whole ladder."""
@@ -512,10 +552,14 @@ DSV2_TOP_6 = {**_DSV2, "num_hidden_layers": 1, "first_k_dense_replace": 0, "n_ro
               "num_experts_per_tok": 6, "held_experts": [4, 12]}
 MELLUM_TOP_8 = {**_MELLUM, "num_hidden_layers": 1, "layer_types": ["full_attention"], "num_experts": 64,
                 "num_experts_per_tok": 8, "held_experts": [8, 16]}
+#: Nemotron-H: the published routing at a small width (22 a token, 8 held, of 128); at 512 tokens the mean share is
+#: 704 rows and the worst case min(22, 8) x 512, which every token choosing all 8 held experts fills to its last row.
+NMH_TOP_22 = nmh_blocks("routed", n_routed_experts=128, num_experts_per_tok=22, held_experts=[8, 16])
 #: architecture: (model, the ladder's heights, the tokens a step routes, (dtype, tolerance) pairs)
 LADDERS = {"lfm2_moe": (LFM2_LADDER, (1024, 1536, 2048), 1024, (("float32", 1e-6), ("bfloat16", 1e-2))),
            "deepseek_v2": (DSV2_TOP_6, (2048, 4608, 6144), 1024, (("float32", 1e-6), ("bfloat16", 1e-2))),
-           "mellum2": (MELLUM_TOP_8, (1024, 1536, 4096), 512, (("float32", 1e-6),))}
+           "mellum2": (MELLUM_TOP_8, (1024, 1536, 4096), 512, (("float32", 1e-6),)),
+           "nemotron_h": (NMH_TOP_22, (1024, 2048, 4096), 512, (("float32", 1e-6),))}
 
 
 def genome_of(arch: Arch):

@@ -60,13 +60,26 @@ def _laguna_outcome(arch, cfg, m, bias, load, stats, forward):
     assert float(stats.balance) == 0.0
 
 
+def _nmh_tree(arch, cfg, m, w):
+    assert cfg.single_half_layers and not cfg.gated_experts and cfg.positional_encoding == "none" and cfg.typed_attention
+    assert cfg.mamba_heads == tuple(m["held_mamba_heads"]) and cfg.moe_layers == tuple(
+        i for i, kind in enumerate(m["layer_types"]) if kind == "routed")
+    shapes = M.param_shapes(cfg)
+    assert jax.tree_util.tree_map(lambda a: a.shape, w) == jax.tree_util.tree_map(lambda s: s, shapes, is_leaf=M._is_shape)
+    # a block has ONE norm and ONE of a mixer and a feed-forward
+    for layer, kind in zip(shapes["layers"], m["layer_types"]):
+        assert sorted(layer) == {"mamba2": ["mamba", "op_norm"], "full_attention": ["attn", "op_norm"],
+                                 "routed": ["ffn_norm", "moe"]}[kind]
+
+
 #: architecture: (its assertions on the configuration and the tree, its assertions on the outcome)
 OWN = {"lfm2_moe": (None, _no_layer_took_the_wide_buffer), "deepseek_v2": (None, _no_layer_took_the_wide_buffer),
        "mellum2": (_mellum_tree, None),
-       "qwen3_next": (_q3n_tree, None), "laguna": (_laguna_tree, _laguna_outcome)}
+       "qwen3_next": (_q3n_tree, None), "laguna": (_laguna_tree, _laguna_outcome),
+       "nemotron_h": (_nmh_tree, _laguna_outcome)}
 #: the parity test's router bias, where a rule reads one: (seed, deviation)
-PARITY_BIAS = {"lfm2_moe": (2, 0.1), "laguna": (3, 0.2)}
-STEP_BIAS = {"lfm2_moe": (1, 0.1), "laguna": (3, 0.2)}
+PARITY_BIAS = {"lfm2_moe": (2, 0.1), "laguna": (3, 0.2), "nemotron_h": (3, 0.2)}
+STEP_BIAS = {"lfm2_moe": (1, 0.1), "laguna": (3, 0.2), "nemotron_h": (3, 0.2)}
 NO_BIAS = jnp.zeros((8, 8), jnp.float32)  # the state's bias: zeros, never read under the ``aux_loss`` rule
 ALPHA = 0.05  # the balance term's weight in the compared loss
 
@@ -134,7 +147,12 @@ def _laguna_step(arch, state, ref, bias):
         "every expert's bias steps, held here or not"
 
 
-OWN_STEP = {"lfm2_moe": _lfm2_step, "laguna": _laguna_step}
+def _nmh_step(arch, state, ref, bias):
+    moved = np.abs(ref["bias"] - bias) / arch.genes["bias_step"]
+    assert moved.max() == pytest.approx(2.0, abs=1e-3) and (moved > 0.5).any(axis=0).all(), "every expert's bias steps, held here or not"
+
+
+OWN_STEP = {"lfm2_moe": _lfm2_step, "laguna": _laguna_step, "nemotron_h": _nmh_step}
 
 
 def two_train_steps_match_the_reference(name):
@@ -178,6 +196,8 @@ def two_train_steps_match_the_reference(name):
 
 
 def _one_layer(arch, kind, **over):
+    if arch.name == "nemotron_h":
+        return {**F.nmh_blocks(kind), **over}
     if arch.name == "laguna":
         return F.laguna_one_layer(kind, {"sliding_attention": 6, "full_attention": 4}[kind], **over)
     return {**arch.model, "num_hidden_layers": 1, "layer_types": [kind], **over}
@@ -199,6 +219,10 @@ SHARES = {
     # 16 experts, 8 a token; each share 2.5 times its routed part, the shared expert once
     **{f"laguna-{kind}": ("laguna", _one_layer(ARCHS["laguna"], kind, num_experts=16, num_experts_per_tok=8), 16)
        for kind in ("sliding_attention", "full_attention")},
+    # 128 experts, 22 a token (more than a share holds: a token reaches a held expert once), in 64 shares of 2; each
+    # share 5 times its routed part formed in the latent state; the two latent projections and the shared expert once
+    "nemotron_h-routed": ("nemotron_h", _one_layer(ARCHS["nemotron_h"], "routed", n_routed_experts=128,
+                                                   num_experts_per_tok=22), 128),
 }
 
 
@@ -221,7 +245,7 @@ def the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(case):
 
     def share(cfg, weights):
         layer = lambda p, e: M._layer(cfg, 0, jnp.float32, p, bias, e)[0]
-        return (jax.jit(layer) if name == "qwen3_next" else layer)(weights, jnp.asarray(embedded))
+        return (jax.jit(layer) if name in ("qwen3_next", "nemotron_h") else layer)(weights, jnp.asarray(embedded))
 
     with HIGHEST:
         whole = reference_layer(uncut, layer_w)
@@ -240,9 +264,14 @@ def the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(case):
                                                                                                layer_w["moe"]["shared"])}}
             assert float(jnp.abs(whole - reference_layer(uncut, no_shared)).max()) > 1e-3, \
                 "the shared experts are part of the layer"
-        if name in ("qwen3_next", "laguna"):
+        if name in ("qwen3_next", "laguna", "nemotron_h"):
             without_shared = reference_layer({**uncut, "held_experts": [0, 0], "shared_expert": False}, share_of(0, 0))
             assert float(jnp.abs(alike - without_shared).max()) > 1e-3, "and so is the shared expert, once"
         if name == "laguna":
             unscaled = reference_layer({**uncut, "moe_routed_scaling_factor": 1.0}, layer_w)
             np.testing.assert_allclose(whole - alike, 2.5 * (unscaled - alike), atol=3e-5)  # the factor is on the routed sum alone
+        if name == "nemotron_h":
+            unscaled = reference_layer({**uncut, "routed_scaling_factor": 1.0}, layer_w)
+            np.testing.assert_allclose(whole - alike, 5.0 * (unscaled - alike), atol=1e-4)  # on the routed sum alone
+            flat = {**layer_w, "moe": {**layer_w["moe"], "latent_out": jnp.zeros_like(layer_w["moe"]["latent_out"])}}
+            np.testing.assert_allclose(reference_layer(uncut, flat), alike, atol=1e-6)  # the routed sum passes W_up, once

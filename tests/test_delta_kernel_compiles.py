@@ -1,4 +1,4 @@
-"""The delta rule's fused kernels compiled for a described TPU v5e, no chip attached: what Pallas' interpreter lets
+"""The delta rule's fused kernels, and the Nemotron-H cut's whole train step, compiled for a described TPU v5e, no chip attached: what Pallas' interpreter lets
 pass and the chip's compiler refuses (an op Mosaic has no rule for, more fast memory than a kernel may use, a slice
 off the tiling) fails here, in seconds, and not in a chip call.  Nothing runs, so nothing here is a time or a result.
 
@@ -47,3 +47,38 @@ def test_the_kernels_forward_and_backward_compile_for_the_described_chip(sequenc
         jax.config.update("jax_enable_compilation_cache", cache)
     assert forward.count("tpu_custom_call") == 1 and "delta_core_fwd" in forward
     assert "delta_core_fwd_keep" in both and "delta_core_bwd" in both
+
+
+def test_the_nemotron_h_cuts_train_step_compiles_for_the_described_chip_and_fits_it(one_chip, monkeypatch):
+    """The sixth routed architecture's published cut (``benchmark/configs/nemotron3_super_120b_a12b_ep64.json``:
+    731 M parameters, 11.7 GB of training state) as the chip's compiler takes it, in this file because one file
+    describes the chip: the fused attention kernel at 16 query heads a key-value head, the grouped products at a
+    contraction of 1,024 and 2,688 columns on each of the ladder's three heights, the Mamba-2 core as XLA's ops; and
+    the step's arguments, temporaries and code together under the chip's 16 GiB (``memory_analysis``): the guard of
+    the fit on every later PR, at no chip time."""
+    import routed_family as F
+    from gentun_tpu.models import lfm2_moe as M
+
+    _, _, cfg = F.published_cfg("nemotron_h", "nemotron3_super_120b_a12b_ep64")
+    monkeypatch.setattr(M, "_use_megablox", lambda: True)  # ``jax.default_backend()`` is the CPU here
+    monkeypatch.setattr(M, "_use_attention_kernel", lambda length: M._kernel_blocks(length) is not None)
+    M._programs.cache_clear()
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # an entry written for a described chip cannot be read back
+    try:
+        programs = M._programs(cfg)
+        shaped = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        state = jax.tree_util.tree_map(shaped, jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32)))
+        tokens = jax.ShapeDtypeStruct((cfg.n_sequences, cfg.seq_len), jnp.int32, sharding=one_chip)
+        compiled = programs.train_step.lower(
+            state, tokens, tokens, jax.ShapeDtypeStruct((cfg.train_steps, cfg.batch_sequences), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((5,), jnp.float32, sharding=one_chip), jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+        M._programs.cache_clear()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert programs.attention_kernel_layers == 1 and "splash_mqa" in text and text.count("tpu_custom_call") > 100
+    assert 8.7e9 < memory.argument_size_in_bytes < 8.9e9  # weights and AdamW's moments, 12 bytes a parameter, and the tokens
+    assert memory.alias_size_in_bytes > 8.7e9  # donated: the state is updated in place
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.generated_code_size_in_bytes
+    assert held < 14.5e9 < 16 * 2**30, memory  # the gradients are among the temporaries: 12.2 GB when this was written

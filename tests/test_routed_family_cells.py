@@ -24,7 +24,9 @@ from routed_family import ARCHS, LAGUNA_ROPE as L_ROPE
 def _ladder_cases():
     return [pytest.param(name, count, rung, dtype, tol, id=f"{name}-{count}-{rung}-{dtype}")
             for name, (_, heights, _, dtypes) in F.LADDERS.items()
-            for dtype, tol in dtypes for count, rung in routed_ladder.counts_at_the_rungs(heights)]
+            for dtype, tol in dtypes for count, rung in routed_ladder.counts_at_the_rungs(heights) + (
+                # every token chooses all the held experts: the worst case, min(top-k, held) x tokens, to its last row
+                [(heights[-1], len(heights) - 1)] if name == "nemotron_h" else [])]
 
 
 @pytest.mark.parametrize("name,count,rung,dtype,tol", _ladder_cases())
@@ -37,12 +39,14 @@ def test_the_narrow_and_the_wide_row_buffer_give_the_same_layer(name, count, run
     cfg = arch.config_of(m, tokens=F.long_tokens() if name == "lfm2_moe" else None)
     assert M._row_buffer_heights(cfg, tokens) == heights and (name != "lfm2_moe" or cfg.tokens_per_step == tokens)
     w = arch.seeded_weights(m, 3)["layers"][0]["moe"]
-    assert ("shared" in w) == (name == "deepseek_v2") and (name != "mellum2" or (cfg.norm_topk_prob and cfg.scoring_func == "softmax"))
-    bias = jnp.asarray(0.01 * np.random.default_rng(5).normal(size=8), jnp.float32) if arch.rule == "bias" else None
+    assert ("shared" in w) == (name in ("deepseek_v2", "nemotron_h")) and (name != "mellum2" or (cfg.norm_topk_prob and cfg.scoring_func == "softmax"))
+    if name == "nemotron_h":  # 22 a token and 8 held: a token reaches a held expert once, so 8 rows a token at the worst
+        assert heights[-1] == min(cfg.num_experts_per_tok, cfg.n_held) * tokens == 8 * tokens and "latent_in" in w
+    bias = jnp.asarray(0.01 * np.random.default_rng(5).normal(size=cfg.num_experts), jnp.float32) if arch.rule == "bias" else None
     routed_ladder.assert_the_ladders_layer_is_the_worst_case_heights(cfg, w, bias, tokens, count, rung, dtype, tol)
 
 
-READERS = {"lfm2_moe": "lm", "deepseek_v2": "dsv2", "mellum2": "mel", "qwen3_next": "q3n"}
+READERS = {"lfm2_moe": "lm", "deepseek_v2": "dsv2", "mellum2": "mel", "qwen3_next": "q3n", "nemotron_h": "q3n"}
 
 
 @pytest.mark.parametrize("name", list(READERS))
@@ -66,9 +70,13 @@ def _mellum_metrics(per_layer, cell):
 
 
 def _q3n_metrics(per_layer, cell):
-    names = [m["name"] for m in per_layer if m.get("workloads") == [cell]]
+    mine = [m for m in per_layer if m.get("workloads", [None])[0] == cell]
+    names = [m["name"] for m in mine]
     assert len(names) == 34 and all(n.startswith("q3n_") for n in names), names
     assert not [m["name"] for m in per_layer if cell in m.get("workloads", []) and not m["name"].startswith("q3n_")]
+    # since PR 46 a second cell with a scanning mixer beside full attention reads all of them but the balance term's
+    assert {m["name"] for m in mine if m["workloads"] == [cell]} == {"q3n_aux_loss_mean"}
+    assert all(m["workloads"] == [cell, "nemotron3_super_120b_a12b_ep64.popeval"] for m in mine if m["name"] != "q3n_aux_loss_mean")
     return names
 
 
@@ -82,10 +90,23 @@ def _laguna_metrics(per_layer, cell):
     return names
 
 
+def _nemotron_metrics(per_layer, cell):
+    """The cell adds no metric: it is appended to accepted ones (the manifest stays at 128)."""
+    names = [m["name"] for m in per_layer if cell in m.get("workloads", ())]
+    assert len(per_layer) == 128 and len(names) == 33 and all(n.startswith("q3n_") for n in names), names
+    assert "q3n_aux_loss_mean" not in names  # the bias rule has no balance term
+    assert {"q3n_delta_core_roofline_share", "q3n_full_core_roofline_share", "q3n_expert_mm_roofline_share",
+            "q3n_train_mfu_executed", "q3n_device_idle_share", "q3n_peak_hbm_gb"} < set(names)
+    assert all(m["moves"] == ("setup_s" if m["name"] == "q3n_first_call_s" else "individuals_per_hour_per_chip")
+               and m["workloads"][-1] == cell for m in per_layer if m["name"] in names)
+    return names
+
+
 #: architecture: (its cell, its configuration file, the metrics the manifest gives the cell)
 CELLS = {"mellum2": ("mellum2_12b_a2p5b_ep8.popeval", "mellum2_12b_a2p5b_ep8", _mellum_metrics),
          "qwen3_next": ("qwen3_next_80b_a3b_ep16.popeval", "qwen3_next_80b_a3b_ep16", _q3n_metrics),
-         "laguna": ("laguna_xs2_ep8.popeval", "laguna_xs2_ep8", _laguna_metrics)}
+         "laguna": ("laguna_xs2_ep8.popeval", "laguna_xs2_ep8", _laguna_metrics),
+         "nemotron_h": ("nemotron3_super_120b_a12b_ep64.popeval", "nemotron3_super_120b_a12b_ep64", _nemotron_metrics)}
 
 
 @pytest.mark.parametrize("name", list(CELLS))
@@ -105,8 +126,8 @@ _FULL = L_ROPE["full_attention"]
 REFUSALS = {
     "lfm2_moe": [(dict(held_experts=(6, 9)), "held_experts"), (dict(num_dense_layers=3), "routed layer"),
                  (dict(eval_sequences=3), "held-out"), (dict(vocab_size=32), "held slice"),
-                 (dict(layer_types=("conv", "mamba", "conv")), "layer_types")],
-    "deepseek_v2": [(dict(layer_types=("latent_attention", "state_space", "latent_attention")), "layer_types"),
+                 (dict(layer_types=("conv", "hyena", "conv")), "layer_types")],
+    "deepseek_v2": [(dict(layer_types=("latent_attention", "retention", "latent_attention")), "layer_types"),
                     (dict(kv_lora_rank=0), "rank and head sizes"), (dict(v_head_dim=0), "rank and head sizes"),
                     (dict(qk_rope_head_dim=3), "even rope size"),
                     (dict(rope_scaling={"factor": 40, "type": "yarn"}), "rope_scaling needs"),
@@ -122,7 +143,7 @@ REFUSALS = {
                    (dict(delta_chunk=0), "linear_attention layer needs"),
                    (dict(partial_rotary_factor=0.0), "partial_rotary_factor"),
                    (dict(partial_rotary_factor=0.2), "partial_rotary_factor"), (dict(n_shared_experts=0), "shared_expert_gate"),
-                   (dict(layer_types=("linear_attention", "mamba")), "layer_types")],
+                   (dict(layer_types=("linear_attention", "mamba")), "layer_types")],  # the kind's name is mamba2
     # refused by the layer at fault
     "laguna": [(dict(num_attention_heads_per_layer=(4, 5, 4)), r"layer 1 \(sliding_attention\).*5 heads"),
                (dict(num_attention_heads_per_layer=(4, 6)), "num_attention_heads_per_layer"),
@@ -133,6 +154,13 @@ REFUSALS = {
                 r"layer 1 \(sliding_attention\): rope turns 24 "),
                (dict(attn_output_gate=True), "two forms of one gate"), (dict(sliding_window=0), "sliding_window"),
                (dict(layer_ids=(0, 7, 8), num_attention_heads_per_layer=(4, 6, 3)), r"layer 8 \(full_attention\).*3 heads")],
+    # a share of the heads is whole groups; the latent layer is the ungated experts'; a block is one half
+    "nemotron_h": [(dict(held_mamba_heads=(1, 3)), "whole groups"), (dict(held_mamba_heads=(2, 2)), "whole groups"),
+                   (dict(held_mamba_heads=(2, 6)), "whole groups"), (dict(mlp_hidden_act="silu"), "not for a gated expert"),
+                   (dict(mlp_hidden_act="gelu"), "mlp_hidden_act"), (dict(positional_encoding="alibi"), "positional_encoding"),
+                   (dict(mamba_n_groups=3), "mamba2 layer needs"), (dict(ssm_state_size=0), "mamba2 layer needs"),
+                   (dict(mamba_chunk=0), "mamba2 layer needs"), (dict(num_dense_layers=1), "no dense layer"),
+                   (dict(layer_types=("mamba2", "state_space", "routed")), "layer_types")],
 }
 
 
@@ -253,6 +281,26 @@ PLACED = {
         ("jit(lm_train_step)/transpose(jvp(layer0))/dense_ffn/dot_general", ("dense_ffn", "layer0")),
         ("jit(lm_train_step)/jvp(head)/slh,vh->slv/dot_general", ("head_loss", "head")),
         ("jit(lm_train_step)/optimizer/sqrt", ("optimizer", "optimizer")),
+        ("jit(lm_train_step)/jvp(layer3)/rsqrt", ("rest", "layer3")),
+        ("", ("unattributed", ""))],
+    "nemotron_h": [  # the classes carry the accepted q3n readers' names; the latent projections have their own
+        ("jit(lm_train_step)/jvp(layer0)/mamba2/core/Nsgrij,Nsgrjp->Nsgrip/dot_general", ("delta_core", "core")),
+        ("jit(lm_train_step)/transpose(jvp(layer2))/mamba2/core/while/body/mul", ("delta_core", "core")),
+        ("jit(lm_train_step)/checkpoint/rematted_computation/layer4/mamba2/conv/mul", ("delta_conv", "conv")),
+        ("jit(lm_train_step)/jvp(layer0)/mamba2/proj/dot_general", ("delta_proj", "proj")),
+        ("jit(lm_train_step)/jvp(layer6)/mamba2/gates/softplus", ("delta_proj", "gates")),
+        ("jit(lm_eval)/layer9/mamba2/norm_gate/rsqrt", ("delta_proj", "norm_gate")),
+        ("jit(lm_train_step)/jvp(layer7)/full_attention/core/vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/"
+         "pallas_call", ("full_core", "core")),
+        ("jit(lm_train_step)/transpose(jvp(layer7))/full_attention/proj/slngd,ngdh->slh/dot_general", ("attention_proj", "proj")),
+        ("jit(lm_train_step)/jvp(layer1)/moe/latent_in/dot_general", ("latent_proj", "latent_in")),
+        ("jit(lm_train_step)/transpose(jvp(layer3))/moe/latent_out/dot_general", ("latent_proj", "latent_out")),
+        ("jit(lm_train_step)/jvp(layer1)/cond/branch_0_fun/moe/experts/jit(gmm)/pallas_call", ("expert_mm", "experts")),
+        ("jit(lm_train_step)/jvp(layer5)/moe/shared/dot_general", ("shared_expert", "shared")),
+        ("jit(lm_train_step)/jvp(layer5)/moe/router/top_k", ("moe_route", "router")),
+        ("jit(lm_eval)/layer8/cond/branch_1_fun/moe/combine/scatter-add", ("moe_route", "combine")),
+        ("jit(lm_train_step)/jvp(head)/slh,vh->slv/dot_general", ("head_loss", "head")),
+        ("jit(lm_train_step)/bias_update/sign", ("optimizer", "bias_update")),
         ("jit(lm_train_step)/jvp(layer3)/rsqrt", ("rest", "layer3")),
         ("", ("unattributed", ""))],
 }
