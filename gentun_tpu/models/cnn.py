@@ -63,7 +63,9 @@ from ..parallel.mesh import (
     auto_mesh,
     classify_genome_cost,
     cnn_genome_cost,
+    get_mesh_override,
     mesh_axis_sizes,
+    mesh_factor,
     pad_population,
     pop_bucket,
 )
@@ -71,6 +73,7 @@ from ..parallel.multihost import fetch, place, place_tree
 from ..telemetry import lineage as _lineage
 from ..telemetry import spans as _tele
 from ..telemetry.registry import get_registry as _get_registry
+from ..utils.xla_cache import oom_cap_key, read_oom_cap, resolved_cache_dir, write_oom_cap
 from .evaluation import (
     base_keys,
     evaluation_prelude,
@@ -736,7 +739,11 @@ def _dataset_lookup(key_x, key_y, xp, yp, perm, cfg, mesh):
 #: Per-config cap on how many genomes one compiled program may carry,
 #: learned from device OOMs (see _chunked_by_cap).  Keyed by the shape-
 #: relevant config fingerprint so a memory-hungry deep config's cap never
-#: throttles a small config evaluated later in the same process.
+#: throttles a small config evaluated later in the same process.  A cap of
+#: 2 or more is also kept in the persistent cache directory
+#: (``utils/xla_cache.py``, ``.oom_caps.json``), where the next process of
+#: the same configuration, device and compiler finds it before its first
+#: attempt; this dictionary is what a process consults after that.
 _POP_PROGRAM_CAP: Dict[Any, int] = {}
 
 #: cap_keys whose cap=1 exact-size routing has already been warned about
@@ -777,7 +784,46 @@ def _record_oom_split(t0: float, genomes: int, cap: int) -> None:
     _tele.record_span("oom_attempt", t0, time.monotonic() - t0, attrs=dict(attrs))
 
 
-def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
+def _cap_entry(cfg: Optional[Dict[str, Any]], cap_key) -> Optional[Tuple[str, str]]:
+    """Where this configuration's cap outlives the process: the cache
+    directory its evaluations enable and the key of its entry there.  None
+    where it does not: no configuration given, the cache off, or a backend
+    with no memory limit to key by (``xla_cache.oom_cap_key``).  The mesh's
+    part of the key is the factoring a population as wide as the devices
+    gets, so it does not follow the size of the batch at hand."""
+    if cfg is None:
+        return None
+    cache_dir = resolved_cache_dir(cfg["cache_dir"])
+    if cache_dir is None:
+        return None
+    mesh = cfg["mesh"]
+    if mesh == "auto":
+        axes = get_mesh_override() or mesh_factor(jax.device_count())
+    else:
+        axes = mesh_axis_sizes(mesh)
+    key = oom_cap_key(cap_key, axes)
+    return None if key is None else (cache_dir, key)
+
+
+def _restored_cap(cfg: Optional[Dict[str, Any]], cap_key, genomes: int) -> Optional[int]:
+    """The cap an earlier process learned for this configuration on this
+    device, read before the first attempt: made this process's own, with an
+    ``oom_cap_restored`` event and ``oom_cap_restored_total``.  ``oom_split``
+    and ``oom_attempt`` keep meaning that this process paid an attempt.  A
+    cap of 1 is never inherited (see ``_chunked_by_cap``)."""
+    entry = _cap_entry(cfg, cap_key)
+    cap = read_oom_cap(*entry) if entry else None
+    if cap is None or cap < 2:
+        return None
+    _POP_PROGRAM_CAP[cap_key] = cap
+    _tele.record_event("oom_cap_restored", {"genomes": genomes, "cap": cap})
+    _get_registry().counter("oom_cap_restored_total").inc()
+    logger.info("chunking to <=%d genomes per program: the cap an earlier process "
+                "learned for this config on this device (%s)", cap, entry[0])
+    return cap
+
+
+def _chunked_by_cap(run, genomes, cap_key, run_exact=None, cfg=None):
     """Run the batched evaluator, splitting the population on device OOM.
 
     BASELINE config #5 (S=(5,5,5), 256 channels, pop=50) is sized for a
@@ -789,6 +835,20 @@ def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
     axis shards and no OOM ever happens, so the cap stays unset and
     behavior is unchanged.
 
+    With ``cfg`` (the normalised config) the cap outlives the process: it
+    is written beside the compiled programs in the persistent cache
+    directory (``_cap_entry``), and a process whose own dictionary holds
+    nothing for the config looks there BEFORE its first attempt and
+    pre-chunks exactly as it would after healing — same widths, same
+    programs, same order of chunks.  So the failed attempt is paid once a
+    cache directory, not once a process.  A restored cap is where the
+    attempts start, not a promise: a chunk that still runs out of memory
+    heals as below and the smaller cap replaces the entry; the file never
+    raises a cap this process has learned.  A restarted search whose first
+    batch is smaller than the population therefore chunks it at the width
+    the earlier process ran at, which is the width its fitness cache was
+    filled under.
+
     ``run_exact`` is the unpadded (exact-size) runner: since the compile
     bucket floors at 2, a singleton chunk padded by ``run`` still executes
     a 2-wide program, so a learned cap of 1 is only honorable — and a
@@ -797,11 +857,15 @@ def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
     1-wide unpadded program, so batch-composition purity is gone for the
     rest of the search (values measured before the boundary came from
     multi-slot programs) — survival over purity, warned once per config.
+    That is why a cap of 1 is never written and never restored: a process
+    re-learns it rather than inherit it from one bad moment of another.
     """
     cap = _POP_PROGRAM_CAP.get(cap_key)
+    if cap is None:
+        cap = _restored_cap(cfg, cap_key, len(genomes))
     if cap is not None and len(genomes) > cap:
         return np.concatenate(
-            [_chunked_by_cap(run, genomes[i : i + cap], cap_key, run_exact)
+            [_chunked_by_cap(run, genomes[i : i + cap], cap_key, run_exact, cfg)
              for i in range(0, len(genomes), cap)]
         )
     if cap == 1 and len(genomes) == 1 and run_exact is not None:
@@ -841,10 +905,14 @@ def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
                 b *= 2
             _POP_PROGRAM_CAP[cap_key] = b
             _record_oom_split(t0, len(genomes), b)
+            entry = _cap_entry(cfg, cap_key) if b > 1 else None
+            if entry:
+                write_oom_cap(*entry, b)
             logger.warning(
                 "population batch of %d genomes exhausted device memory; "
                 "chunking to <=%d genomes per program (remembered for this "
-                "config in this process)", len(genomes), b,
+                "config in this process%s)", len(genomes), b,
+                f", and in {entry[0]} for the next" if entry else "",
             )
     # Retry OUTSIDE the except block, deliberately: the failed attempt's
     # exception traceback pins the frames (and therefore the device
@@ -858,7 +926,7 @@ def _chunked_by_cap(run, genomes, cap_key, run_exact=None):
     gc.collect()
     if fallback is not None:
         return fallback(genomes)
-    return _chunked_by_cap(run, genomes, cap_key, run_exact)
+    return _chunked_by_cap(run, genomes, cap_key, run_exact, cfg)
 
 
 # Compile-shape bucketing moved to parallel/mesh.pop_bucket so the
@@ -1172,7 +1240,8 @@ class GeneticCnnModel(GentunModel):
         computation with P-wide batched convolutions.  A population too
         large for the device's memory (deep configs on few chips) is
         chunked automatically, with the learned cap reused across
-        generations (``_chunked_by_cap``).
+        generations and, through the persistent cache directory, across
+        processes (``_chunked_by_cap``).
         """
         reps_raw = config.get("fitness_reps", 1)
         reps = 1 if reps_raw is None else int(reps_raw)
@@ -1223,6 +1292,7 @@ class GeneticCnnModel(GentunModel):
             run_exact=lambda gs: cls._cross_validate_population_one(
                 x_train, y_train, gs, **{**config, "pop_padding": False}
             ),
+            cfg=cfg0,
         )
 
     @classmethod
@@ -1359,6 +1429,7 @@ class GeneticCnnModel(GentunModel):
             run_exact=lambda gs: cls._train_and_score_one(
                 x_train, y_train, x_test, y_test, gs, **{**config, "pop_padding": False}
             ),
+            cfg=cfg0,
         )
 
     @classmethod

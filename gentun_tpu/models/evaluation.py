@@ -30,8 +30,8 @@ from ..telemetry import spans as _tele
 from ..telemetry.registry import get_registry as _get_registry
 from ..utils.jax_state import mark_backend_used
 from ..utils.xla_cache import (
-    default_cache_dir,
     enable_compilation_cache,
+    resolved_cache_dir,
     run_publish_hooks,
 )
 
@@ -168,10 +168,7 @@ def evaluation_prelude(cache_dir) -> None:
     here: enable_compilation_cache never re-points a cache placed from
     outside.
     """
-    if cache_dir is None:
-        cache_dir = default_cache_dir()
-    elif cache_dir is False or str(cache_dir).strip().lower() in ("", "0", "off", "none", "disabled"):
-        cache_dir = None
+    cache_dir = resolved_cache_dir(cache_dir)
     if cache_dir:
         enable_compilation_cache(cache_dir)
     # Fleet-wide compile cache (distributed/compile_service.py): a worker

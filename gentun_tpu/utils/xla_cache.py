@@ -32,22 +32,36 @@ warning — it must never take the training path down.
 The thresholds are dropped to zero because GA fitness programs are small by
 XLA standards: the default "only cache compiles > 1 s / > 0 bytes" heuristics
 would skip exactly the programs we want cached.
+
+Beside the compiled programs the directory holds one file of this package's
+own, ``.oom_caps.json``: the population widths the evaluator's out-of-memory
+healer learned (``models/cnn.py::_chunked_by_cap``), each under the
+configuration, device and compiler it was learned on (:func:`oom_cap_key`).
+A process that shares the directory starts at that width and does not pay
+the attempt that found it; deleting the directory forgets the caps with
+the programs.  With the cache off nothing is read and nothing is written.
 """
 
 from __future__ import annotations
 
+import functools
+import json
 import logging
 import os
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 __all__ = [
     "cache_stats",
     "default_cache_dir",
     "enable_compilation_cache",
     "list_cache_entries",
+    "oom_cap_key",
+    "read_oom_cap",
     "register_publish_hook",
+    "resolved_cache_dir",
     "run_publish_hooks",
     "unregister_publish_hook",
+    "write_oom_cap",
 ]
 
 logger = logging.getLogger("gentun_tpu")
@@ -90,6 +104,30 @@ def default_cache_dir() -> Optional[str]:
     return _env_cache_dir() or _CHECKOUT_CACHE_DIR
 
 
+def _cache_path(cache_dir) -> str:
+    """``cache_dir`` as one absolute path; ``JAX_COMPILATION_CACHE_DIR`` beats it."""
+    return os.path.abspath(os.path.expanduser(str(_env_cache_dir() or cache_dir)))
+
+
+def resolved_cache_dir(cache_dir) -> Optional[str]:
+    """The directory an evaluation configured with ``cache_dir`` caches in.
+
+    ``None`` means the default (:func:`default_cache_dir`); ``False`` or
+    ``"off"``/``"0"``/``"none"`` is the programmatic opt-out.  As in
+    :func:`enable_compilation_cache`, ``JAX_COMPILATION_CACHE_DIR`` beats a
+    path given here.  Returns None where the cache is off or the directory
+    has failed to enable.
+    """
+    if cache_dir is None:
+        cache_dir = default_cache_dir()
+    elif cache_dir is False or str(cache_dir).strip().lower() in ("", "0", "off", "none", "disabled"):
+        cache_dir = None
+    if not cache_dir:
+        return None
+    path = _cache_path(cache_dir)
+    return None if path in _failed_dirs else path
+
+
 def enable_compilation_cache(cache_dir: str) -> Optional[str]:
     """Point jax's persistent compilation cache at ``cache_dir``.
 
@@ -105,7 +143,7 @@ def enable_compilation_cache(cache_dir: str) -> Optional[str]:
     """
     global _enabled_dir
     env_dir = _env_cache_dir()
-    cache_dir = os.path.abspath(os.path.expanduser(str(env_dir or cache_dir)))
+    cache_dir = _cache_path(cache_dir)
     if _enabled_dir == cache_dir:
         return cache_dir
     if cache_dir in _failed_dirs:
@@ -182,6 +220,103 @@ def list_cache_entries(cache_dir: Optional[str] = None) -> Dict[str, Tuple[int, 
     except FileNotFoundError:
         return {}
     return out
+
+
+# -- learned out-of-memory caps ------------------------------------------------
+
+#: The leading dot keeps the file out of :func:`list_cache_entries`, so the
+#: compile service never ships it as an executable; jax's own LRU only
+#: counts ``*-cache`` files.
+_OOM_CAPS_FILE = ".oom_caps.json"
+
+#: ``{directory: {key: cap}}`` as first read (or last written) by this
+#: process: the file is read once a process and directory.
+_oom_caps_read: Dict[str, Dict[str, int]] = {}
+
+
+@functools.lru_cache(maxsize=None)
+def _device_facts() -> Optional[Dict[str, Any]]:
+    """What a cap depends on besides the configuration: the device, how much
+    memory the backend gives it, how many there are, and the compiler
+    (``platform_version`` is libtpu's build on a TPU; jax hashes the same
+    string into its own cache keys).  None where a cap must not outlive the
+    process: a backend that reports no memory limit (the CPU), and a mesh
+    over several processes, which must all chunk alike and may not share a
+    directory."""
+    import jax
+    import jaxlib
+
+    if jax.process_count() > 1:
+        return None
+    device = jax.local_devices()[0]
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        return None
+    return {
+        "device_kind": device.device_kind,
+        "bytes_limit": int(limit),
+        "local_devices": jax.local_device_count(),
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "platform_version": device.client.platform_version,
+    }
+
+
+def oom_cap_key(config_key: Any, mesh_axes: Sequence[int]) -> Optional[str]:
+    """The canonical string a learned cap is kept under, or None where it is
+    not kept (:func:`_device_facts`).  ``config_key`` is the evaluator's own
+    key (ints, strings and tuples of them), ``mesh_axes`` the ``(pop, data)``
+    sizes: on a mesh the pop axis shards and the same configuration fits.
+    Any difference is another key, so a miss."""
+    facts = _device_facts()
+    if facts is None:
+        return None
+    return json.dumps({"config": config_key, "mesh": list(mesh_axes), **facts},
+                      sort_keys=True, separators=(",", ":"))
+
+
+def _load_oom_caps(path: str, say: Callable[..., None] = logger.warning) -> Dict[str, int]:
+    """The file's whole-number entries; absent or unreadable is empty."""
+    try:
+        with open(path, encoding="utf-8") as f:
+            caps = json.load(f)["caps"]
+        return {k: v for k, v in caps.items() if type(v) is int}
+    except FileNotFoundError:
+        return {}
+    except (OSError, ValueError, KeyError, TypeError, AttributeError) as e:
+        say("ignoring the learned out-of-memory caps in %s: %r", path, e)
+        return {}
+
+
+def read_oom_cap(cache_dir: str, key: str) -> Optional[int]:
+    """The cap kept in ``cache_dir`` under ``key``, or None."""
+    caps = _oom_caps_read.get(cache_dir)
+    if caps is None:
+        caps = _oom_caps_read[cache_dir] = _load_oom_caps(os.path.join(cache_dir, _OOM_CAPS_FILE))
+    return caps.get(key)
+
+
+def write_oom_cap(cache_dir: str, key: str, cap: int) -> None:
+    """Keep ``cap`` under ``key``, beside what other processes have kept.
+
+    Read again, merge, write to a temporary name, ``os.replace``: a reader
+    never sees half a file.  Two processes that heal at once write the same
+    value for the same key; a write lost between two keys costs some later
+    process one more attempt.  Failing to write is a warning, never an error.
+    """
+    path = os.path.join(cache_dir, _OOM_CAPS_FILE)
+    # quietly: the evaluator read the directory before the attempt that ends here, and
+    # an unreadable file was called so then
+    caps = _load_oom_caps(path, logger.debug)
+    caps[key] = cap
+    _oom_caps_read[cache_dir] = caps
+    tmp = os.path.join(cache_dir, f".oom_caps-{os.getpid()}.tmp")
+    try:
+        with open(tmp, "w", encoding="utf-8") as f:
+            json.dump({"caps": caps}, f, indent=0, sort_keys=True)
+        os.replace(tmp, path)
+    except OSError as e:
+        logger.warning("could not keep the learned out-of-memory cap in %s: %r", path, e)
 
 
 def cache_stats(cache_dir: Optional[str] = None) -> Dict[str, Any]:

@@ -5,6 +5,9 @@ single-chip train-step correctness the reference never had.  Everything here
 runs on the virtual CPU mesh (conftest pins jax to cpu).
 """
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -514,6 +517,208 @@ class TestOomChunking:
         finally:
             cnn_mod._POP_PROGRAM_CAP.pop(key, None)
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+
+
+#: A device as ``xla_cache._device_facts`` describes one: the CPU these tests
+#: run on reports no memory limit, so there nothing outlives the process.
+V5E = {"device_kind": "TPU v5 lite", "bytes_limit": 16_909_336_576, "local_devices": 1,
+       "jax": "0.9.0", "jaxlib": "0.9.0", "platform_version": "libtpu 0.0.34"}
+
+
+class Events:
+    def __init__(self):
+        self.items = []
+
+    def record(self, rec):
+        self.items.append(rec)
+
+    def named(self, name):
+        return [r.get("data") or r.get("attrs") for r in self.items if r.get("name") == name]
+
+
+class TestOomCapOutlivesTheProcess:
+    """The healer's cap is kept beside the compiled programs
+    (``<cache dir>/.oom_caps.json``) under configuration, device and
+    compiler, and read before a process's first attempt."""
+
+    KEY = ("deep-cfg", (5, 5, 5), 256)
+    GENOMES = [{"S_1": (1, 0, 1)} for _ in range(50)]
+
+    @pytest.fixture
+    def store(self, tmp_path, monkeypatch):
+        from gentun_tpu.models import cnn as cnn_mod
+        from gentun_tpu.telemetry import spans
+        from gentun_tpu.utils import xla_cache
+
+        facts, the_cpu = dict(V5E), xla_cache._device_facts
+        monkeypatch.setattr(xla_cache, "_device_facts", lambda: dict(facts))
+        monkeypatch.setattr(xla_cache, "_oom_caps_read", {})
+        monkeypatch.setattr(cnn_mod, "_POP_PROGRAM_CAP", {})
+        events = Events()
+        spans.set_run_sink(events)
+        spans.enable()
+
+        class Store:
+            cfg = {"cache_dir": str(tmp_path), "mesh": None}
+            path = tmp_path / ".oom_caps.json"
+
+            def __init__(self):
+                self.facts, self.the_cpu, self.events = facts, the_cpu, events
+
+            def new_process(self):
+                cnn_mod._POP_PROGRAM_CAP.clear()
+                xla_cache._oom_caps_read.clear()
+                events.items.clear()
+
+            def run(self, fail_above, genomes=TestOomCapOutlivesTheProcess.GENOMES,
+                    key=TestOomCapOutlivesTheProcess.KEY, cfg=None, run_exact=None):
+                """One ``_chunked_by_cap`` call: the widths it ran, in order."""
+                run, calls = TestOomChunking()._fake_oom_run(fail_above)
+                out = cnn_mod._chunked_by_cap(run, genomes, key, run_exact, cfg or self.cfg)
+                assert out.shape == (len(genomes),)
+                return calls
+
+            def kept(self):
+                return sorted(json.loads(self.path.read_text())["caps"].values()) if self.path.exists() else None
+
+        try:
+            yield Store()
+        finally:
+            spans.disable()
+            spans.set_run_sink(None)
+
+    def test_a_new_process_starts_at_the_kept_cap(self, store):
+        from gentun_tpu.telemetry.registry import get_registry
+
+        assert store.run(16) == [50, 16, 16, 16, 2]
+        assert store.kept() == [16] and store.events.named("oom_cap_restored") == []
+        restored = get_registry().counter("oom_cap_restored_total")
+        before = restored.value
+        store.new_process()
+        assert store.run(16) == [16, 16, 16, 2]  # no failing attempt
+        assert store.events.named("oom_cap_restored") == [{"genomes": 50, "cap": 16}]
+        assert store.events.named("oom_split") == [] and store.events.named("oom_attempt") == []
+        assert store.run(16) == [16, 16, 16, 2]  # and said once a process
+        assert len(store.events.named("oom_cap_restored")) == 1 and restored.value == before + 1
+        # a resumed search's smaller first batch runs at the width the cache was filled under
+        store.new_process()
+        assert store.run(16, genomes=self.GENOMES[:20]) == [16, 4]
+
+    @pytest.mark.parametrize("what", ["config", "bytes_limit", "local_devices", "device_kind", "jax",
+                                      "jaxlib", "platform_version", "mesh"])
+    def test_any_difference_in_the_key_is_a_miss(self, store, what):
+        assert store.run(16)[0] == 50 and store.kept() == [16]
+        store.new_process()
+        key, cfg = self.KEY, dict(store.cfg)
+        if what == "config":
+            key = self.KEY[:-1] + (128,)
+        elif what == "mesh":
+            cfg["mesh"] = "auto"  # eight virtual devices: (8, 1), not (1, 1)
+        else:
+            store.facts[what] = 2 * store.facts[what]
+        assert store.run(16, key=key, cfg=cfg)[0] == 50  # today's behaviour: the attempt
+        assert store.events.named("oom_cap_restored") == []
+        assert store.kept() == [16, 16]  # and both entries are kept
+
+    @pytest.mark.parametrize("content", [
+        "not json at all", "[1, 2]", '{"caps": [16]}', '{"caps": {"another key": 16}}', "KEY: 16.5", "KEY: true",
+        'KEY: "16"', "KEY: 1", "KEY: 0"],
+        ids=["torn", "a_list", "caps_a_list", "foreign", "a_float", "a_bool", "a_string", "one", "zero"])
+    def test_a_file_that_cannot_be_used_is_ignored(self, store, content, caplog):
+        from gentun_tpu.utils import xla_cache
+
+        if content.startswith("KEY: "):
+            key = xla_cache.oom_cap_key(self.KEY, (1, 1))
+            content = json.dumps({"caps": {key: json.loads(content[5:])}})
+        store.path.write_text(content)
+        with caplog.at_level("WARNING", logger="gentun_tpu"):
+            assert store.run(16) == [50, 16, 16, 16, 2]
+        said = [r for r in caplog.records if "ignoring the learned out-of-memory caps" in r.getMessage()]
+        assert len(said) == (1 if '"caps": {' not in content else 0)  # one line a process, not one a read
+        assert store.events.named("oom_cap_restored") == []
+        assert store.kept()[-1] == 16  # and the healed cap replaces it
+
+    @pytest.mark.parametrize("cache_dir", [None, False, "off"])
+    def test_with_the_cache_off_nothing_is_read_or_written(self, store, tmp_path, monkeypatch, cache_dir):
+        from gentun_tpu.utils import xla_cache
+
+        assert os.environ["GENTUN_TPU_CACHE_DIR"] == "off"  # tests/conftest.py: what None resolves by
+        store.run(16)
+        reads = []
+        monkeypatch.setattr(xla_cache, "_load_oom_caps", reads.append)
+        before = sorted(os.listdir(tmp_path))
+        store.new_process()
+        assert store.run(16, cfg={"cache_dir": cache_dir, "mesh": None}) == [50, 16, 16, 16, 2]
+        assert reads == [] and sorted(os.listdir(tmp_path)) == before
+        assert store.events.named("oom_cap_restored") == []
+
+    def test_a_backend_with_no_memory_limit_keeps_nothing(self, store, monkeypatch):
+        from gentun_tpu.utils import xla_cache
+
+        monkeypatch.setattr(xla_cache, "_device_facts", store.the_cpu)
+        assert xla_cache.oom_cap_key(self.KEY, (1, 1)) is None
+        assert store.run(16)[0] == 50 and store.kept() is None
+
+    def test_a_cap_of_one_is_not_written(self, store):
+        exact = []
+
+        def run_exact(genomes):
+            exact.append(len(genomes))
+            return np.zeros(len(genomes))
+
+        # three genomes halve to one; each then runs through the unpadded program
+        assert store.run(1, genomes=self.GENOMES[:3], run_exact=run_exact) == [3] and exact == [1, 1, 1]
+        assert store.kept() is None
+        store.new_process()
+        assert store.run(0, genomes=self.GENOMES[:1], run_exact=run_exact) == [1]  # the singleton branch
+        assert store.kept() is None and exact == [1, 1, 1, 1]
+
+    def test_a_restored_cap_that_still_fails_heals_and_replaces_the_entry(self, store):
+        store.run(16)
+        store.new_process()
+        assert store.run(8) == [16, 8, 8, 8, 8, 8, 8, 2]  # one failing attempt, at the restored width
+        assert store.kept() == [8]
+        assert store.events.named("oom_split") == [{"genomes": 16, "cap": 8}]
+        store.new_process()
+        assert store.run(8) == [8, 8, 8, 8, 8, 8, 2]
+        # the file never raises a cap a process has learned
+        store.path.write_text(store.path.read_text().replace(": 8", ": 32"))
+        assert store.run(8) == [8, 8, 8, 8, 8, 8, 2]
+
+    def test_two_writers_with_different_keys_both_survive(self, store):
+        from gentun_tpu.utils import xla_cache
+
+        store.run(16)
+        # another worker, another device: it read the directory before the first wrote
+        store.new_process()
+        xla_cache._oom_caps_read[store.cfg["cache_dir"]] = {}
+        store.facts["bytes_limit"] = 8 << 30
+        store.run(4)
+        assert store.kept() == [4, 16]
+        assert [n for n in os.listdir(store.cfg["cache_dir"]) if n != ".oom_caps.json"] == []  # no temporary left
+
+    def test_the_model_hands_its_configuration_to_the_healer(self, store, separable_data, monkeypatch):
+        """Through ``cross_validate_population``: the entry is keyed by the
+        normalised configuration and lives in the directory it names."""
+        from gentun_tpu.models import cnn as cnn_mod
+        from gentun_tpu.models.cnn import GeneticCnnModel
+
+        def too_big_above_two(cls, x, y, genomes, **config):
+            if len(genomes) > 2:
+                raise RuntimeError("RESOURCE_EXHAUSTED: out of memory (made up by the test)")
+            return np.zeros(len(genomes), np.float32)
+
+        monkeypatch.setattr(GeneticCnnModel, "_cross_validate_population_one", classmethod(too_big_above_two))
+        x, y = separable_data
+        cfg = dict(nodes=(3,), kernels_per_layer=(8,), kfold=2, batch_size=32, mesh=None, **{"cache_dir": store.cfg["cache_dir"]})
+        GeneticCnnModel.cross_validate_population(x, y, self.GENOMES[:5], **cfg)
+        assert store.kept() == [2] and store.events.named("oom_split") == [{"genomes": 5, "cap": 2}]
+        store.new_process()
+        GeneticCnnModel.cross_validate_population(x, y, self.GENOMES[:5], **cfg)
+        assert store.events.named("oom_cap_restored") == [{"genomes": 5, "cap": 2}]
+        assert store.events.named("oom_split") == []
+        (key,) = cnn_mod._POP_PROGRAM_CAP
+        assert key == cnn_mod._oom_cap_key(cnn_mod._normalize_config(x, y, dict(cfg)))
 
 
 class TestBatchCompositionPurity:

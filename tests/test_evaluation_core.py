@@ -94,7 +94,7 @@ def test_prelude_is_the_same_from_either_family(monkeypatch, call, cache_dir, en
     def stop():
         raise Stop
 
-    monkeypatch.setattr(evaluation, "default_cache_dir", lambda: "the default")
+    monkeypatch.setattr(xla_cache, "default_cache_dir", lambda: "the default")
     monkeypatch.setattr(evaluation, "enable_compilation_cache", seen["enabled"].append)
     monkeypatch.setattr(evaluation, "mark_backend_used", stop)  # the prelude's last step
     xla_cache.register_publish_hook(hook)
@@ -103,7 +103,7 @@ def test_prelude_is_the_same_from_either_family(monkeypatch, call, cache_dir, en
             call(cache_dir)
     finally:
         xla_cache.unregister_publish_hook(hook)
-    assert seen == {"enabled": [enabled] if enabled else [], "published": 1}
+    assert seen == {"enabled": [os.path.abspath(enabled)] if enabled else [], "published": 1}
 
 
 # -- the options that went --------------------------------------------------------------

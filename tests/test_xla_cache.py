@@ -19,6 +19,9 @@ from gentun_tpu.utils.xla_cache import (
     default_cache_dir,
     enable_compilation_cache,
     list_cache_entries,
+    read_oom_cap,
+    resolved_cache_dir,
+    write_oom_cap,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -165,6 +168,59 @@ class TestEntryListing:
         size, mtime = entries["entry_a"]
         assert size == 10 and mtime > 0
 
+    def test_the_learned_caps_file_is_no_entry(self, tmp_path):
+        """``.oom_caps.json`` lives beside the executables and is never
+        listed, counted or shipped as one."""
+        d = tmp_path / "cache"
+        d.mkdir()
+        (d / "entry_a").write_bytes(b"x" * 10)
+        write_oom_cap(str(d), "a key", 16)
+        write_oom_cap(str(d), "another", 8)
+        assert sorted(os.listdir(d)) == [".oom_caps.json", "entry_a"]  # no temporary left behind
+        assert (read_oom_cap(str(d), "a key"), read_oom_cap(str(d), "another"), read_oom_cap(str(d), "none")) == (16, 8, None)
+        assert set(list_cache_entries(str(d))) == {"entry_a"} and cache_stats(str(d))["entries"] == 1
+
+    def test_a_cap_is_keyed_by_device_and_compiler(self, monkeypatch):
+        """``oom_cap_key``: one canonical string (no salted ``hash``) of the
+        evaluator's key, the mesh and what the backend says of itself; None
+        where a cap must not outlive the process."""
+        import jax
+
+        from gentun_tpu.utils import xla_cache
+
+        class Client:
+            platform_version = "libtpu built for the test"
+
+        class Device:
+            device_kind, client = "TPU v5 lite", Client()
+
+            def __init__(self, stats):
+                self.memory_stats = lambda: stats
+
+        facts = xla_cache._device_facts.__wrapped__
+        monkeypatch.setattr(xla_cache, "_device_facts", facts)
+        monkeypatch.setattr(jax, "local_devices", lambda: [Device({"bytes_limit": 1 << 34})])
+        key = xla_cache.oom_cap_key(((5, 5), "bfloat16", None), (1, 1))
+        assert json.loads(key) == {
+            "config": [[5, 5], "bfloat16", None], "mesh": [1, 1], "device_kind": "TPU v5 lite", "bytes_limit": 1 << 34,
+            "local_devices": jax.local_device_count(), "jax": jax.__version__,
+            "jaxlib": __import__("jaxlib").__version__, "platform_version": "libtpu built for the test"}
+        assert key == xla_cache.oom_cap_key(((5, 5), "bfloat16", None), [1, 1]) and '"mesh":[1,1]' in key
+        monkeypatch.setattr(jax, "process_count", lambda: 2)  # processes of one mesh must all chunk alike
+        assert xla_cache.oom_cap_key((), (1, 1)) is None
+        monkeypatch.setattr(jax, "process_count", lambda: 1)
+        for stats in (None, {}, {"bytes_in_use": 5}):  # the CPU says None
+            monkeypatch.setattr(jax, "local_devices", lambda stats=stats: [Device(stats)])
+            assert xla_cache.oom_cap_key((), (1, 1)) is None
+
+    def test_a_cap_that_cannot_be_written_is_a_warning(self, tmp_path, caplog):
+        import logging
+
+        with caplog.at_level(logging.WARNING, logger="gentun_tpu"):
+            write_oom_cap(str(tmp_path / "nope"), "a key", 16)
+        assert "could not keep the learned out-of-memory cap" in caplog.text
+        assert not (tmp_path / "nope").exists()
+
     def test_missing_dir_is_empty_cache_not_error(self, tmp_path):
         assert list_cache_entries(str(tmp_path / "nope")) == {}
 
@@ -188,6 +244,22 @@ class TestEntryListing:
 
 
 class TestCacheOptOutAndDegrade:
+    def test_where_an_evaluation_caches(self, monkeypatch, tmp_path):
+        """``resolved_cache_dir``: what ``evaluation_prelude`` enables and
+        where a learned cap is kept; None is off."""
+        from gentun_tpu.utils import xla_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("GENTUN_TPU_CACHE_DIR", "off")
+        assert [resolved_cache_dir(v) for v in (None, False, "", "0", "off", " None ", "disabled")] == [None] * 7
+        assert resolved_cache_dir("a/path") == os.path.abspath("a/path")  # a path given beats the kill switch
+        monkeypatch.delenv("GENTUN_TPU_CACHE_DIR")
+        assert resolved_cache_dir(None) == default_cache_dir()
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert resolved_cache_dir("a/path") == resolved_cache_dir(None) == str(tmp_path)  # the environment's beats both
+        monkeypatch.setattr(xla_cache, "_failed_dirs", {str(tmp_path)})
+        assert resolved_cache_dir(None) is None
+
     def test_unwritable_dir_degrades_with_warning(self, caplog, monkeypatch):
         import logging
 
