@@ -62,9 +62,12 @@ normalised over the chosen::
          0 <= i - j <= sliding_window - 1; rope at theta^(-2c/head) (``rope_parameters.sliding_attention``)
     Op = full_attention:     key j visible iff j <= i; YaRN's frequencies, cos and sin times ``attention_factor``
          (``rope_parameters.full_attention``).  The same two cores run both masks: the fused kernel is handed
-         the library's ``LocalMask`` or ``CausalMask`` and visits only the block pairs that hold a visible key
-         (15 of 64 against 36 at 8,192 positions and 1,024 x 1,024 blocks: :func:`_kernel_visits`); XLA's
-         query blocks are handed the window's keys alone
+         the library's ``CausalMask`` and visits only the block pairs that hold a visible key (36 of 64 at
+         8,192 positions and 1,024 x 1,024 blocks), or, under the window, runs banded: the sequence in chunks,
+         a chunk's queries of a key-value head's whole group as one block of rows against the chunk's own
+         ``chunk + window`` keys under the library's ``LocalMask`` (:func:`_banded_core`; a head visits
+         ``length x (chunk + window)`` score elements, :func:`_kernel_visits`, where the unbanded kernel's 15
+         pairs cost 15.7 M at any window up to 1,024); XLA's query blocks are handed the window's keys alone
     FFN routed:  p = softmax(W_r x); chosen = top-k; w = p[chosen] / sum p[chosen]; the held experts' part
     loss = cross-entropy (head untied) + alpha * the balance term above: the *recipe's* (``aux_alpha``), the
            published config gives it no weight
@@ -233,11 +236,12 @@ _GMM_TILING = (512, 512, 512)
 #: The fused attention kernel's blocks (splash attention): queries x keys a grid step holds and
 #: the keys one product inside it takes, forward, then the same for the one backward kernel
 #: (dk, dv and dq together).  Set by chip runs at the published shape (PERF.md, PR 31).
-#: A layer whose mask is a window takes the same blocks: the kernel visits the block pairs that
-#: hold a visible key, and smaller blocks waste less of a 1,024-key window (45 of 256 pairs at
-#: 512 x 512 against 15 of 64, a quarter less area) but pay more grid steps for it: forward and
-#: backward of one layer-step at Mellum2's shape took 16.8 ms at these blocks, 20.4 at 512 /
-#: 512 / 512, 18.0-21.2 at four other shapes (PERF.md, PR 34).
+#: A layer whose mask is a window runs banded where its shape allows (:func:`_kernel_chunk`) and
+#: takes these blocks where not: the unbanded kernel visits the block pairs that hold a visible
+#: key and costs each its whole area (15 of 64 at a window of 512 and of 1,024 alike), and
+#: smaller blocks waste less of it (45 of 256 pairs at 512 x 512, a quarter less area) but pay
+#: more grid steps: forward and backward of one layer-step at Mellum2's shape took 16.8 ms at
+#: these blocks, 20.4 at 512 / 512 / 512, 18.0-21.2 at four other shapes (PERF.md, PR 34).
 _ATTN_KERNEL_BLOCKS = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
                            block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512)
 #: The columns of a head of q (as wide as k's, zero columns counted) and of v together up to which the
@@ -245,6 +249,10 @@ _ATTN_KERNEL_BLOCKS = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
 #: (a head size of 256) asks for 16.57 MB of the 16 it may take, and its query block halves
 #: (:func:`_kernel_blocks`).  The key block stays: the fused backward writes a partial dq a key block.
 _ATTN_KERNEL_COLUMNS = 384
+#: The chunks a windowed layer's banded core may take, the first that fits the shape (:func:`_kernel_chunk`),
+#: and the widest product inside its one key block (:func:`_band_blocks`).
+_ATTN_KERNEL_CHUNKS = (256, 128, 512)
+_ATTN_BAND_COMPUTE = 768
 #: The row buffer's heights below the worst case (top-k x tokens, always the last rung), in
 #: shares: times the rows a routed layer sends this rank on average.  A layer-step runs at the
 #: first that holds its rows; dispatch, combine and the experts' masks cost their height.
@@ -705,21 +713,25 @@ def yarn_amplitude(scaling: Mapping[str, Any]) -> float:
     return yarn_mscale(scaling["factor"], 1.0)
 
 
-def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]], rotary: Optional[int] = None):
+def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]], rotary: Optional[int] = None, chunks: int = 0):
     """cos and sin of the positions of ``x`` (sequences, length, ..., head size), float32, one
     column a rotated pair (half the head size, or half of ``rotary``, the leading columns that
     turn) and shaped to broadcast against ``x``'s halves.
     With ``scaling`` (YaRN) the frequencies are :func:`yarn_inv_freq`'s and both carry
-    :func:`yarn_amplitude`."""
+    :func:`yarn_amplitude`.  ``chunks``: ``x`` holds its sequences in that many chunks each,
+    (sequences x chunks, chunk, ..., head size), and row ``i`` is at chunk ``i % chunks``."""
     turning = x.shape[-1] if rotary is None else rotary
     half = turning // 2
     if scaling is None:
         inv_freq, amplitude = theta ** (-jnp.arange(half, dtype=jnp.float32) / half), 1.0
     else:
         inv_freq, amplitude = jnp.asarray(yarn_inv_freq(turning, theta, scaling)), yarn_amplitude(scaling)
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    per_position = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
+    rows = max(chunks, 1)  # of the tables: a sequence's chunks, which every sequence shares
+    angle = jnp.arange(rows * x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    per_position = (rows, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
     cos, sin = jnp.cos(angle).reshape(per_position), jnp.sin(angle).reshape(per_position)
+    if chunks:
+        cos, sin = (jnp.tile(table, (x.shape[0] // chunks,) + (1,) * (x.ndim - 1)) for table in (cos, sin))
     if amplitude != 1.0:
         cos, sin = cos * amplitude, sin * amplitude
     return cos, sin
@@ -736,7 +748,8 @@ def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None, rotary: Optional[int] = None):
+def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None, rotary: Optional[int] = None,
+                      chunks: int = 0):
     """:func:`_rope`'s function on whole heads without a slice or a concatenation:
     ``x * [cos | cos] + (x @ T) * [sin | sin]``, where ``T`` is the signed
     permutation that sends ``[x1 | x2]`` to ``[-x2 | x1]``.  Every product with
@@ -751,10 +764,12 @@ def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None, rot
     against 3.56; 45.5 ms against 35.6).  With ``rotary`` under the head size
     only the leading ``rotary`` columns turn (pairs ``(c, c + rotary / 2)``
     inside them): the tables read cos 1 and sin 0 on the columns that pass, and
-    ``T`` has no entry for them -- the same one fusion."""
+    ``T`` has no entry for them -- the same one fusion.  ``chunks``: ``x`` holds
+    each sequence in that many chunks, as the banded core's queries are
+    (:func:`_rope_tables`)."""
     size = x.shape[-1]
     turning = size if rotary is None else rotary
-    cos, sin = (jnp.concatenate([table, table], axis=-1) for table in _rope_tables(x, theta, scaling, rotary))
+    cos, sin = (jnp.concatenate([table, table], axis=-1) for table in _rope_tables(x, theta, scaling, rotary, chunks))
     if turning < size:
         passing = [(0, 0)] * (cos.ndim - 1) + [(0, size - turning)]
         cos, sin = jnp.pad(cos, passing, constant_values=1.0), jnp.pad(sin, passing)
@@ -799,17 +814,62 @@ def _use_attention_kernel(length: int) -> bool:
     return True
 
 
+def _kernel_chunk(length: int, window: Optional[int], group: int, columns: int = 0) -> int:
+    """The positions a chunk of the banded core holds (:func:`_kernel_core`), or 0
+    where the core runs unbanded: no window, or no chunk of ``_ATTN_KERNEL_CHUNKS``
+    that divides the window and the length, whose ``group`` query heads' rows are
+    whole query blocks and whose keys (chunk + window) are whole 128 lanes -- a
+    rule by shape, as :func:`_gmm_tiling`'s."""
+    if window is None or window >= length:
+        return 0
+    return next((chunk for chunk in _ATTN_KERNEL_CHUNKS
+                 if window % chunk == 0 and length % chunk == 0 and (chunk + window) % 128 == 0
+                 and _kernel_blocks(group * chunk, columns) is not None), 0)
+
+
+def _band_blocks(window: int, chunk: int, group: int, columns: int = 0) -> Dict[str, int]:
+    """The banded kernel's blocks: the query block of :func:`_kernel_blocks` (a
+    chunk's rows are a whole number of it), ONE key block of the chunk's keys, and
+    inside it the widest product of whole 128 lanes that divides them up to the
+    unbanded kernel's."""
+    blocks, keys = _kernel_blocks(group * chunk, columns), chunk + window
+    compute = next(n for n in range(min(_ATTN_BAND_COMPUTE, keys) // 128 * 128, 0, -128) if keys % n == 0)
+    return dict(blocks, block_kv=keys, block_kv_compute=compute, block_kv_dkv=keys, block_kv_dkv_compute=compute)
+
+
+def _band_segments(length: int, window: int, chunk: int) -> np.ndarray:
+    """(chunks, chunk + window) int32: 1 where a chunk's key stands at a position
+    of the sequence, 0 where it stands before position 0 (the first ``window /
+    chunk`` chunks' leading keys).  Every query's segment is 1, so the kernel
+    gives such a key no weight at all."""
+    first = np.arange(0, length, chunk)[:, None] - window  # the position of each chunk's first key
+    return (first + np.arange(chunk + window)[None, :] >= 0).astype(np.int32)
+
+
 def _splash_kernel(length: int, group: int, window: Optional[int], columns: int = 0):
     """The fused kernel of one key-value head and its ``group`` query heads over
     ``length`` positions.  The mask is an object of the library: ``CausalMask``,
     or, with ``window``, ``LocalMask`` reaching ``window - 1`` keys back and none
     ahead (a query's own position counts into the window).  The library turns it
     into a table of the (query block, key block) pairs that hold a visible key
-    and runs its grid over those alone, forward and backward, so a windowed
-    layer's cost follows its window."""
+    and runs its grid over those alone, forward and backward.
+
+    Where :func:`_kernel_chunk` gives a chunk, the kernel is that of ONE chunk
+    of the banded core: ``group x chunk`` rows (head g's query c at row ``g chunk
+    + c``) against the ``chunk + window`` keys that end with the chunk's last.
+    The mask is still the library's ``LocalMask``, computed inside the kernel from
+    the rows' positions: row r stands at ``window + r % chunk`` of the chunk's
+    keys, which is all the object is told (``q_sequence``)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
 
+    chunk = _kernel_chunk(length, window, group, columns)
+    if chunk:
+        mask = masks.LocalMask((group * chunk, chunk + window), window_size=(window - 1, 0), offset=0)
+        mask.q_sequence = (window + np.arange(group * chunk) % chunk).astype(np.int32)
+        return splash.make_splash_mqa_single_device(
+            masks.MultiHeadMask([mask]),
+            block_sizes=splash.BlockSizes(**_band_blocks(window, chunk, group, columns), use_fused_bwd_kernel=True))
     shape = (length, length)
     mask = masks.CausalMask(shape) if window is None else masks.LocalMask(shape, window_size=(window - 1, 0), offset=0)
     return splash.make_splash_mqa_single_device(
@@ -818,19 +878,65 @@ def _splash_kernel(length: int, group: int, window: Optional[int], columns: int 
 
 
 @functools.lru_cache(maxsize=16)
-def _kernel_visits(length: int, window: Optional[int], columns: int = 0) -> Dict[str, int]:
-    """The block pairs the fused kernel visits for one head and sequence, read
-    from the kernel's own table (:func:`_splash_kernel`), never from a formula
-    beside it: ``pairs`` and their area ``elements`` of the forward kernel,
-    ``pairs_bwd`` and ``elements_bwd`` of the backward one.  What a roofline's
-    count of the executed work reads (the ``train`` span carries it)."""
+def _kernel_visits(length: int, window: Optional[int], columns: int = 0, group: int = 1) -> Dict[str, int]:
+    """What the fused kernel visits for one head and sequence, read from the
+    kernel's own table (:func:`_splash_kernel`), never from a formula beside it:
+    the (query block, key block) ``pairs`` and their area ``elements`` of the
+    forward kernel, ``pairs_bwd`` and ``elements_bwd`` of the backward one; under
+    a window also ``chunk``, the banded core's, 0 where the core runs unbanded.
+    A banded kernel's table is one chunk's, of ``group`` heads together: a head's
+    share of all chunks is counted (its grid steps rounded up to whole).  What a
+    roofline's count of the executed work reads (the ``train`` span carries it)."""
+    chunk = _kernel_chunk(length, window, group, columns)
     with jax.ensure_compile_time_eval():
-        kernel = _splash_kernel(length, 1, window, columns)
-    blocks = _kernel_blocks(length, columns)
-    visited = lambda info: int(np.count_nonzero(np.asarray(info.block_mask)[0]))  # one table serves every head
+        kernel = _splash_kernel(length, group if chunk else 1, window, columns)
+    blocks = _band_blocks(window, chunk, group, columns) if chunk else _kernel_blocks(length, columns)
+    calls, heads = (length // chunk, group) if chunk else (1, 1)
+    visited = lambda info: int(np.count_nonzero(np.asarray(info.block_mask)[0])) * calls  # one table serves every head
     forward, backward = visited(kernel.fwd_mask_info), visited(kernel.dkv_mask_info)
-    return {"pairs": forward, "elements": forward * blocks["block_q"] * blocks["block_kv"],
-            "pairs_bwd": backward, "elements_bwd": backward * blocks["block_q_dkv"] * blocks["block_kv_dkv"]}
+    return {"pairs": -(-forward // heads), "elements": forward * blocks["block_q"] * blocks["block_kv"] // heads,
+            "pairs_bwd": -(-backward // heads),
+            "elements_bwd": backward * blocks["block_q_dkv"] * blocks["block_kv_dkv"] // heads,
+            **({} if window is None else {"chunk": chunk})}
+
+
+def _chunk_rows(a):
+    """(sequences x chunks, chunk, kv heads, group, size) as the banded kernel holds it: (sequences x chunks, kv
+    heads, 1, group x chunk, size), head g's position c of a chunk at row ``g chunk + c``.  No pass over memory
+    where ``a`` was written head-major a chunk (:func:`_head_major`)."""
+    return a.transpose(0, 2, 3, 1, 4).reshape(a.shape[0], a.shape[2], 1, -1, a.shape[-1])
+
+
+def _from_chunk_rows(a, group: int):
+    """:func:`_chunk_rows` undone."""
+    return a.reshape(a.shape[0], a.shape[1], group, a.shape[3] // group, a.shape[-1]).transpose(0, 3, 1, 2, 4)
+
+
+def _banded_core(kernel, q, k, v, window: int, chunks: int):
+    """The windowed core in chunks, ``chunks`` a sequence: ``q`` (sequences x
+    chunks, chunk, kv heads, group, head size), a chunk a row; ``k`` and ``v``
+    (sequences x chunks, chunk, kv heads, head size) likewise; ``kernel`` one
+    chunk's (:func:`_splash_kernel`).  A chunk's queries of all ``group`` heads
+    are one block of rows, its keys the ``chunk + window`` positions that end
+    with its last: its own and the ``window / chunk`` chunks before it of the
+    same sequence, zero rows where those would lie before position 0, which are
+    shut out by their segment (:func:`_band_segments`), never scored.  The
+    result as ``q``."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+
+    rows, chunk, nkv, group, _ = q.shape
+    s, reach = rows // chunks, window // chunk
+
+    def keys_of(a):  # (sequences x chunks, kv heads, chunk + window, head size): chunk i holds chunks i - reach .. i
+        held = jnp.pad(a.reshape(s, chunks, *a.shape[1:]), ((0, 0), (reach, 0)) + ((0, 0),) * (a.ndim - 1))
+        held = jnp.concatenate([held[:, j:j + chunks] for j in range(reach + 1)], axis=2)
+        return held.reshape(rows, chunk + window, nkv, -1).transpose(0, 2, 1, 3)
+
+    segments = splash.SegmentIds(q=jnp.ones((group * chunk,), jnp.int32),
+                                 kv=jnp.asarray(np.tile(_band_segments(chunks * chunk, window, chunk), (s, 1))))
+    one_chunk = jax.vmap(kernel, in_axes=(0, 0, 0, None))  # over the key-value heads
+    out = jax.vmap(one_chunk, in_axes=(0, 0, 0, splash.SegmentIds(q=None, kv=0)))(_chunk_rows(q), keys_of(k), keys_of(v), segments)
+    return _from_chunk_rows(out, group)
 
 
 def _kernel_core(q, k, v, scale: float, window: Optional[int] = None):
@@ -850,8 +956,20 @@ def _kernel_core(q, k, v, scale: float, window: Optional[int] = None):
     concatenation as one pass over the parts, the scale and the cast in it,
     where a padding of a scaled array is a pass of its own (PERF.md, PR 33).  The
     kernel takes one key-value head with its query heads (no copy of K or V);
-    ``vmap`` makes the key-value heads and the sequences its outer grid.  With
-    ``window`` a query sees that many keys, its own the last (:func:`_splash_kernel`)."""
+    ``vmap`` makes the key-value heads and the sequences its outer grid.
+
+    With ``window`` a query sees that many keys, its own the last
+    (:func:`_splash_kernel`), and where the shape allows (:func:`_kernel_chunk`)
+    the core runs banded (:func:`_banded_core`): the unbanded kernel costs every
+    (query block, key block) pair it visits its whole area, most of which a
+    window hides, and each of a key-value head's query heads walks the same keys
+    on a grid of its own.  The banded core's operands and result are a chunk a
+    row, (sequences x chunks, chunk, ...): the same shapes as whole sequences to
+    jax, and the same memory where the caller wrote each chunk head-major as a
+    sequence would be (:func:`_attention` does: XLA cancels the reshapes between
+    there and here); for operands written head-major a whole sequence, cutting
+    them is a transposing pass over each, over the output and over every
+    cotangent."""
     length, group = q.shape[1], q.shape[3]
     columns = _core_columns(q.shape[-1], v.shape[-1])
     pad = -q.shape[-1] % 128 if q.shape[-1] > 128 else 0
@@ -859,6 +977,11 @@ def _kernel_core(q, k, v, scale: float, window: Optional[int] = None):
         q, k = (jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)]) for a in (q, k))
     q = (q.astype(jnp.float32) * scale).astype(k.dtype)
     kernel = _splash_kernel(length, group, window, columns)
+    chunk = _kernel_chunk(length, window, group, columns)
+    if chunk:
+        shape = q.shape[:-1] + v.shape[-1:]
+        q, k, v = (a.reshape(-1, chunk, *a.shape[2:]) for a in (q, k, v))
+        return _banded_core(kernel, q, k, v, window, length // chunk).reshape(shape)
     out = jax.vmap(jax.vmap(kernel))(q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     return out.transpose(0, 3, 1, 2, 4)
 
@@ -919,38 +1042,57 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
     weights' shape, so no reshape follows); the q/k norm, rope
     (:func:`_rope_whole_heads`), the core's scale and the one cast are float32
     arithmetic inside one fusion an operand; the output product contracts the
-    kernel's head-major output as it is."""
-    hidden = x.shape[-1]
+    kernel's head-major output as it is.  Where the windowed core runs banded
+    (:func:`_kernel_chunk`) "a sequence" above reads "a chunk": the products
+    take the tokens as (sequences x chunks, chunk, hidden), so q, k, v, the
+    gates and the core's output are head-major a chunk, which is the order the
+    banded kernel reads and writes (:func:`_banded_core`), and rope turns each
+    chunk at its own positions."""
+    hidden, window = x.shape[-1], cfg.window_of(kind)
     nkv, hd = cfg.num_key_value_heads, cfg.head_dim
     nh = p["o"].shape[0] // hd  # a matter of the layer: its parameters say
     part = jax.named_scope if cfg.typed_attention else (lambda name: contextlib.nullcontext())
     (theta, scaling), rotary = cfg.rope_of(kind), cfg.rotary_of(kind)
+    # where the core runs banded, every operand of it, the gates and its output are made, read and written in the core's
+    # chunks, each a sequence of its own to the products: (sequences x chunks, chunk, hidden) is the same memory
+    chunk = _kernel_chunk(x.shape[1], window, nh // nkv, _core_columns(hd, hd)) if _use_attention_kernel(x.shape[1]) else 0
+    chunks = x.shape[1] // chunk if chunk else 0
+    rows = x.reshape(-1, chunk, hidden) if chunk else x
     with part("proj"):
         if cfg.attn_output_gate:  # a head's columns are [query | gate]: two products, as the latent operator's blocks
             w_q = p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, 2 * hd)
-            q, gate = _head_major(x, w_q[..., :hd]), _head_major(x, w_q[..., hd:])
+            q, gate = _head_major(rows, w_q[..., :hd]), _head_major(rows, w_q[..., hd:])
         else:
-            q = _head_major(x, p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, hd))
-        k = _head_major(x, p["k"].astype(dtype).reshape(hidden, nkv, hd))
-        v = _head_major(x, p["v"].astype(dtype).reshape(hidden, nkv, hd))
+            q = _head_major(rows, p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, hd))
+        k = _head_major(rows, p["k"].astype(dtype).reshape(hidden, nkv, hd))
+        v = _head_major(rows, p["v"].astype(dtype).reshape(hidden, nkv, hd))
     with part("rope"):
         normed = lambda a, weight: _rms_norm(a, p[weight], cfg.norm_eps) if cfg.qk_norm else a
         if cfg.positional_encoding == "none":  # the order of the tokens reaches such a model through its other layers
             q, k = normed(q, "q_norm"), normed(k, "k_norm").astype(dtype)
         else:
-            q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling, rotary)
-            k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling, rotary).astype(dtype)
+            q = _rope_whole_heads(normed(q, "q_norm"), theta, scaling, rotary, chunks)
+            k = _rope_whole_heads(normed(k, "k_norm"), theta, scaling, rotary, chunks).astype(dtype)
     with part("core"):
-        out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg, cfg.window_of(kind))
+        if chunk:  # whole sequences to the core, which cuts them into these chunks again: shapes, not passes over memory
+            q, k, v = (a.reshape(*x.shape[:2], *a.shape[2:]) for a in (q, k, v))
+        out = _causal_core(q, k, v, 1.0 / math.sqrt(hd), cfg, window)
+        if chunk:
+            out = out.reshape(-1, chunk, *out.shape[2:])
     if cfg.attn_output_gate or cfg.attn_head_gate:
         with part("gate"):
             if cfg.attn_head_gate:  # one scalar a head and token, a float32 product as the router's; head-major as the core's output
-                gate = jnp.einsum("slh,hng->sngl", x.astype(jnp.float32), p["gate"].reshape(hidden, nkv, nh // nkv),
+                gate = jnp.einsum("slh,hng->sngl", rows.astype(jnp.float32), p["gate"].reshape(hidden, nkv, nh // nkv),
                                   precision=jax.lax.Precision.HIGHEST)
                 gate = jnp.moveaxis(gate, -1, 1)[..., None]
+            if chunk:  # in the rows the banded kernel wrote: XLA then reads its output once for the gate and for the kernel's own backward
+                out, gate = _chunk_rows(out), _chunk_rows(gate)
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
+            if chunk:
+                out = _from_chunk_rows(out, nh // nkv)
     with part("proj"):
-        return jnp.einsum("slngd,ngdh->slh", out, p["o"].astype(dtype).reshape(nkv, nh // nkv, hd, hidden))
+        out = jnp.einsum("slngd,ngdh->slh", out, p["o"].astype(dtype).reshape(nkv, nh // nkv, hd, hidden))
+        return out.reshape(x.shape) if chunk else out
 
 
 def latent_softmax_scale(cfg: Lfm2MoeConfig) -> float:
@@ -1760,8 +1902,11 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
         heads.append((mask, tuple(cfg.heads_of(i) for i in layers)))
         rotary.append((mask, tuple(0 if cfg.positional_encoding == "none" else cfg.qk_rope_head_dim if latent
                                    else cfg.rotary_of(cfg.layer_types[i]) for i in layers)))
-        if engaged:
-            visits.append((mask, tuple(sorted(_kernel_visits(cfg.seq_len, window, columns).items()))))
+        if engaged:  # a head's visits are its layer's, by its group; a mask's are the mean over its layers' heads
+            per_layer = [_kernel_visits(cfg.seq_len, window, columns, 1 if latent else cfg.heads_of(i) // cfg.num_key_value_heads)
+                         for i in layers]
+            means = {name: sum(v[name] * n for v, n in zip(per_layer, heads[-1][1])) / sum(heads[-1][1]) for name in per_layer[0]}
+            visits.append((mask, tuple(sorted((name, int(n) if n.is_integer() else n) for name, n in means.items()))))
     linear = cfg.layer_types.count("linear_attention")
     by_kernels, inverse_products = 0, 0.0
     if linear:

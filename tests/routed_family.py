@@ -188,6 +188,28 @@ def masks_handed_to_the_kernel(length, group, window):
         splash.make_splash_mqa_single_device = real
 
 
+def rows_the_kernel_lets_through(length, group, window, head, rows):
+    """What the windowed kernel's mask lets query head ``head`` of a key-value head see, as a 0/1 array
+    (``rows``, ``length``) over absolute positions: the unbanded kernel's mask object as it is; the banded core's
+    (one chunk's rectangle of ``group x chunk`` rows by ``chunk + window`` keys) laid back through the chunk layout
+    of ``_banded_core`` -- row ``head x chunk + c`` of chunk ``i`` is query ``i x chunk + c``, its key ``j`` stands
+    at ``i x chunk - window + j`` -- with the keys that ``_band_segments`` shuts out (and every key no chunk holds) 0."""
+    chunk = M._kernel_chunk(length, window, group)
+    masks = masks_handed_to_the_kernel(length, group, window)
+    if not chunk:
+        assert len(masks) == group
+        return np.asarray(masks[head][rows, :]).astype(np.int32)
+    (mask,), segments = masks, M._band_segments(length, window, chunk)
+    assert mask.shape == (group * chunk, chunk + window) and segments.shape == (length // chunk, chunk + window)
+    assert rows.start % chunk == 0 and rows.stop % chunk == 0
+    seen = np.zeros((rows.stop - rows.start, length + window), np.int32)  # key j of chunk i at column i x chunk + j
+    rectangle = np.asarray(mask[head * chunk:(head + 1) * chunk, :]).astype(np.int32)
+    for i in range(rows.start // chunk, rows.stop // chunk):
+        seen[i * chunk - rows.start:(i + 1) * chunk - rows.start, i * chunk:(i + 1) * chunk + window] = rectangle * segments[i]
+    assert not seen[:, :window].any(), "a key before position 0 got through"
+    return seen[:, window:]
+
+
 # -- small functions --------------------------------------------------------------------------------------------------
 
 

@@ -131,26 +131,30 @@ _masks_handed_to_the_kernel = F.masks_handed_to_the_kernel
 def test_the_windows_mask_object_at_512_is_the_references_array_entry_by_entry(length):
     """What the fused kernel is handed at 8 query heads a key-value head under the window and at 6 under the causal
     mask (the library's ``LocalMask`` / ``CausalMask``, evaluated on the host: no TPU) against ``reference.visible``'s
-    0/1 array: every entry, in slices of rows; a window one key short or one key long is another array."""
-    windowed, causal = _masks_handed_to_the_kernel(length, 8, 512), _masks_handed_to_the_kernel(length, 6, None)
+    0/1 array: every entry, in slices of rows; a window one key short or one key long is another array.  At 512
+    positions the window is as long as the sequence and the core runs unbanded; past it the kernel is handed one
+    chunk's rectangle with the segments that shut out the keys before position 0, and laid back through the chunk
+    layout (``F.rows_the_kernel_lets_through``) that is the reference's array too, in the first chunk (the window
+    reaches 512 keys before the sequence) as in the last; 511 and 513 are no whole lanes and run unbanded."""
+    causal = _masks_handed_to_the_kernel(length, 6, None)
     short, long = _masks_handed_to_the_kernel(length, 8, 511), _masks_handed_to_the_kernel(length, 8, 513)
-    assert (len(windowed), len(causal)) == (8, 6)
+    chunk = M._kernel_chunk(length, 512, 8)
+    assert bool(chunk) == (length > 512) and len(causal) == 6 and len(short) == len(long) == 8
     j = np.arange(length)[None, :]
     differ = {"short": 0, "long": 0}
     seen = lambda mask, rows: np.asarray(mask[rows, :]).astype(bool)
-    heads = [windowed[0]] + ([] if windowed[-1] is windowed[0] else [windowed[-1]])  # one mask object serves every head
     for first in range(0, length, 1024):
         rows = slice(first, min(first + 1024, length))
         i = np.arange(length)[rows, None]
         want = np.asarray(R.visible(i, j, "sliding_attention", {"sliding_window": 512})) == 1
-        for head in heads:
-            np.testing.assert_array_equal(seen(head, rows), want)
+        for head in (0, 7):
+            np.testing.assert_array_equal(F.rows_the_kernel_lets_through(length, 8, 512, head, rows) == 1, want)
         np.testing.assert_array_equal(seen(causal[0], rows), np.asarray(R.visible(i, j, "full_attention", {})) == 1)
         differ["short"] += np.count_nonzero(seen(short[0], rows) != want)
         differ["long"] += np.count_nonzero(seen(long[0], rows) != want)
-    assert all(windowed[0] is head or windowed[0] == head for head in windowed)
     assert differ["short"] == max(length - 511, 0) and differ["long"] == max(length - 512, 0)  # one key a row that has it
-    assert int(np.asarray(windowed[0][length - 1:length, :]).sum()) == min(512, length)
+    last = F.rows_the_kernel_lets_through(length, 8, 512, 0, slice(length - (chunk or 1), length))[-1]
+    assert int(last.sum()) == min(512, length)
 
 
 def _core_case(length, group, seed=0, sequences=2, kv_heads=2, head=16):
@@ -189,21 +193,34 @@ def test_the_blockwise_core_at_both_groupings_is_the_written_out_softmax(kind, w
 @pytest.mark.parametrize("group", [6, 8])
 def test_the_kernels_table_of_visits_is_one_for_every_head_of_either_group_and_flops_py_counts_the_same(group):
     """The kernel built at a group of 6 and of 8 query heads under both masks (``jax.ensure_compile_time_eval``: on
-    the host, no TPU): at 8,192 positions and 1,024 x 1,024 blocks 15 of 64 pairs under a window of 512 -- the same
-    15 as under Mellum2's 1,024 -- and 36 under the causal mask, whatever the group; what the mask lets through of
-    what is visited is 26% under the window."""
+    the host, no TPU).  At 8,192 positions and 1,024 x 1,024 blocks 36 of 64 pairs under the causal mask, whatever
+    the group, and ``flops.block_visits`` counts the same.  Under the window of 512 the core runs banded: the kernel
+    is ONE chunk's, its table holds every (query block, key block) pair of the chunk's rectangle for all the group's
+    heads together, and a head visits ``length x (chunk + window)`` elements -- below the 15 pairs of 1,024 x 1,024
+    that the unbanded kernel cost at 512 and at 1,024 alike (``flops.block_visits``: the XLA fall-back's and the
+    benchmark's arithmetic), now smaller at 512 than at 1,024, and the mask lets through well over half of it."""
     m = {"sliding_window": 512}
-    for window, kind, pairs in ((512, "sliding_attention", 15), (None, "full_attention", 36)):
-        with jax.ensure_compile_time_eval():
-            kernel = M._splash_kernel(8192, group, window)
-        table = np.asarray(kernel.fwd_mask_info.block_mask)
-        assert table.shape[0] in (1, group) and all(np.count_nonzero(head) == pairs for head in table)
-        assert np.count_nonzero(np.asarray(kernel.dkv_mask_info.block_mask)[0]) == pairs
-        visits = M._kernel_visits(8192, window)
-        assert visits["pairs"] == pairs and visits == flops.block_visits(m, kind, 8192)
-    assert M._kernel_visits(8192, 512) == M._kernel_visits(8192, 1024)
+    with jax.ensure_compile_time_eval():
+        kernel = M._splash_kernel(8192, group, None)
+    table = np.asarray(kernel.fwd_mask_info.block_mask)
+    assert table.shape[0] in (1, group) and all(np.count_nonzero(head) == 36 for head in table)
+    assert np.count_nonzero(np.asarray(kernel.dkv_mask_info.block_mask)[0]) == 36
+    assert M._kernel_visits(8192, None, 256, group) == flops.block_visits(m, "full_attention", 8192)
+    chunk = M._kernel_chunk(8192, 512, group, 256)
+    assert chunk and 512 % chunk == 0 and (group * chunk) % 1024 == 0  # whole query blocks of every chunk's rows
+    with jax.ensure_compile_time_eval():
+        kernel = M._splash_kernel(8192, group, 512, 256)
+    for info in (kernel.fwd_mask_info, kernel.dkv_mask_info):
+        table = np.asarray(info.block_mask)
+        assert table.shape[0] == 1 and table[0].size == np.count_nonzero(table[0]) == group * chunk // 1024  # one key block a chunk
+    visits, unbanded = M._kernel_visits(8192, 512, 256, group), flops.block_visits(m, "sliding_attention", 8192)
+    assert visits["chunk"] == chunk and visits["elements"] == 8192 * (chunk + 512) == visits["elements_bwd"]
+    assert unbanded["pairs"] == 15 and visits["elements"] < unbanded["elements"] == 15 * 2**20
+    assert visits["elements"] < M._kernel_visits(8192, 1024, 256, group)["elements"] < unbanded["elements"]
     assert flops.visible_elements(m, "sliding_attention", 8192) == 4_063_488
-    assert flops.visible_elements(m, "sliding_attention", 8192) / M._kernel_visits(8192, 512)["elements"] == pytest.approx(0.258, abs=1e-3)
+    assert flops.visible_elements(m, "sliding_attention", 8192) / unbanded["elements"] == pytest.approx(0.258, abs=1e-3)
+    if group == 8:  # the published windowed layers' group
+        assert flops.visible_elements(m, "sliding_attention", 8192) / visits["elements"] > 0.6
     assert flops.visible_elements(m, "full_attention", 8192) == 8192 * 8193 // 2
     i = np.arange(1024)
     for kind in ("sliding_attention", "full_attention"):
@@ -217,14 +234,17 @@ def test_the_kernels_table_of_visits_is_one_for_every_head_of_either_group_and_f
 KERNEL_MODEL = dict(hidden_size=64, head_dim=128, num_key_value_heads=1, rope_parameters=ROPE, sliding_window=96)
 
 
-@pytest.mark.parametrize("kind,heads", [("sliding_attention", 3), ("full_attention", 2)])
-def test_attention_and_every_gradient_by_the_fused_core_at_its_own_group(kind, heads, kernel_on_the_cpu, small_kernel_blocks):
+@pytest.mark.parametrize("kind,heads,window", [("sliding_attention", 3, 96), ("sliding_attention", 3, 128), ("full_attention", 2, 96)])
+def test_attention_and_every_gradient_by_the_fused_core_at_its_own_group(kind, heads, window, kernel_on_the_cpu, small_kernel_blocks):
     """``_attention`` whole with the kernel interpreted (two blocks a side), float32, against
     ``reference.attention`` a sequence: the layer type's mask, rope on its share of a head, its own number of query
     heads to the key-value head, the gate a head; the output within two bfloat16 steps of its size, the gradients of
-    the input and of every weight within 1% in norm."""
+    the input and of every weight within 1% in norm.  At a window of 128 the windowed layer's core runs banded (two
+    chunks a sequence, three heads' rows a chunk), the gate a head made and applied in the core's chunks."""
+    if kind == "sliding_attention":
+        assert M._kernel_chunk(256, window, heads) == (128 if window == 128 else 0)
     cfg = M.Lfm2MoeConfig(hidden_size=64, head_dim=128, num_attention_heads=2, num_key_value_heads=1, qk_norm=False,
-                          attn_head_gate=True, sliding_window=96, norm_eps=1e-6, seq_len=256, attn_block=64,
+                          attn_head_gate=True, sliding_window=window, norm_eps=1e-6, seq_len=256, attn_block=64,
                           rope_parameters=F.rope_table(ROPE),
                           layer_types=("sliding_attention", "full_attention"), layer_ids=(0, 1),
                           num_attention_heads_per_layer=(3, 2), num_dense_layers=0)
@@ -237,7 +257,8 @@ def test_attention_and_every_gradient_by_the_fused_core_at_its_own_group(kind, h
     got = F.value_and_gradients(lambda p, x: M._attention(p, x, cfg, jnp.float32, kind), p, x)
     with HIGHEST:
         want = F.value_and_gradients(
-            lambda p, x: jnp.stack([R.attention(p, xs, KERNEL_MODEL, kind, heads, lambda a: a) for xs in x]), p, x)
+            lambda p, x: jnp.stack([R.attention(p, xs, {**KERNEL_MODEL, "sliding_window": window}, kind, heads, lambda a: a)
+                                    for xs in x]), p, x)
     F.assert_within_bfloat16(got, want, ("q", "k", "v", "o", "gate"), floor=0.2)
 
 
@@ -292,9 +313,11 @@ def test_the_train_span_states_each_masks_heads_and_rotated_columns_on_the_cpu_t
 
 
 def test_one_program_holds_the_kernel_at_two_groups_under_two_masks_and_the_spans_and_the_counter_say_so(kernel_on_the_cpu, small_kernel_blocks):
-    """The cut's three layers at the published head size over 256 positions with a window of 100, the kernel
-    interpreted: 1 windowed layer at 3 query heads to the key-value head and 2 full ones at 2, 2 steps."""
-    m = {**CUT, "head_dim": 128, "num_key_value_heads": 1, "num_attention_heads_per_layer": [2, 3, 2], "sliding_window": 100,
+    """The cut's three layers at the published head size over 256 positions with a window of 128, the kernel
+    interpreted: 1 windowed layer at 3 query heads to the key-value head and 2 full ones at 2, 2 steps.  The windowed
+    layer's core runs banded, two chunks of 128 a sequence, and the span says so: the chunk, and a head's visits
+    as ``length x (chunk + window)`` elements, forward and backward."""
+    m = {**CUT, "head_dim": 128, "num_key_value_heads": 1, "num_attention_heads_per_layer": [2, 3, 2], "sliding_window": 128,
          "train_steps": 2}
     tok = np.random.default_rng(1).integers(0, 64, size=(6, 257)).astype(np.int32)
     x, y = tok[:, :-1], tok[:, 1:]
@@ -304,12 +327,15 @@ def test_one_program_holds_the_kernel_at_two_groups_under_two_masks_and_the_span
     assert programs.heads_by_mask == (("causal", (2, 2)), ("window", (3,)))
     assert programs.rotary_by_mask == (("causal", (64, 64)), ("window", (128,)))
     visits = {mask: dict(v) for mask, v in programs.kernel_visits}
-    assert visits["causal"]["pairs"] == 3 and visits["window"]["pairs"] == 3  # of 4 at 2 x 2 blocks of 128: a window of 100 reaches one block back
+    assert visits["causal"]["pairs"] == 3 and "chunk" not in visits["causal"]  # of 4 at 2 x 2 blocks of 128
+    assert visits["window"] == {"chunk": 128, "pairs": 2, "elements": 256 * 256, "pairs_bwd": 2, "elements_bwd": 256 * 256}
     attrs, _ = _traced_individual(x, y, kw)
     assert attrs["attention_kernel_layer_steps"] == 6 and attrs["attention_kernel_layer_steps_window"] == 2 \
         and attrs["attention_kernel_layer_steps_causal"] == 4
     assert attrs["attention_heads_causal"] == [2, 2] and attrs["attention_heads_window"] == [3]
-    assert attrs["attention_kernel_elements_window"] == 3 * 128 * 128 == attrs["attention_kernel_elements_bwd_window"]
+    assert attrs["attention_kernel_elements_window"] == 256 * (128 + 128) == attrs["attention_kernel_elements_bwd_window"]
+    assert attrs["attention_kernel_chunk_window"] == 128 and "attention_kernel_chunk_causal" not in attrs
+    assert attrs["attention_kernel_elements_causal"] == 3 * 128 * 128
     counter = get_registry().counter
     assert counter("attention_kernel_layer_steps_total", mask="window").value == 2
     assert counter("attention_kernel_layer_steps_total", mask="causal").value == 4

@@ -91,11 +91,16 @@ def test_the_blockwise_cores_cost_follows_the_window():
     assert widths == {16, 32, 48}, widths  # 16 and 32 at the start, then the two blocks back and the block itself
 
 
-@pytest.mark.parametrize("length,window", [(512, 128), (256, 384)])
-def test_the_fused_core_under_a_window_is_the_blockwise_core_to_bfloat16(length, window, kernel_on_the_cpu, small_kernel_blocks):
-    """Four windows long, and shorter than the window; forward and gradients, at head size 128 with 2 query
-    heads a key-value head."""
-    q, k, v = (a.astype(jnp.bfloat16) for a in _core_case(length, sequences=1, kv_heads=1, group=2, head=128))
+@pytest.mark.parametrize("length,window,group,sequences,chunk", [(512, 128, 2, 1, 128), (256, 384, 2, 1, 0), (768, 384, 2, 2, 128),
+                                                                 (640, 384, 8, 1, 128), (512, 256, 8, 1, 256), (512, 200, 2, 1, 0)])
+def test_the_fused_core_under_a_window_is_the_blockwise_core_to_bfloat16(length, window, group, sequences, chunk, kernel_on_the_cpu,
+                                                                          small_kernel_blocks):
+    """Forward and gradients at head size 128.  Banded (``chunk``): four windows long; at a chunk that divides the
+    window three times, over two sequences of two key-value heads (a chunk is a row of the kernel's outer grid: the
+    order of sequences, chunks and heads is the layout's to get right) and at the published group of 8; at a window
+    of one chunk.  Unbanded (0), the parent's path: shorter than the window, and a window of no whole 128 lanes."""
+    assert M._kernel_chunk(length, window, group) == chunk
+    q, k, v = (a.astype(jnp.bfloat16) for a in _core_case(length, sequences=sequences, kv_heads=sequences, group=group, head=128))
     scale = 1.0 / math.sqrt(128)
 
     def value(core):
@@ -108,9 +113,13 @@ def test_the_fused_core_under_a_window_is_the_blockwise_core_to_bfloat16(length,
     assert _rel(got[0], want[0]) < 2e-2
     for a, b in zip(got[1], want[1]):
         assert _rel(a, b) < 3e-2
-    if window < length:  # and it is not the causal core
+    if 2 * window <= length:  # and it is not the causal core
         causal = jax.jit(value(lambda q, k, v: M._kernel_core(q, k, v, scale)))(q, k, v)
         assert _rel(causal, want[0]) > 5e-2
+    if chunk:  # the first chunks' keys before position 0 carry no weight: the first query's output is its own value
+        out = M._kernel_core(q, k, v, scale, window)
+        np.testing.assert_allclose(np.asarray(out[:, 0], np.float32),
+                                   np.broadcast_to(np.asarray(v[:, 0, :, None, :], np.float32), out[:, 0].shape), atol=1e-2)
 
 
 # -- the operator whole: what reaches the fused core, and what comes back (PR 36) ----------------------------
@@ -131,15 +140,17 @@ def _attention_case(kind: str, length: int = 256, sequences: int = 2, window: in
 
 
 @pytest.mark.parametrize("against", ["blockwise-bfloat16", "reference-float32"])
-@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
-def test_attention_and_every_gradient_by_the_fused_core(kind, against, kernel_on_the_cpu, small_kernel_blocks):
+@pytest.mark.parametrize("kind,window", [("sliding_attention", 96), ("sliding_attention", 128), ("full_attention", 96)])
+def test_attention_and_every_gradient_by_the_fused_core(kind, window, against, kernel_on_the_cpu, small_kernel_blocks):
     """``_attention`` whole (two sequences; the head-major products, rope as a product with the signed permutation
     under the layer type's frequencies and amplitude, scale and cast, the kernel under the type's mask, the output
     product over the kernel's head-major output) with the fused core interpreted, two blocks a side: in bfloat16
     against the same call by the blockwise core, in float32 against ``reference.attention`` a sequence; the output
     within two bfloat16 steps of its size, the gradients of the input and of every weight within 1% in norm (the
-    bounds of ``test_deepseek_v2.py``'s latent operator)."""
-    cfg, p, x = _attention_case(kind)
+    bounds of ``test_deepseek_v2.py``'s latent operator).  At a window of 128 the core runs banded, two chunks a
+    sequence: the q product, rope at each chunk's own positions and the output product all work on chunks as rows."""
+    cfg, p, x = _attention_case(kind, window=window)
+    assert M._kernel_chunk(256, cfg.window_of(kind), 2) == (128 if (kind, window) == ("sliding_attention", 128) else 0)
     dtype = jnp.bfloat16 if against == "blockwise-bfloat16" else jnp.float32
     x = x.astype(dtype)
     operator = lambda p, x: M._attention(p, x, cfg, dtype, kind)
@@ -147,7 +158,7 @@ def test_attention_and_every_gradient_by_the_fused_core(kind, against, kernel_on
     if against == "blockwise-bfloat16":
         want = F.by_the_blockwise_core(operator, p, x)
     else:
-        m = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=128, rope_parameters=ROPE, sliding_window=96)
+        m = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=128, rope_parameters=ROPE, sliding_window=window)
         with HIGHEST:
             want = F.value_and_gradients(
                 lambda p, x: jnp.stack([R.attention(p, xs, m, kind, lambda a: a) for xs in x]), p, x)
@@ -202,39 +213,58 @@ def test_what_reaches_the_fused_core_is_written_once_head_major_in_the_compute_d
     assert all(a.dtype == jnp.bfloat16 for a in operands)
 
 
-@pytest.mark.parametrize("length,window", [(1024, 256), (1024, 257), (512, 1024), (8192, 1024)])
-def test_the_kernels_mask_object_is_the_references_array_entry_by_entry(length, window):
+@pytest.mark.parametrize("length,window,group", [(1024, 256, 2), (1024, 257, 2), (512, 1024, 2), (8192, 1024, 8), (2048, 1024, 8),
+                                                 (2048, 384, 2), (512, 256, 8), (8192, 1024, 3)])
+def test_the_kernels_mask_object_is_the_references_array_entry_by_entry(length, window, group):
     """What the fused kernel is handed (the library's ``LocalMask`` / ``CausalMask``, evaluated on the host: no
-    TPU) against ``reference.visible``'s 0/1 array: every entry, in slices of rows at the published length."""
-    windowed, causal = F.masks_handed_to_the_kernel(length, 2, window), F.masks_handed_to_the_kernel(length, 2, None)
-    assert len(windowed) == len(causal) == 2
+    TPU) against ``reference.visible``'s 0/1 array: every entry, in slices of rows at the published length.  Where
+    the core runs banded the kernel is handed ONE chunk's rectangle and the segments that shut out the keys before
+    position 0: laid back through the chunk layout (``F.rows_the_kernel_lets_through``) it is the same array, in the
+    first ``window / chunk`` chunks, whose windows reach before the sequence, as in every other -- at the published
+    shape, at a window of one chunk and of three, at a length of two chunks, at a group of 3 (whose rows are whole
+    query blocks at a chunk of 128 only), and at shapes the rule refuses (a window of 257, a window past the length)."""
+    causal = F.masks_handed_to_the_kernel(length, group, None)
+    assert len(causal) == group
+    chunk = M._kernel_chunk(length, window, group)
+    assert bool(chunk) == (window % 128 == 0 and window < length)
     j = np.arange(length)[None, :]
     for first in range(0, length, 1024):
         rows = slice(first, min(first + 1024, length))
         i = np.arange(length)[rows, None]
-        for head in windowed:
-            np.testing.assert_array_equal(np.asarray(head[rows, :]).astype(np.int32),
-                                          np.asarray(R.visible(i, j, "sliding_attention", {"sliding_window": window})))
+        want = np.asarray(R.visible(i, j, "sliding_attention", {"sliding_window": window}))
+        for head in sorted({0, group - 1}):
+            np.testing.assert_array_equal(F.rows_the_kernel_lets_through(length, group, window, head, rows), want)
         np.testing.assert_array_equal(np.asarray(causal[0][rows, :]).astype(np.int32),
                                       np.asarray(R.visible(i, j, "full_attention", {})))
-    row = length - 1  # the last query sees ``window`` keys, its own the last of them
-    assert int(np.asarray(windowed[0][row:row + 1, :]).sum()) == min(window, length)
+    last = F.rows_the_kernel_lets_through(length, group, window, 0, slice(length - (chunk or 1), length))[-1]
+    assert int(last.sum()) == min(window, length)  # the last query sees ``window`` keys, its own the last of them
 
 
 def test_a_windowed_layers_kernel_visits_fewer_block_pairs_and_flops_py_counts_the_same():
-    """The kernel's own table at the published length and blocks: 15 of 64 pairs under the window, 36 under the
-    causal mask; ``flops.block_visits`` is the same count by arithmetic."""
+    """The kernel's own table at the published length and blocks: 36 pairs of 64 under the causal mask, and
+    ``flops.block_visits`` is the same count by arithmetic.  Under the window the core runs banded at the published
+    group of 8: every chunk's rows meet the chunk's own ``chunk + window`` keys and no others, so a head visits
+    ``length x (chunk + window)`` score elements -- read off the banded kernel's table, never from this formula --
+    which is below what the unbanded kernel's 15 pairs of 1,024 x 1,024 cost (``flops.block_visits``: still the
+    count of a core that runs unbanded, and of the benchmark's arithmetic where no span says otherwise)."""
     m = {"sliding_window": 1024}
-    window, causal = M._kernel_visits(8192, 1024), M._kernel_visits(8192, None)
-    assert (window["pairs"], causal["pairs"]) == (15, 36) and window["elements"] == 15 * 1024 * 1024
-    assert window == flops.block_visits(m, "sliding_attention", 8192) and causal == flops.block_visits(m, "full_attention", 8192)
-    for kind in ("sliding_attention", "full_attention"):  # one set of blocks serves both masks
+    window, causal = M._kernel_visits(8192, 1024, 256, 8), M._kernel_visits(8192, None)
+    unbanded = flops.block_visits(m, "sliding_attention", 8192)
+    chunk = window["chunk"]
+    assert causal["pairs"] == 36 and causal == flops.block_visits(m, "full_attention", 8192) and "chunk" not in causal
+    assert chunk == M._kernel_chunk(8192, 1024, 8, 256) and chunk in M._ATTN_KERNEL_CHUNKS
+    assert window["elements"] == 8192 * (chunk + 1024) == window["elements_bwd"] and window["pairs"] == window["pairs_bwd"]
+    assert (unbanded["pairs"], unbanded["elements"]) == (15, 15 * 1024 * 1024) and window["elements"] < unbanded["elements"]
+    assert window["pairs"] == (8192 // chunk) * (8 * chunk // min(1024, 8 * chunk)) // 8  # a head's share of the grid's steps
+    for kind in ("sliding_attention", "full_attention"):  # one set of blocks serves both masks where the core is unbanded
         assert flops.KERNEL_BLOCKS[kind] == (M._ATTN_KERNEL_BLOCKS["block_q"], M._ATTN_KERNEL_BLOCKS["block_kv"])
-    for length, reach in ((4096, 1024), (2048, 4096), (1024, 300)):
-        assert M._kernel_visits(length, reach) == flops.block_visits({"sliding_window": reach}, "sliding_attention", length)
+    for length, reach in ((4096, 1000), (2048, 4096), (1024, 300)):  # windows the rule refuses: the unbanded kernel's table
+        visits = M._kernel_visits(length, reach, 256, 8)
+        assert visits.pop("chunk") == 0 and visits == flops.block_visits({"sliding_window": reach}, "sliding_attention", length)
     mm = {"head_dim": 128, "num_attention_heads": 32, "num_key_value_heads": 4}
-    assert flops.core_flops(mm, window, 2, 2, 1) == 2 * 32 * 15 * 2**20 * (2 * 4 * 128 + 10 * 128)
-    assert flops.core_flops(mm, window, 1, 1, 0) / flops.core_flops(mm, causal, 1, 1, 0) == 15 / 36
+    assert flops.core_flops(mm, unbanded, 2, 2, 1) == 2 * 32 * 15 * 2**20 * (2 * 4 * 128 + 10 * 128)
+    assert flops.core_flops(mm, unbanded, 1, 1, 0) / flops.core_flops(mm, causal, 1, 1, 0) == 15 / 36
+    assert flops.core_flops(mm, window, 1, 1, 0) / flops.core_flops(mm, unbanded, 1, 1, 0) == (chunk + 1024) / (15 * 128)
 
 
 # -- rope by layer type -----------------------------------------------------------------------------------
@@ -380,7 +410,8 @@ def test_spans_and_the_labelled_counter_split_the_kernels_layer_steps_by_mask(ke
     """One period at 512 positions with a window of 100, the kernel interpreted (the split is by layer type, a
     period has both; PR 45 cut the second period, half the test's 73 s): 3 windowed layers and 1 full one x 3
     steps an individual on the ``train`` span and on ``attention_kernel_layer_steps_total{mask}``, and the block
-    pairs each mask's kernel visits as static attributes."""
+    pairs each mask's kernel visits as static attributes.  A window of 100 is no whole 128 lanes: the rule refuses
+    it, the core runs unbanded as the parent's did (7 pairs of 128 x 128), and the span says so (a chunk of 0)."""
     m = {**MODEL, "head_dim": 128, "num_attention_heads": 2, "num_key_value_heads": 1, "sliding_window": 100}
     tok = np.random.default_rng(1).integers(0, 64, size=(6, 513)).astype(np.int32)
     x, y = tok[:, :-1], tok[:, 1:]
@@ -399,6 +430,7 @@ def test_spans_and_the_labelled_counter_split_the_kernels_layer_steps_by_mask(ke
         and attrs["attention_kernel_layer_steps_causal"] == 3
     assert attrs["attention_kernel_pairs_window"] == 7 and attrs["attention_kernel_pairs_causal"] == 10
     assert attrs["attention_kernel_elements_window"] == 7 * 128 * 128 == attrs["attention_kernel_elements_bwd_window"]
+    assert attrs["attention_kernel_chunk_window"] == 0 and "attention_kernel_chunk_causal" not in attrs
     counter = get_registry().counter
     assert counter("attention_kernel_layer_steps_total", mask="window").value == 9
     assert counter("attention_kernel_layer_steps_total", mask="causal").value == 3
