@@ -1,9 +1,10 @@
 """Routed language models as fitness models: one expert-parallel rank's share, trained under a recipe genome.
 
-The second jax family beside the Genetic-CNN (``models/cnn.py``), and six
+The second jax family beside the Genetic-CNN (``models/cnn.py``), and seven
 architectures of it, told apart by the configuration alone (which operator a
 layer has -- or whether it is an operator alone or a feed-forward alone --,
-which mask, which rope on how many of a head's columns and how many query heads
+which mask -- or whether the mask is data, a learned indexer's choice of keys --,
+which rope on how many of a head's columns and how many query heads
 an attention layer's type gives it, whether its output passes a gate, how the
 router scores, whether the experts are gated and in which state they work,
 whether shared experts stand beside the routed ones and behind a gate, whether
@@ -156,6 +157,34 @@ and work in a latent state narrower than the residual stream::
     ``A_log`` starts at ln u, u uniform on (1, 16), ``D`` at 1, the convolution's bias at 0, ``dt_bias`` at the
     inverse softplus of a step log-uniform on (0.001, 0.1); none of them, nor a norm weight, takes weight decay
 
+``Keye-VL-2.0-30B-A3B``'s language model (``model_type`` ``KeyeVL2``,
+https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json; on text, no vision tower):
+every layer is grouped-query attention whose keys a learned indexer chooses (``layer_types`` of
+``sparse_attention``; DeepSeek Sparse Attention's indexer, the DeepSeek-V3.2-Exp report, over a GQA trunk),
+then 128 routed experts 8 a token by Mellum2's rule.  ``u`` the layer's normed input, ``T`` the
+length, ``k`` = ``sparse_topk`` (2,048)::
+
+    trunk:    q = W_q u (32 heads of 128), k, v = W_k u, W_v u (4 heads of 128); RMSNorm over the head size on q
+              and k; rope in rotate-half layout whose 64 frequencies read their angle by section from three position
+              streams (``mrope_section`` [16, 24, 24]: temporal, then the two spatial; the token's index on text);
+              scale 128^-0.5
+    indexer:  qI_j = rope(W_qI[j] u) (j = 1..16, 64 wide), kI = rope(W_kI u) (ONE key head for all 16), w = W_w u;
+              I[t, s] = 16^-0.5 64^-0.5 sum_j w[t, j] relu(qI_j[t] . kI[s])   for s <= t;   ``u`` DETACHED here
+    select:   tau[t] = the k-th largest of I[t, 0..t] (minus infinity where t has no more than k keys), found by
+              bisection on the scores' bit pattern (:func:`_kth_largest`: 32 counting passes, exact, no sort);
+              S_t = {s <= t : I[t, s] >= tau[t]}: a threshold, so ties are kept; sum_t |S_t| = k (k + 1) / 2 +
+              (T - k) k when none ties, counted on the device.  The choice is made once, on the scores the
+              threshold was found in, and handed on as bits (:func:`_sparse_selection`: 33.5 MB a layer and
+              sequence of 16,384, kept for the backward pass: the rematerialised forward does not select again)
+    core:     each of the 32 heads is softmax attention over S_t alone: a mask that is data, honoured by XLA's
+              query blocks (:func:`_sparse_core`: groups of four blocks of ``attn_block``, each against the keys up
+              to its group's last query; no (heads x T x T) array is ever alive); W_o
+    L_I:      p[t, s] = (1 / 32) sum_h prob_h[t, s] on S_t, a constant to the gradient;
+              L_I = mean_t sum_{s in S_t} p log(p / softmax_{S_t}(I[t, :])), one a layer (DeepSeek-V3.2's sparse
+              training stage).  W_qI, W_kI and W_w get their gradient from L_I alone; nothing else gets any from it
+    loss = cross-entropy (head untied) + alpha * the balance term (the recipe's ``aux_alpha``) + sum over layers of L_I
+    the fitness is the held-out cross-entropy alone
+
 What differs from the CNN family, by design:
 
 - **Genes are data, not structure.**  Every individual is the same
@@ -191,7 +220,9 @@ Parameters are float32, compute is bfloat16 (router, norms, softmax, logits
 and loss float32; of a ``linear_attention`` layer also its gates, its
 convolution's arithmetic, the l2 norms, the state and every product of the
 delta rule's core; of a ``mamba2`` layer its step and decay, its convolution's
-arithmetic, the state and every product of its core, the gate and its norm).  The router bias ``b`` is not trained by the gradient:
+arithmetic, the state and every product of its core, the gate and its norm; of a ``sparse_attention`` layer's
+indexer the relu, the weights, the sum over its heads, the threshold, the comparison and the KL term -- its products
+are the compute dtype's, accumulated in float32).  The router bias ``b`` is not trained by the gradient:
 after each step ``b_e += u * sign(mean load - load_e)`` over all experts
 (arXiv:2408.15664; ``u`` is the ``bias_step`` gene).
 """
@@ -214,7 +245,8 @@ from ..telemetry.registry import get_registry as _get_registry
 from .evaluation import base_keys, evaluation_prelude, genome_hashes, phase
 from .generic import GentunModel
 
-__all__ = ["Lfm2MoeModel", "Lfm2MoeConfig", "Lfm2MoePrograms", "GENE_NAMES", "gene_names", "training_bytes"]
+__all__ = ["Lfm2MoeModel", "Lfm2MoeConfig", "Lfm2MoePrograms", "GENE_NAMES", "gene_names", "training_bytes",
+           "selected_keys"]
 
 #: The recipe's genes in the order the compiled programs take them (one float32 vector); the
 #: fifth belongs to the balance rule (:func:`gene_names`), and this is the bias rule's form.
@@ -354,6 +386,15 @@ class Lfm2MoeConfig:
     moe_latent_size: int = 0
     shared_expert_intermediate_size: int = 0
     route_eps: float = ROUTE_EPS
+    # what a seventh architecture sets (Keye-VL-2.0's language model): a ``sparse_attention`` layer's indexer (its
+    # heads, their size -- they share ONE key head -- and the keys it keeps a query, ``sa_config``'s
+    # ``indexer_num_heads``, ``indexer_head_dim`` and ``topk``), and rope whose frequencies are taken by section from
+    # several position streams (``rope_scaling.mrope_section``: how many of a head's rotated pairs read each stream;
+    # None: one stream, the token's index)
+    indexer_num_heads: int = 0
+    indexer_head_dim: int = 0
+    sparse_topk: int = 0
+    mrope_section: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
         if not self.head_dim:  # frozen: the stated size takes the place of the implied one once, here
@@ -384,7 +425,7 @@ class Lfm2MoeConfig:
     def typed_attention(self) -> bool:
         """Whether attention layers are told apart by type (their scope is the type's name, with
         ``proj``, ``rope`` and ``core`` inside); LFM2's one kind keeps its one ``attention`` scope."""
-        return bool({"sliding_attention", "linear_attention", "mamba2", "routed"} & set(self.layer_types))
+        return bool({"sliding_attention", "linear_attention", "mamba2", "routed", "sparse_attention"} & set(self.layer_types))
 
     @property
     def rotary_dim(self) -> int:
@@ -405,6 +446,11 @@ class Lfm2MoeConfig:
     @property
     def n_held(self) -> int:
         return self.held_experts[1] - self.held_experts[0]
+
+    @property
+    def sparse_layers(self) -> Tuple[int, ...]:
+        """The layers kept (by their place among them) whose attention's keys an indexer chooses, in order."""
+        return tuple(i for i, kind in enumerate(self.layer_types) if kind == "sparse_attention")
 
     @property
     def moe_layers(self) -> Tuple[int, ...]:
@@ -488,6 +534,9 @@ def param_shapes(cfg: Lfm2MoeConfig) -> Dict[str, Any]:
                 layer["attn"].update(q_norm=(hd,), k_norm=(hd,))
             if cfg.attn_head_gate:  # one scalar a head and token: a projection of its own
                 layer["attn"]["gate"] = (h, nh)
+            if kind == "sparse_attention":  # the indexer's heads' queries, their ONE key head, a weight a head and token
+                ni, di = cfg.indexer_num_heads, cfg.indexer_head_dim
+                layer["indexer"] = {"q": (h, ni * di), "k": (h, di), "w": (h, ni)}
         ffn = lambda width, f: {"w1": (width, f), "w2": (f, width), **({"w3": (width, f)} if cfg.gated_experts else {})}
         if "ffn" not in halves:
             pass
@@ -581,6 +630,12 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
         rows = 4 * heads * cfg.mamba_chunk  # a position's row of a chunk's masked C B', a head
         states = 2 * 4 * heads * cfg.mamba_head_dim * cfg.ssm_state_size * -(-t // cfg.mamba_chunk)  # and their cotangents
         interior = max(interior, t * (in_proj + operands + rows) + states)
+    if cfg.sparse_layers:
+        # a block of queries against every key up to its last, float32: each query head's scores, their softmax and its
+        # cotangent, the indexer's heads' products, its score, the mask and the mean share of the heads
+        heads = max(cfg.heads_of(i) for i in cfg.sparse_layers)
+        interior = max(interior, 4 * cfg.batch_sequences * min(cfg.attn_block, cfg.seq_len) * cfg.seq_len
+                       * (3 * heads + cfg.indexer_num_heads + 3))
     activations = 2 * t * h * (len(cfg.layer_types) + 1) + max(interior, 2 * 4 * t * cfg.vocab_size)
     return {"params": n_params, "state": 16 * n_params, "activations": activations,
             "total": 16 * n_params + activations}
@@ -588,7 +643,8 @@ def training_bytes(cfg: Lfm2MoeConfig) -> Dict[str, int]:
 
 #: What a layer can be (``layer_types``): its operator, or ``routed`` -- a routed feed-forward alone, which makes
 #: every other layer of the model its operator alone (:meth:`Lfm2MoeConfig.halves_of`).
-LAYER_KINDS = ("conv", "linear_attention", "mamba2", "full_attention", "sliding_attention", "latent_attention", "routed")
+LAYER_KINDS = ("conv", "linear_attention", "mamba2", "full_attention", "sliding_attention", "latent_attention",
+               "sparse_attention", "routed")
 #: Those of them that are attention over keys under a mask, whose causal core is :func:`_causal_core`'s.
 ATTENTION_KINDS = ("full_attention", "sliding_attention", "latent_attention")
 #: The programs the delta rule's core has, as the spans and the counter name them (one today).
@@ -603,6 +659,15 @@ LINEAR_CORE_KERNEL_CHAIN_PRODUCTS = 2
 STATE_SPACE_CORE_PROGRAMS = ("chunked",)
 #: The masks the core has, as the spans and the counter name them.
 MASKS = ("causal", "window")
+#: The programs a ``sparse_attention`` layer's core has, as the spans and the counter name them (one: XLA's query blocks).
+SPARSE_CORE_PROGRAMS = ("blockwise",)
+#: The query blocks a group of the sparse core's table holds (:func:`_sparse_blocks`).
+_SPARSE_GROUP = 4
+#: What a ``sparse_attention`` layer keeps for its backward pass under rematerialisation, by name: the selection's choice,
+#: as bits (the rematerialised forward does not select again), and the core's output (134 MB a layer and sequence of
+#: 16,384: with it kept, the rematerialised forward does not run the core again; the backward pass runs each block's
+#: forward once, on its own: two forward runs of the core a step, not three).
+SPARSE_KEPT = ("sparse_kept", "sparse_out")
 
 #: A program of this family is one individual wide, always: the published cut
 #: takes 10.4 of a chip's 16 GB in state alone, and a second width would be a
@@ -713,13 +778,18 @@ def yarn_amplitude(scaling: Mapping[str, Any]) -> float:
     return yarn_mscale(scaling["factor"], 1.0)
 
 
-def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]], rotary: Optional[int] = None, chunks: int = 0):
+def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]], rotary: Optional[int] = None, chunks: int = 0,
+                 sections: Optional[Sequence[int]] = None, positions=None):
     """cos and sin of the positions of ``x`` (sequences, length, ..., head size), float32, one
     column a rotated pair (half the head size, or half of ``rotary``, the leading columns that
     turn) and shaped to broadcast against ``x``'s halves.
     With ``scaling`` (YaRN) the frequencies are :func:`yarn_inv_freq`'s and both carry
     :func:`yarn_amplitude`.  ``chunks``: ``x`` holds its sequences in that many chunks each,
-    (sequences x chunks, chunk, ..., head size), and row ``i`` is at chunk ``i % chunks``."""
+    (sequences x chunks, chunk, ..., head size), and row ``i`` is at chunk ``i % chunks``.
+    ``positions`` (streams, length): where each token stands, one row a position stream (None: its
+    index in the sequence, in every stream); ``sections``: how many of the rotated pairs, in
+    order, take their angle from each stream (``mrope_section``: the first 16 of 64 from the
+    temporal stream, then 24 and 24 from the two spatial ones; None: all from the first)."""
     turning = x.shape[-1] if rotary is None else rotary
     half = turning // 2
     if scaling is None:
@@ -727,7 +797,13 @@ def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]], rotary: Optiona
     else:
         inv_freq, amplitude = jnp.asarray(yarn_inv_freq(turning, theta, scaling)), yarn_amplitude(scaling)
     rows = max(chunks, 1)  # of the tables: a sequence's chunks, which every sequence shares
-    angle = jnp.arange(rows * x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    if sections is None and positions is None:
+        angle = jnp.arange(rows * x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    else:  # a pair's angle is its own stream's position times its frequency
+        streams = jnp.arange(rows * x.shape[1], dtype=jnp.float32)[None, :] if positions is None \
+            else jnp.asarray(positions, jnp.float32).reshape(-1, rows * x.shape[1])
+        stream_of = np.repeat(np.arange(len(sections)), sections) if sections is not None else np.zeros(half, np.int64)
+        angle = streams[np.minimum(stream_of, streams.shape[0] - 1)].T * inv_freq[None, :]
     per_position = (rows, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
     cos, sin = jnp.cos(angle).reshape(per_position), jnp.sin(angle).reshape(per_position)
     if chunks:
@@ -737,19 +813,20 @@ def _rope_tables(x, theta, scaling: Optional[Mapping[str, Any]], rotary: Optiona
     return cos, sin
 
 
-def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None):
+def _rope(x, theta, scaling: Optional[Mapping[str, Any]] = None, positions=None):
     """Rotary embedding, rotate-half layout, on (sequences, length, ..., head size):
     heads, or key-value heads and their query heads, between; float32.  The two
     halves are sliced and concatenated: the form for a few columns (latent
-    attention's 64 rope columns a head, concatenated to the rest anyway)."""
-    cos, sin = _rope_tables(x, theta, scaling)
+    attention's 64 rope columns a head, concatenated to the rest anyway; an indexer's 64,
+    at ``positions``' first stream)."""
+    cos, sin = _rope_tables(x, theta, scaling, positions=positions)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
 def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None, rotary: Optional[int] = None,
-                      chunks: int = 0):
+                      chunks: int = 0, sections: Optional[Sequence[int]] = None, positions=None):
     """:func:`_rope`'s function on whole heads without a slice or a concatenation:
     ``x * [cos | cos] + (x @ T) * [sin | sin]``, where ``T`` is the signed
     permutation that sends ``[x1 | x2]`` to ``[-x2 | x1]``.  Every product with
@@ -766,10 +843,12 @@ def _rope_whole_heads(x, theta, scaling: Optional[Mapping[str, Any]] = None, rot
     inside them): the tables read cos 1 and sin 0 on the columns that pass, and
     ``T`` has no entry for them -- the same one fusion.  ``chunks``: ``x`` holds
     each sequence in that many chunks, as the banded core's queries are
-    (:func:`_rope_tables`)."""
+    (:func:`_rope_tables`); ``sections`` and ``positions``: rope by sections over
+    several position streams, the tables' alone."""
     size = x.shape[-1]
     turning = size if rotary is None else rotary
-    cos, sin = (jnp.concatenate([table, table], axis=-1) for table in _rope_tables(x, theta, scaling, rotary, chunks))
+    cos, sin = (jnp.concatenate([table, table], axis=-1)
+                for table in _rope_tables(x, theta, scaling, rotary, chunks, sections, positions))
     if turning < size:
         passing = [(0, 0)] * (cos.ndim - 1) + [(0, size - turning)]
         cos, sin = jnp.pad(cos, passing, constant_values=1.0), jnp.pad(sin, passing)
@@ -1093,6 +1172,246 @@ def _attention(p, x, cfg: Lfm2MoeConfig, dtype, kind: str = "full_attention"):
     with part("proj"):
         out = jnp.einsum("slngd,ngdh->slh", out, p["o"].astype(dtype).reshape(nkv, nh // nkv, hd, hidden))
         return out.reshape(x.shape) if chunk else out
+
+
+def _kth_largest(scores, k: int):
+    """The ``k``-th largest of each row of ``scores`` (..., n), float32, exact: by bisection on
+    the bit pattern.  A float32's order is an unsigned integer's once the sign bit is set on
+    what is not negative and every bit flipped on what is; the largest integer that ``k`` of a
+    row's keys reach is then built a bit at a time from the top, each bit one counting pass
+    over the row: 32 passes, no sort (``lax.top_k`` of 2,048 over 16,384 is a sort on a TPU).
+    A row with fewer than ``k`` entries gives a NaN, which its caller never reads."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def narrow(i, found):
+        candidate = found | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(keys >= candidate[..., None], axis=-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, candidate, found)
+
+    found = jax.lax.fori_loop(0, 32, narrow, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    return jax.lax.bitcast_convert_type(jnp.where(found >> 31 == 1, found & jnp.uint32(0x7FFFFFFF), ~found), jnp.float32)
+
+
+def _indexer_scores(q_idx, k_idx, w_idx, first: int):
+    """The indexer's score of every (query, key) of a block: ``sum_j w[t, j] relu(qI_j[t] . kI[s])``,
+    minus infinity where the key lies ahead of the query (the block's first query stands at
+    ``first``, its keys from position 0).  ``q_idx`` (sequences, queries, indexer heads, size)
+    and ``k_idx`` (sequences, keys, size) in the compute dtype, their products accumulated in
+    float32; ``w_idx`` (sequences, queries, indexer heads) float32, the score's two scales on it;
+    relu, weights and the sum over the heads float32 arithmetic, no product (a float32
+    contraction would cross a TPU's matrix unit in bfloat16).  Returns (sequences, queries,
+    keys) float32."""
+    dots = jnp.einsum("sqjd,skd->sqjk", q_idx, k_idx, preferred_element_type=jnp.float32)
+    ahead = (first + jnp.arange(q_idx.shape[1]))[:, None] < jnp.arange(k_idx.shape[1])[None, :]
+    return jnp.where(ahead, -jnp.inf, jnp.sum(jax.nn.relu(dots) * w_idx[..., None], axis=2))
+
+
+def _sparse_blocks(length: int, block: int) -> Tuple[Tuple[int, int], ...]:
+    """The sparse core's table: (a group of query blocks' first position, its end), in order.  A
+    group is up to ``_SPARSE_GROUP`` blocks of ``block`` queries, each handed every key up to
+    the GROUP's last query: one body a group in the program (a ``lax.map`` over its blocks), a
+    quarter of the bodies a table of single blocks would make and a tenth more pairs.  What the
+    core walks and what its count of visits reads."""
+    block = min(block, length)
+    if length % block:
+        raise ValueError(f"seq_len {length} is not a multiple of attn_block {block}")
+    reach = _SPARSE_GROUP * block
+    return tuple((first, min(first + reach, length)) for first in range(0, length, reach))
+
+
+def _sparse_visits(length: int, block: int) -> Dict[str, int]:
+    """What the sparse core visits for one head and sequence, off its own table
+    (:func:`_sparse_blocks`): the (query block, keys) ``pairs`` and the score ``elements`` in them."""
+    block = min(block, length)
+    table = _sparse_blocks(length, block)
+    return {"pairs": sum((last - first) // block for first, last in table),
+            "elements": sum((last - first) * last for first, last in table)}
+
+
+def _by_block(body, first: int, last: int, block: int, *rows):
+    """``body(*a block's rows, the block's first position)`` for each block of ``block`` queries
+    of [first, last), stacked: ``rows`` are (sequences, length, ...) arrays cut to the group and
+    handed over a block at a time; one trace of ``body`` (a ``lax.map``) where the group has
+    more than one block."""
+    n = (last - first) // block
+    if n == 1:
+        return jax.tree_util.tree_map(lambda a: a[None], body(*(a[:, first:last] for a in rows), first))
+    split = lambda a: jnp.moveaxis(a[:, first:last].reshape(a.shape[0], n, block, *a.shape[2:]), 1, 0)
+    return jax.lax.map(lambda xs: body(*xs[:-1], xs[-1]), (*map(split, rows), first + block * jnp.arange(n)))
+
+
+def _kept(index, tau):
+    """The mask that is data: a block's (query, key) pairs with ``I[t, s] >= tau[t]``, no key
+    ahead of its query (:func:`_indexer_scores` scored those minus infinity)."""
+    return (index >= tau[..., None]) & (index > -jnp.inf)
+
+
+def _packed(kept):
+    """A mask (..., keys) as bits, eight keys a byte (..., ceil(keys / 8)) uint8, key ``8 i + j`` at bit ``j``."""
+    pad = -kept.shape[-1] % 8
+    if pad:
+        kept = jnp.pad(kept, [(0, 0)] * (kept.ndim - 1) + [(0, pad)])
+    bits = kept.reshape(*kept.shape[:-1], -1, 8).astype(jnp.uint8) << jnp.arange(8, dtype=jnp.uint8)
+    return jnp.sum(bits, axis=-1, dtype=jnp.uint8)
+
+
+def _unpacked(bits, keys: int):
+    """:func:`_packed` undone for the first ``keys`` keys: bool (..., keys)."""
+    kept = (bits[..., : -(-keys // 8), None] >> jnp.arange(8, dtype=jnp.uint8)) & jnp.uint8(1)
+    return kept.reshape(*bits.shape[:-1], -1)[..., :keys] == 1
+
+
+def _sparse_selection(q_idx, k_idx, w_idx, top: int, block: int):
+    """Which keys each query keeps, as bits (:func:`_packed`): uint8 (sequences, length,
+    ceil(length / 8)).  ``tau[t]`` is the ``top``-th largest indexer score among the keys up to
+    ``t`` (:func:`_kth_largest`), minus infinity where ``t`` has no more than ``top`` of them (it
+    then keeps them all); query ``t`` keeps key ``s <= t`` iff ``I[t, s] >= tau[t]``
+    (:func:`_kept`) -- decided HERE, on the scores the threshold was found in, and handed on as
+    the choice itself: a score computed again elsewhere may differ in its last bit, and a
+    comparison with a threshold would then drop the very key that set it.  A query block at a
+    time against the keys up to its group's last (:func:`_sparse_blocks`); no gradient passes (a
+    choice has none).  33.5 MB a layer and sequence of 16,384: the first of what a
+    ``sparse_attention`` layer keeps for its backward pass under rematerialisation (``SPARSE_KEPT``)."""
+    length, block = q_idx.shape[1], min(block, q_idx.shape[1])
+    q_idx, k_idx, w_idx = map(jax.lax.stop_gradient, (q_idx, k_idx, w_idx))
+    width = -(-length // 8)
+
+    def one_block(qib, wb, first, kib):
+        with jax.named_scope("indexer_scores"):
+            index = _indexer_scores(qib, kib, wb, first)
+        with jax.named_scope("select"):
+            if kib.shape[1] <= top:  # none of the group's queries has more keys than it may keep
+                tau = jnp.full(index.shape[:2], -jnp.inf, jnp.float32)
+            else:
+                tau = jnp.where(first + jnp.arange(block) + 1 > top, _kth_largest(index, top), -jnp.inf)
+            return _packed(_kept(index, tau))
+
+    chosen = []
+    for first, last in _sparse_blocks(length, block):
+        bits = _by_block(functools.partial(one_block, kib=k_idx[:, :last]), first, last, block, q_idx, w_idx)
+        bits = jnp.moveaxis(bits, 0, 1).reshape(q_idx.shape[0], last - first, -1)
+        chosen.append(jnp.pad(bits, ((0, 0), (0, 0), (0, width - bits.shape[-1]))))
+    return jnp.concatenate(chosen, axis=1)
+
+
+def _heads_share(prob):
+    """``p``: a kept key's share in the heads' attention, their mean, a constant to the gradient;
+    ``prob`` (sequences, kv heads, group, queries, keys)."""
+    return jax.lax.stop_gradient(jnp.mean(prob, axis=(1, 2)))
+
+
+def _indexer_loss(index, share, kept):
+    """``sum_s p log(p / softmax_kept(I[t, :]))`` over a block's queries, a sequence
+    (sequences,): the gradient reaches ``index`` alone."""
+    log_index = jax.nn.log_softmax(jnp.where(kept, index, -jnp.inf), axis=-1)
+    terms = jax.scipy.special.xlogy(share, share) - share * jnp.where(kept, log_index, 0.0)
+    return jnp.sum(jnp.where(kept, terms, 0.0), axis=(1, 2))
+
+
+def _sparse_core(q, k, v, q_idx, k_idx, w_idx, chosen, scale: float, block: int):
+    """Attention over the keys the indexer chose, and the indexer's loss, in query blocks of at
+    most ``block`` as XLA programs: no (length x length) array is alive, a block's scores against
+    the keys up to its group's last query are (:func:`_sparse_blocks`).  ``q``, ``k``, ``v`` as
+    :func:`_blockwise_core`'s; ``q_idx``, ``k_idx``, ``w_idx`` as :func:`_indexer_scores`'s over
+    the whole sequence; ``chosen`` the selection's bits (:func:`_sparse_selection`).
+
+    A block unpacks its queries' choices -- a mask that is data -- and runs every head's softmax
+    over the kept keys alone; it computes its indexer scores ``I`` again for the loss (an eighth
+    of the core's products: cheaper than keeping them).  The loss: ``p`` the heads'
+    mean share of each kept key, a constant to the gradient (:func:`_heads_share`);
+    ``sum_s p log(p / softmax_kept(I[t, :]))`` a query (:func:`_indexer_loss`), whose gradient
+    reaches ``I`` alone (so the indexer's operands, and nothing of the trunk).  Returns (the
+    heads' outputs as ``q``, the queries' loss terms summed a sequence (sequences,) float32, the
+    (query, key) pairs kept, int32)."""
+    length, dtype = q.shape[1], k.dtype
+    q = q.astype(dtype)
+
+    @jax.checkpoint
+    def one_block(qb, kb, vb, qib, kib, wb, bits, first):
+        with jax.named_scope("indexer_scores"):
+            index = _indexer_scores(qib, kib, wb, first)
+        with jax.named_scope("core"):
+            kept = _unpacked(bits, kb.shape[1])
+            scores = jnp.einsum("sqngd,sknd->sngqk", qb, kb, preferred_element_type=jnp.float32) * scale
+            prob = jax.nn.softmax(jnp.where(kept[:, None, None], scores, -jnp.inf), axis=-1)
+            out = jnp.einsum("sngqk,sknd->sqngd", prob.astype(dtype), vb)
+        with jax.named_scope("indexer_loss"):
+            loss = _indexer_loss(index, _heads_share(prob), kept)
+        return out, loss, jnp.sum(kept, dtype=jnp.int32)
+
+    out, loss, pairs = [], 0.0, 0
+    block = min(block, length)
+    for first, last in _sparse_blocks(length, block):
+        body = lambda qb, qib, wb, bits, at, last=last: one_block(qb, k[:, :last], v[:, :last], qib, k_idx[:, :last], wb, bits, at)
+        o, l, n = _by_block(body, first, last, block, q, q_idx, w_idx, chosen)
+        out.append(jnp.moveaxis(o, 0, 1).reshape(o.shape[1], last - first, *o.shape[3:]))
+        loss, pairs = loss + jnp.sum(l, axis=0), pairs + jnp.sum(n)
+    return jnp.concatenate(out, axis=1), loss, pairs
+
+
+def _indexer_reads(x):
+    """What the indexer reads of the layer's normed input: its value, DETACHED -- the indexer
+    learns from its own loss and moves nothing it reads."""
+    return jax.lax.stop_gradient(x)
+
+
+def _indexer_operands(indexer, x, cfg: Lfm2MoeConfig, dtype, positions=None):
+    """(``qI`` (sequences, length, heads, size) and ``kI`` (sequences, length, size) in the
+    compute dtype, ``w`` (sequences, length, heads) float32 times ``heads^-0.5 size^-0.5``) of a
+    ``sparse_attention`` layer's indexer (``q`` hidden x (heads x size), ``k`` hidden x size, ONE
+    key head for all its heads, ``w`` hidden x heads) from the layer's normed input, detached
+    (:func:`_indexer_reads`): products in the compute dtype accumulated in float32; plain rope on
+    every column of its queries and of its key at the first stream's positions."""
+    hidden, ni, di = x.shape[-1], cfg.indexer_num_heads, cfg.indexer_head_dim
+    theta, _ = cfg.rope_of("sparse_attention")
+    seen = _indexer_reads(x).astype(dtype)
+    product = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    q_idx = product("slh,hjd->sljd", seen, indexer["q"].astype(dtype).reshape(hidden, ni, di))
+    k_idx = product("slh,hd->sld", seen, indexer["k"].astype(dtype))
+    w_idx = product("slh,hj->slj", seen, indexer["w"].astype(dtype)) * (ni ** -0.5 * di ** -0.5)
+    q_idx = _rope(q_idx, theta, positions=positions).astype(dtype)
+    k_idx = _rope(k_idx[:, :, None], theta, positions=positions)[:, :, 0].astype(dtype)
+    return q_idx, k_idx, w_idx
+
+
+def _sparse_attention(p, indexer, x, cfg: Lfm2MoeConfig, dtype, positions=None):
+    """Causal GQA whose keys a learned indexer chooses (DeepSeek Sparse Attention's indexer over a
+    grouped-query trunk), on (sequences, length, hidden).  The trunk is :func:`_attention`'s:
+    q, k, v head-major, the per-head norm of q and k where the configuration has one, rope on
+    whole heads -- by sections over the position streams where it has ``mrope_section``
+    (``positions`` (streams, length); None: the token's index in each).  The indexer's operands
+    (:func:`_indexer_operands`) come from the layer's normed input detached.  Then the selection
+    (:func:`_sparse_selection`: the keys whose score reaches the ``sparse_topk``-th largest among a
+    query's keys, as bits) and the core with the indexer's loss (:func:`_sparse_core`); the
+    selection and the core's output are kept for the backward pass under rematerialisation
+    (``SPARSE_KEPT``).  Scopes: ``proj``, ``rope``, ``indexer_proj``,
+    ``indexer_scores``, ``select``, ``core``, ``indexer_loss``.
+
+    Returns (the output, (the indexer's loss: the mean over queries of the KL term, float32; the
+    (query, key) pairs kept, int32))."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    hidden, nkv, hd = x.shape[-1], cfg.num_key_value_heads, cfg.head_dim
+    nh = p["o"].shape[0] // hd
+    theta, scaling = cfg.rope_of("sparse_attention")
+    with jax.named_scope("proj"):
+        q = _head_major(x, p["q"].astype(dtype).reshape(hidden, nkv, nh // nkv, hd))
+        k = _head_major(x, p["k"].astype(dtype).reshape(hidden, nkv, hd))
+        v = _head_major(x, p["v"].astype(dtype).reshape(hidden, nkv, hd))
+    with jax.named_scope("rope"):
+        normed = lambda a, weight: _rms_norm(a, p[weight], cfg.norm_eps) if cfg.qk_norm else a
+        turned = functools.partial(_rope_whole_heads, theta=theta, scaling=scaling, sections=cfg.mrope_section,
+                                   positions=positions)
+        q, k = turned(normed(q, "q_norm")), turned(normed(k, "k_norm")).astype(dtype)
+    with jax.named_scope("indexer_proj"):
+        q_idx, k_idx, w_idx = _indexer_operands(indexer, x, cfg, dtype, positions)
+    chosen = checkpoint_name(_sparse_selection(q_idx, k_idx, w_idx, cfg.sparse_topk, cfg.attn_block), SPARSE_KEPT[0])
+    out, loss, pairs = _sparse_core(q, k, v, q_idx, k_idx, w_idx, chosen, 1.0 / math.sqrt(hd), cfg.attn_block)
+    out = checkpoint_name(out, SPARSE_KEPT[1])
+    with jax.named_scope("proj"):
+        out = jnp.einsum("slngd,ngdh->slh", out, p["o"].astype(dtype).reshape(nkv, nh // nkv, hd, hidden))
+    return out, (jnp.sum(loss) / (x.shape[0] * x.shape[1]), pairs)
 
 
 def latent_softmax_scale(cfg: Lfm2MoeConfig) -> float:
@@ -1474,6 +1793,10 @@ class RoutedStats(NamedTuple):
     dropped: Any  # int32: held assignments that found no room in the row buffer: 0, the height taken holds them
     heights: Any  # int32 (rungs,): routed layers that took each of :func:`_row_buffer_heights`, shortest first
     balance: Any  # float32: the balance terms (``aux_loss`` rule; 0 under the bias rule, which has none)
+    # of a model with ``sparse_attention`` layers (None, no leaf, in every other): the indexers' losses, float32, and
+    # the (query, key) pairs each such layer kept, int32 (one a layer in what :func:`forward` returns)
+    indexer_loss: Any = None
+    selected: Any = None
 
     @property
     def wide(self):
@@ -1674,6 +1997,10 @@ def _mixer(cfg: Lfm2MoeConfig, index: int, dtype, p, x):
         elif kind == "mamba2":
             with jax.named_scope("mamba2"):
                 return x + _state_space(p["mamba"], normed, cfg, dtype)
+        elif kind == "sparse_attention":  # the one mixer that reports: (the output, (its indexer's loss, the pairs it kept))
+            with jax.named_scope("sparse_attention"):
+                out, report = _sparse_attention(p["attn"], p["indexer"], normed, cfg, dtype)
+                return x + out, report
         with jax.named_scope(kind if cfg.typed_attention else "attention"):
             return x + _attention(p["attn"], normed, cfg, dtype, kind)
 
@@ -1698,6 +2025,10 @@ def _layer(cfg: Lfm2MoeConfig, index: int, dtype, p, bias, x, by_count=_expert_r
     half each (:meth:`Lfm2MoeConfig.halves_of`) is that half: its one norm, its
     one residual add."""
     halves = cfg.halves_of(index)
+    if cfg.layer_types[index] == "sparse_attention":  # a routed layer (:func:`_normalize_config`): its stats carry the mixer's report
+        x, (indexer_loss, selected) = _mixer(cfg, index, dtype, p, x)
+        x, (load, stats) = _ffn(cfg, index, dtype, p, bias, x, by_count)
+        return x, (load, stats._replace(indexer_loss=indexer_loss, selected=selected))
     if "mixer" in halves:
         x = _mixer(cfg, index, dtype, p, x)
     return _ffn(cfg, index, dtype, p, bias, x, by_count) if "ffn" in halves else (x, None)
@@ -1711,7 +2042,9 @@ def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dtype)
     rungs = len(_row_buffer_heights(cfg, tokens.size))
-    loads, use = [], RoutedStats(jnp.zeros((), jnp.int32), jnp.zeros(rungs, jnp.int32), jnp.zeros((), jnp.float32))
+    loads, use = [], RoutedStats(jnp.zeros((), jnp.int32), jnp.zeros(rungs, jnp.int32), jnp.zeros((), jnp.float32),
+                                 jnp.zeros((), jnp.float32) if cfg.sparse_layers else None)
+    selected = []  # the pairs each ``sparse_attention`` layer kept
     by_count = functools.lru_cache(maxsize=None)(_expert_rows_by_count)  # one for the layers of this trace
     routed = cfg.moe_layers
     for i, p in enumerate(params["layers"]):
@@ -1724,16 +2057,41 @@ def forward(cfg: Lfm2MoeConfig, params, bias, tokens, remat: bool = False):
             # feed-forward half is differentiated -- 2 GB past the chip at the published cut (TPU compiler)
             h = jax.checkpoint(functools.partial(_mixer, cfg, i, dtype))(p, x)
             x, aux = jax.checkpoint(functools.partial(_ffn, cfg, i, dtype, by_count=by_count))(p, layer_bias, h)
+        elif remat and cfg.layer_types[i] == "sparse_attention":
+            # the selection (a bit a query and key) and the core's output are kept: the backward pass neither selects
+            # again nor runs the whole core's forward again
+            x, aux = jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(*SPARSE_KEPT))(p, layer_bias, x)
         else:
             x, aux = (jax.checkpoint(fn) if remat else fn)(p, layer_bias, x)
         if moe:
             loads.append(aux[0])
-            use = jax.tree_util.tree_map(jnp.add, use, aux[1])
+            if aux[1].selected is not None:
+                selected.append(aux[1].selected)
+            use = jax.tree_util.tree_map(jnp.add, use, aux[1]._replace(selected=None))
+    if selected:
+        use = use._replace(selected=jnp.stack(selected))
     with jax.named_scope("head"):
         x = _rms_norm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
         head = params["embed" if cfg.tie_word_embeddings else "head"]
         logits = jnp.einsum("slh,vh->slv", x, head.astype(dtype), preferred_element_type=jnp.float32)
     return logits, jnp.stack(loads), use
+
+
+def selected_keys(cfg: Lfm2MoeConfig, params, bias, tokens):
+    """Which keys each query of each ``sparse_attention`` layer keeps on ``tokens`` (sequences,
+    length), as the programs choose them (:func:`_sparse_selection`'s bits, unpacked): bool
+    (sparse layers, sequences, length, length), entry [l, s, t, k] true where query ``t`` keeps key ``k``.  What a comparison of the chosen sets and the tests read; no
+    program the evaluator runs holds such an array."""
+    dtype = jnp.dtype(cfg.compute_dtype)
+    x = jnp.take(params["embed"], tokens, axis=0).astype(dtype)
+    routed, masks, length = cfg.moe_layers, [], tokens.shape[1]
+    for i, p in enumerate(params["layers"]):
+        if cfg.layer_types[i] == "sparse_attention":
+            normed = _rms_norm(x, p["op_norm"], cfg.norm_eps).astype(dtype)
+            q_idx, k_idx, w_idx = _indexer_operands(p["indexer"], normed, cfg, dtype)
+            masks.append(_unpacked(_sparse_selection(q_idx, k_idx, w_idx, cfg.sparse_topk, cfg.attn_block), length))
+        x = _layer(cfg, i, dtype, p, bias[routed.index(i)] if i in routed else None, x)[0]
+    return jnp.stack(masks)
 
 
 def token_loss(logits, targets):
@@ -1780,7 +2138,12 @@ class Lfm2MoePrograms(NamedTuple):
     of a head that its rope turns (a kernel's visits are a head's: the work of a mask's layers is theirs
     times these heads; 0 columns where the configuration has no positional encoding), whichever core runs.
     ``state_space_core_layers``: the ``mamba2`` layers by the program their core runs as
-    (``STATE_SPACE_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none."""
+    (``STATE_SPACE_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none.
+    ``sparse_core_layers``: the ``sparse_attention`` layers by the program their core runs as
+    (``SPARSE_CORE_PROGRAMS``: ``(("blockwise", n),)``), and ``sparse_core_visits``: what that core visits a head
+    and sequence off its own table (:func:`_sparse_visits`, as sorted items); both empty where the configuration has none.
+    Such a state also holds ``indexer_loss`` (the indexers' losses, summed over layers and steps) and
+    ``selected_pairs`` (the (query, key) pairs each such layer kept, summed over the steps)."""
 
     config: Lfm2MoeConfig
     init: Any
@@ -1795,6 +2158,8 @@ class Lfm2MoePrograms(NamedTuple):
     linear_core_kernel_layers: int = 0
     linear_core_inverse_products: float = 0.0
     state_space_core_layers: Tuple[Tuple[str, int], ...] = ()
+    sparse_core_layers: Tuple[Tuple[str, int], ...] = ()
+    sparse_core_visits: Tuple[Tuple[str, int], ...] = ()
 
 
 def _init_leaf(name: str, key, index: int, shape):
@@ -1830,6 +2195,7 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
     lo, hi = cfg.held_experts
     n_moe = len(cfg.moe_layers)
     by_loss = cfg.balance_rule == "aux_loss"
+    sparse = len(cfg.sparse_layers)
 
     def init(base_key, genome_hash):
         key = jax.random.fold_in(jax.random.fold_in(base_key, genome_hash[0]), genome_hash[1])
@@ -1843,12 +2209,18 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
                  "row_buffer_heights": jnp.zeros(len(_row_buffer_heights(cfg, cfg.tokens_per_step)), jnp.int32)}
         if by_loss:
             state["aux_loss"] = jnp.zeros((), jnp.float32)
+        if sparse:
+            state.update(indexer_loss=jnp.zeros((), jnp.float32), selected_pairs=jnp.zeros(sparse, jnp.int32))
         return state
 
     def loss_fn(params, bias, x, y, balance_weight):
         logits, load, use = forward(cfg, params, bias, x, remat=True)
         loss = token_loss(logits, y).mean()
-        return (loss + balance_weight * use.balance if by_loss else loss), (load, use)
+        if by_loss:
+            loss = loss + balance_weight * use.balance
+        if sparse:  # each indexer's loss at a weight of 1: it shares no parameter's gradient with any other term
+            loss = loss + use.indexer_loss
+        return loss, (load, use)
 
     def train_step(state, x_all, y_all, batch_rows, genes, step):
         rows = batch_rows[step]
@@ -1881,6 +2253,9 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
                "row_buffer_heights": state["row_buffer_heights"] + use.heights}
         if by_loss:
             new["aux_loss"] = state["aux_loss"] + use.balance
+        if sparse:
+            new.update(indexer_loss=state["indexer_loss"] + use.indexer_loss,
+                       selected_pairs=state["selected_pairs"] + use.selected)
         return new, loss, held
 
     def lm_eval(params, bias, x_all, y_all, rows):
@@ -1919,7 +2294,9 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
                            sum(n for _, n in by_mask), tuple(by_mask), tuple(visits),
                            ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else (), tuple(heads), tuple(rotary),
                            by_kernels, inverse_products,
-                           ((STATE_SPACE_CORE_PROGRAMS[0], state_space),) if state_space else ())
+                           ((STATE_SPACE_CORE_PROGRAMS[0], state_space),) if state_space else (),
+                           ((SPARSE_CORE_PROGRAMS[0], sparse),) if sparse else (),
+                           tuple(sorted(_sparse_visits(cfg.seq_len, cfg.attn_block).items())) if sparse else ())
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -1932,7 +2309,8 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
     x = np.asarray(x_train)
     if x.ndim != 2 or not np.issubdtype(x.dtype, np.integer):
         raise ValueError(f"x_train must be integer tokens (sequences, length); got {x.dtype} {x.shape}")
-    for key in ("layer_types", "layer_ids", "held_experts", "num_attention_heads_per_layer", "held_mamba_heads"):
+    for key in ("layer_types", "layer_ids", "held_experts", "num_attention_heads_per_layer", "held_mamba_heads",
+                "mrope_section"):
         if config.get(key) is not None:
             config[key] = tuple(config[key])
     if isinstance(config.get("rope_scaling"), Mapping):
@@ -1967,6 +2345,18 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
         if not 0 <= first < last <= cfg.mamba_num_heads or first % per_group or last % per_group:
             raise ValueError(f"held_mamba_heads {cfg.held_mamba_heads} is no range of whole groups ({per_group} heads "
                              f"each, with their one B and C) of the {cfg.mamba_num_heads} heads")
+    if cfg.sparse_layers:
+        sizes = {k: getattr(cfg, k) for k in ("indexer_num_heads", "indexer_head_dim", "sparse_topk")}
+        if min(sizes.values()) <= 0 or cfg.indexer_head_dim % 2:
+            raise ValueError(f"a sparse_attention layer needs its indexer's heads, their (even) size and the keys it "
+                             f"keeps a query: {sizes}")
+        if cfg.num_dense_layers or cfg.single_half_layers:
+            raise ValueError("a sparse_attention layer reports its indexer's loss through its routed feed-forward: "
+                             "such a model has no dense layer and no layer that is one half")
+    if cfg.mrope_section is not None and (min(cfg.mrope_section, default=0) <= 0
+                                          or 2 * sum(cfg.mrope_section) != cfg.rotary_of("sparse_attention")):
+        raise ValueError(f"mrope_section {cfg.mrope_section} must share out the {cfg.rotary_of('sparse_attention') // 2} "
+                         f"pairs of columns that rope turns")
     if cfg.mlp_hidden_act not in ("silu", "relu2") or cfg.positional_encoding not in ("rope", "none"):
         raise ValueError(f"mlp_hidden_act {cfg.mlp_hidden_act!r} (silu: a SwiGLU; relu2: two matrices, no gate) / "
                          f"positional_encoding {cfg.positional_encoding!r} (rope or none)")
@@ -1991,7 +2381,7 @@ def _normalize_config(x_train, config: Mapping[str, Any]) -> Tuple[Lfm2MoeConfig
     if per_layer is not None and len(per_layer) != len(cfg.layer_types):
         raise ValueError(f"num_attention_heads_per_layer {per_layer} against {len(cfg.layer_types)} layer_types")
     for i, kind in enumerate(cfg.layer_types):  # each attention layer by its own type's rope and its own heads
-        if kind not in ("full_attention", "sliding_attention"):
+        if kind not in ("full_attention", "sliding_attention", "sparse_attention"):
             continue
         name, heads, rotary = f"layer {cfg.layer_ids[i]} ({kind})", cfg.heads_of(i), cfg.rotary_of(kind)
         if cfg.head_dim % 2 or heads <= 0 or heads % cfg.num_key_value_heads:
@@ -2128,6 +2518,11 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
         kernel_attrs["state_space_heads_held"] = cfg.mamba_held[0]
     if cfg.moe_latent_size:
         kernel_attrs["latent_experts_width"] = cfg.moe_latent_size
+    by_sparse = {program: layers * cfg.train_steps for program, layers in programs.sparse_core_layers}
+    if by_sparse:  # static: the layers x steps, the indexer's sizes, whether the core is a kernel and what it visits
+        kernel_attrs.update(sparse_attention_layer_steps=sum(by_sparse.values()), sparse_topk=cfg.sparse_topk,
+                            indexer_heads=cfg.indexer_num_heads, sparse_core_kernel_layer_steps=0,
+                            **{f"sparse_core_{name}": n for name, n in programs.sparse_core_visits})
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)
@@ -2143,8 +2538,9 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
         losses = sp.fence([programs.eval(state["params"], state["bias"], x, y, rows) for rows in val_rows])
     with phase("fetch", {"individual": individual}) as sp:
         if _tele.enabled():
-            losses, rows, dropped, taken, balance = jax.device_get(
-                (losses, state["rows"], state["dropped"], state["row_buffer_heights"], state.get("aux_loss")))
+            losses, rows, dropped, taken, balance, indexer_loss, selected = jax.device_get(
+                (losses, state["rows"], state["dropped"], state["row_buffer_heights"], state.get("aux_loss"),
+                 state.get("indexer_loss"), state.get("selected_pairs")))
             wide = int(RoutedStats(dropped, taken, balance).wide)
             by_height = list(zip(_row_buffer_heights(cfg, cfg.tokens_per_step), taken.tolist()))
             _count_expert_rows(cfg, rows, int(dropped), wide, by_height)
@@ -2156,6 +2552,11 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
                 _get_registry().counter("linear_core_kernel_layer_steps_total").inc(linear_kernel_steps)
             for program, n in by_state_space.items():
                 _get_registry().counter("state_space_core_layer_steps_total", program=program).inc(n)
+            for program, n in by_sparse.items():
+                _get_registry().counter("sparse_attention_layer_steps_total", program=program).inc(n)
+            if by_sparse:  # the pairs a layer kept over the steps; the indexer's loss a layer and step
+                sp.set(selected_pairs=selected.tolist(),
+                       indexer_loss_mean=float(indexer_loss) / sum(by_sparse.values()))
             sp.set(expert_rows=rows.tolist(), dropped=int(dropped), wide_buffer=wide,
                    row_buffer_heights=[list(pair) for pair in by_height])
             if balance is not None:  # the ``aux_loss`` rule: the term before its weight, a routed layer and step

@@ -33,16 +33,17 @@ import pytest
 #: there (PR 45's second whole run on its builder's machine, 8 cores; CHANGES.md has the table of the last one).  A plain tuple: every xdist worker
 #: must collect the same order, so nothing here is measured at run time.
 LONGEST_FIRST = (
+    "test_delta_kernel_compiles.py",  # ~400 since PR 49: two cuts' whole train steps compiled for the described chip
     "test_benchmark_mel_faults.py",   # 378
     "test_benchmark_lag_faults.py",   # 356
     "test_benchmark_q3n_faults.py",   # 311
     "test_benchmark_nmh_faults.py",   # 326 (PR 46's whole run)
     "test_carry_builder.py",          # 274
     "test_benchmark_dsv2_faults.py",  # 260
+    "test_benchmark_kvl_faults.py",   # ~230 (PR 49: 14 planted faults, a process each)
     "test_multihost.py",              # 227
     "test_benchmark_nmh_correct.py",  # 246 (PR 46)
     "test_benchmark_q3n_correct.py",  # 216
-    "test_delta_kernel_compiles.py",  # 223 since PR 46: the Nemotron-H cut's whole train step compiled for the described chip
     "test_benchmark_lag_correct.py",  # 204
     "test_benchmark_nmh_mixer_faults.py",  # 184 (PR 46)
     "test_examples.py",               # 196
@@ -50,6 +51,7 @@ LONGEST_FIRST = (
     "test_routed_family.py",          # 148
     "test_mellum2.py",                # 141
     "test_routed_family_steps.py",    # 130
+    "test_benchmark_kvl_correct.py",  # ~130 (PR 49)
     "test_routed_family_shares.py",   # 126
     "test_lfm2_moe.py",               # 115
     "test_qwen3_next.py",             # ~110 (220 before its delta rule's tests got a file of their own)

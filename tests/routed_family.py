@@ -447,6 +447,15 @@ _NMH = dict(hidden_size=40, head_dim=16, num_attention_heads=4, num_key_value_he
             time_step_floor=1e-4, train_steps=3)
 
 
+#: The cut's shape at toy widths: two layers alike, 4 query heads over 2 key-value heads with the norm of q and k, rope
+#: by sections (2, 3 and 3 of a head's 8 pairs), an indexer of 8 heads of 8 over one key head that keeps 6 keys a query
+#: (24 positions in blocks of 8: the last two blocks' queries choose), 8 experts 3 a token.
+_KEYE = dict(hidden_size=40, head_dim=16, num_attention_heads=4, num_key_value_heads=2, moe_intermediate_size=24,
+             num_experts=8, num_experts_per_tok=3, held_experts=[2, 4], num_hidden_layers=2, vocab_size=64,
+             rms_norm_eps=1e-6, rope_theta=1e7, mrope_section=[2, 3, 3], indexer_num_heads=8, indexer_head_dim=8, topk=6,
+             train_steps=3)
+
+
 def nmh_blocks(*kinds, **over):
     """The toy model cut to the blocks ``kinds`` (a program needs a routed one: one follows a mixer that stands alone)."""
     kinds = list(kinds) if "routed" in kinds else list(kinds) + ["routed"]
@@ -558,6 +567,20 @@ ARCHS = {arch.name: arch for arch in (
                       "a_mamba2_block_of_both_groups": nmh_blocks("mamba2", held_mamba_heads=[0, 4]),
                       "the_cut": _NMH, "two_periods": nmh_blocks(*NMH_BLOCKS * 2)},
          tol=dict(logits=3e-5, gradient=3e-6, step=5e-5, eval=3e-5, shares=1e-4)),  # 64 shares added up in float32
+    Arch(name="keye_vl2", family="keye_vl2", model=_KEYE,
+         copied=("hidden_size", "head_dim", "moe_intermediate_size", "num_experts", "num_experts_per_tok",
+                 "num_attention_heads", "num_key_value_heads", "vocab_size", "rope_theta", "indexer_num_heads",
+                 "indexer_head_dim", "train_steps"),
+         extras=lambda m: dict(layer_types=("sparse_attention",) * m["num_hidden_layers"], num_dense_layers=0,
+                               held_experts=tuple(m["held_experts"]), norm_eps=m["rms_norm_eps"], qk_norm=True,
+                               sparse_topk=m["topk"], mrope_section=tuple(m["mrope_section"]), scoring_func="softmax",
+                               norm_topk_prob=True, balance_rule="aux_loss", tie_word_embeddings=False),
+         genes=_AUX_GENES, genome="deepseek_v2_genome", species="", positions=24,  # three blocks of 8, four times the 6 kept
+         weights=dict(std=STD), rule="aux_loss",
+         layer_cases={"a_sparse_layer": {**_KEYE, "num_hidden_layers": 1, "held_experts": [1, 5]},
+                      "a_layer_that_keeps_every_key": {**_KEYE, "num_hidden_layers": 1, "topk": 24, "held_experts": [1, 5]},
+                      "the_cut": {**_KEYE, "held_experts": [1, 5]}},
+         tol=dict(logits=3e-5, gradient=3e-6, eval=3e-5, shares=3e-5)),
 )}
 def long_tokens():
     """6 sequences of 512 positions: the smallest at which a configuration's row buffer has the whole ladder."""

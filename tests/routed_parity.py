@@ -72,11 +72,28 @@ def _nmh_tree(arch, cfg, m, w):
                                  "routed": ["ffn_norm", "moe"]}[kind]
 
 
+def _keye_tree(arch, cfg, m, w):
+    assert cfg.typed_attention and cfg.sparse_layers == tuple(range(m["num_hidden_layers"])) and cfg.qk_norm
+    assert cfg.sparse_topk == m["topk"] and cfg.mrope_section == tuple(m["mrope_section"])
+    shapes = M.param_shapes(cfg)
+    assert jax.tree_util.tree_map(lambda a: a.shape, w) == jax.tree_util.tree_map(lambda s: s, shapes, is_leaf=M._is_shape)
+    assert sorted(shapes["layers"][0]) == ["attn", "ffn_norm", "indexer", "moe", "op_norm"]
+
+
+def _keye_outcome(arch, cfg, m, bias, load, stats, forward):
+    """The pairs each layer kept are the arithmetic's (no two scores tie): every key of a query with no more than
+    ``topk`` of them, ``topk`` of every other's; and the indexers' losses are a positive number."""
+    length, top = arch.positions, min(m["topk"], arch.positions)
+    per_sequence = top * (top + 1) // 2 + (length - top) * top
+    np.testing.assert_array_equal(stats.selected, [2 * per_sequence] * m["num_hidden_layers"])
+    assert float(stats.indexer_loss) > 0
+
+
 #: architecture: (its assertions on the configuration and the tree, its assertions on the outcome)
 OWN = {"lfm2_moe": (None, _no_layer_took_the_wide_buffer), "deepseek_v2": (None, _no_layer_took_the_wide_buffer),
        "mellum2": (_mellum_tree, None),
        "qwen3_next": (_q3n_tree, None), "laguna": (_laguna_tree, _laguna_outcome),
-       "nemotron_h": (_nmh_tree, _laguna_outcome)}
+       "nemotron_h": (_nmh_tree, _laguna_outcome), "keye_vl2": (_keye_tree, _keye_outcome)}
 #: the parity test's router bias, where a rule reads one: (seed, deviation)
 PARITY_BIAS = {"lfm2_moe": (2, 0.1), "laguna": (3, 0.2), "nemotron_h": (3, 0.2)}
 STEP_BIAS = {"lfm2_moe": (1, 0.1), "laguna": (3, 0.2), "nemotron_h": (3, 0.2)}
@@ -89,7 +106,7 @@ def _reference_forward(arch, m, params, bias, tokens):
     if arch.rule == "bias":
         logits, load = arch.R.forward(m, params, bias, tokens)[:2]
         return logits, load, None
-    return arch.R.forward(m, params, tokens)
+    return arch.R.forward(m, params, tokens)  # a fourth entry where the model has an indexer: (its losses summed, the pairs kept)
 
 
 def logits_loss_and_every_gradient_match_the_reference(name, case):
@@ -107,6 +124,8 @@ def logits_loss_and_every_gradient_match_the_reference(name, case):
     def system_loss(params):
         logits, load, stats = M.forward(cfg, params, bias, x, remat=True)
         nll = M.token_loss(logits, y).mean()
+        if stats.indexer_loss is not None:  # the indexers' own term, at a weight of 1
+            nll = nll + stats.indexer_loss
         return (nll + ALPHA * stats.balance if balanced else nll), (logits, load, stats)
 
     def reference_loss(params):
@@ -114,6 +133,8 @@ def logits_loss_and_every_gradient_match_the_reference(name, case):
         logits = jnp.stack([o[0] for o in out])
         nll = jnp.mean(jnp.stack([R.token_loss(l, ys) for l, ys in zip(logits, y)]))
         balance = sum(o[2] for o in out) / len(out) if balanced else 0.0
+        if len(out[0]) > 3:
+            nll = nll + sum(o[3][0] for o in out) / len(out)
         return (nll + ALPHA * balance if balanced else nll), (logits, sum(o[1] for o in out), balance)
 
     with HIGHEST:
@@ -179,6 +200,9 @@ def two_train_steps_match_the_reference(name):
     else:
         np.testing.assert_allclose(state["bias"], ref["bias"], atol=1e-7)
         OWN_STEP[name](arch, state, ref, bias)
+    if "indexer_losses" in ref:  # the indexers' own term and the pairs they kept, summed on the device over the steps
+        np.testing.assert_allclose(float(state["indexer_loss"]), sum(ref["indexer_losses"]), rtol=arch.tolerance("loss"))
+        np.testing.assert_array_equal(np.asarray(state["selected_pairs"]), sum(ref["selected"]))
     for (path, a), b, start in zip(jax.tree_util.tree_flatten_with_path(state["params"])[0],
                                    jax.tree_util.tree_leaves(ref["weights"]), jax.tree_util.tree_leaves(w)):
         change, ref_change = np.asarray(a) - start, np.asarray(b) - start
@@ -203,7 +227,7 @@ def _one_layer(arch, kind, **over):
     return {**arch.model, "num_hidden_layers": 1, "layer_types": [kind], **over}
 
 
-#: id: (architecture, the one-layer model, its experts); every model is cut in shares of 2
+#: id: (architecture, the one-layer model, its experts[, the experts a share holds: 2 unless given])
 SHARES = {
     # 8 experts in 4 shares of 2: operator, residual and each share's own experts' part
     "lfm2_moe-conv": ("lfm2_moe", F.lfm2_one_layer("conv", "moe"), 8),
@@ -223,13 +247,17 @@ SHARES = {
     # share 5 times its routed part formed in the latent state; the two latent projections and the shared expert once
     "nemotron_h-routed": ("nemotron_h", _one_layer(ARCHS["nemotron_h"], "routed", n_routed_experts=128,
                                                    num_experts_per_tok=22), 128),
+    # 128 experts, 8 a token, in 8 shares of 16 (the cut's own numbers); attention, its indexer and the router once
+    "keye_vl2-sparse_attention": ("keye_vl2", {**ARCHS["keye_vl2"].model, "num_hidden_layers": 1, "num_experts": 128,
+                                               "num_experts_per_tok": 8}, 128, 16),
 }
 
 
 def the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(case):
     """Each share's program computes the operator, the residual, what is shared and its own routed experts' part;
     the routed parts, with what every share computes alike counted once, are the uncut reference's layer output."""
-    name, m, experts = SHARES[case]
+    name, m, experts, *share = SHARES[case]
+    step = share[0] if share else 2  # the experts a share holds
     arch = ARCHS[name]
     R, x = arch.R, arch.tokens[0][:2]
     uncut = {**m, "held_experts": [0, experts]}
@@ -245,15 +273,15 @@ def the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(case):
 
     def share(cfg, weights):
         layer = lambda p, e: M._layer(cfg, 0, jnp.float32, p, bias, e)[0]
-        return (jax.jit(layer) if name in ("qwen3_next", "nemotron_h") else layer)(weights, jnp.asarray(embedded))
+        return (jax.jit(layer) if name in ("qwen3_next", "nemotron_h", "keye_vl2") else layer)(weights, jnp.asarray(embedded))
 
     with HIGHEST:
         whole = reference_layer(uncut, layer_w)
         # operator, residual and what is shared, no routed expert: what every share computes alike
         alike = reference_layer({**uncut, "held_experts": [0, 0]}, share_of(0, 0))
         total = alike
-        for first in range(0, experts, 2):
-            part = share(arch.config_of({**m, "held_experts": [first, first + 2]}), share_of(first, first + 2)) - alike
+        for first in range(0, experts, step):
+            part = share(arch.config_of({**m, "held_experts": [first, first + step]}), share_of(first, first + step)) - alike
             assert float(jnp.abs(part).max()) > 0
             total = total + part
         np.testing.assert_allclose(total, whole, atol=arch.tolerance("shares"))

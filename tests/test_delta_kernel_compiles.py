@@ -1,4 +1,4 @@
-"""The delta rule's fused kernels, and the Nemotron-H cut's whole train step, compiled for a described TPU v5e, no chip attached: what Pallas' interpreter lets
+"""The delta rule's fused kernels, and the Nemotron-H and Keye-VL-2.0 cuts' whole train steps, compiled for a described TPU v5e, no chip attached: what Pallas' interpreter lets
 pass and the chip's compiler refuses (an op Mosaic has no rule for, more fast memory than a kernel may use, a slice
 off the tiling) fails here, in seconds, and not in a chip call.  Nothing runs, so nothing here is a time or a result.
 
@@ -82,3 +82,40 @@ def test_the_nemotron_h_cuts_train_step_compiles_for_the_described_chip_and_fits
     assert memory.alias_size_in_bytes > 8.7e9  # donated: the state is updated in place
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.generated_code_size_in_bytes
     assert held < 14.5e9 < 16 * 2**30, memory  # the gradients are among the temporaries: 12.2 GB when this was written
+
+
+def test_the_keye_vl2_cuts_train_step_compiles_for_the_described_chip_and_fits_it(one_chip, monkeypatch):
+    """The seventh routed architecture's published cut (``benchmark/configs/keye_vl2_30b_a3b_ep8.json``: 465 M
+    parameters, 7.45 GB of training state) as the chip's compiler takes it: the selection's bisection and the masked
+    core as XLA's query blocks over 16,384 positions (32 heads' float32 scores of a block of 512 queries against up
+    to 16,384 keys are 1 GB a copy: what has to fit beside the state), the grouped products at 16 held experts on
+    each of the ladder's heights; the step's arguments, temporaries and code together under the chip's 16 GiB
+    (``memory_analysis``), and no rematerialisation of the compiler's own (a form of the core that ran past the chip's
+    memory compiled with 222 of them and twice the bytes moved)."""
+    import routed_family as F
+    from gentun_tpu.models import lfm2_moe as M
+
+    _, _, cfg = F.published_cfg("keye_vl2", "keye_vl2_30b_a3b_ep8")
+    monkeypatch.setattr(M, "_use_megablox", lambda: True)  # ``jax.default_backend()`` is the CPU here
+    M._programs.cache_clear()
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # an entry written for a described chip cannot be read back
+    try:
+        programs = M._programs(cfg)
+        shaped = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        state = jax.tree_util.tree_map(shaped, jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32)))
+        tokens = jax.ShapeDtypeStruct((cfg.n_sequences, cfg.seq_len), jnp.int32, sharding=one_chip)
+        compiled = programs.train_step.lower(
+            state, tokens, tokens, jax.ShapeDtypeStruct((cfg.train_steps, cfg.batch_sequences), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((5,), jnp.float32, sharding=one_chip), jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+        M._programs.cache_clear()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert programs.sparse_core_layers == (("blockwise", 4),) and programs.attention_kernel_layers == 0
+    assert text.count("tpu_custom_call") > 50 and "splash_mqa" not in text  # the grouped products' kernels; no fused core
+    assert 5.58e9 < memory.argument_size_in_bytes < 5.60e9  # weights and AdamW's moments, 12 bytes a parameter, and the tokens
+    assert memory.alias_size_in_bytes > 5.58e9  # donated: the state is updated in place
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.generated_code_size_in_bytes
+    assert held < 13.5e9 < 16 * 2**30, memory  # the gradients are among the temporaries: 12.2 GB when this was written
+    assert text.count(".remat") == 0

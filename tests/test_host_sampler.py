@@ -463,7 +463,7 @@ def test_the_manifest_checks_and_lists_this_modules_metrics_for_the_cells_that_r
     assert set(NUMBERS) <= set(by_name)
     # the five cells there were (the sixth reads them as ``q3n_*``) and PR 42's, which reads the accepted entries
     cells = ["c10_flagship.popeval", "c100_deep.popeval", "lfm2_24b_a2b_ep8.popeval", "deepseek_v2_lite_ep8.popeval",
-             "mellum2_12b_a2p5b_ep8.popeval", "laguna_xs2_ep8.popeval"]
+             "mellum2_12b_a2p5b_ep8.popeval", "laguna_xs2_ep8.popeval", "keye_vl2_30b_a3b_ep8.popeval"]  # and PR 49's
     assert set(cells) <= {w["name"] for w in manifest["workloads"]}
     for name in NUMBERS:
         m = by_name[name]

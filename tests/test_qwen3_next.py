@@ -122,7 +122,8 @@ def test_flops_counts_the_block_pairs_the_kernel_would_visit():
 
 def test_the_kinds_of_layer_say_which_are_attention_over_keys():
     assert set(M.ATTENTION_KINDS) == {"full_attention", "sliding_attention", "latent_attention"}
-    assert set(M.LAYER_KINDS) == set(M.ATTENTION_KINDS) | {"conv", "linear_attention", "mamba2", "routed"}  # a scanning mixer, a feed-forward alone
+    # a scanning mixer, a feed-forward alone, and attention whose mask is data (its core is not ``_causal_core``'s)
+    assert set(M.LAYER_KINDS) == set(M.ATTENTION_KINDS) | {"conv", "linear_attention", "mamba2", "routed", "sparse_attention"}
     lfm2 = M.Lfm2MoeConfig()
     assert not lfm2.typed_attention and lfm2.rotary_dim == lfm2.head_dim == 64
     assert M.Lfm2MoeConfig(layer_types=("linear_attention", "full_attention"), layer_ids=(0, 1)).typed_attention

@@ -64,8 +64,9 @@ def _mellum_metrics(per_layer, cell):
     names = [m["name"] for m in mine]
     assert len(names) == 27 and all(n.startswith("mel_") for n in names), names  # 26 of PR 34, the row buffer's of PR 39
     # since PR 42 a second cell with window and full attention mixed reads all of them but the balance term's
-    assert {m["name"] for m in mine if m["workloads"] == [cell]} == {"mel_aux_loss_mean"}
-    assert all(m["workloads"] == [cell, "laguna_xs2_ep8.popeval"] for m in mine if m["name"] != "mel_aux_loss_mean")
+    # and since PR 49 a third, whose keys an indexer chooses, reads all 27 (it has a balance term too)
+    assert {m["name"] for m in mine if m["workloads"] == [cell, KEYE_CELL]} == {"mel_aux_loss_mean"}
+    assert all(m["workloads"] == [cell, "laguna_xs2_ep8.popeval", KEYE_CELL] for m in mine if m["name"] != "mel_aux_loss_mean")
     return names
 
 
@@ -86,7 +87,17 @@ def _laguna_metrics(per_layer, cell):
     assert len(names) == 30 and sum(n.startswith("mel_") for n in names) == 26 and "mel_aux_loss_mean" not in names, names
     assert {"device_stall_s", "stall_between_programs_s", "stall_host_late_s", "host_tick_late_max_ms"} < set(names)
     assert all(m["moves"] == ("setup_s" if m["name"] == "mel_first_call_s" else "individuals_per_hour_per_chip")
-               and m["workloads"][-1] == cell for m in per_layer if m["name"] in names)
+               and m["workloads"][-2:] == [cell, KEYE_CELL] for m in per_layer if m["name"] in names)
+    return names
+
+
+def _keye_metrics(per_layer, cell):
+    """The cell adds no metric: it is appended, last, to the 27 ``mel_*`` entries and the four stall entries."""
+    names = [m["name"] for m in per_layer if cell in m.get("workloads", ())]
+    assert len(per_layer) == 128 and len(names) == 31 and sum(n.startswith("mel_") for n in names) == 27, names
+    assert {"device_stall_s", "stall_between_programs_s", "stall_host_late_s", "host_tick_late_max_ms", "mel_aux_loss_mean",
+            "mel_full_core_roofline_share", "mel_window_core_roofline_share", "mel_train_mfu_executed"} < set(names)
+    assert all(m["workloads"][-1] == cell for m in per_layer if m["name"] in names)
     return names
 
 
@@ -102,8 +113,10 @@ def _nemotron_metrics(per_layer, cell):
     return names
 
 
+KEYE_CELL = "keye_vl2_30b_a3b_ep8.popeval"
 #: architecture: (its cell, its configuration file, the metrics the manifest gives the cell)
-CELLS = {"mellum2": ("mellum2_12b_a2p5b_ep8.popeval", "mellum2_12b_a2p5b_ep8", _mellum_metrics),
+CELLS = {"keye_vl2": (KEYE_CELL, "keye_vl2_30b_a3b_ep8", _keye_metrics),
+         "mellum2": ("mellum2_12b_a2p5b_ep8.popeval", "mellum2_12b_a2p5b_ep8", _mellum_metrics),
          "qwen3_next": ("qwen3_next_80b_a3b_ep16.popeval", "qwen3_next_80b_a3b_ep16", _q3n_metrics),
          "laguna": ("laguna_xs2_ep8.popeval", "laguna_xs2_ep8", _laguna_metrics),
          "nemotron_h": ("nemotron3_super_120b_a12b_ep64.popeval", "nemotron3_super_120b_a12b_ep64", _nemotron_metrics)}
@@ -161,6 +174,13 @@ REFUSALS = {
                    (dict(mamba_n_groups=3), "mamba2 layer needs"), (dict(ssm_state_size=0), "mamba2 layer needs"),
                    (dict(mamba_chunk=0), "mamba2 layer needs"), (dict(num_dense_layers=1), "no dense layer"),
                    (dict(layer_types=("mamba2", "state_space", "routed")), "layer_types")],
+    # an indexer has its sizes; rope's sections share out the pairs it turns; the layer reports through a routed feed-forward
+    "keye_vl2": [(dict(indexer_num_heads=0), "sparse_attention layer needs"), (dict(indexer_head_dim=7), "sparse_attention layer needs"),
+                 (dict(sparse_topk=0), "sparse_attention layer needs"), (dict(mrope_section=(2, 3, 2)), "mrope_section"),
+                 (dict(mrope_section=(8, 0, 0)), "mrope_section"), (dict(num_dense_layers=1), "no dense layer"),
+                 (dict(layer_types=("sparse_attention", "routed")), "no layer that is one half"),
+                 (dict(num_key_value_heads=3), "key-value heads"),
+                 (dict(layer_types=("sparse_attention", "sparse")), "layer_types")],
 }
 
 
@@ -301,6 +321,23 @@ PLACED = {
         ("jit(lm_eval)/layer8/cond/branch_1_fun/moe/combine/scatter-add", ("moe_route", "combine")),
         ("jit(lm_train_step)/jvp(head)/slh,vh->slv/dot_general", ("head_loss", "head")),
         ("jit(lm_train_step)/bias_update/sign", ("optimizer", "bias_update")),
+        ("jit(lm_train_step)/jvp(layer3)/rsqrt", ("rest", "layer3")),
+        ("", ("unattributed", ""))],
+    "keye_vl2": [  # the classes carry the accepted mel readers' names: full_core the masked core, window_core the indexer
+        ("jit(lm_train_step)/jvp(layer0)/sparse_attention/while/body/checkpoint/core/sqngd,sknd->sngqk/dot_general", ("full_core", "core")),
+        ("jit(lm_train_step)/transpose(jvp(layer0))/sparse_attention/while/body/rematted_computation/core/exp", ("full_core", "core")),
+        ("jit(lm_train_step)/jvp(layer1)/sparse_attention/while/body/indexer_scores/sqjd,skd->sqjk/dot_general", ("window_core", "indexer_scores")),
+        ("jit(lm_train_step)/jvp(layer1)/sparse_attention/while/body/select/while/body/reduce_sum", ("window_core", "select")),
+        ("jit(lm_train_step)/transpose(jvp(layer2))/sparse_attention/while/body/checkpoint/indexer_loss/log_softmax", ("window_core", "indexer_loss")),
+        ("jit(lm_eval)/layer3/sparse_attention/indexer_proj/slh,hjd->sljd/dot_general", ("attention_proj", "indexer_proj")),
+        ("jit(lm_train_step)/jvp(layer3)/sparse_attention/rope/mul", ("attention_proj", "rope")),
+        ("jit(lm_train_step)/transpose(jvp(layer3))/sparse_attention/proj/slngd,ngdh->slh/dot_general", ("attention_proj", "proj")),
+        ("jit(lm_train_step)/jvp(layer3)/sparse_attention/concatenate", ("attention_proj", "other")),
+        ("jit(lm_train_step)/jvp(layer1)/cond/branch_0_fun/moe/experts/jit(gmm)/pallas_call", ("expert_mm", "experts")),
+        ("jit(lm_train_step)/jvp(layer1)/moe/router/top_k", ("moe_route", "router")),
+        ("jit(lm_train_step)/jvp(layer1)/aux_loss/reduce_sum", ("moe_route", "aux_loss")),
+        ("jit(lm_train_step)/jvp(head)/slh,vh->slv/dot_general", ("head_loss", "head")),
+        ("jit(lm_train_step)/optimizer/sqrt", ("optimizer", "optimizer")),
         ("jit(lm_train_step)/jvp(layer3)/rsqrt", ("rest", "layer3")),
         ("", ("unattributed", ""))],
 }
