@@ -175,10 +175,16 @@ length, ``k`` = ``sparse_topk`` (2,048)::
               S_t = {s <= t : I[t, s] >= tau[t]}: a threshold, so ties are kept; sum_t |S_t| = k (k + 1) / 2 +
               (T - k) k when none ties, counted on the device.  The choice is made once, on the scores the
               threshold was found in, and handed on as bits (:func:`_sparse_selection`: 33.5 MB a layer and
-              sequence of 16,384, kept for the backward pass: the rematerialised forward does not select again)
-    core:     each of the 32 heads is softmax attention over S_t alone: a mask that is data, honoured by XLA's
-              query blocks (:func:`_sparse_core`: groups of four blocks of ``attn_block``, each against the keys up
-              to its group's last query; no (heads x T x T) array is ever alive); W_o
+              sequence of 16,384, kept for the backward pass: the rematerialised forward does not select again;
+              planes of bits over super-tiles of 4,096 keys, which a kernel unpacks with two integer ops,
+              :func:`gentun_tpu.models.sparse_kernel.packed`)
+    core:     each of the 32 heads is softmax attention over S_t alone: a mask that is data.  One function with two
+              programs: on a TPU, at a shape the kernels take (:func:`_use_sparse_kernel`), three fused kernels of the
+              repo's own (:mod:`gentun_tpu.models.sparse_kernel`, :func:`_sparse_kernel_core`: forward with online
+              softmax, its own backward, and the heads' share for L_I; they read the choice as bits, walk every tile
+              up to the diagonal, and no score reaches memory); anywhere else XLA's query blocks (:func:`_sparse_core`:
+              groups of four blocks of ``attn_block``, each against the keys up to its group's last query; no (heads x
+              T x T) array is ever alive), which are also what the kernels are tested against; W_o
     L_I:      p[t, s] = (1 / 32) sum_h prob_h[t, s] on S_t, a constant to the gradient;
               L_I = mean_t sum_{s in S_t} p log(p / softmax_{S_t}(I[t, :])), one a layer (DeepSeek-V3.2's sparse
               training stage).  W_qI, W_kI and W_w get their gradient from L_I alone; nothing else gets any from it
@@ -285,6 +291,12 @@ _ATTN_KERNEL_COLUMNS = 384
 #: and the widest product inside its one key block (:func:`_band_blocks`).
 _ATTN_KERNEL_CHUNKS = (256, 128, 512)
 _ATTN_BAND_COMPUTE = 768
+#: The (queries, keys) a grid step of the masked core's three kernels holds (:mod:`gentun_tpu.models.sparse_kernel`: a
+#: ``sparse_attention`` layer's forward, backward and heads' share), all the query heads of a key-value head in it.  Set by
+#: chip runs at the published shape (8 query heads a key-value head, 16,384 positions of 128 columns), each kernel timed
+#: alone over six tiles (``scripts/sparse_core_study.py``; PERF.md section 5 has the table): the backward and the share were
+#: fastest at this one, the forward 2% faster at 1,024 x 1,024, where the backward is 22% slower: they share one.
+_SPARSE_KERNEL_TILE = (512, 1024)
 #: The row buffer's heights below the worst case (top-k x tokens, always the last rung), in
 #: shares: times the rows a routed layer sends this rank on average.  A layer-step runs at the
 #: first that holds its rows; dispatch, combine and the experts' masks cost their height.
@@ -659,15 +671,19 @@ LINEAR_CORE_KERNEL_CHAIN_PRODUCTS = 2
 STATE_SPACE_CORE_PROGRAMS = ("chunked",)
 #: The masks the core has, as the spans and the counter name them.
 MASKS = ("causal", "window")
-#: The programs a ``sparse_attention`` layer's core has, as the spans and the counter name them (one: XLA's query blocks).
-SPARSE_CORE_PROGRAMS = ("blockwise",)
+#: The programs a ``sparse_attention`` layer's core has, as the spans and the counter name them: XLA's query blocks
+#: (:func:`_sparse_core`), and the fused kernels of :mod:`gentun_tpu.models.sparse_kernel` (:func:`_sparse_kernel_core`).
+SPARSE_CORE_PROGRAMS = ("blockwise", "kernel")
 #: The query blocks a group of the sparse core's table holds (:func:`_sparse_blocks`).
 _SPARSE_GROUP = 4
 #: What a ``sparse_attention`` layer keeps for its backward pass under rematerialisation, by name: the selection's choice,
-#: as bits (the rematerialised forward does not select again), and the core's output (134 MB a layer and sequence of
-#: 16,384: with it kept, the rematerialised forward does not run the core again; the backward pass runs each block's
-#: forward once, on its own: two forward runs of the core a step, not three).
-SPARSE_KEPT = ("sparse_kept", "sparse_out")
+#: as bits (the rematerialised forward does not select again), the core's output (134 MB a layer and sequence of
+#: 16,384: with it kept, the rematerialised forward does not run the core again; XLA's blocks then run each block's
+#: forward once more in the backward pass, on its own: two forward runs of the core a step, not three) and, where the
+#: core is the fused kernels, their log-sum-exp (float32 a head and query: 2 MB a layer, 32 MB as the chip lays out an
+#: array whose last axis is a key-value head's 8 query heads of 128 lanes; with ``out`` and it kept the forward kernel runs
+#: once a step and the backward kernel starts from them).
+SPARSE_KEPT = ("sparse_kept", "sparse_out", "sparse_lse")
 
 #: A program of this family is one individual wide, always: the published cut
 #: takes 10.4 of a chip's 16 GB in state alone, and a second width would be a
@@ -1247,24 +1263,42 @@ def _kept(index, tau):
     return (index >= tau[..., None]) & (index > -jnp.inf)
 
 
+def _use_sparse_kernel(length: int, group: int, size: int, block: int) -> bool:
+    """Whether the masked core of a ``sparse_attention`` layer of a program traced now runs as the fused kernels
+    (:mod:`gentun_tpu.models.sparse_kernel`): the backend is a TPU, a head of ``size`` is whole 128 lanes, the length is
+    whole super-tiles of the bits and whole tiles of the kernels, the loss pass's query block of ``block`` and the keys
+    its groups reach are whole tiles too, and a key-value head's dk and dv fit fast memory
+    (:func:`gentun_tpu.models.sparse_kernel.fits`).  A rule by backend and shape, as :func:`_use_attention_kernel`'s."""
+    if jax.default_backend() != "tpu":
+        return False
+    from . import sparse_kernel
+    block = min(block, length)
+    return sparse_kernel.fits(length, group, size, block, _SPARSE_GROUP * block, _SPARSE_KERNEL_TILE)
+
+
+def _sparse_kernel_dims(scale: float):
+    """The static sizes the masked core's kernels are called with: their tile (``_SPARSE_KERNEL_TILE``), the scores'
+    ``scale`` and the names their forward's residuals are kept under (``SPARSE_KEPT``'s second and third)."""
+    from . import sparse_kernel
+    return sparse_kernel.Dims(_SPARSE_KERNEL_TILE, scale, SPARSE_KEPT[1:])
+
+
 def _packed(kept):
-    """A mask (..., keys) as bits, eight keys a byte (..., ceil(keys / 8)) uint8, key ``8 i + j`` at bit ``j``."""
-    pad = -kept.shape[-1] % 8
-    if pad:
-        kept = jnp.pad(kept, [(0, 0)] * (kept.ndim - 1) + [(0, pad)])
-    bits = kept.reshape(*kept.shape[:-1], -1, 8).astype(jnp.uint8) << jnp.arange(8, dtype=jnp.uint8)
-    return jnp.sum(bits, axis=-1, dtype=jnp.uint8)
+    """A mask (..., keys) as bits: int32 words (..., ceil(keys / 4,096) x 128), bit ``b`` of word ``l`` of a super-tile
+    of 4,096 keys its key ``128 b + l`` (:func:`gentun_tpu.models.sparse_kernel.packed`: the layout is the kernels')."""
+    from . import sparse_kernel
+    return sparse_kernel.packed(kept)
 
 
 def _unpacked(bits, keys: int):
     """:func:`_packed` undone for the first ``keys`` keys: bool (..., keys)."""
-    kept = (bits[..., : -(-keys // 8), None] >> jnp.arange(8, dtype=jnp.uint8)) & jnp.uint8(1)
-    return kept.reshape(*bits.shape[:-1], -1)[..., :keys] == 1
+    from . import sparse_kernel
+    return sparse_kernel.unpacked(bits, keys)
 
 
 def _sparse_selection(q_idx, k_idx, w_idx, top: int, block: int):
-    """Which keys each query keeps, as bits (:func:`_packed`): uint8 (sequences, length,
-    ceil(length / 8)).  ``tau[t]`` is the ``top``-th largest indexer score among the keys up to
+    """Which keys each query keeps, as bits (:func:`_packed`): int32 (sequences, length, 128 a
+    super-tile of 4,096 keys: length / 32 at whole super-tiles).  ``tau[t]`` is the ``top``-th largest indexer score among the keys up to
     ``t`` (:func:`_kth_largest`), minus infinity where ``t`` has no more than ``top`` of them (it
     then keeps them all); query ``t`` keeps key ``s <= t`` iff ``I[t, s] >= tau[t]``
     (:func:`_kept`) -- decided HERE, on the scores the threshold was found in, and handed on as
@@ -1275,7 +1309,8 @@ def _sparse_selection(q_idx, k_idx, w_idx, top: int, block: int):
     ``sparse_attention`` layer keeps for its backward pass under rematerialisation (``SPARSE_KEPT``)."""
     length, block = q_idx.shape[1], min(block, q_idx.shape[1])
     q_idx, k_idx, w_idx = map(jax.lax.stop_gradient, (q_idx, k_idx, w_idx))
-    width = -(-length // 8)
+    from . import sparse_kernel
+    width = sparse_kernel.words(length)
 
     def one_block(qib, wb, first, kib):
         with jax.named_scope("indexer_scores"):
@@ -1350,6 +1385,45 @@ def _sparse_core(q, k, v, q_idx, k_idx, w_idx, chosen, scale: float, block: int)
     return jnp.concatenate(out, axis=1), loss, pairs
 
 
+def _sparse_kernel_core(q, k, v, q_idx, k_idx, w_idx, chosen, scale: float, block: int):
+    """:func:`_sparse_core` (its arguments and result) with the heads' scores on the chip's fast memory
+    (:mod:`gentun_tpu.models.sparse_kernel`): one fused kernel gives every head's output and log-sum-exp, its backward
+    kernel dq, dk and dv, and no score reaches memory.  ``scale`` multiplies the float32 products on the tile, as
+    :func:`_sparse_core`'s; the operands are handed over head-major, which is how :func:`_head_major` wrote them.  The loss pass stays XLA's, a query block at a time in :func:`_sparse_core`'s
+    groups: a block's indexer scores again, and ``p`` -- the heads' mean share of each kept key, exactly 0 on every
+    other pair -- from a third kernel that reads q, k, the log-sum-exp and the same bits (so the heads' scores a third
+    time, and a fourth in the block's own rematerialisation: two products of the nine a tile costs).  All that is the
+    heads' scores lies under the scope ``core``."""
+    from . import sparse_kernel
+
+    length, dims = q.shape[1], _sparse_kernel_dims(scale)
+    block = min(block, length)
+    with jax.named_scope("core"):
+        heads_q = q.astype(k.dtype).transpose(0, 2, 3, 1, 4)
+        heads_k, heads_v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        out, lse = sparse_kernel.core(heads_q, heads_k, heads_v, chosen, dims)
+        out = out.transpose(0, 3, 1, 2, 4)
+        seen = jax.lax.stop_gradient((heads_q, heads_k, lse))  # ``p`` is a constant to the gradient
+
+    @jax.checkpoint
+    def one_block(qib, kib, wb, bits, first):
+        with jax.named_scope("indexer_scores"):
+            index = _indexer_scores(qib, kib, wb, first)
+        with jax.named_scope("core"):
+            kept = _unpacked(bits, kib.shape[1])
+            share = sparse_kernel.heads_share(*seen, chosen, first, block, kib.shape[1], dims)
+        with jax.named_scope("indexer_loss"):
+            loss = _indexer_loss(index, share, kept)
+        return loss, jnp.sum(kept, dtype=jnp.int32)
+
+    loss, pairs = 0.0, 0
+    for first, last in _sparse_blocks(length, block):
+        body = lambda qib, wb, bits, at, last=last: one_block(qib, k_idx[:, :last], wb, bits, at)
+        l, n = _by_block(body, first, last, block, q_idx, w_idx, chosen)
+        loss, pairs = loss + jnp.sum(l, axis=0), pairs + jnp.sum(n)
+    return out, loss, pairs
+
+
 def _indexer_reads(x):
     """What the indexer reads of the layer's normed input: its value, DETACHED -- the indexer
     learns from its own loss and moves nothing it reads."""
@@ -1383,8 +1457,10 @@ def _sparse_attention(p, indexer, x, cfg: Lfm2MoeConfig, dtype, positions=None):
     (``positions`` (streams, length); None: the token's index in each).  The indexer's operands
     (:func:`_indexer_operands`) come from the layer's normed input detached.  Then the selection
     (:func:`_sparse_selection`: the keys whose score reaches the ``sparse_topk``-th largest among a
-    query's keys, as bits) and the core with the indexer's loss (:func:`_sparse_core`); the
-    selection and the core's output are kept for the backward pass under rematerialisation
+    query's keys, as bits) and the core with the indexer's loss: the fused kernels where
+    :func:`_use_sparse_kernel` says so (:func:`_sparse_kernel_core`), XLA's query blocks elsewhere
+    (:func:`_sparse_core`) -- one function, chosen by backend and shape; the selection, the core's
+    output and the kernels' log-sum-exp are kept for the backward pass under rematerialisation
     (``SPARSE_KEPT``).  Scopes: ``proj``, ``rope``, ``indexer_proj``,
     ``indexer_scores``, ``select``, ``core``, ``indexer_loss``.
 
@@ -1407,8 +1483,11 @@ def _sparse_attention(p, indexer, x, cfg: Lfm2MoeConfig, dtype, positions=None):
     with jax.named_scope("indexer_proj"):
         q_idx, k_idx, w_idx = _indexer_operands(indexer, x, cfg, dtype, positions)
     chosen = checkpoint_name(_sparse_selection(q_idx, k_idx, w_idx, cfg.sparse_topk, cfg.attn_block), SPARSE_KEPT[0])
-    out, loss, pairs = _sparse_core(q, k, v, q_idx, k_idx, w_idx, chosen, 1.0 / math.sqrt(hd), cfg.attn_block)
-    out = checkpoint_name(out, SPARSE_KEPT[1])
+    if _use_sparse_kernel(x.shape[1], nh // nkv, hd, cfg.attn_block):  # the kernels name what they keep themselves
+        out, loss, pairs = _sparse_kernel_core(q, k, v, q_idx, k_idx, w_idx, chosen, 1.0 / math.sqrt(hd), cfg.attn_block)
+    else:
+        out, loss, pairs = _sparse_core(q, k, v, q_idx, k_idx, w_idx, chosen, 1.0 / math.sqrt(hd), cfg.attn_block)
+        out = checkpoint_name(out, SPARSE_KEPT[1])
     with jax.named_scope("proj"):
         out = jnp.einsum("slngd,ngdh->slh", out, p["o"].astype(dtype).reshape(nkv, nh // nkv, hd, hidden))
     return out, (jnp.sum(loss) / (x.shape[0] * x.shape[1]), pairs)
@@ -2140,8 +2219,11 @@ class Lfm2MoePrograms(NamedTuple):
     ``state_space_core_layers``: the ``mamba2`` layers by the program their core runs as
     (``STATE_SPACE_CORE_PROGRAMS``: ``(("chunked", n),)``), empty where the configuration has none.
     ``sparse_core_layers``: the ``sparse_attention`` layers by the program their core runs as
-    (``SPARSE_CORE_PROGRAMS``: ``(("blockwise", n),)``), and ``sparse_core_visits``: what that core visits a head
-    and sequence off its own table (:func:`_sparse_visits`, as sorted items); both empty where the configuration has none.
+    (``SPARSE_CORE_PROGRAMS``: ``(("blockwise", n),)`` or ``(("kernel", n),)``, decided when they were built by
+    :func:`_use_sparse_kernel`: all or none), ``sparse_core_visits``: what XLA's query blocks visit a head
+    and sequence off their own table (:func:`_sparse_visits`, as sorted items: the selection and the loss pass walk it
+    whichever core runs), and ``sparse_kernel_visits``: what the fused kernels visit off theirs
+    (:func:`gentun_tpu.models.sparse_kernel.visits`), empty where XLA's blocks run; all empty where the configuration has none.
     Such a state also holds ``indexer_loss`` (the indexers' losses, summed over layers and steps) and
     ``selected_pairs`` (the (query, key) pairs each such layer kept, summed over the steps)."""
 
@@ -2160,6 +2242,7 @@ class Lfm2MoePrograms(NamedTuple):
     state_space_core_layers: Tuple[Tuple[str, int], ...] = ()
     sparse_core_layers: Tuple[Tuple[str, int], ...] = ()
     sparse_core_visits: Tuple[Tuple[str, int], ...] = ()
+    sparse_kernel_visits: Tuple[Tuple[str, int], ...] = ()
 
 
 def _init_leaf(name: str, key, index: int, shape):
@@ -2290,13 +2373,20 @@ def _programs(cfg: Lfm2MoeConfig) -> Lfm2MoePrograms:
             from . import delta_kernel
             by_kernels, inverse_products = linear, delta_kernel.inverse_products(cfg.delta_chunk, per_key)
     state_space = cfg.layer_types.count("mamba2")
+    sparse_program, sparse_kernel_visits = SPARSE_CORE_PROGRAMS[0], ()
+    if sparse and _use_sparse_kernel(cfg.seq_len, cfg.heads_of(cfg.sparse_layers[0]) // cfg.num_key_value_heads, cfg.head_dim,
+                                     cfg.attn_block):
+        from . import sparse_kernel
+        sparse_program = SPARSE_CORE_PROGRAMS[1]
+        sparse_kernel_visits = tuple(sorted(sparse_kernel.visits(cfg.seq_len, _SPARSE_KERNEL_TILE).items()))
     return Lfm2MoePrograms(cfg, jax.jit(init), jax.jit(train_step, donate_argnums=0), jax.jit(lm_eval),
                            sum(n for _, n in by_mask), tuple(by_mask), tuple(visits),
                            ((LINEAR_CORE_PROGRAMS[0], linear),) if linear else (), tuple(heads), tuple(rotary),
                            by_kernels, inverse_products,
                            ((STATE_SPACE_CORE_PROGRAMS[0], state_space),) if state_space else (),
-                           ((SPARSE_CORE_PROGRAMS[0], sparse),) if sparse else (),
-                           tuple(sorted(_sparse_visits(cfg.seq_len, cfg.attn_block).items())) if sparse else ())
+                           ((sparse_program, sparse),) if sparse else (),
+                           tuple(sorted(_sparse_visits(cfg.seq_len, cfg.attn_block).items())) if sparse else (),
+                           sparse_kernel_visits)
 
 
 # -- configuration, data ------------------------------------------------------------------------------
@@ -2519,10 +2609,12 @@ def _score_one(programs: Lfm2MoePrograms, init_base, genome_hash, genes, x, y, t
     if cfg.moe_latent_size:
         kernel_attrs["latent_experts_width"] = cfg.moe_latent_size
     by_sparse = {program: layers * cfg.train_steps for program, layers in programs.sparse_core_layers}
-    if by_sparse:  # static: the layers x steps, the indexer's sizes, whether the core is a kernel and what it visits
+    if by_sparse:  # static: the layers x steps, the indexer's sizes, where the core is the fused kernels and what each form visits
         kernel_attrs.update(sparse_attention_layer_steps=sum(by_sparse.values()), sparse_topk=cfg.sparse_topk,
-                            indexer_heads=cfg.indexer_num_heads, sparse_core_kernel_layer_steps=0,
-                            **{f"sparse_core_{name}": n for name, n in programs.sparse_core_visits})
+                            indexer_heads=cfg.indexer_num_heads,
+                            sparse_core_kernel_layer_steps=by_sparse.get(SPARSE_CORE_PROGRAMS[1], 0),
+                            **{f"sparse_core_{name}": n for name, n in programs.sparse_core_visits},
+                            **{f"sparse_kernel_{name}": n for name, n in programs.sparse_kernel_visits})
     with phase("init_params", {"individual": individual}, program=(id(programs.init),)) as sp:
         state = sp.fence(programs.init(init_base, genome_hash))
         genes = jnp.asarray(genes)

@@ -33,7 +33,7 @@ import pytest
 #: there (PR 45's second whole run on its builder's machine, 8 cores; CHANGES.md has the table of the last one).  A plain tuple: every xdist worker
 #: must collect the same order, so nothing here is measured at run time.
 LONGEST_FIRST = (
-    "test_delta_kernel_compiles.py",  # ~400 since PR 49: two cuts' whole train steps compiled for the described chip
+    "test_delta_kernel_compiles.py",  # ~350 since PR 50 (~400 at PR 49): two cuts' whole train steps compiled for the described chip
     "test_benchmark_mel_faults.py",   # 378
     "test_benchmark_lag_faults.py",   # 356
     "test_benchmark_q3n_faults.py",   # 311
@@ -48,6 +48,7 @@ LONGEST_FIRST = (
     "test_benchmark_nmh_mixer_faults.py",  # 184 (PR 46)
     "test_examples.py",               # 196
     "test_cnn_model.py",              # 182
+    "test_sparse_kernel.py",          # ~160 (PR 50: the masked core's kernels interpreted at 8,192 positions, four cases)
     "test_routed_family.py",          # 148
     "test_mellum2.py",                # 141
     "test_routed_family_steps.py",    # 130

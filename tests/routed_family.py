@@ -170,6 +170,26 @@ def kernel_on_the_cpu(monkeypatch):
     M._kernel_visits.cache_clear()
 
 
+def sparse_kernels_by_shape_alone(monkeypatch):
+    """``_use_sparse_kernel`` without its backend half: where ``jax.default_backend()`` is the CPU and a program is
+    lowered for, or interpreted as, a TPU's."""
+    from gentun_tpu.models import sparse_kernel
+
+    monkeypatch.setattr(M, "_use_sparse_kernel", lambda length, group, size, block: sparse_kernel.fits(
+        length, group, size, min(block, length), M._SPARSE_GROUP * min(block, length), M._SPARSE_KERNEL_TILE))
+
+
+@pytest.fixture()
+def sparse_kernels_on_the_cpu(monkeypatch):
+    """The masked core's fused kernels chosen by shape whatever the backend, and interpreted: the program has no such knob."""
+    real = M._sparse_kernel_dims
+    sparse_kernels_by_shape_alone(monkeypatch)
+    monkeypatch.setattr(M, "_sparse_kernel_dims", lambda scale: real(scale)._replace(interpret=True))
+    M._programs.cache_clear()
+    yield
+    M._programs.cache_clear()
+
+
 @pytest.fixture()
 def small_kernel_blocks(monkeypatch):
     """Blocks of 128: a few hundred positions are several blocks a side."""

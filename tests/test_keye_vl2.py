@@ -23,7 +23,7 @@ import routed_family as F
 from gentun_tpu import deepseek_v2_genome
 from gentun_tpu.models import lfm2_moe as M
 from gentun_tpu.telemetry.registry import get_registry
-from routed_family import HIGHEST
+from routed_family import HIGHEST, sparse_kernels_on_the_cpu  # noqa: F401  (the fixture)
 
 A = F.ARCHS["keye_vl2"]
 R, flops, scope_rules = A.R, A.flops, A.scope_rules
@@ -119,6 +119,56 @@ def test_the_layer_its_gradients_its_loss_and_its_count_are_the_references_over_
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
         assert float(jnp.abs(r).max()) > 0, jax.tree_util.keystr(path)
         np.testing.assert_allclose(g, r, atol=2e-5 * max(float(jnp.abs(r).max()), 1.0), rtol=1e-4, err_msg=jax.tree_util.keystr(path))
+
+
+#: One layer at the smallest shape the fused kernels take: one super-tile of the bits (4,096 positions, blocks of 512),
+#: 2 key-value heads of 2 query heads of 128 columns, 256 keys kept.
+KERNEL = {**A.model, "num_hidden_layers": 1, "held_experts": [1, 5], "head_dim": 128, "mrope_section": [16, 24, 24], "topk": 256}
+
+
+def test_the_layer_through_the_fused_kernels_is_the_references(sparse_kernels_on_the_cpu):
+    """The whole layer once with the masked core as the three kernels (interpreted), under ``forward``'s
+    rematerialisation that keeps the choice, the output and the log-sum-exp: its output, the indexer's loss, the count
+    of kept pairs and every gradient against the reference's written-out arrays."""
+    tok = np.random.default_rng(11).integers(0, 64, size=(2, 4097)).astype(np.int32)
+    cfg = A.config_of(KERNEL, tokens=(tok[:, :-1], tok[:, 1:]), attn_block=512, batch_sequences=1, eval_sequences=1)
+    tok = tok[:1]
+    programs = M._programs(cfg)
+    assert programs.sparse_core_layers == (("kernel", 1),) and dict(programs.sparse_kernel_visits)["tiles"] == 20
+    w_all = A.seeded_weights(KERNEL, 5)
+    w, embedded = w_all["layers"][0], jnp.asarray(w_all["embed"][tok[:, :-1]])
+    probe = jnp.asarray(np.random.default_rng(1).normal(size=embedded.shape), jnp.float32)
+
+    def ours(w):
+        policy = jax.checkpoint_policies.save_only_these_names(*M.SPARSE_KEPT)
+        fn = jax.checkpoint(lambda w: M._layer(cfg, 0, jnp.float32, w, None, embedded), policy=policy)
+        out, (_, stats) = fn(w)
+        return jnp.sum(out * probe) + stats.indexer_loss, (out, stats)
+
+    def reference(w):
+        out, _, _, (loss, pairs) = R.layer(KERNEL, 0, IDENTITY, w, embedded[0])
+        return jnp.sum(out * probe[0]) + loss, (out[None], loss, pairs)
+
+    def every(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from every(sub)
+
+    differentiated = list(every(jax.make_jaxpr(jax.grad(lambda w: ours(w)[0]))(w).jaxpr))
+    assert {e.params["name"] for e in differentiated if e.primitive.name == "name"} == set(M.SPARSE_KEPT)
+    calls = [str(e.params["name"]) for e in differentiated if e.primitive.name == "pallas_call"]
+    assert sum("sparse_core_fwd" in c for c in calls) == 1, "the forward kernel runs once: out and the log-sum-exp are kept"
+    assert sum("sparse_core_bwd" in c for c in calls) == 1 and sum("sparse_core_share" in c for c in calls) == 2 * 2
+    with HIGHEST:
+        (_, (out, stats)), grads = jax.jit(jax.value_and_grad(ours, has_aux=True))(w)
+        (_, (ref_out, ref_loss, ref_pairs)), ref_grads = jax.jit(jax.value_and_grad(reference, has_aux=True))(w)
+    np.testing.assert_allclose(out, ref_out, atol=1e-4)
+    np.testing.assert_allclose(stats.indexer_loss, ref_loss, rtol=2e-5)
+    assert float(ref_loss) > 1e-3 and int(stats.selected) == int(ref_pairs) >= _expected_pairs(4096, 256)  # ties at the threshold are kept
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(ref_grads)):
+        assert float(jnp.abs(r).max()) > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(g, r, atol=1e-4 * max(float(jnp.abs(r).max()), 1.0), rtol=1e-3, err_msg=jax.tree_util.keystr(path))
 
 
 def test_the_gradient_is_fenced_the_indexer_learns_from_its_loss_alone_and_nothing_else_learns_from_it(long_tokens, long_cfg):
@@ -235,7 +285,8 @@ def test_the_spans_and_the_counter_say_what_the_sparse_layers_did(long_tokens):
     assert np.isfinite(fitness).all()
     (train,), (fetch,) = F.span_attrs(records, steps=2), F.span_attrs(records, "fetch")
     assert train["sparse_attention_layer_steps"] == 4 and train["sparse_topk"] == 16 and train["indexer_heads"] == 8
-    assert train["sparse_core_kernel_layer_steps"] == 0, "XLA's query blocks: no kernel in this PR"
+    assert train["sparse_core_kernel_layer_steps"] == 0 and not any(k.startswith("sparse_kernel_") for k in train), \
+        "XLA's query blocks: the CPU's path, and every shape's that the kernels' rule refuses"
     assert (train["sparse_core_pairs"], train["sparse_core_elements"]) == (12, 32 * (32 + 64 + 96))
     assert fetch["selected_pairs"] == [2 * 2 * _expected_pairs(96, 16)] * 2, "a layer, over 2 steps of 2 sequences"
     assert 0.0 < fetch["indexer_loss_mean"] < 5.0 and fetch["dropped"] == 0 and fetch["aux_loss"] > 0.5
@@ -247,6 +298,24 @@ def test_the_spans_and_the_counter_say_what_the_sparse_layers_did(long_tokens):
     assert dict(programs.sparse_core_visits) == {"pairs": 12, "elements": 6144}
     state = jax.eval_shape(programs.init, jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))
     assert state["selected_pairs"].shape == (2,) and state["indexer_loss"].shape == ()
+
+
+def test_the_spans_and_the_counter_say_where_the_fused_kernels_ran(sparse_kernels_on_the_cpu):
+    """An individual through the kernels (interpreted): the ``train`` span counts its layers x steps under
+    ``sparse_core_kernel_layer_steps`` (what the accepted ``mel_full_kernel_layer_steps`` reads), carries what the kernels
+    visit off their own table beside what XLA's blocks (the selection, the loss pass) visit off theirs, and the counter
+    names the program."""
+    tok = np.random.default_rng(12).integers(0, 64, size=(2, 4097)).astype(np.int32)
+    kw = A.model_kwargs({**KERNEL, "train_steps": 1}, cache_dir=False, attn_block=512, batch_sequences=1, eval_sequences=1)
+    with F.traced() as records:
+        fitness = M.Lfm2MoeModel.cross_validate_population(tok[:, :-1], tok[:, 1:], [deepseek_v2_genome().default()], **kw)
+    assert np.isfinite(fitness).all()
+    (train,), (fetch,) = F.span_attrs(records, steps=1), F.span_attrs(records, "fetch")
+    assert train["sparse_attention_layer_steps"] == train["sparse_core_kernel_layer_steps"] == 1
+    assert (train["sparse_kernel_tiles"], train["sparse_kernel_elements"], train["sparse_kernel_elements_bwd"]) == (20, 20 * 512 * 1024, 20 * 512 * 1024)
+    assert (train["sparse_core_pairs"], train["sparse_core_elements"]) == (8, 2048 * (2048 + 4096))
+    assert fetch["selected_pairs"][0] >= _expected_pairs(4096, 256) and 0.0 < fetch["indexer_loss_mean"] < 5.0
+    assert get_registry().counter("sparse_attention_layer_steps_total", program="kernel").value == 1
 
 
 # -- the benchmark's family: configuration file, counts, readers -----------------------------------------------------------

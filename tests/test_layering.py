@@ -84,7 +84,8 @@ MAY_IMPORT = {
 #: the one arrow that points up, under the name of its debt
 EXCEPTIONS = {("telemetry.canary", "distributed.sessions"): "ROADMAP D4: the canary plane probes through a session client"}
 #: a model family's own modules; ``evaluation`` and ``generic`` serve every family
-FAMILIES = {"cnn": {"cnn"}, "lfm2_moe": {"lfm2_moe", "delta_kernel"}, "delta_kernel": {"delta_kernel"},
+FAMILIES = {"cnn": {"cnn"}, "lfm2_moe": {"lfm2_moe", "delta_kernel", "sparse_kernel"}, "delta_kernel": {"delta_kernel"},
+            "sparse_kernel": {"sparse_kernel"},
             "boosting": {"boosting"}, "xgboost": {"xgboost"}, "evaluation": set(), "generic": set()}
 SHARED_BY_THE_FAMILIES = {"evaluation", "generic"}
 #: inside ``distributed``: what the planes are built from knows none of the parties
