@@ -2,11 +2,13 @@
 
 Every name, unit and layer against the character rules; every ``moves``
 names an end-to-end metric that every cell of the per-layer metric reports;
-every file a cell needs exists; every configuration states its model
-``family``, whose directory ``families/<family>/`` lies under ``paths`` and
-holds the family's files and the four functions the harness calls; and
+every file a cell needs exists and ``layer_metrics/`` holds no reader that no
+entry names; every configuration states its model ``family``, whose directory
+``families/<family>/`` lies under ``paths`` and holds the family's files and
+the four functions the harness calls; and
 ``trace_reduce.reduce`` gives the fixture's expected busy time, idle share,
-top ops and named gaps.
+top ops and named gaps.  The last line counts cells, per-layer metrics and the
+manifest's bytes (the contract's limits: 24, 128, 65,536).
 """
 
 from __future__ import annotations
@@ -142,6 +144,8 @@ def check(manifest) -> list:
              f"metric {m['name']}: a cell of it does not report {m['moves']}")
         layered |= listed
     need(layered == set(cells), "a cell without a per-layer metric")
+    readers = {f[:-3] for f in os.listdir(os.path.join(HERE, "layer_metrics")) if f.endswith(".py")}
+    need(readers <= names, f"layer_metrics/ holds readers no entry names: {sorted(readers - names)}")
     need(len(json.dumps(manifest)) < 64 * 1024, "manifest over 64 KiB")
     return bad
 
@@ -166,7 +170,8 @@ def main() -> int:
     for line in bad:
         print("check_manifest:", line)
     print("check_manifest:", "FAILED" if bad else
-          f"ok ({len(manifest['workloads'])} cells, {len(manifest['per_layer'])} per-layer metrics)")
+          f"ok ({len(manifest['workloads'])} cells, {len(manifest['per_layer'])} per-layer metrics, "
+          f"{len(json.dumps(manifest))} bytes)")
     return 1 if bad else 0
 
 

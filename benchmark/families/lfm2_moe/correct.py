@@ -66,11 +66,13 @@ def _leaves(tree) -> List[Tuple[str, Any]]:
 
 def check_inputs(ctx) -> Dict[str, Any]:
     """What both sides start from, all from the seed: weights, a router bias
-    large enough that ignoring it changes the choice, the batches, the recipe."""
+    large enough that ignoring it changes the choice, the batches (rows of the
+    seed's own tokens, ``ctx.check_x``: the window's come from the
+    configuration's ``window_seed``, ``family.make_inputs``), the recipe."""
     check, cfg = ctx.config["check"], ctx.config
     rng = np.random.default_rng([ctx.seed, 0xC0DE])
     n_routed = cfg["num_hidden_layers"] - cfg["num_dense_layers"]
-    n_train = ctx.x.shape[0] - cfg["run"]["eval_sequences"]
+    n_train = ctx.check_x.shape[0] - cfg["run"]["eval_sequences"]
     rows = rng.permutation(n_train)[:cfg["train_steps"] * cfg["run"]["batch_sequences"]]
     return {"weights": reference.seeded_weights(ctx.model, ctx.seed, check["weight_std"]),
             "bias": (check["bias_std"] * rng.standard_normal((n_routed, cfg["num_experts"]))).astype(np.float32),
@@ -88,7 +90,7 @@ def program_side(ctx) -> Dict[str, Any]:
     t0 = time.monotonic()
     inputs = check_inputs(ctx)
     programs = Lfm2MoeModel.compiled_programs(ctx.x, **ctx.params)
-    x, y = jnp.asarray(ctx.x), jnp.asarray(ctx.y)
+    x, y = jnp.asarray(ctx.check_x), jnp.asarray(ctx.check_y)
     state = programs.init(jax.random.PRNGKey(0), jnp.zeros(2, jnp.uint32))  # the state's form; its weights go
     state = {**state, "params": jax.device_put(inputs["weights"]), "bias": jnp.asarray(inputs["bias"])}
     nll = np.asarray(programs.eval(state["params"], state["bias"], x, y, jnp.asarray(inputs["eval_rows"])))
@@ -114,7 +116,7 @@ def reference_side(ctx, inputs: Dict[str, Any], control: Optional[str] = None) -
 
     m = ctx.model
     lo, hi = m["held_experts"]
-    x, y = ctx.x, ctx.y
+    x, y = ctx.check_x, ctx.check_y
     nll = reference.eval_token_loss(m, inputs["weights"], jnp.asarray(inputs["bias"]), x[inputs["eval_rows"]],
                                     y[inputs["eval_rows"]], control)
     batches = [(x[r], y[r]) for r in inputs["train_rows"][:inputs["steps"]]]

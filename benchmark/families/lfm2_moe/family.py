@@ -85,14 +85,30 @@ def make_pool(size: int, seed, log10_lr_max: float) -> List[Dict[str, float]]:
 
 
 def make_inputs(config: Dict[str, Any], mix: Dict[str, Any], seed: int, rehearsal: bool = False) -> Dict[str, Any]:
-    """Tokens (``x``) and next tokens (``y``) and the seed of the recipes'
-    starting weights, all from the seed; the pool of recipes from the mix's
-    ``pool_seed``."""
-    data = config["data"]
-    tokens = markov_tokens(data, config["vocab_size"], config["n_sequences"], data["seq_len"], seed)
+    """What the window trains on and what the check compares, apart.
+
+    **The window's pool is one fixed pool, whole**, as the four later routed
+    configurations have it: the recipes come from the mix's ``pool_seed``, and
+    the seed of their starting weights and the tokens (``x``, ``y``) from the
+    configuration's ``window_seed``; ``--seed`` gives the window the order of
+    each call (``traffic_kinds/lmpopeval.py``) and nothing else.  A routed
+    model's work follows its routing, and the routing follows the starting
+    weights and the tokens: with both from ``--seed`` the rate ran from
+    1,146 to 1,160 ind/h/chip by seed, the same seed reading the same rate
+    again, and the driver's check refused the cell (quartiles 0.47-0.63% of
+    the median apart against a bound of 1%: PERF.md, PR 51).  So every seed
+    does the same work, as every seed scores the same recipes.
+    ``window_seed`` is the median draw of the eleven measured (the
+    configuration's ``assumed.window_inputs``).
+
+    **The check's inputs come from ``--seed``** as they always did: its tokens
+    (``check_x``, ``check_y``), its weights, its bias, its batches (``correct.py``)."""
+    data, window_seed = config["data"], int(config["window_seed"])
+    window, check = (markov_tokens(data, config["vocab_size"], config["n_sequences"], data["seq_len"], s)
+                     for s in (window_seed, seed))
     pool = make_pool(config["population"], [int(mix["pool_seed"])], float(mix["pool_log10_lr_max"]))
-    return {"params": model_params(config, seed, rehearsal), "x": tokens[:, :-1], "y": tokens[:, 1:],
-            "pool": pool, "model": model_block(config)}
+    return {"params": model_params(config, window_seed, rehearsal), "x": window[:, :-1], "y": window[:, 1:],
+            "check_x": check[:, :-1], "check_y": check[:, 1:], "pool": pool, "model": model_block(config)}
 
 
 def window_checks(ctx, units: List[Dict[str, Any]]) -> List[Dict[str, Any]]:

@@ -11,15 +11,22 @@ mix (``traffic/<traffic>.json``, which names its kind), the kind's module
 metric (``layer_metrics/<metric>.py``).  The harness itself names no model,
 no gene and no data shape.  See ``README.md`` beside this file.
 
-Set-up (backend, the family's inputs from ``--seed``, warm-up of the cell's
-own programs, the program's half of the correctness check) ends where the
-window opens.  The window starts a new unit of work only while less than
-``--seconds`` have passed and closes when the unit in flight returns; every
-rate divides by the time that really passed.  The reference's half of the
-check runs after the window, outside ``setup_s`` and after the peak memory has
-been read.  Each number compared is printed beside its limit on a ``check``
-line; the last line of standard output is the result object and holds the
-contract's keys only.
+Set-up has four parts, read off the clock between its statements, printed on
+the ``set-up:`` line and handed to the readers as ``reading["setup"]``
+(``layer_metrics/setup_warmup_s.py`` reads the third; the manifest has no room
+for the others yet): the backend (``T_START`` to ``require_device`` returning),
+the inputs (the compile cache's directory, the family's files,
+``family.make_inputs`` from ``--seed``), the warm-up (``kind.setup``: every
+program's first call and one pass over the pool) and the program's half of the
+correctness check (``family.program_side`` up to the window's opening; in a
+traced run ``jax.profiler.start_trace`` lies here).  They sum to ``setup_s``,
+which ends where the window opens.  The window starts a new unit of work only
+while less than ``--seconds`` have passed and closes when the unit in flight
+returns; every rate divides by the time that really passed.  The reference's
+half of the check runs after the window, outside ``setup_s`` and after the peak
+memory has been read.  Each number compared is printed beside its limit on a
+``check`` line; the last line of standard output is the result object and holds
+the contract's keys only.
 
 ``--rehearsal`` runs the same code at the configuration's ``rehearsal`` sizes
 on whatever jax comes up on; its result says so and is never ``correct``.
@@ -228,7 +235,8 @@ def run(args) -> Dict[str, Any]:
     kind = load_module("traffic_kinds", mix["kind"])
 
     device = require_device(cell["chips"], args.rehearsal)
-    backend_s = time.monotonic() - T_START
+    t_backend = time.monotonic()
+    backend_s = t_backend - T_START
     import jax
 
     from gentun_tpu.telemetry import spans
@@ -246,9 +254,11 @@ def run(args) -> Dict[str, Any]:
     ctx = Ctx(config=config, mix=mix, cell=cell, seed=args.seed, monitor=monitor, records=records,
               trace=bool(args.trace), rehearsal=args.rehearsal, chips=cell["chips"],
               **family.make_inputs(config, mix, args.seed, args.rehearsal))
+    t_inputs = time.monotonic()
 
     memory = MemoryPeak()
     state = kind.setup(ctx, mix)
+    t_warm = time.monotonic()
     print("info memory_stats after the warm-up call, the window's programs loaded and no other:",
           json.dumps(memory.sample()))
     program = family.program_side(ctx)
@@ -263,6 +273,8 @@ def run(args) -> Dict[str, Any]:
         tracing = True
     t_open, open_wall = time.monotonic(), time.time()
     setup_s = t_open - T_START
+    setup_parts = {"backend_s": backend_s, "inputs_s": t_inputs - t_backend, "warmup_s": t_warm - t_inputs,
+                   "program_check_s": t_open - t_warm}
     if tracing:
         anchor_wall = time.time()
         with jax.profiler.TraceAnnotation("bench_anchor"):
@@ -313,8 +325,9 @@ def run(args) -> Dict[str, Any]:
     print(f"window: {len(units)} units, {scored} individuals scored, {trained} trained, "
           f"elapsed {elapsed:.4f} s of {args.seconds} asked; unit walls "
           f"{[round(u['wall_s'], 3) for u in units]}")
-    print(f"set-up: {setup_s:.3f} s (backend {backend_s:.3f} s); jax asked for {setup_requests} "
-          f"programs, {setup_hits} from the cache at {cache_dir}, {setup_compiles} backend compiles; "
+    print(f"set-up: {setup_s:.3f} s (backend {backend_s:.3f} s, inputs {setup_parts['inputs_s']:.3f} s, warm-up "
+          f"{setup_parts['warmup_s']:.3f} s, program's check {setup_parts['program_check_s']:.3f} s); jax asked for "
+          f"{setup_requests} programs, {setup_hits} from the cache at {cache_dir}, {setup_compiles} backend compiles; "
           f"in the window: {monitor.requests_in_window()} asked for; reference {reference_s:.3f} s, "
           f"not in setup_s")
 
@@ -343,7 +356,7 @@ def run(args) -> Dict[str, Any]:
             breakdown = {"device_ops": reduction["device_ops"], "idle_gaps": reduction["idle_gaps"]}
         reading = {"config": config, "cell": cell, "chips": cell["chips"], "units": units,
                    "records": records.items, "window": (open_wall, close_wall), "elapsed": elapsed,
-                   "monitor": monitor, "trace": reduction, "memory_peak_bytes": memory_peak,
+                   "monitor": monitor, "trace": reduction, "setup": setup_parts, "memory_peak_bytes": memory_peak,
                    "peak": None if args.rehearsal else load_json(HERE, "peaks.json")["peaks"][device["kind"]]}
         for m in manifest["per_layer"]:
             if applies(m, cell["name"]):

@@ -13,7 +13,7 @@ optimised ``HloProto`` (stat "Hlo Proto"), every instruction of every fused
 computation with its ``op_name``.  "XLA Modules" has one event per program run,
 named ``jit_<function>(<fingerprint>)``, the name the HLO proto is filed under.
 The host plane's "python" line has the program's ``gentun/<kind>`` annotations
-(``models/cnn.py::_phase``) with their scalars as stats (``n_real``, ``fold``).
+(``models/evaluation.py::phase``) with their scalars as stats (``n_real``, ``fold``).
 
 This file is the trace: the protobuf, matching events to modules, self time,
 the fusion vote, ``per_individual``.  What is the model -- which programs to

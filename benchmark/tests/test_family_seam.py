@@ -9,10 +9,12 @@ model family is added by new files and manifest entries alone (CPU, pytest).
   its entries appended to the copy's manifest; ``check_manifest.py`` passes
   there, a ``--rehearsal --trace 1`` run of the fixture's cell ends in a result
   line with the fixture's own inputs, checks and reader having run and none of
-  the Genetic-CNN's, and every file that was there before is byte-identical;
+  the Genetic-CNN's, set-up's four parts sum to ``setup_s``, and every file that
+  was there before is byte-identical;
 - ``check_manifest.py`` refuses, with a line that says why, a configuration
   without ``family``, a family without its directory, its files or one of the
-  four functions, and a family directory outside ``paths``.
+  four functions, a family directory outside ``paths``, and a reader under
+  ``layer_metrics/`` that no entry names.
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -106,7 +109,11 @@ def test_a_second_family_is_added_by_files_and_entries_alone(tmp_path):
     rehearsal = next(l for l in lines if l.startswith("rehearsal (no measurement): checks say "))
     assert rehearsal.startswith("rehearsal (no measurement): checks say True ")
     metrics = json.loads(rehearsal.split("checks say True ", 1)[1])
-    assert list(metrics) == ["tiny_lm_loss_mean"] and metrics["tiny_lm_loss_mean"]["value"] > 0.0
+    assert list(metrics) == ["setup_warmup_s", "tiny_lm_loss_mean"] and metrics["tiny_lm_loss_mean"]["value"] > 0.0
+    # ... and set-up's four parts, which the harness reads off its own clock in any family's cell, sum to setup_s
+    setup_s, *parts = map(float, re.search(r"^set-up: (\S+) s \(backend (\S+) s, inputs (\S+) s, warm-up (\S+) s, "
+                                           r"program's check (\S+) s\)", ran.stdout, re.M).groups())
+    assert abs(sum(parts) - setup_s) < 0.003 and abs(metrics["setup_warmup_s"]["value"] - parts[2]) < 0.001, (setup_s, parts)
 
     # Nothing that was there was edited: every file byte-identical, every old entry in place.
     after, manifest_after = hashes(tree), manifest_of(tree)
@@ -164,6 +171,12 @@ def _outside_paths(tree):
                lambda m: m.update(paths=["benchmark/configs", "benchmark/traffic"]))
 
 
+def _reader_left_behind(tree):
+    """An entry taken out of the manifest (as ``setup_oom_attempt_s`` was) whose reader stayed."""
+    with open(os.path.join(tree, "benchmark", "layer_metrics", "setup_oom_attempt_s.py"), "w", encoding="utf-8") as fh:
+        fh.write("def read(run):\n    return None\n")
+
+
 @pytest.mark.parametrize("edit,why", [
     (_no_family, "config c10_flagship: its file states no 'family'"),
     (_no_directory, "config c100_deep: family 'no_such_family' has no directory benchmark/families/no_such_family/"),
@@ -173,8 +186,9 @@ def _outside_paths(tree):
     (_without("after_window"), "benchmark/families/genetic_cnn/family.py lacks after_window"),
     (_without("window_checks"), "benchmark/families/genetic_cnn/family.py lacks window_checks"),
     (_outside_paths, "family directory benchmark/families/genetic_cnn/ is outside paths"),
+    (_reader_left_behind, "layer_metrics/ holds readers no entry names: ['setup_oom_attempt_s']"),
 ], ids=["no_family", "no_directory", "no_reference", "no_make_inputs", "no_program_side", "no_after_window",
-        "no_window_checks", "outside_paths"])
+        "no_window_checks", "outside_paths", "reader_left_behind"])
 def test_check_manifest_refuses_a_family_that_is_not_whole(tmp_path, edit, why):
     tree = copy_of_the_benchmark(tmp_path)
     assert run_in(tree, "benchmark/check_manifest.py").returncode == 0

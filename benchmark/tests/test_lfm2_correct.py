@@ -60,11 +60,11 @@ def _plant(fault: str) -> None:
     elif fault == "an_expert_outside_the_share":
         real_ffn = M._moe_ffn
 
-        def with_a_foreign_expert(p, bias, x, cfg, dtype):
-            out, load, dropped = real_ffn(p, bias, x, cfg, dtype)
+        def with_a_foreign_expert(p, bias, x, cfg, dtype, *args, **kw):
+            out, load, dropped = real_ffn(p, bias, x, cfg, dtype, *args, **kw)
             beyond = dataclasses.replace(cfg, held_experts=(cfg.held_experts[1], cfg.held_experts[1] + 1))
             foreign = {k: (v if k == "router" else v[:1]) for k, v in p.items()}  # expert 0's weights stand in
-            return out + real_ffn(foreign, bias, x, beyond, dtype)[0], load, dropped
+            return out + real_ffn(foreign, bias, x, beyond, dtype, *args, **kw)[0], load, dropped
 
         M._moe_ffn = with_a_foreign_expert
     elif fault == "learning_rate_a_fifth_high":

@@ -3,13 +3,16 @@
 Unit of work: one ``Lfm2MoeModel.cross_validate_population`` call on the cell's
 pool of genomes (``families/lfm2_moe/family.py::make_pool``: the recipe's
 defaults and draws from its ranges), taken in a new order each call, closed
-loop, the fitness cache bypassed.  The order, the tokens and the seed of the
-recipes' starting weights come from ``--seed``; the pool comes from the mix
-(``pool_seed``; no recipe hotter than ``pool_log10_lr_max``), so every seed
-trains the same recipes in other positions, from other weights, on other data.
-A routed model's work follows its routing: what keeps it alike from seed to
-seed is a token law with many effective ids and a pool none of whose recipes
-diverges (PERF.md, PR 28).
+loop, the fitness cache bypassed.  The order comes from ``--seed``; the pool
+comes from the mix (``pool_seed``; no recipe hotter than ``pool_log10_lr_max``);
+the tokens and the seed of the recipes' starting weights are what the family's
+``make_inputs`` hands over: one fixed draw (the configuration's ``window_seed``)
+in every routed configuration but DeepSeek-V2-Lite's and Qwen3-Next's, whose
+come from ``--seed``.
+A routed model's work follows its routing: a token law with many effective ids
+and a pool none of whose recipes diverges keep it nearly alike from seed to
+seed (PERF.md, PR 28), and only one draw for every seed keeps it alike
+(PERF.md, PRs 34, 42, 46, 51).
 """
 
 from __future__ import annotations
